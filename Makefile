@@ -169,7 +169,7 @@ bench:
 	python bench.py
 
 # Perf-regression diff of two bench JSON files (docs/benchmarking.md
-# "Comparing runs"): make bench-compare BASE=BENCH_r05.json CAND=BENCH_r06.json
+# "Comparing runs"): make bench-compare BASE=base.json CAND=cand.json
 bench-compare:
 	python -m tools.bench_compare $(BASE) $(CAND)
 

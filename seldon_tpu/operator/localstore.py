@@ -155,8 +155,9 @@ class LocalProcessStore:
         pod = _Pod()
         pod_spec = manifest["spec"]["template"]["spec"]
         containers = pod_spec["containers"]
+        # Inherited as is: a unit runs on whatever platform this process
+        # would (JAX_PLATFORMS included) — nothing is defaulted to CPU.
         base_env = dict(os.environ)
-        base_env["JAX_PLATFORMS"] = base_env.get("JAX_PLATFORMS", "cpu")
         base_env["PYTHONPATH"] = (
             self.repo_root + os.pathsep + base_env.get("PYTHONPATH", "")
         )

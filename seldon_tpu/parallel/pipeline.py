@@ -32,8 +32,6 @@ from seldon_tpu.models import transformer
 from seldon_tpu.models.config import ModelConfig
 from seldon_tpu.models.transformer import _dtype
 from seldon_tpu.parallel import sharding as shd
-from seldon_tpu.parallel import compat
-from seldon_tpu.parallel.compat import shard_map
 
 
 def pp_param_pspecs(cfg) -> Dict[str, Any]:
@@ -120,7 +118,8 @@ def make_pipeline_forward(
         # in f32 (same CPU-backend bf16 all-reduce workaround as below) by
         # casting AFTER the pcast.
         def pvary(shape, dtype):
-            z = compat.pvary(jnp.zeros(shape, jnp.float32), ("pp",))
+            z = jax.lax.pcast(jnp.zeros(shape, jnp.float32), ("pp",),
+                              to="varying")
             return z.astype(dtype)
 
         dt = _dtype(cfg)
@@ -179,15 +178,13 @@ def make_pipeline_forward(
         return hidden.reshape(-1, *hidden.shape[2:]), aux_mean
 
     # Partial-manual ('pp' manual, dp/tp/... auto) lets GSPMD shard the
-    # stage bodies internally; the pinned 0.4.x partial-auto mode is
-    # broken (see compat.PARTIAL_AUTO), and since no spec here mentions
-    # an auto axis, full-manual is semantically identical there.
-    staged_sm = shard_map(
+    # stage bodies internally.
+    staged_sm = jax.shard_map(
         staged,
         mesh=mesh,
         in_specs=(block_manual_specs, P(), P(), P(), P()),
         out_specs=(P(), P()),
-        axis_names=frozenset({"pp"}) if compat.PARTIAL_AUTO else None,
+        axis_names=frozenset({"pp"}),
         check_vma=False,
     )
 

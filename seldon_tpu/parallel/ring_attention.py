@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from seldon_tpu.parallel.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -140,7 +139,7 @@ def ring_attention(
                 .reshape(B, s, H, Dh))
 
     spec = P(None, axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -41,14 +41,16 @@ Peak provenance (``snapshot()["peaks"]["source"]``):
    published TPU specs; W8A8 int8 runs the MXU at 2x this basis, so an
    int8-serving MFU of ~0.5 is the practical ceiling — documented in
    docs/benchmarking.md "Reading the roofline");
- * ``microbench`` — unknown platform (CPU smoke runs): a one-shot
+ * ``microbench`` — the host CPU only (CPU smoke runs): a one-shot
    cached numpy matmul + memcpy calibration, run at ``bind()`` time
    (engine init — cold path, never under ``_book``).
 
+An accelerator ``device_kind`` the table does not know is an error
+(``resolve_peaks`` raises), never a default.
+
 Pure stdlib — no jax import, like ``shape_lattice`` — so lint and tools
-can load it anywhere; numpy for the calibration fallback is imported
-lazily inside the microbench and failure degrades to fixed conservative
-constants.
+can load it anywhere; numpy for the CPU calibration is imported lazily
+inside the microbench.
 
 Single-writer discipline (the sched-ledger idiom): every ``note_*`` /
 ``audit`` mutator runs on the scheduler thread (or the fetcher) under
@@ -127,9 +129,6 @@ _PEAK_TABLE = (
     ("v3", (123.0, 900.0)),
     ("v2", (46.0, 700.0)),
 )
-# Conservative floor when even the numpy calibration is unavailable.
-_FALLBACK_PEAKS = (0.05, 5.0)
-
 # Per-variant table cap: past it, new keys fold into one overflow row
 # (the sched ledger's _MAX_SHAPES idiom) so the payload stays bounded.
 _MAX_VARIANTS = 128
@@ -347,39 +346,36 @@ def ragged_occupancy_cost(cfg, *, q_tokens: int, kv_read_tokens: int,
 
 
 def _cpu_microbench() -> Tuple[float, float]:
-    """One-shot achievable-peak calibration for platforms the table
-    does not know (CPU smoke runs): a small numpy matmul for FLOP/s
-    and an array copy for bytes/s, cached process-wide. Cold path only
-    — called from bind()/resolve_peaks, never under _book."""
+    """One-shot achievable-peak calibration for the HOST CPU only (CPU
+    smoke runs, which have no published peak): a small numpy matmul
+    for FLOP/s and an array copy for bytes/s, cached process-wide.
+    Cold path only — called from bind()/resolve_peaks, never under
+    _book."""
     global _MICROBENCH_PEAKS
     if _MICROBENCH_PEAKS is not None:
         return _MICROBENCH_PEAKS
-    try:
-        import time as _time
+    import time as _time
 
-        import numpy as np
-        n = 192
-        a = np.ones((n, n), np.float32)
-        b = np.ones((n, n), np.float32)
-        a @ b  # warm the BLAS path
-        t0 = _time.perf_counter()
-        reps = 8
-        for _ in range(reps):
-            a @ b
-        dt = max(_time.perf_counter() - t0, 1e-9)
-        tflops = (2.0 * n ** 3 * reps) / dt / 1e12
-        src = np.ones((4 << 20,), np.uint8)
-        dst = np.empty_like(src)
-        np.copyto(dst, src)  # fault the pages
-        t0 = _time.perf_counter()
-        for _ in range(4):
-            np.copyto(dst, src)
-        dt = max(_time.perf_counter() - t0, 1e-9)
-        gbs = (2.0 * src.nbytes * 4) / dt / 1e9
-        _MICROBENCH_PEAKS = (max(tflops, 1e-4), max(gbs, 1e-3))
-    except Exception:  # numpy absent/broken: fixed conservative floor
-        logger.debug("roof: peak microbench unavailable", exc_info=True)
-        _MICROBENCH_PEAKS = _FALLBACK_PEAKS
+    import numpy as np
+    n = 192
+    a = np.ones((n, n), np.float32)
+    b = np.ones((n, n), np.float32)
+    a @ b  # warm the BLAS path
+    t0 = _time.perf_counter()
+    reps = 8
+    for _ in range(reps):
+        a @ b
+    dt = max(_time.perf_counter() - t0, 1e-9)
+    tflops = (2.0 * n ** 3 * reps) / dt / 1e12
+    src = np.ones((4 << 20,), np.uint8)
+    dst = np.empty_like(src)
+    np.copyto(dst, src)  # fault the pages
+    t0 = _time.perf_counter()
+    for _ in range(4):
+        np.copyto(dst, src)
+    dt = max(_time.perf_counter() - t0, 1e-9)
+    gbs = (2.0 * src.nbytes * 4) / dt / 1e9
+    _MICROBENCH_PEAKS = (max(tflops, 1e-4), max(gbs, 1e-3))
     return _MICROBENCH_PEAKS
 
 
@@ -387,7 +383,10 @@ def resolve_peaks(platform: str = "") -> Dict[str, Any]:
     """{"tflops", "gbs", "source"} for a platform hint (the JAX
     device_kind string). Resolution order: ROOF_PEAK_TFLOPS /
     ROOF_PEAK_GBS env (each may override individually) > the builtin
-    table > the one-shot CPU microbench."""
+    table > the one-shot microbench, which stands in for a CPU (hint
+    "cpu...", or empty before an engine binds one) and for nothing
+    else: an accelerator the table does not know raises — a utilization
+    against a made-up peak is worse than none."""
     plat = (platform or "").lower()
     tflops = gbs = None
     source = "table"
@@ -395,7 +394,7 @@ def resolve_peaks(platform: str = "") -> Dict[str, Any]:
         if frag in plat:
             tflops, gbs = tf, gb
             break
-    if tflops is None:
+    if tflops is None and (not plat or plat.startswith("cpu")):
         tflops, gbs = _cpu_microbench()
         source = "microbench"
     env_tf = os.environ.get("ROOF_PEAK_TFLOPS", "")
@@ -410,6 +409,12 @@ def resolve_peaks(platform: str = "") -> Dict[str, Any]:
             gbs, source = float(env_gb), "env"
         except ValueError:
             logger.warning("ROOF_PEAK_GBS=%r is not a float", env_gb)
+    if tflops is None or gbs is None:
+        raise ValueError(
+            f"no published peak for device_kind {platform!r}: add it to "
+            f"cost_model._PEAK_TABLE with its source, or set both "
+            f"ROOF_PEAK_TFLOPS and ROOF_PEAK_GBS"
+        )
     return {"tflops": float(tflops), "gbs": float(gbs), "source": source}
 
 

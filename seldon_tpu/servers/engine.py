@@ -156,7 +156,8 @@ class EngineConfig:
     # full-width baseline above; "sparse" = the block-sparse jnp walker
     # (ops/ragged_paged_attention.py) that touches only live KV blocks
     # and skips dead prefill legs — the CPU/default-perf leg; "pallas"
-    # = the Mosaic kernel for the same walk (interpret-mode on CPU).
+    # = the Mosaic kernel for the same walk (TPU only: it raises
+    # elsewhere).
     # All legs compile into the SAME single ("ragged", C) variant.
     # Greedy outputs are token-identical across legs; non-greedy
     # sampling may diverge in ulps (masked is the any-temperature
@@ -469,6 +470,9 @@ class EngineStats:
         self.lock = threading.Lock()
         self.requests = 0  # graftlint: guarded-by(lock) via(stats)
         self.completed = 0  # graftlint: guarded-by(lock) via(stats)
+        # Terminal outcomes other than a normal completion (every typed
+        # error kind); completed counts these too.
+        self.failed_total = 0  # graftlint: guarded-by(lock) via(stats)
         self.tokens_out = 0  # graftlint: guarded-by(lock) via(stats)
         self.ttft_sum = 0.0  # graftlint: guarded-by(lock) via(stats)
         self.ttft_count = 0  # graftlint: guarded-by(lock) via(stats)
@@ -662,6 +666,7 @@ class EngineStats:
                 "prefix_seed_copies": self.prefix_seed_copies,
                 "requests": self.requests,
                 "completed": self.completed,
+                "failed_total": self.failed_total,
                 "tokens_out": self.tokens_out,
                 "mean_ttft_ms": (
                     1000.0 * self.ttft_sum / self.ttft_count
@@ -1310,6 +1315,18 @@ class InferenceEngine:
             # shardings every impl's constrain_state pins — one stable
             # jit cache key from wave zero.
             state = tp_sharding.shard_state(self._tp.mesh, state)
+        elif self.mesh is not None and self.mesh.devices.size == 1:
+            # Same reason on the one-chip mesh: an uncommitted state
+            # next to mesh-committed weights keys the first dispatch
+            # differently from every later one (whose state is a jit
+            # output, hence committed), and the first variant an engine
+            # runs would compile twice — 16 s of a live request at 8B
+            # on a v5e (chip_smoke, PR 21).
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            state = jax.device_put(
+                state, NamedSharding(self.mesh, PartitionSpec())
+            )
         return state
 
     # --- jitted kernels -----------------------------------------------------
@@ -4314,7 +4331,6 @@ class InferenceEngine:
             req.prefix_handle = None
         if self._paged:
             self._release_blocks(req)
-        req.out.put(None)
         slot = req.slot
         if 0 <= slot < len(self._slots) and self._slots[slot] is req:
             self._slots[slot] = None
@@ -4322,7 +4338,12 @@ class InferenceEngine:
             self._free.append(slot)
         with self.stats.lock:
             self.stats.completed += 1
+            if req.outcome:
+                self.stats.failed_total += 1
             self.stats.record_slo_locked(margin_ms, req.outcome == "")
+        # The sentinel goes LAST: a client that has seen its stream end
+        # reads stats and slot books that already count it.
+        req.out.put(None)
 
     def _perf_ns(self, t: float) -> int:
         """perf_counter seconds -> wall-clock ns via the init-time epoch

@@ -73,6 +73,7 @@ class JAXServer(SeldonComponent):
         spec_draft: str = "",
         max_queue: int = 0,
         default_deadline_ms: int = 0,
+        platform: str = "",
     ):
         self.model_uri = model_uri
         self.preset = preset
@@ -165,7 +166,8 @@ class JAXServer(SeldonComponent):
         # graftkern attention leg (models/ragged_attention.py +
         # ops/ragged_paged_attention.py): masked (bit-exact baseline) /
         # sparse (block-sparse jnp walker) / pallas (Mosaic kernel;
-        # interpret-mode on CPU). Also selects the spec verify leg.
+        # TPU only — it raises elsewhere). Also selects the spec verify
+        # leg.
         # Empty = follow the env (default masked).
         self.ragged_kernel = (
             ragged_kernel or _os.environ.get("RAGGED_KERNEL", "")
@@ -210,6 +212,12 @@ class JAXServer(SeldonComponent):
             default_deadline_ms
             or _os.environ.get("DEFAULT_DEADLINE_MS", "0") or 0
         )
+        # Deployment pin: the JAX platform this unit must find ("tpu").
+        # load() raises on any other, before a weight is built — a pod
+        # whose accelerator did not come up must crash, not serve an 8B
+        # model from the host CPU. Empty = serve wherever JAX landed
+        # (/metadata says where).
+        self.platform = platform
         self._loaded = False
         self._load_lock = threading.Lock()
         self.engine: Optional[InferenceEngine] = None
@@ -225,16 +233,22 @@ class JAXServer(SeldonComponent):
                 return
             import jax
 
-            from seldon_tpu.models import transformer
-            from seldon_tpu.parallel import MeshPlan, make_mesh
+            from seldon_tpu import device
             from seldon_tpu.parallel import sharding as shd
             from seldon_tpu.parallel import distributed
 
+            device.enable_compile_cache()
             # Multi-host slice: join via the StatefulSet env the operator
             # injects (no-op single-host). Must happen before any backend
             # query — jax.devices() is global after initialize.
             distributed.ensure_initialized()
             self._slice_ready = distributed.SliceReadiness()
+            found = jax.devices()[0].platform
+            if self.platform and found != self.platform:
+                raise RuntimeError(
+                    f"JAXServer requires platform {self.platform!r} but "
+                    f"JAX found {found!r}"
+                )
 
             if self.model_uri:
                 import os as _os
@@ -269,6 +283,9 @@ class JAXServer(SeldonComponent):
                 else:
                     mesh = self._serving_mesh(ckpt.load_config(local))
                     params, cfg = ckpt.load_checkpoint(local, mesh)
+                # Checkpoints are bf16 on disk: int8 is selected here
+                # and applied by quantize_params below.
+                cfg = self._dtype_overrides(cfg)
             else:
                 cfg = get_config(self.preset)
                 self.tokenizer = ByteTokenizer()
@@ -278,22 +295,9 @@ class JAXServer(SeldonComponent):
                         eos_token_id=self.tokenizer.eos_token_id,
                         pad_token_id=self.tokenizer.pad_token_id,
                     )
+                cfg = self._dtype_overrides(cfg)
                 mesh = self._serving_mesh(cfg)
-                with mesh:
-                    params = jax.jit(
-                        lambda k: transformer.init_params(cfg, k),
-                        out_shardings=shd.named_shardings(
-                            mesh, shd.param_pspecs(cfg)
-                        ),
-                    )(jax.random.key(self.init_seed))
-            if self.weight_dtype:
-                import dataclasses as _dc
-
-                cfg = _dc.replace(cfg, weight_dtype=self.weight_dtype)
-            if self.act_dtype and cfg.weight_dtype == "int8":
-                import dataclasses as _dc
-
-                cfg = _dc.replace(cfg, act_dtype=self.act_dtype)
+                params = self._synthetic_params(cfg, mesh, self.init_seed)
             if cfg.act_dtype == "int8" and self.model_uri:
                 # Real (trained) checkpoints carry activation outliers in
                 # the down-projection inputs that per-token int8 clips —
@@ -370,20 +374,11 @@ class JAXServer(SeldonComponent):
                         eos_token_id=cfg.eos_token_id,
                         pad_token_id=cfg.pad_token_id,
                     )
-                    with mesh:
-                        dparams = jax.jit(
-                            lambda k: transformer.init_params(dcfg, k),
-                            out_shardings=shd.named_shardings(
-                                mesh, shd.param_pspecs(dcfg)
-                            ),
-                        )(jax.random.key(self.init_seed + 1))
-                    if dcfg.weight_dtype == "int8":
-                        from seldon_tpu.models.quantize import (
-                            quantize_params,
-                        )
-
-                        dparams = quantize_params(dparams)
-                    draft = (dparams, dcfg)
+                    draft = (
+                        self._synthetic_params(dcfg, mesh,
+                                               self.init_seed + 1),
+                        dcfg,
+                    )
             if self.max_queue:
                 ekw["max_queue"] = self.max_queue
             if self.default_deadline_ms:
@@ -409,7 +404,11 @@ class JAXServer(SeldonComponent):
             if self.warmup:
                 self.engine.warmup()
             self.engine.start()
-            self.params = params
+            # The engine's tree, not the loader's: under tp > 1 the
+            # engine re-committed the weights across the group, and a
+            # second reference would pin the whole staging copy on the
+            # first device.
+            self.params = self.engine.params
 
             # One compiled scorer for predict() (cfg baked in statically).
             import functools
@@ -446,6 +445,45 @@ class JAXServer(SeldonComponent):
                 self.max_slots,
                 seq,
             )
+
+    def _dtype_overrides(self, cfg):
+        """cfg with the unit's weight_dtype / act_dtype applied (W8A8
+        only rides int8 weights)."""
+        import dataclasses
+
+        if self.weight_dtype:
+            cfg = dataclasses.replace(cfg, weight_dtype=self.weight_dtype)
+        if self.act_dtype and cfg.weight_dtype == "int8":
+            cfg = dataclasses.replace(cfg, act_dtype=self.act_dtype)
+        return cfg
+
+    @staticmethod
+    def _synthetic_params(cfg, mesh, seed: int):
+        """Seeded random weights for a preset, committed on `mesh`
+        under the GSPMD specs. int8 configs are BORN int8
+        (quantize.init_params_int8, layer slice by layer slice): a bf16
+        llama3-8b tree is 16 GB and quantize_params' f32 copy of one
+        stacked leaf another 7.5 GB — neither fits the 16 GB chip the
+        8 GB int8 tree serves from."""
+        import jax
+
+        from seldon_tpu.models import transformer
+        from seldon_tpu.parallel import sharding as shd
+
+        int8 = cfg.weight_dtype == "int8"
+        shardings = shd.named_shardings(
+            mesh, shd.param_pspecs(cfg, quantized=int8)
+        )
+        key = jax.random.key(seed)
+        if int8:
+            from seldon_tpu.models.quantize import init_params_int8
+
+            return jax.device_put(init_params_int8(cfg, key), shardings)
+        with mesh:
+            return jax.jit(
+                lambda k: transformer.init_params(cfg, k),
+                out_shardings=shardings,
+            )(key)
 
     def _serving_mesh(self, cfg):
         """The mesh load() commits onto: a dedicated tp-wide 'tp' mesh
@@ -518,10 +556,22 @@ class JAXServer(SeldonComponent):
         self._ensure_loaded()
         import dataclasses
 
+        from seldon_tpu import device
+
+        # `device` is where this unit ran, as JAX reports it — a client
+        # tells a TPU from a CPU here without importing JAX. `count` is
+        # what JAX sees; `mesh_devices` is what this unit serves from.
         return {
             "name": "jaxserver",
             "config": dataclasses.asdict(self.cfg),
             "mesh": {k: int(v) for k, v in self.mesh.shape.items()},
+            "mesh_devices": [int(d.id) for d in self.mesh.devices.flat],
+            "device": device.describe(),
+            "engine": {
+                "max_slots": self.engine.ecfg.max_slots,
+                "max_seq_len": self.engine.ecfg.max_seq_len,
+                "prompt_buckets": list(self.engine.ecfg.prompt_buckets),
+            },
         }
 
     # --- text generation ----------------------------------------------------
@@ -893,6 +943,8 @@ class JAXServer(SeldonComponent):
              "value": float(s["tokens_out"])},
             {"type": "GAUGE", "key": "jaxserver_completed",
              "value": float(s["completed"])},
+            {"type": "GAUGE", "key": "jaxserver_failed_total",
+             "value": float(s["failed_total"])},
             {"type": "GAUGE", "key": "jaxserver_slots_busy",
              "value": float(self.engine.slots_busy())},
             {"type": "GAUGE", "key": "jaxserver_decode_dispatches",

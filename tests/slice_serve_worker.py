@@ -24,9 +24,6 @@ os.environ["XLA_FLAGS"] = (
 )
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 

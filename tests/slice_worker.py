@@ -13,9 +13,6 @@ os.environ["XLA_FLAGS"] = (
 )
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 from seldon_tpu.parallel import distributed

@@ -303,9 +303,21 @@ def test_peak_resolution_order(monkeypatch):
     assert table == {"tflops": 197.0, "gbs": 819.0, "source": "table"}
     # Longest-substring wins: v5p must not fall through to "v5 lite".
     assert cost_model.resolve_peaks("TPU v5p")["tflops"] == 459.0
-    # Unknown platform: the cached one-shot microbench.
+    # The host CPU (and only it): the cached one-shot microbench.
     mb = cost_model.resolve_peaks("cpu")
     assert mb["source"] == "microbench" and mb["tflops"] > 0.0
+    # An accelerator the table does not know is an error, not a default
+    # — unless the operator supplies BOTH peaks.
+    with pytest.raises(ValueError, match="TPU v9"):
+        cost_model.resolve_peaks("TPU v9")
+    monkeypatch.setenv("ROOF_PEAK_TFLOPS", "1000")
+    with pytest.raises(ValueError, match="TPU v9"):
+        cost_model.resolve_peaks("TPU v9")
+    monkeypatch.setenv("ROOF_PEAK_GBS", "2000")
+    assert cost_model.resolve_peaks("TPU v9") == {
+        "tflops": 1000.0, "gbs": 2000.0, "source": "env"}
+    monkeypatch.delenv("ROOF_PEAK_TFLOPS")
+    monkeypatch.delenv("ROOF_PEAK_GBS")
     # Env overrides everything, each knob individually.
     monkeypatch.setenv("ROOF_PEAK_TFLOPS", "123.5")
     env = cost_model.resolve_peaks("TPU v5e")

@@ -189,6 +189,81 @@ def test_loadtester_generate_against_live_server(server, capsys):
         assert d[f"itl_p{q}_ms"] >= 0
 
 
+def test_jaxserver_metadata_says_where_it_ran(server):
+    """/metadata carries platform / device_kind / device count, so a
+    client tells a TPU from a CPU without importing JAX."""
+    import asyncio
+
+    import jax
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from seldon_tpu.runtime.wrapper import build_rest_app
+
+    async def fetch():
+        async with TestClient(TestServer(build_rest_app(server))) as c:
+            r = await c.get("/metadata")
+            assert r.status == 200
+            return await r.json()
+
+    md = asyncio.run(fetch())
+    dev = md["device"]
+    assert dev["platform"] == "cpu"
+    assert dev["device_kind"] == jax.devices()[0].device_kind
+    assert dev["count"] == len(jax.devices())
+    assert sorted(md["mesh_devices"]) == list(range(len(jax.devices())))
+    assert md["engine"] == {"max_slots": 4, "max_seq_len": 64,
+                            "prompt_buckets": [32]}
+
+
+def test_jaxserver_int8_preset_is_born_int8(monkeypatch):
+    """Synthetic int8 weights never pass through a bf16 tree: the bf16
+    initialiser and the whole-tree quantiser are both off limits (a
+    bf16 llama3-8b is 16 GB on a 16 GB chip), every matmul leaf lands
+    int8, and the server generates."""
+    import jax.numpy as jnp
+
+    from seldon_tpu.models import quantize, transformer
+
+    def forbidden(*a, **kw):
+        raise AssertionError("a bf16 weight tree was materialised")
+
+    monkeypatch.setattr(transformer, "init_params", forbidden)
+    real_leaf = quantize._quantize_leaf
+
+    def slice_only(w):
+        assert w.ndim == 2, f"quantised a stacked leaf {w.shape}"
+        return real_leaf(w)
+
+    monkeypatch.setattr(quantize, "_quantize_leaf", slice_only)
+    srv = JAXServer(preset="tiny", weight_dtype="int8", max_slots=2,
+                    max_seq_len=64, tp=1)
+    srv.load()
+    try:
+        assert srv.cfg.weight_dtype == "int8"
+        blocks = srv.params["blocks"]
+        for name in quantize._BLOCK_WEIGHTS:
+            assert blocks[name].dtype == jnp.int8, name
+        assert srv.params["embed"].dtype == jnp.int8
+        assert srv.init_metadata()["mesh_devices"] == [0]
+        out = srv.generate(
+            {"prompt": "hi", "max_new_tokens": 4, "temperature": 0.0})
+        assert out["completion_tokens"] >= 1
+        # One compile per variant: the engine state is committed next to
+        # the mesh-committed weights, so the second request re-uses the
+        # first one's admission program.
+        srv.generate(
+            {"prompt": "hi", "max_new_tokens": 4, "temperature": 0.0})
+        assert srv.engine._jit_admit._cache_size() == 1
+    finally:
+        srv.engine.stop()
+
+
+def test_jaxserver_platform_pin_refuses_another_platform():
+    srv = JAXServer(preset="tiny", platform="tpu")
+    with pytest.raises(RuntimeError, match="requires platform 'tpu'.*'cpu'"):
+        srv.load()
+
+
 def test_jaxserver_predict_scores(server):
     scores = server.predict(np.array([[3, 4, 5, 6]]), [])
     assert scores.shape == (1,)

@@ -11,6 +11,8 @@ from seldon_tpu.ops.flash_attention import attention_reference, flash_attention
 from seldon_tpu.parallel import MeshPlan, make_mesh
 from seldon_tpu.parallel.ring_attention import ring_attention
 
+from pallas_interpret import pallas_interpret
+
 
 def _qkv(key, BH=4, Sq=64, Skv=64, Dh=16, dtype=jnp.float32):
     kq, kk, kv = jax.random.split(key, 3)
@@ -32,30 +34,26 @@ def test_reference_attention_causality():
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_pallas_interpret_matches_reference(causal):
-    """Run the pallas kernel in interpret mode (CPU) vs the reference."""
-    import importlib
-
-    fa = importlib.import_module("seldon_tpu.ops.flash_attention")
-
+    """The kernel, interpreted on CPU, vs the reference."""
     q, k, v = _qkv(jax.random.key(1), BH=2, Sq=32, Skv=32, Dh=8)
     ref = attention_reference(q, k, v, causal=causal)
-
-    import functools
-    from unittest import mock
-
-    from jax.experimental import pallas as pl
-
-    # interpret=True makes pallas_call run on CPU.
-    orig = pl.pallas_call
-
-    def interp(*a, **kw):
-        kw["interpret"] = True
-        return orig(*a, **kw)
-
-    with mock.patch.object(pl, "pallas_call", interp):
-        out = fa._flash_pallas(q, k, v, causal, 0, 16, 16)
+    with pallas_interpret():
+        out = flash_attention(q, k, v, causal=causal, block_q=16,
+                              block_k=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3,
                                atol=2e-3)
+
+
+def test_flash_raises_instead_of_falling_back():
+    """No second implementation stands behind the kernel: a shape the
+    grid cannot cover raises, and so does a platform that cannot
+    compile it (CPU without the test-only interpret mode)."""
+    q, k, v = _qkv(jax.random.key(3), BH=2, Sq=24, Skv=24, Dh=8)
+    with pytest.raises(ValueError, match="divisible blocks"):
+        flash_attention(q, k, v, block_q=16, block_k=16)
+    q, k, v = _qkv(jax.random.key(3), BH=2, Sq=32, Skv=32, Dh=8)
+    with pytest.raises(ValueError, match="interpret mode"):
+        flash_attention(q, k, v, block_q=16, block_k=16)
 
 
 def test_flash_q_offset_decode_window():
@@ -142,7 +140,7 @@ def test_ring_attention_grad_flows():
 
 
 def test_forward_flash_flag_matches_xla():
-    """cfg.attn_impl='flash' (reference fallback on CPU) == default path."""
+    """cfg.attn_impl='flash' (the kernel, interpreted) == default path."""
     from seldon_tpu.models import forward, get_config, init_params
 
     cfg = get_config("tiny")
@@ -150,19 +148,14 @@ def test_forward_flash_flag_matches_xla():
     tokens = jax.random.randint(jax.random.key(1), (2, 16), 0, cfg.vocab_size)
     base = forward(params, tokens, cfg)
     flash_cfg = get_config("tiny", attn_impl="flash")
-    out = forward(params, tokens, flash_cfg)
+    with pallas_interpret():
+        out = forward(params, tokens, flash_cfg)
     np.testing.assert_allclose(np.asarray(base), np.asarray(out), rtol=2e-2,
                                atol=2e-2)
 
 
 def test_flash_gqa_native_interpret():
     """GQA via kv index_map == expanded-kv reference (interpret mode)."""
-    import importlib
-    from unittest import mock
-
-    from jax.experimental import pallas as pl
-
-    fa = importlib.import_module("seldon_tpu.ops.flash_attention")
     B, H, Hkv, S, Dh = 2, 4, 2, 32, 8
     G = H // Hkv
     key = jax.random.key(7)
@@ -173,15 +166,9 @@ def test_flash_gqa_native_interpret():
     ref = attention_reference(
         q, jnp.repeat(k, G, axis=0), jnp.repeat(v, G, axis=0), causal=True
     )
-
-    orig = pl.pallas_call
-
-    def interp(*a, **kw):
-        kw["interpret"] = True
-        return orig(*a, **kw)
-
-    with mock.patch.object(pl, "pallas_call", interp):
-        out = fa._flash_pallas(q, k, v, True, 0, 16, 16, q_per_kv=G)
+    with pallas_interpret():
+        out = flash_attention(q, k, v, causal=True, q_per_kv=G, block_q=16,
+                              block_k=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3,
                                atol=2e-3)
 

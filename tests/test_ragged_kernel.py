@@ -2,8 +2,9 @@
 
 The contract under test (ops/ragged_paged_attention module doc):
 
- * the ops-level walkers (``partials_sparse``, ``partials_pallas``
-   interpret-mode) agree with the full-width ``partials_reference``
+ * the ops-level walkers (``partials_sparse``, and ``partials_pallas``
+   under ``pallas_interpret()`` — the only way the Mosaic leg runs off
+   a TPU) agree with the full-width ``partials_reference``
    oracle on every bound shape — empty, single-block,
    partially-filled-block, multi-block;
  * the masked-MATCHED two-pass walk (``sparse_max_sum`` +
@@ -15,7 +16,7 @@ The contract under test (ops/ragged_paged_attention module doc):
    greedy token streams IDENTICAL to ``kernel="masked"`` across
    prefill / chunk-continuation / decode / verify rows, including the
    decode-only skip cond and the block-budget masked fallback;
-   ``kernel="pallas"`` (interpret on CPU) matches greedy tokens on the
+   ``kernel="pallas"`` (interpreted on CPU) matches greedy tokens on the
    same waves and stays within :data:`RAGGED_LOGITS_ATOL` on raw
    logits;
  * the engine end to end: ``ragged_kernel="sparse"`` streams equal
@@ -35,7 +36,7 @@ from seldon_tpu.models import ragged_attention as ra
 from seldon_tpu.models.config import PRESETS
 from seldon_tpu.ops import ragged_paged_attention as rpa
 
-jax.config.update("jax_platforms", "cpu")
+from pallas_interpret import pallas_interpret
 
 TINY = PRESETS["tiny"]
 BLOCK, NBS = 8, 16
@@ -114,12 +115,35 @@ def test_partials_pallas_interpret_matches_reference(kv_dtype):
          cfg.head_dim), jnp.bfloat16)
     bound = jnp.broadcast_to(jnp.asarray(BOUNDS)[:, None], (B, sq))
     ref = _combine(rpa.partials_reference(q, layer, table, bound))
-    got = _combine(rpa.ragged_paged_partials(q, layer, table, bound,
-                                             mode="pallas"))
+    with pallas_interpret():
+        got = _combine(rpa.ragged_paged_partials(q, layer, table, bound,
+                                                 mode="pallas"))
     live = BOUNDS > 0
     np.testing.assert_allclose(
         np.asarray(got, np.float32)[live],
         np.asarray(ref, np.float32)[live], atol=1e-4, rtol=1e-4)
+
+
+def test_pallas_leg_raises_instead_of_falling_back(monkeypatch):
+    """mode="pallas" means the Mosaic kernel or an error — never the
+    jnp walker under its name. Off a TPU and outside the interpret
+    context the lowering refuses; a kernel that fails for any other
+    reason (monkeypatched) propagates too."""
+    cfg = _cfg("bf16")
+    key = jax.random.key(2)
+    layer, table = _pool_and_table(cfg, key)
+    q = jnp.zeros((B, 1, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads,
+                   cfg.head_dim), jnp.bfloat16)
+    bound = jnp.broadcast_to(jnp.asarray(BOUNDS)[:, None], (B, 1))
+    with pytest.raises(ValueError, match="interpret mode"):
+        rpa.ragged_paged_partials(q, layer, table, bound, mode="pallas")
+
+    def boom(*a, **kw):
+        raise RuntimeError("mosaic refused")
+
+    monkeypatch.setattr(rpa, "partials_pallas", boom)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        rpa.ragged_paged_partials(q, layer, table, bound, mode="pallas")
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -290,7 +314,8 @@ def test_wave_pallas_interpret_matches_masked():
     # interpret-mode is too slow to sweep both dtypes here.
     fix = _wave_fixture("int8")
     m = _run_wave(*fix, kernel="masked")
-    p = _run_wave(*fix, kernel="pallas")
+    with pallas_interpret():
+        p = _run_wave(*fix, kernel="pallas")
     _assert_wave_equal(m, p)
 
 
@@ -340,7 +365,8 @@ def test_prefill_logits_within_atol(kv_dtype):
 
     want = np.asarray(jax.jit(masked)(), np.float32)
     got_s = np.asarray(jax.jit(lambda: leg("sparse"))(), np.float32)
-    got_p = np.asarray(jax.jit(lambda: leg("pallas"))(), np.float32)
+    with pallas_interpret():
+        got_p = np.asarray(jax.jit(lambda: leg("pallas"))(), np.float32)
     live = np.asarray(args["is_prefill"])
     np.testing.assert_array_equal(got_s[live], want[live])
     assert np.abs(got_p[live] - want[live]).max() <= rpa.RAGGED_LOGITS_ATOL
@@ -409,7 +435,8 @@ def test_verify_sparse_matches_masked(kv_dtype):
 def test_verify_pallas_interpret_matches_masked():
     fix = _verify_fixture("int8")
     m = _run_verify(*fix, kernel="masked")
-    p = _run_verify(*fix, kernel="pallas")
+    with pallas_interpret():
+        p = _run_verify(*fix, kernel="pallas")
     liv = m["valid"]
     np.testing.assert_array_equal(m["toks"][liv], p["toks"][liv])
     np.testing.assert_array_equal(m["valid"], p["valid"])

@@ -1,11 +1,11 @@
 #!/usr/bin/env python
 """Compare two bench JSON runs and fail on perf regressions.
 
-Input files are either the supervisor wrapper written by the bench
-driver (``{"n", "cmd", "rc", "tail", "parsed"}`` — the metric line
-lives under ``parsed``), a raw metric line
+Input files are either the wrapper a bench driver writes
+(``{"n", "cmd", "rc", "tail", "parsed"}`` — the metric line lives under
+``parsed``), a raw metric line
 (``{"metric", "value", "detail": {...}}``), or a JSONL stream of metric
-lines (the last complete one wins, matching the supervisor's pick).
+lines (the last complete one wins).
 
 Every numeric scalar in the metric line is flattened to a dot path
 (``value``, ``detail.p50_ttft_ms``, ``detail.bench_1b.req_per_s``, ...)
@@ -45,8 +45,8 @@ A gated metric regresses when it moves the wrong way by more than the
 tolerance (default 10%, ``--tol 0.05`` for 5%). Exit is non-zero iff
 at least one gated metric regressed. Usage::
 
-    make bench-compare BASE=BENCH_r05.json CAND=BENCH_r06.json
-    python -m tools.bench_compare BENCH_r05.json BENCH_r06.json --tol 0.05
+    make bench-compare BASE=base.json CAND=cand.json
+    python -m tools.bench_compare base.json cand.json --tol 0.05
 
 See docs/benchmarking.md ("Comparing runs") for how this slots into
 the release flow.
@@ -103,7 +103,7 @@ def load_metric(path: str) -> Dict[str, Any]:
     except ValueError:
         obj = None
     if isinstance(obj, dict):
-        if isinstance(obj.get("parsed"), dict):  # supervisor wrapper
+        if isinstance(obj.get("parsed"), dict):  # driver wrapper
             return obj["parsed"]
         if "metric" in obj:  # raw metric line
             return obj
@@ -205,7 +205,7 @@ def _fmt(v: Optional[float]) -> str:
 def main(argv: Optional[List[str]] = None) -> int:
     p = argparse.ArgumentParser(
         description="diff two bench JSON runs; non-zero exit on regression")
-    p.add_argument("base", help="baseline bench JSON (e.g. BENCH_r05.json)")
+    p.add_argument("base", help="baseline bench JSON")
     p.add_argument("cand", help="candidate bench JSON")
     p.add_argument("--tol", type=float, default=0.10,
                    help="relative tolerance for gated metrics "

@@ -42,8 +42,8 @@ retraces. The masked leg's numbers ride the metric line
 (``ragged_compile_variants`` / ``ragged_live_retraces``) and the
 sparse leg adds ``ragged_sparse_*`` twins, so ``bench_compare`` gates
 both strictly. The pallas leg is exercised by
-tests/test_ragged_kernel.py instead — interpret-mode through a full
-server drive is too slow for this audit's budget.
+tests/test_ragged_kernel.py (interpreted) and chip_smoke.py (compiled,
+on the chip) instead — the kernel raises on the CPU this audit runs on.
 
 A third, SPEC leg boots the same server under ``SPEC=1`` and asserts
 the graftspec lattice contract: the pow2 ``verify/k`` ladder replaces

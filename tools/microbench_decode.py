@@ -20,8 +20,8 @@ n-gram drafter's host cost (~0) is assumed.
 wave (models/ragged_attention.ragged_wave — the decode-only regime
 that dominates a serving trace) over all slots at MIXED context
 lengths, timed per kernel leg: masked (full-width baseline) vs sparse
-(block-sparse walker); ``MB_PALLAS=1`` adds the pallas leg (interpret
-mode off-TPU — slow on CPU, so opt-in). Prints ms/wave per leg and
+(block-sparse walker); ``MB_PALLAS=1`` adds the pallas leg (TPU only:
+the Mosaic kernel raises elsewhere, so opt-in). Prints ms/wave per leg and
 the sparse-vs-masked speedup.
 
 ``--roof`` adds graftroof's analytical prediction next to every
