@@ -1,0 +1,79 @@
+"""What the access-log readers share (not a metric: no UNIT).
+
+The unit writes one `request {json}` line per finished request and one
+`startup {json}` line per load on the logger `seldon_tpu.access`
+(docs/distributed-tracing.md); run.py sends the unit's output to
+chiprun_out/benchmark/<cell>/unit.log, which outlives the unit. A unit
+that writes no such lines (an older program) leaves every reader here
+with nothing to read: None, and the metric is left out of the line."""
+
+import json
+import os
+import re
+import time
+
+import stats
+
+LINE = re.compile(r"\b(request|startup) (\{.*\})\s*$")
+PHASES = ("executor_wait_ms", "queue_wait_ms", "device_wait_ms",
+          "first_token_held_ms")
+MAX_MISCOUNT = 2  # requests an edge of the window may add or drop
+
+
+def log_path(obs):
+    """run.py's self.work: <checkout>/chiprun_out/benchmark/<cell name>."""
+    name = (obs.cell or {}).get("name")
+    if not name:
+        return None
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(root, "chiprun_out", "benchmark", name, "unit.log")
+
+
+def lines(obs, kind):
+    """The parsed `kind {json}` lines of the cell's unit.log, in order."""
+    path = log_path(obs)
+    if path is None or not os.path.exists(path):
+        return []
+    out = []
+    with open(path, errors="replace") as f:
+        for ln in f:
+            m = LINE.search(ln)
+            if m and m.group(1) == kind:
+                try:
+                    out.append(json.loads(m.group(2)))
+                except ValueError:
+                    pass  # a torn line: the count check below notices
+    return out
+
+
+def window(obs):
+    """The request lines of the measured window: received by the unit
+    between obs.t0 and obs.t1 (the generator's perf_counter, turned into
+    the unit's wall clock in this process, which is run.py's). None
+    unless they are the sampled requests to within MAX_MISCOUNT."""
+    if obs.t0 is None or obs.t1 is None or obs.samples is None:
+        return None
+    off = time.time() - time.perf_counter()
+    rows = [r for r in lines(obs, "request")
+            if isinstance(r.get("received_unix"), (int, float))
+            and obs.t0 + off <= r["received_unix"] <= obs.t1 + off]
+    if not rows or abs(len(rows) - len(obs.samples)) > MAX_MISCOUNT:
+        return None
+    return rows
+
+
+def mid80(obs, key):
+    """10 %-trimmed mean of one field over the window's requests, the
+    statistic of ttft_mid80_ms."""
+    rows = window(obs)
+    xs = [r[key] for r in rows or ()
+          if isinstance(r.get(key), (int, float))]
+    return stats.trimmed_mean(xs) if xs else None
+
+
+def startup(obs, key):
+    """One field of the unit's start-up line."""
+    rows = lines(obs, "startup")
+    v = rows[-1].get(key) if rows else None
+    return float(v) if isinstance(v, (int, float)) else None

@@ -9,6 +9,7 @@ token — the reference has no generation path at all, SURVEY.md §5.7).
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,11 @@ class SamplingParams:
     # trace when tracing is on). Rides meta.tags["traceparent"] over the
     # proto transports, same route as deadline_ms.
     traceparent: str = ""
+    # time.perf_counter() of this process when the transport handler had
+    # parsed the request (runtime/wrapper.py stamps it; same route as
+    # traceparent). None = the caller is the transport: the engine uses
+    # its own submit time and the request's executor wait reads 0.
+    received_at: Optional[float] = None
 
 
 def _mask_top_k_top_p(
@@ -66,6 +72,7 @@ def _mask_top_k_top_p(
     return jnp.where(masked < min_keep, -jnp.inf, masked)
 
 
+@jax.named_scope("sampler")
 def sample_per_row(
     logits: jnp.ndarray,  # [B, V]
     keys: jax.Array,  # [B] PRNG keys (one per row)

@@ -236,12 +236,16 @@ def gqa_attention(
     Hkv = k.shape[2]
     G = H // Hkv
     q = q.reshape(B, Sq, Hkv, G, Dh)
-    scores = jnp.einsum(
-        "bskgd,btkd->bkgst", q, k, preferred_element_type=jnp.float32
-    ) / (Dh**0.5)
-    scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
-    w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgst,btkd->bskgd", w, v)
+    with jax.named_scope("attn/scores"):
+        scores = jnp.einsum(
+            "bskgd,btkd->bkgst", q, k, preferred_element_type=jnp.float32
+        ) / (Dh**0.5)
+        scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
+        w = jax.nn.softmax(
+            scores.astype(jnp.float32), axis=-1
+        ).astype(q.dtype)
+    with jax.named_scope("attn/out"):
+        out = jnp.einsum("bkgst,btkd->bskgd", w, v)
     return out.reshape(B, Sq, H * Dh)
 
 
@@ -281,35 +285,38 @@ def gqa_attention_decode(
     Hkv = ck.shape[1]
     G = H // Hkv
     qr = q.reshape(B, S, Hkv, G, Dh)
-    scores = jnp.einsum(
-        "bskgd,bktd->bkgst", qr, ck.astype(qr.dtype),
-        preferred_element_type=jnp.float32,
-    ) / (Dh**0.5)
-    if k_scale is not None:
-        scores = scores * k_scale[:, :, None, None, :]
-    s_fresh = jnp.einsum(
-        "bskgd,bukd->bkgsu", qr, k_fresh.astype(qr.dtype),
-        preferred_element_type=jnp.float32,
-    ) / (Dh**0.5)
-    scores = jnp.where(mask_lt[:, None, None, :, :], scores, -1e30)
-    # Flash-style combine of the fresh column — concatenating it as a
-    # T+1th score column forces XLA to relayout the whole (lane-padded)
-    # score tensor; explicit max/exp algebra touches only what it must.
-    m = jnp.maximum(
-        jnp.max(scores, axis=-1, keepdims=True), s_fresh
-    )  # [B,k,g,1,1]
-    p = jnp.exp(scores - m)
-    p_f = jnp.exp(s_fresh - m)  # [B,k,g,1,1]
-    l = jnp.sum(p, axis=-1, keepdims=True) + p_f
-    wc = p / l
-    if v_scale is not None:
-        wc = wc * v_scale[:, :, None, None, :]
-    out = jnp.einsum(
-        "bkgst,bktd->bskgd", wc.astype(qr.dtype), cv.astype(qr.dtype)
-    ) + jnp.einsum(
-        "bkgsu,bukd->bskgd", (p_f / l).astype(qr.dtype),
-        v_fresh.astype(qr.dtype),
-    )
+    with jax.named_scope("attn/scores"):
+        scores = jnp.einsum(
+            "bskgd,bktd->bkgst", qr, ck.astype(qr.dtype),
+            preferred_element_type=jnp.float32,
+        ) / (Dh**0.5)
+        if k_scale is not None:
+            scores = scores * k_scale[:, :, None, None, :]
+        s_fresh = jnp.einsum(
+            "bskgd,bukd->bkgsu", qr, k_fresh.astype(qr.dtype),
+            preferred_element_type=jnp.float32,
+        ) / (Dh**0.5)
+        scores = jnp.where(mask_lt[:, None, None, :, :], scores, -1e30)
+        # Flash-style combine of the fresh column — concatenating it as
+        # a T+1th score column forces XLA to relayout the whole (lane-
+        # padded) score tensor; explicit max/exp algebra touches only
+        # what it must.
+        m = jnp.maximum(
+            jnp.max(scores, axis=-1, keepdims=True), s_fresh
+        )  # [B,k,g,1,1]
+        p = jnp.exp(scores - m)
+        p_f = jnp.exp(s_fresh - m)  # [B,k,g,1,1]
+        l = jnp.sum(p, axis=-1, keepdims=True) + p_f
+        wc = p / l
+        if v_scale is not None:
+            wc = wc * v_scale[:, :, None, None, :]
+    with jax.named_scope("attn/out"):
+        out = jnp.einsum(
+            "bkgst,bktd->bskgd", wc.astype(qr.dtype), cv.astype(qr.dtype)
+        ) + jnp.einsum(
+            "bkgsu,bukd->bskgd", (p_f / l).astype(qr.dtype),
+            v_fresh.astype(qr.dtype),
+        )
     return out.reshape(B, S, H * Dh)
 
 
@@ -322,24 +329,30 @@ def moe_block(x: jnp.ndarray, bp: Dict[str, jnp.ndarray], cfg: ModelConfig):
     """
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.n_experts_per_token
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), bp["router"])
-    probs_full = jax.nn.softmax(logits, axis=-1)  # [B,S,E] f32
-    top_vals, top_idx = jax.lax.top_k(logits, K)  # [B,S,K]
-    gates = jax.nn.softmax(top_vals, axis=-1)
-    # Scatter the top-k gates back into a dense [B,S,E] mixing matrix.
-    onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [B,S,K,E]
-    mix = jnp.einsum("bske,bsk->bse", onehot, gates)
-    # Switch-style load-balance aux: E * Σ_e frac_routed(e) · mean_prob(e);
-    # minimized (→1) by a uniform router, grows as experts collapse.
-    frac = onehot.sum(axis=2).mean(axis=(0, 1)) / K  # [E]
-    lb_loss = E * jnp.sum(frac * probs_full.mean(axis=(0, 1)))
-    hidden = jax.nn.silu(
-        jnp.einsum("bsd,edf->besf", x, _w(bp, "w_gate", x.dtype))
-    ) * jnp.einsum("bsd,edf->besf", x, _w(bp, "w_up", x.dtype))
-    expert_out = jnp.einsum(
-        "besf,efd->besd", hidden, _w(bp, "w_down", x.dtype)
-    )
-    return jnp.einsum("besd,bse->bsd", expert_out, mix.astype(x.dtype)), lb_loss
+    with jax.named_scope("moe/router"):
+        logits = jnp.einsum(
+            "bsd,de->bse", x.astype(jnp.float32), bp["router"]
+        )
+        probs_full = jax.nn.softmax(logits, axis=-1)  # [B,S,E] f32
+        top_vals, top_idx = jax.lax.top_k(logits, K)  # [B,S,K]
+        gates = jax.nn.softmax(top_vals, axis=-1)
+        # Scatter the top-k gates back into a dense [B,S,E] mixing matrix.
+        onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [B,S,K,E]
+        mix = jnp.einsum("bske,bsk->bse", onehot, gates)
+        # Switch-style load-balance aux: E * Σ_e frac_routed(e) ·
+        # mean_prob(e); minimized (→1) by a uniform router, grows as
+        # experts collapse.
+        frac = onehot.sum(axis=2).mean(axis=(0, 1)) / K  # [E]
+        lb_loss = E * jnp.sum(frac * probs_full.mean(axis=(0, 1)))
+    with jax.named_scope("moe/experts"):
+        hidden = jax.nn.silu(
+            jnp.einsum("bsd,edf->besf", x, _w(bp, "w_gate", x.dtype))
+        ) * jnp.einsum("bsd,edf->besf", x, _w(bp, "w_up", x.dtype))
+        expert_out = jnp.einsum(
+            "besf,efd->besd", hidden, _w(bp, "w_down", x.dtype)
+        )
+        out = jnp.einsum("besd,bse->bsd", expert_out, mix.astype(x.dtype))
+    return out, lb_loss
 
 
 # ---------------------------------------------------------------------------
@@ -420,7 +433,8 @@ def _block(
     else:
         attn = gqa_attention(q, k, v, mask)
 
-    x = x + _qdot(attn, bp, "wo", cfg)
+    with jax.named_scope("attn/out"):
+        x = x + _qdot(attn, bp, "wo", cfg)
     if act_spec is not None:
         x = jax.lax.with_sharding_constraint(x, act_spec)
     x, aux = _mlp_res(x, bp, cfg, act_spec)
@@ -449,14 +463,16 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
     reduction order — and hence the bits — match tp=1 exactly."""
     B, S, _ = h.shape
     Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
-    hq = _quantize_act(h) if _w8a8_applies(bp, "wq", cfg) else None
-    q = _qdot(h, bp, "wq", cfg, act_q=hq).reshape(B, S, cfg.n_heads, Dh)
-    k = _qdot(h, bp, "wk", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
-    v = _qdot(h, bp, "wv", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
-    if tp is not None:
-        q, k, v = tp.heads(q), tp.heads(k), tp.heads(v)
+    with jax.named_scope("attn/qkv"):
+        hq = _quantize_act(h) if _w8a8_applies(bp, "wq", cfg) else None
+        q = _qdot(h, bp, "wq", cfg, act_q=hq).reshape(
+            B, S, cfg.n_heads, Dh)
+        k = _qdot(h, bp, "wk", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
+        v = _qdot(h, bp, "wv", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
+        if tp is not None:
+            q, k, v = tp.heads(q), tp.heads(k), tp.heads(v)
     return q, k, v
 
 
@@ -474,12 +490,14 @@ def _mlp_res(x, bp, cfg, act_spec, tp=None):
         mlp_out, aux = moe_block(h, bp, cfg)
         x = x + mlp_out
     else:
-        hq = _quantize_act(h) if _w8a8_applies(bp, "w_gate", cfg) else None
-        hidden = jax.nn.silu(_qdot(h, bp, "w_gate", cfg, act_q=hq)) \
-            * _qdot(h, bp, "w_up", cfg, act_q=hq)
-        if tp is not None:
-            hidden = tp.gather(tp.flat(hidden))
-        x = x + _qdot(hidden, bp, "w_down", cfg)
+        with jax.named_scope("mlp"):
+            hq = (_quantize_act(h) if _w8a8_applies(bp, "w_gate", cfg)
+                  else None)
+            hidden = jax.nn.silu(_qdot(h, bp, "w_gate", cfg, act_q=hq)) \
+                * _qdot(h, bp, "w_up", cfg, act_q=hq)
+            if tp is not None:
+                hidden = tp.gather(tp.flat(hidden))
+            x = x + _qdot(hidden, bp, "w_down", cfg)
     if act_spec is not None:
         x = jax.lax.with_sharding_constraint(x, act_spec)
     return x, aux
@@ -531,7 +549,8 @@ def _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
             # Exact all-gather of the head-sharded attention before the
             # REPLICATED wo contraction (tp_sharding module doc).
             attn = tp.gather(tp.flat(attn))
-        x = carry + _qdot(attn, bp, "wo", cfg)
+        with jax.named_scope("attn/out"):
+            x = carry + _qdot(attn, bp, "wo", cfg)
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
         x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp)
@@ -578,7 +597,8 @@ def _run_blocks_prefill_prefix(params, x, cfg, positions, inv_freq, mask,
         attn = gqa_attention(q, k_all, v_all, mask)
         if tp is not None:
             attn = tp.gather(tp.flat(attn))
-        x = carry + _qdot(attn, bp, "wo", cfg)
+        with jax.named_scope("attn/out"):
+            x = carry + _qdot(attn, bp, "wo", cfg)
         x, aux = _mlp_res(x, bp, cfg, None, tp=tp)
         return x, (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), aux)
 
@@ -617,7 +637,8 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
         attn = attend(q, k, v, cl)
         if tp is not None:
             attn = tp.gather(tp.flat(attn))
-        x = carry + _qdot(attn, bp, "wo", cfg)
+        with jax.named_scope("attn/out"):
+            x = carry + _qdot(attn, bp, "wo", cfg)
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
         x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp)
@@ -635,15 +656,17 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
     # One scatter covers all layers. k/v are [L,B,Hkv,T,Dh]; advanced
     # indices (rows on dim 1, pos on dim 3) land in front, so the update
     # operand is fresh[key] [L,B,Hkv,(Dh)] transposed to [B,L,Hkv,(Dh)].
-    new_cache = {
-        key: cache[key].at[:, rows, :, pos].set(
-            jnp.swapaxes(fresh[key], 0, 1), unique_indices=True
-        )
-        for key in cache
-    }
+    with jax.named_scope("attn/cache_update"):
+        new_cache = {
+            key: cache[key].at[:, rows, :, pos].set(
+                jnp.swapaxes(fresh[key], 0, 1), unique_indices=True
+            )
+            for key in cache
+        }
     return x, new_cache, jnp.mean(aux)
 
 
+@jax.named_scope("lm_head")
 def _logits(params, x, cfg):
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     if "lm_head" not in params:
@@ -845,7 +868,8 @@ def _run_blocks_decode_paged(params, x, cfg, positions, inv_freq, pos,
         )
         if tp is not None:
             attn = tp.gather(tp.flat(attn))
-        x = carry + _qdot(attn, bp, "wo", cfg)
+        with jax.named_scope("attn/out"):
+            x = carry + _qdot(attn, bp, "wo", cfg)
         x, aux = _mlp_res(x, bp, cfg, None, tp=tp)
         if quantized:
             kq, ksc = _quantize_kv(k[:, 0])
@@ -873,12 +897,13 @@ def _run_blocks_decode_paged(params, x, cfg, positions, inv_freq, pos,
     # indices (bid on dim 1, off on dim 3) land in front, update operand
     # is fresh[key] [L, B, Hkv, (Dh)] with B swapped forward. Inactive
     # rows write through table entry 0 (trash) — collisions allowed.
-    new_pool = {
-        key: pool[key].at[:, bid, :, off].set(
-            jnp.swapaxes(fresh[key], 0, 1)
-        )
-        for key in pool
-    }
+    with jax.named_scope("attn/cache_update"):
+        new_pool = {
+            key: pool[key].at[:, bid, :, off].set(
+                jnp.swapaxes(fresh[key], 0, 1)
+            )
+            for key in pool
+        }
     return x, new_pool, jnp.mean(aux)
 
 
@@ -942,21 +967,23 @@ def prefill(
     x, kv, _ = _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
                                    ring_mesh=ring_mesh if use_ring else None,
                                    tp=tp)
-    if cfg.kv_cache_dtype == "int8":
-        kq, ks = _quantize_kv(kv["k"])
-        vq, vs = _quantize_kv(kv["v"])
-        writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    else:
-        dt = cache["k"].dtype
-        writes = {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
-    if S == Smax:
-        cache = writes
-    else:
-        # T is dim 3 of k/v and the trailing dim of the scales, so one
-        # indexing expression covers every cache array.
-        cache = {
-            key: cache[key].at[:, :, :, :S].set(writes[key]) for key in cache
-        }
+    with jax.named_scope("attn/cache_update"):
+        if cfg.kv_cache_dtype == "int8":
+            kq, ks = _quantize_kv(kv["k"])
+            vq, vs = _quantize_kv(kv["v"])
+            writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+        else:
+            dt = cache["k"].dtype
+            writes = {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+        if S == Smax:
+            cache = writes
+        else:
+            # T is dim 3 of k/v and the trailing dim of the scales, so
+            # one indexing expression covers every cache array.
+            cache = {
+                key: cache[key].at[:, :, :, :S].set(writes[key])
+                for key in cache
+            }
     # Gather each row's last real hidden state BEFORE the vocab projection:
     # projecting all S positions would materialize [B,S,V] f32 (~4 GB for an
     # 8k-prompt llama3-8b bucket) only to keep one row.

@@ -242,6 +242,10 @@ def _generate_request_dict(request: pb.GenerateRequest) -> dict:
         tp = request.meta.tags["traceparent"].string_value
         if tp:
             d["traceparent"] = tp
+    # When the transport handler had the request (its perf_counter;
+    # runtime/wrapper._stamp_received): the engine's executor-wait phase.
+    if "received_at" in request.meta.tags:
+        d["received_at"] = request.meta.tags["received_at"].number_value
     return d
 
 
@@ -254,4 +258,6 @@ def _generate_response(request: pb.GenerateRequest, out: dict) -> pb.GenerateRes
     resp.total_ms = float(out.get("total_ms", 0.0))
     resp.prompt_tokens = int(out.get("prompt_tokens", 0))
     resp.completion_tokens = int(out.get("completion_tokens", len(out.get("token_ids", []))))
+    for k, v in (out.get("timings") or {}).items():
+        resp.timings[k] = float(v)
     return resp

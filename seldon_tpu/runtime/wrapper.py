@@ -33,24 +33,30 @@ from seldon_tpu.core import http, payloads, tracing
 from seldon_tpu.core.http import PROTO_CONTENT_TYPE
 from seldon_tpu.proto import prediction_pb2 as pb
 from seldon_tpu.proto import prediction_grpc
-from seldon_tpu.runtime import seldon_methods
+from seldon_tpu.runtime import REST_WORKERS, seldon_methods
 from seldon_tpu.runtime.metrics_server import ServerMetrics, get_default_metrics
 from seldon_tpu.runtime.user_model import SeldonNotImplementedError
 
 logger = logging.getLogger(__name__)
 
 
-def _absorb_user_metrics(metrics: ServerMetrics, user_obj) -> None:
+def _absorb_user_metrics(metrics: ServerMetrics, user_obj,
+                         gauges_only: bool = False) -> None:
     """Pull the unit's validated custom metrics() into the registry.
     The predict path does this through response meta
     (construct_response); generate responses carry no meta.metrics, so
     TextGen-only units would otherwise never surface their gauges on
     /metrics. Uses the same validation (client_custom_metrics) and
-    dict->Metric conversion (payloads.add_metric_dicts) as predict."""
+    dict->Metric conversion (payloads.add_metric_dicts) as predict.
+    `gauges_only` is the /metrics scrape's form: a GAUGE is a state and
+    may be set again at any time, a COUNTER or TIMER entry is an event
+    of a served request and a scrape must not repeat it."""
     from seldon_tpu.runtime.user_model import client_custom_metrics
 
     try:
         dicts = client_custom_metrics(user_obj)
+        if gauges_only:
+            dicts = [d for d in dicts or () if d.get("type") == "GAUGE"]
         if not dicts:
             return
         meta = pb.Meta()
@@ -82,6 +88,18 @@ def _stamp_traceparent(msg, carrier) -> None:
             msg.meta.tags["traceparent"].string_value = ctx.to_traceparent()
     except Exception:  # propagation must never fail a served request
         logger.exception("traceparent stamping failed")
+
+
+def _stamp_received(msg) -> None:
+    """Stamp when this transport handler had the parsed request in hand
+    (time.perf_counter() of this process) into meta.tags["received_at"]:
+    the first of the five instants that cut a request's TTFT inside the
+    unit (servers/engine.py _Request). It rides to the engine like
+    traceparent, via SamplingParams.received_at, and received -> submit
+    is the wait for a transport worker thread. A client's own value is
+    always overwritten: the clock is this process's."""
+    msg.meta.tags["received_at"].number_value = time.perf_counter()
+
 
 _METHOD_TABLE = {
     "predict": (seldon_methods.predict, pb.SeldonMessage),
@@ -123,7 +141,8 @@ def build_rest_app(
     executor: Optional[concurrent.futures.Executor] = None,
     metrics: Optional[ServerMetrics] = None,
 ) -> web.Application:
-    executor = executor or concurrent.futures.ThreadPoolExecutor(max_workers=8)
+    executor = executor or concurrent.futures.ThreadPoolExecutor(
+        max_workers=REST_WORKERS)
     metrics = metrics or get_default_metrics()
     tracer = tracing.get_tracer(_unit_name())
     app = web.Application(client_max_size=1024**3)
@@ -193,6 +212,7 @@ def build_rest_app(
             msg, encoding = await _parse_request(request, pb.GenerateRequest)
         except Exception as e:
             return web.json_response(SeldonMicroserviceException(str(e)).to_dict(), status=400)
+        _stamp_received(msg)
         _stamp_traceparent(msg, request.headers)
         loop = asyncio.get_running_loop()
         t0 = time.perf_counter()
@@ -236,6 +256,7 @@ def build_rest_app(
             return web.json_response(
                 SeldonMicroserviceException(str(e)).to_dict(), status=400
             )
+        _stamp_received(msg)
         _stamp_traceparent(msg, request.headers)
         loop = asyncio.get_running_loop()
         t0 = time.perf_counter()
@@ -382,6 +403,14 @@ def build_rest_app(
         return web.json_response({})
 
     async def handle_metrics(request: web.Request) -> web.Response:
+        # A fresh metrics() from the user object first: its gauges are
+        # otherwise refreshed only as a by-product of a /generate, and
+        # an idle unit's scrape would miss its last requests.
+        loop = asyncio.get_running_loop()
+        await loop.run_in_executor(
+            request.app["executor"], _absorb_user_metrics, metrics,
+            user_obj, True,
+        )
         body, ctype = metrics.export()
         return web.Response(body=body, content_type=ctype.split(";")[0])
 
@@ -551,6 +580,7 @@ class _UnitServicer:
         return resp
 
     def Generate(self, request, context):
+        _stamp_received(request)
         _stamp_traceparent(
             request,
             context.invocation_metadata() if context is not None else None,
@@ -564,6 +594,7 @@ class _UnitServicer:
         here as client-liveness poll points (a cancelled RPC stops the
         stream and, via generator close, the engine request)."""
         t0 = time.perf_counter()
+        _stamp_received(request)
         _stamp_traceparent(
             request,
             context.invocation_metadata() if context is not None else None,

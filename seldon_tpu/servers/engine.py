@@ -28,9 +28,11 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import json
 import logging
 import os
 import queue
+import secrets
 import threading
 import time
 from typing import (Any, Deque, Dict, List, NamedTuple, Optional, Sequence,
@@ -51,6 +53,22 @@ from seldon_tpu.servers import sched_ledger, shape_lattice, supervisor
 from seldon_tpu.servers.chaos import ChaosConfig, ChaosMonkey
 
 logger = logging.getLogger(__name__)
+# One INFO line per finished request and one per load (docs/
+# distributed-tracing.md "The access line"); standard logging
+# configuration silences it.
+access_log = logging.getLogger("seldon_tpu.access")
+
+
+def _named_partial(impl, /, *args, **bound):
+    """functools.partial carrying `impl`'s name. jax.jit names the XLA
+    module after its callable and a bare partial has none (every engine
+    program then reads `jit__unknown` in a profile); with the name the
+    "XLA Modules" line reads jit__admit_impl, jit__chunk_impl, ... and
+    a trace reduction finds programs by name. Every engine jit that
+    binds arguments is built over one of these."""
+    fn = functools.partial(impl, *args, **bound)
+    fn.__name__ = fn.__qualname__ = impl.__name__
+    return fn
 
 
 # HTTP status per error-item kind, for errors that surface BEFORE any
@@ -423,6 +441,20 @@ class _Request:
     # latest token burst was emitted (drives the ITL histogram).
     first_dispatch_at: Optional[float] = None
     last_burst_at: Optional[float] = None
+    # TTFT from inside: the five instants received_at <= submitted_at <=
+    # first_dispatch_at <= admit_ready_at <= first_token_at cut a
+    # request's first-token time into executor_wait, queue_wait,
+    # device_wait and first_token_held (docs/distributed-tracing.md).
+    # received_at: the transport handler had parsed the request
+    # (SamplingParams.received_at; == submitted_at for a direct caller).
+    # admit_ready_at: the admission's first-token array reached the host
+    # (stamped where it is fetched; a chunked prefill's final chunk).
+    # waves_ahead: waves dispatched and not yet retired at first
+    # dispatch, i.e. what the device still had to run first. A
+    # resurrected request keeps its first set, like its TTFT sample.
+    received_at: float = 0.0
+    admit_ready_at: Optional[float] = None
+    waves_ahead: int = 0
     # Lifecycle: absolute deadline (perf_counter seconds, None = no TTL)
     # and the cancel flag — set from any thread (a GIL-atomic bool
     # store), acted on by the scheduler at the next boundary reap.
@@ -492,6 +524,16 @@ class EngineStats:
         self.queue_depth = 0  # graftlint: guarded-by(lock) via(stats)
         self.queue_wait_sum = 0.0  # graftlint: guarded-by(lock) via(stats)
         self.queue_wait_count = 0  # graftlint: guarded-by(lock) via(stats)
+        # The other phases of a first token (seconds; _Request's five
+        # instants), each booked where its closing instant is stamped,
+        # beside a count that is already kept there: executor wait at
+        # submit (`requests`), waves ahead at first dispatch
+        # (`queue_wait_count`), device wait and first-token hold at the
+        # first token (`ttft_count`).
+        self.executor_wait_sum = 0.0  # graftlint: guarded-by(lock) via(stats)
+        self.waves_ahead_sum = 0  # graftlint: guarded-by(lock) via(stats)
+        self.device_wait_sum = 0.0  # graftlint: guarded-by(lock) via(stats)
+        self.first_token_held_sum = 0.0  # graftlint: guarded-by(lock) via(stats)
         # Inter-token latency histogram (ms, per decode-chunk burst gap).
         # Fixed edges keep the lock hold O(buckets) and make prometheus
         # export trivial; quantiles read the bucket upper edge.
@@ -684,6 +726,21 @@ class EngineStats:
                     if self.queue_wait_count
                     else 0.0
                 ),
+                # phase -> (sum, count): ms, but waves_ahead in waves.
+                "ttft_phases": {
+                    "executor_wait_ms": (
+                        1000.0 * self.executor_wait_sum, self.requests),
+                    "queue_wait_ms": (
+                        1000.0 * self.queue_wait_sum,
+                        self.queue_wait_count),
+                    "device_wait_ms": (
+                        1000.0 * self.device_wait_sum, self.ttft_count),
+                    "first_token_held_ms": (
+                        1000.0 * self.first_token_held_sum,
+                        self.ttft_count),
+                    "waves_ahead": (
+                        self.waves_ahead_sum, self.queue_wait_count),
+                },
                 "itl_count": itl_count,
                 "mean_itl_ms": (
                     self.itl_sum_ms / itl_count if itl_count else 0.0
@@ -845,6 +902,12 @@ class InferenceEngine:
         # puts and stranded whole waves (epoch-discarded unread, their
         # requests in no book).
         self._inflight_waves: List[_PendingWave] = []  # graftlint: guarded-by(_book)
+        # The synchronous loops keep their one undelivered wave in a
+        # local and never register it: 1 while it is outstanding, so
+        # _record_first_dispatch reads one depth on every path.
+        self._sync_depth = 0  # graftlint: guarded-by(_book)
+        # Waves dispatched so far: `wave` on the sched.dispatch span.
+        self._wave_seq = 0  # graftlint: guarded-by(_book)
 
         # Host-side bookkeeping.
         self._slots: List[Optional[_Request]] = [None] * B  # graftlint: guarded-by(_book)
@@ -900,8 +963,8 @@ class InferenceEngine:
         # to a build without graftmesh.
         tpkw = {"tp": self._tp} if self._tp is not None else {}
         self._jit_admit = jax.jit(
-            functools.partial(self._admit_impl, cfg=self.cfg, mesh=mesh,
-                              ring_mesh=self._ring_mesh, **tpkw),
+            _named_partial(self._admit_impl, cfg=self.cfg, mesh=mesh,
+                           ring_mesh=self._ring_mesh, **tpkw),
             donate_argnums=(1,),
         )
         # Prefix KV cache (opt-in, single-process only — the trie is
@@ -941,14 +1004,14 @@ class InferenceEngine:
                     byte_budget=self.ecfg.prefix_cache_bytes,
                 )
                 self._jit_admit_sub = jax.jit(
-                    functools.partial(
+                    _named_partial(
                         self._admit_impl, cfg=self.cfg, mesh=mesh,
                         ring_mesh=self._ring_mesh, return_sub=True, **tpkw,
                     ),
                     donate_argnums=(1,),
                 )
                 self._jit_admit_prefix = jax.jit(
-                    functools.partial(
+                    _named_partial(
                         self._admit_prefix_impl, cfg=self.cfg, mesh=mesh,
                         **tpkw,
                     ),
@@ -972,7 +1035,7 @@ class InferenceEngine:
             ))
             if self._paged:
                 self._jit_admit_chunk_paged = jax.jit(
-                    functools.partial(
+                    _named_partial(
                         self._paged_admit_chunk_impl, cfg=self.cfg,
                         mesh=mesh, **tpkw,
                     ),
@@ -981,7 +1044,7 @@ class InferenceEngine:
                 )
             else:
                 self._jit_admit_chunk = jax.jit(
-                    functools.partial(
+                    _named_partial(
                         self._admit_chunk_impl, cfg=self.cfg, mesh=mesh,
                         return_sub=self._prefix is not None, **tpkw,
                     ),
@@ -1002,7 +1065,7 @@ class InferenceEngine:
         self._jit_cow = None
         if self._paged:
             self._jit_admit_paged = jax.jit(
-                functools.partial(
+                _named_partial(
                     self._paged_admit_impl, cfg=self.cfg, mesh=mesh,
                     **tpkw,
                 ),
@@ -1026,7 +1089,7 @@ class InferenceEngine:
         self._chunk_sizes = tuple(sorted(set(sizes)))
         self._jit_chunks = {
             n: jax.jit(
-                functools.partial(
+                _named_partial(
                     self._chunk_impl,
                     cfg=self.cfg,
                     n_steps=n,
@@ -1040,7 +1103,7 @@ class InferenceEngine:
         if self._paged:
             self._jit_chunks_paged = {
                 n: jax.jit(
-                    functools.partial(
+                    _named_partial(
                         self._paged_chunk_impl,
                         cfg=self.cfg,
                         n_steps=n,
@@ -1079,7 +1142,7 @@ class InferenceEngine:
             # lattice key, so masked/sparse/pallas all stay inside the
             # ONE ("ragged", C) variant.
             self._jit_ragged = jax.jit(
-                functools.partial(
+                _named_partial(
                     self._ragged_impl, cfg=self.cfg, mesh=mesh,
                     kernel=self.ecfg.ragged_kernel,
                     block_budget=self.ecfg.ragged_block_budget, **tpkw,
@@ -1119,7 +1182,7 @@ class InferenceEngine:
             )
             self._spec_k_live = self._spec_rungs[-1]  # graftlint: guarded-by(_book)
             self._jit_verify = jax.jit(
-                functools.partial(
+                _named_partial(
                     self._verify_impl, cfg=self.cfg, mesh=mesh,
                     kernel=self.ecfg.ragged_kernel,
                     block_budget=self.ecfg.ragged_block_budget, **tpkw,
@@ -1137,7 +1200,7 @@ class InferenceEngine:
                 self._draft_cfg = dcfg.validate()
                 self._jit_draft = {
                     kk: jax.jit(
-                        functools.partial(
+                        _named_partial(
                             spec_model.draft_tokens,
                             dparams,
                             cfg=self._draft_cfg,
@@ -1910,9 +1973,10 @@ class InferenceEngine:
         self, tokens: Sequence[int], params: Optional[SamplingParams] = None
     ) -> "queue.Queue[Optional[dict]]":
         """Enqueue a request. Returns a queue yielding
-        {"tokens": [int, ...], "ttft_ms": float?} dicts (one per scheduler
-        boundary — tokens arrive in decode-chunk bursts), then None at
-        end."""
+        {"tokens": [int, ...], "ttft_ms": float?, "timings": dict?} dicts
+        (one per scheduler boundary — tokens arrive in decode-chunk
+        bursts; the first carries ttft_ms and its phases, _timings), then
+        None at end."""
         params = params or SamplingParams()
         if len(tokens) == 0:
             raise ValueError("empty prompt")
@@ -1959,6 +2023,10 @@ class InferenceEngine:
             else graftsan.TerminalQueue(self._san)
         )
         req = _Request(0, list(tokens), params, out_q, now)
+        received = params.received_at
+        req.received_at = (
+            now if received is None or received > now else received
+        )
         ttl_ms = params.deadline_ms or self.ecfg.default_deadline_ms
         if ttl_ms:
             req.deadline = now + ttl_ms / 1000.0
@@ -1980,6 +2048,7 @@ class InferenceEngine:
             )
         with self.stats.lock:
             self.stats.requests += 1
+            self.stats.executor_wait_sum += now - req.received_at
         self._pending.put(req)
         return req.out
 
@@ -1991,6 +2060,7 @@ class InferenceEngine:
         out = self.submit(tokens, params)
         toks: List[int] = []
         ttft_ms = None
+        timings = None
         error = None
         while True:
             item = out.get()
@@ -2002,6 +2072,7 @@ class InferenceEngine:
             toks.extend(item["tokens"])
             if ttft_ms is None:
                 ttft_ms = item.get("ttft_ms")
+                timings = item.get("timings")
         if error is not None:
             exc = RuntimeError(f"generation failed: {error['error']}")
             # Typed-outcome surface for transports: lifecycle kind plus
@@ -2010,7 +2081,7 @@ class InferenceEngine:
             exc.retriable = bool(error.get("retriable", False))
             exc.http_status = KIND_HTTP_STATUS.get(exc.kind, 500)
             raise exc
-        return {"token_ids": toks, "ttft_ms": ttft_ms}
+        return {"token_ids": toks, "ttft_ms": ttft_ms, "timings": timings}
 
     def cancel(self, rid: int) -> bool:
         """Flag a request for cancellation; the scheduler reaps it at the
@@ -2215,6 +2286,16 @@ class InferenceEngine:
         """graftheal supervisor snapshot for the /debug/health endpoint
         (None when HEAL is off — the raw failure path is in effect)."""
         return self._heal.snapshot() if self._heal is not None else None
+
+    @property
+    def max_admit(self) -> int:
+        """Largest admission group (a power of two) one dispatch forms."""
+        return self._max_admit
+
+    @property
+    def chunk_sizes(self) -> Tuple[int, ...]:
+        """Decode-chunk lengths (steps per dispatch) this engine compiles."""
+        return self._chunk_sizes
 
     def slots_busy(self) -> int:
         """Occupied-slot count, read under the bookkeeping lock. The one
@@ -2780,15 +2861,18 @@ class InferenceEngine:
                      "chunk_bias": self._pilot.chunk_bias()},
                 )
 
-    def _record_first_dispatch(self, group: List[_Request]) -> None:
+    def _record_first_dispatch(self, group: List[_Request]) -> None:  # graftlint: holds(_book)
         """Queue-wait accounting: submit -> first dispatch, once per
-        request (chunked prefills dispatch the same request many times)."""
+        request (chunked prefills dispatch the same request many times),
+        and how many dispatched waves the device still has ahead of it."""
         now = time.perf_counter()
         wait = 0.0
         n = 0
+        ahead = len(self._inflight_waves) + self._sync_depth
         for req in group:
             if req.first_dispatch_at is None:
                 req.first_dispatch_at = now
+                req.waves_ahead = ahead
                 wait += now - req.submitted_at
                 n += 1
                 if self._sled is not None:
@@ -2813,6 +2897,7 @@ class InferenceEngine:
             with self.stats.lock:
                 self.stats.queue_wait_sum += wait
                 self.stats.queue_wait_count += n
+                self.stats.waves_ahead_sum += ahead * n
 
     def _dispatch_admits(self) -> List[Tuple[List[_Request], Any, Any, Any]]:  # graftlint: holds(_book)
         """Admit FIFO prefix runs of same-bucket waiting requests as batched
@@ -2863,7 +2948,10 @@ class InferenceEngine:
                     self._sled.note_pool_stall(self._waiting[0].rid)
                 break
             try:
-                admits.append(self._dispatch_admit_group(group, *key))
+                with jax.profiler.TraceAnnotation(
+                    "sched.admit", bucket=key[0], group=len(group)
+                ):
+                    admits.append(self._dispatch_admit_group(group, *key))
                 last_key = key
             except Exception as e:  # bad batch must not kill the loop
                 logger.exception(
@@ -3613,7 +3701,10 @@ class InferenceEngine:
                     j += 1
                 rows = work[i:j]
                 try:
-                    admits.append(self._dispatch_chunk_group(rows))
+                    with jax.profiler.TraceAnnotation(
+                        "sched.admit", bucket=rows[0][1], group=len(rows)
+                    ):
+                        admits.append(self._dispatch_chunk_group(rows))
                     for _, Sc, _, _, clen in rows:
                         left -= Sc
                         n_chunks += 1
@@ -4163,12 +4254,16 @@ class InferenceEngine:
         self,
         admits: List[Tuple[List[_Request], Any, Any, Any]],
         admit_data: List[Tuple[np.ndarray, np.ndarray]],
+        admit_ready: float,
     ) -> None:
+        """`admit_ready`: when these admissions' first tokens reached
+        the host (_fetch_boundary)."""
         for (group, finals, _, _), (first_h, done_h) in zip(
             admits, admit_data
         ):
             now = time.perf_counter()
             ttft_total = 0.0
+            device_wait = 0.0
             n_first = 0
             # finals=None: one-shot admission, every row armed. A chunked
             # group's non-final rows deposited KV only — no token exists
@@ -4193,10 +4288,13 @@ class InferenceEngine:
                     req.gen_hist.append(first_tok)
                 if req.first_token_at is None:
                     req.first_token_at = now
+                    req.admit_ready_at = admit_ready
                     ttft_ms = 1000.0 * (now - req.submitted_at)
                     ttft_total += ttft_ms
+                    device_wait += admit_ready - req.first_dispatch_at
                     n_first += 1
-                    req.out.put({"tokens": [first_tok], "ttft_ms": ttft_ms})
+                    req.out.put({"tokens": [first_tok], "ttft_ms": ttft_ms,
+                                 "timings": self._timings(req)})
                 else:
                     # Resurrected re-admission: the client saw its first
                     # token before the fault — no second TTFT sample.
@@ -4212,7 +4310,30 @@ class InferenceEngine:
             with self.stats.lock:
                 self.stats.ttft_sum += ttft_total / 1000.0
                 self.stats.ttft_count += n_first
+                self.stats.device_wait_sum += device_wait
+                self.stats.first_token_held_sum += \
+                    n_first * (now - admit_ready)
                 self.stats.tokens_out += n_armed
+
+    @staticmethod
+    def _timings(req: _Request) -> Dict[str, Optional[float]]:
+        """The phases of a request's first token, from its five
+        instants: what the first stream item, the /generate body and
+        the access line carry. At the first token they sum to
+        first_token_at - received_at (ttft_ms + executor_wait_ms)
+        exactly; a phase whose closing instant the request never
+        reached reads None."""
+        def ms(a: Optional[float], b: Optional[float]) -> Optional[float]:
+            return None if a is None or b is None else 1000.0 * (b - a)
+
+        first, ready = req.first_dispatch_at, req.admit_ready_at
+        return {
+            "executor_wait_ms": ms(req.received_at, req.submitted_at),
+            "queue_wait_ms": ms(req.submitted_at, first),
+            "device_wait_ms": ms(first, ready),
+            "first_token_held_ms": ms(ready, req.first_token_at),
+            "waves_ahead": req.waves_ahead if first is not None else None,
+        }
 
     def _process_chunk(self, toks_h, valid_h, active_h, roster) -> None:  # graftlint: holds(_book)
         """toks_h [K, B], valid_h [K, B], active_h [B] — host arrays;
@@ -4313,6 +4434,8 @@ class InferenceEngine:
         )
         if self._tracer.enabled:
             self._emit_request_spans(req, now, margin_ms)
+        if access_log.isEnabledFor(logging.INFO):
+            self._log_access(req, now)
         if self._recorder is not None:
             self._recorder.record(
                 "terminal", req.rid,
@@ -4345,6 +4468,24 @@ class InferenceEngine:
         # reads stats and slot books that already count it.
         req.out.put(None)
 
+    def _log_access(self, req: _Request, now: float) -> None:
+        """The access line: `request {json}` on `seldon_tpu.access`,
+        once per finished request whatever its outcome (same
+        exactly-once gate as the spans): _timings to the microsecond,
+        null for a phase the request never reached."""
+        tok = req.first_token_at
+        phases = self._timings(req)
+        phases["decode_ms"] = None if tok is None else 1000.0 * (now - tok)
+        access_log.info("request %s", json.dumps({
+            "rid": req.rid,
+            "outcome": req.outcome or "ok",
+            "prompt_tokens": len(req.tokens) - req.replayed,
+            "completion_tokens": req.replayed + req.n_generated,
+            "received_unix": round(self._perf_ns(req.received_at) / 1e9, 6),
+            **{k: round(v, 3) if isinstance(v, float) else v
+               for k, v in phases.items()},
+        }))
+
     def _perf_ns(self, t: float) -> int:
         """perf_counter seconds -> wall-clock ns via the init-time epoch
         pairing (Span start/end are time_ns-domain)."""
@@ -4352,12 +4493,14 @@ class InferenceEngine:
 
     def _emit_request_spans(self, req: _Request, now: float,  # graftlint: holds(_book)
                             margin_ms: Optional[float]) -> None:
-        """Retro-emit the request's lifecycle spans — one `engine.request`
-        root (adopting the caller's traceparent when one arrived) plus
-        queued/prefill/decode children — from the timestamps _Request
-        already carries. Runs exactly once per request, gated by the
-        `req.finished` flip in _complete, so terminal spans have the
-        same exactly-once guarantee as the out-queue sentinel."""
+        """Retro-emit the request's lifecycle spans — `unit.executor_wait`
+        and one `engine.request` root (both adopting the caller's
+        traceparent when one arrived) plus queued/prefill/decode
+        children, prefill split into device_wait/first_token_held — from
+        the timestamps _Request already carries. Runs exactly once per
+        request, gated by the `req.finished` flip in _complete, so
+        terminal spans have the same exactly-once guarantee as the
+        out-queue sentinel."""
         outcome = req.outcome or "ok"
         attrs: Dict[str, Any] = {
             "rid": req.rid,
@@ -4377,6 +4520,18 @@ class InferenceEngine:
             attributes=attrs,
             status="OK" if outcome == "ok" else f"ERROR: {outcome}",
         )
+        # Received -> submit, the wait for a transport worker thread: a
+        # sibling just before engine.request, under the caller's span
+        # (with no caller it shares the request's trace as a second root).
+        self._tracer.emit_span(
+            "unit.executor_wait",
+            self._perf_ns(req.received_at),
+            self._perf_ns(req.submitted_at),
+            parent=req.trace,
+            context=None if req.trace is not None else tracing.SpanContext(
+                trace_id=root.trace_id, span_id=secrets.token_hex(8)),
+            attributes={"rid": req.rid},
+        )
         first = req.first_dispatch_at
         self._tracer.emit_span(
             "engine.queued",
@@ -4386,13 +4541,31 @@ class InferenceEngine:
         )
         if first is not None:
             tok = req.first_token_at
-            self._tracer.emit_span(
+            prefill = self._tracer.emit_span(
                 "engine.prefill",
                 self._perf_ns(first),
                 self._perf_ns(tok if tok is not None else now),
                 parent=root,
             )
             if tok is not None:
+                # engine.prefill cut where the first token reached the
+                # host: before, the device queue behind dispatched waves
+                # plus the prefill's own run; after, the hold until the
+                # wave it is delivered with has been fetched.
+                ready = req.admit_ready_at
+                self._tracer.emit_span(
+                    "engine.device_wait",
+                    self._perf_ns(first),
+                    self._perf_ns(ready),
+                    parent=prefill,
+                    attributes={"waves_ahead": req.waves_ahead},
+                )
+                self._tracer.emit_span(
+                    "engine.first_token_held",
+                    self._perf_ns(ready),
+                    self._perf_ns(tok),
+                    parent=prefill,
+                )
                 self._tracer.emit_span(
                     "engine.decode",
                     self._perf_ns(tok),
@@ -4772,18 +4945,31 @@ class InferenceEngine:
         copies, and the NaN/garbage sentinel screens every token id
         before any reaches a client queue. Touches no engine
         bookkeeping — runs under _book on the sync path and lock-free
-        on the fetcher thread."""
+        on the fetcher thread.
+
+        Still ONE logical fetch (one watchdog bound, one chaos hook, one
+        sentinel screen), but the admissions' leaves come to the host
+        first and `admit_ready` — third of the returned triple — is
+        stamped between: the admission's program precedes the decode
+        chunk on the device, so that instant is when a first token
+        existed on the host, and what follows it is the wait for the
+        chunk it is delivered with (_Request.admit_ready_at)."""
         def fetch():
             if self._chaos is not None:
                 self._chaos.maybe_hang()
-            return jax.device_get(  # graftlint: allow(hot-sync, lock-block) deliberate boundary fetch; handles were host-copied via copy_to_host_async at dispatch
-                ([(f, d) for _, _, f, d in admits], chunk_handles)
+            admit_data = jax.device_get(  # graftlint: allow(hot-sync, lock-block) deliberate boundary fetch; handles were host-copied via copy_to_host_async at dispatch
+                [(f, d) for _, _, f, d in admits]
             )
+            admit_ready = time.perf_counter()
+            chunk_data = jax.device_get(chunk_handles)  # graftlint: allow(hot-sync, lock-block) second half of the same boundary fetch
+            return admit_data, chunk_data, admit_ready
 
-        if self._heal is not None and self._heal.watchdog_ms > 0:
-            admit_data, chunk_data = self._heal.bounded_fetch(fetch)
-        else:
-            admit_data, chunk_data = fetch()
+        with jax.profiler.TraceAnnotation("fetch.device_get"):
+            if self._heal is not None and self._heal.watchdog_ms > 0:
+                admit_data, chunk_data, admit_ready = \
+                    self._heal.bounded_fetch(fetch)
+            else:
+                admit_data, chunk_data, admit_ready = fetch()
         if self._chaos is not None and self._chaos.cfg.nan_inject:
             # device_get host copies may be read-only views; poisoning
             # needs owned arrays (chaos-only path, never hot).
@@ -4800,7 +4986,7 @@ class InferenceEngine:
             self._heal.check_tokens(
                 admit_data, chunk_data, self.cfg.vocab_size
             )
-        return admit_data, chunk_data
+        return admit_data, chunk_data, admit_ready
 
     def _process_boundary(self, admits, chunk_handles, roster,  # graftlint: holds(_book)
                           timing=None, epoch=None) -> None:
@@ -4815,24 +5001,25 @@ class InferenceEngine:
             self._chaos.maybe_slow_boundary()  # graftlint: allow(lock-block) deliberate chaos fault: a slow boundary under _book is exactly the race window being tested
         roofing = self._roof is not None and timing is not None
         f0 = time.perf_counter() if roofing else 0.0
-        admit_data, chunk_data = self._fetch_boundary(
+        admit_data, chunk_data, admit_ready = self._fetch_boundary(
             admits, chunk_handles
         )
         f1 = time.perf_counter() if roofing else 0.0
-        self._process_admits(admits, admit_data)
-        if chunk_data is not None:
-            self._process_chunk(*chunk_data, roster)
-        if self._spec:
-            self._spec_post_process(chunk_data, roster)
-        self._record_wave_timing(timing)
-        if roofing:
-            self._roof_note_boundary(timing, f0, f1)
-        if self._san is not None:
-            self._san.audit(self)
-        if self._sled is not None:
-            self._sled.audit()
-        if self._heal is not None:
-            self._heal.note_boundary_ok()
+        with jax.profiler.TraceAnnotation("fetch.process"):
+            self._process_admits(admits, admit_data, admit_ready)
+            if chunk_data is not None:
+                self._process_chunk(*chunk_data, roster)
+            if self._spec:
+                self._spec_post_process(chunk_data, roster)
+            self._record_wave_timing(timing)
+            if roofing:
+                self._roof_note_boundary(timing, f0, f1)
+            if self._san is not None:
+                self._san.audit(self)
+            if self._sled is not None:
+                self._sled.audit()
+            if self._heal is not None:
+                self._heal.note_boundary_ok()
 
     def _make_timing(self):  # graftlint: holds(_book)
         """Boundary timing token built at dispatch end: (stamp, wave
@@ -5034,14 +5221,14 @@ class InferenceEngine:
                     self._chaos.maybe_slow_boundary()
                 roofing = self._roof is not None and timing is not None
                 f0 = time.perf_counter() if roofing else 0.0
-                admit_data, chunk_data = self._fetch_boundary(
-                    admits, chunk_handles
-                )
+                admit_data, chunk_data, admit_ready = \
+                    self._fetch_boundary(admits, chunk_handles)
                 f1 = time.perf_counter() if roofing else 0.0
-                with self._book:
+                with self._book, \
+                        jax.profiler.TraceAnnotation("fetch.process"):
                     if epoch != self._wave_epoch:
                         continue  # rebuild raced the fetch: stale wave
-                    self._process_admits(admits, admit_data)
+                    self._process_admits(admits, admit_data, admit_ready)
                     if chunk_data is not None:
                         self._process_chunk(*chunk_data, roster)
                     self._record_wave_timing(timing)
@@ -5240,7 +5427,27 @@ class InferenceEngine:
                                     time.perf_counter() - t0)
 
     def _dispatch_once(self):  # graftlint: holds(_book)
-        """One scheduling step under the bookkeeping lock. Returns the
+        """One scheduling step under the bookkeeping lock
+        (_dispatch_wave), as a `sched.dispatch` host span on the
+        profiler's clock: in a device profile it shows what the
+        scheduler was doing in the gap before a program. Metadata: the
+        wave's sequence number, the requests it admitted, its decode
+        steps."""
+        with jax.profiler.TraceAnnotation("sched.dispatch") as span:
+            work = self._dispatch_wave()
+            if work is not None:
+                self._wave_seq += 1
+                span.set_metadata(
+                    wave=self._wave_seq,
+                    admits=sum(len(a[0]) for a in work.admits),
+                    # chunk_handles[0] is the tokens array [steps, slots]
+                    chunk_steps=work.chunk_handles[0].shape[0]
+                    if work.chunk_handles else 0,
+                )
+        return work
+
+    def _dispatch_wave(self):  # graftlint: holds(_book)
+        """One scheduling step. Returns the
         (admits, chunk_handles, roster, timing) boundary or None if
         idle. On an
         exception, self._dispatch_wreck holds the partial boundary so
@@ -5340,7 +5547,8 @@ class InferenceEngine:
                 # Blocks OUTSIDE the lock, so the fetcher keeps
                 # draining; the wave stays registered until the fetcher
                 # retires it.
-                self._fetch_q.put(work)
+                with jax.profiler.TraceAnnotation("sched.blocked_on_fetch_q"):
+                    self._fetch_q.put(work)
             elif self._pending.empty():
                 if self._sled is not None:
                     self._sled.note_idle()
@@ -5363,6 +5571,7 @@ class InferenceEngine:
             admits, roster = [], None  # visible to the except path
             try:
                 with self._book:
+                    self._sync_depth = int(pending is not None)
                     if self._roof is not None:
                         self._step_t0 = time.perf_counter()
                     self._reap_lifecycle()
@@ -5468,6 +5677,7 @@ class InferenceEngine:
         while not self._stop.is_set():
             try:
                 with self._book:
+                    self._sync_depth = int(pending is not None)
                     work = self._dispatch_once()
                     if pending is not None:
                         self._process_boundary(*pending)

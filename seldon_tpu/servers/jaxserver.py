@@ -20,7 +20,9 @@ and contract tester all drive it like any other unit.
 
 from __future__ import annotations
 
+import json
 import logging
+import os
 import queue
 import threading
 import time
@@ -31,15 +33,31 @@ import numpy as np
 from seldon_tpu.core import tracing
 from seldon_tpu.models.config import ModelConfig, get_config
 from seldon_tpu.models.sampling import SamplingParams
+from seldon_tpu.runtime import REST_WORKERS
 from seldon_tpu.runtime.user_model import SeldonComponent
 from seldon_tpu.servers.engine import (
     KIND_HTTP_STATUS,
     EngineConfig,
     InferenceEngine,
+    access_log,
 )
 from seldon_tpu.servers.tokenizer import ByteTokenizer, load_tokenizer
 
 logger = logging.getLogger(__name__)
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since this process started, from /proc (None where there
+    is none): load() runs after the interpreter's start-up and every
+    import, which the start-up line counts with the device's init."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        return up - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 class JAXServer(SeldonComponent):
@@ -231,6 +249,8 @@ class JAXServer(SeldonComponent):
         with self._load_lock:
             if self._loaded:
                 return
+            t_load = time.perf_counter()
+            age = _process_age_s()
             import jax
 
             from seldon_tpu import device
@@ -249,6 +269,7 @@ class JAXServer(SeldonComponent):
                     f"JAXServer requires platform {self.platform!r} but "
                     f"JAX found {found!r}"
                 )
+            t_device = time.perf_counter()
 
             if self.model_uri:
                 import os as _os
@@ -315,6 +336,10 @@ class JAXServer(SeldonComponent):
                 from seldon_tpu.models.quantize import quantize_params
 
                 params = quantize_params(params)
+            # The tree is built by asynchronous dispatches: wait, so that
+            # "weights on the device" in the start-up line is that.
+            jax.block_until_ready(params)  # graftlint: allow(hot-sync) load time, before the engine exists; the sync IS the stamp
+            t_weights = time.perf_counter()
             self.cfg = cfg
             self.mesh = mesh
             seq = self.max_seq_len or cfg.max_seq_len
@@ -401,8 +426,10 @@ class JAXServer(SeldonComponent):
                 mesh=mesh,
                 draft=draft,
             )
+            t_engine = time.perf_counter()
             if self.warmup:
                 self.engine.warmup()
+            t_warm = time.perf_counter()
             self.engine.start()
             # The engine's tree, not the loader's: under tp > 1 the
             # engine re-committed the weights across the group, and a
@@ -438,6 +465,23 @@ class JAXServer(SeldonComponent):
 
             self._score_fn = _jax.jit(functools.partial(_score, _cfg=cfg))
             self._loaded = True
+            # Start-up phases on the access log, beside the request
+            # lines (docs/distributed-tracing.md): seconds from the
+            # process's start (from load()'s, where /proc has no answer)
+            # through imports and device init, then weights, engine
+            # construction and the engine's own warm-up.
+            before = age if age is not None else 0.0
+            access_log.info("startup %s", json.dumps({
+                "since_process_start": age is not None,
+                "imports_device_s": round(before + t_device - t_load, 3),
+                "weights_s": round(t_weights - t_device, 3),
+                "weights_ready_s": round(before + t_weights - t_load, 3),
+                "engine_s": round(t_engine - t_weights, 3),
+                "warmup_s": round(t_warm - t_engine, 3),
+                "warmup_variants": (
+                    len(self.engine.static_lattice()) if self.warmup else 0
+                ),
+            }))
             logger.info(
                 "JAXServer loaded: cfg=%s mesh=%s slots=%d seq=%d",
                 self.preset if not self.model_uri else self.model_uri,
@@ -567,10 +611,16 @@ class JAXServer(SeldonComponent):
             "mesh": {k: int(v) for k, v in self.mesh.shape.items()},
             "mesh_devices": [int(d.id) for d in self.mesh.devices.flat],
             "device": device.describe(),
+            # What a client's warm-up otherwise has to discover: the
+            # largest admission group, the decode-chunk rungs and how
+            # many requests the REST transport runs at once.
             "engine": {
                 "max_slots": self.engine.ecfg.max_slots,
                 "max_seq_len": self.engine.ecfg.max_seq_len,
                 "prompt_buckets": list(self.engine.ecfg.prompt_buckets),
+                "max_admit": self.engine.max_admit,
+                "decode_chunk": list(self.engine.chunk_sizes),
+                "rest_workers": REST_WORKERS,
             },
         }
 
@@ -602,6 +652,7 @@ class JAXServer(SeldonComponent):
             seed=int(get("seed", 0)),
             deadline_ms=int(get("deadline_ms", 0) or 0),
             traceparent=tp,
+            received_at=request.get("received_at"),
         )
 
     def _prompt_ids(self, request: Dict) -> List[int]:
@@ -635,6 +686,7 @@ class JAXServer(SeldonComponent):
             "total_ms": 1000.0 * (time.perf_counter() - t0),
             "prompt_tokens": len(ids),
             "completion_tokens": len(toks),
+            "timings": result["timings"] or {},
         }
 
     def generate_stream(self, request: Dict):
@@ -690,6 +742,7 @@ class JAXServer(SeldonComponent):
                     "total_ms": 1000.0 * (time.perf_counter() - t0),
                     "prompt_tokens": len(ids),
                     "completion_tokens": n,
+                    "timings": item.get("timings") or {},
                 }
         finally:
             if not done:
@@ -936,7 +989,18 @@ class JAXServer(SeldonComponent):
         if not self._loaded:
             return []
         s = self.engine.stats.snapshot()
-        return self._slo_metrics(s) + self._observatory_metrics(s) + [
+        # A first token's phases (engine._Request's five instants) as
+        # rate-able sums and counts; ms, but waves_ahead in waves.
+        phases: List[Dict] = []
+        for phase, (total, count) in s["ttft_phases"].items():
+            phases.extend([
+                {"type": "GAUGE", "key": f"jaxserver_ttft_{phase}_sum",
+                 "value": float(total)},
+                {"type": "GAUGE", "key": f"jaxserver_ttft_{phase}_count",
+                 "value": float(count)},
+            ])
+        return self._slo_metrics(s) + self._observatory_metrics(s) \
+            + phases + [
             {"type": "GAUGE", "key": "jaxserver_mean_ttft_ms",
              "value": s["mean_ttft_ms"]},
             {"type": "GAUGE", "key": "jaxserver_tokens_out",

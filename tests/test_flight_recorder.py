@@ -21,6 +21,7 @@ The load-bearing claims, in test form:
 import json
 import random
 import threading
+import time
 
 import jax
 import pytest
@@ -169,14 +170,20 @@ def test_engine_timeline_and_slo_accounting(monkeypatch):
     try:
         assert eng.debug_timeline() is not None
         # One plain request, one with a generous deadline (met), one with
-        # an unmeetable deadline (the first dispatch compiles, so 1 ms is
-        # always expired by the first boundary check).
+        # an unmeetable deadline: submitted while the scheduler's lock is
+        # held until its 1 ms has passed, so the first boundary that sees
+        # it finds it expired (with warm compile caches a 4-token request
+        # can otherwise finish inside a millisecond).
         eng.generate_blocking(PROMPT, GREEDY)
         eng.generate_blocking(
             PROMPT, SamplingParams(temperature=0.0, max_new_tokens=4,
                                    deadline_ms=60_000))
-        q = eng.submit(PROMPT, SamplingParams(
-            temperature=0.0, max_new_tokens=4, deadline_ms=1))
+        with eng._book:
+            q = eng.submit(PROMPT, SamplingParams(
+                temperature=0.0, max_new_tokens=4, deadline_ms=1))
+            expired = time.perf_counter() + 0.002
+            while time.perf_counter() < expired:
+                pass
         saw_deadline = False
         while True:
             item = q.get(timeout=120)
@@ -347,3 +354,16 @@ def test_chaos_soak_exactly_one_terminal_span(tmp_path, monkeypatch):
     for s in roots:
         if s["attributes"]["outcome"] != "ok":
             assert s["status"].startswith("ERROR"), s
+    # The phase spans keep the same parity: one unit.executor_wait per
+    # request, and engine.prefill cut in two at most once — only for a
+    # request that got its first token.
+    count = lambda name: sum(1 for s in spans if s["name"] == name)
+    waits = [s["attributes"]["rid"] for s in spans
+             if s["name"] == "unit.executor_wait"]
+    assert sorted(waits) == sorted(rids)
+    prefills = {s["span_id"] for s in spans if s["name"] == "engine.prefill"}
+    cuts = [s for s in spans if s["name"] in ("engine.device_wait",
+                                              "engine.first_token_held")]
+    assert all(s["parent_id"] in prefills for s in cuts)
+    assert count("engine.device_wait") == count("engine.first_token_held") \
+        == count("engine.decode") <= len(prefills) <= accepted

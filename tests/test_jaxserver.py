@@ -212,7 +212,8 @@ def test_jaxserver_metadata_says_where_it_ran(server):
     assert dev["count"] == len(jax.devices())
     assert sorted(md["mesh_devices"]) == list(range(len(jax.devices())))
     assert md["engine"] == {"max_slots": 4, "max_seq_len": 64,
-                            "prompt_buckets": [32]}
+                            "prompt_buckets": [32], "max_admit": 4,
+                            "decode_chunk": [4, 8], "rest_workers": 8}
 
 
 def test_jaxserver_int8_preset_is_born_int8(monkeypatch):

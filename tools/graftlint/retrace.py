@@ -45,7 +45,9 @@ def _is_jax_jit(call: ast.Call) -> bool:
 
 def _is_partial(call: ast.Call) -> bool:
     f = call.func
-    if isinstance(f, ast.Name) and f.id == "partial":
+    # _named_partial: the engine's partial-with-a-name (same binding
+    # semantics; servers/engine.py).
+    if isinstance(f, ast.Name) and f.id in ("partial", "_named_partial"):
         return True
     if isinstance(f, ast.Attribute) and f.attr == "partial" \
             and isinstance(f.value, ast.Name) and f.value.id == "functools":
