@@ -30,6 +30,7 @@ import dataclasses
 import functools
 import json
 import logging
+import math
 import os
 import queue
 import secrets
@@ -493,6 +494,111 @@ class _PendingWave(NamedTuple):
     epoch: int = 0
 
 
+# How far _loop_async may run ahead of the device: waves dispatched and
+# not yet retired. Two is one wave running and one queued behind it,
+# without which the device idles for a host turn after every wave. Five
+# is what the fixed bound it replaces allowed (a fetch queue of four
+# plus the wave the fetcher held), so no engine is deeper than it was.
+_DEPTH_MIN = 2
+_DEPTH_MAX = 5
+# The queued waves cover this many mean host turns: a turn twice as
+# slow as the mean (an admission group to build, a collection, a
+# descheduled thread) still finds the device with a wave to start.
+_DEPTH_MARGIN = 2.0
+# The means weigh the last ~8 samples; the depth stays at _DEPTH_MIN
+# until each has as many.
+_DEPTH_SAMPLES = 8
+
+
+def _pipeline_depth(period_s: float, turn_s: float) -> int:
+    """The smallest D in [_DEPTH_MIN, _DEPTH_MAX] whose D - 1 queued
+    wave periods cover _DEPTH_MARGIN host turns. An 81 ms wave behind a
+    5 ms turn: 2; a 28 ms wave behind a 100 ms round trip: 5."""
+    if period_s <= 0.0:
+        return _DEPTH_MAX
+    need = 1 + math.ceil(_DEPTH_MARGIN * turn_s / period_s)
+    return max(_DEPTH_MIN, min(_DEPTH_MAX, need))
+
+
+class _RunningMean:
+    """Arithmetic mean of the first _DEPTH_SAMPLES samples, then an
+    exponential one of that weight."""
+
+    def __init__(self):
+        self.value = 0.0
+        self.n = 0
+
+    def add(self, x: float) -> None:
+        self.n += 1
+        self.value += (x - self.value) / min(self.n, _DEPTH_SAMPLES)
+
+
+class _DepthEstimator:
+    """What the async scheduler observes of its own pipeline, and the
+    depth that follows from it (_pipeline_depth). Every method runs
+    under the engine's _book.
+
+    wave period: the interval between two consecutive waves' results
+    reaching the host while a further wave was already dispatched, i.e.
+    the device's time for one wave, admissions included.
+    host turn: a wave's results on the host -> the dispatch that its
+    retirement let through returned (fetch processing, wake-up,
+    _dispatch_once), plus what a device_get costs when the device had
+    finished before it was called (the transfer, not the wait)."""
+
+    def __init__(self):
+        self.period = _RunningMean()
+        self.turn = _RunningMean()
+        self.transfer = _RunningMean()
+        self.fetched_at: Optional[float] = None  # the last retired wave's
+        self._paced_from: Optional[float] = None
+
+    def depth(self) -> int:
+        if min(self.period.n, self.turn.n) < _DEPTH_SAMPLES:
+            return _DEPTH_MIN
+        return _pipeline_depth(self.period.value, self.host_turn_s())
+
+    def host_turn_s(self) -> float:
+        return self.turn.value + self.transfer.value
+
+    def note_retire(self, fetched_at: Optional[float], get_s: Optional[float],
+                    more_in_flight: bool) -> None:
+        """One wave left the registry. `fetched_at`: when its results
+        reached the host, None for a wave dropped unread (stale epoch,
+        fault), which says nothing of the device's pace. `get_s`: what
+        its device_get took if the device had already finished."""
+        if fetched_at is None:
+            self._paced_from = None
+            return
+        if self._paced_from is not None:
+            self.period.add(fetched_at - self._paced_from)
+        self._paced_from = fetched_at if more_in_flight else None
+        self.fetched_at = fetched_at
+        if get_s is not None:
+            self.transfer.add(get_s)
+
+    def note_turn(self, dispatched_at: float) -> None:
+        """The scheduler waited at the bound, a retirement let it
+        through and its dispatch returned at `dispatched_at`. A sample
+        is clipped at the turn that already asks for _DEPTH_MAX: a
+        compile of seconds counts as "deep" for a few waves, not for
+        minutes."""
+        if self.fetched_at is None:
+            return
+        turn = dispatched_at - self.fetched_at
+        if self.period.n:
+            turn = min(turn, (_DEPTH_MAX - 1) * self.period.value
+                       / _DEPTH_MARGIN)
+        self.turn.add(turn)
+
+    def gauges(self) -> Dict[str, float]:
+        return {
+            "depth": self.depth(),
+            "wave_period_ms": 1000.0 * self.period.value,
+            "host_turn_ms": 1000.0 * self.host_turn_s(),
+        }
+
+
 class EngineStats:
     def __init__(self):
         # Guards every mutable counter below. The scheduler thread, the
@@ -883,7 +989,10 @@ class InferenceEngine:
         self._async_fetch = (
             self.ecfg.async_fetch and jax.process_count() == 1
         )
-        self._fetch_q: "queue.Queue" = queue.Queue(maxsize=4)
+        # Scheduler -> fetcher hand-off. Unbounded: the one bound on how
+        # far the scheduler runs ahead is the depth of _inflight_waves
+        # (_loop_async).
+        self._fetch_q: "queue.Queue" = queue.Queue()
         self._fetcher: Optional[threading.Thread] = None
         self._dispatch_wreck = None  # partial boundary for error paths  # graftlint: guarded-by(_book)
         # Bumped by every device-state rebuild; waves dispatched against
@@ -902,6 +1011,12 @@ class InferenceEngine:
         # puts and stranded whole waves (epoch-discarded unread, their
         # requests in no book).
         self._inflight_waves: List[_PendingWave] = []  # graftlint: guarded-by(_book)
+        # The async scheduler holds at most _depth_est.depth() waves in
+        # that registry and waits on _room otherwise: cleared by the
+        # scheduler and set by _wave_retire, both under _book, and set
+        # by stop().
+        self._depth_est = _DepthEstimator()  # graftlint: guarded-by(_book)
+        self._room = threading.Event()
         # The synchronous loops keep their one undelivered wave in a
         # local and never register it: 1 while it is outstanding, so
         # _record_first_dispatch reads one depth on every path.
@@ -2303,6 +2418,17 @@ class InferenceEngine:
         with self._book:
             return sum(1 for r in self._slots if r is not None)
 
+    def pipeline_gauges(self) -> Dict[str, float]:
+        """The async scheduler's pipeline depth in force and the two
+        running means it follows from (_DepthEstimator), read under the
+        bookkeeping lock. A synchronous loop is one deep and measures
+        neither."""
+        with self._book:
+            g = self._depth_est.gauges()
+        if not self._async_fetch:
+            g["depth"] = 1
+        return g
+
     def live_requests(self) -> List["_Request"]:
         """Snapshot of the requests currently holding slots, taken under
         the bookkeeping lock. The list is a copy; the _Request objects are
@@ -2335,19 +2461,13 @@ class InferenceEngine:
     def stop(self):
         self._draining.set()
         self._stop.set()
+        self._room.set()  # a scheduler waiting at the depth bound
         if self._thread is not None:
             self._thread.join(timeout=10)
             self._thread = None
         if self._fetcher is not None:
-            # Sentinel AFTER the last real item; bounded retries so a
-            # dead/wedged fetcher can't hang shutdown on a full queue.
-            for _ in range(60):
-                try:
-                    self._fetch_q.put(None, timeout=0.5)
-                    break
-                except queue.Full:
-                    if not self._fetcher.is_alive():
-                        break
+            # Sentinel AFTER the last real item.
+            self._fetch_q.put(None)
             self._fetcher.join(timeout=30)
             self._fetcher = None
         # No waiter may be left hanging: everything still queued or in
@@ -3937,7 +4057,7 @@ class InferenceEngine:
         out = self._jit_ragged(
             self.params,
             self._state,
-            jnp.asarray(self._table_host),
+            self._table_device(),
             jnp.asarray(toks.reshape(-1)),
             jnp.asarray(plens),
             jnp.asarray(starts),
@@ -4105,7 +4225,7 @@ class InferenceEngine:
                 self._state, toks, valid, active_after = self._jit_verify(
                     self.params,
                     self._state,
-                    jnp.asarray(self._table_host),
+                    self._table_device(),
                     jnp.asarray(drafts),
                     jnp.asarray(wave),
                 )
@@ -4574,13 +4694,20 @@ class InferenceEngine:
                     attributes={"tokens": req.n_generated},
                 )
 
-    def _wave_retire(self, item) -> None:  # graftlint: holds(_book)
+    def _wave_retire(self, item, fetched_at: Optional[float] = None,  # graftlint: holds(_book)
+                     get_s: Optional[float] = None) -> None:
         """Remove one wave from the in-flight registry by identity
-        (waves hold unhashable device arrays). No-op for waves never
+        (waves hold unhashable device arrays), tell the depth estimator
+        (`fetched_at`, `get_s`: _DepthEstimator.note_retire) and wake a
+        scheduler waiting at the depth bound. No-op for waves never
         registered (sync-mode boundaries, partial wrecks)."""
         for i, wave in enumerate(self._inflight_waves):
             if wave is item:
                 del self._inflight_waves[i]
+                self._depth_est.note_retire(
+                    fetched_at, get_s, bool(self._inflight_waves)
+                )
+                self._room.set()
                 return
 
     def _gather_wrecked(self, pendings=()) -> Dict[int, _Request]:  # graftlint: holds(_book)
@@ -5195,16 +5322,20 @@ class InferenceEngine:
             )
 
     def _fetch_loop(self) -> None:
-        """Boundary-fetcher thread: device_get (a full host<->device
-        round trip) runs OUTSIDE the bookkeeping lock, so the scheduler
-        keeps dispatching while results travel; only the host-side
-        processing serializes with it. A request's first token therefore
-        costs ~one round trip under load instead of two."""
+        """Boundary-fetcher thread: device_get (the wait for the device
+        to finish the wave, then the transfer) runs OUTSIDE the
+        bookkeeping lock, so the scheduler keeps dispatching while
+        results travel; only the host-side processing serializes with
+        it. Every wave it takes is retired here, exactly once, and the
+        retirement is what lets the scheduler dispatch the next
+        (_loop_async): a request's first token waits for the waves in
+        flight ahead of its admission, at most the depth less one."""
         while True:
             item = self._fetch_q.get()
             if item is None:
                 return
             admits, chunk_handles, roster, timing, epoch = item
+            fetched_at = get_s = None
             try:
                 with self._book:
                     if epoch != self._wave_epoch:
@@ -5220,14 +5351,19 @@ class InferenceEngine:
                 if self._chaos is not None:
                     self._chaos.maybe_slow_boundary()
                 roofing = self._roof is not None and timing is not None
-                f0 = time.perf_counter() if roofing else 0.0
+                # The chunk is the wave's last program: ready before the
+                # device_get, the call costs the transfer alone.
+                was_ready = chunk_handles[-1].is_ready()
+                f0 = time.perf_counter()
                 admit_data, chunk_data, admit_ready = \
                     self._fetch_boundary(admits, chunk_handles)
-                f1 = time.perf_counter() if roofing else 0.0
+                f1 = time.perf_counter()
                 with self._book, \
                         jax.profiler.TraceAnnotation("fetch.process"):
                     if epoch != self._wave_epoch:
                         continue  # rebuild raced the fetch: stale wave
+                    fetched_at = f1
+                    get_s = f1 - f0 if was_ready else None
                     self._process_admits(admits, admit_data, admit_ready)
                     if chunk_data is not None:
                         self._process_chunk(*chunk_data, roster)
@@ -5242,12 +5378,13 @@ class InferenceEngine:
                         self._heal.note_boundary_ok()
             except Exception as e:
                 logger.exception("boundary fetch failed")
+                fetched_at = None  # the device state is rebuilt: no pace
                 self._drain_and_fail(str(e), current=item)
             finally:
                 # Retire exactly once on every path — processed, stale-
                 # dropped, or faulted (after recovery gathered it).
                 with self._book:
-                    self._wave_retire(item)
+                    self._wave_retire(item, fetched_at, get_s)
 
     def _loop(self) -> None:
         # Software-pipelined scheduler: chunk N+1 is dispatched BEFORE
@@ -5260,10 +5397,12 @@ class InferenceEngine:
         # attribution exact). Length-bounded rows free their slots at
         # DISPATCH time (_recycle_budget_spent), so the pipeline never
         # drains at wave boundaries; EOS-finished rows free one boundary
-        # late. With async_fetch (single-process), fetches run on a
-        # dedicated thread (_fetch_loop) and this loop NEVER blocks on a
-        # round trip; multi-process meshes keep the synchronous variant
-        # so SPMD dispatch decisions stay timing-independent.
+        # late (the async loop's depth less one). With async_fetch
+        # (single-process), fetches run on a dedicated thread
+        # (_fetch_loop) and this loop waits only for room in its
+        # pipeline, never on a fetch; multi-process meshes keep the
+        # synchronous variant so SPMD dispatch decisions stay
+        # timing-independent.
         if self._async_fetch:
             self._loop_async()
         else:
@@ -5314,6 +5453,15 @@ class InferenceEngine:
                      "boundaries": self._profile_count},
                 )
 
+    def _table_device(self):  # graftlint: holds(_book)
+        """The block tables as a program's argument, from a copy: the
+        CPU backend aliases a numpy buffer it is handed (and any backend
+        may read it after the call returns), and the next dispatch grows
+        and rebinds rows of _table_host before this one's program has
+        run. Without the copy a pipelined wave read the tables of the
+        wave after it, whenever the host got that far ahead."""
+        return jnp.asarray(self._table_host.copy())
+
     def _dispatch_decode_chunk(self, n: int):  # graftlint: holds(_book)
         """Dispatch one n-step decode chunk. Dense engines call the slab
         kernel unchanged; paged engines first grow each live row's block
@@ -5325,11 +5473,11 @@ class InferenceEngine:
             self._grow_decode_blocks(n)
             if not self._observe:
                 return self._jit_chunks_paged[n](
-                    self.params, self._state, jnp.asarray(self._table_host)
+                    self.params, self._state, self._table_device()
                 )
             t0 = time.perf_counter()
             out = self._jit_chunks_paged[n](
-                self.params, self._state, jnp.asarray(self._table_host)
+                self.params, self._state, self._table_device()
             )
             self._note_dispatch(("decode", n), -1,
                                 time.perf_counter() - t0)
@@ -5432,7 +5580,8 @@ class InferenceEngine:
         profiler's clock: in a device profile it shows what the
         scheduler was doing in the gap before a program. Metadata: the
         wave's sequence number, the requests it admitted, its decode
-        steps."""
+        steps, and the pipeline depth in force with the two means it
+        follows from (_DepthEstimator)."""
         with jax.profiler.TraceAnnotation("sched.dispatch") as span:
             work = self._dispatch_wave()
             if work is not None:
@@ -5443,6 +5592,7 @@ class InferenceEngine:
                     # chunk_handles[0] is the tokens array [steps, slots]
                     chunk_steps=work.chunk_handles[0].shape[0]
                     if work.chunk_handles else 0,
+                    **self._depth_est.gauges(),
                 )
         return work
 
@@ -5482,9 +5632,10 @@ class InferenceEngine:
             self._recycle_budget_spent(roster, n)
             # Start the host copies NOW: the fetcher's device_get then
             # finds data already in flight, so boundary fetches overlap
-            # each other instead of serializing one round trip each
-            # (the fetcher was the pipeline bottleneck at small decode
-            # chunks, where a chunk computes faster than one round trip).
+            # each other instead of serializing one transfer each. Where
+            # a chunk computes faster than a transfer (a small model, a
+            # remote device) the fetcher paces the pipeline, and the
+            # depth estimator runs it deeper (_DepthEstimator).
             for _, _, f, d in admits:
                 f.copy_to_host_async()
                 d.copy_to_host_async()
@@ -5519,11 +5670,24 @@ class InferenceEngine:
         return None
 
     def _loop_async(self) -> None:
+        # The one bound on how far this loop runs ahead of the device:
+        # it dispatches only while fewer than _depth_est.depth() waves
+        # are registered in _inflight_waves, and waits on _room, outside
+        # _book, otherwise. Everything that retires a wave (the fetcher:
+        # processed, epoch-stale or faulted) sets _room, and so does
+        # stop(). A wave ahead of an admission is a whole chunk of TTFT,
+        # so the depth is what the measured host turn needs and no more.
+        waited = False
         while not self._stop.is_set():
             work = None
             try:
                 with self._book:
-                    work = self._dispatch_once()
+                    full = (len(self._inflight_waves)
+                            >= self._depth_est.depth())
+                    if full:
+                        self._room.clear()
+                    else:
+                        work = self._dispatch_once()
                     # Register the wave before releasing _book: requests
                     # recycled out of _slots this dispatch live only in
                     # its roster, and a recovery at ANY point before the
@@ -5531,6 +5695,8 @@ class InferenceEngine:
                     # (see _gather_wrecked).
                     if work is not None:
                         self._inflight_waves.append(work)
+                        if waited:
+                            self._depth_est.note_turn(time.perf_counter())
             except Exception as e:
                 logger.exception("engine dispatch failed")
                 # _dispatch_once may have recycled requests out of
@@ -5538,17 +5704,20 @@ class InferenceEngine:
                 with self._book:
                     wreck, self._dispatch_wreck = self._dispatch_wreck, None
                 self._drain_and_fail(str(e), current=wreck)
+                waited = False
                 continue
-            if work is not None:
+            waited = full
+            if full:
+                # _stop is read again after the clear() above: a stop()
+                # between the loop's test and the clear() is not lost.
+                if not self._stop.is_set():
+                    with jax.profiler.TraceAnnotation("sched.wait_depth"):
+                        self._room.wait()
+            elif work is not None:
                 if self._profile_n:
                     self._profile_tick()
-                # Bounded queue (maxsize=4): caps how far the host's
-                # slot-state view may lag behind retired boundaries.
-                # Blocks OUTSIDE the lock, so the fetcher keeps
-                # draining; the wave stays registered until the fetcher
-                # retires it.
-                with jax.profiler.TraceAnnotation("sched.blocked_on_fetch_q"):
-                    self._fetch_q.put(work)
+                # The wave stays registered until the fetcher retires it.
+                self._fetch_q.put(work)
             elif self._pending.empty():
                 if self._sled is not None:
                     self._sled.note_idle()

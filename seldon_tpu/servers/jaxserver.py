@@ -999,8 +999,16 @@ class JAXServer(SeldonComponent):
                 {"type": "GAUGE", "key": f"jaxserver_ttft_{phase}_count",
                  "value": float(count)},
             ])
+        # What bounds device_wait: the scheduler's depth (waves_ahead is
+        # at most one less) and the wave period / host turn it is
+        # derived from (engine._DepthEstimator).
+        pipeline = [
+            {"type": "GAUGE", "key": f"jaxserver_sched_{name}",
+             "value": float(value)}
+            for name, value in self.engine.pipeline_gauges().items()
+        ]
         return self._slo_metrics(s) + self._observatory_metrics(s) \
-            + phases + [
+            + phases + pipeline + [
             {"type": "GAUGE", "key": "jaxserver_mean_ttft_ms",
              "value": s["mean_ttft_ms"]},
             {"type": "GAUGE", "key": "jaxserver_tokens_out",

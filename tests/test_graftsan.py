@@ -349,9 +349,11 @@ def test_greedy_output_bit_identical_with_sanitizer(mode, monkeypatch):
         got = eng.generate_blocking(PROMPT, GREEDY)["token_ids"]
         assert eng._san is not None
         assert eng._san.violations == []
-        assert eng._san.audits > 0  # the boundary audit actually ran
     finally:
         eng.stop()
+    # read after stop() has joined the fetcher: the audit runs after the
+    # delivery that lets generate_blocking return, in the same boundary
+    assert eng._san.audits > 0  # the boundary audit actually ran
     assert got == want
 
 

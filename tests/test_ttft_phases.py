@@ -125,9 +125,11 @@ def test_caller_without_a_transport_reads_no_executor_wait():
     assert late["timings"]["executor_wait_ms"] == 0.0
 
 
-@pytest.mark.parametrize("mode", ["dense", "sync"])
+@pytest.mark.parametrize("mode", ["dense", "dense-depth2", "sync"])
 def test_waves_ahead_is_the_depth_at_first_dispatch(mode):
-    eng = _engine(**MODES[mode])
+    eng = _engine(**MODES[mode.split("-")[0]])
+    if mode == "dense-depth2":  # pin the estimator, as both cells sit
+        eng._depth_est.depth = lambda: 2
     seen = {}
     inner = eng._record_first_dispatch
 
@@ -147,7 +149,9 @@ def test_waves_ahead_is_the_depth_at_first_dispatch(mode):
         eng.stop()
     assert {r.rid: r.waves_ahead for r in reqs} == seen
     assert all(d >= 0 for d in seen.values())
-    if mode == "sync":  # one undelivered wave at most
+    if mode == "dense":  # the async loop is never deeper than five
+        assert max(seen.values()) <= 4
+    else:  # one wave queued behind the running one / one undelivered
         assert set(seen.values()) <= {0, 1}
 
 
@@ -477,6 +481,10 @@ def test_metrics_are_current_when_scraped(rest_unit):
         assert _gauge(text, f"jaxserver_ttft_{k}_count") == snap[k][1]
         assert _gauge(text, f"jaxserver_ttft_{k}_sum") == pytest.approx(
             snap[k][0])
+    # what bounds device_wait stands beside the sums
+    assert 2 <= _gauge(text, "jaxserver_sched_depth") <= 5
+    assert _gauge(text, "jaxserver_sched_wave_period_ms") >= 0.0
+    assert _gauge(text, "jaxserver_sched_host_turn_ms") >= 0.0
 
 
 def test_scrape_repeats_no_counter_of_the_unit():
