@@ -1,12 +1,15 @@
 """Child process of the benchmark: the unit, through its normal entry point.
 
 Its only extra acts: (1) register the cell's configuration in
-seldon_tpu.models.PRESETS under its name before the entry point runs (the
-program has no preset for these models and may not be edited by a
-benchmark PR); (2) with --profile-dir, watch that directory for `start`
-and `stop` files and run jax.profiler between them (only the process that
-holds the chip can trace it). Everything else is
-`python -m seldon_tpu.runtime.microservice <Class> ...` unchanged.
+seldon_tpu.models.PRESETS under its name before the entry point runs. The
+configuration's keys become ModelConfig's through the key map of the
+configuration's family (benchmark/families/<family>.py, found by the
+file's `family` key: benchmark/family.py), so an architecture with other
+keys brings its own map as a new file; the ModelConfig fields it maps
+onto have to exist in the program first; (2) with --profile-dir, watch
+that directory for `start` and `stop` files and run jax.profiler between
+them (only the process that holds the chip can trace it). Everything else
+is `python -m seldon_tpu.runtime.microservice <Class> ...` unchanged.
 
 usage: launcher.py --config <file.json> [--preset-name NAME]
                    [--profile-dir DIR] -- <microservice arguments>
@@ -20,41 +23,21 @@ import sys
 import threading
 import time
 
-
-def model_config_kwargs(cfg: dict) -> dict:
-    """The benchmark's configuration file (HF key names) as keyword
-    arguments of seldon_tpu.models.config.ModelConfig."""
-    serving = cfg.get("serving", {})
-    kw = dict(
-        vocab_size=cfg["vocab_size"],
-        d_model=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"],
-        max_seq_len=cfg["max_position_embeddings"],
-        rope_theta=float(cfg["rope_theta"]),
-        rms_norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        n_experts=int(cfg.get("num_local_experts", 0) or 0),
-        weight_dtype=serving.get("weight_dtype", "bf16"),
-        kv_cache_dtype=serving.get("kv_cache_dtype", "bf16"),
-    )
-    if kw["n_experts"]:
-        kw["n_experts_per_token"] = int(cfg["num_experts_per_tok"])
-    head_dim = cfg.get("head_dim")
-    if head_dim and head_dim * kw["n_heads"] != kw["d_model"]:
-        raise ValueError("the program derives head_dim as d_model / n_heads")
-    return kw
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def register_preset(config_file: str, name: str = "") -> str:
+    """The configuration, through its family's key map, as the preset the
+    unit is asked for: the file states all that the unit runs, so a preset
+    the program has under that name is replaced, not mixed in."""
+    import family
     from seldon_tpu.models.config import PRESETS, ModelConfig
 
     with open(config_file) as f:
         cfg = json.load(f)
     name = name or cfg["name"]
-    PRESETS[name] = ModelConfig(**model_config_kwargs(cfg)).validate()
+    kw = family.load(HERE, cfg).model_config_kwargs(cfg)
+    PRESETS[name] = ModelConfig(**kw).validate()
     return name
 
 
@@ -88,8 +71,7 @@ def main(argv) -> int:
     split = argv.index("--")
     own, rest = argv[:split], argv[split + 1:]
     opts = dict(zip(own[::2], own[1::2]))
-    here = os.path.dirname(os.path.abspath(__file__))
-    sys.path.insert(0, os.path.dirname(here))  # the checkout: seldon_tpu/
+    sys.path.insert(0, os.path.dirname(HERE))  # the checkout: seldon_tpu/
     register_preset(opts["--config"], opts.get("--preset-name", ""))
     if opts.get("--profile-dir"):
         os.makedirs(opts["--profile-dir"], exist_ok=True)

@@ -4,9 +4,9 @@
 
 One run = one new process. This process never imports JAX: it starts the
 unit as a child (benchmark/launcher.py -> the normal microservice entry
-point, REST, platform "tpu", tp=1), waits for /ready, checks /metadata,
-warms up the cell's own shapes, sends the greedy probes (which also
-time the hop), runs lead-in + window + tail of the cell's traffic, drains, reads
+point, REST, platform "tpu", tp = the cell's chips), waits for /ready,
+checks /metadata, warms up the cell's own shapes, sends the greedy probes
+(which also time the hop), runs lead-in + window + tail of the cell's traffic, drains, reads
 the counters, stops the child, reduces the trace (--trace 1, in a second
 child), checks parity with the plain reference if this checkout has not
 done so yet (a third child, once the chip is free), and prints one JSON
@@ -39,7 +39,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
 import client  # noqa: E402
-import launcher  # noqa: E402  (imports no JAX until its main runs)
+import family  # noqa: E402  (imports no JAX, nor does loading a family)
 import metrics  # noqa: E402
 import peaks  # noqa: E402
 import stats  # noqa: E402
@@ -67,13 +67,6 @@ def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
-
-
-def cache_dir() -> str:
-    """Where the child keeps JAX's persistent compile cache
-    (seldon_tpu/device.enable_compile_cache: the variable if set, else
-    <checkout>/.jax_cache). The parity markers live in it too."""
-    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(ROOT, ".jax_cache")
 
 
 def tail_of(path: str, n: int = 30) -> str:
@@ -109,6 +102,10 @@ class Run:
         self.config_file = os.path.join(ROOT, entry["file"])
         with open(self.config_file) as f:
             self.cfg = json.load(f)
+        try:  # what differs between architectures: benchmark/families/<family>.py
+            self.family = family.load(HERE, self.cfg)
+        except FileNotFoundError as e:
+            raise BenchFailure(str(e)) from None
         self.spec = traffic.load_traffic(HERE, self.cell["traffic"],
                                          self.cell["name"], args.rehearse)
         self.window_tokens = int(self.spec["window_tokens"])
@@ -121,19 +118,20 @@ class Run:
                                  self.cell["name"])
         os.makedirs(self.work, exist_ok=True)
         self.profile_dir = os.path.join(self.work, "profile")
-        self.obs = metrics.Obs(cfg=self.cfg, spec=self.spec,
+        self.obs = metrics.Obs(cfg=self.cfg, family=self.family, spec=self.spec,
                                cell=self.cell, seconds=args.seconds,
                                slots=self.slots)
         self.problems = []  # what makes the run incorrect
 
     # -- the child ----------------------------------------------------------
 
-    def start_unit(self) -> subprocess.Popen:
+    def unit_parameters(self) -> list:
+        """The unit's parameters: one model over as many chips as the cell asks."""
         params = [
             {"name": "preset", "value": self.preset, "type": "STRING"},
             {"name": "init_seed", "value": str(self.args.seed % (2 ** 31 - 1)),
              "type": "INT"},
-            {"name": "tp", "value": "1", "type": "INT"},
+            {"name": "tp", "value": str(self.cell["chips"]), "type": "INT"},
             {"name": "max_slots", "value": str(self.slots), "type": "INT"},
             {"name": "max_seq_len", "value": str(self.window_tokens), "type": "INT"},
             {"name": "platform", "value": self.platform, "type": "STRING"},
@@ -141,6 +139,9 @@ class Run:
         if not self.args.rehearse:
             params.append({"name": "weight_dtype", "type": "STRING",
                            "value": self.cfg["serving"]["weight_dtype"]})
+        return params
+
+    def start_unit(self) -> subprocess.Popen:
         env = dict(os.environ)
         env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
         env["COMPILE_LEDGER"] = "1"      # acts only on a first dispatch
@@ -160,7 +161,7 @@ class Run:
         cmd += ["--", "seldon_tpu.servers.jaxserver.JAXServer",
                 "--api-type", "REST", "--host", "127.0.0.1",
                 "--http-port", str(self.port),
-                "--parameters", json.dumps(params)]
+                "--parameters", json.dumps(self.unit_parameters())]
         self.log_path = os.path.join(self.work, "unit.log")
         with open(self.log_path, "wb") as log:
             return subprocess.Popen(cmd, cwd=ROOT, env=env, stdout=log,
@@ -194,7 +195,7 @@ class Run:
             raise BenchFailure(f"{dev['count']} device(s), cell needs {self.cell['chips']}")
         if not self.args.rehearse:
             self.obs["peaks"] = peaks.peaks_for(dev["device_kind"])  # raises if unknown
-            for k, v in launcher.model_config_kwargs(self.cfg).items():
+            for k, v in self.family.model_config_kwargs(self.cfg).items():
                 if got.get(k) != v:
                     raise BenchFailure(f"unit's {k}={got.get(k)!r}, configuration says {v!r}")
         eng = md["engine"]
@@ -441,6 +442,22 @@ class Run:
             f"modules {tr['modules']}")
         shutil.rmtree(self.profile_dir, ignore_errors=True)
 
+    def parity_job(self, probes) -> dict:
+        """What the parity child is given. `config_sha` is the digest of the
+        criterion and the code that applies it: the configuration's file,
+        reference.py and the family's file."""
+        h = hashlib.sha256()
+        for path in (self.config_file, os.path.join(HERE, "reference.py"),
+                     family.file_of(HERE, self.cfg)):
+            with open(path, "rb") as f:
+                h.update(f.read())
+        digest = h.hexdigest()[:16]
+        # inside the checkout whatever JAX_COMPILATION_CACHE_DIR says: where two
+        # checkouts share a compile cache, neither reads the other's verdict
+        marker = os.path.join(ROOT, ".jax_cache", f"benchmark_parity_{self.cfg['name']}.json")
+        return {"config": self.config_file, "seed": self.args.seed % (2 ** 31 - 1),
+                "probes": probes, "marker": marker, "config_sha": digest}
+
     def parity(self, probes) -> None:
         """Once per configuration and checkout: the engine's greedy tokens
         against the plain reference's logits, at the widths and depth the
@@ -448,12 +465,8 @@ class Run:
         if self.args.rehearse:
             self.obs["parity"] = "skipped in a rehearsal (benchmark/tests cover it)"
             return
-        h = hashlib.sha256()  # the criterion and the code that applies it
-        for path in (self.config_file, os.path.join(HERE, "reference.py")):
-            with open(path, "rb") as f:
-                h.update(f.read())
-        digest = h.hexdigest()[:16]
-        marker = os.path.join(cache_dir(), f"benchmark_parity_{self.cfg['name']}.json")
+        job = self.parity_job(probes)
+        digest, marker = job["config_sha"], job["marker"]
         if os.path.exists(marker):
             with open(marker) as f:
                 m = json.load(f)
@@ -463,13 +476,12 @@ class Run:
                     self.problems.append(f"parity failed earlier in this checkout: {m}")
                 return
         t = now()
-        job = os.path.join(self.work, "parity_job.json")
-        with open(job, "w") as f:
-            json.dump({"config": self.config_file, "seed": self.args.seed % (2 ** 31 - 1),
-                       "probes": probes, "marker": marker, "config_sha": digest}, f)
+        job_file = os.path.join(self.work, "parity_job.json")
+        with open(job_file, "w") as f:
+            json.dump(job, f)
         env = dict(os.environ)
         env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-        p = subprocess.run([sys.executable, os.path.join(HERE, "reference.py"), job],
+        p = subprocess.run([sys.executable, os.path.join(HERE, "reference.py"), job_file],
                            env=env, capture_output=True, text=True, timeout=900)
         say(f"parity: {now() - t:.1f}s (not part of setup_s)")
         if p.returncode != 0 or not os.path.exists(marker):
@@ -480,6 +492,25 @@ class Run:
         say(f"parity: {m}")
         if not m["ok"]:
             self.problems.append(f"parity with the plain reference failed: {m}")
+
+    def compared(self) -> list:
+        """Each number that `correct` compares, beside its limit."""
+        o = self.obs
+        lines = [f"compared: failed {o.failed} of {o.attempted} (metrics are +inf "
+                 f"over a tenth); compile.in_window {o.compile_in_window} (limit 0); "
+                 f"problems {len(self.problems)} (limit 0)"]
+        m = o.parity
+        if isinstance(m, dict):
+            c = m["control"]
+            lines.append(
+                f"compared: parity of {m['config']} (family {m['family']}, seed "
+                f"{m['seed']}, {m['positions']} positions): share within epsilon "
+                f"{m['epsilon']} is {m['share_within']} (limit >= {m['min_share_within']}); "
+                f"positions beyond {m['epsilon_all']}: {m['over_epsilon_all']} (limit <= "
+                f"{m['max_over_epsilon_all']}), widest gap {m['max_gap']}; control "
+                f"({c['weights']}): share {c['share_within']}, beyond {c['over_epsilon_all']}, "
+                f"widest gap {c['max_gap']}, rejected {c['rejected']}")
+        return lines
 
     def result(self) -> dict:
         o = self.obs
@@ -500,12 +531,17 @@ class Run:
             ms = {k + "_cpu_smoke": v for k, v in ms.items()}
         for p in self.problems:
             say("INCORRECT: " + p)
+        for line in self.compared():  # the end of stderr is what a refusal keeps
+            say(line)
+            print("[bench] " + line, file=sys.stderr, flush=True)
         out = {"correct": not self.problems, "attempted": o["attempted"],
                "failed": o["failed"], "metrics": ms, "device": self.device}
         if isinstance(o.parity, dict):  # which comparison decided `correct`
             out["reference"] = {k: o.parity.get(k) for k in
-                                ("config", "ok", "epsilon", "share_within",
-                                 "max_gap", "positions", "control", "device")}
+                                ("config", "family", "ok", "epsilon", "min_share_within",
+                                 "epsilon_all", "max_over_epsilon_all", "share_within",
+                                 "over_epsilon_all", "max_gap", "positions", "control",
+                                 "device")}
         tr = o.trace
         if tr:
             out["breakdown"] = {"device_ops": tr["device_ops"][:10],
