@@ -1,15 +1,25 @@
-"""Model configuration for the transformer family.
+"""Model configuration for the decoder models this repo serves.
 
 Configs are static dataclasses so every shape is known at trace time —
-XLA requirement (no dynamic shapes under jit). Presets cover the bench
-ladder: `tiny` (CPU tests), `bench-1b` (fits one v5e chip in bf16),
-`llama3-8b` (the BASELINE.json north-star target, TP over a v5e-8 slice).
+XLA requirement (no dynamic shapes under jit). One ModelConfig covers
+the homogeneous stack (attention + SwiGLU or all-expert MoE in every
+layer: the llama / mistral / mixtral presets) and the PATTERNED stack
+(`layer_types` set: a per-layer operator kind, short-conv or attention,
+leading dense feed-forward layers before the sparse ones, an expert
+width of its own, a sigmoid router, QK-norm). Presets: `tiny*` (CPU
+tests), `bench-1b` (one v5e chip in bf16), `llama3-8b` / `llama3-70b`
+(geometry only; the benchmark's configurations are registered from
+benchmark/configs/ by its launcher).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
+
+# Operator kinds of a patterned stack (the published `layer_types` names).
+OP_CONV = "conv"
+OP_ATTN = "full_attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,10 +75,71 @@ class ModelConfig:
     rope_scaling_low_freq_factor: float = 1.0
     rope_scaling_high_freq_factor: float = 4.0
     rope_scaling_original_max_position: int = 8192
+    # --- patterned stack (empty layer_types = homogeneous, as before) ---
+    # Operator of each layer, "conv" (gated short convolution with a
+    # fixed-size state per slot) or "full_attention" (GQA with KV).
+    # A list is accepted and stored as a tuple (hashable); asdict() and
+    # a JSON round trip give the list back, which is what /metadata
+    # serves and the benchmark's harness compares.
+    layer_types: Tuple[str, ...] = ()
+    # Leading layers whose feed-forward is the dense SwiGLU (width d_ff)
+    # even when n_experts > 0; the layers after them are sparse.
+    n_dense_layers: int = 0
+    # Width of one expert's SwiGLU (0 = d_ff).
+    d_ff_expert: int = 0
+    # Router of the sparse block: "softmax" (top-k of the logits, softmax
+    # over those k: Mixtral) or "sigmoid" (scores = sigmoid(logits),
+    # selection on score [+ expert_bias], weights = the unbiased scores
+    # at the selected, optionally renormalised, times router_scale).
+    router: str = "softmax"
+    router_bias: bool = False
+    router_norm_topk: bool = True
+    router_scale: float = 1.0
+    # RMSNorm over head_dim on q and k, before RoPE.
+    qk_norm: bool = False
+    # Taps of the depthwise causal short convolution; the decode state
+    # is the last conv_kernel - 1 inputs per slot and conv layer.
+    conv_kernel: int = 3
+
+    def __post_init__(self):
+        if not isinstance(self.layer_types, tuple):
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def patterned(self) -> bool:
+        return bool(self.layer_types)
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    def op_kind(self, layer: int) -> str:
+        return self.layer_types[layer] if self.layer_types else OP_ATTN
+
+    def ff_sparse(self, layer: int) -> bool:
+        return bool(self.n_experts) and layer >= self.n_dense_layers
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that hold KV."""
+        if not self.layer_types:
+            return self.n_layers
+        return sum(1 for t in self.layer_types if t == OP_ATTN)
+
+    @property
+    def n_conv_layers(self) -> int:
+        """Layers that hold a conv state."""
+        return self.n_layers - self.n_attn_layers
+
+    @property
+    def n_sparse_layers(self) -> int:
+        if not self.n_experts:
+            return 0
+        return self.n_layers - min(self.n_dense_layers, self.n_layers)
 
     @property
     def q_per_kv(self) -> int:
@@ -94,6 +165,35 @@ class ModelConfig:
         )
         if self.n_experts:
             assert self.n_experts_per_token <= self.n_experts
+        assert self.router in ("softmax", "sigmoid"), (
+            f"unknown router {self.router!r}"
+        )
+        assert 0 <= self.n_dense_layers <= self.n_layers, (
+            "n_dense_layers must lie in [0, n_layers]"
+        )
+        if self.layer_types:
+            assert len(self.layer_types) == self.n_layers, (
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"n_layers is {self.n_layers}"
+            )
+            bad = sorted(set(self.layer_types) - {OP_CONV, OP_ATTN})
+            assert not bad, f"unknown layer_types entries {bad}"
+            assert self.conv_kernel >= 2, "conv_kernel must be >= 2"
+            assert self.kv_cache_dtype == "bf16" and \
+                self.weight_dtype == "bf16" and self.attn_impl == "xla", (
+                    "a patterned stack (layer_types) is served in bf16 "
+                    "with attn_impl='xla': int8 weights / KV and the "
+                    "flash / ring kernels know only the homogeneous stack"
+                )
+        else:
+            # These fields act only in the patterned stack; a homogeneous
+            # config that sets them would silently run without them.
+            assert (self.n_dense_layers == 0 and self.d_ff_expert == 0
+                    and self.router == "softmax" and not self.router_bias
+                    and not self.qk_norm), (
+                "n_dense_layers / d_ff_expert / router / router_bias / "
+                "qk_norm need layer_types (the patterned stack)"
+            )
         return self
 
 
@@ -122,6 +222,31 @@ PRESETS = {
         eos_token_id=1,
         n_experts=4,
         n_experts_per_token=2,
+    ),
+    # The patterned stack at CPU-test size: 2 leading dense layers, then
+    # one period (attention, conv, conv, conv) of sparse layers with a
+    # sigmoid router, expert bias, QK-norm, tied embeddings.
+    "tiny-lfm2": ModelConfig(
+        vocab_size=256,
+        d_model=64,
+        n_layers=6,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=128,
+        max_seq_len=128,
+        rope_theta=1000000.0,
+        eos_token_id=1,
+        tie_embeddings=True,
+        n_experts=8,
+        n_experts_per_token=4,
+        layer_types=("conv", "conv", "full_attention", "conv", "conv",
+                     "conv"),
+        n_dense_layers=2,
+        d_ff_expert=32,
+        router="sigmoid",
+        router_bias=True,
+        qk_norm=True,
+        conv_kernel=3,
     ),
     # ~1.1B params: single v5e chip (16 GB HBM) with room for KV cache.
     "bench-1b": ModelConfig(

@@ -73,6 +73,11 @@ def validate(cfg, tp: int) -> None:
     tp = int(tp)
     if tp <= 1:
         return
+    if getattr(cfg, "patterned", False):
+        raise ValueError(
+            f"tp={tp}: the exact-TP table knows the homogeneous stack "
+            "only; a patterned stack (layer_types) has conv and expert "
+            "weights it gives no sharding for")
     if cfg.n_kv_heads % tp:
         raise ValueError(
             f"tp={tp} must divide n_kv_heads={cfg.n_kv_heads} "

@@ -62,6 +62,9 @@ def loss_fn(params, tokens, loss_mask, cfg: ModelConfig, act_spec=None,
     tokens [B,S]; loss_mask [B,S] (0 on pad/prompt).
     forward_fn overrides the dense forward (pipeline-parallel path);
     ring_mesh activates ring attention (attn_impl == "ring")."""
+    # No load-balance loss, no remat and no gradient of the grouped
+    # kernel there: refuse by name, do not train something else.
+    transformer.refuse_patterned(cfg, "training (models/train.py)")
     if forward_fn is not None:
         logits, aux = forward_fn(params, tokens)
     else:

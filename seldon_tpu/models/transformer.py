@@ -1,4 +1,7 @@
-"""Llama-family transformer, functional JAX, TPU-first.
+"""Decoder models, functional JAX, TPU-first: the homogeneous Llama-
+family stack and (cfg.layer_types) the patterned stack at the end of
+this file, whose layers differ in operator (short conv or attention) and
+feed-forward (dense or token -> expert dispatch).
 
 Design (vs the reference's black-box CPU model servers, SURVEY.md §2.5):
  * Params are a plain pytree with layers STACKED on a leading [L, ...] axis
@@ -15,14 +18,16 @@ Design (vs the reference's black-box CPU model servers, SURVEY.md §2.5):
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from seldon_tpu.models.config import ModelConfig
+from seldon_tpu.models.config import OP_ATTN, OP_CONV, ModelConfig
 from seldon_tpu.models.quantize import dequant
+from seldon_tpu.ops import moe_dispatch
 
 Params = Dict[str, Any]
 
@@ -123,6 +128,8 @@ def _dtype(cfg: ModelConfig):
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     cfg = cfg.validate()
+    if cfg.patterned:
+        return _init_params_patterned(cfg, key)
     dt = _dtype(cfg)
     L, D, F, V = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -324,8 +331,11 @@ def moe_block(x: jnp.ndarray, bp: Dict[str, jnp.ndarray], cfg: ModelConfig):
     """Top-k MoE. Dense-mixing formulation: every expert runs on every token
     and results are combined with the (sparsified) router weights. This is
     compute-inflated by E/k but fully static-shaped and shards cleanly over
-    'ep'; the dropless all_to_all dispatch path is ops/moe_dispatch.py's job
-    once capacity-based routing lands.
+    'ep'. The token -> expert dispatch (ops/moe_dispatch.py: grouped
+    products over the experts that hold tokens) is what the patterned
+    stack runs (_sparse_ff); with the softmax router it computes this
+    block's function (tests/test_patterned.py), so moving this path onto
+    it is a swap, judged on its own cell (ROADMAP A6).
     """
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.n_experts_per_token
@@ -469,6 +479,10 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
             B, S, cfg.n_heads, Dh)
         k = _qdot(h, bp, "wk", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
         v = _qdot(h, bp, "wv", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
+        if cfg.qk_norm:
+            with jax.named_scope("attn/qk_norm"):
+                q = rms_norm(q, bp["q_norm"], cfg.rms_norm_eps)
+                k = rms_norm(k, bp["k_norm"], cfg.rms_norm_eps)
         q = apply_rope(q, positions, inv_freq)
         k = apply_rope(k, positions, inv_freq)
         if tp is not None:
@@ -708,6 +722,16 @@ def forward(
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     inv_freq = rope_frequencies(cfg)
     mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
+    if cfg.patterned:
+        refuse_patterned(cfg, "sharded or rematerialised forward",
+                          act_spec is not None or remat
+                          or ring_mesh is not None)
+        x, _, _ = _run_patterned_full(params, x, cfg, positions, inv_freq,
+                                      mask, None)
+        logits = _logits(params, x, cfg)
+        if return_aux:
+            return logits, {"moe_lb_loss": jnp.zeros((), jnp.float32)}
+        return logits
     x, _, aux = _run_blocks(params, x, cfg, positions, inv_freq, mask,
                             act_spec=act_spec, remat=remat,
                             ring_mesh=ring_mesh)
@@ -717,33 +741,107 @@ def forward(
     return logits
 
 
-def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None) -> Cache:
-    """KV cache, HEAD-major [L, B, Hkv, T, Dh] (scales [L, B, Hkv, T]).
+class CacheEntry(NamedTuple):
+    """One array of the per-slot cache: what it holds ("kv" = keys,
+    values and their int8 scales, one position per token; "conv" = the
+    short convolution's last inputs, a fixed size per slot), its shape
+    [layers of that kind, batch, ...], dtype, fill value, and the axis
+    that runs over token positions (None: no such axis)."""
+    kind: str
+    shape: Tuple[int, ...]
+    dtype: Any
+    fill: float
+    time_axis: Optional[int]
 
-    Head-major is the layout the decode attention einsums consume; stored
-    token-major, XLA inserted a per-layer transpose copy of every slice
-    (~2x attention cost at [160 slots, 257 window] on v5e). The write
-    side no longer cares about layout: since the cache is read pre-write
-    (gqa_attention_decode), all L layers' fresh k/v land in ONE batched
-    scatter per step (_run_blocks_decode), not L per-layer scatters."""
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
+
+def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
+               dtype=None) -> Dict[str, CacheEntry]:
+    """THE description of the per-slot cache, by kind. init_cache builds
+    from it; the engine's admission scatter (cache_scatter_slots), the
+    HBM ledger and /metadata read it (cache_bytes); cost_model.py's
+    closed forms (kv_bytes_per_token, state_bytes_per_slot: no JAX there)
+    are held equal to it by tests/test_patterned.py.
+
+    KV is HEAD-major [La, B, Hkv, T, Dh] (scales [La, B, Hkv, T]) over
+    the La layers that hold KV: every layer of a homogeneous stack, the
+    attention layers of a patterned one. Head-major is the layout the
+    decode attention einsums consume; stored token-major, XLA inserted a
+    per-layer transpose copy of every slice (~2x attention cost at
+    [160 slots, 257 window] on v5e). The write side no longer cares
+    about layout: since the cache is read pre-write
+    (gqa_attention_decode), all layers' fresh k/v land in ONE batched
+    scatter per step (_run_blocks_decode), not L per-layer scatters.
+
+    The conv state is [Lc, B, conv_kernel - 1, D] over the Lc conv
+    layers of a patterned stack: the gated inputs u of the slot's last
+    conv_kernel - 1 positions, oldest first."""
+    shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads, max_len,
+             cfg.head_dim)
+    spec: Dict[str, CacheEntry] = {}
     if cfg.kv_cache_dtype == "int8":
         assert dtype is None, (
             "dtype override is meaningless for an int8 cache (slots are "
             "int8 + f32 scales by construction)"
         )
         sshape = shape[:-1]  # [L, B, Hkv, T]
-        return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            # Scales min-clamped at init so a read of a never-written slot
-            # dequantizes to exact zeros (0 * 1e-8), like the bf16 cache.
-            # bf16 storage: see _quantize_kv.
-            "k_scale": jnp.full(sshape, 1e-8, jnp.bfloat16),
-            "v_scale": jnp.full(sshape, 1e-8, jnp.bfloat16),
-        }
-    dt = dtype or _dtype(cfg)
-    return {"k": jnp.zeros(shape, dtype=dt), "v": jnp.zeros(shape, dtype=dt)}
+        spec["k"] = CacheEntry("kv", shape, jnp.int8, 0, 3)
+        spec["v"] = CacheEntry("kv", shape, jnp.int8, 0, 3)
+        # Scales min-clamped at init so a read of a never-written slot
+        # dequantizes to exact zeros (0 * 1e-8), like the bf16 cache.
+        # bf16 storage: see _quantize_kv.
+        spec["k_scale"] = CacheEntry("kv", sshape, jnp.bfloat16, 1e-8, 3)
+        spec["v_scale"] = CacheEntry("kv", sshape, jnp.bfloat16, 1e-8, 3)
+    else:
+        dt = dtype or _dtype(cfg)
+        spec["k"] = CacheEntry("kv", shape, dt, 0, 3)
+        spec["v"] = CacheEntry("kv", shape, dt, 0, 3)
+    if cfg.n_conv_layers:
+        spec["conv"] = CacheEntry(
+            "conv",
+            (cfg.n_conv_layers, batch, cfg.conv_kernel - 1, cfg.d_model),
+            dtype or _dtype(cfg), 0, None)
+    return spec
+
+
+def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None) -> Cache:
+    """The per-slot cache cache_spec describes, empty."""
+    return {
+        key: (jnp.zeros(e.shape, e.dtype) if e.fill == 0
+              else jnp.full(e.shape, e.fill, e.dtype))
+        for key, e in cache_spec(cfg, batch, max_len, dtype).items()
+    }
+
+
+def cache_bytes(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, int]:
+    """Bytes of the per-slot cache by kind ({"kv": ..., "conv": ...})."""
+    out: Dict[str, int] = {}
+    for e in cache_spec(cfg, batch, max_len).values():
+        n = jnp.dtype(e.dtype).itemsize
+        for d in e.shape:
+            n *= d
+        out[e.kind] = out.get(e.kind, 0) + n
+    return out
+
+
+def cache_scatter_slots(cfg: ModelConfig, cache: Cache, sub: Cache,
+                        slots: jnp.ndarray, width: int) -> Cache:
+    """Admission: the group's freshly prefilled cache `sub` ([*, G, ...],
+    `width` positions) into rows `slots` of the slab, array by array as
+    cache_spec describes them. Arrays with a token axis (k/v + scales:
+    head-major [L, B, Hkv, T, ...], T at dim 3 of k/v and trailing on
+    the scales, so one indexing expression covers them all) take the
+    first `width` positions; a fixed-size state (the conv state) is
+    overwritten whole, so a reused slot keeps nothing of its last
+    request."""
+    spec = cache_spec(cfg, 1, 1)
+    out = {}
+    for key, arr in cache.items():
+        upd = sub[key].astype(arr.dtype)
+        if spec[key].time_axis is None:
+            out[key] = arr.at[:, slots].set(upd)
+        else:
+            out[key] = arr.at[:, slots, :, :width].set(upd)
+    return out
 
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block: int) -> Cache:
@@ -754,6 +852,7 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block: int) -> Cache:
     block is identical to the slab, so a gather through the table
     reproduces the dense cache bit-for-bit (paged_gather_kv) and the
     attention math is shared with the dense path."""
+    refuse_patterned(cfg, "the paged KV pool")
     shape = (cfg.n_layers, num_blocks, cfg.n_kv_heads, block, cfg.head_dim)
     if cfg.kv_cache_dtype == "int8":
         sshape = shape[:-1]
@@ -920,6 +1019,7 @@ def paged_decode_step(
     (logits [B, V], updated pool) — the block-table twin of decode_step,
     bit-identical for greedy outputs. `tp` (tp_sharding.TpHints) runs
     the step SPMD over the 'tp' mesh axis, still bit-identical."""
+    refuse_patterned(cfg, "paged decode")
     x = _embed_rows(params, token, _dtype(cfg))[:, None, :]
     positions = pos[:, None]
     inv_freq = rope_frequencies(cfg)
@@ -946,6 +1046,10 @@ def prefill(
     decode cache stays T-unsharded, GSPMD gathers the shards at the
     cache write)."""
     B, S = tokens.shape
+    if cfg.patterned:
+        refuse_patterned(cfg, "tensor-parallel or ring prefill",
+                          tp is not None or ring_mesh is not None)
+        return _prefill_patterned(params, tokens, prompt_lens, cache, cfg)
     x = _embed_rows(params, tokens, _dtype(cfg))
     use_ring = ring_mesh is not None and cfg.attn_impl == "ring" and S > 1
     if use_ring:
@@ -1016,6 +1120,7 @@ def prefill_with_prefix(
     Returns (next-token logits [B, V] at each row's last real suffix
     token, fresh suffix KV {"k","v"} stacked [L, B, Hkv, Sq, Dh] bf16 —
     the caller scatters prefix and suffix into the slot cache)."""
+    refuse_patterned(cfg, "suffix prefill over a reused prefix")
     B, Sq = tokens.shape
     Pb = prefix_kv["k"].shape[3]
     x = _embed_rows(params, tokens, _dtype(cfg))
@@ -1046,11 +1151,435 @@ def decode_step(
     cache: Cache,
     cfg: ModelConfig,
     tp=None,
-) -> Tuple[jnp.ndarray, Cache]:
-    """One autoregressive step. Returns (logits [B, V], updated cache)."""
+    live: Optional[jnp.ndarray] = None,  # [B] bool: rows that decode
+    return_routing: bool = False,
+):
+    """One autoregressive step. Returns (logits [B, V], updated cache).
+
+    `live` tells a patterned stack's sparse layers which rows hold a
+    request: the others route to no expert, so a step reads the weights
+    of the experts its live rows select and no more (None: every row).
+    return_routing adds a third value, int32 [3]: sparse layers run,
+    distinct experts they read summed over those layers, (row, expert)
+    assignments (zeros for a stack without dispatch)."""
     x = _embed_rows(params, token, _dtype(cfg))[:, None, :]  # [B,1,D]
     positions = pos[:, None]
     inv_freq = rope_frequencies(cfg)
-    x, cache, _ = _run_blocks_decode(params, x, cfg, positions, inv_freq,
-                                     pos, cache, tp=tp)
-    return _logits(params, x, cfg)[:, 0], cache
+    routing = jnp.zeros((3,), jnp.int32)
+    if cfg.patterned:
+        refuse_patterned(cfg, "tensor-parallel decode", tp is not None)
+        x, cache, routing = _run_patterned_decode(
+            params, x, cfg, positions, inv_freq, pos, cache, live)
+    else:
+        x, cache, _ = _run_blocks_decode(params, x, cfg, positions,
+                                         inv_freq, pos, cache, tp=tp)
+    logits = _logits(params, x, cfg)[:, 0]
+    if return_routing:
+        return logits, cache, routing
+    return logits, cache
+
+
+# ---------------------------------------------------------------------------
+# The patterned stack (cfg.layer_types)
+# ---------------------------------------------------------------------------
+#
+# Layers differ in two ways: the operator (gated short convolution or
+# attention, cfg.layer_types) and the feed-forward (dense SwiGLU for the
+# first cfg.n_dense_layers, then the sparse block by token -> expert
+# dispatch). The layer list is cut into SEGMENTS, each a period of layer
+# kinds repeated R times (layer_plan), and each segment is ONE lax.scan
+# over its R repeats with the period unrolled inside the body: compile
+# time is flat in depth, as with the homogeneous scan. Parameters are
+# stored the way they are scanned, params["segments"][s][j] = the stacked
+# [R, ...] weights of position j of segment s's period, so that no
+# expert matrix is ever sliced or copied inside a program. The cache is
+# by kind (cache_spec): "k"/"v" over the attention layers in layer order,
+# "conv" over the conv layers in layer order.
+
+
+class Segment(NamedTuple):
+    kinds: Tuple[Tuple[str, bool], ...]  # (operator, sparse ff) per position
+    reps: int
+    first_layer: int
+    attn_start: int  # index of its first attention layer among those
+    conv_start: int  # and of its first conv layer
+
+
+_MAX_PERIOD = 8
+
+
+@functools.lru_cache(maxsize=None)
+def layer_plan(cfg: ModelConfig) -> Tuple[Segment, ...]:
+    """The layer list as (period, repeats) segments, greedily from the
+    front: at each layer the period (up to _MAX_PERIOD kinds) that
+    repeats at least twice and covers most layers, else one layer."""
+    kinds = [(cfg.op_kind(l), cfg.ff_sparse(l)) for l in range(cfg.n_layers)]
+    plan, i, n_attn, n_conv = [], 0, 0, 0
+    while i < len(kinds):
+        best_p, best_r = 1, 1
+        for p in range(1, min(_MAX_PERIOD, len(kinds) - i) + 1):
+            r = 1
+            while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
+                r += 1
+            if r >= 2 and p * r > best_p * best_r:
+                best_p, best_r = p, r
+        period = tuple(kinds[i:i + best_p])
+        plan.append(Segment(period, best_r, i, n_attn, n_conv))
+        n_attn += best_r * sum(1 for op, _ in period if op == OP_ATTN)
+        n_conv += best_r * sum(1 for op, _ in period if op == OP_CONV)
+        i += best_p * best_r
+    return tuple(plan)
+
+
+def refuse_patterned(cfg: ModelConfig, what: str, when: bool = True):
+    if cfg.patterned and when:
+        raise NotImplementedError(
+            f"{what} does not know the patterned stack (layer_types): it "
+            f"would run without the conv layers' state")
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _seeded(key, shape, scale, dtype):
+    """One weight, made in one fused program (the float32 draws of a
+    stacked expert matrix are never held whole beside it)."""
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Seeded weights of a patterned stack, leaf by leaf (each its own
+    small program, so building the tree never needs more than the tree
+    plus one leaf). Matrices are drawn at fan_in ** -0.5 (0.022 at
+    d_model 2048, the usual 0.02; at a test's 64 it keeps the layers'
+    share of the residual stream what it is at real width, where a fixed
+    0.02 would leave a tied head reading little but the input token's
+    own embedding), projections back into the residual stream damped by
+    (2 L) ** -0.5. Layer norms are ones; the router's bias is drawn
+    non-zero (a zero bias would leave 'select with, weight without'
+    unexercised) but small beside the scores' spread, so that routing
+    stays near uniform as a trained, load-balanced router's is."""
+    dt = _dtype(cfg)
+    L, D, F, V = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size
+    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    E, Fe, Kc = cfg.n_experts, cfg.expert_width, cfg.conv_kernel
+    damp = (2 * L) ** -0.5  # residual-stream init damping
+    count = [0]
+
+    def dense(*shape, scale=None, dtype=dt):
+        count[0] += 1
+        if scale is None:
+            scale = shape[-2] ** -0.5  # [..., fan_in, fan_out]
+        return _seeded(jax.random.fold_in(key, count[0]), tuple(shape),
+                       float(scale), jnp.dtype(dtype))
+
+    def ones(*shape):
+        return jnp.ones(shape, jnp.float32)
+
+    segments = []
+    for seg in layer_plan(cfg):
+        R, period = seg.reps, []
+        for op, sparse in seg.kinds:
+            lp = {"op_norm": ones(R, D), "ff_norm": ones(R, D)}
+            if op == OP_ATTN:
+                lp.update(
+                    wq=dense(R, D, H * Dh), wk=dense(R, D, Hkv * Dh),
+                    wv=dense(R, D, Hkv * Dh),
+                    wo=dense(R, H * Dh, D, scale=damp * (H * Dh) ** -0.5))
+                if cfg.qk_norm:
+                    lp.update(q_norm=ones(R, Dh), k_norm=ones(R, Dh))
+            else:
+                lp.update(
+                    conv_in=dense(R, D, 3 * D),
+                    conv_w=dense(R, Kc, D, scale=Kc ** -0.5),
+                    conv_out=dense(R, D, D, scale=damp * D ** -0.5))
+            if sparse:
+                lp.update(
+                    router=dense(R, D, E, dtype=jnp.float32),
+                    w_gate=dense(R, E, D, Fe), w_up=dense(R, E, D, Fe),
+                    w_down=dense(R, E, Fe, D, scale=damp * Fe ** -0.5))
+                if cfg.router_bias:
+                    lp["router_bias"] = dense(R, E, scale=0.05,
+                                              dtype=jnp.float32)
+            else:
+                lp.update(
+                    w_gate=dense(R, D, F), w_up=dense(R, D, F),
+                    w_down=dense(R, F, D, scale=damp * F ** -0.5))
+            period.append(lp)
+        segments.append(tuple(period))
+    params: Params = {
+        "embed": dense(V, D, scale=D ** -0.5),
+        "segments": tuple(segments),
+        "final_norm": ones(D),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(D, V)
+    return params
+
+
+def layer_params(params: Params, cfg: ModelConfig, layer: int) -> Dict:
+    """One layer's weights out of a patterned tree (loaders, tests)."""
+    for seg, sp in zip(layer_plan(cfg), params["segments"]):
+        p = len(seg.kinds)
+        if seg.first_layer <= layer < seg.first_layer + p * seg.reps:
+            r, j = divmod(layer - seg.first_layer, p)
+            return {k: v[r] for k, v in sp[j].items()}
+    raise IndexError(layer)
+
+
+def _conv_mix(u_hist, w):
+    """Depthwise causal convolution: u_hist [B, S + Kc - 1, D] (the Kc - 1
+    inputs before the first output position in front), w [Kc, D] ->
+    [B, S, D] with c_t = sum_j w[j] * u_{t - (Kc - 1) + j}."""
+    Kc = w.shape[0]
+    S = u_hist.shape[1] - (Kc - 1)
+    uf = u_hist.astype(jnp.float32)
+    return sum(w[j].astype(jnp.float32) * uf[:, j:j + S] for j in range(Kc))
+
+
+def _conv_op(h, lp, cfg, state=None, plens=None):
+    """Gated short convolution on normed input h [B, S, D].
+
+    state [B, Kc - 1, D] (decode: the slot's last inputs) or None
+    (a sequence from position 0: zeros before it). Returns (y [B, S, D],
+    new state): with `plens` the state after each row's OWN last real
+    token (right-padded prefill), else after the last position."""
+    B, S, D = h.shape
+    Kc = cfg.conv_kernel
+    with jax.named_scope("conv/in_proj"):
+        bcx = _qdot(h, lp, "conv_in", cfg)
+        b_gate, c_gate, xin = jnp.split(bcx, 3, axis=-1)
+    with jax.named_scope("conv/mix"):
+        u = b_gate * xin
+        if state is None:
+            state = jnp.zeros((B, Kc - 1, D), u.dtype)
+        hist = jnp.concatenate([state.astype(u.dtype), u], axis=1)
+        mixed = (c_gate.astype(jnp.float32)
+                 * _conv_mix(hist, lp["conv_w"])).astype(h.dtype)
+        if plens is None:
+            new_state = hist[:, S:]
+        else:
+            # hist index of position t is t + Kc - 1: the Kc - 1 inputs
+            # that end at position plen - 1 start at hist index plen.
+            idx = plens[:, None] + jnp.arange(Kc - 1)[None, :]
+            new_state = jnp.take_along_axis(hist, idx[:, :, None], axis=1)
+    with jax.named_scope("conv/out_proj"):
+        y = _qdot(mixed, lp, "conv_out", cfg)
+    return y, new_state
+
+
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def _split_experts(period, cfg):
+    """A segment's stacked weights as (what the scan slices per repeat,
+    what the expert kernel is handed whole): position j's expert
+    matrices [R, E, ...] with the repeat and expert axes merged
+    [R * E, ...], which moves nothing. The kernel picks its repeat's E
+    groups by index (ops/moe_dispatch.dispatch_experts: a slice of the
+    stack as a custom call's operand would copy every expert's weights
+    on every step)."""
+    sliced, whole = [], []
+    for lp in period:
+        sparse = "router" in lp
+        sliced.append({k: v for k, v in lp.items()
+                       if not (sparse and k in _EXPERT_STACKS)})
+        whole.append({
+            k: _w(lp, k, _dtype(cfg)).reshape((-1,) + lp[k].shape[2:])
+            for k in _EXPERT_STACKS} if sparse else None)
+    return tuple(sliced), whole
+
+
+def _sparse_ff(h, lp, experts, rep, cfg, live):
+    """The sparse feed-forward by token -> expert dispatch
+    (ops/moe_dispatch.py). h [B, S, D], live [B, S] bool or None;
+    `experts` the segment position's merged expert stacks, `rep` which
+    repeat of the segment this layer is."""
+    B, S, D = h.shape
+    x = h.reshape(B * S, D)
+    with jax.named_scope("moe/router"):
+        top_idx, top_w = moe_dispatch.route(
+            x, lp["router"], lp.get("router_bias"),
+            top_k=cfg.n_experts_per_token, router=cfg.router,
+            norm_topk=cfg.router_norm_topk, scale=cfg.router_scale)
+    out, stats = moe_dispatch.dispatch_experts(
+        x, top_idx, top_w, experts["w_gate"], experts["w_up"],
+        experts["w_down"], None if live is None else live.reshape(B * S),
+        n_experts=cfg.n_experts, layer=rep)
+    return out.reshape(B, S, D), stats
+
+
+def _ff_res(x, lp, experts, rep, cfg, live):
+    """x + FF(RMSNorm(x)); also the routing counters [layers, touched,
+    assignments] of this layer (zeros for a dense one)."""
+    h = rms_norm(x, lp["ff_norm"], cfg.rms_norm_eps)
+    if experts is not None:
+        out, st = _sparse_ff(h, lp, experts, rep, cfg, live)
+        routing = jnp.stack([jnp.ones((), jnp.int32), st["touched"],
+                             st["assignments"]])
+        return x + out, routing
+    with jax.named_scope("mlp"):
+        hidden = jax.nn.silu(_qdot(h, lp, "w_gate", cfg)) \
+            * _qdot(h, lp, "w_up", cfg)
+        return x + _qdot(hidden, lp, "w_down", cfg), \
+            jnp.zeros((3,), jnp.int32)
+
+
+def _segment_cache(cache, seg: Segment):
+    """The slices of the by-kind cache a segment's scan rides on:
+    {"k","v"} [R, na, ...] and "conv" [R, nc, ...] (absent kinds left
+    out). A segment that owns every layer of a kind reshapes and copies
+    nothing."""
+    out = {}
+    na = sum(1 for op, _ in seg.kinds if op == OP_ATTN)
+    nc = len(seg.kinds) - na
+    for key, arr in cache.items():
+        n, start = (nc, seg.conv_start) if key == "conv" else \
+            (na, seg.attn_start)
+        if not n:
+            continue
+        part = arr if arr.shape[0] == n * seg.reps else \
+            arr[start:start + n * seg.reps]
+        out[key] = part.reshape((seg.reps, n) + arr.shape[1:])
+    return out
+
+
+def _unsegment(parts):
+    """Per-segment scan outputs [R, n, ...] back to [layers of kind, ...]."""
+    flat = [p.reshape((-1,) + p.shape[2:]) for p in parts]
+    return flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=0)
+
+
+def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
+    """Every layer over whole sequences from position 0 (forward,
+    prefill). Returns (x, fresh cache arrays by kind or {} when plens is
+    None, routing [3]): k/v [La, B, Hkv, S, Dh] in cache layout, conv
+    [Lc, B, Kc - 1, D] taken at each row's own prompt length. Positions
+    at or past a row's plens are not live: they route to no expert."""
+    S = x.shape[1]
+    live = None if plens is None else \
+        jnp.arange(S)[None, :] < plens[:, None]
+    fresh = {"k": [], "v": [], "conv": []}
+    routing = jnp.zeros((3,), jnp.int32)
+    for seg, sp in zip(layer_plan(cfg), params["segments"]):
+        sliced, experts = _split_experts(sp, cfg)
+
+        def body(carry, xs, seg=seg, experts=experts):
+            x, routing = carry
+            rep, lps = xs
+            ks, vs, cs = [], [], []
+            for lp, ex, (op, _) in zip(lps, experts, seg.kinds):
+                h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
+                if op == OP_ATTN:
+                    q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
+                    attn = gqa_attention(q, k, v, mask)
+                    with jax.named_scope("attn/out"):
+                        x = x + _qdot(attn, lp, "wo", cfg)
+                    ks.append(k.transpose(0, 2, 1, 3))
+                    vs.append(v.transpose(0, 2, 1, 3))
+                else:
+                    y, st = _conv_op(h, lp, cfg, plens=plens)
+                    x = x + y
+                    cs.append(st)
+                x, r = _ff_res(x, lp, ex, rep, cfg, live)
+                routing = routing + r
+            ys = {}
+            if plens is not None:
+                if ks:
+                    ys["k"], ys["v"] = jnp.stack(ks), jnp.stack(vs)
+                if cs:
+                    ys["conv"] = jnp.stack(cs)
+            return (x, routing), ys
+
+        (x, routing), ys = jax.lax.scan(
+            body, (x, routing), (jnp.arange(seg.reps), sliced))
+        for key, val in ys.items():
+            fresh[key].append(val)
+    return x, {k: _unsegment(v) for k, v in fresh.items() if v}, routing
+
+
+def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
+                          live):
+    """One decode step through every layer. KV is read PRE-write and all
+    attention layers' fresh k/v land after the scans in one scatter
+    (_run_blocks_decode's discipline); the conv state is small and is
+    replaced whole. Rows that are not live still shift their conv state
+    and scribble KV at their frozen position: harmless, an admission
+    overwrites both before the slot is read again."""
+    Smax = cache["k"].shape[3]
+    mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
+    live2 = None if live is None else live[:, None]
+    fresh = {"k": [], "v": [], "conv": []}
+    routing = jnp.zeros((3,), jnp.int32)
+    dt = cache["k"].dtype
+    for seg, sp in zip(layer_plan(cfg), params["segments"]):
+        sliced, experts = _split_experts(sp, cfg)
+
+        def body(carry, xs, seg=seg, experts=experts):
+            x, routing = carry
+            rep, lps, cl = xs
+            ia = ic = 0
+            ks, vs, cs = [], [], []
+            for lp, ex, (op, _) in zip(lps, experts, seg.kinds):
+                h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
+                if op == OP_ATTN:
+                    q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
+                    attn = gqa_attention_decode(
+                        q, cl["k"][ia], cl["v"][ia], k, v, mask_lt)
+                    with jax.named_scope("attn/out"):
+                        x = x + _qdot(attn, lp, "wo", cfg)
+                    ks.append(k[:, 0].astype(dt))
+                    vs.append(v[:, 0].astype(dt))
+                    ia += 1
+                else:
+                    y, st = _conv_op(h, lp, cfg, state=cl["conv"][ic])
+                    x = x + y
+                    cs.append(st.astype(cl["conv"].dtype))
+                    ic += 1
+                x, r = _ff_res(x, lp, ex, rep, cfg, live2)
+                routing = routing + r
+            ys = {}
+            if ks:
+                ys["k"], ys["v"] = jnp.stack(ks), jnp.stack(vs)
+            if cs:
+                ys["conv"] = jnp.stack(cs)
+            return (x, routing), ys
+
+        (x, routing), ys = jax.lax.scan(
+            body, (x, routing),
+            (jnp.arange(seg.reps), sliced, _segment_cache(cache, seg)))
+        for key, val in ys.items():
+            fresh[key].append(val)
+    rows = jnp.arange(pos.shape[0])
+    new_cache = dict(cache)
+    with jax.named_scope("attn/cache_update"):
+        for key in ("k", "v"):
+            if fresh[key]:
+                new_cache[key] = cache[key].at[:, rows, :, pos].set(
+                    jnp.swapaxes(_unsegment(fresh[key]), 0, 1),
+                    unique_indices=True)
+    if fresh["conv"]:
+        new_cache["conv"] = _unsegment(fresh["conv"])
+    return x, new_cache, routing
+
+
+def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):
+    """prefill() for a patterned stack: KV of the attention layers into
+    positions [0, S), each conv layer's state at the row's own prompt
+    length (rows of one admission group are right-padded to the bucket:
+    the state at the bucket's end would be the padding's)."""
+    B, S = tokens.shape
+    x = _embed_rows(params, tokens, _dtype(cfg))
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
+    x, fresh, _ = _run_patterned_full(
+        params, x, cfg, positions, rope_frequencies(cfg), mask, prompt_lens)
+    new_cache = dict(cache)
+    with jax.named_scope("attn/cache_update"):
+        for key, val in fresh.items():
+            val = val.astype(cache[key].dtype)
+            if key == "conv" or S == cache[key].shape[3]:
+                new_cache[key] = val
+            else:
+                new_cache[key] = cache[key].at[:, :, :, :S].set(val)
+    last = jnp.clip(prompt_lens - 1, 0, S - 1)
+    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+    return _logits(params, x_last, cfg)[:, 0], new_cache

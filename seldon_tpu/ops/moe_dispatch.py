@@ -1,0 +1,191 @@
+"""Token -> expert dispatch: the sparse feed-forward block computed for
+the experts that hold tokens, not for every expert.
+
+`moe_block` (models/transformer.py) runs every expert on every token and
+mixes with a sparsified weight matrix: E/k times the FLOPs that are
+needed and, in decode, a read of every expert's weights whatever the
+rows route to. Here each (token, selected expert) pair is one row of a
+flat assignment list, sorted by expert; the three expert products are
+GROUPED matmuls over that list (rows of group e times expert e's
+matrix), so FLOPs are tokens x k, not tokens x E, and a group with no
+rows costs neither FLOPs nor a read of its weights. Shapes are static:
+the list always has tokens x k rows; rows that are not live (dead slab
+slots in decode, right-padding in prefill) are routed to no expert: they
+sort behind the last group, no group covers them, and they come back as
+zeros.
+
+Two routers (`route`): "softmax" is Mixtral's (top-k of the logits,
+softmax over those k); "sigmoid" scores each expert by sigmoid(logit),
+SELECTS on score + bias, WEIGHTS by the unbiased score, optionally
+renormalised over the k, times a scale.
+
+`grouped_matmul` is the one kernel. On a TPU it is the Pallas megablox
+grouped matmul (jax.experimental.pallas.ops.tpu.megablox.gmm, which a
+device trace shows under GROUPED_MATMUL_NAME); elsewhere
+`jax.lax.ragged_dot`. PERF.md §5 has the chip readings of both that the
+choice was made from (tools/probe_moe_dispatch.py takes them).
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+# What the expert products are called in a device trace on a TPU: the
+# name of megablox's pallas_call, which XLA keeps as the instruction's
+# name ("%gmm.3 = ... custom-call(...)"; benchmark/layer_metrics reads
+# it). Checked on the chip with benchmark/xplane.py (PERF.md §5).
+GROUPED_MATMUL_NAME = "gmm"
+
+# megablox tiles (m, k, n), from readings on a v5e at E=64, D=2048,
+# F=1536, top-4 (tools/probe_moe_dispatch.py; PERF.md §5). k is whole:
+# with one k-step the weight tile of a group stays put while the grid
+# walks that group's m-tiles, so an expert's weights stream from HBM
+# once per call however many rows it holds (k 1024 cost 1.6x at 8192
+# tokens). m is rows of the assignment list: 128 where a few rows an
+# expert is the rule (decode: one touched expert costs one m-tile of MXU
+# work, which the weight read hides), 256 for prefill-sized lists (512
+# was slower at 1024 and at 8192 tokens). n is sized so that one grid
+# step streams a weight tile of ~2 MB.
+_GMM_TILE_M_SMALL = 128
+_GMM_TILE_M_LARGE = 256
+_GMM_LARGE_ROWS = 1024
+_GMM_TILE_K = 2048
+_GMM_TILE_N = 512
+
+
+def route(
+    x: jnp.ndarray,  # [N, D]
+    router_w: jnp.ndarray,  # [D, E] float32
+    bias: Optional[jnp.ndarray],  # [E] float32 or None
+    *,
+    top_k: int,
+    router: str,
+    norm_topk: bool = True,
+    scale: float = 1.0,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(expert ids [N, k] int32, weights [N, k] float32)."""
+    logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32), router_w)
+    if router == "softmax":
+        top_vals, top_idx = jax.lax.top_k(logits, top_k)
+        return top_idx, jax.nn.softmax(top_vals, axis=-1)
+    scores = jax.nn.sigmoid(logits)
+    select = scores if bias is None else scores + bias[None, :]
+    _, top_idx = jax.lax.top_k(select, top_k)
+    w = jnp.take_along_axis(scores, top_idx, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return top_idx, w * scale
+
+
+def _gmm_tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    def fit(dim, tile):
+        # the largest multiple of 128 that divides dim and is <= tile,
+        # else the whole dim (megablox masks an irregular k remainder,
+        # but not an n remainder)
+        for t in range(min(tile, dim) // 128 * 128, 0, -128):
+            if dim % t == 0:
+                return t
+        return dim
+    tm = _GMM_TILE_M_LARGE if m >= _GMM_LARGE_ROWS else _GMM_TILE_M_SMALL
+    return min(tm, m), fit(k, _GMM_TILE_K), fit(n, _GMM_TILE_N)
+
+
+def _ragged_dot(lhs, rhs, group_sizes):
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32))
+
+
+def _megablox(lhs, rhs, group_sizes):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    m = lhs.shape[0]
+    tm = _gmm_tiles(m, rhs.shape[1], rhs.shape[2])[0]
+    pad = (-m) % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    # The rows no expert owns form one more group, which has no weights:
+    # gmm is told rhs holds the first E of E + 1 groups and skips it.
+    rest = (m + pad) - jnp.sum(group_sizes)
+    sizes = jnp.concatenate(
+        [group_sizes.astype(jnp.int32), rest[None].astype(jnp.int32)])
+    out = gmm(
+        lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+        tiling=_gmm_tiles(m + pad, rhs.shape[1], rhs.shape[2]),
+        group_offset=jnp.zeros((), jnp.int32),
+    )
+    return out[:m] if pad else out
+
+
+def grouped_matmul(
+    lhs: jnp.ndarray,  # [M, K] rows sorted by group
+    rhs: jnp.ndarray,  # [E, K, N]
+    group_sizes: jnp.ndarray,  # [E] int32, sum <= M
+) -> jnp.ndarray:
+    """out[r] = lhs[r] @ rhs[group of r]; rows past sum(group_sizes)
+    belong to no group (callers mask them: their value is unspecified)."""
+    if jax.default_backend() == "tpu":
+        return _megablox(lhs, rhs, group_sizes)
+    return _ragged_dot(lhs, rhs, group_sizes)
+
+
+def dispatch_experts(
+    x: jnp.ndarray,  # [N, D]
+    top_idx: jnp.ndarray,  # [N, k] int32
+    top_w: jnp.ndarray,  # [N, k] float32
+    w_gate: jnp.ndarray,  # [L * E, D, F]
+    w_up: jnp.ndarray,  # [L * E, D, F]
+    w_down: jnp.ndarray,  # [L * E, F, D]
+    live: Optional[jnp.ndarray] = None,  # [N] bool; None = every row
+    *,
+    n_experts: int,
+    layer: Optional[jnp.ndarray] = None,  # int32 scalar in [0, L)
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """sum_j top_w[:, j] * SwiGLU_{top_idx[:, j]}(x) for live rows, zeros
+    for the others. Also what routing did: `touched` (experts that hold
+    at least one live row) and `assignments` (live rows x k).
+
+    The weights may be those of L stacked layers with the layer and
+    expert axes merged ([L * E, ...], a reshape that moves nothing) and
+    `layer` saying which E of them this call uses: the groups of the
+    other layers are empty, so the kernel never touches them. That is
+    how a scan over layers hands the kernel its layer: a slice of the
+    stack would be a copy of every expert's weights each step, because a
+    custom call reads operands that exist in memory."""
+    N, D = x.shape
+    K = top_idx.shape[1]
+    E = n_experts
+    A = N * K
+    with jax.named_scope("moe/dispatch"):
+        eids = top_idx.reshape(A).astype(jnp.int32)
+        if live is not None:
+            # expert id E = nowhere: sorts behind every real group
+            eids = jnp.where(jnp.repeat(live, K), eids, E)
+        order = jnp.argsort(eids, stable=True)  # [A] assignment ids
+        group_sizes = jnp.sum(
+            eids[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32)  # [E]
+        n_routed = jnp.sum(group_sizes)
+        xs = jnp.take(x, order // K, axis=0)  # [A, D] sorted by expert
+        sizes = group_sizes
+        if w_gate.shape[0] != E:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((w_gate.shape[0],), jnp.int32), group_sizes,
+                (layer.astype(jnp.int32) * E,))
+    with jax.named_scope("moe/experts"):
+        hidden = jax.nn.silu(grouped_matmul(xs, w_gate, sizes)) \
+            * grouped_matmul(xs, w_up, sizes)
+        ys = grouped_matmul(hidden, w_down, sizes)  # [A, D]
+    with jax.named_scope("moe/combine"):
+        ys = jnp.where((jnp.arange(A) < n_routed)[:, None], ys, 0)
+        # back to (token, j) order: position of assignment a in `order`
+        inv = jnp.zeros((A,), jnp.int32).at[order].set(
+            jnp.arange(A, dtype=jnp.int32), unique_indices=True)
+        y = jnp.take(ys, inv, axis=0).reshape(N, K, D)
+        out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), top_w)
+    stats = {
+        "touched": jnp.sum(group_sizes > 0, dtype=jnp.int32),
+        "assignments": n_routed.astype(jnp.int32),
+    }
+    return out.astype(x.dtype), stats

@@ -154,6 +154,29 @@ def _kvbytes(cfg) -> int:
     return _DTYPE_BYTES.get(getattr(cfg, "kv_cache_dtype", "bf16"), 2)
 
 
+def _kv_layers(cfg) -> int:
+    """Layers that hold KV: all of a homogeneous stack, the attention
+    layers of a patterned one (ModelConfig.n_attn_layers)."""
+    return getattr(cfg, "n_attn_layers", cfg.n_layers)
+
+
+def _patterned_layer_params(cfg, experts_per_layer: int) -> int:
+    """Matmul weights of a WHOLE patterned stack (per-layer kinds differ,
+    so there is no per-layer figure): attention layers' qkv + o, conv
+    layers' in/out projections, leading dense SwiGLUs, and
+    `experts_per_layer` experts of width d_ff_expert in each sparse
+    layer (k for what a token multiplies through, E for what a batched
+    wave may read)."""
+    d, hd = cfg.d_model, cfg.d_model // cfg.n_heads
+    attn = d * (cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd) + d * d
+    conv = d * 3 * d + d * d
+    sparse = cfg.n_sparse_layers
+    dense = cfg.n_layers - sparse
+    return (cfg.n_attn_layers * attn + cfg.n_conv_layers * conv
+            + dense * 3 * d * cfg.d_ff
+            + sparse * experts_per_layer * 3 * d * cfg.expert_width)
+
+
 def matmul_params_per_layer(cfg, tp: int = 1) -> int:
     """Matmul weights one token multiplies through PER CHIP per layer:
     fused qkv + o projections and the SwiGLU triple (per-token active
@@ -163,7 +186,12 @@ def matmul_params_per_layer(cfg, tp: int = 1) -> int:
     qkv and gate/up shard their output dim over tp chips, while o and
     down — whose contraction would need a psum — stay replicated and
     run redundantly everywhere. MoE expert weights replicate entirely
-    (attention-only sharding), so only the qkv term divides."""
+    (attention-only sharding), so only the qkv term divides.
+
+    A patterned stack (tp = 1 only) reports its mean layer."""
+    if getattr(cfg, "patterned", False):
+        return _patterned_layer_params(
+            cfg, cfg.n_experts_per_token) // cfg.n_layers
     hd = cfg.d_model // cfg.n_heads
     qkv = cfg.d_model * (cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd)
     o = cfg.d_model * cfg.d_model
@@ -189,7 +217,7 @@ def attn_flops(cfg, q_tokens: int, kv_len: int, tp: int = 1) -> int:
     QK^T and PV are 2 flops per (head, dim, position) each, and GQA
     shares K/V without shrinking the query side: 4 * d_model * q * kv
     per layer. Heads shard on 'tp', so per-chip attention divides."""
-    return 4 * cfg.d_model * q_tokens * kv_len * cfg.n_layers // tp
+    return 4 * cfg.d_model * q_tokens * kv_len * _kv_layers(cfg) // tp
 
 
 def causal_attn_flops(cfg, s_tokens: int, prior: int = 0,
@@ -198,7 +226,7 @@ def causal_attn_flops(cfg, s_tokens: int, prior: int = 0,
     attends prior + i + 1 positions — the arithmetic-series sum of
     attn_flops."""
     total_kv = s_tokens * prior + s_tokens * (s_tokens + 1) // 2
-    return 4 * cfg.d_model * total_kv * cfg.n_layers // tp
+    return 4 * cfg.d_model * total_kv * _kv_layers(cfg) // tp
 
 
 def weight_bytes(cfg, tp: int = 1) -> int:
@@ -208,6 +236,14 @@ def weight_bytes(cfg, tp: int = 1) -> int:
     unquantized, models/quantize.py). The exact-TP split shards only
     qkv + gate/up; o / down / embeddings / lm_head are read whole on
     every chip."""
+    emb = cfg.vocab_size * cfg.d_model * 2          # bf16 embedding
+    head = cfg.d_model * cfg.vocab_size * 2         # bf16 lm_head
+    if getattr(cfg, "patterned", False):
+        # Every expert: the dispatch reads the experts live rows route
+        # to, which a wave of many rows makes nearly all of them.
+        tied = getattr(cfg, "tie_embeddings", False)
+        return (_patterned_layer_params(cfg, cfg.n_experts) * _wbytes(cfg)
+                + emb + (0 if tied else head))
     hd = cfg.d_model // cfg.n_heads
     qkv = cfg.d_model * (cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd)
     o = cfg.d_model * cfg.d_model
@@ -216,8 +252,6 @@ def weight_bytes(cfg, tp: int = 1) -> int:
     else:
         mlp = (2 * cfg.d_model * cfg.d_ff) // tp + cfg.d_model * cfg.d_ff
     per_layer = qkv // tp + o + mlp
-    emb = cfg.vocab_size * cfg.d_model * 2          # bf16 embedding
-    head = cfg.d_model * cfg.vocab_size * 2         # bf16 lm_head
     return cfg.n_layers * per_layer * _wbytes(cfg) + emb + head
 
 
@@ -226,7 +260,16 @@ def kv_bytes_per_token(cfg, tp: int = 1) -> int:
     PER CHIP: K + V at the kv dtype, GQA heads only — the cache shards
     exactly on its head axis, so tp divides cleanly."""
     hd = cfg.d_model // cfg.n_heads
-    return 2 * cfg.n_layers * cfg.n_kv_heads * hd * _kvbytes(cfg) // tp
+    return 2 * _kv_layers(cfg) * cfg.n_kv_heads * hd * _kvbytes(cfg) // tp
+
+
+def state_bytes_per_slot(cfg) -> int:
+    """Bytes of fixed-size per-slot state a decode step reads and writes
+    whatever the context: a patterned stack's conv state (conv_kernel - 1
+    inputs of d_model per conv layer, bf16), 0 otherwise. With
+    kv_bytes_per_token this is models/transformer.cache_spec, per kind."""
+    n_conv = getattr(cfg, "n_conv_layers", 0)
+    return n_conv * (cfg.conv_kernel - 1) * cfg.d_model * 2 if n_conv else 0
 
 
 # -- per-key closed forms ---------------------------------------------------
@@ -265,7 +308,8 @@ def cost_of_key(key: Key, cfg, *, max_slots: int, max_seq_len: int,
         # (tag, Sb, G): G rows prefill Sb tokens, causal attention.
         sb, g = key[1], key[2]
         flops = g * (sb * fpt + causal_attn_flops(cfg, sb, tp=tp))
-        return float(flops), float(wb + g * sb * kvpt)
+        return float(flops), float(wb + g * sb * kvpt
+                                   + g * state_bytes_per_slot(cfg))
     if fam == "admit-prefix":
         # (tag, Pb, Sb, G): suffix Sb computed over a Pb-token prefix
         # already resident in the cache.
@@ -288,7 +332,9 @@ def cost_of_key(key: Key, cfg, *, max_slots: int, max_seq_len: int,
         # re-reads the weights and the full cache window.
         n = key[1]
         flops = n * B * (fpt + attn_flops(cfg, 1, W, tp=tp) // 1)
-        bytes_ = n * (wb + B * W * kvpt + B * kvpt)
+        # + the fixed-size per-slot state (conv), read and written
+        bytes_ = n * (wb + B * W * kvpt + B * kvpt
+                      + 2 * B * state_bytes_per_slot(cfg))
         return float(flops), float(bytes_)
     if fam == "ragged":
         # (tag, C): ONE fused wave priced at its static capacity

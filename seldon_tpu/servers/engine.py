@@ -599,6 +599,11 @@ class _DepthEstimator:
         }
 
 
+# EngineStats' routing counters, in the order a decode chunk returns them.
+MOE_COUNTERS = ("moe_sparse_layer_steps", "moe_experts_touched",
+                "moe_assignments")
+
+
 class EngineStats:
     def __init__(self):
         # Guards every mutable counter below. The scheduler thread, the
@@ -619,6 +624,14 @@ class EngineStats:
         # length, the knob the occupancy policy is turning.
         self.decode_dispatches = 0  # graftlint: guarded-by(lock) via(stats)
         self.decode_steps = 0  # graftlint: guarded-by(lock) via(stats)
+        # What routing did in decode (models that dispatch tokens to
+        # experts; _note_routing): sparse layers run over all decode
+        # steps, distinct experts those layers read for live rows
+        # (summed), (row, expert) assignments. touched / layer-steps is
+        # the mean number of experts a sparse layer reads per step.
+        self.moe_sparse_layer_steps = 0  # graftlint: guarded-by(lock) via(stats)
+        self.moe_experts_touched = 0  # graftlint: guarded-by(lock) via(stats)
+        self.moe_assignments = 0  # graftlint: guarded-by(lock) via(stats)
         # Prefix-cache observability: admissions that reused cached KV,
         # prompt tokens whose prefill was skipped, and trie nodes evicted
         # under the byte budget.
@@ -823,6 +836,7 @@ class EngineStats:
                 ),
                 "decode_dispatches": self.decode_dispatches,
                 "decode_steps": self.decode_steps,
+                **{name: getattr(self, name) for name in MOE_COUNTERS},
                 "prefix_hits": self.prefix_hits,
                 "prefix_tokens_saved": self.prefix_tokens_saved,
                 "prefix_evictions": self.prefix_evictions,
@@ -933,6 +947,7 @@ class InferenceEngine:
         # constraints through every jitted impl below; tp=1 leaves
         # self._tp None and every partial without the kwarg.
         self._tp = None
+        self._refuse_unpatterned_paths()
         if self.ecfg.tp > 1:
             tp_sharding.validate(self.cfg, self.ecfg.tp)
             if self.cfg.attn_impl in ("flash", "ring"):
@@ -1415,6 +1430,9 @@ class InferenceEngine:
                 self._hbm.gauge("kv_cache", self._hbm_kv_reserved_bytes)
                 self._hbm.gauge("kv_live", self._hbm_kv_live_bytes)
                 self._hbm.gauge("prefix_cache", self._hbm_prefix_bytes)
+                if self.cfg.n_conv_layers:
+                    self._hbm.set_static(
+                        "conv_state", self.cache_bytes()["conv"])
             else:
                 # Per-device accounting on the mesh: weights are priced
                 # from each leaf's committed shard shape (replicated
@@ -1467,6 +1485,39 @@ class InferenceEngine:
         # order-asserting proxy, so this must stay the LAST piece of
         # engine state __init__ builds.
         self._san = graftsan.instrument(self)
+
+    def _refuse_unpatterned_paths(self) -> None:
+        """A patterned stack (cfg.layer_types: conv state beside KV)
+        runs on the default path: dense slab, tp = 1. Every opt-in path
+        moves, shares or replays KV by token position and knows no
+        fixed-size state, so it would serve the conv layers a state
+        that is stale, another request's or absent. Refuse each by name
+        here, at construction, not with wrong tokens later."""
+        if not self.cfg.patterned:
+            return
+        e = self.ecfg
+        asked = [
+            name for name, on in (
+                ("paged_kv (the block pool holds KV only)", e.paged_kv),
+                ("prefix_cache (a reused prefix carries no conv state)",
+                 e.prefix_cache),
+                ("chunked_prefill (a chunk would have to resume the conv "
+                 "state)", e.chunked_prefill),
+                ("ragged (the fused wave is paged)", e.ragged),
+                ("spec_decode (a rejected draft cannot rewind the conv "
+                 "state)", e.spec_decode),
+                ("heal (replay re-admits by KV position)",
+                 supervisor.build(e) is not None),
+                ("tp > 1 (tp_sharding has no table for this tree)",
+                 e.tp > 1),
+            ) if on
+        ]
+        if asked:
+            raise ValueError(
+                "this model has a patterned stack (layer_types: conv "
+                "state beside KV), which is served on the default path "
+                "only; not with " + "; ".join(asked)
+            )
 
     def _fresh_state(self) -> Dict[str, Any]:
         B, Smax = self.ecfg.max_slots, self.ecfg.max_seq_len
@@ -1558,16 +1609,11 @@ class InferenceEngine:
             | (max_news <= 1)
             | (plens + 1 >= Smax)
         )
-        # Scatter EVERY cache array (k/v + scales for quantized caches —
-        # all share the head-major [L, B, Hkv, T, ...] layout, with T at
-        # dim 3 of k/v and trailing on the scales, so one indexing
-        # expression covers them all).
-        new_cache = {
-            key: cache[key].at[:, slots, :, :Sb].set(
-                sub[key].astype(cache[key].dtype)
-            )
-            for key in cache
-        }
+        # Scatter EVERY cache array by its kind (transformer.cache_spec):
+        # k/v + scales into the slots' first Sb positions, a fixed-size
+        # state (a patterned stack's conv state) overwritten whole.
+        new_cache = transformer.cache_scatter_slots(
+            cfg, cache, sub, slots, Sb)
         new_state = {
             "cache": new_cache,
             "last_tok": state["last_tok"].at[slots].set(first),
@@ -1769,13 +1815,19 @@ class InferenceEngine:
         value-level: finished rows stop advancing and emit invalid tokens
         until the chunk boundary. Returns (state, toks [K,B], valid [K,B])."""
         Smax = state["cache"]["k"].shape[3]
+        routed = InferenceEngine._counts_routing(cfg)
 
         def step(carry, _):
             run = carry["active"]
-            logits, cache = transformer.decode_step(
+            # A model that dispatches tokens to experts is told which
+            # rows hold a request (the others route to no expert), and
+            # what routing did rides out with the tokens.
+            out = transformer.decode_step(
                 params, carry["last_tok"], carry["pos"], carry["cache"],
                 cfg, tp=tp,
+                **(dict(live=run, return_routing=True) if routed else {}),
             )
+            logits, cache = out[:2]
             keys = jax.vmap(
                 lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
             )(carry["seeds"], carry["pos"])
@@ -1804,15 +1856,26 @@ class InferenceEngine:
                 "active": carry["active"] & ~done,
                 "remaining": remaining,
             }
-            return new_carry, (tok, run)
+            return new_carry, (tok, run) + tuple(out[2:])
 
-        state, (toks, valid) = jax.lax.scan(step, state, None, length=n_steps)
+        state, ys = jax.lax.scan(step, state, None, length=n_steps)
+        toks, valid = ys[0], ys[1]
         if tp is not None:
             state = tp.constrain_state(state)
         toks, valid, active = InferenceEngine._replicate(
             mesh, toks, valid, state["active"]
         )
+        if routed:
+            # [3] int32 over the chunk: sparse-layer steps, distinct
+            # experts read (summed over those), assignments.
+            return state, toks, valid, active, jnp.sum(ys[2], axis=0)
         return state, toks, valid, active
+
+    @staticmethod
+    def _counts_routing(cfg) -> bool:
+        """Decode chunks of this model return routing counters as a
+        fifth value (a patterned stack with sparse layers)."""
+        return bool(cfg.patterned and cfg.n_sparse_layers)
 
     # --- paged-KV kernels ---------------------------------------------------
 
@@ -2292,13 +2355,23 @@ class InferenceEngine:
             total += int(np.prod(shp, dtype=np.int64)) * x.dtype.itemsize
         return total
 
+    def cache_bytes(self) -> Dict[str, int]:
+        """Bytes of the slot cache by kind: {"kv": ...} and, for a
+        patterned stack, {"conv": ...} (transformer.cache_spec's kinds;
+        the paged pool is all KV). Shape metadata — no sync."""
+        if self._paged:
+            return {"kv": sum(
+                int(x.nbytes)
+                for x in jax.tree_util.tree_leaves(self._state["cache"])
+            )}
+        return transformer.cache_bytes(
+            self.cfg, self.ecfg.max_slots, self.ecfg.max_seq_len)
+
     def _hbm_kv_reserved_bytes(self) -> int:
-        """Static KV reservation: the full cache tree (dense slot slab
-        or paged block pool). nbytes is shape metadata — no sync."""
-        return sum(
-            int(x.nbytes)
-            for x in jax.tree_util.tree_leaves(self._state["cache"])
-        )
+        """Static KV reservation: the KV arrays of the cache (dense slot
+        slab or paged block pool); a patterned stack's conv state is its
+        own category, "conv_state"."""
+        return self.cache_bytes()["kv"]
 
     def _hbm_kv_live_bytes(self) -> int:
         """Bytes of the reservation actually holding request state:
@@ -2626,7 +2699,7 @@ class InferenceEngine:
             t0 = time.perf_counter()
         if kind == "decode":
             # _dispatch_decode_chunk notes its own dispatch key.
-            self._state, _, _, _ = self._dispatch_decode_chunk(key[1])  # graftlint: allow(holds-site) warmup runs before start(); no scheduler thread exists yet
+            self._state = self._dispatch_decode_chunk(key[1])[0]  # graftlint: allow(holds-site) warmup runs before start(); no scheduler thread exists yet
             return
         if kind == "cow" and self._paged:
             # _cow notes its own dispatch key (traced src/dst scalars).
@@ -4455,6 +4528,16 @@ class InferenceEngine:
             "waves_ahead": req.waves_ahead if first is not None else None,
         }
 
+    def _note_routing(self, chunk_data) -> None:
+        """A decode chunk's routing counters (the fifth value of
+        _chunk_impl for a model that dispatches tokens to experts; came
+        to the host in the boundary's own fetch) into the stats."""
+        if len(chunk_data) < 4:
+            return
+        with self.stats.lock:
+            for name, v in zip(MOE_COUNTERS, chunk_data[3]):
+                setattr(self.stats, name, getattr(self.stats, name) + int(v))
+
     def _process_chunk(self, toks_h, valid_h, active_h, roster) -> None:  # graftlint: holds(_book)
         """toks_h [K, B], valid_h [K, B], active_h [B] — host arrays;
         `roster` is the slot->request snapshot taken when THIS chunk was
@@ -4596,6 +4679,13 @@ class InferenceEngine:
         tok = req.first_token_at
         phases = self._timings(req)
         phases["decode_ms"] = None if tok is None else 1000.0 * (now - tok)
+        if self._counts_routing(self.cfg):
+            # The engine's running routing counters as this request
+            # ended (EngineStats.moe_*): two lines' difference is what
+            # routing did in decode between them.
+            with self.stats.lock:
+                phases.update({name: getattr(self.stats, name)
+                               for name in MOE_COUNTERS})
         access_log.info("request %s", json.dumps({
             "rid": req.rid,
             "outcome": req.outcome or "ok",
@@ -5135,7 +5225,8 @@ class InferenceEngine:
         with jax.profiler.TraceAnnotation("fetch.process"):
             self._process_admits(admits, admit_data, admit_ready)
             if chunk_data is not None:
-                self._process_chunk(*chunk_data, roster)
+                self._process_chunk(*chunk_data[:3], roster)
+                self._note_routing(chunk_data)
             if self._spec:
                 self._spec_post_process(chunk_data, roster)
             self._record_wave_timing(timing)
@@ -5366,7 +5457,8 @@ class InferenceEngine:
                     get_s = f1 - f0 if was_ready else None
                     self._process_admits(admits, admit_data, admit_ready)
                     if chunk_data is not None:
-                        self._process_chunk(*chunk_data, roster)
+                        self._process_chunk(*chunk_data[:3], roster)
+                        self._note_routing(chunk_data)
                     self._record_wave_timing(timing)
                     if roofing:
                         self._roof_note_boundary(timing, f0, f1)
@@ -5623,9 +5715,10 @@ class InferenceEngine:
             roster = self._roster()
             self._dispatch_wreck = _PendingWave(admits, None, roster, None)
             n = self._pick_chunk()
-            self._state, toks, valid, active_after = (
-                self._dispatch_decode_chunk(n)
-            )
+            # (state, toks, valid, active_after[, routing counters])
+            out = self._dispatch_decode_chunk(n)
+            self._state, toks, valid, active_after = out[:4]
+            chunk_handles = tuple(out[1:])
             with self.stats.lock:
                 self.stats.decode_dispatches += 1
                 self.stats.decode_steps += n
@@ -5639,7 +5732,7 @@ class InferenceEngine:
             for _, _, f, d in admits:
                 f.copy_to_host_async()
                 d.copy_to_host_async()
-            for h in (toks, valid, active_after):
+            for h in chunk_handles:
                 h.copy_to_host_async()
             wf = 0.0
             if self._sled is not None:
@@ -5663,7 +5756,7 @@ class InferenceEngine:
             timing = self._make_timing() if self._timing_on else None
             self._dispatch_wreck = None
             return _PendingWave(
-                admits, (toks, valid, active_after), roster, timing,
+                admits, chunk_handles, roster, timing,
                 self._wave_epoch,
             )
         self._dispatch_wreck = None
@@ -5754,10 +5847,9 @@ class InferenceEngine:
                         # though _active_host lags until _process_admits.
                         roster = self._roster()
                         n = self._pick_chunk()
-                        self._state, toks, valid, active_after = (
-                            self._dispatch_decode_chunk(n)
-                        )
-                        chunk_handles = (toks, valid, active_after)
+                        out = self._dispatch_decode_chunk(n)
+                        self._state = out[0]
+                        chunk_handles = tuple(out[1:])
                         with self.stats.lock:
                             self.stats.decode_dispatches += 1
                             self.stats.decode_steps += n

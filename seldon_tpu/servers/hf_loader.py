@@ -1,4 +1,7 @@
-"""HuggingFace Llama-family checkpoint loader -> stacked param tree.
+"""HuggingFace checkpoint loader -> stacked param tree: the Llama family
+(llama, mistral) and the LFM2 family (lfm2, lfm2_moe: a layer pattern of
+short-conv and attention operators, dense or sparse feed-forward; the
+patterned tree of models/transformer.py, _load_patterned below).
 
 The reference loads CPU models via joblib/xgboost/mlflow natives; the
 TPU build's flagship server needs the LLM equivalent: point `modelUri`
@@ -67,10 +70,13 @@ def _rope_scaling_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
 def config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
     """ModelConfig from an HF llama config.json dict."""
     mt = hf.get("model_type", "llama")
+    if mt in ("lfm2", "lfm2_moe"):
+        return _lfm2_config(hf)
     if mt not in ("llama", "mistral"):
         raise ValueError(
             f"unsupported model_type {mt!r}; this loader handles the "
-            "Llama family (llama, mistral)"
+            "Llama family (llama, mistral) and the LFM2 family (lfm2, "
+            "lfm2_moe)"
         )
     return ModelConfig(
         **_rope_scaling_fields(hf),
@@ -84,14 +90,188 @@ def config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
         max_seq_len=hf.get("max_position_embeddings", 4096),
         rope_theta=float(hf.get("rope_theta", 10000.0)),
         rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
-        eos_token_id=(
-            hf.get("eos_token_id", 2)[0]
-            if isinstance(hf.get("eos_token_id"), list)
-            else hf.get("eos_token_id", 2)
-        ),
+        eos_token_id=_eos(hf, 2),
         pad_token_id=hf.get("pad_token_id") or 0,
         tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
     )
+
+
+def _eos(hf: Dict[str, Any], default: int) -> int:
+    eos = hf.get("eos_token_id", default)
+    return eos[0] if isinstance(eos, list) else eos
+
+
+def _lfm2_config(hf: Dict[str, Any]) -> ModelConfig:
+    """ModelConfig (patterned stack) from an lfm2 / lfm2_moe config.json:
+    layer_types (or the older full_attn_idxs), conv_L_cache, and for
+    lfm2_moe the leading dense layers, the expert width and the sigmoid
+    router's switches. QK-norm and tied embeddings are the model code's
+    (Lfm2Attention's q/k_layernorm; tie_word_embeddings defaults true)."""
+    if hf.get("conv_bias"):
+        raise ValueError("conv_bias=true is not supported (no conv or "
+                         "projection bias in the program's short conv)")
+    L = hf["num_hidden_layers"]
+    types = hf.get("layer_types")
+    if types is None:
+        full = set(hf.get("full_attn_idxs") or range(L))
+        types = ["full_attention" if i in full else "conv" for i in range(L)]
+    d_ff = hf["intermediate_size"]
+    moe = hf.get("model_type") == "lfm2_moe"
+    if not moe and hf.get("block_auto_adjust_ff_dim", True):
+        # Lfm2MLP: the declared width is cut to 2/3, scaled and rounded
+        # up to block_multiple_of.
+        d_ff = int(2 * d_ff / 3)
+        mult = hf.get("block_ffn_dim_multiplier", 1.0)
+        if mult is not None:
+            d_ff = int(mult * d_ff)
+            of = hf.get("block_multiple_of", 256)
+            d_ff = of * ((d_ff + of - 1) // of)
+    rope = hf.get("rope_parameters") or {}
+    kw: Dict[str, Any] = dict(
+        vocab_size=hf["vocab_size"],
+        d_model=hf["hidden_size"],
+        n_layers=L,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads",
+                          hf["num_attention_heads"]),
+        d_ff=d_ff,
+        max_seq_len=hf.get("max_position_embeddings", 128000),
+        rope_theta=float(rope.get("rope_theta",
+                                  hf.get("rope_theta", 1000000.0))),
+        rms_norm_eps=float(hf.get("norm_eps", 1e-5)),
+        eos_token_id=_eos(hf, 2),
+        pad_token_id=hf.get("pad_token_id") or 0,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        layer_types=tuple(types),
+        qk_norm=True,
+        conv_kernel=int(hf.get("conv_L_cache", 3)),
+    )
+    if moe:
+        kw.update(
+            n_experts=int(hf["num_experts"]),
+            n_experts_per_token=int(hf["num_experts_per_tok"]),
+            n_dense_layers=int(hf.get("num_dense_layers", 0)),
+            d_ff_expert=int(hf["moe_intermediate_size"]),
+            router="sigmoid",
+            router_bias=bool(hf.get("use_expert_bias", False)),
+            router_norm_topk=bool(hf.get("norm_topk_prob", True)),
+            router_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        )
+    return ModelConfig(**kw)
+
+
+# LFM2 tensor name (after "model.layers.<i>.") -> (slot, transpose?, norm?)
+_LFM2_LAYER_MAP = {
+    "operator_norm.weight": ("op_norm", False, True),
+    "ffn_norm.weight": ("ff_norm", False, True),
+    "conv.in_proj.weight": ("conv_in", True, False),
+    "conv.out_proj.weight": ("conv_out", True, False),
+    "self_attn.q_proj.weight": ("wq", True, False),
+    "self_attn.k_proj.weight": ("wk", True, False),
+    "self_attn.v_proj.weight": ("wv", True, False),
+    "self_attn.out_proj.weight": ("wo", True, False),
+    "self_attn.q_layernorm.weight": ("q_norm", False, True),
+    "self_attn.k_layernorm.weight": ("k_norm", False, True),
+    "feed_forward.w1.weight": ("w_gate", True, False),
+    "feed_forward.w3.weight": ("w_up", True, False),
+    "feed_forward.w2.weight": ("w_down", True, False),
+}
+_LFM2_EXPERT = {"w1": "w_gate", "w3": "w_up", "w2": "w_down"}
+
+
+def _load_patterned(path: str, cfg: ModelConfig, np_dtype, place):
+    """The patterned tree (transformer.init_params' layout for
+    cfg.layer_types) from an lfm2 / lfm2_moe checkpoint: per-layer
+    tensors by the published names, experts stacked [E, ...], the conv
+    taps [D, 1, K] as [K, D], the router (`feed_forward.gate`, [E, D]) as
+    float32 [D, E]; then layers stacked by segment and period position
+    (transformer.layer_plan)."""
+    import ml_dtypes
+
+    from seldon_tpu.models import transformer
+
+    def convert(arr, transpose=False, dtype=np_dtype):
+        arr = np.asarray(arr)
+        if arr.dtype == np.dtype("V2"):  # raw bf16 view
+            arr = arr.view(ml_dtypes.bfloat16)
+        return (arr.T if transpose else arr).astype(dtype)
+
+    layers = [dict() for _ in range(cfg.n_layers)]
+    experts = [dict() for _ in range(cfg.n_layers)]  # slot -> {e: array}
+    top: Dict[str, Any] = {}
+    for name, arr in _open_shards(path):
+        if name == "model.embed_tokens.weight":
+            top["embed"] = convert(arr)
+        elif name == "model.embedding_norm.weight":
+            top["final_norm"] = convert(arr, dtype=np.float32)
+        elif name == "lm_head.weight":
+            top["lm_head"] = convert(arr, True)
+        elif name.startswith("model.layers."):
+            idx_s, _, sub = name[len("model.layers."):].partition(".")
+            lp, parts = layers[int(idx_s)], sub.split(".")
+            if sub in _LFM2_LAYER_MAP:
+                key, tr, norm = _LFM2_LAYER_MAP[sub]
+                lp[key] = convert(arr, tr, np.float32 if norm else np_dtype)
+            elif sub == "conv.conv.weight":
+                lp["conv_w"] = convert(np.asarray(arr)[:, 0, :], True)
+            elif sub == "feed_forward.gate.weight":
+                lp["router"] = convert(arr, True, np.float32)
+            elif sub == "feed_forward.expert_bias":
+                lp["router_bias"] = convert(arr, dtype=np.float32)
+            elif (len(parts) == 5 and parts[:2] == ["feed_forward", "experts"]
+                  and parts[3] in _LFM2_EXPERT and parts[4] == "weight"):
+                experts[int(idx_s)].setdefault(
+                    _LFM2_EXPERT[parts[3]], {})[int(parts[2])] = \
+                    convert(arr, True)
+            else:
+                logger.warning("skipping unmapped tensor %s", name)
+        else:
+            logger.warning("skipping unmapped tensor %s", name)
+    for i, (lp, ex) in enumerate(zip(layers, experts)):
+        for key, by_e in ex.items():
+            if sorted(by_e) != list(range(cfg.n_experts)):
+                raise ValueError(f"layer {i}: {key} has experts "
+                                 f"{sorted(by_e)[:4]}..., not 0..E-1")
+            lp[key] = np.stack([by_e[e] for e in range(cfg.n_experts)])
+        if cfg.ff_sparse(i) and cfg.router_bias:
+            lp.setdefault("router_bias",
+                          np.zeros((cfg.n_experts,), np.float32))
+    if "embed" not in top or "final_norm" not in top:
+        raise ValueError("checkpoint lacks model.embed_tokens.weight or "
+                         "model.embedding_norm.weight")
+    segments = []
+    for seg in transformer.layer_plan(cfg):
+        p, period = len(seg.kinds), []
+        for j, (op, sparse) in enumerate(seg.kinds):
+            members = [layers[seg.first_layer + r * p + j]
+                       for r in range(seg.reps)]
+            need = {"op_norm", "ff_norm", "w_gate", "w_up", "w_down"}
+            need |= ({"wq", "wk", "wv", "wo", "q_norm", "k_norm"}
+                     if op == "full_attention"
+                     else {"conv_in", "conv_w", "conv_out"})
+            if sparse:
+                need |= {"router"} | ({"router_bias"} if cfg.router_bias
+                                      else set())
+            for r, lp in enumerate(members):
+                lack = sorted(need - set(lp))
+                if lack:
+                    raise ValueError(
+                        f"checkpoint incomplete: layer "
+                        f"{seg.first_layer + r * p + j} ({op}) lacks {lack}")
+            period.append({k: place(np.stack([lp[k] for lp in members]))
+                           for k in sorted(need)})
+        segments.append(tuple(period))
+    params: Dict[str, Any] = {
+        "embed": place(top["embed"]),
+        "segments": tuple(segments),
+        "final_norm": place(top["final_norm"]),
+    }
+    if not cfg.tie_embeddings:
+        if "lm_head" not in top:
+            raise ValueError(
+                "config has tie_word_embeddings=false but no lm_head.weight")
+        params["lm_head"] = place(top["lm_head"])
+    return params
 
 
 def _open_shards(path: str):
@@ -134,6 +314,24 @@ def load_hf_checkpoint(path: str, dtype: str = "bfloat16",
     cfg = config_from_hf(hf_cfg).validate()
     L = cfg.n_layers
     np_dtype = ml_dtypes.bfloat16 if dtype == "bfloat16" else np.float32
+    if cfg.patterned:
+        # Served on one chip (the engine refuses tp > 1): every leaf
+        # replicated on whatever mesh make_shardings was built for.
+        sh = None
+        if make_shardings is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            some = jax.tree_util.tree_leaves(make_shardings(cfg))[0]
+            sh = NamedSharding(some.mesh, PartitionSpec())
+        params = _load_patterned(
+            path, cfg, np_dtype,
+            (lambda a: jnp.asarray(a)) if sh is None
+            else (lambda a: jax.device_put(a, sh)))
+        logger.info(
+            "loaded HF checkpoint: %d layers (%d conv, %d attention), "
+            "d_model=%d, vocab=%d (%s)", cfg.n_layers, cfg.n_conv_layers,
+            cfg.n_attn_layers, cfg.d_model, cfg.vocab_size, dtype)
+        return params, cfg
 
     # Per-layer slots filled as shards stream by; stacked at the end.
     per_layer: Dict[str, list] = {

@@ -37,6 +37,7 @@ from seldon_tpu.runtime import REST_WORKERS
 from seldon_tpu.runtime.user_model import SeldonComponent
 from seldon_tpu.servers.engine import (
     KIND_HTTP_STATUS,
+    MOE_COUNTERS,
     EngineConfig,
     InferenceEngine,
     access_log,
@@ -514,6 +515,16 @@ class JAXServer(SeldonComponent):
         from seldon_tpu.models import transformer
         from seldon_tpu.parallel import sharding as shd
 
+        if cfg.patterned:
+            # Leaf by leaf (each leaf its own small program: a whole-tree
+            # jit may hold several stacked expert matrices in float32 at
+            # once), replicated: a patterned stack is served on one chip.
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            return jax.device_put(
+                transformer.init_params(cfg, jax.random.key(seed)),
+                NamedSharding(mesh, PartitionSpec()),
+            )
         int8 = cfg.weight_dtype == "int8"
         shardings = shd.named_shardings(
             mesh, shd.param_pspecs(cfg, quantized=int8)
@@ -611,6 +622,10 @@ class JAXServer(SeldonComponent):
             "mesh": {k: int(v) for k, v in self.mesh.shape.items()},
             "mesh_devices": [int(d.id) for d in self.mesh.devices.flat],
             "device": device.describe(),
+            # Bytes of the per-slot cache by kind (transformer.cache_spec:
+            # "kv" over the layers that hold KV, "conv" the fixed-size
+            # state of a patterned stack's conv layers).
+            "cache_bytes": self.engine.cache_bytes(),
             # What a client's warm-up otherwise has to discover: the
             # largest admission group, the decode-chunk rungs and how
             # many requests the REST transport runs at once.
@@ -1023,6 +1038,11 @@ class JAXServer(SeldonComponent):
              "value": float(s["decode_dispatches"])},
             {"type": "GAUGE", "key": "jaxserver_decode_steps",
              "value": float(s["decode_steps"])},
+            # What routing did in decode (0 unless the model dispatches
+            # tokens to experts): touched / sparse_layer_steps = experts
+            # a sparse layer reads per decode step.
+            *({"type": "GAUGE", "key": "jaxserver_" + name,
+               "value": float(s[name])} for name in MOE_COUNTERS),
             {"type": "GAUGE", "key": "jaxserver_prefix_hits",
              "value": float(s["prefix_hits"])},
             {"type": "GAUGE", "key": "jaxserver_prefix_tokens_saved",

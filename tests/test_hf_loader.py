@@ -197,3 +197,147 @@ def test_rope_scaling_linear_and_unknown():
         {**base, "rope_scaling": {"rope_type": "default"}}
     )
     assert dflt.rope_scaling_type is None
+
+
+# ---------------------------------------------------------------------------
+# The LFM2 family (lfm2, lfm2_moe): the patterned tree
+# ---------------------------------------------------------------------------
+
+
+def test_lfm2_dense_sibling_logit_parity_with_transformers(tmp_path):
+    """`Lfm2ForCausalLM` (the dense sibling the installed transformers
+    carries) is an independent check of the short conv, the QK-norm
+    attention, `embedding_norm` and the tied head: its own forward pass
+    against ours on its own random weights, through the loader."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from seldon_tpu.models import forward
+    from seldon_tpu.servers.hf_loader import load_hf_checkpoint
+
+    if not hasattr(transformers, "Lfm2ForCausalLM"):
+        pytest.skip("this transformers has no Lfm2ForCausalLM")
+    types = ["conv", "conv", "full_attention", "conv", "conv", "full_attention"]
+    hf_cfg = transformers.Lfm2Config(
+        vocab_size=128, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=6, num_attention_heads=4, num_key_value_heads=2,
+        layer_types=types, block_auto_adjust_ff_dim=False,
+        max_position_embeddings=64)
+    torch.manual_seed(0)
+    model = transformers.Lfm2ForCausalLM(hf_cfg)
+    model.eval()
+    model.save_pretrained(tmp_path, safe_serialization=True)
+    params, cfg = load_hf_checkpoint(str(tmp_path), dtype="float32")
+    assert cfg.layer_types == tuple(types) and cfg.qk_norm and cfg.tie_embeddings
+    assert (cfg.d_ff, cfg.conv_kernel, cfg.n_experts) == (128, 3, 0)
+    cfg = dataclasses.replace(cfg, dtype="float32")
+    tokens = np.random.default_rng(0).integers(0, 128, size=(2, 12))
+    with torch.no_grad():
+        hf_logits = model(torch.tensor(tokens)).logits.numpy()
+    ours = np.asarray(forward(params, jnp.asarray(tokens), cfg))
+    np.testing.assert_allclose(ours, hf_logits, rtol=2e-4, atol=2e-4)
+
+
+def _save_lfm2_moe(path, params, cfg):
+    """Our patterned tree under the published lfm2_moe tensor names."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    from seldon_tpu.models import transformer as T
+
+    def t(a, transpose=False):
+        a = np.asarray(a, np.float32)
+        return np.ascontiguousarray(a.T if transpose else a)
+
+    out = {"model.embed_tokens.weight": t(params["embed"]),
+           "model.embedding_norm.weight": t(params["final_norm"])}
+    for i in range(cfg.n_layers):
+        lp, pre = T.layer_params(params, cfg, i), f"model.layers.{i}."
+        out[pre + "operator_norm.weight"] = t(lp["op_norm"])
+        out[pre + "ffn_norm.weight"] = t(lp["ff_norm"])
+        if cfg.op_kind(i) == "conv":
+            out[pre + "conv.in_proj.weight"] = t(lp["conv_in"], True)
+            out[pre + "conv.out_proj.weight"] = t(lp["conv_out"], True)
+            out[pre + "conv.conv.weight"] = t(lp["conv_w"], True)[:, None, :]
+        else:
+            for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"),
+                                 ("wo", "out_proj")):
+                out[pre + f"self_attn.{theirs}.weight"] = t(lp[ours], True)
+            out[pre + "self_attn.q_layernorm.weight"] = t(lp["q_norm"])
+            out[pre + "self_attn.k_layernorm.weight"] = t(lp["k_norm"])
+        if cfg.ff_sparse(i):
+            out[pre + "feed_forward.gate.weight"] = t(lp["router"], True)
+            out[pre + "feed_forward.expert_bias"] = t(lp["router_bias"])
+            for e in range(cfg.n_experts):
+                for ours, theirs in (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2")):
+                    out[pre + f"feed_forward.experts.{e}.{theirs}.weight"] = \
+                        t(lp[ours][e], True)
+        else:
+            for ours, theirs in (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2")):
+                out[pre + f"feed_forward.{theirs}.weight"] = t(lp[ours], True)
+    save_file(out, str(path / "model.safetensors"))
+    with open(path / "config.json", "w") as f:
+        json.dump({
+            "model_type": "lfm2_moe", "vocab_size": cfg.vocab_size,
+            "hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
+            "moe_intermediate_size": cfg.expert_width,
+            "num_hidden_layers": cfg.n_layers, "layer_types": list(cfg.layer_types),
+            "num_dense_layers": cfg.n_dense_layers,
+            "num_attention_heads": cfg.n_heads, "num_key_value_heads": cfg.n_kv_heads,
+            "max_position_embeddings": cfg.max_seq_len, "norm_eps": cfg.rms_norm_eps,
+            "rope_parameters": {"rope_theta": cfg.rope_theta, "rope_type": "default"},
+            "conv_L_cache": cfg.conv_kernel, "conv_bias": False,
+            "num_experts": cfg.n_experts, "num_experts_per_tok": cfg.n_experts_per_token,
+            "use_expert_bias": True, "norm_topk_prob": True,
+            "routed_scaling_factor": 1.0, "eos_token_id": cfg.eos_token_id,
+        }, f)
+
+
+def test_lfm2_moe_checkpoint_maps_the_published_names_onto_the_patterned_tree(tmp_path):
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_tpu.models import forward
+    from seldon_tpu.models import transformer as T
+    from seldon_tpu.models.config import get_config
+    from seldon_tpu.servers.hf_loader import config_from_hf, load_hf_checkpoint
+
+    cfg = get_config("tiny-lfm2", dtype="float32")
+    params = T.init_params(cfg, jax.random.key(0))
+    _save_lfm2_moe(tmp_path, params, cfg)
+    loaded, got = load_hf_checkpoint(str(tmp_path), dtype="float32")
+    assert dataclasses.replace(got, dtype="float32") == cfg  # every field, the pattern too
+    same = jax.tree.map(lambda a, b: bool(jnp.all(a == b)), loaded, params)
+    assert jax.tree.structure(loaded) == jax.tree.structure(params)
+    assert all(jax.tree.leaves(same))
+    toks = jnp.asarray(np.random.default_rng(1).integers(0, 256, size=(1, 9)))
+    np.testing.assert_array_equal(np.asarray(forward(loaded, toks, cfg)),
+                                  np.asarray(forward(params, toks, cfg)))
+    # a layer without its experts is named, not served
+    import os
+    from safetensors.numpy import load_file, save_file
+    part = load_file(str(tmp_path / "model.safetensors"))
+    del part["model.layers.3.feed_forward.experts.2.w3.weight"]
+    save_file(part, str(tmp_path / "model.safetensors"))
+    with pytest.raises(ValueError, match="layer 3: w_up has experts"):
+        load_hf_checkpoint(str(tmp_path), dtype="float32")
+    with pytest.raises(ValueError, match="conv_bias"):
+        config_from_hf({"model_type": "lfm2", "conv_bias": True})
+    assert os.path.exists(tmp_path / "config.json")
+
+
+def test_lfm2_dense_width_follows_the_model_codes_adjustment():
+    from seldon_tpu.servers.hf_loader import config_from_hf
+
+    base = {"model_type": "lfm2", "vocab_size": 65536, "hidden_size": 2560,
+            "intermediate_size": 12288, "num_hidden_layers": 4,
+            "num_attention_heads": 32, "num_key_value_heads": 8,
+            "full_attn_idxs": [2]}
+    cfg = config_from_hf(base)  # Lfm2MLP: int(2 * 12288 / 3) rounded up to 256
+    assert cfg.d_ff == 8192
+    assert cfg.layer_types == ("conv", "conv", "full_attention", "conv")
+    assert config_from_hf(dict(base, block_auto_adjust_ff_dim=False)).d_ff == 12288
