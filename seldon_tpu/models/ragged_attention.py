@@ -85,7 +85,7 @@ import jax.numpy as jnp
 
 from seldon_tpu.models import transformer
 from seldon_tpu.models.config import ModelConfig
-from seldon_tpu.models.sampling import sample_per_row
+from seldon_tpu.models.sampling import live_knobs, sample_per_row
 from seldon_tpu.ops import ragged_paged_attention as rpa
 
 Cache = Dict[str, jnp.ndarray]
@@ -452,11 +452,8 @@ def ragged_decode_phase(
             lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
         )(carry["seeds"], carry["pos"])
         tok = sample_per_row(
-            logits,
-            keys,
-            carry["temp"],
-            jnp.where(run, carry["top_k"], 0),
-            jnp.where(run, carry["top_p"], 1.0),
+            logits, keys,
+            *live_knobs(run, carry["temp"], carry["top_k"], carry["top_p"]),
         )
         tok = jnp.where(run, tok, cfg.pad_token_id)
         pos = carry["pos"] + run.astype(jnp.int32)

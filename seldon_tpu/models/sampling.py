@@ -1,15 +1,21 @@
 """Token sampling — temperature / top-k / top-p, fully jittable.
 
-All branching is value-level (jnp.where), never Python-level, so one
-compiled sampler serves every request config; per-request knobs arrive as
-arrays and sampling stays inside the jitted decode loop (no host sync per
-token — the reference has no generation path at all, SURVEY.md §5.7).
+Per-request knobs arrive as arrays and sampling stays inside the jitted
+decode loop (no host sync per token — the reference has no generation
+path at all, SURVEY.md §5.7). One compiled sampler serves every request
+config, and what it EXECUTES is decided on the device from those arrays
+(`tier`): a batch in which no row samples takes the argmax and nothing
+else; a batch in which some row samples also divides and draws the
+Gumbel noise over [B, V]; only a batch in which a row that samples asks
+for top-k / top-p pays the full-vocabulary sort, softmax and cumsum.
+Within a tier the work is whole-batch (value-level jnp.where per row):
+one sampled row among greedy ones costs the draw over all rows.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +25,10 @@ import jax.numpy as jnp
 class SamplingParams:
     """Host-side request knobs; converted to per-row arrays by the server."""
 
+    # These defaults hold for a caller that builds SamplingParams or
+    # sends the in-process dict. Over REST and gRPC a field the request
+    # does not name is a proto3 zero today: temperature 0.0 (greedy) and
+    # top_p 0.0 (keep the argmax alone) — ROADMAP queue C, item C12.
     temperature: float = 0.7
     top_k: int = 0  # 0 = disabled
     top_p: float = 1.0  # 1.0 = disabled
@@ -48,7 +58,11 @@ def _mask_top_k_top_p(
     top_p: jnp.ndarray,  # [B] f32; 1.0 => off
 ) -> jnp.ndarray:
     """Apply top-k + top-p (nucleus) masks. O(V log V) per row (one sort) —
-    callers skip this entirely via lax.cond when every row has both off."""
+    sample_per_row skips this entirely via lax.cond unless some row that
+    samples has one of them on (`tier`). For a row with both off the
+    mask is the identity (up to the float cumsum's last ulps of tail
+    mass), so it does not matter to such a row whether another switched
+    it on."""
     B, V = scaled.shape
     # top-k: mask everything below the k-th largest logit per row.
     sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
@@ -72,6 +86,39 @@ def _mask_top_k_top_p(
     return jnp.where(masked < min_keep, -jnp.inf, masked)
 
 
+def tier(
+    temperature: jnp.ndarray,  # [B] f32; 0 => greedy
+    top_k: jnp.ndarray,  # [B] int32; 0 => off
+    top_p: jnp.ndarray,  # [B] f32; 1.0 => off
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """What this batch asks of the sampler, as two scalar booleans:
+    (draws, masks). draws: some row samples (temperature > 0). masks:
+    some row that samples has top-k or top-p on. A greedy row never
+    needs a mask — its token is argmax(logits) whatever its top_k /
+    top_p say — so the knobs of greedy rows switch nothing on. The
+    predicates sample_per_row branches on; a decode chunk counts them
+    per step (EngineStats.sampler_*)."""
+    samples = temperature > 0
+    wants_mask = samples & ((top_k > 0) | (top_p < 1.0))
+    return jnp.any(samples), jnp.any(wants_mask)
+
+
+def live_knobs(
+    run: jnp.ndarray,  # [B] bool: the row holds a running request
+    temperature: jnp.ndarray,
+    top_k: jnp.ndarray,
+    top_p: jnp.ndarray,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """(temperature, top_k, top_p) with the rows that are not running
+    read as greedy with both masks off. A program that steps over a
+    whole slab passes these: a freed slot keeps its last request's
+    knobs, and one stale sampled row would otherwise hold every later
+    batch on the drawn (or masked) tier. Such rows' tokens are
+    discarded by the caller."""
+    return (jnp.where(run, temperature, 0.0), jnp.where(run, top_k, 0),
+            jnp.where(run, top_p, 1.0))
+
+
 @jax.named_scope("sampler")
 def sample_per_row(
     logits: jnp.ndarray,  # [B, V]
@@ -83,9 +130,11 @@ def sample_per_row(
     """Row-independent sampling: each row draws from its own key, so a
     request's tokens are reproducible from (seed, position) no matter
     what other requests share the batch (continuous-batching
-    requirement). The top-k/top-p sort is behind a batch-level lax.cond
-    and costs nothing when no active row uses them (the decode-loop
-    common case).
+    requirement). Two nested batch-level lax.conds on `tier`: the
+    divide and the Gumbel draw run only when some row samples, the
+    top-k/top-p sort only when a row that samples asks for it. An
+    all-greedy batch (the decode-loop common case) is one argmax,
+    whatever top_k / top_p its rows carry.
 
     Gumbel-argmax over inverse-CDF: argmax(logits/T + g) IS a categorical
     sample, in ONE pass over the logits — the CDF route (softmax + cumsum
@@ -94,23 +143,24 @@ def sample_per_row(
     entries stay -inf through the addition, so the same argmax serves the
     top-k/top-p branch."""
     B, V = logits.shape
-    greedy = jnp.argmax(logits, axis=-1)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    draws, masks = tier(temperature, top_k, top_p)
 
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    scaled = logits / temp
-    need_mask = jnp.any(top_k > 0) | jnp.any(top_p < 1.0)
-    scaled = jax.lax.cond(
-        need_mask,
-        lambda s: _mask_top_k_top_p(s, top_k, top_p),
-        lambda s: s,
-        scaled,
-    )
+    def draw():
+        temp = jnp.maximum(temperature, 1e-6)[:, None]
+        scaled = jax.lax.cond(
+            masks,
+            lambda s: _mask_top_k_top_p(s, top_k, top_p),
+            lambda s: s,
+            logits / temp,
+        )
+        gumbel = jax.vmap(
+            lambda k: jax.random.gumbel(k, (V,), dtype=jnp.float32)
+        )(keys)
+        sampled = jnp.argmax(scaled + gumbel, axis=-1)
+        return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
 
-    gumbel = jax.vmap(
-        lambda k: jax.random.gumbel(k, (V,), dtype=jnp.float32)
-    )(keys)
-    sampled = jnp.argmax(scaled + gumbel, axis=-1)
-    return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
+    return jax.lax.cond(draws, draw, lambda: greedy)
 
 
 def sample(

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from seldon_tpu.models import transformer
 from seldon_tpu.models.config import ModelConfig
-from seldon_tpu.models.sampling import sample_per_row
+from seldon_tpu.models.sampling import live_knobs, sample_per_row
 from seldon_tpu.ops import ragged_paged_attention as rpa
 
 Cache = Dict[str, jnp.ndarray]
@@ -378,11 +378,8 @@ def verify_wave(
             lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
         )(state["seeds"], pos)
         tok = sample_per_row(
-            logits[:, i],
-            keys,
-            state["temp"],
-            jnp.where(run, state["top_k"], 0),
-            jnp.where(run, state["top_p"], 1.0),
+            logits[:, i], keys,
+            *live_knobs(run, state["temp"], state["top_k"], state["top_p"]),
         )
         tok = jnp.where(run, tok, cfg.pad_token_id)
         pos = pos + run.astype(jnp.int32)

@@ -259,7 +259,7 @@ def gqa_attention(
 def gqa_attention_decode(
     q: jnp.ndarray,  # [B, 1, H, Dh]
     ck: jnp.ndarray,  # [B, Hkv, T, Dh] OLD cache (pre-write; int8 if scales)
-    cv: jnp.ndarray,  # [B, Hkv, T, Dh]
+    cv: jnp.ndarray,  # [B, Hkv, T, Dh]; or rows of several heads, below
     k_fresh: jnp.ndarray,  # [B, 1, Hkv, Dh] bf16 (exact, this token)
     v_fresh: jnp.ndarray,  # [B, 1, Hkv, Dh]
     mask_lt: jnp.ndarray,  # [B, 1, T] True where t < pos (strict)
@@ -287,14 +287,22 @@ def gqa_attention_decode(
     the operand: measured int8 bought only 3% that way). int8 values are
     exact in bf16 and scales apply in f32, so rounding is strictly
     tighter than dequantize-then-multiply. The fresh column is exact
-    bf16 — requantization noise only enters through PAST tokens."""
+    bf16 — requantization noise only enters through PAST tokens.
+
+    A cache whose rows hold `side` heads side by side,
+    [B, Hkv / side, T, side * Dh] (kv_heads_per_row; told by the shapes:
+    k_fresh has the model's Hkv), is read as stored: the queries go
+    block-diagonal over the row (_beside) and each keeps its own head's
+    lanes of the weighted values (_own_side)."""
     B, S, H, Dh = q.shape
-    Hkv = ck.shape[1]
+    Hkv = k_fresh.shape[2]
+    side = Hkv // ck.shape[1]  # heads side by side in a cache row
     G = H // Hkv
     qr = q.reshape(B, S, Hkv, G, Dh)
+    qc = qr if side == 1 else _beside(qr, side)
     with jax.named_scope("attn/scores"):
         scores = jnp.einsum(
-            "bskgd,bktd->bkgst", qr, ck.astype(qr.dtype),
+            "bskgd,bktd->bkgst", qc, ck.astype(qr.dtype),
             preferred_element_type=jnp.float32,
         ) / (Dh**0.5)
         if k_scale is not None:
@@ -302,7 +310,7 @@ def gqa_attention_decode(
         s_fresh = jnp.einsum(
             "bskgd,bukd->bkgsu", qr, k_fresh.astype(qr.dtype),
             preferred_element_type=jnp.float32,
-        ) / (Dh**0.5)
+        ).reshape(B, Hkv // side, side * G, S, 1) / (Dh**0.5)
         scores = jnp.where(mask_lt[:, None, None, :, :], scores, -1e30)
         # Flash-style combine of the fresh column — concatenating it as
         # a T+1th score column forces XLA to relayout the whole (lane-
@@ -320,11 +328,63 @@ def gqa_attention_decode(
     with jax.named_scope("attn/out"):
         out = jnp.einsum(
             "bkgst,bktd->bskgd", wc.astype(qr.dtype), cv.astype(qr.dtype)
-        ) + jnp.einsum(
-            "bkgsu,bukd->bskgd", (p_f / l).astype(qr.dtype),
+        )
+        if side > 1:
+            out = _own_side(out, side)
+        out = out + jnp.einsum(
+            "bkgsu,bukd->bskgd",
+            (p_f / l).reshape(B, Hkv, G, S, 1).astype(qr.dtype),
             v_fresh.astype(qr.dtype),
         )
     return out.reshape(B, S, H * Dh)
+
+
+def kv_heads_per_row(cfg: ModelConfig) -> int:
+    """KV heads that share one row of the cache (cache_spec): all of
+    them in a patterned stack, whose slab is [La, B, 1, T, Hkv * Dh],
+    one row a token; 1 in a homogeneous stack, head-major as before.
+
+    Why (lfm2.chat, heads of 64; PERF.md section 6, PR 28): a [T, 64]
+    tile fills half the TPU's 128 lanes, so the compiler kept the
+    head-major slab T-minor for the layer scan's attention and
+    token-major for the step's scatter, and relaid the whole of it out
+    between the two: on the chunk's entry, on EVERY decode step and on
+    its exit, 1.85 ms of a 7.15 ms step. With a single row a token both
+    agree on the layout as stored and the chunk holds no copy of the
+    slab. The price is gqa_attention_decode's contraction over the whole
+    row (_beside: Hkv times the products, of which all but a head's own
+    are with zeros): n_heads FLOPs a byte of slab read, far under the
+    chip's ~240, so the step stays bound by the read it always made."""
+    return cfg.n_kv_heads if cfg.patterned else 1
+
+
+def _kv_rows(x: jnp.ndarray, side: int) -> jnp.ndarray:
+    """Fresh k or v [B, S, Hkv, Dh] as the cache rows hold them:
+    [B, S, Hkv / side, side * Dh], head h at lanes (h % side) * Dh."""
+    B, S, Hkv, Dh = x.shape
+    return x.reshape(B, S, Hkv // side, side * Dh)
+
+
+def _beside(qr: jnp.ndarray, side: int) -> jnp.ndarray:
+    """Queries [B, S, Hkv, G, Dh] against cache rows of `side` heads:
+    [B, S, Hkv / side, side * G, side * Dh], each query in the lanes of
+    its own KV head and zero in its neighbours', so one contraction over
+    the row gives every head's scores (the zeros add nothing)."""
+    B, S, Hkv, G, Dh = qr.shape
+    q6 = qr.reshape(B, S, Hkv // side, side, G, 1, Dh)
+    own = jnp.eye(side, dtype=bool)[:, None, :, None]
+    return jnp.where(own, q6, 0).reshape(
+        B, S, Hkv // side, side * G, side * Dh)
+
+
+def _own_side(out: jnp.ndarray, side: int) -> jnp.ndarray:
+    """The weighted values [B, S, Hkv / side, side * G, side * Dh] of
+    _beside's scores, each query keeping its own head's lanes:
+    [B, S, Hkv, G, Dh]."""
+    B, S, Hc, N, C = out.shape
+    o7 = out.reshape(B, S, Hc, side, N // side, side, C // side)
+    own = jnp.stack([o7[:, :, :, j, :, j] for j in range(side)], axis=3)
+    return own.reshape(B, S, Hc * side, N // side, C // side)
 
 
 def moe_block(x: jnp.ndarray, bp: Dict[str, jnp.ndarray], cfg: ModelConfig):
@@ -764,7 +824,9 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
 
     KV is HEAD-major [La, B, Hkv, T, Dh] (scales [La, B, Hkv, T]) over
     the La layers that hold KV: every layer of a homogeneous stack, the
-    attention layers of a patterned one. Head-major is the layout the
+    attention layers of a patterned one, whose rows hold all of a
+    token's heads: [La, B, 1, T, Hkv * Dh] (kv_heads_per_row; the same
+    five axes, T still at 3). Head-major is the layout the
     decode attention einsums consume; stored token-major, XLA inserted a
     per-layer transpose copy of every slice (~2x attention cost at
     [160 slots, 257 window] on v5e). The write side no longer cares
@@ -775,8 +837,9 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
     The conv state is [Lc, B, conv_kernel - 1, D] over the Lc conv
     layers of a patterned stack: the gated inputs u of the slot's last
     conv_kernel - 1 positions, oldest first."""
-    shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads, max_len,
-             cfg.head_dim)
+    side = kv_heads_per_row(cfg)
+    shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads // side, max_len,
+             cfg.head_dim * side)
     spec: Dict[str, CacheEntry] = {}
     if cfg.kv_cache_dtype == "int8":
         assert dtype is None, (
@@ -1451,7 +1514,7 @@ def _unsegment(parts):
 def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
     """Every layer over whole sequences from position 0 (forward,
     prefill). Returns (x, fresh cache arrays by kind or {} when plens is
-    None, routing [3]): k/v [La, B, Hkv, S, Dh] in cache layout, conv
+    None, routing [3]): k/v [La, B, 1, S, Hkv * Dh] in cache layout, conv
     [Lc, B, Kc - 1, D] taken at each row's own prompt length. Positions
     at or past a row's plens are not live: they route to no expert."""
     S = x.shape[1]
@@ -1459,6 +1522,7 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
         jnp.arange(S)[None, :] < plens[:, None]
     fresh = {"k": [], "v": [], "conv": []}
     routing = jnp.zeros((3,), jnp.int32)
+    side = kv_heads_per_row(cfg)
     for seg, sp in zip(layer_plan(cfg), params["segments"]):
         sliced, experts = _split_experts(sp, cfg)
 
@@ -1473,8 +1537,8 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
                     attn = gqa_attention(q, k, v, mask)
                     with jax.named_scope("attn/out"):
                         x = x + _qdot(attn, lp, "wo", cfg)
-                    ks.append(k.transpose(0, 2, 1, 3))
-                    vs.append(v.transpose(0, 2, 1, 3))
+                    ks.append(_kv_rows(k, side).transpose(0, 2, 1, 3))
+                    vs.append(_kv_rows(v, side).transpose(0, 2, 1, 3))
                 else:
                     y, st = _conv_op(h, lp, cfg, plens=plens)
                     x = x + y
@@ -1510,6 +1574,7 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
     fresh = {"k": [], "v": [], "conv": []}
     routing = jnp.zeros((3,), jnp.int32)
     dt = cache["k"].dtype
+    side = kv_heads_per_row(cfg)
     for seg, sp in zip(layer_plan(cfg), params["segments"]):
         sliced, experts = _split_experts(sp, cfg)
 
@@ -1526,8 +1591,8 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                         q, cl["k"][ia], cl["v"][ia], k, v, mask_lt)
                     with jax.named_scope("attn/out"):
                         x = x + _qdot(attn, lp, "wo", cfg)
-                    ks.append(k[:, 0].astype(dt))
-                    vs.append(v[:, 0].astype(dt))
+                    ks.append(_kv_rows(k, side)[:, 0].astype(dt))
+                    vs.append(_kv_rows(v, side)[:, 0].astype(dt))
                     ia += 1
                 else:
                     y, st = _conv_op(h, lp, cfg, state=cl["conv"][ic])
@@ -1550,10 +1615,17 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
             fresh[key].append(val)
     rows = jnp.arange(pos.shape[0])
     new_cache = dict(cache)
+    # Layer, row and position are all INDICES of the scatter and only
+    # the token's row is its window. With the layer axis a window
+    # dimension (`.at[:, rows, :, pos]`) the TPU compiler carried the
+    # slab layer-minor through the chunk's steps and relaid the whole of
+    # it out for the layer scan on every step (kv_heads_per_row).
+    layers = jnp.arange(cache["k"].shape[0])[None, :]
     with jax.named_scope("attn/cache_update"):
         for key in ("k", "v"):
             if fresh[key]:
-                new_cache[key] = cache[key].at[:, rows, :, pos].set(
+                new_cache[key] = cache[key].at[
+                    layers, rows[:, None], :, pos[:, None]].set(
                     jnp.swapaxes(_unsegment(fresh[key]), 0, 1),
                     unique_indices=True)
     if fresh["conv"]:

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from seldon_tpu.core import tracing
-from seldon_tpu.models import ragged_attention, tp_sharding, transformer
+from seldon_tpu.models import ragged_attention, sampling, tp_sharding
+from seldon_tpu.models import transformer
 from seldon_tpu.models import spec_decode as spec_model
 from seldon_tpu.models.config import ModelConfig
 from seldon_tpu.models.sampling import SamplingParams, sample_per_row
@@ -599,9 +600,14 @@ class _DepthEstimator:
         }
 
 
-# EngineStats' routing counters, in the order a decode chunk returns them.
+# EngineStats' counters that a decode chunk counts on the device, in the
+# order of its fifth value: the sampler's tiers (every model), then what
+# routing did (a model that dispatches tokens to experts).
+SAMPLER_COUNTERS = ("sampler_steps", "sampler_drawn_steps",
+                    "sampler_masked_steps")
 MOE_COUNTERS = ("moe_sparse_layer_steps", "moe_experts_touched",
                 "moe_assignments")
+CHUNK_COUNTERS = SAMPLER_COUNTERS + MOE_COUNTERS
 
 
 class EngineStats:
@@ -624,8 +630,17 @@ class EngineStats:
         # length, the knob the occupancy policy is turning.
         self.decode_dispatches = 0  # graftlint: guarded-by(lock) via(stats)
         self.decode_steps = 0  # graftlint: guarded-by(lock) via(stats)
+        # The tier the sampler took in decode (_note_chunk_counts;
+        # models/sampling.tier, decided on the device per step): steps
+        # whose tier a chunk reported, those in which some live row
+        # sampled (the batch divided and drew Gumbel noise), and those
+        # of them in which a sampling row asked for top-k / top-p (the
+        # batch sorted the vocabulary). steps - drawn were an argmax.
+        self.sampler_steps = 0  # graftlint: guarded-by(lock) via(stats)
+        self.sampler_drawn_steps = 0  # graftlint: guarded-by(lock) via(stats)
+        self.sampler_masked_steps = 0  # graftlint: guarded-by(lock) via(stats)
         # What routing did in decode (models that dispatch tokens to
-        # experts; _note_routing): sparse layers run over all decode
+        # experts; _note_chunk_counts): sparse layers run over all decode
         # steps, distinct experts those layers read for live rows
         # (summed), (row, expert) assignments. touched / layer-steps is
         # the mean number of experts a sparse layer reads per step.
@@ -836,7 +851,7 @@ class EngineStats:
                 ),
                 "decode_dispatches": self.decode_dispatches,
                 "decode_steps": self.decode_steps,
-                **{name: getattr(self, name) for name in MOE_COUNTERS},
+                **{name: getattr(self, name) for name in CHUNK_COUNTERS},
                 "prefix_hits": self.prefix_hits,
                 "prefix_tokens_saved": self.prefix_tokens_saved,
                 "prefix_evictions": self.prefix_evictions,
@@ -1831,15 +1846,12 @@ class InferenceEngine:
             keys = jax.vmap(
                 lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
             )(carry["seeds"], carry["pos"])
-            # Mask inactive rows' knobs so stale top_k/top_p in freed slots
-            # can't force the sampler's O(V log V) masking path forever.
-            tok = sample_per_row(
-                logits,
-                keys,
-                carry["temp"],
-                jnp.where(run, carry["top_k"], 0),
-                jnp.where(run, carry["top_p"], 1.0),
-            )
+            # Rows that are not running ask nothing of the sampler
+            # (sampling.live_knobs); the tier this step took rides out
+            # with the tokens.
+            knobs = sampling.live_knobs(
+                run, carry["temp"], carry["top_k"], carry["top_p"])
+            tok = sample_per_row(logits, keys, *knobs)
             tok = jnp.where(run, tok, cfg.pad_token_id)
             pos = carry["pos"] + run.astype(jnp.int32)
             remaining = carry["remaining"] - run.astype(jnp.int32)
@@ -1856,25 +1868,38 @@ class InferenceEngine:
                 "active": carry["active"] & ~done,
                 "remaining": remaining,
             }
-            return new_carry, (tok, run) + tuple(out[2:])
+            counts = InferenceEngine._step_counts(knobs)
+            if routed:
+                counts = jnp.concatenate([counts, out[2]])
+            return new_carry, (tok, run, counts)
 
-        state, ys = jax.lax.scan(step, state, None, length=n_steps)
-        toks, valid = ys[0], ys[1]
+        state, (toks, valid, counts) = jax.lax.scan(
+            step, state, None, length=n_steps)
         if tp is not None:
             state = tp.constrain_state(state)
-        toks, valid, active = InferenceEngine._replicate(
-            mesh, toks, valid, state["active"]
+        toks, valid, active, counts = InferenceEngine._replicate(
+            mesh, toks, valid, state["active"], jnp.sum(counts, axis=0)
         )
-        if routed:
-            # [3] int32 over the chunk: sparse-layer steps, distinct
-            # experts read (summed over those), assignments.
-            return state, toks, valid, active, jnp.sum(ys[2], axis=0)
-        return state, toks, valid, active
+        # counts, int32 over the chunk, in CHUNK_COUNTERS' order: steps,
+        # steps that drew, steps that masked; a routed model adds
+        # sparse-layer steps, distinct experts read (summed over those),
+        # assignments.
+        return state, toks, valid, active, counts
+
+    @staticmethod
+    def _step_counts(knobs):
+        """One decode step in SAMPLER_COUNTERS' order, [3] int32: the
+        step itself, whether its sampler drew, whether it masked
+        (sampling.tier of the running rows' knobs)."""
+        return jnp.stack(
+            (jnp.ones((), bool),) + sampling.tier(*knobs)
+        ).astype(jnp.int32)
 
     @staticmethod
     def _counts_routing(cfg) -> bool:
-        """Decode chunks of this model return routing counters as a
-        fifth value (a patterned stack with sparse layers)."""
+        """Decode chunks of this model count what routing did, after
+        the sampler's tiers in their fifth value (a patterned stack
+        with sparse layers)."""
         return bool(cfg.patterned and cfg.n_sparse_layers)
 
     # --- paged-KV kernels ---------------------------------------------------
@@ -2035,13 +2060,9 @@ class InferenceEngine:
             keys = jax.vmap(
                 lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
             )(carry["seeds"], carry["pos"])
-            tok = sample_per_row(
-                logits,
-                keys,
-                carry["temp"],
-                jnp.where(run, carry["top_k"], 0),
-                jnp.where(run, carry["top_p"], 1.0),
-            )
+            knobs = sampling.live_knobs(
+                run, carry["temp"], carry["top_k"], carry["top_p"])
+            tok = sample_per_row(logits, keys, *knobs)
             tok = jnp.where(run, tok, cfg.pad_token_id)
             pos = carry["pos"] + run.astype(jnp.int32)
             remaining = carry["remaining"] - run.astype(jnp.int32)
@@ -2058,16 +2079,17 @@ class InferenceEngine:
                 "active": carry["active"] & ~done,
                 "remaining": remaining,
             }
-            return new_carry, (tok, run)
+            counts = InferenceEngine._step_counts(knobs)
+            return new_carry, (tok, run, counts)
 
-        state, (toks, valid) = jax.lax.scan(step, state, None,
-                                            length=n_steps)
+        state, (toks, valid, counts) = jax.lax.scan(
+            step, state, None, length=n_steps)
         if tp is not None:
             state = tp.constrain_state(state)
-        toks, valid, active = InferenceEngine._replicate(
-            mesh, toks, valid, state["active"]
+        toks, valid, active, counts = InferenceEngine._replicate(
+            mesh, toks, valid, state["active"], jnp.sum(counts, axis=0)
         )
-        return state, toks, valid, active
+        return state, toks, valid, active, counts
 
     @staticmethod
     def _deactivate_impl(state, keep):
@@ -4528,14 +4550,15 @@ class InferenceEngine:
             "waves_ahead": req.waves_ahead if first is not None else None,
         }
 
-    def _note_routing(self, chunk_data) -> None:
-        """A decode chunk's routing counters (the fifth value of
-        _chunk_impl for a model that dispatches tokens to experts; came
-        to the host in the boundary's own fetch) into the stats."""
+    def _note_chunk_counts(self, chunk_data) -> None:
+        """What a decode chunk counted on the device (the fifth value of
+        _chunk_impl / _paged_chunk_impl, CHUNK_COUNTERS' order; came to
+        the host in the boundary's own fetch) into the stats. The
+        ragged and speculative waves report none."""
         if len(chunk_data) < 4:
             return
         with self.stats.lock:
-            for name, v in zip(MOE_COUNTERS, chunk_data[3]):
+            for name, v in zip(CHUNK_COUNTERS, chunk_data[3]):
                 setattr(self.stats, name, getattr(self.stats, name) + int(v))
 
     def _process_chunk(self, toks_h, valid_h, active_h, roster) -> None:  # graftlint: holds(_book)
@@ -4679,13 +4702,15 @@ class InferenceEngine:
         tok = req.first_token_at
         phases = self._timings(req)
         phases["decode_ms"] = None if tok is None else 1000.0 * (now - tok)
-        if self._counts_routing(self.cfg):
-            # The engine's running routing counters as this request
-            # ended (EngineStats.moe_*): two lines' difference is what
-            # routing did in decode between them.
-            with self.stats.lock:
-                phases.update({name: getattr(self.stats, name)
-                               for name in MOE_COUNTERS})
+        # The engine's running device-side counters as this request
+        # ended (EngineStats.sampler_*, and moe_* for a model that
+        # dispatches tokens to experts): two lines' difference is what
+        # the sampler and routing did in decode between them.
+        names = (CHUNK_COUNTERS if self._counts_routing(self.cfg)
+                 else SAMPLER_COUNTERS)
+        with self.stats.lock:
+            phases.update({name: getattr(self.stats, name)
+                           for name in names})
         access_log.info("request %s", json.dumps({
             "rid": req.rid,
             "outcome": req.outcome or "ok",
@@ -5225,8 +5250,10 @@ class InferenceEngine:
         with jax.profiler.TraceAnnotation("fetch.process"):
             self._process_admits(admits, admit_data, admit_ready)
             if chunk_data is not None:
+                # counts first: a request this chunk ends writes its
+                # access line with the chunk's own steps in the totals
+                self._note_chunk_counts(chunk_data)
                 self._process_chunk(*chunk_data[:3], roster)
-                self._note_routing(chunk_data)
             if self._spec:
                 self._spec_post_process(chunk_data, roster)
             self._record_wave_timing(timing)
@@ -5457,8 +5484,8 @@ class InferenceEngine:
                     get_s = f1 - f0 if was_ready else None
                     self._process_admits(admits, admit_data, admit_ready)
                     if chunk_data is not None:
+                        self._note_chunk_counts(chunk_data)
                         self._process_chunk(*chunk_data[:3], roster)
-                        self._note_routing(chunk_data)
                     self._record_wave_timing(timing)
                     if roofing:
                         self._roof_note_boundary(timing, f0, f1)
@@ -5715,7 +5742,7 @@ class InferenceEngine:
             roster = self._roster()
             self._dispatch_wreck = _PendingWave(admits, None, roster, None)
             n = self._pick_chunk()
-            # (state, toks, valid, active_after[, routing counters])
+            # (state, toks, valid, active_after, device-side counts)
             out = self._dispatch_decode_chunk(n)
             self._state, toks, valid, active_after = out[:4]
             chunk_handles = tuple(out[1:])

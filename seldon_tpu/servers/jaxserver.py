@@ -1041,6 +1041,15 @@ class JAXServer(SeldonComponent):
             # What routing did in decode (0 unless the model dispatches
             # tokens to experts): touched / sparse_layer_steps = experts
             # a sparse layer reads per decode step.
+            # Decode steps by the tier the sampler took (exclusive:
+            # they add up to the steps whose tier a chunk reported).
+            *({"type": "GAUGE", "key": "jaxserver_sampler_steps_total",
+               "value": float(v), "tags": {"tier": tier}}
+              for tier, v in (
+                  ("greedy", s["sampler_steps"] - s["sampler_drawn_steps"]),
+                  ("drawn", s["sampler_drawn_steps"]
+                   - s["sampler_masked_steps"]),
+                  ("masked", s["sampler_masked_steps"]))),
             *({"type": "GAUGE", "key": "jaxserver_" + name,
                "value": float(s[name])} for name in MOE_COUNTERS),
             {"type": "GAUGE", "key": "jaxserver_prefix_hits",

@@ -92,15 +92,22 @@ def test_dispatch_fault_fails_waiter_and_engine_recovers(paged):
     """Chaos certainty (dispatch_fail=1.0) mid-decode: the waiter gets
     a typed error, never hangs; chaos off again, the rebuilt device
     state serves bit-identical greedy output and nothing leaked."""
-    ekw = dict(decode_chunk=1, min_chunk=1, adaptive_chunk=False)
+    # The fault is armed from this thread after the first token, so
+    # the request must still have dispatches ahead of it then: 100
+    # single-step ones. PROMPT's own greedy continuation ends at its
+    # third token (EOS), which left two steps, about 2 ms, to arm in:
+    # a race that a loaded machine lost.
+    n_new, long_prompt = 100, list(range(5, 29))
+    ekw = dict(decode_chunk=1, min_chunk=1, adaptive_chunk=False,
+               max_seq_len=128)
     if paged:
         ekw.update(PAGED)
     eng = _engine(**ekw)
     try:
         want = eng.generate_blocking(PROMPT, GREEDY)["token_ids"]
 
-        q = eng.submit(PROMPT, SamplingParams(
-            temperature=0.0, max_new_tokens=40))
+        q = eng.submit(long_prompt, SamplingParams(
+            temperature=0.0, max_new_tokens=n_new))
         first = q.get(timeout=120)
         assert "error" not in first
         # Attribute store is atomic; the scheduler reads it per dispatch.
@@ -109,7 +116,7 @@ def test_dispatch_fault_fails_waiter_and_engine_recovers(paged):
         assert err is not None, "faulted request must error, not complete"
         assert err["kind"] == "internal"
         assert eng._chaos.snapshot()["dispatch_faults"] >= 1
-        assert len(first["tokens"]) + toks < 40
+        assert len(first["tokens"]) + toks < n_new
 
         eng._chaos = None
         got = eng.generate_blocking(PROMPT, GREEDY)["token_ids"]

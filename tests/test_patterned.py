@@ -88,7 +88,8 @@ def test_layer_plan_scans_by_period_and_counts_layers_by_kind():
 def test_cache_spec_holds_kv_for_attention_layers_and_state_for_conv_layers():
     cfg = two_periods()
     spec = T.cache_spec(cfg, 4, 32)
-    assert spec["k"].shape == spec["v"].shape == (2, 4, cfg.n_kv_heads, 32, cfg.head_dim)
+    # one row a token: all its KV heads side by side (kv_heads_per_row)
+    assert spec["k"].shape == spec["v"].shape == (2, 4, 1, 32, cfg.n_kv_heads * cfg.head_dim)
     assert spec["conv"].shape == (8, 4, cfg.conv_kernel - 1, cfg.d_model)
     assert (spec["k"].kind, spec["k"].time_axis, spec["conv"].kind,
             spec["conv"].time_axis) == ("kv", 3, "conv", None)
@@ -201,6 +202,48 @@ def test_conv_state_after_prefill_is_the_state_decode_builds_token_by_token():
     _, one = T.prefill(params, seq[:, :1], jnp.asarray([1]), one, cfg)
     assert float(jnp.max(jnp.abs(one["conv"][:, :, 0]))) == 0.0
     assert float(jnp.max(jnp.abs(one["conv"][:, :, 1]))) > 0.0
+
+
+@pytest.mark.parametrize("side", [1, 2, 4])
+def test_decode_attention_over_rows_of_several_heads_is_the_head_major_one(side):
+    """cache rows that hold `side` KV heads side by side (the patterned
+    stack stores all of a token's heads in one row) against one head a
+    row, in float32: same weighted values for every query head."""
+    B, T_, Hkv, G, Dh = 3, 16, 4, 2, 8
+    ks = jax.random.split(jax.random.key(7), 5)
+    q = jax.random.normal(ks[0], (B, 1, Hkv * G, Dh))
+    ck, cv = (jax.random.normal(k, (B, Hkv, T_, Dh)) for k in ks[1:3])
+    kf, vf = (jax.random.normal(k, (B, 1, Hkv, Dh)) for k in ks[3:5])
+    mask_lt = jnp.arange(T_)[None, None, :] < jnp.asarray([5, 16, 1])[:, None, None]
+
+    def rows(c):  # [B, Hkv, T, Dh] -> [B, Hkv / side, T, side * Dh]
+        return c.reshape(B, Hkv // side, side, T_, Dh).transpose(
+            0, 1, 3, 2, 4).reshape(B, Hkv // side, T_, side * Dh)
+    want = T.gqa_attention_decode(q, ck, cv, kf, vf, mask_lt)
+    got = T.gqa_attention_decode(q, rows(ck), rows(cv), kf, vf, mask_lt)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
+    # what prefill and decode write is that row: head h at lanes h * Dh
+    fresh = T._kv_rows(kf, side)
+    np.testing.assert_array_equal(
+        np.asarray(fresh[:, 0, 0, :Dh]), np.asarray(kf[:, 0, 0]))
+    np.testing.assert_array_equal(
+        np.asarray(fresh[:, 0, -1, -Dh:]), np.asarray(kf[:, 0, -1]))
+
+
+def test_decode_writes_each_layers_token_row_and_no_other():
+    """The step's scatter indexes layer, slot and position: after one
+    decode step every attention layer's row at (slot, pos) is new and
+    nothing else of the slab moved."""
+    cfg = two_periods()
+    params = T.init_params(cfg, jax.random.key(0))
+    cache = jax.tree.map(lambda a: a + 1, T.init_cache(cfg, 3, 8))
+    pos = jnp.asarray([2, 5, 0])
+    _, new = T.decode_step(params, jnp.asarray([3, 4, 5]), pos, cache, cfg)
+    for key in ("k", "v"):
+        moved = np.asarray(new[key] != cache[key]).any(axis=(2, 4))  # [La, B, T]
+        want = np.zeros_like(moved)
+        want[:, np.arange(3), np.asarray(pos)] = True
+        np.testing.assert_array_equal(moved, want)
 
 
 def test_right_padded_rows_take_their_state_at_their_own_length():

@@ -284,6 +284,14 @@ def test_one_access_line_per_finished_request_whatever_the_outcome(caplog):
         assert row["waves_ahead"] is None and row["decode_ms"] is None
     assert "timings" in first
     assert mine[long.rid]["first_token_held_ms"] is not None
+    # the sampler's running totals ride on every line, whatever its
+    # outcome (tests/test_sampler_tiers.py reads them); a model without
+    # token -> expert dispatch writes no routing counters
+    for row in rows:
+        assert row["sampler_steps"] >= row["sampler_drawn_steps"] \
+            >= row["sampler_masked_steps"] == 0
+        assert not any(k.startswith("moe_") for k in row)
+    assert done["sampler_steps"] >= 1
 
 
 def test_silenced_access_log_formats_nothing(monkeypatch):
@@ -481,6 +489,15 @@ def test_metrics_are_current_when_scraped(rest_unit):
         assert _gauge(text, f"jaxserver_ttft_{k}_count") == snap[k][1]
         assert _gauge(text, f"jaxserver_ttft_{k}_sum") == pytest.approx(
             snap[k][0])
+    # decode steps by the sampler's tier: greedy traffic, so all of the
+    # steps a chunk has reported stand under "greedy"
+    tiers = {t: _gauge(text.replace('{tier="%s"}' % t, ""),
+                       "jaxserver_sampler_steps_total")
+             for t in ("greedy", "drawn", "masked")}
+    st = srv.engine.stats.snapshot()
+    assert tiers == {"greedy": st["sampler_steps"], "drawn": 0.0,
+                     "masked": 0.0}
+    assert 0 < tiers["greedy"] <= _gauge(text, "jaxserver_decode_steps")
     # what bounds device_wait stands beside the sums
     assert 2 <= _gauge(text, "jaxserver_sched_depth") <= 5
     assert _gauge(text, "jaxserver_sched_wave_period_ms") >= 0.0

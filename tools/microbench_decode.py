@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from seldon_tpu.models import get_config, init_params, transformer
-from seldon_tpu.models.sampling import sample_per_row
+from seldon_tpu.models.sampling import live_knobs, sample_per_row
 
 import os
 PRESET = os.environ.get("MB_PRESET", "bench-1b")
@@ -74,9 +74,8 @@ def chunk_impl(params, state, *, cfg, n_steps):
             lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
         )(carry["seeds"], carry["pos"])
         tok = sample_per_row(
-            logits, keys, carry["temp"],
-            jnp.where(run, carry["top_k"], 0),
-            jnp.where(run, carry["top_p"], 1.0),
+            logits, keys,
+            *live_knobs(run, carry["temp"], carry["top_k"], carry["top_p"]),
         )
         tok = jnp.where(run, tok, cfg.pad_token_id)
         pos = carry["pos"] + run.astype(jnp.int32)

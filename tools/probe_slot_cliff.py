@@ -66,14 +66,12 @@ def probe(params, cfg, slots: int, paged: bool = False) -> None:
         table = jnp.asarray(eng.table_host_snapshot())
 
         def step(state):
-            s2, _, _, _ = chunk1(params, state, table)
-            return s2
+            return chunk1(params, state, table)[0]
     else:
         chunk1 = eng._jit_chunks[1]  # decode_chunk=1 -> single-step rung
 
         def step(state):
-            s2, _, _, _ = chunk1(params, state)
-            return s2
+            return chunk1(params, state)[0]
 
     # Slope-fit per-step time (a host sync's fixed cost swamps
     # per-call timing; chained calls cancel it).
