@@ -63,6 +63,21 @@ def window(obs):
     return rows
 
 
+def window_delta(obs, fields):
+    """The growth over the measured window of the engine's running
+    counters `fields`, which every access line carries as its request
+    ended: last line minus first of the window's requests, in the order
+    they ended (fields[0] only grows). None where fewer than two lines
+    carry them or fields[0] did not move."""
+    rows = [r for r in window(obs) or ()
+            if all(isinstance(r.get(f), (int, float)) for f in fields)]
+    if len(rows) < 2:
+        return None
+    rows.sort(key=lambda r: r[fields[0]])
+    d = {f: rows[-1][f] - rows[0][f] for f in fields}
+    return d if d[fields[0]] > 0 else None
+
+
 def mid80(obs, key):
     """10 %-trimmed mean of one field over the window's requests, the
     statistic of ttft_mid80_ms."""

@@ -14,6 +14,7 @@ import re
 import time
 
 import _access
+import _trace
 
 FIELDS = ("moe_sparse_layer_steps", "moe_experts_touched", "moe_assignments")
 # megablox's pallas_call, as benchmark/xplane.py cleans an XLA Ops event:
@@ -21,21 +22,9 @@ FIELDS = ("moe_sparse_layer_steps", "moe_experts_touched", "moe_assignments")
 GROUPED_OP = re.compile(r"^gmm(\.\d+)?_bf16_(\d+)_(\d+)_")
 
 
-def window_delta(obs):
-    """The counters' growth over the measured window: last line minus
-    first of the window's requests, in the order they ended."""
-    rows = [r for r in _access.window(obs) or ()
-            if all(isinstance(r.get(f), (int, float)) for f in FIELDS)]
-    if len(rows) < 2:
-        return None
-    rows.sort(key=lambda r: r[FIELDS[0]])
-    d = {f: rows[-1][f] - rows[0][f] for f in FIELDS}
-    return d if d[FIELDS[0]] > 0 else None
-
-
 def experts_touched(obs):
     """Mean distinct experts one sparse layer reads per decode step."""
-    d = window_delta(obs)
+    d = _access.window_delta(obs, FIELDS)
     return d["moe_experts_touched"] / d["moe_sparse_layer_steps"] if d else None
 
 
@@ -86,16 +75,17 @@ def slice_delta(obs):
 
 def decode_grouped_ops(obs):
     """[(seconds in the traced slice, output columns)] of the grouped
-    expert products of the DECODE program among the trace's listed ops:
-    those whose row count is the slab's assignment list (slots x experts
-    per token; a prefill's list is longer but for the rare two-row group
-    of the shortest bucket, whose time is noise beside a slice of
-    decode)."""
-    tr, k = obs.trace, (obs.cfg or {}).get("num_experts_per_tok")
-    if not tr or not k or not obs.slots:
+    expert products of the DECODE program: found by name among all the
+    device ops that ran inside that program (_trace.program_ops), whatever
+    their rank, at the row count of the slab's assignment list (slots x
+    experts per token). An admission's products are another program's,
+    also where its shortest group has as many rows and XLA numbers the
+    instruction alike."""
+    k = (obs.cfg or {}).get("num_experts_per_tok")
+    if not k or not obs.slots:
         return []
     out = []
-    for name, seconds in tr.get("device_ops", ()):
+    for name, seconds in _trace.program_ops(obs, _trace.DECODE).items():
         m = GROUPED_OP.match(name)
         if m and int(m.group(2)) == obs.slots * k:
             out.append((seconds, int(m.group(3))))

@@ -15,19 +15,7 @@ import _access
 FIELDS = ("sampler_steps", "sampler_drawn_steps", "sampler_masked_steps")
 
 
-def window_delta(obs):
-    """The counters' growth over the measured window: last line minus
-    first of the window's requests, in the order they ended."""
-    rows = [r for r in _access.window(obs) or ()
-            if all(isinstance(r.get(f), (int, float)) for f in FIELDS)]
-    if len(rows) < 2:
-        return None
-    rows.sort(key=lambda r: r[FIELDS[0]])
-    d = {f: rows[-1][f] - rows[0][f] for f in FIELDS}
-    return d if d[FIELDS[0]] > 0 else None
-
-
 def share(obs, field):
     """Percent of the window's decode steps counted under `field`."""
-    d = window_delta(obs)
+    d = _access.window_delta(obs, FIELDS)
     return 100.0 * d[field] / d["sampler_steps"] if d else None

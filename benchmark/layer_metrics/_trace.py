@@ -15,6 +15,14 @@ def module(obs, names):
     return None
 
 
+def program_ops(obs, names):
+    """{cleaned op name: seconds} of one program's device
+    ops in the traced slice (xplane's ops_by_program), every op whatever
+    its rank; {} where the trace holds no such program."""
+    by = (obs.trace or {}).get("ops_by_program") or {}
+    return next((by[n] for n in names if n in by), {})
+
+
 def steps_per_dispatch(obs):
     return obs.decode_steps / obs.decode_dispatches if obs.decode_dispatches else None
 

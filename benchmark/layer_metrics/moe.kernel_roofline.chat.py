@@ -7,10 +7,10 @@ def read(obs):
     """Least time the decode steps' grouped expert products need over
     their device time, both of the traced slice.
 
-    Device time: the trace's listed ops that carry the grouped kernel's
-    name at the decode program's shape (_moe.decode_grouped_ops; the
-    reduction lists the ten longest ops, so this is the decode products
-    among them: the longest of their kind, which reads low if anything).
+    Device time: every device op that ran inside the decode program and
+    carries the grouped kernel's name at that program's shape
+    (_moe.decode_grouped_ops, by name from the reduction's table of all
+    ops by program: where the kernel ranks among them does not matter).
     Need: each listed op is one of the products of one position of the
     layer period, run once per repeat of the period in every decode step
     of the slice (steps = executions of _chunk_impl x steps per chunk;
@@ -22,7 +22,7 @@ def read(obs):
     counters (_moe.slice_delta): the experts a step reads follow its live
     rows, which a slice of three seconds holds a quarter more or fewer of
     than the window does, and a need reckoned at the window's mean over a
-    time taken in the slice read 79 to 107 %. None when no listed op
+    time taken in the slice read 79 to 107 %. None when no op
     carries the name or the counters do not cover the slice."""
     import _moe
     import _trace

@@ -522,6 +522,9 @@ class Run:
                 "an untraced run's): " + json.dumps({k: v["value"] for k, v in e2e.items()}))
         else:
             ms = metrics.end_to_end(self.bench, name, o)
+            say("per-layer readings that need no trace: " + json.dumps(
+                {k: v["value"] for k, v in
+                 metrics.per_layer(self.bench, HERE, name, o).items()}))
         bad = [k for k, v in ms.items() if not metrics.finite(v["value"])]
         if bad:
             self.problems.append(f"no finite value for {bad}")

@@ -4,6 +4,7 @@ the closed forms against values worked out by hand from the published
 widths, the control's grid, and the two readers its cell adds."""
 import json
 import os
+import re
 
 import pytest
 
@@ -59,6 +60,29 @@ def test_every_published_number_is_kept_and_the_cut_is_depth_alone():
     assert cfg["layer_types"] == pub["layer_types"][:10] and cfg["num_hidden_layers"] == 10
     assert cfg["layer_types"][2:6] == cfg["layer_types"][6:10] == \
         ["full_attention", "conv", "conv", "conv"]
+
+
+def test_the_cell_runs_at_the_rate_its_why_names_and_holds_both_limits():
+    """benchmark/cells/lfm2.chat.json against the cell's entry: the offered
+    rate is the number the entry's `why` names and is the fraction of the
+    knee that stands beside it, whatever fraction that is; `limit` holds
+    both keys, each by the cells' rule: 2.2 x the TTFT and 2 x the TPOT
+    read at that rate (`limit_from`, the readings it was set from), rounded
+    by at most a twentieth."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        (cell,) = [w for w in json.load(f)["workloads"] if w["name"] == "lfm2.chat"]
+    with open(os.path.join(BENCH, "cells", "lfm2.chat.json")) as f:
+        over = json.load(f)
+    m = re.search(r"at ([0-9.]+) req/s \(([0-9.]+) of its knee, ~?([0-9.]+)\)", cell["why"])
+    assert m, cell["why"]
+    rate, fraction, knee = (float(g) for g in m.groups())
+    assert rate == over["rate_rps"]
+    assert 0 < fraction < 1
+    assert rate == pytest.approx(fraction * knee, abs=0.051)  # rates go by 0.1
+    assert sorted(over["limit"]) == sorted(over["limit_from"]) == ["tpot_ms", "ttft_ms"]
+    for key, times in (("ttft_ms", 2.2), ("tpot_ms", 2.0)):
+        assert over["limit"][key] == pytest.approx(times * over["limit_from"][key], rel=0.05)
+    assert len(cell["why"]) <= 200
 
 
 def test_key_map_gives_the_patterned_fields_and_survives_a_json_round_trip(fam):
@@ -147,6 +171,7 @@ def test_readers_return_nothing_where_the_program_writes_nothing(fam, tmp_path, 
     roof = metrics.load_reader(BENCH, "moe.kernel_roofline.chat")
     obs = _Obs(cfg=_cfg(), family=fam, cell={"name": "no-such-cell"}, slots=64,
                trace={"device_ops": [["fusion.1_bf16_64_2048", 0.5]],
+                      "ops_by_program": {"_chunk_impl": {"fusion.1_bf16_64_2048": 0.5}},
                       "modules": {"_chunk_impl": {"count": 10, "total_s": 1.0,
                                                   "median_s": 0.1}}},
                decode_steps=400.0, decode_dispatches=100.0, rows_per_step=4.0,
@@ -176,10 +201,17 @@ def test_kernel_roofline_reads_the_decode_products_by_name_and_shape(fam, tmp_pa
     import _moe
     roof = metrics.load_reader(BENCH, "moe.kernel_roofline.chat")
     cfg = _cfg()
-    ops = [["sort.45_f32_64_65536_1_0", 1.0],
-           ["gmm.5_bf16_256_2048_1_0_T_8_128_2_1_S_1_custom-call_s32", 0.06],
-           ["gmm.4_bf16_256_1536_1_0_T_8_128_2_1_S_1_custom-call_s32", 0.06],
-           ["gmm.9_bf16_16384_1536_1_0_T_8_128", 0.2]]       # a prefill's: not counted
+    # every op of the slice by program and name, in seconds. An admission's
+    # products are not counted: a prefill's longer list, nor the two-row
+    # group of the shortest bucket (2 x 32 x 4 rows), which XLA numbers like
+    # the decode program's own gmm.4
+    ops = {"_chunk_impl": {
+               "sort.45_f32_64_65536_1_0": 1.0,
+               "gmm.5_bf16_256_2048_1_0_T_8_128_2_1_S_1_custom-call_s32": 0.06,
+               "gmm.4_bf16_256_1536_1_0_T_8_128_2_1_S_1_custom-call_s32": 0.06},
+           "_admit_impl": {
+               "gmm.9_bf16_16384_1536_1_0_T_8_128": 0.2,
+               "gmm.4_bf16_256_1536_1_0_T_8_128_2_1_S_1_custom-call_s32": 0.001}}
     a = time.perf_counter()
     wall = a + (time.time() - time.perf_counter())
     log = tmp_path / "unit.log"
@@ -198,7 +230,8 @@ def test_kernel_roofline_reads_the_decode_products_by_name_and_shape(fam, tmp_pa
     log.write_text("startup {}\n" + text)
     monkeypatch.setattr(_access, "log_path", lambda obs: str(log))
     obs = _Obs(cfg=cfg, family=fam, cell={"name": "no-such-cell"}, slots=64,
-               trace={"device_ops": ops, "slice": (a, a + 3.0),
+               trace={"device_ops": [["sort.45_f32_64_65536_1_0", 1.0]],  # the ranking holds no gmm
+                      "ops_by_program": ops, "slice": (a, a + 3.0),
                       "modules": {"_chunk_impl": {"count": 60, "total_s": 2.8,
                                                   "median_s": 0.044}}},
                decode_steps=400.0, decode_dispatches=100.0, rows_per_step=5.0,

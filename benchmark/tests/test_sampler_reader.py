@@ -37,8 +37,9 @@ def test_share_is_the_window_difference_of_the_counters(cell, per_request, want)
     obs, _, work = cell
     (work / "unit.log").write_text("\n".join(counted(obs, per_request)) + "\n")
     assert read(NAME, obs) == pytest.approx(want)
+    import _access
     import _sampler
-    d = _sampler.window_delta(obs)
+    d = _access.window_delta(obs, _sampler.FIELDS)
     assert d["sampler_steps"] == (N - 1) * per_request[0]
     assert _sampler.share(obs, "sampler_drawn_steps") == pytest.approx(
         100.0 * per_request[1] / per_request[0])

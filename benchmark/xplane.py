@@ -7,7 +7,10 @@ prints one JSON object: busy_s (union of device-op intervals, averaged
 over device planes), window_s (first device event to last), modules
 {name: {count, total_s, median_s}} from the "XLA Modules" line (one event
 per execution of a jitted program), the device ops that took most time,
-and the longest idle gaps named by the program that ran next.
+every device op's total seconds by the program it ran in and its
+cleaned name (ops_by_program: a reader that looks for a kernel
+finds it whatever its rank, and apart from the op another program numbers
+alike), and the longest idle gaps named by the program that ran next.
 
 The engine jits functools.partial objects, which carry no name: XLA calls
 every one of its programs "jit__unknown(<program id>)". Programs without
@@ -18,6 +21,7 @@ the program names its jits, the real names come through unchanged."""
 
 from __future__ import annotations
 
+import bisect
 import glob
 import json
 import os
@@ -80,7 +84,9 @@ def label_unnamed(names: List[str], counts: Dict[str, int]) -> Dict[str, str]:
 def reduce_planes(planes) -> Dict:
     """planes: [(plane name, [(line name, [(event name, start_ns, dur_ns)])])]"""
     busy, windows = [], []
-    ops: Dict[str, int] = {}
+    # (program, op name) -> duration: XLA numbers each program's
+    # instructions from its own count, so two programs can hold a "gmm.5"
+    ops: Dict[Tuple[str, str], int] = {}
     runs: List[Tuple[str, int]] = []      # (program, duration)
     raw_gaps: List[Tuple[int, str]] = []  # (gap, program that ran next)
     n_planes = 0
@@ -96,10 +102,15 @@ def reduce_planes(planes) -> Dict:
         first = min(s for _, s, _ in op_events)
         last = max(s + d for _, s, d in op_events)
         windows.append((last - first) / 1e9)
-        for name, _, d in op_events:
-            if not CONTAINER.match(name):
-                ops[name] = ops.get(name, 0) + d
         mod_events = sorted(lines.get(MODULES_LINE, []), key=lambda e: e[1])
+        starts = [s for _, s, _ in mod_events]
+        for name, s, d in op_events:
+            if not CONTAINER.match(name):
+                # a core runs one program at a time: the op is of the last
+                # program that started before it ("" before the first)
+                i = bisect.bisect_right(starts, s) - 1
+                prog = module_name(mod_events[i][0]) if i >= 0 else ""
+                ops[prog, name] = ops.get((prog, name), 0) + d
         for name, _, d in mod_events:
             runs.append((module_name(name), d))
         for (_, s0, d0), (name, s1, _) in zip(mod_events, mod_events[1:]):
@@ -115,8 +126,14 @@ def reduce_planes(planes) -> Dict:
     gaps = [(g, "gap_before_" + label.get(n, n)) for g, n in raw_gaps]
     if not n_planes:
         return {"busy_s": 0.0, "window_s": 0.0, "modules": {}, "device_ops": [],
-                "idle_gaps": [], "device_planes": 0}
-    top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
+                "ops_by_program": {}, "idle_gaps": [], "device_planes": 0}
+    whole: Dict[str, int] = {}            # the ranking adds the programs up, as it did
+    by_program: Dict[str, Dict[str, float]] = {}
+    for (prog, n), d in ops.items():
+        whole[n] = whole.get(n, 0) + d
+        of = by_program.setdefault(label.get(prog, prog), {})
+        of[clean(n)] = of.get(clean(n), 0.0) + d / 1e9
+    ranked = sorted(whole.items(), key=lambda kv: -kv[1])
     return {
         "device_planes": n_planes,
         "busy_s": sum(busy) / n_planes,
@@ -124,7 +141,8 @@ def reduce_planes(planes) -> Dict:
         "modules": {m: {"count": len(d), "total_s": sum(d) / 1e9,
                         "median_s": statistics.median(d) / 1e9}
                     for m, d in mods.items()},
-        "device_ops": [[clean(n), d / 1e9] for n, d in top],
+        "device_ops": [[clean(n), d / 1e9] for n, d in ranked[:10]],
+        "ops_by_program": by_program,
         "idle_gaps": [[clean(n), g / 1e9]
                       for g, n in sorted(gaps, reverse=True)[:10]],
     }
