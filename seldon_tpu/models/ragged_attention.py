@@ -35,10 +35,10 @@ into one trace: the prefill phase is ``_paged_admit_chunk_impl`` with
 the resident-prefix width pinned to the FULL table (masking, not
 shape, hides the tail — f32 softmax with the -1e30 mask makes wider
 padding bit-neutral) and per-row occupancy masking; the decode phase
-is ``_paged_chunk_impl`` with one step. Sampling keys stay
-``fold_in(key(seed), plen)`` / ``fold_in(key(seed), pos + 1)``, int8
-KV scales ride along unchanged, so greedy outputs are bit-identical to
-the ragged-off engine — the migration gate tests/test_ragged.py pins.
+is ``_paged_chunk_impl`` with one step. A slot's keys, termination and
+arming are the engine's own (models/slot.py), int8 KV scales ride along
+unchanged, so greedy outputs are bit-identical to the ragged-off engine
+— the migration gate tests/test_ragged.py pins.
 
 Kernel legs (``RAGGED_KERNEL`` / EngineConfig.ragged_kernel —
 graftkern): the paragraph above describes ``kernel="masked"``, the
@@ -83,9 +83,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from seldon_tpu.models import transformer
+from seldon_tpu.models import slot, transformer
 from seldon_tpu.models.config import ModelConfig
-from seldon_tpu.models.sampling import live_knobs, sample_per_row
 from seldon_tpu.ops import ragged_paged_attention as rpa
 
 Cache = Dict[str, jnp.ndarray]
@@ -100,19 +99,6 @@ def token_buffer_size(max_slots: int, chunk: int) -> int:
     progress per slot, so TTFT under load ~ ceil(prompt / chunk) waves;
     HBM workspace and host-array traffic scale with the product."""
     return max_slots * chunk
-
-
-def _mask_state(old: State, new: State, mask: jnp.ndarray) -> State:
-    """Merge per-slot state writes under the occupancy mask: masked-out
-    rows keep every field bit-for-bit (``where`` on the [B] leaves; the
-    KV pool is excluded — its writes are trash-routed by position, not
-    masked here)."""
-    out = dict(old)
-    for key in ("last_tok", "pos", "active", "temp", "top_k", "top_p",
-                "seeds", "remaining"):
-        out[key] = jnp.where(mask, new[key], old[key])
-    out["cache"] = new["cache"]
-    return out
 
 
 def _prefill_logits_sparse(
@@ -132,7 +118,7 @@ def _prefill_logits_sparse(
     with the causal fresh suffix — no full-width gather, no
     [B, Sc, Smax] score slab. Same (logits, fresh-KV ys) contract as
     prefill_with_prefix; idle rows' pool walk is clamped to zero
-    blocks via `bound` (their outputs are discarded by _mask_state, so
+    blocks via `bound` (their outputs are discarded by slot.arm's mask, so
     only live rows pin parity). mode "sparse" runs the masked-MATCHED
     two-pass walk in gqa_attention's convention — int8 pool KV
     dequantized into the query dtype first, softmax weights rounded to
@@ -323,7 +309,7 @@ def ragged_prefill_phase(
 
     ``kernel`` swaps the attention head for the block-sparse walkers
     (module docstring "Kernel legs"); sampling, scatter and state
-    masking below are shared verbatim across legs. ``block_budget`` > 0
+    masking below are shared across legs. ``block_budget`` > 0
     bounds the sparse walk: a wave whose longest live row needs more
     blocks falls back to the masked head in-trace (lax.cond — one
     variant either way)."""
@@ -359,40 +345,19 @@ def ragged_prefill_phase(
             )
         else:
             logits, kv = sparse_head()
-    keys = jax.vmap(
-        lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-    )(seeds, plens)
-    first = sample_per_row(logits, keys, temps, top_ks, top_ps)
-    first_done = (
-        (first == cfg.eos_token_id)
-        | (max_news <= 1)
-        | (plens + 1 >= Smax)
-    )
+    first, first_done = slot.first_token(
+        logits, seeds, plens, temps, top_ks, top_ps, max_news, Smax, cfg)
     new_pos = jnp.minimum(plens, starts + Sc)
-    if cfg.kv_cache_dtype == "int8":
-        kq, ks = transformer._quantize_kv(kv["k"])
-        vq, vs = transformer._quantize_kv(kv["v"])
-        writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    else:
-        dt = pool["k"].dtype
-        writes = {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+    writes = transformer.kv_writes(kv, pool, cfg)
     spos = starts[:, None] + jnp.arange(Sc)[None, :]
     new_pool = transformer.paged_scatter_tokens(pool, writes, table,
                                                 spos)
-    new_state = _mask_state(
-        state,
-        {
-            "cache": new_pool,
-            "last_tok": first,
-            "pos": new_pos,
-            "active": finals & ~first_done,
-            "temp": temps,
-            "top_k": top_ks,
-            "top_p": top_ps,
-            "seeds": seeds,
-            "remaining": max_news - 1,
-        },
-        is_prefill,
+    # The KV pool is not masked: idle rows' writes are trash-routed by
+    # position.
+    new_state = slot.arm(
+        state, mask=is_prefill, cache=new_pool, first=first,
+        done=first_done, pos=new_pos, finals=finals, temps=temps,
+        top_ks=top_ks, top_ps=top_ps, seeds=seeds, max_news=max_news,
     )
     return new_state, first, first_done
 
@@ -405,10 +370,10 @@ def ragged_decode_phase(
     tp=None,
     kernel: str = "masked",
     block_budget: int = 0,
-) -> Tuple[State, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[State, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The wave's decode leg: ONE decode step over every slot, reading
     and writing KV through the block tables — ``_paged_chunk_impl``
-    with n_steps = 1 (the same lax.scan wrapper, so the primitive
+    with n_steps = 1 (the same slot.decode_chunk, so the primitive
     sequence — and therefore greedy argmax — matches the ragged-off
     engine exactly). Rows armed by this wave's prefill leg decode
     immediately, mirroring the off path where the decode chunk follows
@@ -417,13 +382,12 @@ def ragged_decode_phase(
     ``kernel`` != "masked" swaps paged_decode_step for the block-sparse
     step (inactive rows' pool walk clamps to zero blocks — their
     outputs and KV writes are already dead by the ``run`` mask and
-    trash routing); sampling and state updates are shared verbatim."""
+    trash routing). Returns slot.decode_chunk's (state, toks, valid,
+    counts)."""
     block = state["cache"]["k"].shape[3]
     Smax = table.shape[1] * block
 
-    def step(carry, _):
-        run = carry["active"]
-
+    def step_model(carry):
         def masked_step():
             return transformer.paged_decode_step(
                 params, carry["last_tok"], carry["pos"], carry["cache"],
@@ -431,50 +395,24 @@ def ragged_decode_phase(
             )
 
         if kernel == "masked":
-            logits, pool = masked_step()
-        else:
-            bound = jnp.where(run, carry["pos"], 0).astype(jnp.int32)
+            return masked_step()
+        bound = jnp.where(
+            carry["active"], carry["pos"], 0).astype(jnp.int32)
 
-            def sparse_step():
-                return _decode_step_sparse(
-                    params, carry["last_tok"], carry["pos"], bound,
-                    carry["cache"], table, cfg, kernel, tp=tp,
-                )
+        def sparse_step():
+            return _decode_step_sparse(
+                params, carry["last_tok"], carry["pos"], bound,
+                carry["cache"], table, cfg, kernel, tp=tp,
+            )
 
-            if block_budget > 0:
-                n_live = (jnp.max(bound) + block - 1) // block
-                logits, pool = jax.lax.cond(
-                    n_live <= block_budget, sparse_step, masked_step
-                )
-            else:
-                logits, pool = sparse_step()
-        keys = jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
-        )(carry["seeds"], carry["pos"])
-        tok = sample_per_row(
-            logits, keys,
-            *live_knobs(run, carry["temp"], carry["top_k"], carry["top_p"]),
-        )
-        tok = jnp.where(run, tok, cfg.pad_token_id)
-        pos = carry["pos"] + run.astype(jnp.int32)
-        remaining = carry["remaining"] - run.astype(jnp.int32)
-        done = run & (
-            (tok == cfg.eos_token_id)
-            | (remaining <= 0)
-            | (pos >= Smax - 1)
-        )
-        new_carry = {
-            **carry,
-            "cache": pool,
-            "last_tok": jnp.where(run, tok, carry["last_tok"]),
-            "pos": pos,
-            "active": carry["active"] & ~done,
-            "remaining": remaining,
-        }
-        return new_carry, (tok, run)
+        if block_budget > 0:
+            n_live = (jnp.max(bound) + block - 1) // block
+            return jax.lax.cond(
+                n_live <= block_budget, sparse_step, masked_step
+            )
+        return sparse_step()
 
-    state, (toks, valid) = jax.lax.scan(step, state, None, length=1)
-    return state, toks, valid
+    return slot.decode_chunk(step_model, state, 1, Smax, cfg)
 
 
 def ragged_wave(
@@ -495,13 +433,14 @@ def ragged_wave(
     tp=None,
     kernel: str = "masked",
     block_budget: int = 0,
-) -> Tuple[State, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[State, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray,
+           jnp.ndarray]:
     """One full unified wave: prefill leg then decode leg in a single
     trace (ONE dispatch, ONE compiled variant). Returns
-    ``(state, first [B], first_done [B], toks [1, B], valid [1, B])``
-    — first/first_done are slot-indexed (the caller reads row
-    ``req.slot``), toks/valid flow through the engine's chunk-boundary
-    processing unchanged.
+    ``(state, first [B], first_done [B], toks [1, B], valid [1, B],
+    counts)`` — first/first_done are slot-indexed (the caller reads row
+    ``req.slot``), toks/valid/counts flow through the engine's
+    chunk-boundary processing unchanged.
 
     Sparse/pallas kernels additionally skip the WHOLE prefill leg on
     decode-only waves via a traced ``lax.cond`` — the dominant masked-
@@ -532,8 +471,8 @@ def ragged_wave(
         state, first, first_done = jax.lax.cond(
             jnp.any(is_prefill), run_prefill, skip_prefill, state
         )
-    state, toks, valid = ragged_decode_phase(
+    state, toks, valid, counts = ragged_decode_phase(
         params, state, table, cfg, tp=tp, kernel=kernel,
         block_budget=block_budget,
     )
-    return state, first, first_done, toks, valid
+    return state, first, first_done, toks, valid, counts

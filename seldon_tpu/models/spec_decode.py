@@ -5,11 +5,12 @@ throughput: a cheap drafter proposes ``k`` tokens per live slot and the
 target model scores all ``k + 1`` positions in ONE wide dispatch
 (``verify_wave``) instead of ``k + 1`` sequential decode steps. Because
 this engine's sampling is deterministic-per-row — every emitted token
-is keyed ``fold_in(key(seed), pos + 1)`` — verification is EXACT, not
-probabilistic: the wave samples the target's own token at each
-position with the sequential keys and accepts drafts only while they
-match, so the emitted stream is bit-identical to the spec-off engine
-for ANY temperature, not just greedy. The draft only ever decides how
+is keyed by its row's seed and position (``slot.step_key``) —
+verification is EXACT, not probabilistic: the wave samples the
+target's own token at each position with the sequential keys and
+accepts drafts only while they match, so the emitted stream is
+bit-identical to the spec-off engine for ANY temperature, not just
+greedy. The draft only ever decides how
 many sequential steps are skipped, never what is emitted.
 
 Numerics: sequential decode computes position ``p`` by attending
@@ -42,9 +43,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from seldon_tpu.models import transformer
+from seldon_tpu.models import slot, transformer
 from seldon_tpu.models.config import ModelConfig
-from seldon_tpu.models.sampling import live_knobs, sample_per_row
 from seldon_tpu.ops import ragged_paged_attention as rpa
 
 Cache = Dict[str, jnp.ndarray]
@@ -289,22 +289,23 @@ def verify_wave(
     tp=None,
     kernel: str = "masked",
     block_budget: int = 0,
-) -> Tuple[State, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[State, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One speculative verify wave over all B slots.
 
     Inputs per wave row are ``[last_tok, d_1 .. d_k]`` at positions
     ``pos .. pos + k``; the target's token at each position is sampled
-    with the sequential key ``fold_in(key(seed), pos_i + 1)`` and
+    with the sequential key (``slot.step_key`` at ``pos_i``) and
     drafts are accepted while they MATCH — so every row emits between
     1 (first draft rejected: plain decode) and k + 1 (full acceptance
     + the bonus token) tokens, all bit-identical to sequential decode.
-    The per-step accept chain is unrolled host-side (k is static);
-    termination (EOS / budget / window) uses the decode chunk's exact
-    value-level rule, so a row finishing mid-prefix truncates its
-    acceptance chain the same way a finished row freezes a chunk.
+    The per-step accept chain is unrolled host-side (k is static) and
+    each link IS the decode chunk's step (``slot.decode_step``), so a
+    row finishing mid-prefix truncates its acceptance chain the same
+    way a finished row freezes a chunk.
 
-    Returns (state, toks [k+1, B], valid [k+1, B]) — valid columns are
-    True-prefixes, the _process_chunk contract.
+    Returns (state, toks [k+1, B], valid [k+1, B], counts) — valid
+    columns are True-prefixes and counts the k + 1 steps' summed, the
+    _process_chunk contract.
 
     ``kernel`` != "masked" swaps the layer scan for the block-sparse
     twin (_run_blocks_verify_sparse). The docstring's ANY-temperature
@@ -363,47 +364,23 @@ def verify_wave(
     spos = jnp.where(wave[:, None], positions, Smax)
     new_pool = transformer.paged_scatter_tokens(pool, fresh, table, spos)
 
-    # Unrolled acceptance chain — each iteration IS the decode chunk's
-    # step body (same keys, same masking, same termination), with the
-    # chain broken at the first draft mismatch or finished row.
+    # Unrolled acceptance chain: decode steps over the wave's rows, the
+    # chain broken at the first draft mismatch or finished row (a row
+    # that ran is still active exactly when its token did not end it).
+    carry = state
     run = wave & state["active"]
-    pos = pos0
-    remaining = state["remaining"]
-    active = state["active"]
-    last = state["last_tok"]
     toks_list = []
     valid_list = []
+    counts = 0
     for i in range(Sq):
-        keys = jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
-        )(state["seeds"], pos)
-        tok = sample_per_row(
-            logits[:, i], keys,
-            *live_knobs(run, state["temp"], state["top_k"], state["top_p"]),
-        )
-        tok = jnp.where(run, tok, cfg.pad_token_id)
-        pos = pos + run.astype(jnp.int32)
-        remaining = remaining - run.astype(jnp.int32)
-        done = run & (
-            (tok == cfg.eos_token_id)
-            | (remaining <= 0)
-            | (pos >= Smax - 1)
-        )
-        last = jnp.where(run, tok, last)
-        active = active & ~done
+        carry, tok, ran, step_counts = slot.decode_step(
+            carry, logits[:, i], new_pool, Smax, cfg, run=run)
         toks_list.append(tok)
-        valid_list.append(run)
+        valid_list.append(ran)
+        counts = counts + step_counts
         if i < k:
-            run = run & ~done & (tok == drafts[:, i])
-    new_state = {
-        **state,
-        "cache": new_pool,
-        "last_tok": last,
-        "pos": pos,
-        "active": active,
-        "remaining": remaining,
-    }
-    return new_state, jnp.stack(toks_list), jnp.stack(valid_list)
+            run = run & carry["active"] & (tok == drafts[:, i])
+    return carry, jnp.stack(toks_list), jnp.stack(valid_list), counts
 
 
 def draft_tokens(

@@ -453,6 +453,18 @@ def _quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return q, scale.astype(jnp.bfloat16)
 
 
+def kv_writes(kv: Cache, cache: Cache, cfg: ModelConfig) -> Cache:
+    """Fresh k / v (a prefill's stacked ys, in the activation dtype) as
+    `cache` stores them: int8 with per-(token, head) scales, or a cast.
+    What every admission scatters, into a slab or through block tables."""
+    if cfg.kv_cache_dtype == "int8":
+        kq, ks = _quantize_kv(kv["k"])
+        vq, vs = _quantize_kv(kv["v"])
+        return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    dt = cache["k"].dtype
+    return {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+
+
 def _block(
     x: jnp.ndarray,
     bp: Dict[str, jnp.ndarray],
@@ -1135,13 +1147,7 @@ def prefill(
                                    ring_mesh=ring_mesh if use_ring else None,
                                    tp=tp)
     with jax.named_scope("attn/cache_update"):
-        if cfg.kv_cache_dtype == "int8":
-            kq, ks = _quantize_kv(kv["k"])
-            vq, vs = _quantize_kv(kv["v"])
-            writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-        else:
-            dt = cache["k"].dtype
-            writes = {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+        writes = kv_writes(kv, cache, cfg)
         if S == Smax:
             cache = writes
         else:

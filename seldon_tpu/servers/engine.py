@@ -44,11 +44,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from seldon_tpu.core import tracing
-from seldon_tpu.models import ragged_attention, sampling, tp_sharding
+from seldon_tpu.models import ragged_attention, tp_sharding
+from seldon_tpu.models import slot as slot_rules
 from seldon_tpu.models import transformer
 from seldon_tpu.models import spec_decode as spec_model
 from seldon_tpu.models.config import ModelConfig
-from seldon_tpu.models.sampling import SamplingParams, sample_per_row
+from seldon_tpu.models.sampling import SamplingParams
 from seldon_tpu.servers import compile_ledger, controller, cost_model
 from seldon_tpu.servers import flight_recorder, graftsan, hbm_ledger
 from seldon_tpu.servers import sched_ledger, shape_lattice, supervisor
@@ -1542,17 +1543,7 @@ class InferenceEngine:
             )
         else:
             cache = transformer.init_cache(self.cfg, B, Smax)
-        state = {
-            "cache": cache,
-            "last_tok": jnp.zeros((B,), jnp.int32),
-            "pos": jnp.zeros((B,), jnp.int32),
-            "active": jnp.zeros((B,), jnp.bool_),
-            "temp": jnp.ones((B,), jnp.float32),
-            "top_k": jnp.zeros((B,), jnp.int32),
-            "top_p": jnp.ones((B,), jnp.float32),
-            "seeds": jnp.zeros((B,), jnp.uint32),
-            "remaining": jnp.zeros((B,), jnp.int32),
-        }
+        state = slot_rules.fresh(cache, B)
         if self._tp is not None:
             # Commit the state onto the mesh (KV heads on 'tp', per-slot
             # scalars replicated) so the FIRST dispatch already sees the
@@ -1597,13 +1588,8 @@ class InferenceEngine:
         return_sub=False, tp=None,
     ):
         """Fused admission: prefill [G, Sb], scatter into cache slots, sample
-        first tokens, arm slot state. One dispatch, no host sync.
-
-        Each row's first token is keyed by fold_in(key(seed), plen), matching
-        the decode convention fold_in(key(seed), pos+1): the same seed and
-        prompt reproduce the completion regardless of co-batched traffic.
-        Duplicate slot indices (admission padding rows) carry identical data,
-        so the duplicate scatter writes are well-defined."""
+        first tokens, arm slot state (models/slot.py: key, termination
+        and arming rules). One dispatch, no host sync."""
         G, Sb = toks.shape
         sub = transformer.init_cache(cfg, G, Sb)
         if ring_mesh is not None:
@@ -1612,34 +1598,20 @@ class InferenceEngine:
                 ring_mesh = None
         logits, sub = transformer.prefill(params, toks, plens, sub, cfg,
                                           ring_mesh=ring_mesh, tp=tp)
-        keys = jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-        )(seeds, plens)
-        first = sample_per_row(logits, keys, temps, top_ks, top_ps)
-
         cache = state["cache"]
         Smax = cache["k"].shape[3]
-        first_done = (
-            (first == cfg.eos_token_id)
-            | (max_news <= 1)
-            | (plens + 1 >= Smax)
-        )
+        first, first_done = slot_rules.first_token(
+            logits, seeds, plens, temps, top_ks, top_ps, max_news, Smax, cfg)
         # Scatter EVERY cache array by its kind (transformer.cache_spec):
         # k/v + scales into the slots' first Sb positions, a fixed-size
         # state (a patterned stack's conv state) overwritten whole.
         new_cache = transformer.cache_scatter_slots(
             cfg, cache, sub, slots, Sb)
-        new_state = {
-            "cache": new_cache,
-            "last_tok": state["last_tok"].at[slots].set(first),
-            "pos": state["pos"].at[slots].set(plens),
-            "active": state["active"].at[slots].set(~first_done),
-            "temp": state["temp"].at[slots].set(temps),
-            "top_k": state["top_k"].at[slots].set(top_ks),
-            "top_p": state["top_p"].at[slots].set(top_ps),
-            "seeds": state["seeds"].at[slots].set(seeds),
-            "remaining": state["remaining"].at[slots].set(max_news - 1),
-        }
+        new_state = slot_rules.arm(
+            state, slots, cache=new_cache, first=first, done=first_done,
+            pos=plens, temps=temps, top_ks=top_ks, top_ps=top_ps,
+            seeds=seeds, max_news=max_news,
+        )
         if tp is not None:
             new_state = tp.constrain_state(new_state)
         first, first_done = InferenceEngine._replicate(
@@ -1663,8 +1635,7 @@ class InferenceEngine:
         _admit_impl.
 
         `toks` holds ONLY each prompt's uncached suffix [G, Sq]; `plens`
-        are FULL prompt lengths, so the first-token sampling key
-        fold_in(key(seed), plen) matches the cold path bit-for-bit.
+        are FULL prompt lengths (slot_rules.first_key).
         `prefix_kv` arrives in cache storage dtype [L, G, Hkv, Pb, (Dh)]
         (gathered host-side from the trie, zero-padded past each row's
         prefix_len — the padded tail is overwritten by the suffix scatter
@@ -1674,25 +1645,11 @@ class InferenceEngine:
         logits, kv = transformer.prefill_with_prefix(
             params, toks, plens, prefix_kv, prefix_lens, cfg, tp=tp
         )
-        keys = jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-        )(seeds, plens)
-        first = sample_per_row(logits, keys, temps, top_ks, top_ps)
-
         cache = state["cache"]
         Smax = cache["k"].shape[3]
-        first_done = (
-            (first == cfg.eos_token_id)
-            | (max_news <= 1)
-            | (plens + 1 >= Smax)
-        )
-        if cfg.kv_cache_dtype == "int8":
-            kq, ks = transformer._quantize_kv(kv["k"])
-            vq, vs = transformer._quantize_kv(kv["v"])
-            writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-        else:
-            dt = cache["k"].dtype
-            writes = {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+        first, first_done = slot_rules.first_token(
+            logits, seeds, plens, temps, top_ks, top_ps, max_news, Smax, cfg)
+        writes = transformer.kv_writes(kv, cache, cfg)
         Pb = prefix_kv["k"].shape[3]
         # Suffix rows land at absolute positions prefix_len + i; rows past
         # the cache window drop out of the scatter (jax default OOB mode).
@@ -1708,17 +1665,11 @@ class InferenceEngine:
             new_cache[key] = c.at[:, slots[:, None], :, spos].set(
                 jnp.moveaxis(writes[key], (1, 3), (0, 1))
             )
-        new_state = {
-            "cache": new_cache,
-            "last_tok": state["last_tok"].at[slots].set(first),
-            "pos": state["pos"].at[slots].set(plens),
-            "active": state["active"].at[slots].set(~first_done),
-            "temp": state["temp"].at[slots].set(temps),
-            "top_k": state["top_k"].at[slots].set(top_ks),
-            "top_p": state["top_p"].at[slots].set(top_ps),
-            "seeds": state["seeds"].at[slots].set(seeds),
-            "remaining": state["remaining"].at[slots].set(max_news - 1),
-        }
+        new_state = slot_rules.arm(
+            state, slots, cache=new_cache, first=first, done=first_done,
+            pos=plens, temps=temps, top_ks=top_ks, top_ps=top_ps,
+            seeds=seeds, max_news=max_news,
+        )
         if tp is not None:
             new_state = tp.constrain_state(new_state)
         first, first_done = InferenceEngine._replicate(
@@ -1737,10 +1688,9 @@ class InferenceEngine:
         against the KV that chunks 0..k-1 (and any prefix-cache hit)
         already scattered into the slot cache, then scatter the fresh
         suffix KV back. Rows with finals=True are each prompt's LAST
-        chunk: they sample the first token under the same
-        fold_in(key(seed), plen) key as _admit_impl — co-batched chunk
-        traffic cannot perturb greedy outputs — and arm the slot. Non-
-        final rows only deposit KV; their sampled token is discarded.
+        chunk: they sample the first token under _admit_impl's key —
+        co-batched chunk traffic cannot perturb greedy outputs — and
+        arm the slot. Non-final rows only deposit KV (slot_rules.arm).
 
         `prefix_width` (static) buckets how much resident KV the chunk
         attends to: the slice cache[:, slots, :, :W] covers every row's
@@ -1759,23 +1709,10 @@ class InferenceEngine:
         logits, kv = transformer.prefill_with_prefix(
             params, toks, plens, prefix_kv, starts, cfg, tp=tp
         )
-        keys = jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-        )(seeds, plens)
-        first = sample_per_row(logits, keys, temps, top_ks, top_ps)
-        first_done = (
-            (first == cfg.eos_token_id)
-            | (max_news <= 1)
-            | (plens + 1 >= Smax)
-        )
+        first, first_done = slot_rules.first_token(
+            logits, seeds, plens, temps, top_ks, top_ps, max_news, Smax, cfg)
         new_pos = jnp.minimum(plens, starts + Sc)
-        if cfg.kv_cache_dtype == "int8":
-            kq, ks = transformer._quantize_kv(kv["k"])
-            vq, vs = transformer._quantize_kv(kv["v"])
-            writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-        else:
-            dt = cache["k"].dtype
-            writes = {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+        writes = transformer.kv_writes(kv, cache, cfg)
         # Chunk rows land at absolute positions start + i (same advanced-
         # indexing shape as _admit_prefix_impl's suffix scatter); padding
         # rows duplicate a real row's slot + data, so duplicate writes
@@ -1787,17 +1724,11 @@ class InferenceEngine:
             )
             for key in cache
         }
-        new_state = {
-            "cache": new_cache,
-            "last_tok": state["last_tok"].at[slots].set(first),
-            "pos": state["pos"].at[slots].set(new_pos),
-            "active": state["active"].at[slots].set(finals & ~first_done),
-            "temp": state["temp"].at[slots].set(temps),
-            "top_k": state["top_k"].at[slots].set(top_ks),
-            "top_p": state["top_p"].at[slots].set(top_ps),
-            "seeds": state["seeds"].at[slots].set(seeds),
-            "remaining": state["remaining"].at[slots].set(max_news - 1),
-        }
+        new_state = slot_rules.arm(
+            state, slots, cache=new_cache, first=first, done=first_done,
+            pos=new_pos, finals=finals, temps=temps, top_ks=top_ks,
+            top_ps=top_ps, seeds=seeds, max_news=max_news,
+        )
         if tp is not None:
             new_state = tp.constrain_state(new_state)
         first, first_done = InferenceEngine._replicate(
@@ -1825,75 +1756,34 @@ class InferenceEngine:
 
     @staticmethod
     def _chunk_impl(params, state, *, cfg, n_steps, mesh=None, tp=None):
-        """`n_steps` decode iterations over every slot in one lax.scan.
-        Per-row termination (EOS / length budget / cache window) is
-        value-level: finished rows stop advancing and emit invalid tokens
-        until the chunk boundary. Returns (state, toks [K,B], valid [K,B])."""
+        """`n_steps` decode iterations over every slot in one lax.scan
+        (slot_rules.decode_chunk). Returns (state, toks [K,B], valid [K,B],
+        active [B], counts): counts int32 over the chunk, in
+        CHUNK_COUNTERS' order: steps, steps that drew, steps that masked;
+        a routed model adds sparse-layer steps, distinct experts read
+        (summed over those), assignments."""
         Smax = state["cache"]["k"].shape[3]
+        # A model that dispatches tokens to experts is told which rows
+        # hold a request (the others route to no expert), and what
+        # routing did rides out with the tokens.
         routed = InferenceEngine._counts_routing(cfg)
 
-        def step(carry, _):
-            run = carry["active"]
-            # A model that dispatches tokens to experts is told which
-            # rows hold a request (the others route to no expert), and
-            # what routing did rides out with the tokens.
-            out = transformer.decode_step(
+        def step_model(carry):
+            return transformer.decode_step(
                 params, carry["last_tok"], carry["pos"], carry["cache"],
                 cfg, tp=tp,
-                **(dict(live=run, return_routing=True) if routed else {}),
+                **(dict(live=carry["active"], return_routing=True)
+                   if routed else {}),
             )
-            logits, cache = out[:2]
-            keys = jax.vmap(
-                lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
-            )(carry["seeds"], carry["pos"])
-            # Rows that are not running ask nothing of the sampler
-            # (sampling.live_knobs); the tier this step took rides out
-            # with the tokens.
-            knobs = sampling.live_knobs(
-                run, carry["temp"], carry["top_k"], carry["top_p"])
-            tok = sample_per_row(logits, keys, *knobs)
-            tok = jnp.where(run, tok, cfg.pad_token_id)
-            pos = carry["pos"] + run.astype(jnp.int32)
-            remaining = carry["remaining"] - run.astype(jnp.int32)
-            done = run & (
-                (tok == cfg.eos_token_id)
-                | (remaining <= 0)
-                | (pos >= Smax - 1)
-            )
-            new_carry = {
-                **carry,
-                "cache": cache,
-                "last_tok": jnp.where(run, tok, carry["last_tok"]),
-                "pos": pos,
-                "active": carry["active"] & ~done,
-                "remaining": remaining,
-            }
-            counts = InferenceEngine._step_counts(knobs)
-            if routed:
-                counts = jnp.concatenate([counts, out[2]])
-            return new_carry, (tok, run, counts)
 
-        state, (toks, valid, counts) = jax.lax.scan(
-            step, state, None, length=n_steps)
+        state, toks, valid, counts = slot_rules.decode_chunk(
+            step_model, state, n_steps, Smax, cfg)
         if tp is not None:
             state = tp.constrain_state(state)
         toks, valid, active, counts = InferenceEngine._replicate(
-            mesh, toks, valid, state["active"], jnp.sum(counts, axis=0)
+            mesh, toks, valid, state["active"], counts
         )
-        # counts, int32 over the chunk, in CHUNK_COUNTERS' order: steps,
-        # steps that drew, steps that masked; a routed model adds
-        # sparse-layer steps, distinct experts read (summed over those),
-        # assignments.
         return state, toks, valid, active, counts
-
-    @staticmethod
-    def _step_counts(knobs):
-        """One decode step in SAMPLER_COUNTERS' order, [3] int32: the
-        step itself, whether its sampler drew, whether it masked
-        (sampling.tier of the running rows' knobs)."""
-        return jnp.stack(
-            (jnp.ones((), bool),) + sampling.tier(*knobs)
-        ).astype(jnp.int32)
 
     @staticmethod
     def _counts_routing(cfg) -> bool:
@@ -1934,41 +1824,22 @@ class InferenceEngine:
             logits, kv = transformer.prefill_with_prefix(
                 params, toks, plens, prefix_kv, prefix_lens, cfg, tp=tp
             )
-            if cfg.kv_cache_dtype == "int8":
-                kq, ks = transformer._quantize_kv(kv["k"])
-                vq, vs = transformer._quantize_kv(kv["v"])
-                writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-            else:
-                dt = pool["k"].dtype
-                writes = {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+            writes = transformer.kv_writes(kv, pool, cfg)
             spos = prefix_lens[:, None] + jnp.arange(Sb)[None, :]
         else:
             sub = transformer.init_cache(cfg, G, Sb)
             logits, writes = transformer.prefill(params, toks, plens, sub,
                                                  cfg, tp=tp)
             spos = jnp.broadcast_to(jnp.arange(Sb)[None, :], (G, Sb))
-        keys = jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-        )(seeds, plens)
-        first = sample_per_row(logits, keys, temps, top_ks, top_ps)
-        first_done = (
-            (first == cfg.eos_token_id)
-            | (max_news <= 1)
-            | (plens + 1 >= Smax)
-        )
+        first, first_done = slot_rules.first_token(
+            logits, seeds, plens, temps, top_ks, top_ps, max_news, Smax, cfg)
         new_pool = transformer.paged_scatter_tokens(pool, writes, table,
                                                     spos)
-        new_state = {
-            "cache": new_pool,
-            "last_tok": state["last_tok"].at[slots].set(first),
-            "pos": state["pos"].at[slots].set(plens),
-            "active": state["active"].at[slots].set(~first_done),
-            "temp": state["temp"].at[slots].set(temps),
-            "top_k": state["top_k"].at[slots].set(top_ks),
-            "top_p": state["top_p"].at[slots].set(top_ps),
-            "seeds": state["seeds"].at[slots].set(seeds),
-            "remaining": state["remaining"].at[slots].set(max_news - 1),
-        }
+        new_state = slot_rules.arm(
+            state, slots, cache=new_pool, first=first, done=first_done,
+            pos=plens, temps=temps, top_ks=top_ks, top_ps=top_ps,
+            seeds=seeds, max_news=max_news,
+        )
         if tp is not None:
             new_state = tp.constrain_state(new_state)
         first, first_done = InferenceEngine._replicate(
@@ -1986,8 +1857,8 @@ class InferenceEngine:
         0..k-1 (and any zero-copy warm prefix) is a block-table GATHER of
         each row's first prefix_width/kv_block blocks instead of a slab
         slice, and the fresh chunk KV scatters back through the table.
-        Attention math, sampling keys, and slot-state writes are
-        identical, so greedy outputs match the dense chunked path
+        Attention math is identical and the slot's rules are shared
+        (models/slot.py), so greedy outputs match the dense chunked path
         bit-for-bit. No writes are returned — paged trie insertion is
         host-side block bookkeeping, not device KV."""
         G, Sc = toks.shape
@@ -2000,37 +1871,18 @@ class InferenceEngine:
         logits, kv = transformer.prefill_with_prefix(
             params, toks, plens, prefix_kv, starts, cfg, tp=tp
         )
-        keys = jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.key(s), p)
-        )(seeds, plens)
-        first = sample_per_row(logits, keys, temps, top_ks, top_ps)
-        first_done = (
-            (first == cfg.eos_token_id)
-            | (max_news <= 1)
-            | (plens + 1 >= Smax)
-        )
+        first, first_done = slot_rules.first_token(
+            logits, seeds, plens, temps, top_ks, top_ps, max_news, Smax, cfg)
         new_pos = jnp.minimum(plens, starts + Sc)
-        if cfg.kv_cache_dtype == "int8":
-            kq, ks = transformer._quantize_kv(kv["k"])
-            vq, vs = transformer._quantize_kv(kv["v"])
-            writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-        else:
-            dt = pool["k"].dtype
-            writes = {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+        writes = transformer.kv_writes(kv, pool, cfg)
         spos = starts[:, None] + jnp.arange(Sc)[None, :]
         new_pool = transformer.paged_scatter_tokens(pool, writes, table,
                                                     spos)
-        new_state = {
-            "cache": new_pool,
-            "last_tok": state["last_tok"].at[slots].set(first),
-            "pos": state["pos"].at[slots].set(new_pos),
-            "active": state["active"].at[slots].set(finals & ~first_done),
-            "temp": state["temp"].at[slots].set(temps),
-            "top_k": state["top_k"].at[slots].set(top_ks),
-            "top_p": state["top_p"].at[slots].set(top_ps),
-            "seeds": state["seeds"].at[slots].set(seeds),
-            "remaining": state["remaining"].at[slots].set(max_news - 1),
-        }
+        new_state = slot_rules.arm(
+            state, slots, cache=new_pool, first=first, done=first_done,
+            pos=new_pos, finals=finals, temps=temps, top_ks=top_ks,
+            top_ps=top_ps, seeds=seeds, max_news=max_news,
+        )
         if tp is not None:
             new_state = tp.constrain_state(new_state)
         first, first_done = InferenceEngine._replicate(
@@ -2042,52 +1894,26 @@ class InferenceEngine:
     def _paged_chunk_impl(params, state, table, *, cfg, n_steps, mesh=None,
                           tp=None):
         """Paged twin of _chunk_impl: `n_steps` decode iterations reading
-        K/V through the block tables (transformer.paged_decode_step).
-        Per-row termination, sampling keys and masking are identical, so
-        greedy tokens match the dense chunk bit-for-bit. Inactive rows'
-        garbage writes route through table entry 0 (trash) once the host
-        zeroes a freed row — the paged analogue of the dense path's
-        frozen-position scribble."""
+        K/V through the block tables (transformer.paged_decode_step);
+        the same slot_rules.decode_chunk, so greedy tokens match the dense
+        chunk bit-for-bit. Inactive rows' garbage writes route through
+        table entry 0 (trash) once the host zeroes a freed row — the
+        paged analogue of the dense path's frozen-position scribble."""
         block = state["cache"]["k"].shape[3]
         Smax = table.shape[1] * block
 
-        def step(carry, _):
-            run = carry["active"]
-            logits, pool = transformer.paged_decode_step(
+        def step_model(carry):
+            return transformer.paged_decode_step(
                 params, carry["last_tok"], carry["pos"], carry["cache"],
                 table, cfg, tp=tp,
             )
-            keys = jax.vmap(
-                lambda s, p: jax.random.fold_in(jax.random.key(s), p + 1)
-            )(carry["seeds"], carry["pos"])
-            knobs = sampling.live_knobs(
-                run, carry["temp"], carry["top_k"], carry["top_p"])
-            tok = sample_per_row(logits, keys, *knobs)
-            tok = jnp.where(run, tok, cfg.pad_token_id)
-            pos = carry["pos"] + run.astype(jnp.int32)
-            remaining = carry["remaining"] - run.astype(jnp.int32)
-            done = run & (
-                (tok == cfg.eos_token_id)
-                | (remaining <= 0)
-                | (pos >= Smax - 1)
-            )
-            new_carry = {
-                **carry,
-                "cache": pool,
-                "last_tok": jnp.where(run, tok, carry["last_tok"]),
-                "pos": pos,
-                "active": carry["active"] & ~done,
-                "remaining": remaining,
-            }
-            counts = InferenceEngine._step_counts(knobs)
-            return new_carry, (tok, run, counts)
 
-        state, (toks, valid, counts) = jax.lax.scan(
-            step, state, None, length=n_steps)
+        state, toks, valid, counts = slot_rules.decode_chunk(
+            step_model, state, n_steps, Smax, cfg)
         if tp is not None:
             state = tp.constrain_state(state)
         toks, valid, active, counts = InferenceEngine._replicate(
-            mesh, toks, valid, state["active"], jnp.sum(counts, axis=0)
+            mesh, toks, valid, state["active"], counts
         )
         return state, toks, valid, active, counts
 
@@ -2134,17 +1960,17 @@ class InferenceEngine:
         wave math IS _paged_admit_chunk_impl + _paged_chunk_impl(1)
         with masking instead of slot-gather, so greedy outputs stay
         bit-identical to the bucketed engine (tests/test_ragged.py)."""
-        state, first, first_done, toks, valid = ragged_attention.ragged_wave(
-            params, state, table, tokens, plens, starts, seeds, temps,
-            top_ks, top_ps, max_news, finals, is_prefill, cfg, tp=tp,
-            kernel=kernel, block_budget=block_budget,
-        )
+        state, first, first_done, toks, valid, counts = (
+            ragged_attention.ragged_wave(
+                params, state, table, tokens, plens, starts, seeds, temps,
+                top_ks, top_ps, max_news, finals, is_prefill, cfg, tp=tp,
+                kernel=kernel, block_budget=block_budget,
+            ))
         if tp is not None:
             state = tp.constrain_state(state)
-        first, first_done, toks, valid, active = InferenceEngine._replicate(
-            mesh, first, first_done, toks, valid, state["active"]
+        return (state,) + InferenceEngine._replicate(
+            mesh, first, first_done, toks, valid, state["active"], counts
         )
-        return state, first, first_done, toks, valid, active
 
     @staticmethod
     def _verify_impl(params, state, table, drafts, wave, *, cfg,
@@ -2156,16 +1982,16 @@ class InferenceEngine:
         keyed ("verify", k) in the lattice. Returns the decode chunk's
         exact contract (toks/valid are [k+1, B] True-prefix columns),
         so _process_chunk consumes a wave unchanged."""
-        state, toks, valid = spec_model.verify_wave(
+        state, toks, valid, counts = spec_model.verify_wave(
             params, state, table, drafts, wave, cfg, tp=tp,
             kernel=kernel, block_budget=block_budget,
         )
         if tp is not None:
             state = tp.constrain_state(state)
-        toks, valid, active = InferenceEngine._replicate(
-            mesh, toks, valid, state["active"]
+        toks, valid, active, counts = InferenceEngine._replicate(
+            mesh, toks, valid, state["active"], counts
         )
-        return state, toks, valid, active
+        return state, toks, valid, active, counts
 
     # --- public API ---------------------------------------------------------
 
@@ -2796,7 +2622,7 @@ class InferenceEngine:
             # keep the compile a pure no-op over real state.
             _, C = key
             B = self.ecfg.max_slots
-            self._state, _, _, _, _, _ = self._jit_ragged(
+            self._state = self._jit_ragged(
                 self.params,
                 self._state,
                 jnp.zeros((B, self._nbs), jnp.int32),
@@ -2810,7 +2636,7 @@ class InferenceEngine:
                 jnp.ones((B,), jnp.int32),
                 jnp.zeros((B,), jnp.bool_),
                 jnp.zeros((B,), jnp.bool_),
-            )
+            )[0]
         elif kind == "chunk" and self._chunked:
             _, Sc, G, W = key
             start = min(W, Smax - Sc)
@@ -2854,13 +2680,13 @@ class InferenceEngine:
             # pure no-op over real state.
             _, kk = key
             B = self.ecfg.max_slots
-            self._state, _, _, _ = self._jit_verify(
+            self._state = self._jit_verify(
                 self.params,
                 self._state,
                 jnp.zeros((B, self._nbs), jnp.int32),
                 jnp.zeros((B, kk), jnp.int32),
                 jnp.zeros((B,), jnp.bool_),
-            )
+            )[0]
         elif kind == "draft" and self._jit_draft is not None:
             # Draft-model proposal at rung k over its scratch cache —
             # stateless by design, so the warm call touches no engine
@@ -4164,7 +3990,8 @@ class InferenceEngine:
             jnp.asarray(finals),
             jnp.asarray(is_prefill),
         )
-        self._state, first, first_done, toks_d, valid_d, active_d = out
+        self._state, first, first_done = out[:3]
+        chunk_handles = tuple(out[3:])  # toks, valid, active, counts
         if self._observe:
             self._note_dispatch(
                 ("ragged", C), group[0].rid if group else -1,
@@ -4186,47 +4013,29 @@ class InferenceEngine:
                 self.stats.budget_tokens += packed
                 self.stats.budget_limit = budget
         self._recycle_budget_spent(roster, 1)
-        for h in (first, first_done, toks_d, valid_d, active_d):
+        for h in (first, first_done) + chunk_handles:
             h.copy_to_host_async()
-        wf = 0.0
-        if self._sled is not None:
+        if self._sled is not None and packed:
             # A wave's unused token-slots are NOT padding: the ragged
             # kernel walks per-request token counts, so cost scales with
             # packed tokens, not capacity (docs/benchmarking.md "Ragged
             # dispatch") — cells == useful, zero bucket/group pad.
-            if packed:
-                self._sled.note_group(("ragged", C), packed, packed, 0, 0)
-                with self.stats.lock:
-                    self.stats.sched_useful_tokens += packed
-                starved = bool(
-                    self._prefilling or (self._waiting and self._free)
-                )
-                self._sled.note_budget(budget, packed, starved)
-                if starved and budget > packed:
-                    with self.stats.lock:
-                        self.stats.sched_frag_tokens += budget - packed
-            self._sled.note_boundary()
-            wf = self._sled.boundary_waste()
+            self._sled.note_group(("ragged", C), packed, packed, 0, 0)
             with self.stats.lock:
-                self.stats.record_waste_locked(wf)
-        if self._pilot is not None:
-            self._pilot_tick()
-        if self._recorder is not None:
-            detail = {
-                "admits": len(group),
-                "chunk": 1,
-                "active": int(self._active_host.sum()),
-                "packed_tokens": packed,
-                "pool_free": int(self._allocator.free_count),
-            }
-            if self._sled is not None:
-                detail["waste_frac"] = round(wf, 4)
-            self._recorder.record("boundary", -1, detail)
+                self.stats.sched_useful_tokens += packed
+            starved = bool(
+                self._prefilling or (self._waiting and self._free)
+            )
+            self._sled.note_budget(budget, packed, starved)
+            if starved and budget > packed:
+                with self.stats.lock:
+                    self.stats.sched_frag_tokens += budget - packed
+        self._note_boundary(admits=len(group), chunk=1,
+                            packed_tokens=packed)
         timing = self._make_timing() if self._timing_on else None
         self._dispatch_wreck = None
         return _PendingWave(
-            admits, (toks_d, valid_d, active_d), roster, timing,
-            self._wave_epoch,
+            admits, chunk_handles, roster, timing, self._wave_epoch,
         )
 
     # --- speculative decoding (graftspec) ----------------------------------
@@ -4294,7 +4103,7 @@ class InferenceEngine:
         rollback, pilot tick) runs at process time
         (_spec_post_process): how many tokens a wave emitted is
         unknowable until its results land, which is also why the spec
-        loop never pipelines (_loop_sync_spec)."""
+        loop never pipelines (_loop_sync)."""
         admits = (
             self._dispatch_prefill_chunks() if self._chunked
             else self._dispatch_admits()
@@ -4317,7 +4126,7 @@ class InferenceEngine:
                 self._grow_decode_blocks(k + 1)
                 if self._observe:
                     t0 = time.perf_counter()
-                self._state, toks, valid, active_after = self._jit_verify(
+                out = self._jit_verify(
                     self.params,
                     self._state,
                     self._table_device(),
@@ -4327,7 +4136,8 @@ class InferenceEngine:
                 if self._observe:
                     self._note_dispatch(("verify", k), -1,
                                         time.perf_counter() - t0)
-                chunk_handles = (toks, valid, active_after)
+                self._state = out[0]
+                chunk_handles = tuple(out[1:])  # toks, valid, active, counts
                 with self.stats.lock:
                     self.stats.decode_dispatches += 1
                     self.stats.decode_steps += 1
@@ -4372,7 +4182,7 @@ class InferenceEngine:
         if chunk_data is not None and wave_info is not None:
             k, wave, n_wave = wave_info
             if n_wave:
-                _, valid_h, _ = chunk_data
+                valid_h = chunk_data[1]
                 emitted = int(valid_h.sum(axis=0)[wave].sum())
                 cells = (k + 1) * n_wave
                 drafted = k * n_wave
@@ -4403,65 +4213,11 @@ class InferenceEngine:
                         self._allocator.unref(bid)
                     self._table_host[slot, keep:len(req.block_ids)] = 0
                     del req.block_ids[keep:]
-        wf = 0.0
-        if self._sled is not None:
-            self._sled.note_boundary()
-            wf = self._sled.boundary_waste()
-            with self.stats.lock:
-                self.stats.record_waste_locked(wf)
-        if self._pilot is not None:
-            self._pilot_tick()
-        if self._recorder is not None:
-            detail = {
-                "active": int(self._active_host.sum()),
-                "pool_free": int(self._allocator.free_count),
-            }
-            if n_wave:
-                detail.update(
-                    verify_k=k, wave=n_wave, emitted=emitted,
-                    accepted=accepted, rejected=rejected,
-                )
-            if self._sled is not None:
-                detail["waste_frac"] = round(wf, 4)
-            self._recorder.record("boundary", -1, detail)
-
-    def _loop_sync_spec(self) -> None:
-        """Synchronous UNPIPELINED scheduler loop under SPEC=1: every
-        boundary is processed before the next dispatch. Pipelining is
-        structurally off because the next wave depends on THIS wave's
-        acceptance results three ways — the drafter reads the emitted
-        history, _grow_decode_blocks sizes k + 1 positions from the
-        resynced expected, and rollback trims the tables the next
-        dispatch snapshots. The wide verify dispatch amortizes the
-        round trip the pipeline used to hide: one sync per up-to-(k+1)
-        tokens per row instead of one per chunk."""
-        while not self._stop.is_set():
-            try:
-                with self._book:
-                    work = self._dispatch_once()
-                    if work is not None:
-                        self._process_boundary(*work)
-                    idle = (
-                        work is None and not self._active_host.any()
-                    )
-                if self._profile_n and work is not None:
-                    self._profile_tick()
-                # Sleep outside the lock so drain()/cancel() never wait
-                # on an idle tick.
-                if idle and self._pending.empty():
-                    if self._sled is not None:
-                        self._sled.note_idle()
-                        with self.stats.lock:
-                            self.stats.sched_idle_boundaries += 1
-                    time.sleep(self.ecfg.idle_sleep_s)
-            except Exception as e:  # fail requests, reset, keep serving
-                logger.exception("engine iteration failed")
-                with self._book:
-                    wreck, self._dispatch_wreck = (
-                        self._dispatch_wreck, None
-                    )
-                    self._spec_wave = None
-                    self._fail_or_heal(str(e), [wreck])
+        detail = dict(
+            verify_k=k, wave=n_wave, emitted=emitted, accepted=accepted,
+            rejected=rejected,
+        ) if n_wave else {}
+        self._note_boundary(**detail)
 
     # --- boundary processing -----------------------------------------------
 
@@ -4551,12 +4307,9 @@ class InferenceEngine:
         }
 
     def _note_chunk_counts(self, chunk_data) -> None:
-        """What a decode chunk counted on the device (the fifth value of
-        _chunk_impl / _paged_chunk_impl, CHUNK_COUNTERS' order; came to
-        the host in the boundary's own fetch) into the stats. The
-        ragged and speculative waves report none."""
-        if len(chunk_data) < 4:
-            return
+        """What a decode chunk, a ragged wave or a verify wave counted
+        on the device (the last value of each, CHUNK_COUNTERS' order;
+        came to the host in the boundary's own fetch) into the stats."""
         with self.stats.lock:
             for name, v in zip(CHUNK_COUNTERS, chunk_data[3]):
                 setattr(self.stats, name, getattr(self.stats, name) + int(v))
@@ -4945,7 +4698,7 @@ class InferenceEngine:
         the innocents for replay, then rebuild device state and re-queue
         them at the FRONT of the admission queue in ascending-rid order
         so replays stay ahead of fresh traffic. Deterministic
-        per-position sampling keys (fold_in(key(seed), abs_pos)) make
+        per-position sampling keys (models/slot.py) make
         each replayed continuation bit-identical to its unfaulted run,
         greedy and sampled alike."""
         heal = self._heal
@@ -5693,6 +5446,27 @@ class InferenceEngine:
                 self._note_dispatch(("deactivate",), -1,
                                     time.perf_counter() - t0)
 
+    def _note_boundary(self, **detail) -> None:  # graftlint: holds(_book)
+        """A wave is dispatched (under SPEC=1: processed, its acceptance
+        known): close the sched ledger's boundary, tick the pilot and
+        write the flight recorder's "boundary" record, `detail` plus
+        what every wave reports."""
+        wf = 0.0
+        if self._sled is not None:
+            self._sled.note_boundary()
+            wf = self._sled.boundary_waste()
+            with self.stats.lock:
+                self.stats.record_waste_locked(wf)
+        if self._pilot is not None:
+            self._pilot_tick()
+        if self._recorder is not None:
+            detail["active"] = int(self._active_host.sum())
+            if self._paged:
+                detail["pool_free"] = int(self._allocator.free_count)
+            if self._sled is not None:
+                detail["waste_frac"] = round(wf, 4)
+            self._recorder.record("boundary", -1, detail)
+
     def _dispatch_once(self):  # graftlint: holds(_book)
         """One scheduling step under the bookkeeping lock
         (_dispatch_wave), as a `sched.dispatch` host span on the
@@ -5742,10 +5516,9 @@ class InferenceEngine:
             roster = self._roster()
             self._dispatch_wreck = _PendingWave(admits, None, roster, None)
             n = self._pick_chunk()
-            # (state, toks, valid, active_after, device-side counts)
             out = self._dispatch_decode_chunk(n)
-            self._state, toks, valid, active_after = out[:4]
-            chunk_handles = tuple(out[1:])
+            self._state = out[0]
+            chunk_handles = tuple(out[1:])  # toks, valid, active, counts
             with self.stats.lock:
                 self.stats.decode_dispatches += 1
                 self.stats.decode_steps += n
@@ -5761,25 +5534,8 @@ class InferenceEngine:
                 d.copy_to_host_async()
             for h in chunk_handles:
                 h.copy_to_host_async()
-            wf = 0.0
-            if self._sled is not None:
-                self._sled.note_boundary()
-                wf = self._sled.boundary_waste()
-                with self.stats.lock:
-                    self.stats.record_waste_locked(wf)
-            if self._pilot is not None:
-                self._pilot_tick()
-            if self._recorder is not None:
-                detail = {
-                    "admits": sum(len(g) for g, _, _, _ in admits),
-                    "chunk": n,
-                    "active": int(self._active_host.sum()),
-                }
-                if self._paged:
-                    detail["pool_free"] = int(self._allocator.free_count)
-                if self._sled is not None:
-                    detail["waste_frac"] = round(wf, 4)
-                self._recorder.record("boundary", -1, detail)
+            self._note_boundary(
+                admits=sum(len(g) for g, _, _, _ in admits), chunk=n)
             timing = self._make_timing() if self._timing_on else None
             self._dispatch_wreck = None
             return _PendingWave(
@@ -5846,134 +5602,37 @@ class InferenceEngine:
                 time.sleep(self.ecfg.idle_sleep_s)
 
     def _loop_sync(self) -> None:
-        # Slot/free-list/active bookkeeping runs under _book even in the
-        # synchronous (no fetcher thread) mode: drain(), cancel paths and
-        # debug_lifecycle_check() read the same state from other threads.
-        if self._ragged:
-            self._loop_sync_ragged()
-            return
-        if self._spec:
-            self._loop_sync_spec()
-            return
-        pending: Optional[_PendingWave] = None
-        while not self._stop.is_set():
-            admits, roster = [], None  # visible to the except path
-            try:
-                with self._book:
-                    self._sync_depth = int(pending is not None)
-                    if self._roof is not None:
-                        self._step_t0 = time.perf_counter()
-                    self._reap_lifecycle()
-                    admits = (
-                        self._dispatch_prefill_chunks() if self._chunked
-                        else self._dispatch_admits()
-                    )
-                    if admits or self._active_host.any():
-                        # Chunk consumes the post-admission state;
-                        # device-side `active` is already armed even
-                        # though _active_host lags until _process_admits.
-                        roster = self._roster()
-                        n = self._pick_chunk()
-                        out = self._dispatch_decode_chunk(n)
-                        self._state = out[0]
-                        chunk_handles = tuple(out[1:])
-                        with self.stats.lock:
-                            self.stats.decode_dispatches += 1
-                            self.stats.decode_steps += n
-                        self._recycle_budget_spent(roster, n)
-                        wf = 0.0
-                        if self._sled is not None:
-                            self._sled.note_boundary()
-                            wf = self._sled.boundary_waste()
-                            with self.stats.lock:
-                                self.stats.record_waste_locked(wf)
-                        if self._pilot is not None:
-                            self._pilot_tick()
-                        if self._recorder is not None:
-                            detail = {
-                                "admits": sum(
-                                    len(g) for g, _, _, _ in admits
-                                ),
-                                "chunk": n,
-                                "active": int(self._active_host.sum()),
-                            }
-                            if self._paged:
-                                detail["pool_free"] = int(
-                                    self._allocator.free_count
-                                )
-                            if self._sled is not None:
-                                detail["waste_frac"] = round(wf, 4)
-                            self._recorder.record("boundary", -1, detail)
-                    else:
-                        chunk_handles = None
-                    timing = (
-                        self._make_timing()
-                        if self._timing_on
-                        and (admits or chunk_handles is not None)
-                        else None
-                    )
-                    if pending is not None:
-                        self._process_boundary(*pending)
-                    pending = (
-                        _PendingWave(admits, chunk_handles, roster, timing,
-                                     self._wave_epoch)
-                        if (admits or chunk_handles is not None)
-                        else None
-                    )
-                    idle = (
-                        pending is None and not self._active_host.any()
-                    )
-                if self._profile_n and pending is not None:
-                    self._profile_tick()
-                # Sleep outside the lock so drain()/cancel() never wait
-                # on an idle tick.
-                if idle and self._pending.empty():
-                    if self._sled is not None:
-                        self._sled.note_idle()
-                        with self.stats.lock:
-                            self.stats.sched_idle_boundaries += 1
-                    time.sleep(self.ecfg.idle_sleep_s)
-            except Exception as e:  # fail requests, reset, keep serving
-                logger.exception("engine iteration failed")
-                # The CURRENT iteration's admits/roster may hold requests
-                # already recycled out of _slots — fail them too.
-                with self._book:
-                    self._fail_or_heal(
-                        str(e),
-                        [pending, _PendingWave(admits, None, roster, None)],
-                    )
-                pending = None
-        # Drain the in-flight boundary so stop() doesn't strand requests.
-        if pending is not None:
-            try:
-                with self._book:
-                    self._process_boundary(*pending)
-            except Exception as e:
-                logger.exception("final boundary failed")
-                with self._book:
-                    self._fail_all(str(e), [pending])
+        """The synchronous scheduler loop (no fetcher thread: a
+        multi-process mesh, or async_fetch off): wave N+1 is dispatched
+        before wave N's results are fetched, one boundary ahead.
+        Slot/free-list/active bookkeeping runs under _book here too:
+        drain(), cancel paths and debug_lifecycle_check() read the same
+        state from other threads. Requests optimistically recycled out
+        of _slots live only in a wave's roster — the one in flight, the
+        one just dispatched, or the wreck of a dispatch that raised —
+        so the error path fails all three.
 
-    def _loop_sync_ragged(self) -> None:
-        """Synchronous scheduler loop under RAGGED=1: each iteration is
-        ONE fused wave (_dispatch_once routes to _dispatch_ragged),
-        software-pipelined one boundary deep exactly like the bucketed
-        loop — wave N+1 dispatches before wave N's results are
-        fetched. Requests optimistically recycled out of _slots live in
-        `pending` rosters and the dispatch wreck, so the error path
-        fails both."""
+        Under SPEC=1 it runs nothing ahead: the next wave depends on
+        THIS wave's acceptance three ways — the drafter reads the
+        emitted history, _grow_decode_blocks sizes k + 1 positions from
+        the resynced expected, and rollback trims the tables the next
+        dispatch snapshots. The wide verify dispatch amortizes the round
+        trip the look-ahead hides: one sync per up-to-(k+1) tokens per
+        row instead of one per chunk."""
+        ahead = 0 if self._spec else 1
         pending: Optional[_PendingWave] = None
         while not self._stop.is_set():
+            work = None
             try:
                 with self._book:
                     self._sync_depth = int(pending is not None)
                     work = self._dispatch_once()
-                    if pending is not None:
-                        self._process_boundary(*pending)
-                    pending = work
-                    idle = (
-                        pending is None and not self._active_host.any()
-                    )
-                if self._profile_n and pending is not None:
+                    due = pending if ahead else work
+                    if due is not None:
+                        self._process_boundary(*due)
+                    pending = work if ahead else None
+                    idle = work is None and not self._active_host.any()
+                if self._profile_n and work is not None:
                     self._profile_tick()
                 # Sleep outside the lock so drain()/cancel() never wait
                 # on an idle tick.
@@ -5989,7 +5648,7 @@ class InferenceEngine:
                     wreck, self._dispatch_wreck = (
                         self._dispatch_wreck, None
                     )
-                    self._fail_or_heal(str(e), [pending, wreck])
+                    self._fail_or_heal(str(e), [pending, work, wreck])
                 pending = None
         # Drain the in-flight boundary so stop() doesn't strand requests.
         if pending is not None:

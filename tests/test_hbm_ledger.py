@@ -16,18 +16,22 @@ Claims under test:
 import jax
 import pytest
 
+from _engine_fixture import LIVE_TOKENS, PROMPT, live_config
+
 from seldon_tpu.models import init_params
-from seldon_tpu.models.config import get_config
 from seldon_tpu.models.sampling import SamplingParams
 from seldon_tpu.servers import hbm_ledger
 from seldon_tpu.servers.engine import EngineConfig, InferenceEngine
 
-PROMPT = list(range(2, 26))
-GREEDY = SamplingParams(temperature=0.0, max_new_tokens=8)
+# A slot is recycled when the chunk that spends its budget is DISPATCHED,
+# up to the pipeline's depth ahead of the first token's delivery: a test
+# that looks for live KV "after the first token" needs a budget that
+# outlasts that run-ahead (LIVE_TOKENS).
+GREEDY = SamplingParams(temperature=0.0, max_new_tokens=LIVE_TOKENS)
 
 
 def _engine(start=True, **ekw):
-    cfg = get_config("tiny")
+    cfg = live_config()
     params = init_params(cfg, jax.random.key(0))
     ekw.setdefault("max_slots", 4)
     ekw.setdefault("max_seq_len", 64)

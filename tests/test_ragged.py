@@ -171,8 +171,8 @@ def test_ragged_prefix_warm_bit_identical_and_zero_copy(kv_dtype):
 
 
 def test_ragged_sync_fetch_loop_bit_identical():
-    """async_fetch=False exercises _loop_sync_ragged (the one-wave-
-    lookahead pipeline) instead of the fetch-thread path."""
+    """async_fetch=False exercises _loop_sync (the one-wave-lookahead
+    pipeline) instead of the fetch-thread path."""
     cfg = get_config("tiny")
     want = _want(cfg)
     eng = _engine(cfg, **RAGGED, async_fetch=False)

@@ -280,7 +280,7 @@ def _wave_fixture(kv_dtype):
 
 def _run_wave(cfg, params, table, state, args, kernel, block_budget=0):
     st = jax.tree.map(lambda x: x, state)
-    st2, first, fdone, toks, valid = ra.ragged_wave(
+    st2, first, fdone, toks, valid, _ = ra.ragged_wave(
         params, st, table, args["tokens"], args["plens"], args["starts"],
         args["seeds"], args["temps"], args["top_ks"], args["top_ps"],
         args["max_news"], args["finals"], args["is_prefill"], cfg,
@@ -410,7 +410,7 @@ def _verify_fixture(kv_dtype):
 def _run_verify(cfg, params, table, state, drafts, wave, kernel,
                 block_budget=0):
     st = jax.tree.map(lambda x: x, state)
-    st2, toks, valid = spec_decode.verify_wave(
+    st2, toks, valid, _ = spec_decode.verify_wave(
         params, st, table, drafts, wave, cfg, kernel=kernel,
         block_budget=block_budget)
     return dict(toks=np.asarray(toks), valid=np.asarray(valid),

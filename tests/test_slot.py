@@ -1,0 +1,219 @@
+"""models/slot.py: the one place a slot's keys, termination, arming and
+decode step are written, and the engine paths that call it.
+
+ (a) the two key rules against the literal expressions;
+ (b) the two termination rules on a table of edge rows;
+ (c) a decode chunk of n steps is n chunks of one step, bit for bit;
+ (d) the same request through every engine path yields one stream;
+ (e) every path counts its sampler steps;
+ and the guard of tests/_engine_fixture.py: the live stream is live.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from _engine_fixture import LIVE_TOKENS, PROMPT, live_config
+
+from seldon_tpu.models import init_params, slot, transformer
+from seldon_tpu.models.sampling import SamplingParams
+from seldon_tpu.servers.engine import EngineConfig, InferenceEngine
+
+GREEDY = SamplingParams(temperature=0.0, max_new_tokens=LIVE_TOKENS)
+SAMPLED = SamplingParams(temperature=0.8, top_k=8, seed=7,
+                         max_new_tokens=LIVE_TOKENS)
+
+PAGED = dict(paged_kv=True, kv_block=8, prefix_block=8)
+CHUNKED = dict(chunked_prefill=True, prefill_chunk=8, prefix_block=8)
+PATHS = {
+    "dense": {},
+    "dense-sync": dict(async_fetch=False),
+    "paged": PAGED,
+    "chunked": CHUNKED,
+    "ragged": {"ragged": True, **PAGED, **CHUNKED},
+    "spec": dict(spec_decode=True, spec_k=4, **PAGED),
+}
+
+
+def _engine(cfg, **ekw):
+    params = init_params(cfg, jax.random.key(0))
+    ekw.setdefault("max_slots", 4)
+    ekw.setdefault("max_seq_len", 64)
+    ekw.setdefault("prompt_buckets", (8, 32))
+    eng = InferenceEngine(params, cfg, EngineConfig(**ekw))
+    eng.start()
+    return eng
+
+
+def _stream(cfg, sp, **ekw):
+    eng = _engine(cfg, **ekw)
+    try:
+        return eng.generate_blocking(PROMPT, sp)["token_ids"]
+    finally:
+        eng.stop()
+
+
+# --- (a) keys ---------------------------------------------------------------
+
+
+def test_keys_are_the_literal_fold_ins():
+    seeds = jnp.array([0, 7, 2**32 - 1], jnp.uint32)
+    at = jnp.array([1, 24, 63], jnp.int32)
+    data = jax.random.key_data
+    for i in range(3):
+        s, p = seeds[i], at[i]
+        assert np.array_equal(
+            data(slot.first_key(seeds, at))[i],
+            data(jax.random.fold_in(jax.random.key(s), p)))
+        assert np.array_equal(
+            data(slot.step_key(seeds, at))[i],
+            data(jax.random.fold_in(jax.random.key(s), p + 1)))
+    # One sequence by absolute position: the first token of a prompt of
+    # p + 1 tokens and the decode step after position p share a key.
+    assert np.array_equal(data(slot.first_key(seeds, at + 1)),
+                          data(slot.step_key(seeds, at)))
+
+
+# --- (b) termination --------------------------------------------------------
+
+SMAX = 64
+EOS = live_config().eos_token_id
+
+
+@pytest.mark.parametrize("first,max_new,plen,want", [
+    (5, 8, 10, False),           # an ordinary first token
+    (EOS, 8, 10, True),          # EOS
+    (5, 1, 10, True),            # a budget of one token
+    (5, 0, 10, True),
+    (5, 8, SMAX - 1, True),      # the prompt fills the window
+    (5, 8, SMAX - 2, False),     # one position left
+])
+def test_first_done_edges(first, max_new, plen, want):
+    got = slot.first_done(jnp.array([first]), jnp.array([max_new]),
+                          jnp.array([plen]), SMAX, live_config())
+    assert bool(got[0]) is want
+
+
+@pytest.mark.parametrize("run,tok,remaining,pos,want", [
+    (True, 5, 3, 30, False),          # mid-stream
+    (True, EOS, 3, 30, True),         # EOS
+    (True, 5, 0, 30, True),           # budget spent by this token
+    (True, 5, 3, SMAX - 1, True),     # the window's last position
+    (True, 5, 3, SMAX - 2, False),
+    (False, EOS, 0, SMAX - 1, False),  # a dead row ends nothing
+])
+def test_step_done_edges(run, tok, remaining, pos, want):
+    got = slot.step_done(jnp.array([run]), jnp.array([tok]),
+                         jnp.array([remaining]), jnp.array([pos]), SMAX,
+                         live_config())
+    assert bool(got[0]) is want
+
+
+# --- (c) n steps = n chunks of one step ------------------------------------
+
+
+def _armed(cfg):
+    """Params and a slot state with two requests admitted by the
+    engine's own _admit_impl (one greedy, one sampled with top-k)."""
+    params = init_params(cfg, jax.random.key(0))
+    B, Smax = 4, 48
+    state = slot.fresh(transformer.init_cache(cfg, B, Smax), B)
+    toks = np.zeros((2, 16), np.int32)
+    toks[0, :12] = np.arange(2, 14)
+    toks[1, :7] = np.arange(30, 37)
+    state, first, _ = InferenceEngine._admit_impl(
+        params, state, jnp.asarray(toks), jnp.array([12, 7], jnp.int32),
+        jnp.array([3, 7], jnp.uint32), jnp.array([0.0, 0.8], jnp.float32),
+        jnp.array([0, 8], jnp.int32), jnp.array([1.0, 1.0], jnp.float32),
+        jnp.array([20, 5], jnp.int32), jnp.array([2, 0], jnp.int32),
+        cfg=cfg)
+    return params, state
+
+
+@pytest.mark.parametrize("preset,kv_dtype", [
+    ("tiny", "bf16"), ("tiny", "int8"),
+    ("tiny-moe", "bf16"), ("tiny-moe", "int8"),
+    ("tiny-lfm2", "bf16"),  # a patterned stack stores bf16 only
+])
+def test_chunk_of_n_is_n_chunks_of_one(preset, kv_dtype):
+    cfg = live_config(preset, kv_cache_dtype=kv_dtype)
+    params, state = _armed(cfg)
+    n = 6  # the sampled row's budget of 5 ends inside the chunk
+    whole = InferenceEngine._chunk_impl(params, state, cfg=cfg, n_steps=n)
+    toks, valid, counts = [], [], 0
+    for _ in range(n):
+        state, t, v, active, c = InferenceEngine._chunk_impl(
+            params, state, cfg=cfg, n_steps=1)
+        toks.append(t)
+        valid.append(v)
+        counts = counts + c
+    stepped = (state, jnp.concatenate(toks), jnp.concatenate(valid),
+               active, counts)
+    assert jax.tree.structure(whole) == jax.tree.structure(stepped)
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(stepped)):
+        assert a.dtype == b.dtype and np.array_equal(
+            np.asarray(a.astype(jnp.float32)),
+            np.asarray(b.astype(jnp.float32)))
+    valid = np.asarray(whole[2])
+    assert valid[:, 2].all() and valid[:, 0].sum() == 4  # 5 less the first
+    assert not valid[:, 1].any() and not valid[:, 3].any()
+    assert int(whole[4][0]) == n
+
+
+# --- (d) one request, every path, one stream --------------------------------
+
+
+@pytest.fixture(scope="module")
+def want():
+    """The dense engine's streams, one per (preset, sampling)."""
+    memo = {}
+
+    def get(preset, name, sp):
+        if (preset, name) not in memo:
+            memo[preset, name] = _stream(live_config(preset), sp)
+        return memo[preset, name]
+
+    return get
+
+
+@pytest.mark.parametrize("name,sp", [("greedy", GREEDY),
+                                     ("sampled", SAMPLED)])
+@pytest.mark.parametrize("preset,path", [
+    *(("tiny", p) for p in PATHS if p != "dense"),
+    # the opt-in paths refuse a patterned stack by name
+    ("tiny-lfm2", "dense-sync"),
+])
+def test_every_path_answers_the_same(want, preset, path, name, sp):
+    ref = want(preset, name, sp)
+    assert len(ref) == LIVE_TOKENS
+    assert _stream(live_config(preset), sp, **PATHS[path]) == ref
+
+
+# --- (e) every path counts its sampler steps --------------------------------
+
+
+@pytest.mark.parametrize("path", ["paged", "ragged", "spec"])
+def test_paths_count_sampler_steps(path):
+    eng = _engine(live_config(), **PATHS[path])
+    try:
+        eng.generate_blocking(PROMPT, SAMPLED)
+        snap = eng.stats.snapshot()
+    finally:
+        eng.stop()
+    assert snap["sampler_steps"] >= LIVE_TOKENS - 1
+    assert snap["sampler_masked_steps"] > 0  # top_k 8 asks for the sort
+    assert snap["sampler_drawn_steps"] >= snap["sampler_masked_steps"]
+
+
+# --- the fixture's guard ----------------------------------------------------
+
+
+@pytest.mark.parametrize("path", ["dense", "paged"])
+def test_live_stream_is_live(path):
+    """tests/_engine_fixture.py: the greedy continuation of PROMPT under
+    live_config() runs its whole budget and never meets EOS."""
+    cfg = live_config()
+    got = _stream(cfg, GREEDY, **PATHS[path])
+    assert len(got) == LIVE_TOKENS
+    assert cfg.eos_token_id not in got

@@ -32,12 +32,12 @@ import queue
 import jax
 import pytest
 
+from _engine_fixture import PROMPT, live_config
+
 from seldon_tpu.models import init_params
-from seldon_tpu.models.config import get_config
 from seldon_tpu.models.sampling import SamplingParams
 from seldon_tpu.servers.engine import EngineConfig, InferenceEngine
 
-PROMPT = list(range(2, 26))  # 24 tokens: 3 kv_blocks exactly
 GREEDY = SamplingParams(temperature=0.0, max_new_tokens=12)
 SAMPLED = SamplingParams(temperature=0.9, top_k=8, top_p=0.95,
                          max_new_tokens=12, seed=7)
@@ -134,7 +134,7 @@ def test_spec_bit_identical_across_modes(kv_dtype, mode):
     """The acceptance gate's exactness criterion: greedy output under
     SPEC matches the spec-off engine token-for-token in every paged
     mode x KV dtype."""
-    cfg = dataclasses.replace(get_config("tiny"), kv_cache_dtype=kv_dtype)
+    cfg = live_config(kv_cache_dtype=kv_dtype)
     extra = {}
     if mode == "chunked":
         extra = dict(chunked_prefill=True, prefill_chunk=8)
@@ -161,7 +161,7 @@ def test_spec_sampled_bit_identical():
     """Exact-match verification is temperature-blind: per-row keys are
     position-derived, so sampled output is bit-identical too (this is
     what separates graftspec from rejection-sampling schemes)."""
-    cfg = get_config("tiny")
+    cfg = live_config()
     want = _want(cfg, sp=SAMPLED, **PAGED)
     eng = _engine(cfg, **SPEC)
     try:
@@ -175,7 +175,7 @@ def test_spec_mixed_burst_bit_identical():
     """A concurrent mixed-length burst: every row's stream matches its
     spec-off reference even as waves carry different per-row rewind
     depths."""
-    cfg = get_config("tiny")
+    cfg = live_config()
     wants = [_want(cfg, p, **PAGED) for p in MIXED]
     eng = _engine(cfg, **SPEC)
     try:
@@ -199,7 +199,7 @@ def test_spec_oracle_compresses_dispatches():
     """With a perfect drafter the engine emits k+1 tokens per verify
     wave: 12 decode tokens land in ~3 dispatches instead of 11 — the
     CPU-smoke form of the 2x TPU target (docs/benchmarking.md)."""
-    cfg = get_config("tiny")
+    cfg = live_config()
     want = _want(cfg, **PAGED)
     eng = _engine(cfg, start=False, **SPEC)
     eng._drafter = _Oracle(want)
@@ -226,7 +226,7 @@ def test_spec_rejection_at_position_zero_is_leak_free():
     """An always-wrong drafter rejects at position 0 every wave: the
     engine degrades to one token per dispatch, stays bit-exact, and
     the per-wave block growth + tail trim nets out to zero leaks."""
-    cfg = get_config("tiny")
+    cfg = live_config()
     want = _want(cfg, **PAGED)
     eng = _engine(cfg, start=False, **SPEC)
     eng._drafter = _AntiOracle(want, cfg.vocab_size)
@@ -248,7 +248,7 @@ def test_spec_full_k_acceptance_crosses_block_boundary():
     boundaries (24-token prompt + 12 generated crosses pos 32 with
     kv_block=8): the commit allocates blocks mid-wave and the
     allocator's refcount discipline stays exact."""
-    cfg = get_config("tiny")
+    cfg = live_config()
     want = _want(cfg, **PAGED)
     eng = _engine(cfg, start=False, **SPEC)
     eng._drafter = _Oracle(want)
@@ -269,7 +269,7 @@ def test_spec_eos_mid_accepted_prefix():
     """EOS landing inside an accepted run terminates the row exactly
     there: drafts that matched but fell after the terminal token count
     rejected, and the stream matches the spec-off engine's EOS stop."""
-    cfg = get_config("tiny")
+    cfg = live_config()
     base = _want(cfg, **PAGED)
     # Re-point EOS at a token the greedy continuation actually emits,
     # mid-stream, so the terminal lands inside a wave.
@@ -300,7 +300,7 @@ def test_spec_lattice_declares_verify_ladder_and_never_retraces(
     compiles it, and a full generation stays inside it (zero live
     retraces) — the compile-audit SPEC=1 leg's criterion."""
     monkeypatch.setenv("COMPILE_LEDGER", "1")
-    cfg = get_config("tiny")
+    cfg = live_config()
     eng = _engine(cfg, start=False, **SPEC)
     static = set(eng.static_lattice())
     assert {"verify/1", "verify/2", "verify/4"} <= static
@@ -323,7 +323,7 @@ def test_spec_model_drafter_declares_draft_family():
     """A resident draft model adds the ("draft", k) ladder to the
     lattice and stays bit-exact — even with weights that disagree with
     the target (bad drafts cost acceptance, never output)."""
-    cfg = get_config("tiny")
+    cfg = live_config()
     want = _want(cfg, **PAGED)
     params = init_params(cfg, jax.random.key(0))
     dparams = init_params(cfg, jax.random.key(1))
@@ -347,7 +347,7 @@ def test_spec_self_draft_perfect_greedy_acceptance():
     """The same weights as drafter: greedy drafts are the greedy
     continuation, so acceptance is perfect and the wave count collapses
     to ceil(n/(k+1)) — the strongest compression witness."""
-    cfg = get_config("tiny")
+    cfg = live_config()
     params = init_params(cfg, jax.random.key(0))
     want = _want(cfg, **PAGED)
     eng = InferenceEngine(
@@ -376,7 +376,7 @@ def test_spec_conservation_and_acceptance_identities(monkeypatch):
     acceptance identity accepted + rejected == drafted re-sums, and the
     ledger's own boundary audits never breach."""
     monkeypatch.setenv("SCHED_LEDGER", "1")
-    cfg = get_config("tiny")
+    cfg = live_config()
     want = _want(cfg, **PAGED)
     eng = _engine(cfg, start=False, **SPEC)
     eng._drafter = _Oracle(want)
@@ -414,7 +414,7 @@ def test_spec_pilot_binds_fourth_knob(monkeypatch):
     ladder envelope and the spec acceptance signals flow into decision
     windows — output stays bit-identical (pilot-at-defaults)."""
     monkeypatch.setenv("PILOT", "1")
-    cfg = get_config("tiny")
+    cfg = live_config()
     want = _want(cfg, **PAGED)
     eng = _engine(cfg, **SPEC)
     try:
@@ -434,7 +434,7 @@ def test_spec_pilot_binds_fourth_knob(monkeypatch):
 
 
 def test_spec_off_engine_is_untouched():
-    cfg = get_config("tiny")
+    cfg = live_config()
     eng = _engine(cfg, start=False, **PAGED)
     assert not any(k.startswith(("verify/", "draft/"))
                    for k in eng.static_lattice())

@@ -31,7 +31,6 @@ The long-haul soak (FUZZ_EXAMPLES requests) is marked fuzz+slow:
 `make fuzz-chaos` runs it, tier-1 does not.
 """
 
-import dataclasses
 import random
 import threading
 import time
@@ -41,8 +40,9 @@ import jax
 import numpy as np
 import pytest
 
+from _engine_fixture import LIVE_TOKENS, PROMPT, live_config
+
 from seldon_tpu.models import init_params
-from seldon_tpu.models.config import get_config
 from seldon_tpu.models.sampling import SamplingParams
 from seldon_tpu.servers import supervisor
 from seldon_tpu.servers.chaos import ChaosConfig, ChaosMonkey
@@ -53,10 +53,11 @@ from seldon_tpu.servers.supervisor import (
     WatchdogError,
 )
 
-PROMPT = list(range(2, 26))  # 24 tokens
-GREEDY = SamplingParams(temperature=0.0, max_new_tokens=20)
+# LIVE_TOKENS: a fault armed after the first item must find a wave still
+# to be dispatched, whatever depth the scheduler runs ahead at.
+GREEDY = SamplingParams(temperature=0.0, max_new_tokens=LIVE_TOKENS)
 SAMPLED = SamplingParams(temperature=0.9, top_k=8, top_p=0.95,
-                         max_new_tokens=20, seed=7)
+                         max_new_tokens=LIVE_TOKENS, seed=7)
 
 # The resurrection matrix's serving modes (the migration gate: heal
 # must not perturb any substrate it rides).
@@ -72,7 +73,7 @@ MODES = {
 
 
 def _engine(cfg=None, start=True, **ekw):
-    cfg = cfg or get_config("tiny")
+    cfg = cfg or live_config()
     params = init_params(cfg, jax.random.key(0))
     ekw.setdefault("max_slots", 4)
     ekw.setdefault("max_seq_len", 64)
@@ -339,8 +340,8 @@ def test_resurrection_bit_identical_across_modes(mode, kv_dtype):
     fault-free reference exactly — per-position sampling keys make the
     replayed continuation bit-identical on every substrate x KV
     dtype."""
-    cfg = dataclasses.replace(get_config("tiny"), kv_cache_dtype=kv_dtype)
-    # A bucket that holds the prompt folded with all 20 tokens: how many
+    cfg = live_config(kv_cache_dtype=kv_dtype)
+    # A bucket that holds the prompt folded with all its tokens: how many
     # were delivered when the fault lands is the host's timing, and a
     # fold past the largest bucket cannot be resurrected at all.
     ekw = dict(MODES[mode])
@@ -495,7 +496,7 @@ def test_sentinel_quarantines_corrupt_tokens_before_delivery():
     finally:
         eng.stop()
     assert got == want, "post-sentinel resurrection diverged"
-    assert all(0 <= t < get_config("tiny").vocab_size for t in got), \
+    assert all(0 <= t < live_config().vocab_size for t in got), \
         "a corrupt token id reached the client"
 
 

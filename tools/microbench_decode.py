@@ -205,7 +205,7 @@ def bench_spec(k: int, weights: str, kv: str, attn: str = "xla") -> None:
             st = dict(st, pos=jnp.full((B,), 128, jnp.int32),
                       remaining=jnp.full((B,), 64, jnp.int32),
                       active=jnp.ones((B,), jnp.bool_))
-            st, _, _ = fn(params, st, table, drafts, wave)
+            st = fn(params, st, table, drafts, wave)[0]
             return st
 
         dt, state = slope_time(one, state, k1=2, k2=6)
@@ -318,9 +318,9 @@ def bench_ragged(weights: str, kv: str, attn: str = "xla") -> None:
         def one(st):
             st = dict(st, pos=pos0 + 0, active=jnp.ones((B,), jnp.bool_),
                       remaining=jnp.full((B,), 64, jnp.int32))
-            st, _, _, _, _ = fn(params, st, table, tokens, plens, starts,
-                                seeds, temps, top_ks, top_ps, max_news,
-                                finals, is_prefill)
+            st = fn(params, st, table, tokens, plens, starts, seeds,
+                    temps, top_ks, top_ps, max_news, finals,
+                    is_prefill)[0]
             return st
 
         dt, state = slope_time(one, state, k1=2, k2=6)
