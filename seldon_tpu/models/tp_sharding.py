@@ -29,7 +29,12 @@ contraction:
  * embeddings / lm_head / norms are replicated; logits, samples and
    every host-visible output are therefore replicated and identical
    across the TP group by construction.
- * the KV cache (dense slab or paged pool) shards on its ``Hkv`` axis;
+ * the KV cache shards by KV head: the paged pool on its ``Hkv`` axis,
+   the dense slab, whose rows hold a token's heads side by side
+   (``Hkv * Dh`` lanes), on the row, so that each device holds the
+   contiguous lanes of its own ``Hkv / tp`` heads and the decode
+   attention contracts over that part of the row alone (``rows``): a
+   whole head group a device, never a contraction across devices;
    block tables stay host-side int32 and replicated.
 
 W8A8 stays exact for the same reason: the per-token activation scale is
@@ -134,12 +139,16 @@ def param_pspecs(cfg, params: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def state_leaf_spec(leaf) -> P:
-    """Spec for one engine-state leaf, by rank: 5D KV slabs/pools
-    [L, B|NB, Hkv, T|block, Dh] shard Hkv on 'tp'; their 4D int8 scale
-    twins [L, B|NB, Hkv, T|block] likewise; everything else (the [B]
-    per-slot scalars) replicates."""
+    """Spec for one engine-state leaf, by rank: 5D KV shards its heads
+    on 'tp', the paged pool [L, NB, Hkv, block, Dh] on the Hkv axis and
+    the slab [L, B, 1, T, Hkv * Dh] (one row a token: axis 2 is 1) on
+    the row's lanes, a contiguous group of whole heads a device; the 4D
+    int8 scales [L, B|NB, Hkv, T|block] on Hkv; everything else (the
+    [B] per-slot scalars) replicates."""
     nd = np.ndim(leaf)
     if nd == 5:
+        if np.shape(leaf)[2] == 1:
+            return P(None, None, None, None, TP_AXIS)
         return P(None, None, TP_AXIS, None, None)
     if nd == 4:
         return P(None, None, TP_AXIS, None)
@@ -206,6 +215,15 @@ class TpHints:
         """[B, S, H*Dh] or [B, S, F]: head-major flattened / hidden
         features sharded contiguously on the last axis."""
         return self._pin(x, P(None, None, TP_AXIS))
+
+    def rows(self, c):
+        """A layer of the slab [B, 1, T, Hkv * Dh], lanes sharded, as
+        rows of the device's own heads [B, tp, T, (Hkv / tp) * Dh] with
+        the group axis sharded: the same bytes on every device."""
+        B, one, T, C = c.shape
+        assert one == 1, c.shape
+        groups = c.reshape(B, T, self.tp, C // self.tp).transpose(0, 2, 1, 3)
+        return self._pin(groups, P(None, TP_AXIS, None, None))
 
     def gather(self, x):
         """Exact all-gather to replicated — pure data movement, placed

@@ -258,16 +258,16 @@ def gqa_attention(
 
 def gqa_attention_decode(
     q: jnp.ndarray,  # [B, 1, H, Dh]
-    ck: jnp.ndarray,  # [B, Hkv, T, Dh] OLD cache (pre-write; int8 if scales)
-    cv: jnp.ndarray,  # [B, Hkv, T, Dh]; or rows of several heads, below
+    ck: jnp.ndarray,  # [B, 1, T, Hkv*Dh] OLD cache (pre-write; int8 if scales)
+    cv: jnp.ndarray,  # or rows of fewer heads, [B, Hkv, T, Dh] the paged pool
     k_fresh: jnp.ndarray,  # [B, 1, Hkv, Dh] bf16 (exact, this token)
     v_fresh: jnp.ndarray,  # [B, 1, Hkv, Dh]
     mask_lt: jnp.ndarray,  # [B, 1, T] True where t < pos (strict)
     k_scale: Optional[jnp.ndarray] = None,  # [B, Hkv, T] f32 (int8 cache)
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Decode attention over the PRE-write head-major cache plus a
-    fresh-token column.
+    """Decode attention over the PRE-write cache, read as it is stored,
+    plus a fresh-token column.
 
     Why pre-write: scattering this step's k/v into the carried cache and
     slice-reading it back defeats XLA's operand fusion — the read-after-
@@ -277,9 +277,12 @@ def gqa_attention_decode(
     bf16 column appended to the score matrix, and cache writes happen
     OUTSIDE the layer scan in one batched scatter.
 
-    Why head-major [B,Hkv,T,Dh]: it is the layout the attention einsums
-    want; storing token-major made XLA insert a per-layer transpose copy
-    of every slice (seen in HLO as bf16[1,B,T,Hkv,Dh]{4,2,3,1,0} copies).
+    Why the cache is not viewed per head first: a slab row holds a
+    token's heads side by side (cache_spec), and reading it as
+    [B, T, Hkv, Dh] transposed to head-major brings a per-layer copy of
+    the slice back on every step (seen in the v5e's compiled HLO as
+    copy s8[64,1024,8,128]). The contraction over the whole row, below,
+    is what reads the slab as stored.
 
     For int8 caches the per-(token, head) scales are factored OUT of the
     einsums — scores = (q . k_q) * k_scale, out = (w * v_scale) . v_q —
@@ -290,10 +293,13 @@ def gqa_attention_decode(
     bf16 — requantization noise only enters through PAST tokens.
 
     A cache whose rows hold `side` heads side by side,
-    [B, Hkv / side, T, side * Dh] (kv_heads_per_row; told by the shapes:
-    k_fresh has the model's Hkv), is read as stored: the queries go
-    block-diagonal over the row (_beside) and each keeps its own head's
-    lanes of the weighted values (_own_side)."""
+    [B, Hkv / side, T, side * Dh] (kv_heads_per_row: all Hkv in the
+    slab, 1 in the paged pool's view; told by the shapes: k_fresh has
+    the model's Hkv), is read as stored: the queries go block-diagonal
+    over the row (_beside) and each keeps its own head's lanes of the
+    weighted values (_own_side). The int8 scales stay [B, Hkv, T]
+    whatever the rows: a query's scores and weights take the scale of
+    its own head (_head_scale)."""
     B, S, H, Dh = q.shape
     Hkv = k_fresh.shape[2]
     side = Hkv // ck.shape[1]  # heads side by side in a cache row
@@ -306,7 +312,7 @@ def gqa_attention_decode(
             preferred_element_type=jnp.float32,
         ) / (Dh**0.5)
         if k_scale is not None:
-            scores = scores * k_scale[:, :, None, None, :]
+            scores = scores * _head_scale(k_scale, side, G)
         s_fresh = jnp.einsum(
             "bskgd,bukd->bkgsu", qr, k_fresh.astype(qr.dtype),
             preferred_element_type=jnp.float32,
@@ -324,7 +330,7 @@ def gqa_attention_decode(
         l = jnp.sum(p, axis=-1, keepdims=True) + p_f
         wc = p / l
         if v_scale is not None:
-            wc = wc * v_scale[:, :, None, None, :]
+            wc = wc * _head_scale(v_scale, side, G)
     with jax.named_scope("attn/out"):
         out = jnp.einsum(
             "bkgst,bktd->bskgd", wc.astype(qr.dtype), cv.astype(qr.dtype)
@@ -340,22 +346,26 @@ def gqa_attention_decode(
 
 
 def kv_heads_per_row(cfg: ModelConfig) -> int:
-    """KV heads that share one row of the cache (cache_spec): all of
-    them in a patterned stack, whose slab is [La, B, 1, T, Hkv * Dh],
-    one row a token; 1 in a homogeneous stack, head-major as before.
+    """KV heads that share one row of the slab (cache_spec): all of
+    them, [La, B, 1, T, Hkv * Dh], one row a token, in every stack.
 
-    Why (lfm2.chat, heads of 64; PERF.md section 6, PR 28): a [T, 64]
-    tile fills half the TPU's 128 lanes, so the compiler kept the
-    head-major slab T-minor for the layer scan's attention and
-    token-major for the step's scatter, and relaid the whole of it out
-    between the two: on the chunk's entry, on EVERY decode step and on
-    its exit, 1.85 ms of a 7.15 ms step. With a single row a token both
-    agree on the layout as stored and the chunk holds no copy of the
-    slab. The price is gqa_attention_decode's contraction over the whole
-    row (_beside: Hkv times the products, of which all but a head's own
-    are with zeros): n_heads FLOPs a byte of slab read, far under the
-    chip's ~240, so the step stays bound by the read it always made."""
-    return cfg.n_kv_heads if cfg.patterned else 1
+    Why (PERF.md section 6, PR 28 for heads of 64, PR 32 for heads of
+    128): the decode step WRITES a token's heads together and the layer
+    scan's attention READS a layer at a time. With the slab head-major
+    [L, B, Hkv, T, Dh] the v5e compiler kept it T-minor for the
+    attention and token-major ({4,2,3,1,0}) for the step's scatter and
+    relaid the whole of it out between the two: on the chunk's entry
+    and exit, and a layer's K and V slice on EVERY step of every layer
+    (31 % of mistral7b.chat's device time, ledger PR 31; the form of
+    the scatter alone did not cure it). With a single row a token both
+    agree on the layout as stored: the compiled chunk holds no copy of
+    the slab and the layer's slice is read inside the attention fusion.
+    Token-major storage no longer costs the per-layer transpose it
+    once did because attention does not view the row per head: it
+    contracts over the whole row (_beside: Hkv times the products, of
+    which all but a head's own are with zeros), n_heads FLOPs a byte of
+    a bf16 slab, twice that of an int8 one."""
+    return cfg.n_kv_heads
 
 
 def _kv_rows(x: jnp.ndarray, side: int) -> jnp.ndarray:
@@ -385,6 +395,33 @@ def _own_side(out: jnp.ndarray, side: int) -> jnp.ndarray:
     o7 = out.reshape(B, S, Hc, side, N // side, side, C // side)
     own = jnp.stack([o7[:, :, :, j, :, j] for j in range(side)], axis=3)
     return own.reshape(B, S, Hc * side, N // side, C // side)
+
+
+def _head_scale(scale: jnp.ndarray, side: int, G: int) -> jnp.ndarray:
+    """int8 scales [B, Hkv, T] against scores or weights
+    [B, Hkv / side, side * G, S, T]: query n of a row reads KV head
+    n // G of that row."""
+    B, Hkv, T = scale.shape
+    per_query = jnp.broadcast_to(
+        scale.reshape(B, Hkv // side, side, 1, 1, T),
+        (B, Hkv // side, side, G, 1, T))
+    return per_query.reshape(B, Hkv // side, side * G, 1, T)
+
+
+def _kv_slab(x: jnp.ndarray, side: int) -> jnp.ndarray:
+    """Fresh k or v [B, S, Hkv, Dh] as a layer of the slab holds them,
+    [B, Hkv / side, S, side * Dh]: with a row a token (side = Hkv) the
+    transpose is over an axis of 1 and moves nothing."""
+    return _kv_rows(x, side).transpose(0, 2, 1, 3)
+
+
+def _kv_tokens(c: jnp.ndarray, n_kv_heads: int) -> jnp.ndarray:
+    """A cache layer [B, Hkv / side, P, side * Dh], slab rows or the
+    paged pool's head-major view alike, as attention over whole
+    sequences wants it: [B, P, Hkv, Dh]."""
+    B, Hc, P_, C = c.shape
+    return c.transpose(0, 2, 1, 3).reshape(
+        B, P_, n_kv_heads, Hc * C // n_kv_heads)
 
 
 def moe_block(x: jnp.ndarray, bp: Dict[str, jnp.ndarray], cfg: ModelConfig):
@@ -453,16 +490,43 @@ def _quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return q, scale.astype(jnp.bfloat16)
 
 
+def _quantize_kv_rows(x: jnp.ndarray, head_dim: int):
+    """_quantize_kv over stacked cache rows [L, B, Hkv / side, S,
+    side * Dh]: each head's lanes by that head's own scale. Returns
+    (int8 rows of the same shape, scales [L, B, Hkv, S] as every cache
+    layout holds them)."""
+    L, B, Hc, S, C = x.shape
+    side = C // head_dim
+    q, scale = _quantize_kv(x.reshape(L, B, Hc, S, side, head_dim))
+    return q.reshape(x.shape), scale.transpose(0, 1, 2, 4, 3).reshape(
+        L, B, Hc * side, S)
+
+
 def kv_writes(kv: Cache, cache: Cache, cfg: ModelConfig) -> Cache:
-    """Fresh k / v (a prefill's stacked ys, in the activation dtype) as
-    `cache` stores them: int8 with per-(token, head) scales, or a cast.
-    What every admission scatters, into a slab or through block tables."""
+    """Fresh k / v (a prefill's stacked ys in the activation dtype, slab
+    rows or by head) as `cache` stores them: int8 with per-(token, head)
+    scales, or a cast. What every admission scatters, into a slab or
+    through block tables."""
     if cfg.kv_cache_dtype == "int8":
-        kq, ks = _quantize_kv(kv["k"])
-        vq, vs = _quantize_kv(kv["v"])
+        kq, ks = _quantize_kv_rows(kv["k"], cfg.head_dim)
+        vq, vs = _quantize_kv_rows(kv["v"], cfg.head_dim)
         return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
     dt = cache["k"].dtype
     return {"k": kv["k"].astype(dt), "v": kv["v"].astype(dt)}
+
+
+def kv_by_head(kv: Cache, cfg: ModelConfig) -> Cache:
+    """Slab-layout k / v [L, B, 1, S, Hkv * Dh] (a cold prefill's) as
+    the paged pool and its scatter hold them, head-major
+    [L, B, Hkv, S, Dh]: a transpose of one admission's fresh KV, never
+    of a cache. The int8 scales are [L, B, Hkv, S] in both and pass
+    through."""
+    def turn(x):
+        L, B, _, S, C = x.shape
+        return x.reshape(L, B, S, cfg.n_kv_heads, cfg.head_dim).transpose(
+            0, 1, 3, 2, 4)
+    return {key: turn(x) if key in ("k", "v") else x
+            for key, x in kv.items()}
 
 
 def _block(
@@ -594,9 +658,10 @@ def _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
     """Layer scan for PREFILL: attention runs over the fresh k/v only
     (every serving prefill starts at position 0, so the fresh tokens ARE
     the whole visible window — the cache is never read) and each layer's
-    rope'd k/v come back as scan ys, stacked [L, B, Hkv, S, Dh], exactly
-    the head-major cache layout. The caller builds/updates the cache from
-    them in ONE operation — no per-layer cache traffic at all.
+    rope'd k/v come back as scan ys, stacked [L, B, 1, S, Hkv * Dh],
+    exactly the slab's layout (a reshape of the fresh [B, S, Hkv, Dh]).
+    The caller builds/updates the cache from them in ONE operation — no
+    per-layer cache traffic at all.
 
     `ring_mesh` (with cfg.attn_impl == "ring") runs the attention as
     CONTEXT-PARALLEL ring attention over the 'sp' mesh axis — long
@@ -605,6 +670,7 @@ def _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
     are full arrays; GSPMD gathers the sp shards when the caller
     scatters them into the (T-unsharded) decode cache. Returns
     (x, {"k","v"} stacked bf16, aux)."""
+    side = kv_heads_per_row(cfg)
 
     def body(carry, bp):
         h = rms_norm(carry, bp["attn_norm"], cfg.rms_norm_eps)
@@ -640,8 +706,8 @@ def _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
         x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp)
-        # ys in cache layout: [B, Hkv, S, Dh] per layer.
-        return x, (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), aux)
+        # ys in cache layout: [B, 1, S, Hkv * Dh] per layer.
+        return x, (_kv_slab(k, side), _kv_slab(v, side), aux)
 
     x, (ks, vs, aux) = jax.lax.scan(body, x, params["blocks"])
     return x, {"k": ks, "v": vs}, jnp.mean(aux)
@@ -651,33 +717,41 @@ def _run_blocks_prefill_prefix(params, x, cfg, positions, inv_freq, mask,
                                prefix_kv, tp=None):
     """Layer scan for SUFFIX prefill (prefix-cache admissions): attention
     runs over reused prefix KV plus the fresh suffix k/v. `prefix_kv` is
-    {"k","v"[,"k_scale","v_scale"]} stacked [L, B, Hkv, Pb, (Dh)] in
-    cache storage dtype — it rides the scan as xs next to the blocks, so
-    each layer reads exactly its own [B, Hkv, Pb, Dh] slice (int8 caches
-    dequantize per layer; the scales' relative error already sits below
-    the int8 noise, see _quantize_kv). Fresh suffix k/v come back as ys
-    in cache layout, same contract as _run_blocks_prefill."""
+    {"k","v"[,"k_scale","v_scale"]} in cache storage dtype, stacked slab
+    rows [L, B, 1, Pb, Hkv * Dh] or the paged pool's head-major view
+    [L, B, Hkv, Pb, Dh] (told by the shapes; scales [L, B, Hkv, Pb] in
+    both) — it rides the scan as xs next to the blocks, so each layer
+    reads exactly its own slice (int8 caches dequantize per layer; the
+    scales' relative error already sits below the int8 noise, see
+    _quantize_kv). Fresh suffix k/v come back as ys in the layout the
+    prefix came in (its caller scatters them where the prefix was
+    read), stacked like _run_blocks_prefill's."""
     quantized = "k_scale" in prefix_kv
+    Hkv = cfg.n_kv_heads
+    side = Hkv // prefix_kv["k"].shape[2]  # heads in a row of the prefix
 
     def body(carry, xs):
         bp, pl = xs
         h = rms_norm(carry, bp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(h, bp, cfg, positions, inv_freq, tp=tp)
-        pk = pl["k"].astype(q.dtype)
-        pv = pl["v"].astype(q.dtype)
+        # Attention wants the prefix as token-major columns
+        # [B, Pb, Hkv, Dh] in front of the fresh suffix: a reshape of
+        # slab rows, a transpose of the pool's head-major view.
+        pk = _kv_tokens(pl["k"], Hkv).astype(q.dtype)
+        pv = _kv_tokens(pl["v"], Hkv).astype(q.dtype)
         if quantized:
             # Barrier-pinned like ops/ragged_paged_attention._sparse_block:
             # the dequanted prefix must materialize to ONE value before
             # the concat so every consumer fusion reads the same bits
             # (certified by graftlint's num-barrier pass).
             pk = jax.lax.optimization_barrier(
-                pk * pl["k_scale"][..., None].astype(q.dtype))
+                pk * pl["k_scale"].transpose(0, 2, 1)[..., None].astype(
+                    q.dtype))
             pv = jax.lax.optimization_barrier(
-                pv * pl["v_scale"][..., None].astype(q.dtype))
-        # Prefix is head-major [B, Hkv, Pb, Dh]; attention wants
-        # token-major columns in front of the fresh suffix.
-        k_all = jnp.concatenate([pk.transpose(0, 2, 1, 3), k], axis=1)
-        v_all = jnp.concatenate([pv.transpose(0, 2, 1, 3), v], axis=1)
+                pv * pl["v_scale"].transpose(0, 2, 1)[..., None].astype(
+                    q.dtype))
+        k_all = jnp.concatenate([pk, k], axis=1)
+        v_all = jnp.concatenate([pv, v], axis=1)
         if tp is not None:
             k_all, v_all = tp.heads(k_all), tp.heads(v_all)
         attn = gqa_attention(q, k_all, v_all, mask)
@@ -686,7 +760,7 @@ def _run_blocks_prefill_prefix(params, x, cfg, positions, inv_freq, mask,
         with jax.named_scope("attn/out"):
             x = carry + _qdot(attn, bp, "wo", cfg)
         x, aux = _mlp_res(x, bp, cfg, None, tp=tp)
-        return x, (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), aux)
+        return x, (_kv_slab(k, side), _kv_slab(v, side), aux)
 
     x, (ks, vs, aux) = jax.lax.scan(body, x, (params["blocks"], prefix_kv))
     return x, {"k": ks, "v": vs}, jnp.mean(aux)
@@ -709,10 +783,16 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
     quantized = cfg.kv_cache_dtype == "int8"
     Smax = cache["k"].shape[3]
     mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
+    side = kv_heads_per_row(cfg)
 
     def attend(q, k, v, cl):
+        ck, cv = cl["k"], cl["v"]
+        if tp is not None:
+            # Each device contracts over its own head group's lanes of
+            # the row, never over another's (tp_sharding module doc).
+            ck, cv = tp.rows(ck), tp.rows(cv)
         return gqa_attention_decode(
-            q, cl["k"], cl["v"], k, v, mask_lt,
+            q, ck, cv, k, v, mask_lt,
             k_scale=cl.get("k_scale"), v_scale=cl.get("v_scale"),
         )
 
@@ -729,24 +809,39 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
             x = jax.lax.with_sharding_constraint(x, act_spec)
         x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp)
         if quantized:
-            kq, ksc = _quantize_kv(k[:, 0])
-            vq, vsc = _quantize_kv(v[:, 0])
-            fresh = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
+            kq, ksc = _quantize_kv(k)
+            vq, vsc = _quantize_kv(v)
+            fresh = {"k": _kv_rows(kq, side)[:, 0],
+                     "v": _kv_rows(vq, side)[:, 0],
+                     "k_scale": ksc[:, 0], "v_scale": vsc[:, 0]}
         else:
             dt = cache["k"].dtype
-            fresh = {"k": k[:, 0].astype(dt), "v": v[:, 0].astype(dt)}
+            fresh = {"k": _kv_rows(k, side)[:, 0].astype(dt),
+                     "v": _kv_rows(v, side)[:, 0].astype(dt)}
         return x, (fresh, aux)
 
     x, (fresh, aux) = jax.lax.scan(body, x, (params["blocks"], cache))
     rows = jnp.arange(pos.shape[0])
-    # One scatter covers all layers. k/v are [L,B,Hkv,T,Dh]; advanced
-    # indices (rows on dim 1, pos on dim 3) land in front, so the update
-    # operand is fresh[key] [L,B,Hkv,(Dh)] transposed to [B,L,Hkv,(Dh)].
+    # k / v: one scatter covers all layers, with layer, row and position
+    # all INDICES of it and only the token's row [1, Hkv*Dh] its window:
+    # advanced indices land in front, so the update operand is fresh[key]
+    # [L, B, ...] transposed to [B, L, ...]. With the layer axis a window
+    # dimension (`.at[:, rows, :, pos]`) the TPU compiler carried the
+    # slab layer-minor through the chunk's steps and relaid the whole of
+    # it out for the layer scan on every step (kv_heads_per_row).
+    # The int8 scales [L, B, Hkv, T] are T-minor, so a token's are single
+    # elements T apart: a select over the array (1/64 of the slab) writes
+    # them. Scattered, the v5e compiler kept k_scale in fast memory
+    # through the chunk and moved all of it out and back in inside every
+    # layer: 2.7 ms of a 22.2 ms step (PERF.md section 6, PR 32).
+    layers = jnp.arange(cache["k"].shape[0])[None, :]
+    here = (jnp.arange(Smax)[None, :] == pos[:, None])[None, :, None, :]
     with jax.named_scope("attn/cache_update"):
         new_cache = {
-            key: cache[key].at[:, rows, :, pos].set(
-                jnp.swapaxes(fresh[key], 0, 1), unique_indices=True
-            )
+            key: cache[key].at[layers, rows[:, None], :, pos[:, None]].set(
+                jnp.swapaxes(fresh[key], 0, 1), unique_indices=True)
+            if key in ("k", "v") else
+            jnp.where(here, fresh[key][..., None], cache[key])
             for key in cache
         }
     return x, new_cache, jnp.mean(aux)
@@ -834,17 +929,26 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
     closed forms (kv_bytes_per_token, state_bytes_per_slot: no JAX there)
     are held equal to it by tests/test_patterned.py.
 
-    KV is HEAD-major [La, B, Hkv, T, Dh] (scales [La, B, Hkv, T]) over
-    the La layers that hold KV: every layer of a homogeneous stack, the
-    attention layers of a patterned one, whose rows hold all of a
-    token's heads: [La, B, 1, T, Hkv * Dh] (kv_heads_per_row; the same
-    five axes, T still at 3). Head-major is the layout the
-    decode attention einsums consume; stored token-major, XLA inserted a
-    per-layer transpose copy of every slice (~2x attention cost at
-    [160 slots, 257 window] on v5e). The write side no longer cares
-    about layout: since the cache is read pre-write
-    (gqa_attention_decode), all layers' fresh k/v land in ONE batched
-    scatter per step (_run_blocks_decode), not L per-layer scatters.
+    KV is one row a token, [La, B, 1, T, Hkv * Dh], over the La layers
+    that hold KV: every layer of a homogeneous stack, the attention
+    layers of a patterned one (kv_heads_per_row; five axes with T at 3,
+    so that a head-major array indexes alike). The int8 scales are per
+    (token, head), [La, B, Hkv, T]: T-minor, because a trailing axis of
+    Hkv = 8 would be padded to the TPU's 128 lanes, and small enough
+    (1/64 of the slab) that how they are carried costs little.
+
+    Why a row a token (the v5e's compiled HLO, PERF.md section 6, PR 28
+    and PR 32): it is the layout BOTH sides of a decode chunk want. The
+    step writes a token's heads together, in ONE batched scatter for all
+    layers after the layer scan (_run_blocks_decode: the cache is read
+    pre-write, gqa_attention_decode); the scan reads a layer at a time.
+    Stored head-major [La, B, Hkv, T, Dh] the compiler kept two layouts
+    of the slab, one for each side, and copied the whole of it between
+    them on every chunk and a layer's slice on every step. Stored
+    token-major and VIEWED per head ([B, T, Hkv, Dh] transposed for the
+    einsums) it copied every layer's slice on every step instead. The
+    attention now contracts over the row as stored (_beside), so
+    neither copy is left.
 
     The conv state is [Lc, B, conv_kernel - 1, D] over the Lc conv
     layers of a patterned stack: the gated inputs u of the slot's last
@@ -858,7 +962,7 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
             "dtype override is meaningless for an int8 cache (slots are "
             "int8 + f32 scales by construction)"
         )
-        sshape = shape[:-1]  # [L, B, Hkv, T]
+        sshape = (cfg.n_attn_layers, batch, cfg.n_kv_heads, max_len)
         spec["k"] = CacheEntry("kv", shape, jnp.int8, 0, 3)
         spec["v"] = CacheEntry("kv", shape, jnp.int8, 0, 3)
         # Scales min-clamped at init so a read of a never-written slot
@@ -902,10 +1006,10 @@ def cache_scatter_slots(cfg: ModelConfig, cache: Cache, sub: Cache,
                         slots: jnp.ndarray, width: int) -> Cache:
     """Admission: the group's freshly prefilled cache `sub` ([*, G, ...],
     `width` positions) into rows `slots` of the slab, array by array as
-    cache_spec describes them. Arrays with a token axis (k/v + scales:
-    head-major [L, B, Hkv, T, ...], T at dim 3 of k/v and trailing on
-    the scales, so one indexing expression covers them all) take the
-    first `width` positions; a fixed-size state (the conv state) is
+    cache_spec describes them. Arrays with a token axis (k/v
+    [L, B, 1, T, Hkv*Dh] + scales [L, B, Hkv, T]: T at dim 3 of k/v and
+    trailing on the scales, so one indexing expression covers them all)
+    take the first `width` positions; a fixed-size state (the conv state) is
     overwritten whole, so a reused slot keeps nothing of its last
     request."""
     spec = cache_spec(cfg, 1, 1)
@@ -1169,7 +1273,8 @@ def prefill_with_prefix(
     params: Params,
     tokens: jnp.ndarray,  # [B, Sq] right-padded SUFFIX tokens
     prompt_lens: jnp.ndarray,  # [B] FULL prompt lengths
-    prefix_kv: Cache,  # [L, B, Hkv, Pb, (Dh)] reused prefix, cache dtype
+    prefix_kv: Cache,  # reused prefix, cache dtype: slab rows
+    # [L, B, 1, Pb, Hkv*Dh] or the pool's view [L, B, Hkv, Pb, Dh]
     prefix_lens: jnp.ndarray,  # [B] true prefix lengths (<= Pb)
     cfg: ModelConfig,
     tp=None,
@@ -1187,8 +1292,9 @@ def prefill_with_prefix(
     decode-side strict t < pos mask guarantees write-before-read.
 
     Returns (next-token logits [B, V] at each row's last real suffix
-    token, fresh suffix KV {"k","v"} stacked [L, B, Hkv, Sq, Dh] bf16 —
-    the caller scatters prefix and suffix into the slot cache)."""
+    token, fresh suffix KV {"k","v"} bf16, stacked in prefix_kv's layout:
+    slab rows [L, B, 1, Sq, Hkv*Dh] or by head [L, B, Hkv, Sq, Dh] — the
+    caller scatters prefix and suffix into the slot cache)."""
     refuse_patterned(cfg, "suffix prefill over a reused prefix")
     B, Sq = tokens.shape
     Pb = prefix_kv["k"].shape[3]
@@ -1543,8 +1649,8 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
                     attn = gqa_attention(q, k, v, mask)
                     with jax.named_scope("attn/out"):
                         x = x + _qdot(attn, lp, "wo", cfg)
-                    ks.append(_kv_rows(k, side).transpose(0, 2, 1, 3))
-                    vs.append(_kv_rows(v, side).transpose(0, 2, 1, 3))
+                    ks.append(_kv_slab(k, side))
+                    vs.append(_kv_slab(v, side))
                 else:
                     y, st = _conv_op(h, lp, cfg, plens=plens)
                     x = x + y

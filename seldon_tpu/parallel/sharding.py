@@ -73,11 +73,12 @@ def param_pspecs(cfg, quantized: bool = False) -> Dict[str, Any]:
 def cache_pspec(cfg=None) -> Any:
     """KV-cache shardings: batch over dp, kv heads over tp.
 
-    Returns a spec DICT matching transformer.init_cache's head-major
-    leaves: k/v [L, B, Hkv, T, Dh] (+ k_scale/v_scale [L, B, Hkv, T] for
-    kv_cache_dtype == "int8" configs). Apply with
-    `jax.tree.map(..., cache, cache_pspec(cfg))`."""
-    kv = P(None, "dp", "tp", None, None)
+    Returns a spec DICT matching transformer.init_cache's leaves: k/v
+    [L, B, 1, T, Hkv * Dh], a token's heads side by side in a row, so
+    'tp' takes the row's lanes (whole heads a device while tp divides
+    Hkv) (+ k_scale/v_scale [L, B, Hkv, T] for kv_cache_dtype == "int8"
+    configs). Apply with `jax.tree.map(..., cache, cache_pspec(cfg))`."""
+    kv = P(None, "dp", None, None, "tp")
     specs = {"k": kv, "v": kv}
     if cfg is not None and getattr(cfg, "kv_cache_dtype", "bf16") == "int8":
         scale = P(None, "dp", "tp", None)

@@ -7,7 +7,8 @@ answer, vLLM-style iteration-level scheduling mapped onto XLA's static-shape
 world:
 
  * A fixed pool of B slots shares one pre-allocated KV cache
-   [L, B, Smax, Hkv, Dh]; decode runs in CHUNKS of `decode_chunk` steps —
+   [L, B, 1, Smax, Hkv*Dh] (one row a token, transformer.cache_spec);
+   decode runs in CHUNKS of `decode_chunk` steps —
    one jitted `lax.scan` over all slots per dispatch — so the host pays
    one dispatch + one sync per K tokens/slot instead of per token.
    Per-row EOS/length termination inside the chunk is value-level masking.
@@ -1619,8 +1620,8 @@ class InferenceEngine:
         )
         if return_sub:
             # Prefix-cache insertion path: `sub` already holds the
-            # cache-dtype KV writes [L, G, Hkv, Sb, (Dh)] the host slices
-            # into trie blocks.
+            # cache-dtype KV writes, slab rows [L, G, 1, Sb, Hkv*Dh] (scales
+            # [L, G, Hkv, Sb]), that the host slices into trie blocks.
             return new_state, first, first_done, sub
         return new_state, first, first_done
 
@@ -1636,7 +1637,8 @@ class InferenceEngine:
 
         `toks` holds ONLY each prompt's uncached suffix [G, Sq]; `plens`
         are FULL prompt lengths (slot_rules.first_key).
-        `prefix_kv` arrives in cache storage dtype [L, G, Hkv, Pb, (Dh)]
+        `prefix_kv` arrives in cache storage dtype, slab rows
+        [L, G, 1, Pb, Hkv*Dh] (int8 scales [L, G, Hkv, Pb])
         (gathered host-side from the trie, zero-padded past each row's
         prefix_len — the padded tail is overwritten by the suffix scatter
         below, and decode's strict t < pos mask never reads past-plen
@@ -1660,8 +1662,8 @@ class InferenceEngine:
                 prefix_kv[key].astype(cache[key].dtype)
             )
             # Advanced indices (slots, spos) broadcast to [G, Sq] and land
-            # in front: update operand is writes[key] [L, G, Hkv, Sq, ...]
-            # with G and Sq moved to the front.
+            # in front: update operand is writes[key] [L, G, 1, Sq, Hkv*Dh]
+            # (scales [L, G, Hkv, Sq]) with G and Sq moved to the front.
             new_cache[key] = c.at[:, slots[:, None], :, spos].set(
                 jnp.moveaxis(writes[key], (1, 3), (0, 1))
             )
@@ -1741,9 +1743,9 @@ class InferenceEngine:
     @staticmethod
     def _seed_prefix_impl(state, prefix_kv, slot):
         """Chunked-prefill warm start: scatter a prefix-cache hit's
-        trie-gathered KV [L, Hkv, W, (Dh)] into one slot's cache rows
-        [0, W), so every chunk reads resident KV uniformly whether it
-        came from the trie or from earlier chunks."""
+        trie-gathered KV [L, 1, W, Hkv*Dh] (scales [L, Hkv, W]) into one
+        slot's cache rows [0, W), so every chunk reads resident KV
+        uniformly whether it came from the trie or from earlier chunks."""
         cache = state["cache"]
         W = prefix_kv["k"].shape[2]
         new_cache = {
@@ -1830,6 +1832,8 @@ class InferenceEngine:
             sub = transformer.init_cache(cfg, G, Sb)
             logits, writes = transformer.prefill(params, toks, plens, sub,
                                                  cfg, tp=tp)
+            # A cold prefill fills slab rows; the pool is by head.
+            writes = transformer.kv_by_head(writes, cfg)
             spos = jnp.broadcast_to(jnp.arange(Sb)[None, :], (G, Sb))
         first, first_done = slot_rules.first_token(
             logits, seeds, plens, temps, top_ks, top_ps, max_news, Smax, cfg)
@@ -3130,7 +3134,7 @@ class InferenceEngine:
         if Pb:
             # Per-row device gather of the pinned trie path, zero-padded
             # to the prefix bucket and stacked on the batch axis (dim 1
-            # of the [L, G, Hkv, Pb, ...] cache layout).
+            # of the [L, G, 1, Pb, Hkv*Dh] cache layout).
             rows = [
                 self._prefix.gather(group[min(i, G - 1)].prefix_handle, Pb)
                 for i in range(Gp)
@@ -3209,7 +3213,7 @@ class InferenceEngine:
     def _insert_prompt_kv(self, group: List[_Request], writes: Dict[str, Any],
                           warm: bool) -> None:
         """Insert each admitted prompt's KV into the prefix trie. `writes`
-        holds cache-dtype KV [L, G(padded), Hkv, S, ...] — full prompts
+        holds cache-dtype KV [L, G(padded), 1, S, Hkv*Dh] — full prompts
         for cold groups, uncached suffixes for warm ones (warm block
         spans are rebased by the row's prefix_len; the prefix blocks
         themselves already live in the trie, pinned by the row's handle,

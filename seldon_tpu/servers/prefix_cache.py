@@ -4,12 +4,12 @@ The SGLang/DeepServe idea (PAPERS.md: arXiv 2501.14417 reports large
 TTFT/throughput wins from KV reuse at scale) mapped onto this engine's
 static-shape world: a radix trie keyed by fixed-size token BLOCKS, each
 node owning that block's KV segment for every layer — jax device arrays
-in cache storage dtype ([L, Hkv, block, Dh] k/v, plus [L, Hkv, block]
-scales for int8 caches). Block granularity keeps reuse block-aligned so
-admission shapes stay bucketable (one compile variant per prefix bucket,
-mirroring the engine's prompt_buckets discipline), and the trie dedups
-shared prefixes structurally — two prompts sharing a system prompt share
-the nodes, not copies.
+in cache storage dtype (slab rows [L, 1, block, Hkv*Dh] k/v, plus
+[L, Hkv, block] scales for int8 caches). Block granularity keeps reuse
+block-aligned so admission shapes stay bucketable (one compile variant
+per prefix bucket, mirroring the engine's prompt_buckets discipline),
+and the trie dedups shared prefixes structurally — two prompts sharing
+a system prompt share the nodes, not copies.
 
 Concurrency/lifetime model (engine scheduler + boundary-fetcher threads):
  * `lookup` pins the matched path (refcount) and returns a PrefixHandle;
@@ -40,7 +40,8 @@ class _Node:
         self.key = key
         self.parent = parent
         self.children: Dict[Tuple[int, ...], "_Node"] = {}
-        self.arrays = arrays  # cache key -> [L, Hkv, block, (Dh)]
+        # cache key -> k/v [L, 1, block, Hkv*Dh], scales [L, Hkv, block]
+        self.arrays = arrays
         self.nbytes = nbytes
         self.refs = 0
         self.tick = tick

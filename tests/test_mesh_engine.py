@@ -187,6 +187,60 @@ def test_sampled_bit_identical_tp2_vs_tp1():
 # ---------------------------------------------------------------------------
 
 
+def test_slab_rows_shard_into_whole_head_groups():
+    """The dense slab stores a token's heads side by side in one row
+    (transformer.cache_spec): 'tp' takes the row's lanes, so that each
+    device holds the contiguous lanes of its own Hkv / tp heads (and
+    those heads' int8 scales), the paged pool still shards its Hkv
+    axis, and TpHints.rows views a device's lanes as rows of its own
+    head group without moving a byte between devices."""
+    import numpy as np
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from seldon_tpu.models import transformer
+
+    cfg = dataclasses.replace(get_config("tiny"), kv_cache_dtype="int8")
+    Hkv, Dh, tp = cfg.n_kv_heads, cfg.head_dim, 2
+    assert Hkv % tp == 0
+    slab = transformer.init_cache(cfg, 4, 16)
+    pool = transformer.init_paged_cache(cfg, 6, 8)
+    assert tp_sharding.state_leaf_spec(slab["k"]) == \
+        P(None, None, None, None, "tp")
+    assert tp_sharding.state_leaf_spec(slab["k_scale"]) == \
+        P(None, None, "tp", None)
+    assert tp_sharding.state_leaf_spec(pool["k"]) == \
+        P(None, None, "tp", None, None)
+    assert tp_sharding.state_leaf_spec(jnp.zeros((4,))) == P()
+
+    mesh = mesh_engine.build_tp_mesh(tp)
+    # Lane c of a row holds head c // Dh: mark every lane with its head.
+    L, B, _, T, C = slab["k"].shape
+    heads = jnp.broadcast_to(
+        (jnp.arange(C) // Dh).astype(jnp.int8), slab["k"].shape)
+    sharded = tp_sharding.shard_state(mesh, {"k": heads})["k"]
+    group = Hkv // tp
+    for shard in sharded.addressable_shards:
+        d = list(mesh.devices.flat).index(shard.device)
+        got = np.unique(np.asarray(shard.data))
+        assert list(got) == list(range(d * group, (d + 1) * group))
+        assert shard.data.shape == (L, B, 1, T, C // tp)
+
+    hints = tp_sharding.hints(mesh, tp)
+    view = jax.jit(hints.rows)
+    layer = sharded[0]
+    out = view(layer)
+    assert out.shape == (B, tp, T, C // tp)
+    for g in range(tp):  # group g = the heads of device g, lanes in order
+        np.testing.assert_array_equal(
+            np.asarray(out[:, g]),
+            np.asarray(heads[0, :, 0, :, g * (C // tp):(g + 1) * (C // tp)]))
+    hlo = view.lower(layer).compile().as_text()
+    for collective in ("all-gather", "all-to-all", "all-reduce",
+                       "collective-permute"):
+        assert collective not in hlo, collective
+
+
 def test_validate_rejects_indivisible_configs():
     cfg = get_config("tiny")  # n_kv_heads=2, n_heads=4, d_ff=128
     with pytest.raises(ValueError, match="n_kv_heads"):

@@ -208,7 +208,10 @@ def test_int8_kv_cache_matches_bf16_decode():
         cache = transformer.init_cache(c, 1, 32)
         if c.kv_cache_dtype == "int8":
             assert cache["k"].dtype == jnp.int8
-            assert cache["k_scale"].shape == cache["k"].shape[:-1]
+            # One row a token, one scale a (token, head).
+            L, B, one, T, C = cache["k"].shape
+            assert (one, C) == (1, c.n_kv_heads * c.head_dim)
+            assert cache["k_scale"].shape == (L, B, c.n_kv_heads, T)
         logits, cache = transformer.prefill(
             params, prompt, jnp.array([4]), cache, c
         )
@@ -229,6 +232,66 @@ def test_int8_kv_cache_matches_bf16_decode():
     for i, (a, b) in enumerate(zip(ref[1:], quant[1:])):
         rel = float(jnp.max(jnp.abs(a - b))) / float(jnp.max(jnp.abs(a)))
         assert rel < 0.02, (i, rel)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("side", [1, 2, 8])
+def test_decode_attention_reads_rows_of_heads_as_the_plain_one(side, kv_dtype):
+    """gqa_attention_decode over cache rows that hold `side` of the 8 KV
+    heads side by side ([B, 8 / side, T, side * Dh]: 8 is the slab,
+    cache_spec; 1 the paged pool's view), in float32, against attention
+    written out per head on the dequantized cache. int8 KV keeps its
+    scales [B, Hkv, T], per (token, head), and neighbouring heads of a
+    row differ in scale by 100 x: a query that took its neighbour's
+    scale would be off by that factor."""
+    from seldon_tpu.models import transformer as T
+
+    B, T_, Hkv, G, Dh = 3, 16, 8, 2, 8
+    ks = jax.random.split(jax.random.key(11), 7)
+    q = jax.random.normal(ks[0], (B, 1, Hkv * G, Dh))
+    kf, vf = (jax.random.normal(k, (B, 1, Hkv, Dh)) for k in ks[3:5])
+    plen = jnp.asarray([5, 16, 1])
+    mask_lt = jnp.arange(T_)[None, None, :] < plen[:, None, None]
+    scales = {}
+    if kv_dtype == "int8":
+        ck, cv = (jax.random.randint(k, (B, Hkv, T_, Dh), -127, 128
+                                     ).astype(jnp.int8) for k in ks[1:3])
+        per_head = jnp.where(jnp.arange(Hkv) % 2 == 0, 1.0, 100.0)
+        for name, k in (("k_scale", ks[5]), ("v_scale", ks[6])):
+            scales[name] = (
+                0.01 * per_head[None, :, None]
+                * jax.random.uniform(k, (B, Hkv, T_), minval=0.5, maxval=1.5)
+            ).astype(jnp.bfloat16)
+        dk = ck.astype(jnp.float32) * scales["k_scale"].astype(
+            jnp.float32)[..., None]
+        dv = cv.astype(jnp.float32) * scales["v_scale"].astype(
+            jnp.float32)[..., None]
+    else:
+        ck, cv = (jax.random.normal(k, (B, Hkv, T_, Dh)) for k in ks[1:3])
+        dk, dv = ck, cv
+
+    def rows(c):  # [B, Hkv, T, Dh] -> [B, Hkv / side, T, side * Dh]
+        return c.reshape(B, Hkv // side, side, T_, Dh).transpose(
+            0, 1, 3, 2, 4).reshape(B, Hkv // side, T_, side * Dh)
+
+    got = T.gqa_attention_decode(q, rows(ck), rows(cv), kf, vf, mask_lt,
+                                 **scales)
+    # Plain attention, head by head: the visible cache columns, then
+    # the fresh token's own.
+    want = np.zeros((B, Hkv * G, Dh), np.float32)
+    for b in range(B):
+        n = int(plen[b])
+        for h in range(Hkv * G):
+            kv = h // G
+            keys = np.concatenate([np.asarray(dk[b, kv, :n]),
+                                   np.asarray(kf[b, 0, kv])[None]])
+            vals = np.concatenate([np.asarray(dv[b, kv, :n]),
+                                   np.asarray(vf[b, 0, kv])[None]])
+            sc = keys @ np.asarray(q[b, 0, h]) / np.sqrt(Dh)
+            w = np.exp(sc - sc.max())
+            want[b, h] = (w / w.sum()) @ vals
+    np.testing.assert_allclose(
+        np.asarray(got).reshape(B, Hkv * G, Dh), want, atol=2e-5, rtol=2e-4)
 
 
 def test_int8_kv_cache_engine_end_to_end():

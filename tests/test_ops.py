@@ -228,26 +228,37 @@ def test_ring_attn_train_step():
     assert np.isfinite(float(metrics["loss"]))
 
 
-def test_decode_step_head_major_cache_layout():
-    """decode_step writes the head-major [L, B, Hkv, T, Dh] cache at each
-    row's position in one batched scatter — the written slots must hold
-    exactly the rope'd fresh k/v and no other slot may change."""
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_decode_step_writes_each_layers_token_row_and_no_other(kv_dtype):
+    """decode_step writes the slab [L, B, 1, T, Hkv * Dh] (scales
+    [L, B, Hkv, T]) at each row's position in one batched scatter: in
+    EVERY layer the token's row (and, int8, its heads' scales) changes,
+    and no other position of any array does."""
+    import dataclasses
+
     import numpy as np
 
     from seldon_tpu.models import get_config, init_params, transformer
 
-    cfg = get_config("tiny")
+    cfg = dataclasses.replace(get_config("tiny"), kv_cache_dtype=kv_dtype)
     params = init_params(cfg, jax.random.key(0))
     cache = transformer.init_cache(cfg, 2, 16)
-    assert cache["k"].shape == (cfg.n_layers, 2, cfg.n_kv_heads, 16,
-                                cfg.head_dim)
-    before = np.asarray(cache["k"])
+    assert cache["k"].shape == (cfg.n_layers, 2, 1, 16,
+                                cfg.n_kv_heads * cfg.head_dim)
+    before = {k: np.asarray(v, np.float32) for k, v in cache.items()}
     tok = jnp.array([3, 4], jnp.int32)
     pos = jnp.array([2, 5], jnp.int32)
     _, cache = transformer.decode_step(params, tok, pos, cache, cfg)
-    after = np.asarray(cache["k"])
-    changed = np.any(after != before, axis=(0, 2, 4))  # [B, T]
-    for b, p in enumerate([2, 5]):
-        assert changed[b, p], "fresh k must land at the row's position"
-        changed[b, p] = False
-    assert not changed.any(), "no other slot may be touched"
+    assert set(cache) == set(before)
+    for key, old in before.items():
+        new = np.asarray(cache[key], np.float32)
+        assert new.shape == old.shape
+        diff = new != old
+        if diff.ndim == 5:  # k / v: any lane of the row
+            diff = diff.any(axis=4)
+        changed = diff.any(axis=2)  # [L, B, T]
+        for b, p in enumerate([2, 5]):
+            assert changed[:, b, p].all(), \
+                f"{key}: every layer's fresh row lands at the position"
+            changed[:, b, p] = False
+        assert not changed.any(), f"{key}: no other slot may be touched"

@@ -231,7 +231,9 @@ def _seed_row(cfg, params, pool, table, row, n, seed):
     cache = transformer.init_cache(cfg, 1, SMAX)
     logits, cache = transformer.prefill(
         params, tks, jnp.asarray([n], jnp.int32), cache, cfg)
-    wr = {k: cache[k][:, 0:1, :, :n] for k in cache}
+    # The dense prefill fills slab rows; the pool holds KV by head.
+    wr = transformer.kv_by_head(
+        {k: cache[k][:, 0:1, :, :n] for k in cache}, cfg)
     pool = transformer.paged_scatter_tokens(
         pool, wr, table[row:row + 1], jnp.arange(n)[None, :])
     return pool, int(jnp.argmax(logits[0]))

@@ -36,12 +36,17 @@ PATHS = {
 }
 
 
-def _engine(cfg, **ekw):
+def _engine(cfg, tp=1, **ekw):
     params = init_params(cfg, jax.random.key(0))
     ekw.setdefault("max_slots", 4)
     ekw.setdefault("max_seq_len", 64)
     ekw.setdefault("prompt_buckets", (8, 32))
-    eng = InferenceEngine(params, cfg, EngineConfig(**ekw))
+    if tp > 1:
+        from seldon_tpu.servers import mesh_engine
+
+        eng = mesh_engine.MeshEngine(params, cfg, EngineConfig(**ekw), tp=tp)
+    else:
+        eng = InferenceEngine(params, cfg, EngineConfig(**ekw))
     eng.start()
     return eng
 
@@ -188,6 +193,66 @@ def test_every_path_answers_the_same(want, preset, path, name, sp):
     ref = want(preset, name, sp)
     assert len(ref) == LIVE_TOKENS
     assert _stream(live_config(preset), sp, **PATHS[path]) == ref
+
+
+# --- (d') one slab: a prefill's KV lands where a decode step reads it --------
+
+# The paths that move with the slab's layout (one row a token,
+# transformer.cache_spec): the prefix cache scatters and gathers trie
+# blocks of it, chunked prefill reads it back as its prefix, a
+# tensor-parallel engine shards its rows by head group, and the
+# speculative engine turns a cold prefill's rows by head for its pool.
+SLAB_PATHS = {
+    "prefix": dict(prefix_cache=True, prefix_block=8),
+    "chunked": CHUNKED,
+    "spec": dict(spec_decode=True, spec_k=4, **PAGED),
+    "tp2": dict(tp=2),
+}
+SLAB_TOKENS = 24
+
+
+def _slab_streams(cfg, n_requests=1, **ekw):
+    """The greedy streams of PROMPT, sent n_requests times in turn."""
+    sp = SamplingParams(temperature=0.0, max_new_tokens=SLAB_TOKENS)
+    eng = _engine(cfg, **ekw)
+    try:
+        out = [eng.generate_blocking(PROMPT, sp)["token_ids"]
+               for _ in range(n_requests)]
+        stats = eng.stats.snapshot()
+    finally:
+        eng.stop()
+    return out, stats
+
+
+@pytest.fixture(scope="module")
+def slab_want():
+    memo = {}
+
+    def get(kv_dtype):
+        if kv_dtype not in memo:
+            cfg = live_config(kv_cache_dtype=kv_dtype)
+            memo[kv_dtype] = _slab_streams(cfg)[0][0]
+        return memo[kv_dtype]
+
+    return get
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("path", sorted(SLAB_PATHS))
+def test_prefilled_kv_lands_where_a_decode_step_reads_it(slab_want, path,
+                                                          kv_dtype):
+    """Admission, then 24 greedy tokens: every engine that scatters,
+    gathers, shards or turns the slab's rows answers as the dense one
+    does, bf16 and int8 KV (scales per head). The prefix engine is asked
+    twice: its second admission is warm, prefix rows out of the trie."""
+    ref = slab_want(kv_dtype)
+    assert len(ref) == SLAB_TOKENS
+    cfg = live_config(kv_cache_dtype=kv_dtype)
+    got, stats = _slab_streams(cfg, 2 if path == "prefix" else 1,
+                               **SLAB_PATHS[path])
+    assert got == [ref] * len(got)
+    if path == "prefix":
+        assert stats["prefix_hits"] >= 1
 
 
 # --- (e) every path counts its sampler steps --------------------------------

@@ -1,65 +1,135 @@
-"""Compile the real decode chunk and report XLA's cost analysis plus any
-large copy/convert ops in the optimized HLO (fusion failures show up as
-full-cache-sized copies)."""
+"""What the TPU v5e's compiler makes of the engine's own programs, with
+no chip: AOT-compile `_chunk_impl` (4 steps) or `_admit_impl` of a
+benchmark configuration at the cells' 64 slots x 1024 for the described
+topology "v5e:2x2" (libtpu compiles for a chip that is not attached;
+.claude/skills/verify/SKILL.md), print `memory_analysis()` and list the
+instructions of the entry and loop computations (not the insides of
+fusions) whose result is at least a quarter of one layer's K: a `copy`
+or a stand-alone `dynamic-slice` fusion that large is a relayout the
+chip runs on every chunk, step or layer (PERF.md section 6, PR 28, 32).
 
-import functools
+    JAX_PLATFORMS=cpu python3 tools/inspect_hlo.py mistral-7b-v0.3 chunk
+    JAX_PLATFORMS=cpu python3 tools/inspect_hlo.py mixtral-8x7b admit/1024/8 \\
+        --dump /root/scratch/admit.hlo.txt
+
+About 20 s a program. Nothing runs: no time comes out of this.
+"""
+
+import argparse
+import json
+import os
 import re
 import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import jax
 import jax.numpy as jnp
 
-from seldon_tpu.models import get_config, init_params, transformer
-from seldon_tpu.models.quantize import quantize_params
-from tools.microbench_decode import chunk_impl, SLOTS, WINDOW, CHUNK
+SLOTS, WINDOW, STEPS = 64, 1024, 4
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?(\S+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\](\{[^}]*\})?.*? ([\w-]+)\(")
+_QUIET = ("parameter", "get-tuple-element", "bitcast", "tuple")
 
 
-def main():
-    kv = sys.argv[1] if len(sys.argv) > 1 else "int8"
-    wd = sys.argv[2] if len(sys.argv) > 2 else "int8"
-    cfg = get_config("bench-1b", kv_cache_dtype=kv, weight_dtype=wd)
-    params = init_params(cfg, jax.random.key(0))
-    if wd == "int8":
-        params = quantize_params(params)
-    B = SLOTS
-    state = {
-        "cache": transformer.init_cache(cfg, B, WINDOW),
-        "last_tok": jnp.ones((B,), jnp.int32),
-        "pos": jnp.full((B,), 128, jnp.int32),
-        "active": jnp.ones((B,), jnp.bool_),
-        "temp": jnp.full((B,), 0.7, jnp.float32),
-        "top_k": jnp.zeros((B,), jnp.int32),
-        "top_p": jnp.ones((B,), jnp.float32),
-        "seeds": jnp.arange(B, dtype=jnp.uint32),
-    }
-    fn = jax.jit(functools.partial(chunk_impl, cfg=cfg, n_steps=CHUNK),
-                 donate_argnums=(1,))
-    lowered = fn.lower(params, state)
-    compiled = lowered.compile()
-    ca = compiled.cost_analysis()
-    if ca:
-        for key in sorted(ca):
-            if "bytes" in key or "flops" in key or "time" in key:
-                v = ca[key]
-                if isinstance(v, float) and v > 1e6:
-                    print(f"{key}: {v/1e9:.2f} G")
-    txt = compiled.as_text()
-    # find big copies / converts / broadcasts over cache-sized shapes
-    pat = re.compile(r"(copy|convert|transpose)[^\n]*", re.I)
-    sizes = {}
-    for m in re.finditer(r"\n\s*(\S+)\s*=\s*(\w+)\[([\d,]+)\][^\n]*(copy|transpose)\(", txt):
-        shape = m.group(3)
+def big_instructions(hlo: str, at_least: int):
+    """[(computation, op, result type, name)] of the instructions outside
+    fused computations with at least `at_least` result elements."""
+    out, comp, skip = [], None, True
+    for line in hlo.split("\n"):
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            skip = "fused_computation" in comp
+            continue
+        m = None if skip else _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, dtype, shape, layout, op = m.groups()
         n = 1
-        for d in shape.split(","):
+        for d in filter(None, shape.split(",")):
             n *= int(d)
-        if n >= (1 << 22):
-            sizes[f"{m.group(2)}[{shape}] {m.group(4)}"] = sizes.get(
-                f"{m.group(2)}[{shape}] {m.group(4)}", 0) + 1
-    for k, v in sorted(sizes.items(), key=lambda kv: -kv[1]):
-        print(f"BIG {k} x{v}")
-    # fusion count and total size hints
-    print("n_fusions:", txt.count(" fusion("), " n_copy:", txt.count(" copy("))
+        if n >= at_least and op not in _QUIET:
+            out.append((comp, op, f"{dtype}[{shape}]{layout or ''}", name))
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("config", help="a file of benchmark/configs, by name")
+    ap.add_argument("program", help="chunk, or admit/<bucket>/<group>")
+    ap.add_argument("--dump", help="write the optimized HLO text here")
+    args = ap.parse_args(argv)
+    # quiets libtpu's search for a host it is not on
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+
+    import family
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from seldon_tpu.models import slot, transformer
+    from seldon_tpu.models.config import ModelConfig
+    from seldon_tpu.models.quantize import init_params_int8
+    from seldon_tpu.servers import engine
+    from seldon_tpu.servers.engine import InferenceEngine
+
+    here = os.path.join(ROOT, "benchmark")
+    with open(os.path.join(here, "configs", args.config + ".json")) as f:
+        raw = json.load(f)
+    cfg = ModelConfig(
+        **family.load(here, raw).model_config_kwargs(raw)).validate()
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def shapes(make):
+        return jax.tree.map(lambda a: shaped(a.shape, a.dtype),
+                            jax.eval_shape(make))
+
+    init = init_params_int8 if raw["serving"]["weight_dtype"] == "int8" \
+        else transformer.init_params
+    params = shapes(lambda: init(cfg, jax.random.key(0)))
+    state = shapes(lambda: slot.fresh(
+        transformer.init_cache(cfg, SLOTS, WINDOW), SLOTS))
+    if args.program == "chunk":
+        fn = engine._named_partial(InferenceEngine._chunk_impl, cfg=cfg,
+                                   n_steps=STEPS)
+        more = ()
+    else:
+        _, Sb, G = args.program.split("/")
+        G = int(G)
+        fn = engine._named_partial(InferenceEngine._admit_impl, cfg=cfg)
+        more = (shaped((G, int(Sb)), jnp.int32),) + tuple(
+            shaped((G,), dt) for dt in (
+                jnp.int32, jnp.uint32, jnp.float32, jnp.int32, jnp.float32,
+                jnp.int32, jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, state, *more).compile()
+    hlo = compiled.as_text()
+    if args.dump:
+        with open(args.dump, "w") as f:
+            f.write(hlo)
+    ma = compiled.memory_analysis()
+    print(f"{args.config} {args.program} for {topo.devices[0].device_kind}: "
+          f"temporaries {ma.temp_size_in_bytes / 1e9:.3f} GB, arguments "
+          f"{ma.argument_size_in_bytes / 1e9:.3f} GB, of which aliased to "
+          f"outputs {ma.alias_size_in_bytes / 1e9:.3f} GB")
+    layer_k = SLOTS * WINDOW * cfg.n_kv_heads * cfg.head_dim
+    counts = {}
+    for comp, op, typ, _ in big_instructions(hlo, layer_k // 4):
+        counts[comp[:32], op, typ] = counts.get((comp[:32], op, typ), 0) + 1
+    for (comp, op, typ), n in sorted(counts.items()):
+        print(f"  x{n}  {comp:32s} {op:20s} {typ}")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())
