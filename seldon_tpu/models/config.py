@@ -6,7 +6,10 @@ the homogeneous stack (attention + SwiGLU or all-expert MoE in every
 layer: the llama / mistral / mixtral presets) and the PATTERNED stack
 (`layer_types` set: a per-layer operator kind, short-conv or attention,
 leading dense feed-forward layers before the sparse ones, an expert
-width of its own, a sigmoid router, QK-norm). Presets: `tiny*` (CPU
+width of its own, a sigmoid router, QK-norm; or layers that are ONE
+residual block each: a Mamba-2 mixer, an attention or a sparse
+feed-forward alone, with a shared expert and a share of the routed
+experts held here). Presets: `tiny*` (CPU
 tests), `bench-1b` (one v5e chip in bf16), `llama3-8b` / `llama3-70b`
 (geometry only; the benchmark's configurations are registered from
 benchmark/configs/ by its launcher).
@@ -20,6 +23,13 @@ from typing import Optional, Tuple
 # Operator kinds of a patterned stack (the published `layer_types` names).
 OP_CONV = "conv"
 OP_ATTN = "full_attention"
+# Layers that are one residual block alone (no feed-forward of their
+# own): a Mamba-2 mixer, an attention, a sparse feed-forward.
+OP_MAMBA = "mamba"
+OP_ATTN_ONLY = "attention"
+OP_MOE = "moe"
+FUSED_OPS = (OP_CONV, OP_ATTN)  # operator + feed-forward in one layer
+SINGLE_OPS = (OP_MAMBA, OP_ATTN_ONLY, OP_MOE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,20 +108,67 @@ class ModelConfig:
     # RMSNorm over head_dim on q and k, before RoPE.
     qk_norm: bool = False
     # Taps of the depthwise causal short convolution; the decode state
-    # is the last conv_kernel - 1 inputs per slot and conv layer.
+    # is the last conv_kernel - 1 inputs per slot and conv layer (a
+    # Mamba-2 mixer's convolution over [x | B | C] reads it too).
     conv_kernel: int = 3
+    # Width of one attention head (0 = d_model // n_heads, filled in on
+    # construction; a model whose heads are wider than that states it).
+    head_dim: int = 0
+    # Rotary position embedding on q and k (False: none is applied).
+    rotary: bool = True
+    # --- single-block layers (layer_types of "mamba" / "attention" / "moe") ---
+    # Mamba-2 mixer: heads x head width = its inner width (not a multiple
+    # of d_model), B and C in ssm_groups groups of ssm_state values; the
+    # decode state per slot and layer is [ssm_heads, ssm_head_dim,
+    # ssm_state] float32. Prefill is the chunked (SSD) scan at ssm_chunk.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_chunk: int = 128
+    # One expert's block: "swiglu" (gate, up, down) or "relu2"
+    # (down(relu(up x) ** 2): no gate).
+    ff_act: str = "swiglu"
+    # Width of the shared expert every token goes through beside the
+    # routed ones (0 = none).
+    d_ff_shared: int = 0
+    # The share of the routed experts this program holds: experts
+    # [expert_first, expert_first + n_experts_held) of the n_experts the
+    # router scores (0 = all). Assignments to the others go nowhere.
+    n_experts_held: int = 0
+    expert_first: int = 0
+    # Added to the sum of the selected scores before renormalising.
+    router_norm_eps: float = 1e-6
 
     def __post_init__(self):
         if not isinstance(self.layer_types, tuple):
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        if not self.head_dim:
+            # dataclasses.replace carries the filled-in value: a replace
+            # that changes d_model or n_heads passes head_dim=0 with them.
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
 
     @property
     def patterned(self) -> bool:
         return bool(self.layer_types)
+
+    @property
+    def single_blocks(self) -> bool:
+        """Each layer is one residual block (SINGLE_OPS)."""
+        return any(t in SINGLE_OPS for t in self.layer_types)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the mixer's convolution: [x | B | C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def expert_width(self) -> int:
@@ -121,24 +178,37 @@ class ModelConfig:
         return self.layer_types[layer] if self.layer_types else OP_ATTN
 
     def ff_sparse(self, layer: int) -> bool:
-        return bool(self.n_experts) and layer >= self.n_dense_layers
+        """The layer's OWN feed-forward is sparse (a single-block layer
+        has none: its sparse block is the operator OP_MOE)."""
+        return (bool(self.n_experts) and layer >= self.n_dense_layers
+                and self.op_kind(layer) in FUSED_OPS)
+
+    def _count(self, *kinds: str) -> int:
+        return sum(1 for t in self.layer_types if t in kinds)
 
     @property
     def n_attn_layers(self) -> int:
         """Layers that hold KV."""
         if not self.layer_types:
             return self.n_layers
-        return sum(1 for t in self.layer_types if t == OP_ATTN)
+        return self._count(OP_ATTN, OP_ATTN_ONLY)
 
     @property
     def n_conv_layers(self) -> int:
-        """Layers that hold a conv state."""
-        return self.n_layers - self.n_attn_layers
+        """Layers that hold a short-conv state."""
+        return self._count(OP_CONV)
+
+    @property
+    def n_mamba_layers(self) -> int:
+        """Layers that hold an SSM state (and the mixer's conv state)."""
+        return self._count(OP_MAMBA)
 
     @property
     def n_sparse_layers(self) -> int:
         if not self.n_experts:
             return 0
+        if self.single_blocks:
+            return self._count(OP_MOE)
         return self.n_layers - min(self.n_dense_layers, self.n_layers)
 
     @property
@@ -163,8 +233,6 @@ class ModelConfig:
         assert self.rope_scaling_type in (None, "linear", "llama3"), (
             f"unknown rope_scaling_type {self.rope_scaling_type!r}"
         )
-        if self.n_experts:
-            assert self.n_experts_per_token <= self.n_experts
         assert self.router in ("softmax", "sigmoid"), (
             f"unknown router {self.router!r}"
         )
@@ -176,9 +244,23 @@ class ModelConfig:
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"n_layers is {self.n_layers}"
             )
-            bad = sorted(set(self.layer_types) - {OP_CONV, OP_ATTN})
+            bad = sorted(set(self.layer_types) - set(FUSED_OPS + SINGLE_OPS))
             assert not bad, f"unknown layer_types entries {bad}"
             assert self.conv_kernel >= 2, "conv_kernel must be >= 2"
+            if self.single_blocks:
+                assert not set(self.layer_types) & set(FUSED_OPS) \
+                    and self.n_dense_layers == 0, (
+                        "layers of one block each (mamba / attention / moe) "
+                        "do not mix with operator + feed-forward layers or "
+                        "leading dense ones")
+                assert (OP_MOE in self.layer_types) == bool(self.n_experts), (
+                    "moe layers need n_experts, and n_experts moe layers")
+            if self.n_mamba_layers:
+                assert self.ssm_heads > 0 and self.ssm_head_dim > 0 \
+                    and self.ssm_state > 0 and self.ssm_chunk > 0 \
+                    and self.ssm_heads % self.ssm_groups == 0, (
+                        "mamba layers need ssm_heads (a multiple of "
+                        "ssm_groups), ssm_head_dim, ssm_state and ssm_chunk")
             assert self.kv_cache_dtype == "bf16" and \
                 self.weight_dtype == "bf16" and self.attn_impl == "xla", (
                     "a patterned stack (layer_types) is served in bf16 "
@@ -194,6 +276,23 @@ class ModelConfig:
                 "n_dense_layers / d_ff_expert / router / router_bias / "
                 "qk_norm need layer_types (the patterned stack)"
             )
+            assert (self.head_dim * self.n_heads == self.d_model
+                    and self.rotary and self.ff_act == "swiglu"
+                    and not self.d_ff_shared and not self.n_experts_held
+                    and not self.expert_first and not self.ssm_heads), (
+                "head_dim / rotary / ff_act / d_ff_shared / n_experts_held "
+                "/ expert_first / ssm_* need layer_types (the patterned "
+                "stack)"
+            )
+        assert self.ff_act in ("swiglu", "relu2"), (
+            f"unknown ff_act {self.ff_act!r}")
+        assert 0 <= self.expert_first and \
+            self.expert_first + self.experts_held <= max(self.n_experts, 0) \
+            or not self.n_experts, (
+                "the experts held, [expert_first, expert_first + "
+                "n_experts_held), must lie among the n_experts routed over")
+        if self.n_experts:
+            assert self.n_experts_per_token <= self.n_experts
         return self
 
 
@@ -248,6 +347,41 @@ PRESETS = {
         qk_norm=True,
         conv_kernel=3,
     ),
+    # Layers of one block each at CPU-test size: one period (mamba, moe,
+    # mamba, moe, mamba, attention, moe), heads wider than d_model /
+    # n_heads and without rotary embedding, 8 routed relu2 experts top-2
+    # of which this program holds the first 4, a shared expert, 4 SSM
+    # heads in 2 groups, prefill scanned in chunks of 8.
+    "tiny-nemotron": ModelConfig(
+        vocab_size=256,
+        d_model=64,
+        n_layers=7,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=32,
+        rotary=False,
+        d_ff=32,
+        max_seq_len=128,
+        eos_token_id=1,
+        n_experts=8,
+        n_experts_per_token=2,
+        n_experts_held=4,
+        layer_types=("mamba", "moe", "mamba", "moe", "mamba", "attention",
+                     "moe"),
+        d_ff_expert=32,
+        d_ff_shared=96,
+        ff_act="relu2",
+        router="sigmoid",
+        router_bias=True,
+        router_scale=2.5,
+        router_norm_eps=1e-20,
+        conv_kernel=4,
+        ssm_heads=4,
+        ssm_head_dim=16,
+        ssm_groups=2,
+        ssm_state=16,
+        ssm_chunk=8,
+    ),
     # ~1.1B params: single v5e chip (16 GB HBM) with room for KV cache.
     "bench-1b": ModelConfig(
         vocab_size=32000,
@@ -278,5 +412,8 @@ def get_config(name_or_cfg, **overrides) -> ModelConfig:
     else:
         cfg = PRESETS[name_or_cfg]
     if overrides:
+        if {"d_model", "n_heads"} & set(overrides) \
+                and cfg.head_dim * cfg.n_heads == cfg.d_model:
+            overrides.setdefault("head_dim", 0)  # derived: derive it again
         cfg = dataclasses.replace(cfg, **overrides)
     return cfg.validate()

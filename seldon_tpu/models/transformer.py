@@ -1,7 +1,8 @@
 """Decoder models, functional JAX, TPU-first: the homogeneous Llama-
 family stack and (cfg.layer_types) the patterned stack at the end of
 this file, whose layers differ in operator (short conv or attention) and
-feed-forward (dense or token -> expert dispatch).
+feed-forward (dense or token -> expert dispatch), or are one residual
+block each (a Mamba-2 mixer, an attention or a sparse feed-forward alone).
 
 Design (vs the reference's black-box CPU model servers, SURVEY.md §2.5):
  * Params are a plain pytree with layers STACKED on a leading [L, ...] axis
@@ -25,9 +26,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from seldon_tpu.models.config import OP_ATTN, OP_CONV, ModelConfig
+from seldon_tpu.models.config import (
+    FUSED_OPS,
+    OP_ATTN,
+    OP_ATTN_ONLY,
+    OP_CONV,
+    OP_MAMBA,
+    OP_MOE,
+    ModelConfig,
+)
 from seldon_tpu.models.quantize import dequant
-from seldon_tpu.ops import moe_dispatch
+from seldon_tpu.ops import moe_dispatch, ssm_update
 
 Params = Dict[str, Any]
 
@@ -619,8 +628,9 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
             with jax.named_scope("attn/qk_norm"):
                 q = rms_norm(q, bp["q_norm"], cfg.rms_norm_eps)
                 k = rms_norm(k, bp["k_norm"], cfg.rms_norm_eps)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        if cfg.rotary:
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
         if tp is not None:
             q, k, v = tp.heads(q), tp.heads(k), tp.heads(v)
     return q, k, v
@@ -911,7 +921,9 @@ def forward(
 class CacheEntry(NamedTuple):
     """One array of the per-slot cache: what it holds ("kv" = keys,
     values and their int8 scales, one position per token; "conv" = the
-    short convolution's last inputs, a fixed size per slot), its shape
+    short convolution's last inputs, "ssm" = a Mamba-2 mixer's state and
+    "ssm_conv" = its convolution's last inputs, each a fixed size per
+    slot), its shape
     [layers of that kind, batch, ...], dtype, fill value, and the axis
     that runs over token positions (None: no such axis)."""
     kind: str
@@ -952,7 +964,16 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
 
     The conv state is [Lc, B, conv_kernel - 1, D] over the Lc conv
     layers of a patterned stack: the gated inputs u of the slot's last
-    conv_kernel - 1 positions, oldest first."""
+    conv_kernel - 1 positions, oldest first.
+
+    The SSM state is [Lm, B, ssm_heads, ssm_head_dim, ssm_state] over the
+    Lm Mamba-2 layers, FLOAT32 whatever the compute dtype: the recurrence
+    accumulates into it over the whole context. It is three orders larger
+    than a conv state (2 MB a slot and layer at 64 x 64 x 128) and every
+    decode step reads and writes all of it, so the step updates it in
+    place (_run_patterned_decode carries it whole through the layer
+    scan). "ssm_conv" is [Lm, B, conv_kernel - 1, ssm_conv_dim]: the
+    mixer's [x | B | C] inputs of the last conv_kernel - 1 positions."""
     side = kv_heads_per_row(cfg)
     shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads // side, max_len,
              cfg.head_dim * side)
@@ -979,6 +1000,15 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
             "conv",
             (cfg.n_conv_layers, batch, cfg.conv_kernel - 1, cfg.d_model),
             dtype or _dtype(cfg), 0, None)
+    if cfg.n_mamba_layers:
+        spec["ssm"] = CacheEntry(
+            "ssm",
+            (cfg.n_mamba_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim,
+             cfg.ssm_state), jnp.float32, 0, None)
+        spec["ssm_conv"] = CacheEntry(
+            "ssm_conv",
+            (cfg.n_mamba_layers, batch, cfg.conv_kernel - 1,
+             cfg.ssm_conv_dim), dtype or _dtype(cfg), 0, None)
     return spec
 
 
@@ -992,7 +1022,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None) -> Cache:
 
 
 def cache_bytes(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, int]:
-    """Bytes of the per-slot cache by kind ({"kv": ..., "conv": ...})."""
+    """Bytes of the per-slot cache by kind ({"kv": ..., "conv": ...,
+    "ssm": ..., "ssm_conv": ...}: the kinds the stack has)."""
     out: Dict[str, int] = {}
     for e in cache_spec(cfg, batch, max_len).values():
         n = jnp.dtype(e.dtype).itemsize
@@ -1009,7 +1040,7 @@ def cache_scatter_slots(cfg: ModelConfig, cache: Cache, sub: Cache,
     cache_spec describes them. Arrays with a token axis (k/v
     [L, B, 1, T, Hkv*Dh] + scales [L, B, Hkv, T]: T at dim 3 of k/v and
     trailing on the scales, so one indexing expression covers them all)
-    take the first `width` positions; a fixed-size state (the conv state) is
+    take the first `width` positions; a fixed-size state (conv, SSM) is
     overwritten whole, so a reused slot keeps nothing of its last
     request."""
     spec = cache_spec(cfg, 1, 1)
@@ -1334,13 +1365,16 @@ def decode_step(
     `live` tells a patterned stack's sparse layers which rows hold a
     request: the others route to no expert, so a step reads the weights
     of the experts its live rows select and no more (None: every row).
-    return_routing adds a third value, int32 [3]: sparse layers run,
-    distinct experts they read summed over those layers, (row, expert)
-    assignments (zeros for a stack without dispatch)."""
+    return_routing adds a third value, int32 [routing_width(cfg)]:
+    sparse layers run, distinct experts they read summed over those
+    layers, (row, expert) assignments (zeros for a stack without
+    dispatch); a stack that holds a share of its experts or has Mamba-2
+    layers adds the assignments to experts held here and the Mamba-2
+    layers run."""
     x = _embed_rows(params, token, _dtype(cfg))[:, None, :]  # [B,1,D]
     positions = pos[:, None]
     inv_freq = rope_frequencies(cfg)
-    routing = jnp.zeros((3,), jnp.int32)
+    routing = jnp.zeros((routing_width(cfg),), jnp.int32)
     if cfg.patterned:
         refuse_patterned(cfg, "tensor-parallel decode", tp is not None)
         x, cache, routing = _run_patterned_decode(
@@ -1369,7 +1403,14 @@ def decode_step(
 # [R, ...] weights of position j of segment s's period, so that no
 # expert matrix is ever sliced or copied inside a program. The cache is
 # by kind (cache_spec): "k"/"v" over the attention layers in layer order,
-# "conv" over the conv layers in layer order.
+# "conv" over the conv layers in layer order, "ssm" / "ssm_conv" over the
+# Mamba-2 layers in layer order.
+#
+# A stack of SINGLE-BLOCK layers (config.SINGLE_OPS) is the same plan with
+# other kinds: each layer is x + block(RMSNorm(x)) with the block a
+# Mamba-2 mixer ("mamba"), an attention ("attention") or the sparse
+# feed-forward ("moe": routed experts, of which this program may hold a
+# share, plus a shared expert) and has no feed-forward of its own.
 
 
 class Segment(NamedTuple):
@@ -1378,9 +1419,24 @@ class Segment(NamedTuple):
     first_layer: int
     attn_start: int  # index of its first attention layer among those
     conv_start: int  # and of its first conv layer
+    ssm_start: int = 0  # and of its first Mamba-2 layer
 
 
 _MAX_PERIOD = 8
+_KV_OPS = (OP_ATTN, OP_ATTN_ONLY)  # the operators that hold KV
+_FIXED_STATE = ("conv", "ssm", "ssm_conv")  # cache arrays without a token axis
+
+
+def _count_ops(kinds, *ops) -> int:
+    return sum(1 for op, _ in kinds if op in ops)
+
+
+def routing_width(cfg: ModelConfig) -> int:
+    """Length of the counters a decode step returns (decode_step):
+    [sparse layers run, experts read, assignments] and, for a stack that
+    holds a share of its experts or has Mamba-2 layers, [assignments to
+    experts held here, Mamba-2 layers run] after them."""
+    return 5 if cfg.n_experts_held or cfg.n_mamba_layers else 3
 
 
 @functools.lru_cache(maxsize=None)
@@ -1389,7 +1445,7 @@ def layer_plan(cfg: ModelConfig) -> Tuple[Segment, ...]:
     front: at each layer the period (up to _MAX_PERIOD kinds) that
     repeats at least twice and covers most layers, else one layer."""
     kinds = [(cfg.op_kind(l), cfg.ff_sparse(l)) for l in range(cfg.n_layers)]
-    plan, i, n_attn, n_conv = [], 0, 0, 0
+    plan, i, n_attn, n_conv, n_ssm = [], 0, 0, 0, 0
     while i < len(kinds):
         best_p, best_r = 1, 1
         for p in range(1, min(_MAX_PERIOD, len(kinds) - i) + 1):
@@ -1399,18 +1455,26 @@ def layer_plan(cfg: ModelConfig) -> Tuple[Segment, ...]:
             if r >= 2 and p * r > best_p * best_r:
                 best_p, best_r = p, r
         period = tuple(kinds[i:i + best_p])
-        plan.append(Segment(period, best_r, i, n_attn, n_conv))
-        n_attn += best_r * sum(1 for op, _ in period if op == OP_ATTN)
-        n_conv += best_r * sum(1 for op, _ in period if op == OP_CONV)
+        plan.append(Segment(period, best_r, i, n_attn, n_conv, n_ssm))
+        n_attn += best_r * _count_ops(period, *_KV_OPS)
+        n_conv += best_r * _count_ops(period, OP_CONV)
+        n_ssm += best_r * _count_ops(period, OP_MAMBA)
         i += best_p * best_r
     return tuple(plan)
+
+
+def fixed_state_names(cfg: ModelConfig) -> str:
+    """The fixed-size per-slot state this stack holds, in words."""
+    if cfg.n_mamba_layers:
+        return "the Mamba-2 layers' SSM and conv state"
+    return "the conv layers' state"
 
 
 def refuse_patterned(cfg: ModelConfig, what: str, when: bool = True):
     if cfg.patterned and when:
         raise NotImplementedError(
             f"{what} does not know the patterned stack (layer_types): it "
-            f"would run without the conv layers' state")
+            f"would run without {fixed_state_names(cfg)}")
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
@@ -1436,6 +1500,8 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
     L, D, F, V = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     E, Fe, Kc = cfg.n_experts, cfg.expert_width, cfg.conv_kernel
+    Eh, Fs = cfg.experts_held, cfg.d_ff_shared
+    gated = cfg.ff_act == "swiglu"
     damp = (2 * L) ** -0.5  # residual-stream init damping
     count = [0]
 
@@ -1449,10 +1515,66 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
     def ones(*shape):
         return jnp.ones(shape, jnp.float32)
 
+    def sparse_block(R):
+        """Router over all E, the Eh experts held here, the shared one."""
+        lp = dict(router=dense(R, D, E, dtype=jnp.float32))
+        if gated:
+            lp.update(w_gate=dense(R, Eh, D, Fe), w_up=dense(R, Eh, D, Fe))
+        else:  # stored as w_down is, applied transposed (dispatch_experts)
+            lp["w_up"] = dense(R, Eh, Fe, D, scale=D ** -0.5)
+        lp["w_down"] = dense(R, Eh, Fe, D, scale=damp * Fe ** -0.5)
+        if cfg.router_bias:
+            lp["router_bias"] = dense(R, E, scale=0.05, dtype=jnp.float32)
+        if Fs:
+            if gated:
+                lp["shared_gate"] = dense(R, D, Fs)
+            lp.update(shared_up=dense(R, D, Fs),
+                      shared_down=dense(R, Fs, D, scale=damp * Fs ** -0.5))
+        return lp
+
+    def mamba_block(R):
+        """Mamba-2's own seeded rule for what is not a matrix: A in
+        [1, 16] uniform, dt log-uniform in [1e-3, 1e-1] floored at 1e-4
+        and stored as the inverse softplus, D = 1."""
+        count[0] += 3
+        ka, kd, kb = (jax.random.fold_in(key, count[0] - i) for i in range(3))
+        Hs, Di, Cd = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+        step = jnp.maximum(jnp.exp(
+            jax.random.uniform(kd, (R, Hs), jnp.float32)
+            * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001)), 1e-4)
+        return dict(
+            # in_proj as two stacks, [z | xBC] and dt (stored [Hs, D]):
+            # as one its width, 2 Di + 2 G N + Hs, is no multiple of the
+            # TPU's 128 lanes, the device stores it D-minor and the
+            # compiled chunk relays it out on every entry
+            ssm_in=dense(R, D, Di + Cd), ssm_dt_in=dense(R, Hs, D, scale=D ** -0.5),
+            ssm_conv_w=dense(R, Kc, Cd, scale=Kc ** -0.5),
+            ssm_conv_b=(jax.random.normal(kb, (R, Cd), jnp.float32) * 0.1
+                        ).astype(dt),
+            ssm_dt_bias=step + jnp.log(-jnp.expm1(-step)),
+            ssm_A_log=jnp.log(jax.random.uniform(
+                ka, (R, Hs), jnp.float32, 1.0, 16.0)),
+            ssm_D=ones(R, Hs), ssm_norm=ones(R, Di),
+            ssm_out=dense(R, Di, D, scale=damp * Di ** -0.5))
+
+    def attn_block(R):
+        lp = dict(
+            wq=dense(R, D, H * Dh), wk=dense(R, D, Hkv * Dh),
+            wv=dense(R, D, Hkv * Dh),
+            wo=dense(R, H * Dh, D, scale=damp * (H * Dh) ** -0.5))
+        if cfg.qk_norm:
+            lp.update(q_norm=ones(R, Dh), k_norm=ones(R, Dh))
+        return lp
+
     segments = []
     for seg in layer_plan(cfg):
         R, period = seg.reps, []
         for op, sparse in seg.kinds:
+            if op not in FUSED_OPS:  # one block, one norm
+                block = {OP_MAMBA: mamba_block, OP_ATTN_ONLY: attn_block,
+                         OP_MOE: sparse_block}[op](R)
+                period.append({"op_norm": ones(R, D), **block})
+                continue
             lp = {"op_norm": ones(R, D), "ff_norm": ones(R, D)}
             if op == OP_ATTN:
                 lp.update(
@@ -1541,6 +1663,133 @@ def _conv_op(h, lp, cfg, state=None, plens=None):
     return y, new_state
 
 
+def _ssd_scan(x, dt, a_log, b, c, chunk):
+    """The SSM recurrence over whole sequences, chunk by chunk (the SSD
+    form): S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t, y_t = S_t C_t.
+
+    x [B, S, H, P]; dt [B, S, H] float32, after the softplus and ZERO at
+    positions that are not live (the state passes them unchanged);
+    a_log [H] (A = -exp(a_log)); b, c [B, S, G, N], head h reading group
+    h // (H / G). Inside a chunk of Q positions the outputs are one
+    masked [Q, Q] product (the decays between every pair of positions),
+    between chunks the state is carried by a scan over S / Q steps.
+    Returns (y [B, S, H, P] float32, the state after the last position
+    [B, H, P, N] float32)."""
+    B, S, H, P = x.shape
+    G, N = b.shape[2:]
+    K = H // G
+    Q = min(chunk, S)
+    pad = (-S) % Q
+    if pad:
+        x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                       for t in (x, dt, b, c))
+    nc = (S + pad) // Q
+    f32 = jnp.float32
+    xg = x.reshape(B, nc, Q, G, K, P)
+    dtg = dt.reshape(B, nc, Q, G, K)
+    bg, cg = b.reshape(B, nc, Q, G, N), c.reshape(B, nc, Q, G, N)
+    a = dtg * -jnp.exp(a_log.astype(f32)).reshape(G, K)  # log decay a step
+    acum = jnp.cumsum(a, axis=2)  # [B, nc, Q, G, K], up to and with t
+    # inside a chunk: y_t = sum_{s <= t} (C_t . B_s) exp(acum_t - acum_s) dt_s x_s
+    cb = jnp.einsum("bcqgn,bcsgn->bcqsg", cg, bg, preferred_element_type=f32)
+    seg = acum[:, :, :, None] - acum[:, :, None, :]  # [B, nc, Q, Q, G, K]
+    causal = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None, None]
+    m = cb[..., None] * jnp.exp(jnp.where(causal, seg, -jnp.inf)) \
+        * dtg[:, :, None]
+    y = jnp.einsum("bcqsgk,bcsgkp->bcqgkp", m.astype(x.dtype), xg,
+                   preferred_element_type=f32)
+    # what each chunk adds to the state, decayed to the chunk's end
+    xw = (xg * (dtg * jnp.exp(acum[:, :, -1:] - acum))[..., None]
+          ).astype(x.dtype)
+    added = jnp.einsum("bcsgkp,bcsgn->cbgkpn", xw, bg,
+                       preferred_element_type=f32)
+    decay = jnp.moveaxis(jnp.exp(acum[:, :, -1]), 1, 0)  # [nc, B, G, K]
+
+    def carry_on(state, chunk_):
+        add, dec = chunk_
+        return dec[..., None, None] * state + add, state
+
+    last, before = jax.lax.scan(
+        carry_on, jnp.zeros((B, G, K, P, N), f32), (added, decay))
+    # the state a chunk starts from, read by each of its positions
+    y = y + jnp.einsum("bcqgn,cbgkpn->bcqgkp", cg.astype(f32), before,
+                       preferred_element_type=f32) * jnp.exp(acum)[..., None]
+    return y.reshape(B, S + pad, H, P)[:, :S], last.reshape(B, H, P, N)
+
+
+def _ssm_update(state, layer, x, dt, a_log, b, c):
+    """One step of the recurrence for every row, where the state lies:
+    state [Lm, B, H, P, N] float32 over all the Mamba-2 layers, `layer`
+    the one stepped, x [B, H, P], dt [B, H] float32 (after the softplus),
+    b, c [B, G, N]. Returns (y [B, H, P] float32, the state with that
+    layer stepped): ops/ssm_update.py, a kernel on a TPU."""
+    f32 = jnp.float32
+    keep = jnp.exp(dt * -jnp.exp(a_log.astype(f32)))  # [B, H]
+    return ssm_update.update(state, layer, keep,
+                             dt[..., None] * x.astype(f32), b, c)
+
+
+def _mamba_op(h, lp, cfg, state=None, conv_state=None, live=None,
+              plens=None):
+    """A Mamba-2 mixer on normed input h [B, S, D].
+
+    Decode (S = 1): `state` = (every Mamba-2 layer's state [Lm, B, H, P,
+    N] float32, which of them is this layer's) and `conv_state` [B, Kc -
+    1, C] are the slots'; one step of the recurrence, in place. Whole
+    sequences from position 0 (state None): the chunked scan; positions
+    where `live` [B, S] is False act as dt = 0, and with `plens` the conv
+    state is taken after each row's own last real token, so that a padded
+    row is left the state of its prompt. Returns (y [B, S, D], SSM state
+    (decode: every layer's, this one stepped; else this layer's after the
+    last live position), conv state)."""
+    B, S, _ = h.shape
+    Hs, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    Di, Cd, Kc = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.conv_kernel
+    f32 = jnp.float32
+    with jax.named_scope("ssm/in_proj"):
+        zxbc = _qdot(h, lp, "ssm_in", cfg)
+        z, xbc = zxbc[..., :Di], zxbc[..., Di:]
+        dt = jnp.einsum("bsd,hd->bsh", h, _w(lp, "ssm_dt_in", h.dtype),
+                        preferred_element_type=f32)
+    with jax.named_scope("ssm/conv"):
+        if conv_state is None:
+            conv_state = jnp.zeros((B, Kc - 1, Cd), xbc.dtype)
+        hist = jnp.concatenate([conv_state.astype(xbc.dtype), xbc], axis=1)
+        xbc = jax.nn.silu(
+            _conv_mix(hist, lp["ssm_conv_w"]) + lp["ssm_conv_b"].astype(f32)
+        ).astype(h.dtype)
+        if plens is None:
+            new_conv = hist[:, S:]
+        else:  # as _conv_op: the Kc - 1 inputs that end at plen - 1
+            idx = plens[:, None] + jnp.arange(Kc - 1)[None, :]
+            new_conv = jnp.take_along_axis(hist, idx[:, :, None], axis=1)
+        x = xbc[..., :Di].reshape(B, S, Hs, P)
+        b = xbc[..., Di:Di + G * N].reshape(B, S, G, N)
+        c = xbc[..., Di + G * N:].reshape(B, S, G, N)
+        dt = jax.nn.softplus(dt.astype(f32) + lp["ssm_dt_bias"])
+    if state is not None:
+        with jax.named_scope("ssm/update"):
+            y, new_state = _ssm_update(
+                *state, x[:, 0], dt[:, 0], lp["ssm_A_log"], b[:, 0], c[:, 0])
+            y = y[:, None]
+    else:
+        with jax.named_scope("ssm/scan"):
+            if live is not None:
+                dt = jnp.where(live[..., None], dt, 0.0)
+            y, new_state = _ssd_scan(x, dt, lp["ssm_A_log"], b, c,
+                                     cfg.ssm_chunk)
+    with jax.named_scope("ssm/gate_norm"):
+        y = y + lp["ssm_D"][:, None] * x.astype(f32)
+        y = y.reshape(B, S, Di) * jax.nn.silu(z.astype(f32))
+        # RMSNorm over each of the G groups of Di / G channels, after the gate
+        yg = y.reshape(B, S, G, Di // G)
+        yg = yg * jax.lax.rsqrt(
+            jnp.mean(yg * yg, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+        y = (yg.reshape(B, S, Di) * lp["ssm_norm"]).astype(h.dtype)
+    with jax.named_scope("ssm/out_proj"):
+        return _qdot(y, lp, "ssm_out", cfg), new_state, new_conv
+
+
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
@@ -1559,7 +1808,7 @@ def _split_experts(period, cfg):
                        if not (sparse and k in _EXPERT_STACKS)})
         whole.append({
             k: _w(lp, k, _dtype(cfg)).reshape((-1,) + lp[k].shape[2:])
-            for k in _EXPERT_STACKS} if sparse else None)
+            for k in _EXPERT_STACKS if k in lp} if sparse else None)
     return tuple(sliced), whole
 
 
@@ -1567,19 +1816,44 @@ def _sparse_ff(h, lp, experts, rep, cfg, live):
     """The sparse feed-forward by token -> expert dispatch
     (ops/moe_dispatch.py). h [B, S, D], live [B, S] bool or None;
     `experts` the segment position's merged expert stacks, `rep` which
-    repeat of the segment this layer is."""
+    repeat of the segment this layer is. The router scores all
+    cfg.n_experts; where the program holds a share of them
+    (cfg.n_experts_held) the dispatch is told which, and computes their
+    part of the sum. A shared expert (cfg.d_ff_shared) takes every row."""
     B, S, D = h.shape
     x = h.reshape(B * S, D)
     with jax.named_scope("moe/router"):
         top_idx, top_w = moe_dispatch.route(
             x, lp["router"], lp.get("router_bias"),
             top_k=cfg.n_experts_per_token, router=cfg.router,
-            norm_topk=cfg.router_norm_topk, scale=cfg.router_scale)
+            norm_topk=cfg.router_norm_topk, scale=cfg.router_scale,
+            norm_eps=cfg.router_norm_eps)
     out, stats = moe_dispatch.dispatch_experts(
-        x, top_idx, top_w, experts["w_gate"], experts["w_up"],
+        x, top_idx, top_w, experts.get("w_gate"), experts["w_up"],
         experts["w_down"], None if live is None else live.reshape(B * S),
-        n_experts=cfg.n_experts, layer=rep)
+        n_experts=cfg.experts_held, layer=rep,
+        first=cfg.expert_first if cfg.n_experts_held else None)
+    if cfg.d_ff_shared:
+        with jax.named_scope("moe/shared"):
+            up = _qdot(x, lp, "shared_up", cfg)
+            hidden = jnp.square(jax.nn.relu(up)) if cfg.ff_act == "relu2" \
+                else jax.nn.silu(_qdot(x, lp, "shared_gate", cfg)) * up
+            out = out + _qdot(hidden, lp, "shared_down", cfg)
     return out.reshape(B, S, D), stats
+
+
+def _routing_counts(cfg, stats=None, ssm: bool = False):
+    """One layer's share of a step's counters (routing_width)."""
+    wide = routing_width(cfg) == 5
+    if stats is None and not ssm:
+        return jnp.zeros((routing_width(cfg),), jnp.int32)
+    one, zero = jnp.ones((), jnp.int32), jnp.zeros((), jnp.int32)
+    if stats is None:
+        return jnp.stack([zero, zero, zero, zero, one])
+    r = [one, stats["touched"], stats["assignments"]]
+    if wide:
+        r += [stats.get("held", stats["assignments"]), zero]
+    return jnp.stack(r)
 
 
 def _ff_res(x, lp, experts, rep, cfg, live):
@@ -1588,27 +1862,28 @@ def _ff_res(x, lp, experts, rep, cfg, live):
     h = rms_norm(x, lp["ff_norm"], cfg.rms_norm_eps)
     if experts is not None:
         out, st = _sparse_ff(h, lp, experts, rep, cfg, live)
-        routing = jnp.stack([jnp.ones((), jnp.int32), st["touched"],
-                             st["assignments"]])
+        routing = _routing_counts(cfg, st)
         return x + out, routing
     with jax.named_scope("mlp"):
         hidden = jax.nn.silu(_qdot(h, lp, "w_gate", cfg)) \
             * _qdot(h, lp, "w_up", cfg)
-        return x + _qdot(hidden, lp, "w_down", cfg), \
-            jnp.zeros((3,), jnp.int32)
+        return x + _qdot(hidden, lp, "w_down", cfg), _routing_counts(cfg)
 
 
 def _segment_cache(cache, seg: Segment):
     """The slices of the by-kind cache a segment's scan rides on:
-    {"k","v"} [R, na, ...] and "conv" [R, nc, ...] (absent kinds left
-    out). A segment that owns every layer of a kind reshapes and copies
-    nothing."""
+    {"k","v"} [R, na, ...], "conv" [R, nc, ...] and "ssm_conv"
+    [R, nm, ...] (absent kinds left out). A segment that owns every
+    layer of a kind reshapes and copies nothing. The SSM state is not
+    among them: the decode step carries it whole (_run_patterned_decode)."""
     out = {}
-    na = sum(1 for op, _ in seg.kinds if op == OP_ATTN)
-    nc = len(seg.kinds) - na
+    held = {"conv": ((OP_CONV,), seg.conv_start),
+            "ssm_conv": ((OP_MAMBA,), seg.ssm_start)}
     for key, arr in cache.items():
-        n, start = (nc, seg.conv_start) if key == "conv" else \
-            (na, seg.attn_start)
+        if key == "ssm":
+            continue
+        ops, start = held.get(key, (_KV_OPS, seg.attn_start))
+        n = _count_ops(seg.kinds, *ops)
         if not n:
             continue
         part = arr if arr.shape[0] == n * seg.reps else \
@@ -1626,14 +1901,16 @@ def _unsegment(parts):
 def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
     """Every layer over whole sequences from position 0 (forward,
     prefill). Returns (x, fresh cache arrays by kind or {} when plens is
-    None, routing [3]): k/v [La, B, 1, S, Hkv * Dh] in cache layout, conv
-    [Lc, B, Kc - 1, D] taken at each row's own prompt length. Positions
-    at or past a row's plens are not live: they route to no expert."""
+    None, routing): k/v [La, B, 1, S, Hkv * Dh] in cache layout, conv
+    [Lc, B, Kc - 1, D] and the Mamba-2 layers' SSM and conv state taken
+    at each row's own prompt length. Positions at or past a row's plens
+    are not live: they route to no expert and pass the SSM state on
+    unchanged."""
     S = x.shape[1]
     live = None if plens is None else \
         jnp.arange(S)[None, :] < plens[:, None]
-    fresh = {"k": [], "v": [], "conv": []}
-    routing = jnp.zeros((3,), jnp.int32)
+    fresh = {"k": [], "v": [], "conv": [], "ssm": [], "ssm_conv": []}
+    routing = jnp.zeros((routing_width(cfg),), jnp.int32)
     side = kv_heads_per_row(cfg)
     for seg, sp in zip(layer_plan(cfg), params["segments"]):
         sliced, experts = _split_experts(sp, cfg)
@@ -1641,28 +1918,41 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
         def body(carry, xs, seg=seg, experts=experts):
             x, routing = carry
             rep, lps = xs
-            ks, vs, cs = [], [], []
+            ks, vs, cs, ss, scs = [], [], [], [], []
             for lp, ex, (op, _) in zip(lps, experts, seg.kinds):
                 h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
-                if op == OP_ATTN:
+                if op in _KV_OPS:
                     q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
                     attn = gqa_attention(q, k, v, mask)
                     with jax.named_scope("attn/out"):
                         x = x + _qdot(attn, lp, "wo", cfg)
                     ks.append(_kv_slab(k, side))
                     vs.append(_kv_slab(v, side))
+                elif op == OP_MAMBA:
+                    y, st, cst = _mamba_op(h, lp, cfg, live=live, plens=plens)
+                    x = x + y
+                    ss.append(st)
+                    scs.append(cst)
+                    routing = routing + _routing_counts(cfg, ssm=True)
+                elif op == OP_MOE:
+                    y, st = _sparse_ff(h, lp, ex, rep, cfg, live)
+                    x = x + y
+                    routing = routing + _routing_counts(cfg, st)
                 else:
                     y, st = _conv_op(h, lp, cfg, plens=plens)
                     x = x + y
                     cs.append(st)
-                x, r = _ff_res(x, lp, ex, rep, cfg, live)
-                routing = routing + r
+                if op in FUSED_OPS:
+                    x, r = _ff_res(x, lp, ex, rep, cfg, live)
+                    routing = routing + r
             ys = {}
             if plens is not None:
                 if ks:
                     ys["k"], ys["v"] = jnp.stack(ks), jnp.stack(vs)
                 if cs:
                     ys["conv"] = jnp.stack(cs)
+                if ss:
+                    ys["ssm"], ys["ssm_conv"] = jnp.stack(ss), jnp.stack(scs)
             return (x, routing), ys
 
         (x, routing), ys = jax.lax.scan(
@@ -1677,27 +1967,48 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
     """One decode step through every layer. KV is read PRE-write and all
     attention layers' fresh k/v land after the scans in one scatter
     (_run_blocks_decode's discipline); the conv state is small and is
-    replaced whole. Rows that are not live still shift their conv state
-    and scribble KV at their frozen position: harmless, an admission
-    overwrites both before the slot is read again."""
+    replaced whole. The SSM state is neither: every layer's [B, H, P, N]
+    float32 is read and written on every step, so it rides in the scans'
+    CARRY and each Mamba-2 layer updates its own slice of it where it
+    lies (as scanned inputs and outputs it would be a second array, and
+    the step a copy of the whole state). Rows that are not live still
+    shift their conv state, step their SSM state and scribble KV at
+    their frozen position: harmless, an admission overwrites all three
+    before the slot is read again."""
     Smax = cache["k"].shape[3]
     mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
     live2 = None if live is None else live[:, None]
-    fresh = {"k": [], "v": [], "conv": []}
-    routing = jnp.zeros((3,), jnp.int32)
+    fresh = {"k": [], "v": [], "conv": [], "ssm_conv": []}
+    routing = jnp.zeros((routing_width(cfg),), jnp.int32)
     dt = cache["k"].dtype
     side = kv_heads_per_row(cfg)
+    ssm = (cache["ssm"],) if "ssm" in cache else ()
     for seg, sp in zip(layer_plan(cfg), params["segments"]):
         sliced, experts = _split_experts(sp, cfg)
+        nm = _count_ops(seg.kinds, OP_MAMBA)
 
-        def body(carry, xs, seg=seg, experts=experts):
-            x, routing = carry
+        def body(carry, xs, seg=seg, experts=experts, nm=nm):
+            x, routing, *ssm = carry
             rep, lps, cl = xs
-            ia = ic = 0
-            ks, vs, cs = [], [], []
+            ia = ic = im = 0
+            ks, vs, cs, scs = [], [], [], []
             for lp, ex, (op, _) in zip(lps, experts, seg.kinds):
                 h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
-                if op == OP_ATTN:
+                if op == OP_MAMBA:
+                    at = seg.ssm_start + rep * nm + im
+                    y, st, cst = _mamba_op(
+                        h, lp, cfg, conv_state=cl["ssm_conv"][im],
+                        state=(ssm[0], at))
+                    ssm = [st]
+                    x = x + y
+                    scs.append(cst.astype(cl["ssm_conv"].dtype))
+                    routing = routing + _routing_counts(cfg, ssm=True)
+                    im += 1
+                elif op == OP_MOE:
+                    y, st = _sparse_ff(h, lp, ex, rep, cfg, live2)
+                    x = x + y
+                    routing = routing + _routing_counts(cfg, st)
+                elif op in _KV_OPS:
                     q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
                     attn = gqa_attention_decode(
                         q, cl["k"][ia], cl["v"][ia], k, v, mask_lt)
@@ -1711,17 +2022,20 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     x = x + y
                     cs.append(st.astype(cl["conv"].dtype))
                     ic += 1
-                x, r = _ff_res(x, lp, ex, rep, cfg, live2)
-                routing = routing + r
+                if op in FUSED_OPS:
+                    x, r = _ff_res(x, lp, ex, rep, cfg, live2)
+                    routing = routing + r
             ys = {}
             if ks:
                 ys["k"], ys["v"] = jnp.stack(ks), jnp.stack(vs)
             if cs:
                 ys["conv"] = jnp.stack(cs)
-            return (x, routing), ys
+            if scs:
+                ys["ssm_conv"] = jnp.stack(scs)
+            return (x, routing, *ssm), ys
 
-        (x, routing), ys = jax.lax.scan(
-            body, (x, routing),
+        (x, routing, *ssm), ys = jax.lax.scan(
+            body, (x, routing, *ssm),
             (jnp.arange(seg.reps), sliced, _segment_cache(cache, seg)))
         for key, val in ys.items():
             fresh[key].append(val)
@@ -1742,14 +2056,17 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     unique_indices=True)
     if fresh["conv"]:
         new_cache["conv"] = _unsegment(fresh["conv"])
+    if ssm:
+        new_cache["ssm"] = ssm[0]
+        new_cache["ssm_conv"] = _unsegment(fresh["ssm_conv"])
     return x, new_cache, routing
 
 
 def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):
     """prefill() for a patterned stack: KV of the attention layers into
-    positions [0, S), each conv layer's state at the row's own prompt
-    length (rows of one admission group are right-padded to the bucket:
-    the state at the bucket's end would be the padding's)."""
+    positions [0, S), each conv or Mamba-2 layer's state at the row's
+    own prompt length (rows of one admission group are right-padded to
+    the bucket: the state at the bucket's end would be the padding's)."""
     B, S = tokens.shape
     x = _embed_rows(params, tokens, _dtype(cfg))
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
@@ -1760,7 +2077,7 @@ def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):
     with jax.named_scope("attn/cache_update"):
         for key, val in fresh.items():
             val = val.astype(cache[key].dtype)
-            if key == "conv" or S == cache[key].shape[3]:
+            if key in _FIXED_STATE or S == cache[key].shape[3]:
                 new_cache[key] = val
             else:
                 new_cache[key] = cache[key].at[:, :, :, :S].set(val)

@@ -65,6 +65,7 @@ def route(
     router: str,
     norm_topk: bool = True,
     scale: float = 1.0,
+    norm_eps: float = 1e-6,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(expert ids [N, k] int32, weights [N, k] float32)."""
     logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32), router_w)
@@ -76,7 +77,7 @@ def route(
     _, top_idx = jax.lax.top_k(select, top_k)
     w = jnp.take_along_axis(scores, top_idx, axis=-1)
     if norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
     return top_idx, w * scale
 
 
@@ -93,15 +94,19 @@ def _gmm_tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
     return min(tm, m), fit(k, _GMM_TILE_K), fit(n, _GMM_TILE_N)
 
 
-def _ragged_dot(lhs, rhs, group_sizes):
+def _ragged_dot(lhs, rhs, group_sizes, transpose_rhs=False):
+    if transpose_rhs:
+        rhs = jnp.swapaxes(rhs, 1, 2)
     return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32))
 
 
-def _megablox(lhs, rhs, group_sizes):
+def _megablox(lhs, rhs, group_sizes, transpose_rhs=False):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m = lhs.shape[0]
-    tm = _gmm_tiles(m, rhs.shape[1], rhs.shape[2])[0]
+    # rhs stored [E, N, K] (transpose_rhs): tiles by the logical (m, k, n)
+    k, n = rhs.shape[2:0:-1] if transpose_rhs else rhs.shape[1:]
+    tm = _gmm_tiles(m, k, n)[0]
     pad = (-m) % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
@@ -112,39 +117,53 @@ def _megablox(lhs, rhs, group_sizes):
         [group_sizes.astype(jnp.int32), rest[None].astype(jnp.int32)])
     out = gmm(
         lhs, rhs, sizes, preferred_element_type=lhs.dtype,
-        tiling=_gmm_tiles(m + pad, rhs.shape[1], rhs.shape[2]),
-        group_offset=jnp.zeros((), jnp.int32),
+        tiling=_gmm_tiles(m + pad, k, n),
+        group_offset=jnp.zeros((), jnp.int32), transpose_rhs=transpose_rhs,
     )
     return out[:m] if pad else out
 
 
 def grouped_matmul(
     lhs: jnp.ndarray,  # [M, K] rows sorted by group
-    rhs: jnp.ndarray,  # [E, K, N]
+    rhs: jnp.ndarray,  # [E, K, N]; [E, N, K] with transpose_rhs
     group_sizes: jnp.ndarray,  # [E] int32, sum <= M
+    transpose_rhs: bool = False,
 ) -> jnp.ndarray:
     """out[r] = lhs[r] @ rhs[group of r]; rows past sum(group_sizes)
     belong to no group (callers mask them: their value is unspecified)."""
-    if jax.default_backend() == "tpu":
-        return _megablox(lhs, rhs, group_sizes)
-    return _ragged_dot(lhs, rhs, group_sizes)
+    product = _megablox if jax.default_backend() == "tpu" else _ragged_dot
+    return product(lhs, rhs, group_sizes, transpose_rhs)
 
 
 def dispatch_experts(
     x: jnp.ndarray,  # [N, D]
     top_idx: jnp.ndarray,  # [N, k] int32
     top_w: jnp.ndarray,  # [N, k] float32
-    w_gate: jnp.ndarray,  # [L * E, D, F]
-    w_up: jnp.ndarray,  # [L * E, D, F]
+    w_gate: Optional[jnp.ndarray],  # [L * E, D, F]; None: no gate
+    w_up: jnp.ndarray,  # [L * E, D, F]; without a gate [L * E, F, D]
     w_down: jnp.ndarray,  # [L * E, F, D]
     live: Optional[jnp.ndarray] = None,  # [N] bool; None = every row
     *,
     n_experts: int,
     layer: Optional[jnp.ndarray] = None,  # int32 scalar in [0, L)
+    first: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """sum_j top_w[:, j] * SwiGLU_{top_idx[:, j]}(x) for live rows, zeros
     for the others. Also what routing did: `touched` (experts that hold
     at least one live row) and `assignments` (live rows x k).
+
+    Without w_gate an expert is down(relu(up x) ** 2), and its up matrix
+    is stored as the down matrix is, [F, D], and applied transposed: an
+    expert width that is no multiple of the TPU's 128 lanes (1856) would
+    otherwise be the stack's minor dimension, which the device stores
+    D-minor and the compiled chunk relays out for the kernel, every
+    expert's matrix on every chunk. With `first` the
+    weights are a SHARE of the experts the router scored: n_experts of
+    them, ids [first, first + n_experts). An assignment to an expert
+    that is not held goes nowhere, exactly as a dead row's does: its
+    term of the sum is left out (top_w is over all the chosen, held or
+    not). `touched` then counts held experts, `assignments` stays what
+    live rows chose and `held` says how many of those were held here.
 
     The weights may be those of L stacked layers with the layer and
     expert axes merged ([L * E, ...], a reshape that moves nothing) and
@@ -159,6 +178,9 @@ def dispatch_experts(
     A = N * K
     with jax.named_scope("moe/dispatch"):
         eids = top_idx.reshape(A).astype(jnp.int32)
+        if first is not None:
+            eids = eids - first
+            eids = jnp.where((eids >= 0) & (eids < E), eids, E)
         if live is not None:
             # expert id E = nowhere: sorts behind every real group
             eids = jnp.where(jnp.repeat(live, K), eids, E)
@@ -169,13 +191,17 @@ def dispatch_experts(
         n_routed = jnp.sum(group_sizes)
         xs = jnp.take(x, order // K, axis=0)  # [A, D] sorted by expert
         sizes = group_sizes
-        if w_gate.shape[0] != E:
+        if w_up.shape[0] != E:
             sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((w_gate.shape[0],), jnp.int32), group_sizes,
+                jnp.zeros((w_up.shape[0],), jnp.int32), group_sizes,
                 (layer.astype(jnp.int32) * E,))
     with jax.named_scope("moe/experts"):
-        hidden = jax.nn.silu(grouped_matmul(xs, w_gate, sizes)) \
-            * grouped_matmul(xs, w_up, sizes)
+        if w_gate is None:
+            hidden = jnp.square(jax.nn.relu(
+                grouped_matmul(xs, w_up, sizes, transpose_rhs=True)))
+        else:
+            hidden = jax.nn.silu(grouped_matmul(xs, w_gate, sizes)) \
+                * grouped_matmul(xs, w_up, sizes)
         ys = grouped_matmul(hidden, w_down, sizes)  # [A, D]
     with jax.named_scope("moe/combine"):
         ys = jnp.where((jnp.arange(A) < n_routed)[:, None], ys, 0)
@@ -188,4 +214,9 @@ def dispatch_experts(
         "touched": jnp.sum(group_sizes > 0, dtype=jnp.int32),
         "assignments": n_routed.astype(jnp.int32),
     }
+    if first is not None:
+        stats["held"] = stats["assignments"]
+        stats["assignments"] = (
+            jnp.asarray(A, jnp.int32) if live is None
+            else K * jnp.sum(live, dtype=jnp.int32))
     return out.astype(x.dtype), stats

@@ -160,21 +160,39 @@ def _kv_layers(cfg) -> int:
     return getattr(cfg, "n_attn_layers", cfg.n_layers)
 
 
+def _head_dim(cfg) -> int:
+    return getattr(cfg, "head_dim", 0) or cfg.d_model // cfg.n_heads
+
+
 def _patterned_layer_params(cfg, experts_per_layer: int) -> int:
     """Matmul weights of a WHOLE patterned stack (per-layer kinds differ,
     so there is no per-layer figure): attention layers' qkv + o, conv
-    layers' in/out projections, leading dense SwiGLUs, and
-    `experts_per_layer` experts of width d_ff_expert in each sparse
-    layer (k for what a token multiplies through, E for what a batched
-    wave may read)."""
-    d, hd = cfg.d_model, cfg.d_model // cfg.n_heads
-    attn = d * (cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd) + d * d
+    layers' in/out projections, Mamba-2 layers' in/out projections,
+    dense SwiGLUs (the feed-forwards of layers that have one and are not
+    sparse), and in each sparse layer `experts_per_layer` experts of
+    width d_ff_expert (k for what a token multiplies through, E for what
+    a batched wave may read; at most the experts held here) plus the
+    shared expert. An expert is 3 matrices, 2 without a gate (relu2)."""
+    d, hd = cfg.d_model, _head_dim(cfg)
+    attn = d * (cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd) \
+        + cfg.n_heads * hd * d
     conv = d * 3 * d + d * d
+    n_mamba = getattr(cfg, "n_mamba_layers", 0)
+    mamba = 0
+    if n_mamba:
+        mamba = d * (2 * cfg.ssm_inner
+                     + 2 * cfg.ssm_groups * cfg.ssm_state + cfg.ssm_heads) \
+            + cfg.ssm_inner * d
     sparse = cfg.n_sparse_layers
-    dense = cfg.n_layers - sparse
+    dense = 0 if getattr(cfg, "single_blocks", False) \
+        else cfg.n_layers - sparse
+    mats = 2 if getattr(cfg, "ff_act", "swiglu") == "relu2" else 3
+    held = getattr(cfg, "experts_held", cfg.n_experts)
     return (cfg.n_attn_layers * attn + cfg.n_conv_layers * conv
-            + dense * 3 * d * cfg.d_ff
-            + sparse * experts_per_layer * 3 * d * cfg.expert_width)
+            + n_mamba * mamba + dense * 3 * d * cfg.d_ff
+            + sparse * mats * d * (
+                min(experts_per_layer, held) * cfg.expert_width
+                + getattr(cfg, "d_ff_shared", 0)))
 
 
 def matmul_params_per_layer(cfg, tp: int = 1) -> int:
@@ -217,7 +235,8 @@ def attn_flops(cfg, q_tokens: int, kv_len: int, tp: int = 1) -> int:
     QK^T and PV are 2 flops per (head, dim, position) each, and GQA
     shares K/V without shrinking the query side: 4 * d_model * q * kv
     per layer. Heads shard on 'tp', so per-chip attention divides."""
-    return 4 * cfg.d_model * q_tokens * kv_len * _kv_layers(cfg) // tp
+    return 4 * cfg.n_heads * _head_dim(cfg) * q_tokens * kv_len \
+        * _kv_layers(cfg) // tp
 
 
 def causal_attn_flops(cfg, s_tokens: int, prior: int = 0,
@@ -226,7 +245,8 @@ def causal_attn_flops(cfg, s_tokens: int, prior: int = 0,
     attends prior + i + 1 positions — the arithmetic-series sum of
     attn_flops."""
     total_kv = s_tokens * prior + s_tokens * (s_tokens + 1) // 2
-    return 4 * cfg.d_model * total_kv * _kv_layers(cfg) // tp
+    return 4 * cfg.n_heads * _head_dim(cfg) * total_kv \
+        * _kv_layers(cfg) // tp
 
 
 def weight_bytes(cfg, tp: int = 1) -> int:
@@ -259,17 +279,25 @@ def kv_bytes_per_token(cfg, tp: int = 1) -> int:
     """KV-cache bytes one token position occupies across every layer
     PER CHIP: K + V at the kv dtype, GQA heads only — the cache shards
     exactly on its head axis, so tp divides cleanly."""
-    hd = cfg.d_model // cfg.n_heads
+    hd = _head_dim(cfg)
     return 2 * _kv_layers(cfg) * cfg.n_kv_heads * hd * _kvbytes(cfg) // tp
 
 
 def state_bytes_per_slot(cfg) -> int:
     """Bytes of fixed-size per-slot state a decode step reads and writes
     whatever the context: a patterned stack's conv state (conv_kernel - 1
-    inputs of d_model per conv layer, bf16), 0 otherwise. With
-    kv_bytes_per_token this is models/transformer.cache_spec, per kind."""
+    inputs of d_model per conv layer, bf16) and its Mamba-2 layers'
+    (ssm_heads x ssm_head_dim x ssm_state float32, and conv_kernel - 1
+    inputs of ssm_conv_dim, bf16), 0 otherwise. With kv_bytes_per_token
+    this is models/transformer.cache_spec, per kind."""
     n_conv = getattr(cfg, "n_conv_layers", 0)
-    return n_conv * (cfg.conv_kernel - 1) * cfg.d_model * 2 if n_conv else 0
+    n_mamba = getattr(cfg, "n_mamba_layers", 0)
+    total = n_conv * (cfg.conv_kernel - 1) * cfg.d_model * 2 if n_conv else 0
+    if n_mamba:
+        total += n_mamba * (
+            cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
+            + (cfg.conv_kernel - 1) * cfg.ssm_conv_dim * 2)
+    return total
 
 
 # -- per-key closed forms ---------------------------------------------------

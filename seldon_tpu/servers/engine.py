@@ -609,7 +609,11 @@ SAMPLER_COUNTERS = ("sampler_steps", "sampler_drawn_steps",
                     "sampler_masked_steps")
 MOE_COUNTERS = ("moe_sparse_layer_steps", "moe_experts_touched",
                 "moe_assignments")
-CHUNK_COUNTERS = SAMPLER_COUNTERS + MOE_COUNTERS
+# ... and, after them, of a stack that holds a share of its experts or
+# has Mamba-2 layers (transformer.routing_width): the assignments that
+# went to experts held here, and the Mamba-2 layers run.
+SHARE_COUNTERS = ("moe_assignments_held", "ssm_layer_steps")
+CHUNK_COUNTERS = SAMPLER_COUNTERS + MOE_COUNTERS + SHARE_COUNTERS
 
 
 class EngineStats:
@@ -649,6 +653,12 @@ class EngineStats:
         self.moe_sparse_layer_steps = 0  # graftlint: guarded-by(lock) via(stats)
         self.moe_experts_touched = 0  # graftlint: guarded-by(lock) via(stats)
         self.moe_assignments = 0  # graftlint: guarded-by(lock) via(stats)
+        # A program that holds a share of its routed experts: how many
+        # of moe_assignments went to experts held here (held /
+        # assignments = the share, if routing is even). And the Mamba-2
+        # layers run over all decode steps.
+        self.moe_assignments_held = 0  # graftlint: guarded-by(lock) via(stats)
+        self.ssm_layer_steps = 0  # graftlint: guarded-by(lock) via(stats)
         # Prefix-cache observability: admissions that reused cached KV,
         # prompt tokens whose prefill was skipped, and trie nodes evicted
         # under the byte budget.
@@ -1447,9 +1457,9 @@ class InferenceEngine:
                 self._hbm.gauge("kv_cache", self._hbm_kv_reserved_bytes)
                 self._hbm.gauge("kv_live", self._hbm_kv_live_bytes)
                 self._hbm.gauge("prefix_cache", self._hbm_prefix_bytes)
-                if self.cfg.n_conv_layers:
-                    self._hbm.set_static(
-                        "conv_state", self.cache_bytes()["conv"])
+                for kind, nbytes in self.cache_bytes().items():
+                    if kind != "kv":  # a fixed-size state, by its kind
+                        self._hbm.set_static(kind + "_state", nbytes)
             else:
                 # Per-device accounting on the mesh: weights are priced
                 # from each leaf's committed shard shape (replicated
@@ -1504,25 +1514,27 @@ class InferenceEngine:
         self._san = graftsan.instrument(self)
 
     def _refuse_unpatterned_paths(self) -> None:
-        """A patterned stack (cfg.layer_types: conv state beside KV)
-        runs on the default path: dense slab, tp = 1. Every opt-in path
-        moves, shares or replays KV by token position and knows no
-        fixed-size state, so it would serve the conv layers a state
-        that is stale, another request's or absent. Refuse each by name
-        here, at construction, not with wrong tokens later."""
+        """A patterned stack (cfg.layer_types: conv state, or a Mamba-2
+        mixer's SSM and conv state, beside KV) runs on the default path:
+        dense slab, tp = 1. Every opt-in path moves, shares or replays
+        KV by token position and knows no fixed-size state, so it would
+        serve those layers a state that is stale, another request's or
+        absent. Refuse each by name here, at construction, not with
+        wrong tokens later."""
         if not self.cfg.patterned:
             return
         e = self.ecfg
+        state = "SSM state" if self.cfg.n_mamba_layers else "conv state"
         asked = [
             name for name, on in (
                 ("paged_kv (the block pool holds KV only)", e.paged_kv),
-                ("prefix_cache (a reused prefix carries no conv state)",
+                (f"prefix_cache (a reused prefix carries no {state})",
                  e.prefix_cache),
-                ("chunked_prefill (a chunk would have to resume the conv "
-                 "state)", e.chunked_prefill),
+                (f"chunked_prefill (a chunk would have to resume the {state})",
+                 e.chunked_prefill),
                 ("ragged (the fused wave is paged)", e.ragged),
-                ("spec_decode (a rejected draft cannot rewind the conv "
-                 "state)", e.spec_decode),
+                (f"spec_decode (a rejected draft cannot rewind the {state})",
+                 e.spec_decode),
                 ("heal (replay re-admits by KV position)",
                  supervisor.build(e) is not None),
                 ("tp > 1 (tp_sharding has no table for this tree)",
@@ -1531,8 +1543,8 @@ class InferenceEngine:
         ]
         if asked:
             raise ValueError(
-                "this model has a patterned stack (layer_types: conv "
-                "state beside KV), which is served on the default path "
+                f"this model has a patterned stack (layer_types: {state} "
+                "beside KV), which is served on the default path "
                 "only; not with " + "; ".join(asked)
             )
 
@@ -1605,7 +1617,7 @@ class InferenceEngine:
             logits, seeds, plens, temps, top_ks, top_ps, max_news, Smax, cfg)
         # Scatter EVERY cache array by its kind (transformer.cache_spec):
         # k/v + scales into the slots' first Sb positions, a fixed-size
-        # state (a patterned stack's conv state) overwritten whole.
+        # state (a patterned stack's conv or SSM state) overwritten whole.
         new_cache = transformer.cache_scatter_slots(
             cfg, cache, sub, slots, Sb)
         new_state = slot_rules.arm(
@@ -1763,7 +1775,9 @@ class InferenceEngine:
         active [B], counts): counts int32 over the chunk, in
         CHUNK_COUNTERS' order: steps, steps that drew, steps that masked;
         a routed model adds sparse-layer steps, distinct experts read
-        (summed over those), assignments."""
+        (summed over those), assignments; one that holds a share of its
+        experts or has Mamba-2 layers adds the assignments held here
+        and the Mamba-2 layers run (transformer.routing_width)."""
         Smax = state["cache"]["k"].shape[3]
         # A model that dispatches tokens to experts is told which rows
         # hold a request (the others route to no expert), and what
@@ -2209,8 +2223,9 @@ class InferenceEngine:
 
     def cache_bytes(self) -> Dict[str, int]:
         """Bytes of the slot cache by kind: {"kv": ...} and, for a
-        patterned stack, {"conv": ...} (transformer.cache_spec's kinds;
-        the paged pool is all KV). Shape metadata — no sync."""
+        patterned stack, {"conv": ...} or {"ssm": ..., "ssm_conv": ...}
+        (transformer.cache_spec's kinds; the paged pool is all KV).
+        Shape metadata — no sync."""
         if self._paged:
             return {"kv": sum(
                 int(x.nbytes)
@@ -2221,8 +2236,9 @@ class InferenceEngine:
 
     def _hbm_kv_reserved_bytes(self) -> int:
         """Static KV reservation: the KV arrays of the cache (dense slot
-        slab or paged block pool); a patterned stack's conv state is its
-        own category, "conv_state"."""
+        slab or paged block pool); a patterned stack's fixed-size state
+        is categories of its own ("conv_state", "ssm_state",
+        "ssm_conv_state")."""
         return self.cache_bytes()["kv"]
 
     def _hbm_kv_live_bytes(self) -> int:
@@ -4463,8 +4479,9 @@ class InferenceEngine:
         # ended (EngineStats.sampler_*, and moe_* for a model that
         # dispatches tokens to experts): two lines' difference is what
         # the sampler and routing did in decode between them.
-        names = (CHUNK_COUNTERS if self._counts_routing(self.cfg)
-                 else SAMPLER_COUNTERS)
+        names = (CHUNK_COUNTERS[:len(SAMPLER_COUNTERS)
+                                + transformer.routing_width(self.cfg)]
+                 if self._counts_routing(self.cfg) else SAMPLER_COUNTERS)
         with self.stats.lock:
             phases.update({name: getattr(self.stats, name)
                            for name in names})

@@ -38,9 +38,11 @@ snapshot — never a device sync.  Rules of the house:
 Expected category names: "weights", "kv_cache" (static reservation),
 "kv_live" (bytes holding active request state), "prefix_cache",
 "workspace", and for a model with a patterned stack "conv_state" (the
-conv layers' fixed-size per-slot state, static; "kv_cache" then counts
-the attention layers only — the engine reads both from the one per-kind
-cache spec, models/transformer.cache_spec).
+conv layers' fixed-size per-slot state, static) or "ssm_state" and
+"ssm_conv_state" (a Mamba-2 mixer's float32 state and its
+convolution's inputs); "kv_cache" then counts the attention layers
+only — the engine reads them all from the one per-kind cache spec,
+models/transformer.cache_spec.
 
 graftmesh (tp > 1) grows per-device accounting, not a new schema mode:
 ``set_devices`` records the mesh size, ``set_static``/``gauge`` take an

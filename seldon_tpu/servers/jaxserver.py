@@ -38,6 +38,7 @@ from seldon_tpu.runtime.user_model import SeldonComponent
 from seldon_tpu.servers.engine import (
     KIND_HTTP_STATUS,
     MOE_COUNTERS,
+    SHARE_COUNTERS,
     EngineConfig,
     InferenceEngine,
     access_log,
@@ -624,7 +625,8 @@ class JAXServer(SeldonComponent):
             "device": device.describe(),
             # Bytes of the per-slot cache by kind (transformer.cache_spec:
             # "kv" over the layers that hold KV, "conv" the fixed-size
-            # state of a patterned stack's conv layers).
+            # state of a patterned stack's conv layers, "ssm" and
+            # "ssm_conv" that of its Mamba-2 layers).
             "cache_bytes": self.engine.cache_bytes(),
             # What a client's warm-up otherwise has to discover: the
             # largest admission group, the decode-chunk rungs and how
@@ -1051,7 +1053,8 @@ class JAXServer(SeldonComponent):
                    - s["sampler_masked_steps"]),
                   ("masked", s["sampler_masked_steps"]))),
             *({"type": "GAUGE", "key": "jaxserver_" + name,
-               "value": float(s[name])} for name in MOE_COUNTERS),
+               "value": float(s[name])}
+              for name in MOE_COUNTERS + SHARE_COUNTERS),
             {"type": "GAUGE", "key": "jaxserver_prefix_hits",
              "value": float(s["prefix_hits"])},
             {"type": "GAUGE", "key": "jaxserver_prefix_tokens_saved",

@@ -13,6 +13,7 @@ compiles in this one file: the worker that runs it holds libtpu.
 
 import dataclasses
 import functools
+import re
 
 import jax
 import pytest
@@ -54,7 +55,8 @@ def dense_config(kv_dtype):
     copies three such stacks on entry, PERF.md section 7): an op that
     large can only be cache."""
     return dataclasses.replace(
-        get_config("tiny"), d_model=1024, n_heads=8, n_kv_heads=4, d_ff=1024,
+        get_config("tiny"), d_model=1024, n_heads=8, head_dim=0, n_kv_heads=4,
+        d_ff=1024,
         n_layers=3, vocab_size=512, max_seq_len=WINDOW,
         kv_cache_dtype=kv_dtype).validate()
 
@@ -96,3 +98,58 @@ def test_decode_chunk_copies_no_layer_of_the_slab(one_chip, kv_dtype):
     assert relayouts(hlo, layer_k) == []
     # the reader finds what it looks for: the weights' copies are there
     assert relayouts(hlo, 1)
+
+
+def mamba_config():
+    """Single-block layers sized so that ONE Mamba-2 layer's SSM state
+    over the slab (SLOTS x 8 heads x 64 x 128 float32 = 2 Mi elements)
+    is larger than any weight matrix stacked over the period's repeats
+    and than the KV and conv state: a float32 result that large can only
+    be the SSM state."""
+    return dataclasses.replace(
+        get_config("tiny-nemotron"), d_model=256, n_layers=14,
+        layer_types=("mamba", "moe", "mamba", "moe", "mamba", "attention",
+                     "moe") * 2,
+        ssm_heads=8, ssm_head_dim=64, ssm_groups=2, ssm_state=128,
+        ssm_chunk=128, vocab_size=512, max_seq_len=WINDOW).validate()
+
+
+def test_decode_chunk_updates_the_ssm_state_in_place(one_chip, monkeypatch):
+    """Every decode step reads and writes every Mamba-2 layer's state for
+    every slot. The compiled chunk does so where the state lies: the
+    whole state appears only as the aliased result of the update kernel
+    (ops/ssm_update.py, one call a Mamba-2 layer of the period) or
+    carried through the loops, and nothing at the top level (a copy, a
+    transpose, a stand-alone slice or its fusion) yields an array of one
+    layer's state."""
+    from seldon_tpu.ops import moe_dispatch, ssm_update
+
+    # the chip's branches (the program asks jax.default_backend())
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul", moe_dispatch._megablox)
+    monkeypatch.setattr(ssm_update, "update", ssm_update._pallas)
+    cfg = mamba_config()
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    state = shapes(jax.eval_shape(
+        lambda: slot.fresh(transformer.init_cache(cfg, SLOTS, WINDOW),
+                           SLOTS)))
+    chunk = jax.jit(
+        functools.partial(InferenceEngine._chunk_impl, cfg=cfg,
+                          n_steps=STEPS),
+        donate_argnums=(1,))
+    hlo = chunk.lower(params, state).compile().as_text()
+    ssm = state["cache"]["ssm"]
+    assert ssm.shape == (6, SLOTS, 8, 64, 128) and ssm.dtype == "float32"
+    layer = SLOTS * 8 * 64 * 128
+    # no instruction at all yields an array as large as one layer's state
+    # (the loops and the kernel carry it inside tuples)
+    assert big_instructions(hlo, layer) == []
+    whole = re.escape("f32[6,%d,8,64,128]" % SLOTS)
+    calls = re.findall(r"%(ssm_update[.\w]*) = \(" + whole + r"\S* f32\[", hlo)
+    assert len(calls) == 3, calls

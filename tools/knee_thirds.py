@@ -15,7 +15,18 @@ import sys
 LINE = re.compile(r"\brequest (\{.*\})\s*$")
 
 
-AFTER_THE_LOOP = 5  # run.py's four probes and its last request
+PROBE_LENS = [24, 60, 100, 120]  # benchmark/run.py's probes, sent alone and in this order
+
+
+def the_loop(rows):
+    """The access lines of the open loop: those between run.py's two sets
+    of probes (before the lead-in, after the drain). client.run_open cuts
+    the tail once the window's requests are done, so the loop's length is
+    not the generator's."""
+    lens = [r.get("prompt_tokens") for r in rows]
+    at = [i for i in range(len(rows) - 3) if lens[i:i + 4] == PROBE_LENS]
+    assert len(at) >= 2, f"found {len(at)} sets of probes in unit.log, need the two around the loop"
+    return rows[at[-2] + 4:at[-1]]
 
 
 def main(cell: str, out_file: str, seconds: str, seed: str) -> int:
@@ -39,8 +50,8 @@ def main(cell: str, out_file: str, seconds: str, seed: str) -> int:
     spec = traffic.load_traffic(os.path.join(root, "benchmark"), mix, cell)
     reqs = traffic.open_loop(spec, int(seed), float(seconds), 65536)
     phases = [r.phase for r in sorted(reqs, key=lambda r: r.due)]
-    loop = rows[-(AFTER_THE_LOOP + len(reqs)):-AFTER_THE_LOOP]
-    assert len(loop) == len(reqs), (len(rows), len(reqs))
+    loop = the_loop(rows)
+    assert len(loop) <= len(reqs), (len(loop), len(reqs))
     # the unit received them in the order they were due (one per 1/rate s)
     win = [r for r, ph in zip(loop, phases) if ph == "window"]
     lo, hi = win[0]["received_unix"], win[0]["received_unix"] + float(seconds)
