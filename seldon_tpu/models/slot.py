@@ -161,16 +161,15 @@ def decode_chunk(
     """`n_steps` decode steps over every slot in one lax.scan.
     `step_model(carry)` is all that differs between paths: it runs the
     model on the carry's last tokens and returns (logits, cache), and
-    after them what routing did where the model dispatches tokens to
-    experts. Returns (state, toks [K, B], valid [K, B], counts): valid
-    is a True-prefix per column, counts the steps' counts summed,
-    decode_step's three and then routing's."""
+    after them an int32 vector of whatever else the path counts a step
+    (what attention read of the slab, what routing did). Returns (state,
+    toks [K, B], valid [K, B], counts): valid is a True-prefix per
+    column, counts the steps' counts summed, decode_step's three and
+    then the path's."""
     def step(carry, _):
-        logits, cache, *routing = step_model(carry)
+        logits, cache, *more = step_model(carry)
         carry, tok, run, counts = decode_step(carry, logits, cache, Smax, cfg)
-        if routing:
-            counts = jnp.concatenate([counts, routing[0]])
-        return carry, (tok, run, counts)
+        return carry, (tok, run, jnp.concatenate([counts, *more]))
 
     state, (toks, valid, counts) = jax.lax.scan(
         step, state, None, length=n_steps)

@@ -36,7 +36,7 @@ from seldon_tpu.models.config import (
     ModelConfig,
 )
 from seldon_tpu.models.quantize import dequant
-from seldon_tpu.ops import moe_dispatch, ssm_update
+from seldon_tpu.ops import decode_attention, moe_dispatch, ssm_update
 
 Params = Dict[str, Any]
 
@@ -776,26 +776,67 @@ def _run_blocks_prefill_prefix(params, x, cfg, positions, inv_freq, mask,
     return x, {"k": ks, "v": vs}, jnp.mean(aux)
 
 
+def _sparse_decode(cfg, cache, live, pos, spread: bool):
+    """The decode step's work list for ops/decode_attention, or None
+    where its attention layers keep gqa_attention_decode's einsums over
+    the whole layer: off a TPU, where the slab is `spread` over several
+    devices (tensor parallelism: each device contracts its own lanes of
+    the row, tp.rows; or a mesh the compiler partitions the program
+    over, which it cannot do to a kernel) and for a slab the kernel
+    cannot read (decode_attention.applies). Made once a step: every
+    layer walks the same live slots to the same positions."""
+    k = cache["k"]
+    block = 0 if spread else decode_attention.applies(k, cfg.head_dim)
+    if not block:
+        return None
+    if live is None:
+        live = jnp.ones(pos.shape, bool)
+    return decode_attention.schedule(live, pos, k.shape[3], block)
+
+
+def decode_kv_counts(cfg, cache, live, pos, spread=False) -> jnp.ndarray:
+    """int32 [2]: KV tokens the attention layers of one decode step
+    read, and KV tokens the slab holds for them (slots x window x
+    attention layers); their ratio is the share of the slab a step
+    touches. The einsums read all they hold; the kernel whole blocks of
+    the live slots up to their positions (_sparse_decode)."""
+    La, B, _, T, _ = cache["k"].shape
+    held = jnp.asarray(La * B * T, jnp.int32)
+    sched = _sparse_decode(cfg, cache, live, pos, spread)
+    if sched is None:
+        return jnp.stack([held, held])
+    return jnp.stack([La * decode_attention.tokens_read(sched), held])
+
+
 def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
-                       act_spec=None, tp=None):
+                       act_spec=None, tp=None, live=None, spread=False):
     """Layer scan for DECODE: the cache is read PRE-write (attention
     handles the current token via an exact fresh column) and all L
     layers' fresh k/v are written back AFTER the scan in one batched
-    scatter. The cache rides the scan as xs — read-only per-layer slices
-    fuse into the attention einsums (GSPMD-shardable), unlike
-    slice-reads of a just-scattered carry. (A pallas decode-attention
-    kernel was built and measured here in rounds 3-4: 16.3 vs 8.1
-    ms/step against this XLA path at 160-slot serving shapes — the
-    einsum path rides XLA's fusions to ~80% of HBM roofline, so the
-    kernel was removed. See git history for the implementation.)
+    scatter. Two ways to read it, told by what is there to see
+    (_sparse_decode): on a TPU, with the dense slab whole on one device,
+    the scan rides on the layer's INDEX and ops/decode_attention reads
+    the live rows' tokens out of the slab where it lies (`live`: the
+    slots that hold a request; None = every slot); otherwise the cache
+    rides the scan as xs — read-only per-layer slices fuse into the
+    attention einsums (GSPMD-shardable), unlike slice-reads of a
+    just-scattered carry — and every slot's whole window is scored and
+    masked. (The Pallas decode kernel of rounds 3-4 that lost to these
+    einsums, 16.3 vs 8.1 ms/step, read every block of 160 slots ALL
+    live: it priced reading everything by a kernel, where the einsums
+    ride at 85-90 % of the HBM peak. The kernel here wins by what it
+    does not read; PERF.md section 5 has its table by occupancy.)
 
     Returns (x, new_cache, aux)."""
     quantized = cfg.kv_cache_dtype == "int8"
     Smax = cache["k"].shape[3]
     mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
     side = kv_heads_per_row(cfg)
+    sched = _sparse_decode(cfg, cache, live, pos, spread or tp is not None)
 
     def attend(q, k, v, cl):
+        if sched is not None:
+            return decode_attention.attend(q, k, v, cache, cl, sched)
         ck, cv = cl["k"], cl["v"]
         if tp is not None:
             # Each device contracts over its own head group's lanes of
@@ -830,7 +871,9 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
                      "v": _kv_rows(v, side)[:, 0].astype(dt)}
         return x, (fresh, aux)
 
-    x, (fresh, aux) = jax.lax.scan(body, x, (params["blocks"], cache))
+    x, (fresh, aux) = jax.lax.scan(
+        body, x, (params["blocks"],
+                  cache if sched is None else jnp.arange(cache["k"].shape[0])))
     rows = jnp.arange(pos.shape[0])
     # k / v: one scatter covers all layers, with layer, row and position
     # all INDICES of it and only the token's row [1, Hkv*Dh] its window:
@@ -1359,12 +1402,18 @@ def decode_step(
     tp=None,
     live: Optional[jnp.ndarray] = None,  # [B] bool: rows that decode
     return_routing: bool = False,
+    spread: bool = False,  # the cache lies over several devices
 ):
     """One autoregressive step. Returns (logits [B, V], updated cache).
 
-    `live` tells a patterned stack's sparse layers which rows hold a
-    request: the others route to no expert, so a step reads the weights
-    of the experts its live rows select and no more (None: every row).
+    `live` tells which rows hold a request (None: every row): a
+    patterned stack's sparse layers route the others to no expert, so a
+    step reads the weights of the experts its live rows select and no
+    more, and on a TPU the attention layers read the live rows' tokens
+    of the slab and nothing of the others (_sparse_decode), whose
+    output is then their fresh token's value alone; `spread` says that
+    the cache lies over several devices (tp says so too), where the
+    einsums stay.
     return_routing adds a third value, int32 [routing_width(cfg)]:
     sparse layers run, distinct experts they read summed over those
     layers, (row, expert) assignments (zeros for a stack without
@@ -1378,10 +1427,11 @@ def decode_step(
     if cfg.patterned:
         refuse_patterned(cfg, "tensor-parallel decode", tp is not None)
         x, cache, routing = _run_patterned_decode(
-            params, x, cfg, positions, inv_freq, pos, cache, live)
+            params, x, cfg, positions, inv_freq, pos, cache, live, spread)
     else:
         x, cache, _ = _run_blocks_decode(params, x, cfg, positions,
-                                         inv_freq, pos, cache, tp=tp)
+                                         inv_freq, pos, cache, tp=tp,
+                                         live=live, spread=spread)
     logits = _logits(params, x, cfg)[:, 0]
     if return_routing:
         return logits, cache, routing
@@ -1963,7 +2013,7 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
 
 
 def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
-                          live):
+                          live, spread=False):
     """One decode step through every layer. KV is read PRE-write and all
     attention layers' fresh k/v land after the scans in one scatter
     (_run_blocks_decode's discipline); the conv state is small and is
@@ -1974,10 +2024,15 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
     the step a copy of the whole state). Rows that are not live still
     shift their conv state, step their SSM state and scribble KV at
     their frozen position: harmless, an admission overwrites all three
-    before the slot is read again."""
+    before the slot is read again. On a TPU the attention layers read
+    the live rows' tokens out of the whole slab by the layer's index
+    (_sparse_decode), and K and V do not ride the scans at all."""
     Smax = cache["k"].shape[3]
     mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
     live2 = None if live is None else live[:, None]
+    sched = _sparse_decode(cfg, cache, live, pos, spread)
+    riding = cache if sched is None else \
+        {key: arr for key, arr in cache.items() if key not in ("k", "v")}
     fresh = {"k": [], "v": [], "conv": [], "ssm_conv": []}
     routing = jnp.zeros((routing_width(cfg),), jnp.int32)
     dt = cache["k"].dtype
@@ -1986,8 +2041,9 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
     for seg, sp in zip(layer_plan(cfg), params["segments"]):
         sliced, experts = _split_experts(sp, cfg)
         nm = _count_ops(seg.kinds, OP_MAMBA)
+        na = _count_ops(seg.kinds, *_KV_OPS)
 
-        def body(carry, xs, seg=seg, experts=experts, nm=nm):
+        def body(carry, xs, seg=seg, experts=experts, nm=nm, na=na):
             x, routing, *ssm = carry
             rep, lps, cl = xs
             ia = ic = im = 0
@@ -2010,8 +2066,13 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     routing = routing + _routing_counts(cfg, st)
                 elif op in _KV_OPS:
                     q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
-                    attn = gqa_attention_decode(
-                        q, cl["k"][ia], cl["v"][ia], k, v, mask_lt)
+                    if sched is None:
+                        attn = gqa_attention_decode(
+                            q, cl["k"][ia], cl["v"][ia], k, v, mask_lt)
+                    else:
+                        attn = decode_attention.attend(
+                            q, k, v, cache, seg.attn_start + rep * na + ia,
+                            sched)
                     with jax.named_scope("attn/out"):
                         x = x + _qdot(attn, lp, "wo", cfg)
                     ks.append(_kv_rows(k, side)[:, 0].astype(dt))
@@ -2036,7 +2097,7 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
 
         (x, routing, *ssm), ys = jax.lax.scan(
             body, (x, routing, *ssm),
-            (jnp.arange(seg.reps), sliced, _segment_cache(cache, seg)))
+            (jnp.arange(seg.reps), sliced, _segment_cache(riding, seg)))
         for key, val in ys.items():
             fresh[key].append(val)
     rows = jnp.arange(pos.shape[0])

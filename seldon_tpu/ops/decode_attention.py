@@ -1,0 +1,281 @@
+"""Decode attention over the dense slab's LIVE rows only, where they lie.
+
+The slab holds every slot's window, K and V [La, B, 1, T, Hkv * Dh] one
+row a token (models/transformer.cache_spec), and a decode step asks for
+one query token of each slot that holds a request against the tokens
+that slot has reached: a few thousand rows of 64 x 1024. The einsums of
+models/transformer.gqa_attention_decode contract the queries against the
+whole layer and mask the rest away, which is as fast as reading a layer
+can be and reads ~70 times what the live rows own (PERF.md section 5).
+
+The kernel here leaves the slab in HBM and is told by scalar prefetch
+which layer, and a work list (`schedule`, made once a step from `active`
+and `pos`): one item a (live slot, block of `block` tokens below its
+position), live slots in slot order, each slot's blocks in token order.
+ONE grid step walks the list with a traced trip count: it copies an
+item's K and V block (and, for an int8 slab, the block's per-(token,
+head) scales [Hkv, block]) into one of two VMEM buffers while the
+previous item is computed, so a step costs what its live tokens cost
+and neither dead slots nor the empty tail of a window are ever
+addressed. No slice of the slab is an operand (ops/ssm_update.py's
+discipline): the compiled chunk holds the slab once, as the custom
+call's operand.
+
+Arithmetic is gqa_attention_decode's with the softmax summed block by
+block: scores bf16 x bf16 -> f32 times 1/sqrt(Dh), the int8 scales
+applied to the f32 scores and weights (the read stays one byte an
+element), the strict mask t < pos, f32 running (max, sum, weighted
+values). A token's row holds its heads side by side, so the queries go
+block-diagonal over the row (transformer._beside's trick, built in VMEM
+from `own`, the 0/1 matrix of which lanes are a query's own head) and
+each keeps its own head's lanes of the weighted values; whole rows are
+the matrix unit's operands and no head is ever sliced out of a row, so
+heads of 64 at 64-lane offsets cost nothing extra. When a slot's last
+block is done the fresh token's exact bf16 column is folded in as one
+more block (a max/exp combine, as gqa_attention_decode does it) and the
+normalised row is written out. Slots the list does not name (dead, or
+live at position 0) are never written by the kernel: `attend` gives them
+the fresh token's value alone, which is what attention over no past is,
+so they are finite and nothing of a dead row reaches a live one.
+
+Like the other kernels of seldon_tpu/ops it never chooses interpret mode
+itself: `applies` is False off a TPU, the caller then keeps
+gqa_attention_decode, and tests run `attend` through
+tests/pallas_interpret.py.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Dict, NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG_INF = -1e30
+LANES = 128
+# Bytes of K an item covers (and as many of V): 256 tokens of a bf16 row
+# of 1024 lanes, 512 of an int8 one. A narrower row takes as many more
+# tokens, so that an item moves the same bytes whatever the row: what an
+# item costs beside its bytes (its copies started and awaited, the loop,
+# ~0.3 us) is then the same small share of it
+# (tools/probe_decode_attention.py: at 256 tokens of 256 bf16 lanes a
+# full slab took 1.5 x the einsums' time, at 1024 tokens 0.9 x).
+ITEM_BYTES = 512 * 1024
+
+
+def block_size(k_shape, head_dim: int, itemsize: int = 2) -> int:
+    """Tokens a work item covers for a slab K of this shape and element
+    size (1: an int8 slab), or 0 where the kernel cannot read it: it
+    reads a row a token whose heads tile the 128 lanes (heads of 128, or
+    of 64 two a tile: mistral's, nemotron's, lfm2's) in a window that
+    whole blocks of whole tiles cover."""
+    if len(k_shape) != 5 or k_shape[2] != 1:
+        return 0
+    T, C = k_shape[3], k_shape[4]
+    if head_dim <= 0 or LANES % head_dim or C % LANES or C % head_dim:
+        return 0
+    most = min(ITEM_BYTES // (C * itemsize), T) // LANES * LANES
+    return next((b for b in range(most, 0, -LANES) if T % b == 0), 0)
+
+
+def reads(k: jnp.ndarray, head_dim: int) -> int:
+    """`block_size` of the slab's K array (or its ShapeDtypeStruct)."""
+    return block_size(k.shape, head_dim, k.dtype.itemsize)
+
+
+def applies(k: jnp.ndarray, head_dim: int) -> int:
+    """Tokens a work item covers (`block_size`) where the decode step
+    takes the kernel: on a TPU, for a slab K it can read; else 0."""
+    return reads(k, head_dim) if jax.default_backend() == "tpu" else 0
+
+
+class Schedule(NamedTuple):
+    """A decode step's work list (the same for every layer of it)."""
+    n_items: jnp.ndarray  # [1] int32
+    slot: jnp.ndarray  # [B * T / block] int32: item -> slot
+    blk: jnp.ndarray  # [B * T / block] int32: item -> block of that slot
+    pos: jnp.ndarray  # [B] int32
+    has_past: jnp.ndarray  # [B] bool: live and past position 0
+    block: int
+
+
+def schedule(active: jnp.ndarray, pos: jnp.ndarray, window: int,
+             block: int) -> Schedule:
+    has = active & (pos > 0)
+    per_slot = jnp.where(has, (pos + block - 1) // block, 0).astype(jnp.int32)
+    ends = jnp.cumsum(per_slot)
+    items = jnp.arange(pos.shape[0] * (window // block), dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, items, side="right"),
+                       pos.shape[0] - 1).astype(jnp.int32)
+    return Schedule(ends[-1:], slot, items - (ends - per_slot)[slot],
+                    pos.astype(jnp.int32), has, block)
+
+
+def tokens_read(sched: Schedule) -> jnp.ndarray:
+    """KV tokens one attention layer fetches under `sched` (whole
+    blocks: what the copies move)."""
+    return sched.n_items[0] * sched.block
+
+
+def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref,
+            q_ref, kf_ref, vf_ref, own_ref, *rest,
+            quantized: bool, block: int, scale: float):
+    if quantized:  # K, V and their scales: four arrays in HBM, four buffers
+        spread_ref, *rest = rest
+    n_hbm = 4 if quantized else 2
+    hbm, out_ref, bufs = rest[:n_hbm], rest[n_hbm], rest[n_hbm + 1:2 * n_hbm + 1]
+    sem, qbd, m_scr, l_scr, acc_scr = rest[2 * n_hbm + 1:]
+    kbuf, vbuf = bufs[:2]
+    layer, n = layer_ref[0], n_ref[0]
+    H, C = qbd.shape
+    tiles = C // LANES
+
+    def copies(w, buf):
+        b = slot_ref[w]
+        rows = pl.ds(pl.multiple_of(blk_ref[w] * block, block), block)
+        # a token's row of K or V; its scales, [Hkv, T], a column a token
+        at = [(layer, b, 0, rows), (layer, b, 0, rows),
+              (layer, b, slice(None), rows), (layer, b, slice(None), rows)]
+        return [pltpu.make_async_copy(src.at[at[i]], dst.at[buf], sem.at[i, buf])
+                for i, (src, dst) in enumerate(zip(hbm, bufs))]
+
+    @pl.when(n > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def item(w, carry):
+        buf = w % 2
+        b, j, p = slot_ref[w], blk_ref[w], pos_ref[slot_ref[w]]
+
+        @pl.when(w + 1 < n)
+        def _next():
+            for c in copies(w + 1, 1 - buf):
+                c.start()
+
+        @pl.when(j == 0)
+        def _slot_begins():
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+            q = q_ref[b]  # [H, 128]: the head's lanes, tiled to a tile
+            for t in range(tiles):
+                lanes = slice(t * LANES, (t + 1) * LANES)
+                qbd[:, lanes] = (q * own_ref[:, lanes]).astype(qbd.dtype)
+
+        for c in copies(w, buf):
+            c.wait()
+        s = jax.lax.dot_general(
+            qbd[...], kbuf[buf], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, block]
+        if quantized:
+            s = s * jnp.dot(spread_ref[...], bufs[2][buf],
+                            preferred_element_type=jnp.float32)
+        cols = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        mask = cols < p
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pr = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        if quantized:
+            pr = pr * jnp.dot(spread_ref[...], bufs[3][buf],
+                              preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            pr.astype(qbd.dtype), vbuf[buf],
+            preferred_element_type=jnp.float32)  # [H, C]
+
+        @pl.when((j + 1) * block >= p)
+        def _slot_ends():
+            # the fresh token's exact column, folded in as one more block
+            f32 = jnp.float32
+            s_f = jnp.sum(qbd[...].astype(f32) * kf_ref[b].astype(f32),
+                          axis=-1, keepdims=True) * scale  # [H, 1]
+            m_t = jnp.maximum(m_scr[...], s_f)
+            alpha = jnp.exp(m_scr[...] - m_t)
+            p_f = jnp.exp(s_f - m_t)
+            inv = 1.0 / (l_scr[...] * alpha + p_f)  # the sum is >= p_f or >= 1
+            # each query keeps its own head's lanes; the others' are
+            # zeroed, so the row's tiles add up to one tile
+            own = jnp.zeros((H, LANES), f32)
+            for t in range(tiles):
+                lanes = slice(t * LANES, (t + 1) * LANES)
+                own = own + own_ref[:, lanes] * (
+                    acc_scr[:, lanes] * alpha
+                    + p_f * vf_ref[b][:, lanes].astype(f32))
+            out_ref[b] = (own * inv).astype(out_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, n, item, 0)
+
+
+def attend(
+    q: jnp.ndarray,  # [B, 1, H, Dh]
+    k_fresh: jnp.ndarray,  # [B, 1, Hkv, Dh] (exact, this token)
+    v_fresh: jnp.ndarray,  # [B, 1, Hkv, Dh]
+    cache: Dict[str, jnp.ndarray],  # the WHOLE slab, PRE-write
+    layer: jnp.ndarray,  # int32 scalar: the attention layer, of La
+    sched: Schedule,
+) -> jnp.ndarray:
+    """gqa_attention_decode over layer `layer` of the slab for the rows
+    `sched` was made of: [B, 1, H * Dh] in q's dtype.
+
+    cache {"k", "v"[, "k_scale", "v_scale"]}: [La, B, 1, T, Hkv * Dh]
+    (scales [La, B, Hkv, T])."""
+    B, _, H, Dh = q.shape
+    C = cache["k"].shape[4]
+    Hkv, block = C // Dh, sched.block
+    G = H // Hkv
+    f32 = jnp.float32
+    slab = [cache[key] for key in ("k", "v", "k_scale", "v_scale")
+            if key in cache]
+    quantized = len(slab) == 4
+    own = (jnp.arange(C) // Dh)[None, :] == (jnp.arange(H) // G)[:, None]
+    args = [jnp.tile(q[:, 0], (1, 1, LANES // Dh)),
+            k_fresh.astype(q.dtype).reshape(B, 1, C),
+            v_fresh.astype(q.dtype).reshape(B, 1, C), own.astype(f32)]
+    whole = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
+    in_specs = [whole((B, H, LANES)), whole((B, 1, C)), whole((B, 1, C)),
+                whole((H, C))]
+    if quantized:
+        # [H, Hkv] 0/1: a query head's row of scales is its KV head's
+        spread = jnp.arange(H)[:, None] // G == jnp.arange(Hkv)[None, :]
+        args.append(spread.astype(cache["k_scale"].dtype))
+        in_specs.append(whole((H, Hkv)))
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(slab)
+    scratch = [pltpu.VMEM((2, block, C) if a.ndim == 5 else (2, Hkv, block),
+                          a.dtype) for a in slab]
+    scratch += [pltpu.SemaphoreType.DMA((len(slab), 2)),
+                pltpu.VMEM((H, C), q.dtype),
+                pltpu.VMEM((H, 1), f32), pltpu.VMEM((H, 1), f32),
+                pltpu.VMEM((H, C), f32)]
+    with jax.named_scope("attn/scores"):
+        out = pl.pallas_call(
+            functools.partial(_kernel, quantized=quantized, block=block,
+                              scale=Dh ** -0.5),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5, grid=(1,),
+                in_specs=in_specs,
+                out_specs=whole((B, H, LANES)),
+                scratch_shapes=scratch),
+            out_shape=jax.ShapeDtypeStruct((B, H, LANES), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=48 * 1024 * 1024),
+            name="decode_attention",
+        )(jnp.reshape(layer, (1,)).astype(jnp.int32), sched.n_items,
+          sched.slot, sched.blk, sched.pos, *args, *slab)
+    with jax.named_scope("attn/out"):
+        # a row's own head is the one segment of its tile that is not zero
+        out = out.reshape(B, H, LANES // Dh, Dh).sum(axis=2)
+        # rows the kernel never wrote hold whatever the buffer held: with
+        # no past, attention is the fresh token's value
+        alone = jnp.repeat(v_fresh[:, 0].astype(q.dtype), G, axis=1)
+        out = jnp.where(sched.has_past[:, None, None], out, alone)
+    return out.reshape(B, 1, H * Dh)

@@ -603,17 +603,21 @@ class _DepthEstimator:
 
 
 # EngineStats' counters that a decode chunk counts on the device, in the
-# order of its fifth value: the sampler's tiers (every model), then what
+# order of its fifth value: the sampler's tiers (every model), what the
+# attention layers read of the dense slab (every model's dense chunk;
+# the paged chunk and the waves stop at the sampler's), then what
 # routing did (a model that dispatches tokens to experts).
 SAMPLER_COUNTERS = ("sampler_steps", "sampler_drawn_steps",
                     "sampler_masked_steps")
+KV_COUNTERS = ("attn_kv_tokens_read", "attn_kv_tokens_held")
 MOE_COUNTERS = ("moe_sparse_layer_steps", "moe_experts_touched",
                 "moe_assignments")
 # ... and, after them, of a stack that holds a share of its experts or
 # has Mamba-2 layers (transformer.routing_width): the assignments that
 # went to experts held here, and the Mamba-2 layers run.
 SHARE_COUNTERS = ("moe_assignments_held", "ssm_layer_steps")
-CHUNK_COUNTERS = SAMPLER_COUNTERS + MOE_COUNTERS + SHARE_COUNTERS
+CHUNK_COUNTERS = (SAMPLER_COUNTERS + KV_COUNTERS + MOE_COUNTERS
+                  + SHARE_COUNTERS)
 
 
 class EngineStats:
@@ -645,6 +649,15 @@ class EngineStats:
         self.sampler_steps = 0  # graftlint: guarded-by(lock) via(stats)
         self.sampler_drawn_steps = 0  # graftlint: guarded-by(lock) via(stats)
         self.sampler_masked_steps = 0  # graftlint: guarded-by(lock) via(stats)
+        # What decode attention read of the dense slab
+        # (transformer.decode_kv_counts, summed over decode steps): KV
+        # tokens the attention layers fetched, and KV tokens the slab
+        # holds for them (slots x window x attention layers). read /
+        # held is the share of the slab a step touches: 1 where the
+        # einsums score every slot's whole window, the live rows' share
+        # where ops/decode_attention reads them alone.
+        self.attn_kv_tokens_read = 0  # graftlint: guarded-by(lock) via(stats)
+        self.attn_kv_tokens_held = 0  # graftlint: guarded-by(lock) via(stats)
         # What routing did in decode (models that dispatch tokens to
         # experts; _note_chunk_counts): sparse layers run over all decode
         # steps, distinct experts those layers read for live rows
@@ -1774,23 +1787,28 @@ class InferenceEngine:
         (slot_rules.decode_chunk). Returns (state, toks [K,B], valid [K,B],
         active [B], counts): counts int32 over the chunk, in
         CHUNK_COUNTERS' order: steps, steps that drew, steps that masked;
-        a routed model adds sparse-layer steps, distinct experts read
+        KV tokens the attention layers read and KV tokens the slab holds
+        for them; a routed model adds sparse-layer steps, distinct experts read
         (summed over those), assignments; one that holds a share of its
         experts or has Mamba-2 layers adds the assignments held here
         and the Mamba-2 layers run (transformer.routing_width)."""
         Smax = state["cache"]["k"].shape[3]
-        # A model that dispatches tokens to experts is told which rows
-        # hold a request (the others route to no expert), and what
-        # routing did rides out with the tokens.
+        # The model is told which rows hold a request: attention reads
+        # no other row's KV where it can tell them apart, and a model
+        # that dispatches tokens to experts routes the others nowhere;
+        # what routing did rides out with the tokens.
         routed = InferenceEngine._counts_routing(cfg)
+        # over a mesh of several devices the compiler partitions the
+        # program (or tp does, exactly): no kernel reads the slab there
+        spread = tp is not None or (mesh is not None and mesh.size > 1)
 
         def step_model(carry):
-            return transformer.decode_step(
-                params, carry["last_tok"], carry["pos"], carry["cache"],
-                cfg, tp=tp,
-                **(dict(live=carry["active"], return_routing=True)
-                   if routed else {}),
-            )
+            live, pos, cache = carry["active"], carry["pos"], carry["cache"]
+            logits, cache_, *routing = transformer.decode_step(
+                params, carry["last_tok"], pos, cache, cfg, tp=tp, live=live,
+                return_routing=routed, spread=spread)
+            kv = transformer.decode_kv_counts(cfg, cache, live, pos, spread)
+            return logits, cache_, jnp.concatenate([kv, *routing])
 
         state, toks, valid, counts = slot_rules.decode_chunk(
             step_model, state, n_steps, Smax, cfg)
@@ -4476,12 +4494,14 @@ class InferenceEngine:
         phases = self._timings(req)
         phases["decode_ms"] = None if tok is None else 1000.0 * (now - tok)
         # The engine's running device-side counters as this request
-        # ended (EngineStats.sampler_*, and moe_* for a model that
-        # dispatches tokens to experts): two lines' difference is what
-        # the sampler and routing did in decode between them.
-        names = (CHUNK_COUNTERS[:len(SAMPLER_COUNTERS)
-                                + transformer.routing_width(self.cfg)]
-                 if self._counts_routing(self.cfg) else SAMPLER_COUNTERS)
+        # ended (EngineStats.sampler_* and attn_kv_*, and moe_* for a
+        # model that dispatches tokens to experts): two lines'
+        # difference is what the sampler, attention and routing did in
+        # decode between them.
+        names = SAMPLER_COUNTERS + KV_COUNTERS
+        if self._counts_routing(self.cfg):
+            names = CHUNK_COUNTERS[:len(names)
+                                   + transformer.routing_width(self.cfg)]
         with self.stats.lock:
             phases.update({name: getattr(self.stats, name)
                            for name in names})

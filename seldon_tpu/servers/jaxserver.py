@@ -37,6 +37,7 @@ from seldon_tpu.runtime import REST_WORKERS
 from seldon_tpu.runtime.user_model import SeldonComponent
 from seldon_tpu.servers.engine import (
     KIND_HTTP_STATUS,
+    KV_COUNTERS,
     MOE_COUNTERS,
     SHARE_COUNTERS,
     EngineConfig,
@@ -1040,9 +1041,11 @@ class JAXServer(SeldonComponent):
              "value": float(s["decode_dispatches"])},
             {"type": "GAUGE", "key": "jaxserver_decode_steps",
              "value": float(s["decode_steps"])},
-            # What routing did in decode (0 unless the model dispatches
-            # tokens to experts): touched / sparse_layer_steps = experts
-            # a sparse layer reads per decode step.
+            # What decode attention read of the dense slab (read / held =
+            # the share of it a step touches) and what routing did in
+            # decode (0 unless the model dispatches tokens to experts):
+            # touched / sparse_layer_steps = experts a sparse layer
+            # reads per decode step.
             # Decode steps by the tier the sampler took (exclusive:
             # they add up to the steps whose tier a chunk reported).
             *({"type": "GAUGE", "key": "jaxserver_sampler_steps_total",
@@ -1054,7 +1057,7 @@ class JAXServer(SeldonComponent):
                   ("masked", s["sampler_masked_steps"]))),
             *({"type": "GAUGE", "key": "jaxserver_" + name,
                "value": float(s[name])}
-              for name in MOE_COUNTERS + SHARE_COUNTERS),
+              for name in KV_COUNTERS + MOE_COUNTERS + SHARE_COUNTERS),
             {"type": "GAUGE", "key": "jaxserver_prefix_hits",
              "value": float(s["prefix_hits"])},
             {"type": "GAUGE", "key": "jaxserver_prefix_tokens_saved",

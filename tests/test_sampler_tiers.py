@@ -295,3 +295,29 @@ def test_the_benchmarks_body_over_rest_never_masks(rest_unit):
     assert before["sampler_steps"] < row["sampler_steps"] <= snap["sampler_steps"]
     assert row["sampler_drawn_steps"] == snap["sampler_drawn_steps"]
     assert row["sampler_masked_steps"] == snap["sampler_masked_steps"]
+
+
+def test_the_attention_counters_ride_beside_the_samplers(rest_unit):
+    """What decode attention read of the slab (transformer.decode_kv_counts):
+    off a TPU the einsums score every slot's whole window, so every step
+    reads all the slab holds, slots x window x layers; the two counters
+    are on /metrics and on the access line like the sampler's."""
+    srv, url, records = rest_unit
+    before = srv.engine.stats.snapshot()
+    _post(url + "/generate", {"prompt_token_ids": [9, 8, 7],
+                              "max_new_tokens": 6, "temperature": 0.0})
+    snap = srv.engine.stats.snapshot()
+    steps = snap["sampler_steps"] - before["sampler_steps"]
+    cfg = srv.engine.cfg
+    assert steps > 0
+    assert snap["attn_kv_tokens_held"] - before["attn_kv_tokens_held"] \
+        == steps * cfg.n_layers * 4 * 64
+    assert snap["attn_kv_tokens_read"] == snap["attn_kv_tokens_held"]
+    text = _get(url + "/metrics")
+    for name in ("attn_kv_tokens_read", "attn_kv_tokens_held"):
+        assert float(re.search(
+            r"^jaxserver_%s(?:\{[^}]*\})? (\S+)$" % name, text,
+            re.MULTILINE).group(1)) == snap[name]
+    row = json.loads(records[-1].getMessage()[len("request "):])
+    assert 0 < row["attn_kv_tokens_read"] == row["attn_kv_tokens_held"] \
+        <= snap["attn_kv_tokens_held"]

@@ -77,7 +77,10 @@ def _engine(cfg=None, start=True, **ekw):
     params = init_params(cfg, jax.random.key(0))
     ekw.setdefault("max_slots", 4)
     ekw.setdefault("max_seq_len", 64)
-    ekw.setdefault("prompt_buckets", (8, 32))
+    # 64 holds the prompt folded with all its tokens: how many were
+    # delivered when a fault lands is the host's timing, and a fold past
+    # the largest bucket cannot be resurrected at all.
+    ekw.setdefault("prompt_buckets", (8, 32, 64))
     eng = InferenceEngine(params, cfg, EngineConfig(**ekw))
     if start:
         eng.start()

@@ -68,6 +68,26 @@ def relayouts(hlo: str, at_least: int):
             if op in ("copy", "transpose")]
 
 
+def _compiled_chunk(cfg, one_chip, slots=SLOTS, window=WINDOW):
+    """The optimized HLO of the 4-step decode chunk of `cfg` over slots x
+    window, compiled for the described v5e, and the state's shapes."""
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    state = shapes(jax.eval_shape(
+        lambda: slot.fresh(transformer.init_cache(cfg, slots, window),
+                           slots)))
+    chunk = jax.jit(
+        functools.partial(InferenceEngine._chunk_impl, cfg=cfg,
+                          n_steps=STEPS),
+        donate_argnums=(1,))
+    return chunk.lower(params, state).compile().as_text(), state
+
+
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_decode_chunk_copies_no_layer_of_the_slab(one_chip, kv_dtype):
     """The compiled 4-step chunk reads and writes the slab as stored:
@@ -77,27 +97,73 @@ def test_decode_chunk_copies_no_layer_of_the_slab(one_chip, kv_dtype):
     cfg = dense_config(kv_dtype)
     assert cfg.head_dim == 128
 
-    def shapes(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    params = shapes(jax.eval_shape(
-        lambda: init_params(cfg, jax.random.key(0))))
-    state = shapes(jax.eval_shape(
-        lambda: slot.fresh(transformer.init_cache(cfg, SLOTS, WINDOW),
-                           SLOTS)))
-    chunk = jax.jit(
-        functools.partial(InferenceEngine._chunk_impl, cfg=cfg,
-                          n_steps=STEPS),
-        donate_argnums=(1,))
-    hlo = chunk.lower(params, state).compile().as_text()
+    hlo, state = _compiled_chunk(cfg, one_chip)
     layer_k = SLOTS * WINDOW * cfg.n_kv_heads * cfg.head_dim
     assert state["cache"]["k"].shape == (
         cfg.n_layers, SLOTS, 1, WINDOW, cfg.n_kv_heads * cfg.head_dim)
     assert relayouts(hlo, layer_k) == []
     # the reader finds what it looks for: the weights' copies are there
     assert relayouts(hlo, 1)
+
+
+# The cells' 64 slots and twice their window: K and V of a stack below
+# are then 134 MB and more each, as a deployment's are too large for the
+# chip's 128 MiB of fast memory (a slab that fits, the compiler moves
+# there whole before the kernel's call: a copy as large as the slab that
+# no deployment runs).
+KERNEL_SLOTS, KERNEL_WINDOW = 64, 2048
+
+
+@pytest.mark.parametrize("stack", ["bf16", "int8", "patterned"])
+def test_decode_chunk_reads_the_slab_through_the_kernel(
+        one_chip, monkeypatch, stack):
+    """On a TPU the decode step's attention is ops/decode_attention: the
+    compiled chunk of the dense bf16 and int8 stacks and of a patterned
+    one holds its custom call (one a layer position of a scan body),
+    handed the slab whole, and nothing else as large as a layer of the
+    slab: no copy, transpose or slice of it, and no float32 score array
+    [slots, heads, window] (what the einsums of gqa_attention_decode
+    materialise a layer)."""
+    from seldon_tpu.ops import decode_attention, moe_dispatch, ssm_update
+
+    # the chip's branches (the program asks jax.default_backend())
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul", moe_dispatch._megablox)
+    monkeypatch.setattr(ssm_update, "update", ssm_update._pallas)
+    monkeypatch.setattr(decode_attention, "applies", decode_attention.reads)
+    if stack == "patterned":
+        # heads of 128, two to a row: a layer of K is 2 Mi elements, as
+        # large as a layer of the SSM state and larger than any weight
+        cfg = dataclasses.replace(
+            mamba_config(), n_heads=4, n_kv_heads=2, head_dim=128).validate()
+    else:
+        cfg = dense_config(stack)
+    SLOTS, WINDOW = KERNEL_SLOTS, KERNEL_WINDOW
+    hlo, _ = _compiled_chunk(cfg, one_chip, SLOTS, WINDOW)
+    row = cfg.n_kv_heads * cfg.head_dim
+    layer_k = SLOTS * WINDOW * row
+    calls = re.findall(r"%(decode_attention[.\w]*) = bf16\[", hlo)
+    assert len(calls) == 1, calls
+    slab = "%s[%d,%d,1,%d,%d]" % (
+        "s8" if stack == "int8" else "bf16",
+        cfg.n_attn_layers, SLOTS, WINDOW, row)
+    assert slab in hlo  # carried whole, in the loops' tuples
+    big = big_instructions(hlo, layer_k)
+    # what is left at that size is the step's scatter of the fresh rows
+    # into the whole slab, in place (a fusion whose result IS the slab)
+    assert {op for _, op, _, _ in big} <= {"fusion"}, big
+    assert {_elements(typ) for _, _, typ, _ in big} <= {
+        cfg.n_attn_layers * layer_k}, big
+    scores = SLOTS * cfg.n_heads * WINDOW
+    assert [typ for _, _, typ, _ in big_instructions(hlo, scores)
+            if typ.startswith("f32[")
+            and typ.split("]")[0].endswith(",%d" % WINDOW)] == []
+
+
+def _elements(typ: str) -> int:
+    n = 1
+    for d in typ[typ.index("[") + 1:typ.index("]")].split(","):
+        n *= int(d)
+    return n
 
 
 def mamba_config():
@@ -129,21 +195,7 @@ def test_decode_chunk_updates_the_ssm_state_in_place(one_chip, monkeypatch):
     monkeypatch.setattr(ssm_update, "update", ssm_update._pallas)
     cfg = mamba_config()
 
-    def shapes(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    params = shapes(jax.eval_shape(
-        lambda: init_params(cfg, jax.random.key(0))))
-    state = shapes(jax.eval_shape(
-        lambda: slot.fresh(transformer.init_cache(cfg, SLOTS, WINDOW),
-                           SLOTS)))
-    chunk = jax.jit(
-        functools.partial(InferenceEngine._chunk_impl, cfg=cfg,
-                          n_steps=STEPS),
-        donate_argnums=(1,))
-    hlo = chunk.lower(params, state).compile().as_text()
+    hlo, state = _compiled_chunk(cfg, one_chip)
     ssm = state["cache"]["ssm"]
     assert ssm.shape == (6, SLOTS, 8, 64, 128) and ssm.dtype == "float32"
     layer = SLOTS * 8 * 64 * 128

@@ -74,15 +74,16 @@ def main(argv=None) -> int:
 
     from seldon_tpu.models import slot, transformer
     from seldon_tpu.models.config import ModelConfig
-    from seldon_tpu.ops import moe_dispatch, ssm_update
+    from seldon_tpu.ops import decode_attention, moe_dispatch, ssm_update
     from seldon_tpu.models.quantize import init_params_int8
     from seldon_tpu.servers import engine
     from seldon_tpu.servers.engine import InferenceEngine
 
-    # the chip's grouped product and state update: the program asks
-    # jax.default_backend(), which is the CPU here
+    # the chip's grouped product, state update and decode attention: the
+    # program asks jax.default_backend(), which is the CPU here
     moe_dispatch.grouped_matmul = moe_dispatch._megablox
     ssm_update.update = ssm_update._pallas
+    decode_attention.applies = decode_attention.reads
     here = os.path.join(ROOT, "benchmark")
     with open(os.path.join(here, "configs", args.config + ".json")) as f:
         raw = json.load(f)
