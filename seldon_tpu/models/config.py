@@ -6,10 +6,11 @@ the homogeneous stack (attention + SwiGLU or all-expert MoE in every
 layer: the llama / mistral / mixtral presets) and the PATTERNED stack
 (`layer_types` set: a per-layer operator kind, short-conv or attention,
 leading dense feed-forward layers before the sparse ones, an expert
-width of its own, a sigmoid router, QK-norm; or layers that are ONE
-residual block each: a Mamba-2 mixer, an attention or a sparse
-feed-forward alone, with a shared expert and a share of the routed
-experts held here). Presets: `tiny*` (CPU
+width of its own, a sigmoid router, QK-norm; attention and a Mamba-2
+mixer side by side in one layer, with fixed scalar multipliers on the
+projections; or layers that are ONE residual block each: a Mamba-2
+mixer, an attention or a sparse feed-forward alone, with a shared expert
+and a share of the routed experts held here). Presets: `tiny*` (CPU
 tests), `bench-1b` (one v5e chip in bf16), `llama3-8b` / `llama3-70b`
 (geometry only; the benchmark's configurations are registered from
 benchmark/configs/ by its launcher).
@@ -23,13 +24,19 @@ from typing import Optional, Tuple
 # Operator kinds of a patterned stack (the published `layer_types` names).
 OP_CONV = "conv"
 OP_ATTN = "full_attention"
+# Attention and a Mamba-2 mixer reading the same normed input in parallel,
+# their outputs summed into the residual, then the dense feed-forward: the
+# one kind whose layer holds KV AND an SSM state.
+OP_ATTN_MAMBA = "attention_mamba"
 # Layers that are one residual block alone (no feed-forward of their
 # own): a Mamba-2 mixer, an attention, a sparse feed-forward.
 OP_MAMBA = "mamba"
 OP_ATTN_ONLY = "attention"
 OP_MOE = "moe"
-FUSED_OPS = (OP_CONV, OP_ATTN)  # operator + feed-forward in one layer
+FUSED_OPS = (OP_CONV, OP_ATTN, OP_ATTN_MAMBA)  # operator + feed-forward in one layer
 SINGLE_OPS = (OP_MAMBA, OP_ATTN_ONLY, OP_MOE)
+KV_OPS = (OP_ATTN, OP_ATTN_ONLY, OP_ATTN_MAMBA)  # the operators that hold KV
+SSM_OPS = (OP_MAMBA, OP_ATTN_MAMBA)  # and those that hold an SSM state
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,7 +94,9 @@ class ModelConfig:
     rope_scaling_original_max_position: int = 8192
     # --- patterned stack (empty layer_types = homogeneous, as before) ---
     # Operator of each layer, "conv" (gated short convolution with a
-    # fixed-size state per slot) or "full_attention" (GQA with KV).
+    # fixed-size state per slot), "full_attention" (GQA with KV) or
+    # "attention_mamba" (GQA and a Mamba-2 mixer in parallel: KV and an
+    # SSM state in the same layer).
     # A list is accepted and stored as a tuple (hashable); asdict() and
     # a JSON round trip give the list back, which is what /metadata
     # serves and the benchmark's harness compares.
@@ -139,10 +148,27 @@ class ModelConfig:
     expert_first: int = 0
     # Added to the sum of the selected scores before renormalising.
     router_norm_eps: float = 1e-6
+    # --- fixed scalar multipliers (muP; 1 / empty = none is applied) ---
+    # On the embedding's rows and on the logits; on the attention's input
+    # and output and on its keys (before the rotary embedding); on the
+    # mixer's input and output; on the five segments of the mixer's input
+    # projection, (z, x, B, C, dt); on the dense SwiGLU's gate (inside
+    # the activation) and on its output.
+    embed_mult: float = 1.0
+    logits_mult: float = 1.0
+    attn_in_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    key_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_out_mult: float = 1.0
+    ssm_mults: Tuple[float, ...] = ()
+    mlp_gate_mult: float = 1.0
+    mlp_down_mult: float = 1.0
 
     def __post_init__(self):
-        if not isinstance(self.layer_types, tuple):
-            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        for name in ("layer_types", "ssm_mults"):  # a list: stored as a tuple
+            if not isinstance(getattr(self, name), tuple):
+                object.__setattr__(self, name, tuple(getattr(self, name)))
         if not self.head_dim:
             # dataclasses.replace carries the filled-in value: a replace
             # that changes d_model or n_heads passes head_dim=0 with them.
@@ -191,7 +217,7 @@ class ModelConfig:
         """Layers that hold KV."""
         if not self.layer_types:
             return self.n_layers
-        return self._count(OP_ATTN, OP_ATTN_ONLY)
+        return self._count(*KV_OPS)
 
     @property
     def n_conv_layers(self) -> int:
@@ -200,8 +226,9 @@ class ModelConfig:
 
     @property
     def n_mamba_layers(self) -> int:
-        """Layers that hold an SSM state (and the mixer's conv state)."""
-        return self._count(OP_MAMBA)
+        """Layers that hold an SSM state (and the mixer's conv state);
+        an "attention_mamba" layer counts here AND among n_attn_layers."""
+        return self._count(*SSM_OPS)
 
     @property
     def n_sparse_layers(self) -> int:
@@ -210,6 +237,14 @@ class ModelConfig:
         if self.single_blocks:
             return self._count(OP_MOE)
         return self.n_layers - min(self.n_dense_layers, self.n_layers)
+
+    @property
+    def multipliers(self) -> Tuple[float, ...]:
+        """Every scalar multiplier, in the fields' order."""
+        return (self.embed_mult, self.logits_mult, self.attn_in_mult,
+                self.attn_out_mult, self.key_mult, self.ssm_in_mult,
+                self.ssm_out_mult, *self.ssm_mults, self.mlp_gate_mult,
+                self.mlp_down_mult)
 
     @property
     def q_per_kv(self) -> int:
@@ -251,7 +286,8 @@ class ModelConfig:
                 assert not set(self.layer_types) & set(FUSED_OPS) \
                     and self.n_dense_layers == 0, (
                         "layers of one block each (mamba / attention / moe) "
-                        "do not mix with operator + feed-forward layers or "
+                        "do not mix with operator + feed-forward layers "
+                        "(conv / full_attention / attention_mamba) or "
                         "leading dense ones")
                 assert (OP_MOE in self.layer_types) == bool(self.n_experts), (
                     "moe layers need n_experts, and n_experts moe layers")
@@ -259,8 +295,18 @@ class ModelConfig:
                 assert self.ssm_heads > 0 and self.ssm_head_dim > 0 \
                     and self.ssm_state > 0 and self.ssm_chunk > 0 \
                     and self.ssm_heads % self.ssm_groups == 0, (
-                        "mamba layers need ssm_heads (a multiple of "
-                        "ssm_groups), ssm_head_dim, ssm_state and ssm_chunk")
+                        "mamba and attention_mamba layers need ssm_heads (a "
+                        "multiple of ssm_groups), ssm_head_dim, ssm_state "
+                        "and ssm_chunk")
+            assert OP_ATTN_MAMBA in self.layer_types or \
+                self.attn_in_mult == self.attn_out_mult == 1.0, (
+                    "attn_in_mult / attn_out_mult act in attention_mamba "
+                    "layers only")
+            assert len(self.ssm_mults) in (0, 5) and (
+                self.n_mamba_layers or not self.ssm_mults), (
+                    "ssm_mults is the five multipliers of a mixer's input "
+                    "projection (z, x, B, C, dt): mamba or attention_mamba "
+                    "layers only")
             assert self.kv_cache_dtype == "bf16" and \
                 self.weight_dtype == "bf16" and self.attn_impl == "xla", (
                     "a patterned stack (layer_types) is served in bf16 "
@@ -279,10 +325,11 @@ class ModelConfig:
             assert (self.head_dim * self.n_heads == self.d_model
                     and self.rotary and self.ff_act == "swiglu"
                     and not self.d_ff_shared and not self.n_experts_held
-                    and not self.expert_first and not self.ssm_heads), (
+                    and not self.expert_first and not self.ssm_heads
+                    and all(m == 1.0 for m in self.multipliers)), (
                 "head_dim / rotary / ff_act / d_ff_shared / n_experts_held "
-                "/ expert_first / ssm_* need layer_types (the patterned "
-                "stack)"
+                "/ expert_first / ssm_* / the *_mult multipliers need "
+                "layer_types (the patterned stack)"
             )
         assert self.ff_act in ("swiglu", "relu2"), (
             f"unknown ff_act {self.ff_act!r}")
@@ -381,6 +428,41 @@ PRESETS = {
         ssm_groups=2,
         ssm_state=16,
         ssm_chunk=8,
+    ),
+    # Attention and a Mamba-2 mixer side by side in every layer at
+    # CPU-test size: 5 query heads a KV head, heads whose total width is
+    # not d_model, 4 SSM heads in 2 groups with a state wider than a head,
+    # rotary embedding, a dense SwiGLU, untied head, every multiplier
+    # off 1 (the embedding's and the gate's no power of two).
+    "tiny-falcon-h1": ModelConfig(
+        vocab_size=256,
+        d_model=80,
+        n_layers=3,
+        n_heads=10,
+        n_kv_heads=2,
+        head_dim=16,
+        d_ff=160,
+        max_seq_len=128,
+        rope_theta=1e11,
+        eos_token_id=1,
+        layer_types=("attention_mamba",) * 3,
+        conv_kernel=4,
+        ssm_heads=4,
+        ssm_head_dim=16,
+        ssm_groups=2,
+        ssm_state=32,
+        ssm_chunk=8,
+        embed_mult=2.8284271247461903,
+        logits_mult=0.125,
+        attn_in_mult=0.75,
+        attn_out_mult=0.3,
+        key_mult=0.35,
+        ssm_in_mult=0.5,
+        ssm_out_mult=0.4,
+        ssm_mults=(0.7071067811865476, 0.5, 0.3535533905932738, 1.5,
+                   0.7071067811865476),
+        mlp_gate_mult=0.6,
+        mlp_down_mult=0.2,
     ),
     # ~1.1B params: single v5e chip (16 GB HBM) with room for KV cache.
     "bench-1b": ModelConfig(

@@ -1,8 +1,9 @@
 """Decoder models, functional JAX, TPU-first: the homogeneous Llama-
 family stack and (cfg.layer_types) the patterned stack at the end of
-this file, whose layers differ in operator (short conv or attention) and
-feed-forward (dense or token -> expert dispatch), or are one residual
-block each (a Mamba-2 mixer, an attention or a sparse feed-forward alone).
+this file, whose layers differ in operator (short conv, attention, or
+attention and a Mamba-2 mixer in parallel) and feed-forward (dense or
+token -> expert dispatch), or are one residual block each (a Mamba-2
+mixer, an attention or a sparse feed-forward alone).
 
 Design (vs the reference's black-box CPU model servers, SURVEY.md §2.5):
  * Params are a plain pytree with layers STACKED on a leading [L, ...] axis
@@ -24,15 +25,19 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from seldon_tpu.models.config import (
     FUSED_OPS,
+    KV_OPS,
     OP_ATTN,
+    OP_ATTN_MAMBA,
     OP_ATTN_ONLY,
     OP_CONV,
     OP_MAMBA,
     OP_MOE,
+    SSM_OPS,
     ModelConfig,
 )
 from seldon_tpu.models.quantize import dequant
@@ -123,6 +128,19 @@ def _embed_rows(params: Params, tokens: jnp.ndarray, dtype) -> jnp.ndarray:
     # rows: the int8 bits and per-column scale are load-time constants
     # identical in every compilation, so the product is too.
     return rows.astype(dtype) * scale.astype(dtype)[0]
+
+
+def _scaled(x: jnp.ndarray, mult) -> jnp.ndarray:
+    """x times one of ModelConfig's fixed multipliers (a Python float, or
+    a vector over the last axis), in x's dtype and where the published
+    model applies it; a multiplier of 1 adds no operation, so a stack
+    without multipliers lowers as it did."""
+    if isinstance(mult, (int, float)) and mult == 1:
+        return x
+    with jax.named_scope("mixer/scale"):
+        return x * jnp.asarray(mult, x.dtype)
+
+
 Cache = Dict[str, jnp.ndarray]
 
 
@@ -622,7 +640,8 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
         hq = _quantize_act(h) if _w8a8_applies(bp, "wq", cfg) else None
         q = _qdot(h, bp, "wq", cfg, act_q=hq).reshape(
             B, S, cfg.n_heads, Dh)
-        k = _qdot(h, bp, "wk", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
+        k = _scaled(_qdot(h, bp, "wk", cfg, act_q=hq),
+                    cfg.key_mult).reshape(B, S, Hkv, Dh)
         v = _qdot(h, bp, "wv", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
         if cfg.qk_norm:
             with jax.named_scope("attn/qk_norm"):
@@ -907,14 +926,14 @@ def _logits(params, x, cfg):
         # Tied embeddings: contract against embed's OWN layout ("vd") —
         # materializing embed.T would move the whole vocab matrix per
         # decode step (measured 2.3ms/step for a 131MB bf16 table on v5e).
-        return jnp.einsum(
+        return _scaled(jnp.einsum(
             "bsd,vd->bsv", x, _w(params, "embed", x.dtype),
             preferred_element_type=jnp.float32,
-        )
-    return jnp.einsum(
+        ), cfg.logits_mult)
+    return _scaled(jnp.einsum(
         "bsd,dv->bsv", x, _w(params, "lm_head", x.dtype),
         preferred_element_type=jnp.float32,
-    )
+    ), cfg.logits_mult)
 
 
 # ---------------------------------------------------------------------------
@@ -936,7 +955,7 @@ def forward(
     dense configs). `ring_mesh` activates ring attention over 'sp' when
     cfg.attn_impl == "ring" (long-context path)."""
     B, S = tokens.shape
-    x = _embed_rows(params, tokens, _dtype(cfg))
+    x = _scaled(_embed_rows(params, tokens, _dtype(cfg)), cfg.embed_mult)
     if act_spec is not None:
         x = jax.lax.with_sharding_constraint(x, act_spec)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
@@ -986,7 +1005,9 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
 
     KV is one row a token, [La, B, 1, T, Hkv * Dh], over the La layers
     that hold KV: every layer of a homogeneous stack, the attention
-    layers of a patterned one (kv_heads_per_row; five axes with T at 3,
+    layers of a patterned one, among them those that run attention and a
+    Mamba-2 mixer side by side and so hold an SSM state as well, each
+    counted once under either kind (kv_heads_per_row; five axes with T at 3,
     so that a head-major array indexes alike). The int8 scales are per
     (token, head), [La, B, Hkv, T]: T-minor, because a trailing axis of
     Hkv = 8 would be padded to the TPU's 128 lanes, and small enough
@@ -1012,7 +1033,8 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
     The SSM state is [Lm, B, ssm_heads, ssm_head_dim, ssm_state] over the
     Lm Mamba-2 layers, FLOAT32 whatever the compute dtype: the recurrence
     accumulates into it over the whole context. It is three orders larger
-    than a conv state (2 MB a slot and layer at 64 x 64 x 128) and every
+    than a conv state (2 MB a slot and layer at 64 x 64 x 128, 4 MB at
+    32 x 128 x 256) and every
     decode step reads and writes all of it, so the step updates it in
     place (_run_patterned_decode carries it whole through the layer
     scan). "ssm_conv" is [Lm, B, conv_kernel - 1, ssm_conv_dim]: the
@@ -1420,7 +1442,8 @@ def decode_step(
     dispatch); a stack that holds a share of its experts or has Mamba-2
     layers adds the assignments to experts held here and the Mamba-2
     layers run."""
-    x = _embed_rows(params, token, _dtype(cfg))[:, None, :]  # [B,1,D]
+    x = _scaled(_embed_rows(params, token, _dtype(cfg)),
+                cfg.embed_mult)[:, None, :]  # [B,1,D]
     positions = pos[:, None]
     inv_freq = rope_frequencies(cfg)
     routing = jnp.zeros((routing_width(cfg),), jnp.int32)
@@ -1442,8 +1465,9 @@ def decode_step(
 # The patterned stack (cfg.layer_types)
 # ---------------------------------------------------------------------------
 #
-# Layers differ in two ways: the operator (gated short convolution or
-# attention, cfg.layer_types) and the feed-forward (dense SwiGLU for the
+# Layers differ in two ways: the operator (gated short convolution,
+# attention, or attention and a Mamba-2 mixer that read the same normed
+# input and are summed, cfg.layer_types) and the feed-forward (dense SwiGLU for the
 # first cfg.n_dense_layers, then the sparse block by token -> expert
 # dispatch). The layer list is cut into SEGMENTS, each a period of layer
 # kinds repeated R times (layer_plan), and each segment is ONE lax.scan
@@ -1454,7 +1478,8 @@ def decode_step(
 # expert matrix is ever sliced or copied inside a program. The cache is
 # by kind (cache_spec): "k"/"v" over the attention layers in layer order,
 # "conv" over the conv layers in layer order, "ssm" / "ssm_conv" over the
-# Mamba-2 layers in layer order.
+# layers that hold a Mamba-2 mixer in layer order (an "attention_mamba"
+# layer has a row in "k"/"v" AND one in "ssm" / "ssm_conv").
 #
 # A stack of SINGLE-BLOCK layers (config.SINGLE_OPS) is the same plan with
 # other kinds: each layer is x + block(RMSNorm(x)) with the block a
@@ -1469,11 +1494,10 @@ class Segment(NamedTuple):
     first_layer: int
     attn_start: int  # index of its first attention layer among those
     conv_start: int  # and of its first conv layer
-    ssm_start: int = 0  # and of its first Mamba-2 layer
+    ssm_start: int = 0  # and of its first layer with a Mamba-2 mixer
 
 
 _MAX_PERIOD = 8
-_KV_OPS = (OP_ATTN, OP_ATTN_ONLY)  # the operators that hold KV
 _FIXED_STATE = ("conv", "ssm", "ssm_conv")  # cache arrays without a token axis
 
 
@@ -1506,15 +1530,18 @@ def layer_plan(cfg: ModelConfig) -> Tuple[Segment, ...]:
                 best_p, best_r = p, r
         period = tuple(kinds[i:i + best_p])
         plan.append(Segment(period, best_r, i, n_attn, n_conv, n_ssm))
-        n_attn += best_r * _count_ops(period, *_KV_OPS)
+        n_attn += best_r * _count_ops(period, *KV_OPS)
         n_conv += best_r * _count_ops(period, OP_CONV)
-        n_ssm += best_r * _count_ops(period, OP_MAMBA)
+        n_ssm += best_r * _count_ops(period, *SSM_OPS)
         i += best_p * best_r
     return tuple(plan)
 
 
 def fixed_state_names(cfg: ModelConfig) -> str:
     """The fixed-size per-slot state this stack holds, in words."""
+    if OP_ATTN_MAMBA in cfg.layer_types:
+        return ("the attention_mamba layers' SSM and conv state (a Mamba-2 "
+                "mixer beside the attention in the same layer)")
     if cfg.n_mamba_layers:
         return "the Mamba-2 layers' SSM and conv state"
     return "the conv layers' state"
@@ -1545,7 +1572,13 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
     (2 L) ** -0.5. Layer norms are ones; the router's bias is drawn
     non-zero (a zero bias would leave 'select with, weight without'
     unexercised) but small beside the scores' spread, so that routing
-    stays near uniform as a trained, load-balanced router's is."""
+    stays near uniform as a trained, load-balanced router's is. A matrix
+    whose input or output one of the config's multipliers scales is drawn
+    at that scale DIVIDED by the multiplier (the published multipliers
+    are small because trained weights are large: at the usual draws both
+    mixers and the feed-forward would vanish beside the embedding), so
+    every block carries the share of the residual stream it carries in a
+    stack without multipliers."""
     dt = _dtype(cfg)
     L, D, F, V = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -1589,6 +1622,12 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
         count[0] += 3
         ka, kd, kb = (jax.random.fold_in(key, count[0] - i) for i in range(3))
         Hs, Di, Cd = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+        GN = cfg.ssm_groups * cfg.ssm_state
+        into = D ** -0.5 / cfg.ssm_in_mult
+        # [z | x | B | C], each segment against its own multiplier
+        cols = [dense(R, D, w, scale=into / m) for w, m in
+                zip((Di, Di, GN, GN), cfg.ssm_mults)]
+        dt_mult = cfg.ssm_mults[4] if cfg.ssm_mults else 1.0
         step = jnp.maximum(jnp.exp(
             jax.random.uniform(kd, (R, Hs), jnp.float32)
             * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001)), 1e-4)
@@ -1597,7 +1636,9 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
             # as one its width, 2 Di + 2 G N + Hs, is no multiple of the
             # TPU's 128 lanes, the device stores it D-minor and the
             # compiled chunk relays it out on every entry
-            ssm_in=dense(R, D, Di + Cd), ssm_dt_in=dense(R, Hs, D, scale=D ** -0.5),
+            ssm_in=jnp.concatenate(cols, axis=-1) if cols
+            else dense(R, D, Di + Cd, scale=into),
+            ssm_dt_in=dense(R, Hs, D, scale=into / dt_mult),
             ssm_conv_w=dense(R, Kc, Cd, scale=Kc ** -0.5),
             ssm_conv_b=(jax.random.normal(kb, (R, Cd), jnp.float32) * 0.1
                         ).astype(dt),
@@ -1605,13 +1646,17 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
             ssm_A_log=jnp.log(jax.random.uniform(
                 ka, (R, Hs), jnp.float32, 1.0, 16.0)),
             ssm_D=ones(R, Hs), ssm_norm=ones(R, Di),
-            ssm_out=dense(R, Di, D, scale=damp * Di ** -0.5))
+            ssm_out=dense(R, Di, D,
+                          scale=damp * Di ** -0.5 / cfg.ssm_out_mult))
 
     def attn_block(R):
+        into = D ** -0.5 / cfg.attn_in_mult
         lp = dict(
-            wq=dense(R, D, H * Dh), wk=dense(R, D, Hkv * Dh),
-            wv=dense(R, D, Hkv * Dh),
-            wo=dense(R, H * Dh, D, scale=damp * (H * Dh) ** -0.5))
+            wq=dense(R, D, H * Dh, scale=into),
+            wk=dense(R, D, Hkv * Dh, scale=into / cfg.key_mult),
+            wv=dense(R, D, Hkv * Dh, scale=into),
+            wo=dense(R, H * Dh, D,
+                     scale=damp * (H * Dh) ** -0.5 / cfg.attn_out_mult))
         if cfg.qk_norm:
             lp.update(q_norm=ones(R, Dh), k_norm=ones(R, Dh))
         return lp
@@ -1626,7 +1671,10 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
                 period.append({"op_norm": ones(R, D), **block})
                 continue
             lp = {"op_norm": ones(R, D), "ff_norm": ones(R, D)}
-            if op == OP_ATTN:
+            if op == OP_ATTN_MAMBA:  # both mixers' weights in one layer
+                lp.update(attn_block(R))
+                lp.update(mamba_block(R))
+            elif op == OP_ATTN:
                 lp.update(
                     wq=dense(R, D, H * Dh), wk=dense(R, D, Hkv * Dh),
                     wv=dense(R, D, Hkv * Dh),
@@ -1648,17 +1696,19 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
                                               dtype=jnp.float32)
             else:
                 lp.update(
-                    w_gate=dense(R, D, F), w_up=dense(R, D, F),
-                    w_down=dense(R, F, D, scale=damp * F ** -0.5))
+                    w_gate=dense(R, D, F, scale=D ** -0.5 / cfg.mlp_gate_mult),
+                    w_up=dense(R, D, F),
+                    w_down=dense(R, F, D,
+                                 scale=damp * F ** -0.5 / cfg.mlp_down_mult))
             period.append(lp)
         segments.append(tuple(period))
     params: Params = {
-        "embed": dense(V, D, scale=D ** -0.5),
+        "embed": dense(V, D, scale=D ** -0.5 / cfg.embed_mult),
         "segments": tuple(segments),
         "final_norm": ones(D),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = dense(D, V)
+        params["lm_head"] = dense(D, V, scale=D ** -0.5 / cfg.logits_mult)
     return params
 
 
@@ -1791,16 +1841,25 @@ def _mamba_op(h, lp, cfg, state=None, conv_state=None, live=None,
     state is taken after each row's own last real token, so that a padded
     row is left the state of its prompt. Returns (y [B, S, D], SSM state
     (decode: every layer's, this one stepped; else this layer's after the
-    last live position), conv state)."""
+    last live position), conv state). The config's multipliers act where
+    the published mixer applies them: on h, on the input projection's
+    segments (z, x, B, C, dt) and on the output."""
     B, S, _ = h.shape
     Hs, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     Di, Cd, Kc = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.conv_kernel
     f32 = jnp.float32
+    h = _scaled(h, cfg.ssm_in_mult)
     with jax.named_scope("ssm/in_proj"):
         zxbc = _qdot(h, lp, "ssm_in", cfg)
+        if cfg.ssm_mults:  # one multiplier a segment: z, x, B, C
+            zxbc = _scaled(zxbc, np.repeat(
+                np.asarray(cfg.ssm_mults[:4], np.float32),
+                [Di, Di, G * N, G * N]))
         z, xbc = zxbc[..., :Di], zxbc[..., Di:]
         dt = jnp.einsum("bsd,hd->bsh", h, _w(lp, "ssm_dt_in", h.dtype),
                         preferred_element_type=f32)
+        if cfg.ssm_mults:
+            dt = _scaled(dt, cfg.ssm_mults[4])
     with jax.named_scope("ssm/conv"):
         if conv_state is None:
             conv_state = jnp.zeros((B, Kc - 1, Cd), xbc.dtype)
@@ -1837,7 +1896,8 @@ def _mamba_op(h, lp, cfg, state=None, conv_state=None, live=None,
             jnp.mean(yg * yg, axis=-1, keepdims=True) + cfg.rms_norm_eps)
         y = (yg.reshape(B, S, Di) * lp["ssm_norm"]).astype(h.dtype)
     with jax.named_scope("ssm/out_proj"):
-        return _qdot(y, lp, "ssm_out", cfg), new_state, new_conv
+        return _scaled(_qdot(y, lp, "ssm_out", cfg), cfg.ssm_out_mult), \
+            new_state, new_conv
 
 
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
@@ -1915,9 +1975,11 @@ def _ff_res(x, lp, experts, rep, cfg, live):
         routing = _routing_counts(cfg, st)
         return x + out, routing
     with jax.named_scope("mlp"):
-        hidden = jax.nn.silu(_qdot(h, lp, "w_gate", cfg)) \
+        hidden = jax.nn.silu(_scaled(_qdot(h, lp, "w_gate", cfg),
+                                     cfg.mlp_gate_mult)) \
             * _qdot(h, lp, "w_up", cfg)
-        return x + _qdot(hidden, lp, "w_down", cfg), _routing_counts(cfg)
+        return x + _scaled(_qdot(hidden, lp, "w_down", cfg),
+                           cfg.mlp_down_mult), _routing_counts(cfg)
 
 
 def _segment_cache(cache, seg: Segment):
@@ -1928,11 +1990,11 @@ def _segment_cache(cache, seg: Segment):
     among them: the decode step carries it whole (_run_patterned_decode)."""
     out = {}
     held = {"conv": ((OP_CONV,), seg.conv_start),
-            "ssm_conv": ((OP_MAMBA,), seg.ssm_start)}
+            "ssm_conv": (SSM_OPS, seg.ssm_start)}
     for key, arr in cache.items():
         if key == "ssm":
             continue
-        ops, start = held.get(key, (_KV_OPS, seg.attn_start))
+        ops, start = held.get(key, (KV_OPS, seg.attn_start))
         n = _count_ops(seg.kinds, *ops)
         if not n:
             continue
@@ -1952,8 +2014,9 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
     """Every layer over whole sequences from position 0 (forward,
     prefill). Returns (x, fresh cache arrays by kind or {} when plens is
     None, routing): k/v [La, B, 1, S, Hkv * Dh] in cache layout, conv
-    [Lc, B, Kc - 1, D] and the Mamba-2 layers' SSM and conv state taken
-    at each row's own prompt length. Positions at or past a row's plens
+    [Lc, B, Kc - 1, D] and the Mamba-2 mixers' SSM and conv state taken
+    at each row's own prompt length (an "attention_mamba" layer yields
+    both k/v and that state). Positions at or past a row's plens
     are not live: they route to no expert and pass the SSM state on
     unchanged."""
     S = x.shape[1]
@@ -1971,7 +2034,24 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
             ks, vs, cs, ss, scs = [], [], [], [], []
             for lp, ex, (op, _) in zip(lps, experts, seg.kinds):
                 h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
-                if op in _KV_OPS:
+                if op == OP_ATTN_MAMBA:
+                    # attention and the mixer both read h; their outputs,
+                    # each times its multiplier, are summed into x
+                    q, k, v = _qkv(_scaled(h, cfg.attn_in_mult), lp, cfg,
+                                   positions, inv_freq)
+                    attn = gqa_attention(q, k, v, mask)
+                    with jax.named_scope("attn/out"):
+                        a = _scaled(_qdot(attn, lp, "wo", cfg),
+                                    cfg.attn_out_mult)
+                    y, st, cst = _mamba_op(h, lp, cfg, live=live, plens=plens)
+                    with jax.named_scope("mixer/sum"):
+                        x = x + a + y
+                    ks.append(_kv_slab(k, side))
+                    vs.append(_kv_slab(v, side))
+                    ss.append(st)
+                    scs.append(cst)
+                    routing = routing + _routing_counts(cfg, ssm=True)
+                elif op in KV_OPS:
                     q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
                     attn = gqa_attention(q, k, v, mask)
                     with jax.named_scope("attn/out"):
@@ -2040,41 +2120,69 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
     ssm = (cache["ssm"],) if "ssm" in cache else ()
     for seg, sp in zip(layer_plan(cfg), params["segments"]):
         sliced, experts = _split_experts(sp, cfg)
-        nm = _count_ops(seg.kinds, OP_MAMBA)
-        na = _count_ops(seg.kinds, *_KV_OPS)
+        nm = _count_ops(seg.kinds, *SSM_OPS)
+        na = _count_ops(seg.kinds, *KV_OPS)
 
         def body(carry, xs, seg=seg, experts=experts, nm=nm, na=na):
             x, routing, *ssm = carry
             rep, lps, cl = xs
             ia = ic = im = 0
             ks, vs, cs, scs = [], [], [], []
+
+            def attention(h, lp, ia):
+                """Attention layer `ia` of this repeat over the slab as
+                it was before this step, through the output projection;
+                the fresh k and v with it."""
+                q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
+                if sched is None:
+                    attn = gqa_attention_decode(
+                        q, cl["k"][ia], cl["v"][ia], k, v, mask_lt)
+                else:
+                    attn = decode_attention.attend(
+                        q, k, v, cache, seg.attn_start + rep * na + ia,
+                        sched)
+                with jax.named_scope("attn/out"):
+                    return _qdot(attn, lp, "wo", cfg), k, v
+
+            def mixer(h, lp, im, ssm):
+                """Mamba-2 mixer `im` of this repeat, its state stepped
+                where it lies in the carried `ssm`."""
+                at = seg.ssm_start + rep * nm + im
+                y, st, cst = _mamba_op(
+                    h, lp, cfg, conv_state=cl["ssm_conv"][im],
+                    state=(ssm[0], at))
+                return y, [st], cst.astype(cl["ssm_conv"].dtype)
+
             for lp, ex, (op, _) in zip(lps, experts, seg.kinds):
                 h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
-                if op == OP_MAMBA:
-                    at = seg.ssm_start + rep * nm + im
-                    y, st, cst = _mamba_op(
-                        h, lp, cfg, conv_state=cl["ssm_conv"][im],
-                        state=(ssm[0], at))
-                    ssm = [st]
+                if op == OP_ATTN_MAMBA:
+                    # as _run_patterned_full: both read h, summed into x;
+                    # the layer's k/v join the scatter after the scans,
+                    # its SSM state rides the carry
+                    a, k, v = attention(_scaled(h, cfg.attn_in_mult), lp, ia)
+                    a = _scaled(a, cfg.attn_out_mult)
+                    y, ssm, cst = mixer(h, lp, im, ssm)
+                    with jax.named_scope("mixer/sum"):
+                        x = x + a + y
+                    ks.append(_kv_rows(k, side)[:, 0].astype(dt))
+                    vs.append(_kv_rows(v, side)[:, 0].astype(dt))
+                    scs.append(cst)
+                    routing = routing + _routing_counts(cfg, ssm=True)
+                    ia += 1
+                    im += 1
+                elif op == OP_MAMBA:
+                    y, ssm, cst = mixer(h, lp, im, ssm)
                     x = x + y
-                    scs.append(cst.astype(cl["ssm_conv"].dtype))
+                    scs.append(cst)
                     routing = routing + _routing_counts(cfg, ssm=True)
                     im += 1
                 elif op == OP_MOE:
                     y, st = _sparse_ff(h, lp, ex, rep, cfg, live2)
                     x = x + y
                     routing = routing + _routing_counts(cfg, st)
-                elif op in _KV_OPS:
-                    q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
-                    if sched is None:
-                        attn = gqa_attention_decode(
-                            q, cl["k"][ia], cl["v"][ia], k, v, mask_lt)
-                    else:
-                        attn = decode_attention.attend(
-                            q, k, v, cache, seg.attn_start + rep * na + ia,
-                            sched)
-                    with jax.named_scope("attn/out"):
-                        x = x + _qdot(attn, lp, "wo", cfg)
+                elif op in KV_OPS:
+                    a, k, v = attention(h, lp, ia)
+                    x = x + a
                     ks.append(_kv_rows(k, side)[:, 0].astype(dt))
                     vs.append(_kv_rows(v, side)[:, 0].astype(dt))
                     ia += 1
@@ -2129,7 +2237,7 @@ def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):
     own prompt length (rows of one admission group are right-padded to
     the bucket: the state at the bucket's end would be the padding's)."""
     B, S = tokens.shape
-    x = _embed_rows(params, tokens, _dtype(cfg))
+    x = _scaled(_embed_rows(params, tokens, _dtype(cfg)), cfg.embed_mult)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
     x, fresh, _ = _run_patterned_full(

@@ -49,7 +49,7 @@ from seldon_tpu.models import ragged_attention, tp_sharding
 from seldon_tpu.models import slot as slot_rules
 from seldon_tpu.models import transformer
 from seldon_tpu.models import spec_decode as spec_model
-from seldon_tpu.models.config import ModelConfig
+from seldon_tpu.models.config import OP_ATTN_MAMBA, ModelConfig
 from seldon_tpu.models.sampling import SamplingParams
 from seldon_tpu.servers import compile_ledger, controller, cost_model
 from seldon_tpu.servers import flight_recorder, graftsan, hbm_ledger
@@ -1528,7 +1528,8 @@ class InferenceEngine:
 
     def _refuse_unpatterned_paths(self) -> None:
         """A patterned stack (cfg.layer_types: conv state, or a Mamba-2
-        mixer's SSM and conv state, beside KV) runs on the default path:
+        mixer's SSM and conv state, beside KV, in layers of their own or
+        in the attention's own layer) runs on the default path:
         dense slab, tp = 1. Every opt-in path moves, shares or replays
         KV by token position and knows no fixed-size state, so it would
         serve those layers a state that is stale, another request's or
@@ -1538,6 +1539,8 @@ class InferenceEngine:
             return
         e = self.ecfg
         state = "SSM state" if self.cfg.n_mamba_layers else "conv state"
+        both = f", both in each {OP_ATTN_MAMBA} layer" \
+            if OP_ATTN_MAMBA in self.cfg.layer_types else ""
         asked = [
             name for name, on in (
                 ("paged_kv (the block pool holds KV only)", e.paged_kv),
@@ -1557,7 +1560,7 @@ class InferenceEngine:
         if asked:
             raise ValueError(
                 f"this model has a patterned stack (layer_types: {state} "
-                "beside KV), which is served on the default path "
+                f"beside KV{both}), which is served on the default path "
                 "only; not with " + "; ".join(asked)
             )
 
@@ -1823,8 +1826,10 @@ class InferenceEngine:
     def _counts_routing(cfg) -> bool:
         """Decode chunks of this model count what routing did, after
         the sampler's tiers in their fifth value (a patterned stack
-        with sparse layers)."""
-        return bool(cfg.patterned and cfg.n_sparse_layers)
+        with sparse layers, or with Mamba-2 mixers whose layer steps
+        ride in the same counters)."""
+        return bool(cfg.patterned
+                    and (cfg.n_sparse_layers or cfg.n_mamba_layers))
 
     # --- paged-KV kernels ---------------------------------------------------
 

@@ -473,13 +473,18 @@ def test_engine_prefill_and_decode_through_the_slab_follow_the_reference(served,
     ("heal", dict(heal=True)),
     ("tp > 1", dict(tp=2)),
 ])
-def test_the_opt_in_engine_paths_refuse_the_ssm_state_by_name(path, kw):
-    cfg = get_config("tiny-nemotron")
+@pytest.mark.parametrize("preset,names", [
+    ("tiny-nemotron", "SSM state beside KV)"),
+    # the SSM state in the attention's own layer: said by the kind's name
+    ("tiny-falcon-h1", "SSM state beside KV, both in each attention_mamba layer)"),
+])
+def test_the_opt_in_engine_paths_refuse_the_ssm_state_by_name(path, kw, preset, names):
+    cfg = get_config(preset)
     params = T.init_params(cfg, jax.random.key(0))
     with pytest.raises(ValueError, match="SSM state beside KV") as e:
         InferenceEngine(params, cfg, EngineConfig(
             max_slots=2, max_seq_len=64, prompt_buckets=(16, 32), **kw))
-    assert path in str(e.value)
+    assert path in str(e.value) and names in str(e.value)
 
 
 @pytest.mark.parametrize("what,call", [
@@ -491,20 +496,26 @@ def test_the_opt_in_engine_paths_refuse_the_ssm_state_by_name(path, kw):
     ("tensor-parallel", lambda p, c, t: T.decode_step(
         p, t[:, 0], jnp.zeros((2,), jnp.int32), T.init_cache(c, 2, 8), c, tp=object())),
 ])
-def test_the_model_functions_that_know_no_ssm_state_refuse_it_by_name(what, call):
-    cfg = get_config("tiny-nemotron")
+@pytest.mark.parametrize("preset,names", [
+    ("tiny-nemotron", "the Mamba-2 layers' SSM and conv state"),
+    ("tiny-falcon-h1", "the attention_mamba layers' SSM and conv state"),
+])
+def test_the_model_functions_that_know_no_ssm_state_refuse_it_by_name(what, call, preset, names):
+    cfg = get_config(preset)
     params = T.init_params(cfg, jax.random.key(0))
     toks = jnp.ones((2, 4), jnp.int32)
     with pytest.raises(NotImplementedError, match="SSM and conv state") as e:
         call(params, cfg, toks)
-    assert what in str(e.value)
+    assert what in str(e.value) and names in str(e.value)
+    assert T.fixed_state_names(cfg).startswith(names)
 
 
-def test_tp_sharding_refuses_single_block_layers():
+@pytest.mark.parametrize("preset", ["tiny-nemotron", "tiny-falcon-h1"])
+def test_tp_sharding_refuses_single_block_layers(preset):
     from seldon_tpu.models import tp_sharding
 
     with pytest.raises(ValueError, match="patterned stack"):
-        tp_sharding.validate(get_config("tiny-nemotron"), 2)
+        tp_sharding.validate(get_config(preset), 2)
 
 
 def test_cost_model_closed_forms_equal_the_cache_spec_and_the_tree():

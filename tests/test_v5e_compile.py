@@ -205,3 +205,57 @@ def test_decode_chunk_updates_the_ssm_state_in_place(one_chip, monkeypatch):
     whole = re.escape("f32[6,%d,8,64,128]" % SLOTS)
     calls = re.findall(r"%(ssm_update[.\w]*) = \(" + whole + r"\S* f32\[", hlo)
     assert len(calls) == 3, calls
+
+
+def test_attention_and_mixer_in_one_layer_at_the_published_widths(
+        one_chip, monkeypatch):
+    """The benchmark's falcon-h1-34b-instruct as its file states it (20
+    query heads over 4 KV heads of 128, a state of 32 x 128 x 256 float32
+    a slot, hidden 5120, the 261120-row head) over the cell's 64 slots x
+    1024: Mosaic takes both kernels at these shapes (a head count off the
+    sublane tile, a 4 MB block a slot), each layer position of the scan
+    body calls each once, and the compiled chunk holds neither a copy,
+    transpose or slice as large as a layer of the slab or of the state,
+    nor the einsums' score array: KV and the SSM state of the SAME layer
+    are read and written where they lie."""
+    import json
+    import os
+
+    from seldon_tpu.models.config import ModelConfig
+    from seldon_tpu.ops import decode_attention, ssm_update
+    from tests.test_falcon_h1 import ROOT, _family
+
+    monkeypatch.setattr(ssm_update, "update", ssm_update._pallas)
+    monkeypatch.setattr(decode_attention, "applies", decode_attention.reads)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "falcon-h1-34b-instruct.json")) as f:
+        raw = json.load(f)
+    cfg = ModelConfig(**_family().model_config_kwargs(raw)).validate()
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff,
+            cfg.vocab_size) == (5120, 20, 4, 128, 21504, 261120)
+    slots, window, L = 64, 1024, cfg.n_layers
+    hlo, state = _compiled_chunk(cfg, one_chip, slots, window)
+    cache = state["cache"]
+    assert cache["k"].shape == (L, slots, 1, window, 512)
+    assert cache["ssm"].shape == (L, slots, 32, 128, 256)
+    assert len(re.findall(r"%(decode_attention[.\w]*) = bf16\[", hlo)) == 1
+    whole = re.escape("f32[%d,%d,32,128,256]" % (L, slots))
+    assert len(re.findall(
+        r"%(ssm_update[.\w]*) = \(" + whole + r"\S* f32\[", hlo)) == 1
+    layer_k = slots * window * 512
+    # what is as large as a layer of K and shaped like the cache (the
+    # slots beside the window, or beside the state's block; the weights'
+    # relayouts on the chunk's entry, PERF.md section 7 k, are larger and
+    # are not the cache's)
+    cachelike = [
+        (op, typ) for _, op, typ, _ in big_instructions(hlo, layer_k)
+        if re.search(r"\b%d,(1,)?%d,512\]|\b%d,32,128,256\]"
+                     % (slots, window, slots), typ)]
+    # the step's scatter of the fresh rows into the whole slab, in place
+    assert {op for op, _ in cachelike} <= {"fusion"}, cachelike
+    assert {_elements(typ) for _, typ in cachelike} <= {L * layer_k}, cachelike
+    assert not [typ for _, typ in cachelike if typ.startswith("f32[")]
+    scores = slots * cfg.n_heads * window
+    assert [typ for _, _, typ, _ in big_instructions(hlo, scores)
+            if typ.startswith("f32[")
+            and typ.split("]")[0].endswith(",%d" % window)] == []

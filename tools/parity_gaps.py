@@ -7,7 +7,7 @@ family's lower-precision control against the plain reference
 
 Run it after benchmark/run.py has left the job file (the unit has exited:
 one process per chip); appends one JSON line {seed, config, gaps,
-control_gaps}."""
+control_gaps, logit_std}."""
 
 import json
 import os
@@ -29,8 +29,14 @@ def main(job_file: str, out_file: str = "") -> int:
     fam = family.load(os.path.join(ROOT, "benchmark"), cfg)
     params = fam.build_params(cfg, int(job["seed"]))
     gaps, control = reference.logit_gaps(fam, params, cfg, job["probes"], control=True)
+    # the scale the gaps are read against: the reference's own spread of
+    # logits over the vocabulary at the first probe's generated positions
+    import jax.numpy as jnp
+    prompt, toks = job["probes"][0]
+    seq = jnp.asarray(list(prompt) + list(toks[:-1]), jnp.int32)
+    std = float(jnp.std(fam.forward_logits(params, seq, cfg)[len(prompt) - 1:]))
     line = json.dumps({"seed": job["seed"], "config": cfg["name"], "gaps": gaps,
-                       "control_gaps": control})
+                       "control_gaps": control, "logit_std": std})
     print(line, flush=True)
     if out_file:
         os.makedirs(os.path.dirname(os.path.abspath(out_file)), exist_ok=True)

@@ -26,7 +26,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 CONFIGS = ("mistral-7b-v0.3", "mixtral-8x7b", "lfm2-24b-a2b",
-           "nemotron-3-nano-30b-a3b")
+           "nemotron-3-nano-30b-a3b", "falcon-h1-34b-instruct")
 LIVE = (1, 2, 4, 8, 16, 32, 64)
 CONTEXTS = (128, 512, 1024)
 LAYERS, REPEATS = 8, 10

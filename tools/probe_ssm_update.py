@@ -4,7 +4,9 @@ the same arithmetic in jax.numpy (_xla), each over every Mamba-2 layer of
 the state, in place (the state donated), and checks that they agree.
 
     chiprun -- python3 tools/probe_ssm_update.py [--config nemotron-3-nano-30b-a3b] [--slots 64]
+    chiprun -- python3 tools/probe_ssm_update.py --config falcon-h1-34b-instruct
 
+(a state of 64 x 64 x 128 a slot and layer, and of 32 x 128 x 256).
 About a minute. `--rehearse` runs tiny shapes on the CPU with the kernel
 interpreted (no time comes out of that).
 """
@@ -17,6 +19,16 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+
+
+def dims_of(raw):
+    """(layers that hold an SSM state, heads, head width, groups, state
+    size) of a file of benchmark/configs, by its family's key names."""
+    if "hybrid_override_pattern" in raw:  # nemotron_h
+        return (raw["hybrid_override_pattern"].count("M"), raw["mamba_num_heads"],
+                raw["mamba_head_dim"], raw["n_groups"], raw["ssm_state_size"])
+    return (raw["num_hidden_layers"], raw["mamba_n_heads"], raw["mamba_d_head"],
+            raw["mamba_n_groups"], raw["mamba_d_state"])  # falcon_h1
 
 
 def main(argv=None) -> int:
@@ -36,9 +48,8 @@ def main(argv=None) -> int:
 
     from seldon_tpu.ops import ssm_update
 
-    Lm = raw["hybrid_override_pattern"].count("M")
-    B, H, P = args.slots, raw["mamba_num_heads"], raw["mamba_head_dim"]
-    G, N = raw["n_groups"], raw["ssm_state_size"]
+    Lm, H, P, G, N = dims_of(raw)
+    B = args.slots
     interpret = contextlib.nullcontext()
     if args.rehearse:
         from tests.pallas_interpret import pallas_interpret
