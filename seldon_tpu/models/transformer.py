@@ -455,11 +455,15 @@ def moe_block(x: jnp.ndarray, bp: Dict[str, jnp.ndarray], cfg: ModelConfig):
     """Top-k MoE. Dense-mixing formulation: every expert runs on every token
     and results are combined with the (sparsified) router weights. This is
     compute-inflated by E/k but fully static-shaped and shards cleanly over
-    'ep'. The token -> expert dispatch (ops/moe_dispatch.py: grouped
-    products over the experts that hold tokens) is what the patterned
-    stack runs (_sparse_ff); with the softmax router it computes this
-    block's function (tests/test_patterned.py), so moving this path onto
-    it is a swap, judged on its own cell (ROADMAP A6).
+    'ep'. Who runs it: training's forward (the load-balance loss is
+    computed here alone), tp > 1, a mesh of several devices, and the
+    paged, prefix, chunked and speculative paths' own runners. The
+    default engine's two runners (_run_blocks_prefill, _run_blocks_decode
+    with the stack whole on one device) compute the same function by
+    token -> expert dispatch (ops/moe_dispatch.py, _dispatched_experts:
+    grouped products over the experts that hold live rows; with the
+    softmax router it is this block's function, tests/test_patterned.py),
+    as the patterned stack always does (_sparse_ff).
     """
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.n_experts_per_token
@@ -655,17 +659,65 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
     return q, k, v
 
 
-def _mlp_res(x, bp, cfg, act_spec, tp=None):
+def _dispatched_experts(blocks, cfg, whole: bool):
+    """A homogeneous stack's blocks as (what the layer scan slices, what
+    the expert kernel is handed whole), or (blocks, None) where the
+    sparse block stays moe_block: no experts, or a stack that is not
+    `whole` on one device (tp, a mesh the compiler partitions the
+    program over, a ring). The expert matrices [L, E, ...] and their int8
+    scales (under "scales", None for a stack that has none) leave the
+    scan with the layer and expert axes merged, [L * E, ...], which
+    moves nothing (and dequantises nothing: an int8
+    stack goes to the grouped product as it is stored); the scan rides
+    the layer's index and the kernel picks that layer's E groups by it
+    (ops/moe_dispatch.dispatch_experts says why no slice of the stack
+    may be a kernel's operand)."""
+    if not (cfg.n_experts and whole):
+        return blocks, None
+    def merged(name):
+        return blocks[name].reshape((-1,) + blocks[name].shape[2:])
+
+    experts = {n: merged(n) for n in _EXPERT_STACKS}
+    scales = {n: merged(n + "_scale") for n in _EXPERT_STACKS
+              if n + "_scale" in blocks}
+    experts["scales"] = scales or None
+    taken = set(_EXPERT_STACKS) | {n + "_scale" for n in scales}
+    return {n: v for n, v in blocks.items() if n not in taken}, experts
+
+
+def _mlp_res(x, bp, cfg, act_spec, tp=None, experts=None, layer=None,
+             live=None):
     """Post-attention half of a block: residual + (SwiGLU | MoE).
+    Returns (x, aux): the sparse block's load-balance loss (zero for a
+    dense MLP) or, handed the expert stacks, what routing did.
 
     Under `tp` the gate/up projections run output-sharded on d_ff and
     the hidden is ALL-GATHERED (exact data movement) before the
     REPLICATED w_down contraction — no partial-sum reduction ever forms,
     keeping outputs bit-identical to tp=1 (tp_sharding module doc). MoE
-    weights replicate, so that branch needs no hints."""
+    weights replicate, so that branch needs no hints.
+
+    `experts` (_dispatched_experts: the merged expert stacks, `layer`
+    this block's index in them) sends each row of `live` [B, S] to the
+    experts it chose and no row anywhere else
+    (ops/moe_dispatch.dispatch_experts); aux is then this layer's
+    counters [1, experts touched, assignments] (routing_width)."""
     h = rms_norm(x, bp["mlp_norm"], cfg.rms_norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.n_experts:
+    if experts is not None:
+        B, S, D = h.shape
+        rows = h.reshape(B * S, D)
+        with jax.named_scope("moe/router"):
+            top_idx, top_w = moe_dispatch.route(
+                rows, bp["router"], None, top_k=cfg.n_experts_per_token,
+                router="softmax")
+        out, stats = moe_dispatch.dispatch_experts(
+            rows, top_idx, top_w, experts["w_gate"], experts["w_up"],
+            experts["w_down"], None if live is None else live.reshape(B * S),
+            n_experts=cfg.n_experts, layer=layer, scales=experts["scales"])
+        x = x + out.reshape(B, S, D)
+        aux = _routing_counts(cfg, stats)
+    elif cfg.n_experts:
         mlp_out, aux = moe_block(h, bp, cfg)
         x = x + mlp_out
     else:
@@ -683,7 +735,8 @@ def _mlp_res(x, bp, cfg, act_spec, tp=None):
 
 
 def _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
-                        act_spec=None, ring_mesh=None, tp=None):
+                        act_spec=None, ring_mesh=None, tp=None, plens=None,
+                        spread=False):
     """Layer scan for PREFILL: attention runs over the fresh k/v only
     (every serving prefill starts at position 0, so the fresh tokens ARE
     the whole visible window — the cache is never read) and each layer's
@@ -697,11 +750,24 @@ def _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
     prompts prefill with the sequence sharded across devices, k/v blocks
     rotating over ICI (parallel/ring_attention.py). The returned k/v ys
     are full arrays; GSPMD gathers the sp shards when the caller
-    scatters them into the (T-unsharded) decode cache. Returns
-    (x, {"k","v"} stacked bf16, aux)."""
-    side = kv_heads_per_row(cfg)
+    scatters them into the (T-unsharded) decode cache.
 
-    def body(carry, bp):
+    A stack with experts that lies whole on one device (no `tp`, no
+    ring, not `spread` over a mesh) computes its sparse block by token ->
+    expert dispatch (_dispatched_experts) for each row's first `plens`
+    [B] tokens (None: all S), so right-padding routes nowhere, and aux is
+    then the routing counters summed over the layers.
+
+    Returns (x, {"k","v"} stacked bf16, aux)."""
+    side = kv_heads_per_row(cfg)
+    blocks, experts = _dispatched_experts(
+        params["blocks"], cfg,
+        tp is None and ring_mesh is None and act_spec is None and not spread)
+    live = None if experts is None or plens is None else \
+        jnp.arange(x.shape[1])[None, :] < plens[:, None]
+
+    def body(carry, xs):
+        bp, layer = xs
         h = rms_norm(carry, bp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(h, bp, cfg, positions, inv_freq, tp=tp)
         B, S = q.shape[0], q.shape[1]
@@ -734,12 +800,18 @@ def _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
             x = carry + _qdot(attn, bp, "wo", cfg)
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
-        x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp)
+        x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp, experts=experts,
+                          layer=layer, live=live)
         # ys in cache layout: [B, 1, S, Hkv * Dh] per layer.
         return x, (_kv_slab(k, side), _kv_slab(v, side), aux)
 
-    x, (ks, vs, aux) = jax.lax.scan(body, x, params["blocks"])
-    return x, {"k": ks, "v": vs}, jnp.mean(aux)
+    if experts is None:
+        x, (ks, vs, aux) = jax.lax.scan(
+            lambda carry, bp: body(carry, (bp, None)), x, blocks)
+        return x, {"k": ks, "v": vs}, jnp.mean(aux)
+    x, (ks, vs, aux) = jax.lax.scan(
+        body, x, (blocks, jnp.arange(cfg.n_layers)))
+    return x, {"k": ks, "v": vs}, jnp.sum(aux, axis=0)
 
 
 def _run_blocks_prefill_prefix(params, x, cfg, positions, inv_freq, mask,
@@ -846,12 +918,23 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
     ride at 85-90 % of the HBM peak. The kernel here wins by what it
     does not read; PERF.md section 5 has its table by occupancy.)
 
+    A stack with experts that lies whole on one device (no `tp`, not
+    `spread`) computes its sparse block by token -> expert dispatch
+    (_dispatched_experts) for the `live` rows, so a step reads the
+    weights of the experts those rows chose and of no other; aux is then
+    the routing counters summed over the layers (routing_width).
+
     Returns (x, new_cache, aux)."""
     quantized = cfg.kv_cache_dtype == "int8"
     Smax = cache["k"].shape[3]
     mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
     side = kv_heads_per_row(cfg)
     sched = _sparse_decode(cfg, cache, live, pos, spread or tp is not None)
+    blocks, experts = _dispatched_experts(
+        params["blocks"], cfg,
+        tp is None and act_spec is None and not spread)
+    # the one token a slot holds is a live row where the slot is
+    routed = None if experts is None or live is None else live[:, None]
 
     def attend(q, k, v, cl):
         if sched is not None:
@@ -867,7 +950,7 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
         )
 
     def body(carry, xs):
-        bp, cl = xs
+        bp, cl, *layer = xs
         h = rms_norm(carry, bp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(h, bp, cfg, positions, inv_freq, tp=tp)
         attn = attend(q, k, v, cl)
@@ -877,7 +960,8 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
             x = carry + _qdot(attn, bp, "wo", cfg)
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
-        x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp)
+        x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp, experts=experts,
+                          layer=layer[0] if layer else None, live=routed)
         if quantized:
             kq, ksc = _quantize_kv(k)
             vq, vsc = _quantize_kv(v)
@@ -890,9 +974,11 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
                      "v": _kv_rows(v, side)[:, 0].astype(dt)}
         return x, (fresh, aux)
 
-    x, (fresh, aux) = jax.lax.scan(
-        body, x, (params["blocks"],
-                  cache if sched is None else jnp.arange(cache["k"].shape[0])))
+    xs = (blocks,
+          cache if sched is None else jnp.arange(cache["k"].shape[0]))
+    if experts is not None:
+        xs += (jnp.arange(cfg.n_layers),)
+    x, (fresh, aux) = jax.lax.scan(body, x, xs)
     rows = jnp.arange(pos.shape[0])
     # k / v: one scatter covers all layers, with layer, row and position
     # all INDICES of it and only the token's row [1, Hkv*Dh] its window:
@@ -916,7 +1002,8 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
             jnp.where(here, fresh[key][..., None], cache[key])
             for key in cache
         }
-    return x, new_cache, jnp.mean(aux)
+    aux = jnp.mean(aux) if experts is None else jnp.sum(aux, axis=0)
+    return x, new_cache, aux
 
 
 @jax.named_scope("lm_head")
@@ -1312,6 +1399,7 @@ def prefill(
     cfg: ModelConfig,
     ring_mesh=None,
     tp=None,
+    spread: bool = False,  # the program lies over several devices
 ) -> Tuple[jnp.ndarray, Cache]:
     """Run prompts through the model, filling cache slots [0, S).
     Returns (next-token logits [B, V] taken at each row's last real token,
@@ -1319,7 +1407,10 @@ def prefill(
     prefill — the prompt's sequence axis shards over 'sp' and attention
     runs as a ring (long-prompt admissions scale across the slice; the
     decode cache stays T-unsharded, GSPMD gathers the shards at the
-    cache write)."""
+    cache write). A stack with experts, whole on one device, sends the
+    prompts' own tokens to the experts they choose and the right-padding
+    nowhere (_run_blocks_prefill); `spread`, tp and the ring keep
+    moe_block."""
     B, S = tokens.shape
     if cfg.patterned:
         refuse_patterned(cfg, "tensor-parallel or ring prefill",
@@ -1345,7 +1436,7 @@ def prefill(
     # The stacked ys land in the cache in one update per array.
     x, kv, _ = _run_blocks_prefill(params, x, cfg, positions, inv_freq, mask,
                                    ring_mesh=ring_mesh if use_ring else None,
-                                   tp=tp)
+                                   tp=tp, plens=prompt_lens, spread=spread)
     with jax.named_scope("attn/cache_update"):
         writes = kv_writes(kv, cache, cfg)
         if S == Smax:
@@ -1439,7 +1530,8 @@ def decode_step(
     return_routing adds a third value, int32 [routing_width(cfg)]:
     sparse layers run, distinct experts they read summed over those
     layers, (row, expert) assignments (zeros for a stack without
-    dispatch); a stack that holds a share of its experts or has Mamba-2
+    dispatch, and where a homogeneous stack's experts keep moe_block:
+    tp, spread); a stack that holds a share of its experts or has Mamba-2
     layers adds the assignments to experts held here and the Mamba-2
     layers run."""
     x = _scaled(_embed_rows(params, token, _dtype(cfg)),
@@ -1452,9 +1544,11 @@ def decode_step(
         x, cache, routing = _run_patterned_decode(
             params, x, cfg, positions, inv_freq, pos, cache, live, spread)
     else:
-        x, cache, _ = _run_blocks_decode(params, x, cfg, positions,
-                                         inv_freq, pos, cache, tp=tp,
-                                         live=live, spread=spread)
+        x, cache, aux = _run_blocks_decode(params, x, cfg, positions,
+                                           inv_freq, pos, cache, tp=tp,
+                                           live=live, spread=spread)
+        if aux.ndim:  # the sparse block ran by dispatch: its counters
+            routing = aux
     logits = _logits(params, x, cfg)[:, 0]
     if return_routing:
         return logits, cache, routing

@@ -19,11 +19,22 @@ softmax over those k); "sigmoid" scores each expert by sigmoid(logit),
 SELECTS on score + bias, WEIGHTS by the unbiased score, optionally
 renormalised over the k, times a scale.
 
-`grouped_matmul` is the one kernel. On a TPU it is the Pallas megablox
-grouped matmul (jax.experimental.pallas.ops.tpu.megablox.gmm, which a
-device trace shows under GROUPED_MATMUL_NAME); elsewhere
-`jax.lax.ragged_dot`. PERF.md §5 has the chip readings of both that the
-choice was made from (tools/probe_moe_dispatch.py takes them).
+Who runs it: the patterned stack's sparse layers (transformer._sparse_ff:
+lfm2, nemotron_h; bf16 experts) and, on the default engine's two runners
+with the stack whole on one device, the homogeneous stack's sparse block
+(transformer._mlp_res handed the expert stacks: Mixtral, int8 or bf16
+experts). moe_block keeps training's forward, tp > 1, a mesh of several
+devices and the paged, prefix, chunked and speculative runners.
+
+`grouped_matmul` is the one product, chosen by what it observes. On a
+TPU a bf16 or float32 stack goes to the Pallas megablox grouped matmul
+(jax.experimental.pallas.ops.tpu.megablox.gmm, which a device trace
+shows under GROUPED_MATMUL_NAME) and an int8 stack, which megablox
+refuses, with its per-output-channel scales to ops/gmm_int8 (the same
+walk; a tile crosses HBM as int8 and is widened in VMEM). Elsewhere
+`jax.lax.ragged_dot`, on the dequantised stack if it is int8. PERF.md §5
+has the chip readings the choices were made from
+(tools/probe_moe_dispatch.py takes them).
 """
 
 from __future__ import annotations
@@ -32,6 +43,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from seldon_tpu.ops import gmm_int8
 
 # What the expert products are called in a device trace on a TPU: the
 # name of megablox's pallas_call, which XLA keeps as the instruction's
@@ -50,6 +63,14 @@ GROUPED_MATMUL_NAME = "gmm"
 # was slower at 1024 and at 8192 tokens). n is sized so that one grid
 # step streams a weight tile of ~2 MB.
 _GMM_TILE_M_SMALL = 128
+# int8 weights (ops/gmm_int8; readings at E=8, D=4096, F=14336, top-2,
+# PERF.md §5): 128 rows against a tile of one byte an element are 256
+# FLOPs a byte, the chip's ridge, so the matrix unit's pass over a tile
+# no longer hides behind its read: 64 rows read 675 GB/s where 128 read
+# 600 (2 live rows of 64). k and n tiles are twice as long (4 MB of
+# weights a grid step): half the k steps' passes over the float32 sum
+# cost prefill 5 % less at 1024 and 8192 tokens, decode nothing.
+_GMM_TILE_M_SMALL_INT8 = 64
 _GMM_TILE_M_LARGE = 256
 _GMM_LARGE_ROWS = 1024
 _GMM_TILE_K = 2048
@@ -81,7 +102,9 @@ def route(
     return top_idx, w * scale
 
 
-def _gmm_tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
+def _gmm_tiles(m: int, k: int, n: int,
+               int8: bool = False) -> Tuple[int, int, int]:
+    """(m, k, n) tiles; `int8` for weights of one byte an element."""
     def fit(dim, tile):
         # the largest multiple of 128 that divides dim and is <= tile,
         # else the whole dim (megablox masks an irregular k remainder,
@@ -90,23 +113,32 @@ def _gmm_tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
             if dim % t == 0:
                 return t
         return dim
-    tm = _GMM_TILE_M_LARGE if m >= _GMM_LARGE_ROWS else _GMM_TILE_M_SMALL
-    return min(tm, m), fit(k, _GMM_TILE_K), fit(n, _GMM_TILE_N)
+    tm = _GMM_TILE_M_LARGE if m >= _GMM_LARGE_ROWS else \
+        _GMM_TILE_M_SMALL_INT8 if int8 else _GMM_TILE_M_SMALL
+    wider = 2 if int8 else 1
+    return (min(tm, m), fit(k, _GMM_TILE_K * wider),
+            fit(n, _GMM_TILE_N * wider))
 
 
-def _ragged_dot(lhs, rhs, group_sizes, transpose_rhs=False):
+def _ragged_dot(lhs, rhs, group_sizes, transpose_rhs=False, rhs_scale=None):
     if transpose_rhs:
         rhs = jnp.swapaxes(rhs, 1, 2)
+    if rhs.dtype == jnp.int8:  # the dequantised stack: off a TPU only
+        # graftlint: allow(num-barrier) weight dequant of constant
+        # weights, as models/quantize.dequant: fusing it into the
+        # product is the point, and no second leg materialises it
+        rhs = rhs.astype(lhs.dtype) * rhs_scale.astype(lhs.dtype)
     return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32))
 
 
-def _megablox(lhs, rhs, group_sizes, transpose_rhs=False):
+def _megablox(lhs, rhs, group_sizes, transpose_rhs=False, rhs_scale=None):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m = lhs.shape[0]
     # rhs stored [E, N, K] (transpose_rhs): tiles by the logical (m, k, n)
     k, n = rhs.shape[2:0:-1] if transpose_rhs else rhs.shape[1:]
-    tm = _gmm_tiles(m, k, n)[0]
+    int8 = rhs.dtype == jnp.int8
+    tm = _gmm_tiles(m, k, n, int8)[0]
     pad = (-m) % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
@@ -115,11 +147,18 @@ def _megablox(lhs, rhs, group_sizes, transpose_rhs=False):
     rest = (m + pad) - jnp.sum(group_sizes)
     sizes = jnp.concatenate(
         [group_sizes.astype(jnp.int32), rest[None].astype(jnp.int32)])
-    out = gmm(
-        lhs, rhs, sizes, preferred_element_type=lhs.dtype,
-        tiling=_gmm_tiles(m + pad, k, n),
-        group_offset=jnp.zeros((), jnp.int32), transpose_rhs=transpose_rhs,
-    )
+    if int8:
+        out = gmm_int8.gmm(
+            lhs, rhs, rhs_scale, sizes,
+            tiling=_gmm_tiles(m + pad, k, n, int8),
+            transpose_rhs=transpose_rhs)
+    else:
+        out = gmm(
+            lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+            tiling=_gmm_tiles(m + pad, k, n),
+            group_offset=jnp.zeros((), jnp.int32),
+            transpose_rhs=transpose_rhs,
+        )
     return out[:m] if pad else out
 
 
@@ -128,11 +167,15 @@ def grouped_matmul(
     rhs: jnp.ndarray,  # [E, K, N]; [E, N, K] with transpose_rhs
     group_sizes: jnp.ndarray,  # [E] int32, sum <= M
     transpose_rhs: bool = False,
+    rhs_scale: Optional[jnp.ndarray] = None,  # [E, 1, N] float32: rhs int8
 ) -> jnp.ndarray:
     """out[r] = lhs[r] @ rhs[group of r]; rows past sum(group_sizes)
-    belong to no group (callers mask them: their value is unspecified)."""
+    belong to no group (callers mask them: their value is unspecified).
+    int8 weights come with their per-output-channel scales
+    (models/quantize.py) and are never a bf16 array in HBM on a TPU:
+    ops/gmm_int8 widens a tile where it multiplies it."""
     product = _megablox if jax.default_backend() == "tpu" else _ragged_dot
-    return product(lhs, rhs, group_sizes, transpose_rhs)
+    return product(lhs, rhs, group_sizes, transpose_rhs, rhs_scale)
 
 
 def dispatch_experts(
@@ -147,6 +190,7 @@ def dispatch_experts(
     n_experts: int,
     layer: Optional[jnp.ndarray] = None,  # int32 scalar in [0, L)
     first: Optional[int] = None,
+    scales: Optional[Dict[str, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """sum_j top_w[:, j] * SwiGLU_{top_idx[:, j]}(x) for live rows, zeros
     for the others. Also what routing did: `touched` (experts that hold
@@ -171,7 +215,11 @@ def dispatch_experts(
     other layers are empty, so the kernel never touches them. That is
     how a scan over layers hands the kernel its layer: a slice of the
     stack would be a copy of every expert's weights each step, because a
-    custom call reads operands that exist in memory."""
+    custom call reads operands that exist in memory.
+
+    int8 weights come with `scales`: {"w_gate", "w_up", "w_down"} ->
+    float32 [L * E, 1, columns], what models/quantize.py stores beside
+    them, merged the same way."""
     N, D = x.shape
     K = top_idx.shape[1]
     E = n_experts
@@ -196,13 +244,18 @@ def dispatch_experts(
                 jnp.zeros((w_up.shape[0],), jnp.int32), group_sizes,
                 (layer.astype(jnp.int32) * E,))
     with jax.named_scope("moe/experts"):
+        def product(rows, name, w, **kw):
+            if scales is not None:
+                kw["rhs_scale"] = scales[name]
+            return grouped_matmul(rows, w, sizes, **kw)
+
         if w_gate is None:
             hidden = jnp.square(jax.nn.relu(
-                grouped_matmul(xs, w_up, sizes, transpose_rhs=True)))
+                product(xs, "w_up", w_up, transpose_rhs=True)))
         else:
-            hidden = jax.nn.silu(grouped_matmul(xs, w_gate, sizes)) \
-                * grouped_matmul(xs, w_up, sizes)
-        ys = grouped_matmul(hidden, w_down, sizes)  # [A, D]
+            hidden = jax.nn.silu(product(xs, "w_gate", w_gate)) \
+                * product(xs, "w_up", w_up)
+        ys = product(hidden, "w_down", w_down)  # [A, D]
     with jax.named_scope("moe/combine"):
         ys = jnp.where((jnp.arange(A) < n_routed)[:, None], ys, 0)
         # back to (token, j) order: position of assignment a in `order`

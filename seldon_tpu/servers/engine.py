@@ -63,6 +63,12 @@ logger = logging.getLogger(__name__)
 access_log = logging.getLogger("seldon_tpu.access")
 
 
+def _spread(mesh) -> bool:
+    """The program lies over several devices: the compiler partitions
+    it, so no kernel reads the slab or the expert stack whole there."""
+    return mesh is not None and mesh.size > 1
+
+
 def _named_partial(impl, /, *args, **bound):
     """functools.partial carrying `impl`'s name. jax.jit names the XLA
     module after its callable and a bare partial has none (every engine
@@ -1625,8 +1631,9 @@ class InferenceEngine:
             sp = dict(ring_mesh.shape).get("sp", 1)
             if Sb % sp != 0:  # static per-bucket decision
                 ring_mesh = None
-        logits, sub = transformer.prefill(params, toks, plens, sub, cfg,
-                                          ring_mesh=ring_mesh, tp=tp)
+        logits, sub = transformer.prefill(
+            params, toks, plens, sub, cfg, ring_mesh=ring_mesh, tp=tp,
+            spread=_spread(mesh))
         cache = state["cache"]
         Smax = cache["k"].shape[3]
         first, first_done = slot_rules.first_token(
@@ -1803,7 +1810,7 @@ class InferenceEngine:
         routed = InferenceEngine._counts_routing(cfg)
         # over a mesh of several devices the compiler partitions the
         # program (or tp does, exactly): no kernel reads the slab there
-        spread = tp is not None or (mesh is not None and mesh.size > 1)
+        spread = tp is not None or _spread(mesh)
 
         def step_model(carry):
             live, pos, cache = carry["active"], carry["pos"], carry["cache"]
@@ -1827,9 +1834,12 @@ class InferenceEngine:
         """Decode chunks of this model count what routing did, after
         the sampler's tiers in their fifth value (a patterned stack
         with sparse layers, or with Mamba-2 mixers whose layer steps
-        ride in the same counters)."""
-        return bool(cfg.patterned
-                    and (cfg.n_sparse_layers or cfg.n_mamba_layers))
+        ride in the same counters; a homogeneous stack with experts,
+        whose counters grow where its sparse block runs by dispatch and
+        stay 0 where it keeps moe_block: transformer.decode_step)."""
+        if not cfg.patterned:
+            return bool(cfg.n_experts)
+        return bool(cfg.n_sparse_layers or cfg.n_mamba_layers)
 
     # --- paged-KV kernels ---------------------------------------------------
 
@@ -1867,8 +1877,9 @@ class InferenceEngine:
             spos = prefix_lens[:, None] + jnp.arange(Sb)[None, :]
         else:
             sub = transformer.init_cache(cfg, G, Sb)
-            logits, writes = transformer.prefill(params, toks, plens, sub,
-                                                 cfg, tp=tp)
+            logits, writes = transformer.prefill(
+                params, toks, plens, sub, cfg, tp=tp,
+                spread=_spread(mesh))
             # A cold prefill fills slab rows; the pool is by head.
             writes = transformer.kv_by_head(writes, cfg)
             spos = jnp.broadcast_to(jnp.arange(Sb)[None, :], (G, Sb))

@@ -68,7 +68,8 @@ def relayouts(hlo: str, at_least: int):
             if op in ("copy", "transpose")]
 
 
-def _compiled_chunk(cfg, one_chip, slots=SLOTS, window=WINDOW):
+def _compiled_chunk(cfg, one_chip, slots=SLOTS, window=WINDOW,
+                    init=init_params):
     """The optimized HLO of the 4-step decode chunk of `cfg` over slots x
     window, compiled for the described v5e, and the state's shapes."""
     def shapes(tree):
@@ -76,8 +77,7 @@ def _compiled_chunk(cfg, one_chip, slots=SLOTS, window=WINDOW):
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), tree)
 
-    params = shapes(jax.eval_shape(
-        lambda: init_params(cfg, jax.random.key(0))))
+    params = shapes(jax.eval_shape(lambda: init(cfg, jax.random.key(0))))
     state = shapes(jax.eval_shape(
         lambda: slot.fresh(transformer.init_cache(cfg, slots, window),
                            slots)))
@@ -259,3 +259,54 @@ def test_attention_and_mixer_in_one_layer_at_the_published_widths(
     assert [typ for _, _, typ, _ in big_instructions(hlo, scores)
             if typ.startswith("f32[")
             and typ.split("]")[0].endswith(",%d" % window)] == []
+
+
+def test_mixtral_chunk_hands_the_grouped_kernel_the_int8_stack_whole(
+        one_chip, monkeypatch):
+    """The benchmark's mixtral-8x7b as its file states it (8 experts of
+    4096 x 14336, top-2, int8 weights, 5 layers) over the cell's 64 slots
+    x 1024: Mosaic takes ops/gmm_int8 at these widths, the scan body
+    calls it three times (gate, up, down) on the expert stack of ALL
+    layers as the tree stores it, int8 and whole (the layer is picked by
+    the group sizes), and the compiled chunk holds no copy, slice or
+    widened twin as large as one layer's expert matrix stack: a step
+    reads the experts its live rows chose and nothing else of them."""
+    import json
+    import os
+
+    from seldon_tpu.models.config import ModelConfig
+    from seldon_tpu.models.quantize import init_params_int8
+    from seldon_tpu.ops import decode_attention, moe_dispatch
+    from tests.test_falcon_h1 import ROOT
+
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul", moe_dispatch._megablox)
+    monkeypatch.setattr(decode_attention, "applies", decode_attention.reads)
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "benchmark"))
+    import family
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "mixtral-8x7b.json")) as f:
+        raw = json.load(f)
+    assert raw["serving"]["weight_dtype"] == "int8"
+    cfg = ModelConfig(**family.load(os.path.join(ROOT, "benchmark"), raw)
+                      .model_config_kwargs(raw)).validate()
+    L, E, D, F = cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.d_ff
+    assert (L, E, cfg.n_experts_per_token, D, F) == (5, 8, 2, 4096, 14336)
+    hlo, _ = _compiled_chunk(cfg, one_chip, 64, 1024, init=init_params_int8)
+    calls = re.findall(r"%gmm_int8[.\d]* = bf16\[128,(\d+)\]\S* "
+                       r"custom-call\(([^)]*)\)", hlo)
+    assert sorted(n for n, _ in calls) == ["14336", "14336", "4096"], calls
+    # the kernel's weight operand (after the three scalar-prefetch arrays
+    # and the rows) is a parameter or loop-carried value of the merged
+    # stack's shape, not the result of a fusion, copy or slice
+    merged = {"14336": "s8[%d,%d,%d]" % (L * E, D, F),
+              "4096": "s8[%d,%d,%d]" % (L * E, F, D)}
+    for n, operands in calls:
+        weights = operands.split(", ")[5].split("*/")[-1].lstrip("%")
+        made = re.search(r"%" + re.escape(weights) + r" = (\S+) (\S+?)\(", hlo)
+        assert made and made.group(1).startswith(merged[n]), (n, made)
+        assert made.group(2) in ("get-tuple-element", "parameter", "bitcast"), made
+    # nothing the size of one layer's expert matrix, in any dtype
+    one_matrix_stack = E * D * F
+    assert [(op, typ) for _, op, typ, _ in
+            big_instructions(hlo, one_matrix_stack)] == []
