@@ -637,14 +637,28 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
     """`tp` (models/tp_sharding.TpHints, EngineConfig.tp > 1 only) pins
     the projected heads sharded on 'tp': each device computes the FULL
     d_model contraction for its own disjoint head slice, so per-element
-    reduction order — and hence the bits — match tp=1 exactly."""
+    reduction order — and hence the bits — match tp=1 exactly.
+
+    In a decode step (S == 1) the flat [B, 1, H*Dh] results of the wq and
+    wk products are fenced before the reshape to heads. Unfenced, the
+    TPU compiler folds that reshape into the product, which then has two
+    free weight dimensions, wants the whole stored stack relaid out
+    contraction-minor (a copy of the parameter on every chunk's entry)
+    and takes neither the int8 dequantise nor a bf16 stack's layer slice
+    into its fusion: a layer's whole projection matrix is written out
+    and read back, three passes over wq where wv and wo, whose products
+    stay flat, make one (PERF.md section 6, PR 42). The fence moves no
+    value. A step is bound by those bytes; a prefill (S > 1) is bound
+    by the matrix unit and a fence there would only send its
+    activations through memory once more, so it keeps its program."""
     B, S, _ = h.shape
     Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
+    flat = jax.lax.optimization_barrier if S == 1 else (lambda t: t)
     with jax.named_scope("attn/qkv"):
         hq = _quantize_act(h) if _w8a8_applies(bp, "wq", cfg) else None
-        q = _qdot(h, bp, "wq", cfg, act_q=hq).reshape(
+        q = flat(_qdot(h, bp, "wq", cfg, act_q=hq)).reshape(
             B, S, cfg.n_heads, Dh)
-        k = _scaled(_qdot(h, bp, "wk", cfg, act_q=hq),
+        k = _scaled(flat(_qdot(h, bp, "wk", cfg, act_q=hq)),
                     cfg.key_mult).reshape(B, S, Hkv, Dh)
         v = _qdot(h, bp, "wv", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
         if cfg.qk_norm:

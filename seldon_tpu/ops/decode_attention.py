@@ -138,7 +138,7 @@ def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref,
         rows = pl.ds(pl.multiple_of(blk_ref[w] * block, block), block)
         # a token's row of K or V; its scales, [Hkv, T], a column a token
         at = [(layer, b, 0, rows), (layer, b, 0, rows),
-              (layer, b, slice(None), rows), (layer, b, slice(None), rows)]
+              (b, slice(None), rows), (b, slice(None), rows)]
         return [pltpu.make_async_copy(src.at[at[i]], dst.at[buf], sem.at[i, buf])
                 for i, (src, dst) in enumerate(zip(hbm, bufs))]
 
@@ -233,9 +233,18 @@ def attend(
     Hkv, block = C // Dh, sched.block
     G = H // Hkv
     f32 = jnp.float32
-    slab = [cache[key] for key in ("k", "v", "k_scale", "v_scale")
-            if key in cache]
-    quantized = len(slab) == 4
+    slab = [cache["k"], cache["v"]]
+    quantized = "k_scale" in cache
+    if quantized:
+        # The layer's scales (1 MiB each at 64 x 1024 x 8), not the
+        # whole arrays: the compiler takes a call to read its operands
+        # whole, and two whole arrays of scales (32 MiB each) fit the
+        # chip's fast memory, so it may move one there ahead of the call
+        # in EVERY layer: 1.1 GB a step on mistral7b.chat (PERF.md
+        # section 6, PR 42). K and V stay whole: a layer of them sliced
+        # out is 64 MiB moved a layer.
+        slab += [jax.lax.dynamic_index_in_dim(cache[key], layer, 0, False)
+                 for key in ("k_scale", "v_scale")]
     own = (jnp.arange(C) // Dh)[None, :] == (jnp.arange(H) // G)[:, None]
     args = [jnp.tile(q[:, 0], (1, 1, LANES // Dh)),
             k_fresh.astype(q.dtype).reshape(B, 1, C),

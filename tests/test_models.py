@@ -353,6 +353,46 @@ def test_int8_weight_quantization_close_to_bf16():
         assert agree > 0.9, (preset, agree)
 
 
+@pytest.mark.parametrize("S", [1, 4])
+@pytest.mark.parametrize("weights", ["int8", "bf16"])
+def test_qkv_fence_moves_no_value(weights, S):
+    """transformer._qkv fences the flat wq and wk products of a decode
+    step (S == 1) so that the TPU compiler keeps them flat; at S == 1
+    and at S == 4 it returns what the unfenced expression returns, to
+    the bit, for an int8 and a bf16 tree."""
+    from seldon_tpu.models import transformer
+    from seldon_tpu.models.quantize import quantize_params
+
+    cfg = CFG
+    tree = init_params(cfg, jax.random.key(0))
+    if weights == "int8":
+        tree = quantize_params(tree)
+    bp = jax.tree.map(lambda a: a[1], tree["blocks"])
+    B, Hkv, Dh = 3, cfg.n_kv_heads, cfg.head_dim
+    h = jax.random.normal(jax.random.key(1), (B, S, cfg.d_model),
+                          jnp.float32).astype(tree["final_norm"].dtype)
+    positions = jnp.arange(7, 7 + S)[None, :].repeat(B, 0)
+    inv_freq = transformer.rope_frequencies(cfg)
+
+    def unfenced(h, bp):
+        q = transformer._qdot(h, bp, "wq", cfg).reshape(B, S, cfg.n_heads, Dh)
+        k = transformer._qdot(h, bp, "wk", cfg).reshape(B, S, Hkv, Dh)
+        v = transformer._qdot(h, bp, "wv", cfg).reshape(B, S, Hkv, Dh)
+        return (transformer.apply_rope(q, positions, inv_freq),
+                transformer.apply_rope(k, positions, inv_freq), v)
+
+    got = jax.jit(lambda h, bp: transformer._qkv(
+        h, bp, cfg, positions, inv_freq))(h, bp)
+    want = jax.jit(unfenced)(h, bp)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+    text = jax.jit(lambda h, bp: transformer._qkv(
+        h, bp, cfg, positions, inv_freq)).lower(h, bp).as_text()
+    assert text.count("optimization_barrier") == (2 if S == 1 else 0)
+
+
 def test_w8a8_matches_bf16_math():
     """act_dtype='int8' (W8A8: dynamic per-token A8 + s8 x s8 matmuls):
     logits stay close to the int8-weight/bf16-math path and greedy

@@ -13,6 +13,7 @@ compiles in this one file: the worker that runs it holds libtpu.
 
 import dataclasses
 import functools
+import math
 import re
 
 import jax
@@ -21,7 +22,7 @@ import pytest
 from seldon_tpu.models import init_params, slot, transformer
 from seldon_tpu.models.config import get_config
 from seldon_tpu.servers.engine import InferenceEngine
-from tools.inspect_hlo import big_instructions
+from tools.inspect_hlo import big_instructions, configuration
 
 SLOTS, WINDOW, STEPS = 32, 256, 4
 
@@ -51,9 +52,8 @@ def dense_config(kv_dtype):
     """A small homogeneous stack with the dense cells' heads of 128
     (4 KV heads: a row of 512 lanes), sized so that one layer's K over
     the slab (SLOTS x WINDOW x 512 = 4 Mi elements) is larger than any
-    weight matrix stacked over the 3 layers (3 Mi; the compiled chunk
-    copies three such stacks on entry, PERF.md section 7): an op that
-    large can only be cache."""
+    weight matrix stacked over the 3 layers (3 Mi): an op that large can
+    only be cache."""
     return dataclasses.replace(
         get_config("tiny"), d_model=1024, n_heads=8, head_dim=0, n_kv_heads=4,
         d_ff=1024,
@@ -86,6 +86,36 @@ def _compiled_chunk(cfg, one_chip, slots=SLOTS, window=WINDOW,
                           n_steps=STEPS),
         donate_argnums=(1,))
     return chunk.lower(params, state).compile().as_text(), state
+
+
+def _chip_branches(monkeypatch):
+    """The grouped product, state update and decode attention a TPU
+    takes: the program asks jax.default_backend(), the CPU here."""
+    from seldon_tpu.ops import decode_attention, moe_dispatch, ssm_update
+
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul", moe_dispatch._megablox)
+    monkeypatch.setattr(ssm_update, "update", ssm_update._pallas)
+    monkeypatch.setattr(decode_attention, "applies", decode_attention.reads)
+
+
+@pytest.fixture(scope="module")
+def published_chunk(one_chip):
+    """name -> (cfg, optimized HLO, state shapes) of the 4-step chunk of
+    a file of benchmark/configs as it states it, with the chip's
+    branches, over the cells' 64 slots x 1024; a configuration is
+    compiled once for the tests that read it (25 s to a minute each)."""
+    made = {}
+
+    def chunk(name):
+        if name not in made:
+            cfg, init = configuration(name)
+            with pytest.MonkeyPatch.context() as mp:
+                _chip_branches(mp)
+                made[name] = (cfg,) + _compiled_chunk(
+                    cfg, one_chip, 64, 1024, init=init)
+        return made[name]
+
+    return chunk
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -124,12 +154,7 @@ def test_decode_chunk_reads_the_slab_through_the_kernel(
     slab: no copy, transpose or slice of it, and no float32 score array
     [slots, heads, window] (what the einsums of gqa_attention_decode
     materialise a layer)."""
-    from seldon_tpu.ops import decode_attention, moe_dispatch, ssm_update
-
-    # the chip's branches (the program asks jax.default_backend())
-    monkeypatch.setattr(moe_dispatch, "grouped_matmul", moe_dispatch._megablox)
-    monkeypatch.setattr(ssm_update, "update", ssm_update._pallas)
-    monkeypatch.setattr(decode_attention, "applies", decode_attention.reads)
+    _chip_branches(monkeypatch)
     if stack == "patterned":
         # heads of 128, two to a row: a layer of K is 2 Mi elements, as
         # large as a layer of the SSM state and larger than any weight
@@ -159,11 +184,13 @@ def test_decode_chunk_reads_the_slab_through_the_kernel(
             and typ.split("]")[0].endswith(",%d" % WINDOW)] == []
 
 
+def _dims(typ: str):
+    return [int(d) for d in
+            typ[typ.index("[") + 1:typ.index("]")].split(",") if d]
+
+
 def _elements(typ: str) -> int:
-    n = 1
-    for d in typ[typ.index("[") + 1:typ.index("]")].split(","):
-        n *= int(d)
-    return n
+    return math.prod(_dims(typ))
 
 
 def mamba_config():
@@ -208,7 +235,7 @@ def test_decode_chunk_updates_the_ssm_state_in_place(one_chip, monkeypatch):
 
 
 def test_attention_and_mixer_in_one_layer_at_the_published_widths(
-        one_chip, monkeypatch):
+        published_chunk):
     """The benchmark's falcon-h1-34b-instruct as its file states it (20
     query heads over 4 KV heads of 128, a state of 32 x 128 x 256 float32
     a slot, hidden 5120, the 261120-row head) over the cell's 64 slots x
@@ -218,23 +245,10 @@ def test_attention_and_mixer_in_one_layer_at_the_published_widths(
     transpose or slice as large as a layer of the slab or of the state,
     nor the einsums' score array: KV and the SSM state of the SAME layer
     are read and written where they lie."""
-    import json
-    import os
-
-    from seldon_tpu.models.config import ModelConfig
-    from seldon_tpu.ops import decode_attention, ssm_update
-    from tests.test_falcon_h1 import ROOT, _family
-
-    monkeypatch.setattr(ssm_update, "update", ssm_update._pallas)
-    monkeypatch.setattr(decode_attention, "applies", decode_attention.reads)
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "falcon-h1-34b-instruct.json")) as f:
-        raw = json.load(f)
-    cfg = ModelConfig(**_family().model_config_kwargs(raw)).validate()
+    cfg, hlo, state = published_chunk("falcon-h1-34b-instruct")
     assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff,
             cfg.vocab_size) == (5120, 20, 4, 128, 21504, 261120)
     slots, window, L = 64, 1024, cfg.n_layers
-    hlo, state = _compiled_chunk(cfg, one_chip, slots, window)
     cache = state["cache"]
     assert cache["k"].shape == (L, slots, 1, window, 512)
     assert cache["ssm"].shape == (L, slots, 32, 128, 256)
@@ -244,9 +258,7 @@ def test_attention_and_mixer_in_one_layer_at_the_published_widths(
         r"%(ssm_update[.\w]*) = \(" + whole + r"\S* f32\[", hlo)) == 1
     layer_k = slots * window * 512
     # what is as large as a layer of K and shaped like the cache (the
-    # slots beside the window, or beside the state's block; the weights'
-    # relayouts on the chunk's entry, PERF.md section 7 k, are larger and
-    # are not the cache's)
+    # slots beside the window, or beside the state's block)
     cachelike = [
         (op, typ) for _, op, typ, _ in big_instructions(hlo, layer_k)
         if re.search(r"\b%d,(1,)?%d,512\]|\b%d,32,128,256\]"
@@ -262,7 +274,7 @@ def test_attention_and_mixer_in_one_layer_at_the_published_widths(
 
 
 def test_mixtral_chunk_hands_the_grouped_kernel_the_int8_stack_whole(
-        one_chip, monkeypatch):
+        published_chunk):
     """The benchmark's mixtral-8x7b as its file states it (8 experts of
     4096 x 14336, top-2, int8 weights, 5 layers) over the cell's 64 slots
     x 1024: Mosaic takes ops/gmm_int8 at these widths, the scan body
@@ -271,28 +283,10 @@ def test_mixtral_chunk_hands_the_grouped_kernel_the_int8_stack_whole(
     the group sizes), and the compiled chunk holds no copy, slice or
     widened twin as large as one layer's expert matrix stack: a step
     reads the experts its live rows chose and nothing else of them."""
-    import json
-    import os
-
-    from seldon_tpu.models.config import ModelConfig
-    from seldon_tpu.models.quantize import init_params_int8
-    from seldon_tpu.ops import decode_attention, moe_dispatch
-    from tests.test_falcon_h1 import ROOT
-
-    monkeypatch.setattr(moe_dispatch, "grouped_matmul", moe_dispatch._megablox)
-    monkeypatch.setattr(decode_attention, "applies", decode_attention.reads)
-    monkeypatch.syspath_prepend(os.path.join(ROOT, "benchmark"))
-    import family
-
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "mixtral-8x7b.json")) as f:
-        raw = json.load(f)
-    assert raw["serving"]["weight_dtype"] == "int8"
-    cfg = ModelConfig(**family.load(os.path.join(ROOT, "benchmark"), raw)
-                      .model_config_kwargs(raw)).validate()
+    cfg, hlo, _ = published_chunk("mixtral-8x7b")
     L, E, D, F = cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.d_ff
     assert (L, E, cfg.n_experts_per_token, D, F) == (5, 8, 2, 4096, 14336)
-    hlo, _ = _compiled_chunk(cfg, one_chip, 64, 1024, init=init_params_int8)
+    assert "s8[%d,%d,%d]" % (L, D, D) in hlo  # int8 weights, as stored
     calls = re.findall(r"%gmm_int8[.\d]* = bf16\[128,(\d+)\]\S* "
                        r"custom-call\(([^)]*)\)", hlo)
     assert sorted(n for n, _ in calls) == ["14336", "14336", "4096"], calls
@@ -310,3 +304,91 @@ def test_mixtral_chunk_hands_the_grouped_kernel_the_int8_stack_whole(
     one_matrix_stack = E * D * F
     assert [(op, typ) for _, op, typ, _ in
             big_instructions(hlo, one_matrix_stack)] == []
+
+
+def _as_stored(typ: str) -> bool:
+    """The result type's layout is the row-major one the tree is stored
+    in (whatever its tiling and memory space)."""
+    layout = re.search(r"\{([\d,]+)", typ)
+    return layout is None or layout.group(1) == ",".join(
+        str(i) for i in reversed(range(len(_dims(typ)))))
+
+
+def weight_copies(hlo: str):
+    """(result type, parameter) of every `copy` in the entry computation
+    that relays out a parameter of the layer stack: a pass over a whole
+    stored weight that the chip runs on every chunk's entry. (A small
+    stack copied into the chip's fast memory as it is stored is not
+    one.)"""
+    entry = hlo[hlo.index("\nENTRY "):]
+    return [(typ, name) for typ, name in re.findall(
+        r"= (\S+) copy\(%(params__(?:blocks|segments)\w*)",
+        entry[:entry.index("\n}")]) if not _as_stored(typ)]
+
+
+# A weight moved into the chip's fast memory ahead of its product, as it
+# is stored: one pass over it, which small matrices of any kind get.
+_PREFETCH = ("copy-start", "copy-done", "slice-start", "slice-done")
+
+
+def projection_matrices(hlo: str, cfg):
+    """(computation, op, result type) of every instruction outside a
+    fusion's inside that MAKES one layer's whole wq or wk, [d_model,
+    H*Dh] or [d_model, Hkv*Dh] in any order or split of the heads: a
+    dequantised, sliced-out or relaid-out matrix that is written and
+    read back in every layer of every step. A prefetch in the stored
+    layout is not one."""
+    outs = {cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim}
+    found = []
+    for comp, op, typ, _ in big_instructions(hlo, cfg.d_model * min(outs)):
+        dims = [d for d in _dims(typ) if d != 1]
+        if cfg.d_model in dims and not (op in _PREFETCH and _as_stored(typ)):
+            dims.remove(cfg.d_model)
+            if math.prod(dims) in outs:
+                found.append((comp, op, typ))
+    return found
+
+
+def unrotated_config():
+    """nemotron-3-nano-30b-a3b's kind: single-block layers whose
+    attention layers have no rotation between the projections and the
+    heads, 6 query heads over 2 KV heads of 128; no other matrix of the
+    stack has wq's [384, 768] or wk's [384, 256] elements beside
+    d_model."""
+    cfg = dataclasses.replace(
+        mamba_config(), d_model=384, n_heads=6, n_kv_heads=2, head_dim=128,
+        d_ff_expert=64).validate()
+    assert not cfg.rotary
+    return cfg
+
+
+@pytest.mark.parametrize("stack", [
+    "dense-int8", "dense-bf16", "unrotated", "mixtral-8x7b",
+    "falcon-h1-34b-instruct"])
+def test_decode_step_reads_the_query_and_key_weights_once(
+        one_chip, published_chunk, monkeypatch, stack):
+    """transformer._qkv keeps a decode step's wq and wk products flat, so
+    the compiled chunk takes the stored stack as the product's own
+    operand, dequantise or layer slice fused in, as wv, wo and the MLP
+    have it: (a) no copy of a parameter of the layer stack on the
+    chunk's entry and (b) no stand-alone instruction that yields a
+    layer's whole projection matrix. Unfenced, the reshape to heads
+    folded into the product cost both and three passes over wq a layer
+    (PERF.md section 6, PR 42)."""
+    from seldon_tpu.models.quantize import init_params_int8
+
+    if stack.startswith("dense-") or stack == "unrotated":
+        _chip_branches(monkeypatch)
+        # (the dense stack with no other matrix of wq's or wk's elements
+        # beside d_model: wq [1024, 1024], wk [1024, 512])
+        cfg = unrotated_config() if stack == "unrotated" \
+            else dataclasses.replace(dense_config("bf16"), d_ff=1536,
+                                     vocab_size=768).validate()
+        init = init_params_int8 if stack == "dense-int8" else init_params
+        hlo, _ = _compiled_chunk(cfg, one_chip, init=init)
+    else:
+        cfg, hlo, _ = published_chunk(stack)
+    # the reader's names are there
+    assert re.search(r"%params__(blocks|segments)\w*__wq__", hlo)
+    assert weight_copies(hlo) == []
+    assert projection_matrices(hlo, cfg) == []

@@ -58,6 +58,25 @@ def big_instructions(hlo: str, at_least: int):
     return out
 
 
+def configuration(name: str):
+    """(ModelConfig, the init that bears its tree) of a file of
+    benchmark/configs, by name, as the file states it."""
+    import family
+
+    from seldon_tpu.models import transformer
+    from seldon_tpu.models.config import ModelConfig
+    from seldon_tpu.models.quantize import init_params_int8
+
+    here = os.path.join(ROOT, "benchmark")
+    with open(os.path.join(here, "configs", name + ".json")) as f:
+        raw = json.load(f)
+    cfg = ModelConfig(
+        **family.load(here, raw).model_config_kwargs(raw)).validate()
+    init = init_params_int8 if raw["serving"]["weight_dtype"] == "int8" \
+        else transformer.init_params
+    return cfg, init
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("config", help="a file of benchmark/configs, by name")
@@ -68,14 +87,11 @@ def main(argv=None) -> int:
     os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
     os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
 
-    import family
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
     from seldon_tpu.models import slot, transformer
-    from seldon_tpu.models.config import ModelConfig
     from seldon_tpu.ops import decode_attention, moe_dispatch, ssm_update
-    from seldon_tpu.models.quantize import init_params_int8
     from seldon_tpu.servers import engine
     from seldon_tpu.servers.engine import InferenceEngine
 
@@ -84,11 +100,7 @@ def main(argv=None) -> int:
     moe_dispatch.grouped_matmul = moe_dispatch._megablox
     ssm_update.update = ssm_update._pallas
     decode_attention.applies = decode_attention.reads
-    here = os.path.join(ROOT, "benchmark")
-    with open(os.path.join(here, "configs", args.config + ".json")) as f:
-        raw = json.load(f)
-    cfg = ModelConfig(
-        **family.load(here, raw).model_config_kwargs(raw)).validate()
+    cfg, init = configuration(args.config)
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -100,8 +112,6 @@ def main(argv=None) -> int:
         return jax.tree.map(lambda a: shaped(a.shape, a.dtype),
                             jax.eval_shape(make))
 
-    init = init_params_int8 if raw["serving"]["weight_dtype"] == "int8" \
-        else transformer.init_params
     params = shapes(lambda: init(cfg, jax.random.key(0)))
     state = shapes(lambda: slot.fresh(
         transformer.init_cache(cfg, SLOTS, WINDOW), SLOTS))
