@@ -24,6 +24,10 @@ from typing import Optional, Tuple
 # Operator kinds of a patterned stack (the published `layer_types` names).
 OP_CONV = "conv"
 OP_ATTN = "full_attention"
+# Attention over the last `sliding_window` positions only (the query's own
+# among them), with a head count, a rotary table and a KV kind of its own:
+# a ring of `sliding_window` rows a slot (transformer.cache_spec).
+OP_SWA = "sliding_attention"
 # Attention and a Mamba-2 mixer reading the same normed input in parallel,
 # their outputs summed into the residual, then the dense feed-forward: the
 # one kind whose layer holds KV AND an SSM state.
@@ -33,9 +37,10 @@ OP_ATTN_MAMBA = "attention_mamba"
 OP_MAMBA = "mamba"
 OP_ATTN_ONLY = "attention"
 OP_MOE = "moe"
-FUSED_OPS = (OP_CONV, OP_ATTN, OP_ATTN_MAMBA)  # operator + feed-forward in one layer
+FUSED_OPS = (OP_CONV, OP_ATTN, OP_ATTN_MAMBA, OP_SWA)  # operator + feed-forward in one layer
 SINGLE_OPS = (OP_MAMBA, OP_ATTN_ONLY, OP_MOE)
 KV_OPS = (OP_ATTN, OP_ATTN_ONLY, OP_ATTN_MAMBA)  # the operators that hold KV
+WINDOW_OPS = (OP_SWA,)  # and those whose KV is a ring as long as the window
 SSM_OPS = (OP_MAMBA, OP_ATTN_MAMBA)  # and those that hold an SSM state
 
 
@@ -82,9 +87,10 @@ class ModelConfig:
     # RoPE frequency scaling (long-context checkpoints). Flat scalar
     # fields rather than a dict so the frozen config stays hashable.
     # rope_scaling_type: None (no scaling), "linear" (inv_freq / factor),
-    # or "llama3" (HF _compute_llama3_parameters: wavelengths past the
+    # "llama3" (HF _compute_llama3_parameters: wavelengths past the
     # original context window are divided by `factor`, with a smooth
-    # ramp between the low/high frequency knees). Llama-3.1/3.2
+    # ramp between the low/high frequency knees) or "yarn" (below, the
+    # patterned stack). Llama-3.1/3.2
     # checkpoints declare rope_type=llama3 — ignoring it would produce
     # subtly wrong logits at every position.
     rope_scaling_type: Optional[str] = None
@@ -164,6 +170,31 @@ class ModelConfig:
     ssm_mults: Tuple[float, ...] = ()
     mlp_gate_mult: float = 1.0
     mlp_down_mult: float = 1.0
+    # --- attention by kind ("sliding_attention" beside "full_attention") ---
+    # A sliding_attention layer's query sees key j from position i when
+    # j <= i and i - j < sliding_window; its KV is a ring of that many
+    # rows a slot. Its query heads (0 = n_heads; the KV heads are the
+    # stack's) and its plain rotary base over the whole head
+    # (0 = rope_theta); n_heads, rope_theta, rotary_share and the "yarn"
+    # scaling are the full_attention layers'.
+    sliding_window: int = 0
+    n_heads_window: int = 0
+    rope_theta_window: float = 0.0
+    # Share of a full_attention head's dims that rotate (the first
+    # rotary_share * head_dim, half-split pairing over those; the rest
+    # pass through).
+    rotary_share: float = 1.0
+    # rope_scaling_type "yarn" over the rotated dims: frequencies below
+    # the ramp [beta_fast, beta_slow] (rotations within
+    # rope_scaling_original_max_position) are divided by
+    # rope_scaling_factor, cos and sin are multiplied by
+    # rope_attention_factor (0 = 0.1 ln(factor) + 1).
+    rope_scaling_beta_fast: float = 32.0
+    rope_scaling_beta_slow: float = 1.0
+    rope_attention_factor: float = 0.0
+    # sigmoid(h Wa), one value a head from the layer's normed input, on
+    # each head's attention output before the output projection.
+    attn_gate: bool = False
 
     def __post_init__(self):
         for name in ("layer_types", "ssm_mults"):  # a list: stored as a tuple
@@ -220,6 +251,16 @@ class ModelConfig:
         return self._count(*KV_OPS)
 
     @property
+    def n_window_layers(self) -> int:
+        """Layers whose KV is the window's ring."""
+        return self._count(*WINDOW_OPS)
+
+    def heads(self, op: str) -> int:
+        """Query heads of an attention layer of kind `op`."""
+        return self.n_heads_window if op in WINDOW_OPS and \
+            self.n_heads_window else self.n_heads
+
+    @property
     def n_conv_layers(self) -> int:
         """Layers that hold a short-conv state."""
         return self._count(OP_CONV)
@@ -251,7 +292,8 @@ class ModelConfig:
         return self.n_heads // self.n_kv_heads
 
     def validate(self) -> "ModelConfig":
-        assert self.d_model % self.n_heads == 0, "d_model must divide by n_heads"
+        assert self.patterned or self.d_model % self.n_heads == 0, (
+            "d_model must divide by n_heads")
         assert self.n_heads % self.n_kv_heads == 0, "n_heads must divide by n_kv_heads"
         assert self.attn_impl in ("xla", "flash", "ring"), (
             f"unknown attn_impl {self.attn_impl!r}"
@@ -265,7 +307,7 @@ class ModelConfig:
         assert self.act_dtype in ("bf16", "int8"), (
             f"unknown act_dtype {self.act_dtype!r}"
         )
-        assert self.rope_scaling_type in (None, "linear", "llama3"), (
+        assert self.rope_scaling_type in (None, "linear", "llama3", "yarn"), (
             f"unknown rope_scaling_type {self.rope_scaling_type!r}"
         )
         assert self.router in ("softmax", "sigmoid"), (
@@ -307,6 +349,30 @@ class ModelConfig:
                     "ssm_mults is the five multipliers of a mixer's input "
                     "projection (z, x, B, C, dt): mamba or attention_mamba "
                     "layers only")
+            if self.n_window_layers:
+                assert self.sliding_window > 0 and self.n_attn_layers \
+                    and not self.single_blocks and not self.n_mamba_layers \
+                    and self.heads(OP_SWA) % self.n_kv_heads == 0, (
+                        "sliding_attention layers need sliding_window, at "
+                        "least one full_attention layer beside them (the "
+                        "slab as long as the engine's window), query heads "
+                        "that divide by n_kv_heads, and no Mamba-2 mixer "
+                        "or single-block layer in the stack: not built")
+                assert self.rotary and not self.qk_norm \
+                    and self.key_mult == 1.0, (
+                        "rotary=False, qk_norm and key_mult are not built "
+                        "for a stack with sliding_attention layers")
+            else:
+                assert not (self.sliding_window or self.n_heads_window
+                            or self.rope_theta_window or self.attn_gate
+                            or self.rotary_share != 1.0
+                            or self.rope_scaling_type == "yarn"), (
+                    "sliding_window / n_heads_window / rope_theta_window / "
+                    "attn_gate / rotary_share / yarn act in a stack with "
+                    "sliding_attention layers only")
+            assert 0.0 < self.rotary_share <= 1.0 and \
+                int(self.head_dim * self.rotary_share) % 2 == 0, (
+                    "rotary_share must leave an even number of rotated dims")
             assert self.kv_cache_dtype == "bf16" and \
                 self.weight_dtype == "bf16" and self.attn_impl == "xla", (
                     "a patterned stack (layer_types) is served in bf16 "
@@ -326,9 +392,16 @@ class ModelConfig:
                     and self.rotary and self.ff_act == "swiglu"
                     and not self.d_ff_shared and not self.n_experts_held
                     and not self.expert_first and not self.ssm_heads
-                    and all(m == 1.0 for m in self.multipliers)), (
+                    and all(m == 1.0 for m in self.multipliers)
+                    and not (self.sliding_window or self.n_heads_window
+                             or self.rope_theta_window or self.attn_gate)
+                    and self.rotary_share == 1.0
+                    and self.rope_scaling_type != "yarn"
+                    and self.router_scale == 1.0), (
                 "head_dim / rotary / ff_act / d_ff_shared / n_experts_held "
-                "/ expert_first / ssm_* / the *_mult multipliers need "
+                "/ expert_first / ssm_* / the *_mult multipliers / "
+                "sliding_window / n_heads_window / rope_theta_window / "
+                "attn_gate / rotary_share / yarn / router_scale need "
                 "layer_types (the patterned stack)"
             )
         assert self.ff_act in ("swiglu", "relu2"), (
@@ -463,6 +536,44 @@ PRESETS = {
                    0.7071067811865476),
         mlp_gate_mult=0.6,
         mlp_down_mult=0.2,
+    ),
+    # Window and full attention layers in one pattern at CPU-test size:
+    # one dense full layer, then one period (sliding x 3, full) of sparse
+    # layers; 6 query heads on the full kind and 8 on the window kind over
+    # 2 KV heads, a window of 8, half-rotated YaRN on the full kind and a
+    # plain table of another base on the window kind, the per-head gate,
+    # 16 experts top-4 scaled by 2.5 beside a shared one, untied head.
+    "tiny-laguna": ModelConfig(
+        vocab_size=256,
+        d_model=64,
+        n_layers=5,
+        n_heads=6,
+        n_heads_window=8,
+        n_kv_heads=2,
+        head_dim=16,
+        d_ff=128,
+        max_seq_len=128,
+        rope_theta=500000.0,
+        rope_theta_window=10000.0,
+        rotary_share=0.5,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=8.0,
+        rope_scaling_original_max_position=16,
+        rope_scaling_beta_fast=4.0,
+        rope_scaling_beta_slow=1.0,
+        rms_norm_eps=1e-6,
+        eos_token_id=1,
+        n_experts=16,
+        n_experts_per_token=4,
+        layer_types=("full_attention", "sliding_attention",
+                     "sliding_attention", "sliding_attention",
+                     "full_attention"),
+        n_dense_layers=1,
+        d_ff_expert=32,
+        d_ff_shared=32,
+        router_scale=2.5,
+        sliding_window=8,
+        attn_gate=True,
     ),
     # ~1.1B params: single v5e chip (16 GB HBM) with room for KV cache.
     "bench-1b": ModelConfig(

@@ -1,7 +1,9 @@
 """Decoder models, functional JAX, TPU-first: the homogeneous Llama-
 family stack and (cfg.layer_types) the patterned stack at the end of
 this file, whose layers differ in operator (short conv, attention, or
-attention and a Mamba-2 mixer in parallel) and feed-forward (dense or
+attention and a Mamba-2 mixer in parallel, or attention inside a
+sliding window beside full attention, each kind with its own head count,
+rotary table and cache length) and feed-forward (dense or
 token -> expert dispatch), or are one residual block each (a Mamba-2
 mixer, an attention or a sparse feed-forward alone).
 
@@ -37,11 +39,18 @@ from seldon_tpu.models.config import (
     OP_CONV,
     OP_MAMBA,
     OP_MOE,
+    OP_SWA,
     SSM_OPS,
+    WINDOW_OPS,
     ModelConfig,
 )
 from seldon_tpu.models.quantize import dequant
-from seldon_tpu.ops import decode_attention, moe_dispatch, ssm_update
+from seldon_tpu.ops import (
+    decode_attention,
+    moe_dispatch,
+    prefill_attention,
+    ssm_update,
+)
 
 Params = Dict[str, Any]
 
@@ -249,11 +258,59 @@ def rope_frequencies(cfg: ModelConfig) -> jnp.ndarray:
     return inv_freq
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: jnp.ndarray):
-    """x: [B, S, H, Dh], positions: [B, S] -> rotated x (half-split pairing)."""
+def rope_by_kind(cfg: ModelConfig, op: str):
+    """(inv_freq, factor on cos and sin) of an attention layer of kind
+    `op` in a stack whose kinds differ in them (cfg.n_window_layers).
+
+    A sliding_attention layer: the plain table of rope_theta_window over
+    the whole head. A full_attention layer: the first d = rotary_share x
+    head_dim dims rotate; with "yarn" over those d,
+    dim(r) = d ln(original / (2 pi r)) / (2 ln theta),
+    low = floor(dim(beta_fast)), high = ceil(dim(beta_slow)), both
+    clamped to [0, d - 1], ramp_i = clip((i - low) / (high - low), 0, 1),
+    inv_freq_i = theta^(-2i/d) x ((1 - ramp_i) + ramp_i / factor), and cos
+    and sin are multiplied by rope_attention_factor (0: 0.1 ln(factor) +
+    1), so the rotated halves' product carries its square and the
+    pass-through dims' none. Computed on the host in float64 (a constant
+    of the program), handed over in float32."""
+    if op in WINDOW_OPS:
+        half = cfg.head_dim // 2
+        theta = cfg.rope_theta_window or cfg.rope_theta
+        return jnp.asarray(
+            theta ** -(np.arange(half) / half), jnp.float32), 1.0
+    d = int(cfg.head_dim * cfg.rotary_share)
+    inv = cfg.rope_theta ** -(np.arange(d // 2) * 2.0 / d)
+    if cfg.rope_scaling_type != "yarn":
+        return jnp.asarray(inv, jnp.float32), 1.0
+    factor = cfg.rope_scaling_factor
+
+    def dim(rotations):
+        return d * np.log(cfg.rope_scaling_original_max_position
+                          / (2 * np.pi * rotations)) \
+            / (2 * np.log(cfg.rope_theta))
+
+    low = min(max(int(np.floor(dim(cfg.rope_scaling_beta_fast))), 0), d - 1)
+    high = min(max(int(np.ceil(dim(cfg.rope_scaling_beta_slow))), 0), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    mscale = cfg.rope_attention_factor or 0.1 * float(np.log(factor)) + 1.0
+    return jnp.asarray(inv * ((1 - ramp) + ramp / factor), jnp.float32), \
+        float(mscale)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: jnp.ndarray,
+               mscale: float = 1.0):
+    """x: [B, S, H, Dh], positions: [B, S] -> rotated x (half-split pairing
+    over the first 2 x len(inv_freq) dims; the rest pass through)."""
+    rot = 2 * inv_freq.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], positions, inv_freq, mscale),
+             x[..., rot:]], axis=-1)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B,S,half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -633,8 +690,11 @@ def _run_blocks(params, x, cfg, positions, inv_freq, mask,
     return x, None, jnp.mean(aux)
 
 
-def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
-    """`tp` (models/tp_sharding.TpHints, EngineConfig.tp > 1 only) pins
+def _qkv(h, bp, cfg, positions, inv_freq, tp=None, op=None):
+    """`op`: the layer's kind where a stack's attention kinds differ in
+    head count and rotary table (cfg.n_window_layers; rope_by_kind).
+
+    `tp` (models/tp_sharding.TpHints, EngineConfig.tp > 1 only) pins
     the projected heads sharded on 'tp': each device computes the FULL
     d_model contraction for its own disjoint head slice, so per-element
     reduction order — and hence the bits — match tp=1 exactly.
@@ -657,7 +717,7 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
     with jax.named_scope("attn/qkv"):
         hq = _quantize_act(h) if _w8a8_applies(bp, "wq", cfg) else None
         q = flat(_qdot(h, bp, "wq", cfg, act_q=hq)).reshape(
-            B, S, cfg.n_heads, Dh)
+            B, S, cfg.heads(op) if op else cfg.n_heads, Dh)
         k = _scaled(flat(_qdot(h, bp, "wk", cfg, act_q=hq)),
                     cfg.key_mult).reshape(B, S, Hkv, Dh)
         v = _qdot(h, bp, "wv", cfg, act_q=hq).reshape(B, S, Hkv, Dh)
@@ -666,8 +726,10 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None):
                 q = rms_norm(q, bp["q_norm"], cfg.rms_norm_eps)
                 k = rms_norm(k, bp["k_norm"], cfg.rms_norm_eps)
         if cfg.rotary:
-            q = apply_rope(q, positions, inv_freq)
-            k = apply_rope(k, positions, inv_freq)
+            rope = rope_by_kind(cfg, op) if cfg.n_window_layers \
+                else (inv_freq,)
+            q = apply_rope(q, positions, *rope)
+            k = apply_rope(k, positions, *rope)
         if tp is not None:
             q, k, v = tp.heads(q), tp.heads(k), tp.heads(v)
     return q, k, v
@@ -881,8 +943,11 @@ def _run_blocks_prefill_prefix(params, x, cfg, positions, inv_freq, mask,
     return x, {"k": ks, "v": vs}, jnp.mean(aux)
 
 
-def _sparse_decode(cfg, cache, live, pos, spread: bool):
-    """The decode step's work list for ops/decode_attention, or None
+def _sparse_decode(cfg, cache, live, pos, spread: bool, ring: bool = False):
+    """`ring`: the list of the sliding_attention layers, over their ring
+    "kw" (decode_attention.schedule), else:
+
+    The decode step's work list for ops/decode_attention, or None
     where its attention layers keep gqa_attention_decode's einsums over
     the whole layer: off a TPU, where the slab is `spread` over several
     devices (tensor parallelism: each device contracts its own lanes of
@@ -890,13 +955,13 @@ def _sparse_decode(cfg, cache, live, pos, spread: bool):
     over, which it cannot do to a kernel) and for a slab the kernel
     cannot read (decode_attention.applies). Made once a step: every
     layer walks the same live slots to the same positions."""
-    k = cache["k"]
+    k = cache["kw" if ring else "k"]
     block = 0 if spread else decode_attention.applies(k, cfg.head_dim)
     if not block:
         return None
     if live is None:
         live = jnp.ones(pos.shape, bool)
-    return decode_attention.schedule(live, pos, k.shape[3], block)
+    return decode_attention.schedule(live, pos, k.shape[3], block, ring)
 
 
 def decode_kv_counts(cfg, cache, live, pos, spread=False) -> jnp.ndarray:
@@ -908,9 +973,32 @@ def decode_kv_counts(cfg, cache, live, pos, spread=False) -> jnp.ndarray:
     La, B, _, T, _ = cache["k"].shape
     held = jnp.asarray(La * B * T, jnp.int32)
     sched = _sparse_decode(cfg, cache, live, pos, spread)
+    if "kw" in cache:
+        return _decode_kv_counts_by_kind(cfg, cache, live, pos, spread,
+                                         sched, held)
     if sched is None:
         return jnp.stack([held, held])
     return jnp.stack([La * decode_attention.tokens_read(sched), held])
+
+
+def _decode_kv_counts_by_kind(cfg, cache, live, pos, spread, sched, held):
+    """int32 [7] for a stack with sliding_attention layers: [read, held]
+    over both kinds (decode_kv_counts' two), then the window layers'
+    tokens read and held (their rings: slots x window x layers), what
+    those layers' live rows would have read without a window (their
+    positions), and the full layers' read and held."""
+    La, Lw, B, W = (cache["k"].shape[0], *cache["kw"].shape[:2],
+                    cache["kw"].shape[3])
+    read = held if sched is None else \
+        La * decode_attention.tokens_read(sched)
+    w_held = jnp.asarray(Lw * B * W, jnp.int32)
+    sched_w = _sparse_decode(cfg, cache, live, pos, spread, ring=True)
+    w_read = w_held if sched_w is None else \
+        Lw * decode_attention.tokens_read(sched_w)
+    alive = pos if live is None else jnp.where(live, pos, 0)
+    unwindowed = Lw * jnp.sum(alive).astype(jnp.int32)
+    return jnp.stack([read + w_read, held + w_held, w_read, w_held,
+                      unwindowed, read, held])
 
 
 def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
@@ -1061,7 +1149,8 @@ def forward(
         x = jax.lax.with_sharding_constraint(x, act_spec)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     inv_freq = rope_frequencies(cfg)
-    mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
+    mask = None if cfg.n_window_layers else \
+        jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
     if cfg.patterned:
         refuse_patterned(cfg, "sharded or rematerialised forward",
                           act_spec is not None or remat
@@ -1083,7 +1172,9 @@ def forward(
 
 class CacheEntry(NamedTuple):
     """One array of the per-slot cache: what it holds ("kv" = keys,
-    values and their int8 scales, one position per token; "conv" = the
+    values and their int8 scales, one position per token; "kv_window" =
+    the keys and values of the layers that attend inside a window, a
+    ring of the window's length a slot; "conv" = the
     short convolution's last inputs, "ssm" = a Mamba-2 mixer's state and
     "ssm_conv" = its convolution's last inputs, each a fixed size per
     slot), its shape
@@ -1139,7 +1230,19 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
     decode step reads and writes all of it, so the step updates it in
     place (_run_patterned_decode carries it whole through the layer
     scan). "ssm_conv" is [Lm, B, conv_kernel - 1, ssm_conv_dim]: the
-    mixer's [x | B | C] inputs of the last conv_kernel - 1 positions."""
+    mixer's [x | B | C] inputs of the last conv_kernel - 1 positions.
+
+    "kw" / "vw" (kind "kv_window") are the keys and values of the Lw
+    sliding_attention layers, [Lw, B, 1, W, Hkv * Dh] with W =
+    cfg.sliding_window whatever max_len: a RING, position p at row
+    p % W, so a slot holds the W newest positions and nothing older. W
+    rows are enough, with none added for a decode chunk's steps: every
+    step of a chunk writes its own token after its own read
+    (_run_patterned_decode), and the one row a step reads too many, the
+    row it is about to overwrite, which holds position p - W, is masked.
+    It has no token axis an admission could cut at a bucket's width: a
+    prefill hands over the ring whole (_ring_rows) and the scatter
+    replaces a slot's (time_axis None, as the fixed-size states)."""
     side = kv_heads_per_row(cfg)
     shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads // side, max_len,
              cfg.head_dim * side)
@@ -1161,6 +1264,13 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
         dt = dtype or _dtype(cfg)
         spec["k"] = CacheEntry("kv", shape, dt, 0, 3)
         spec["v"] = CacheEntry("kv", shape, dt, 0, 3)
+    if cfg.n_window_layers:
+        ring = (cfg.n_window_layers, batch, 1, cfg.sliding_window,
+                cfg.head_dim * cfg.n_kv_heads)
+        spec["kw"] = CacheEntry("kv_window", ring, dtype or _dtype(cfg), 0,
+                                None)
+        spec["vw"] = CacheEntry("kv_window", ring, dtype or _dtype(cfg), 0,
+                                None)
     if cfg.n_conv_layers:
         spec["conv"] = CacheEntry(
             "conv",
@@ -1188,8 +1298,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None) -> Cache:
 
 
 def cache_bytes(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, int]:
-    """Bytes of the per-slot cache by kind ({"kv": ..., "conv": ...,
-    "ssm": ..., "ssm_conv": ...}: the kinds the stack has)."""
+    """Bytes of the per-slot cache by kind ({"kv": ..., "kv_window": ...,
+    "conv": ..., "ssm": ..., "ssm_conv": ...}: the kinds the stack has)."""
     out: Dict[str, int] = {}
     for e in cache_spec(cfg, batch, max_len).values():
         n = jnp.dtype(e.dtype).itemsize
@@ -1603,10 +1713,12 @@ class Segment(NamedTuple):
     attn_start: int  # index of its first attention layer among those
     conv_start: int  # and of its first conv layer
     ssm_start: int = 0  # and of its first layer with a Mamba-2 mixer
+    window_start: int = 0  # and of its first sliding_attention layer
 
 
 _MAX_PERIOD = 8
-_FIXED_STATE = ("conv", "ssm", "ssm_conv")  # cache arrays without a token axis
+# cache arrays without a token axis: an admission replaces a slot's whole
+_FIXED_STATE = ("conv", "ssm", "ssm_conv", "kw", "vw")
 
 
 def _count_ops(kinds, *ops) -> int:
@@ -1627,7 +1739,7 @@ def layer_plan(cfg: ModelConfig) -> Tuple[Segment, ...]:
     front: at each layer the period (up to _MAX_PERIOD kinds) that
     repeats at least twice and covers most layers, else one layer."""
     kinds = [(cfg.op_kind(l), cfg.ff_sparse(l)) for l in range(cfg.n_layers)]
-    plan, i, n_attn, n_conv, n_ssm = [], 0, 0, 0, 0
+    plan, i, n_attn, n_conv, n_ssm, n_win = [], 0, 0, 0, 0, 0
     while i < len(kinds):
         best_p, best_r = 1, 1
         for p in range(1, min(_MAX_PERIOD, len(kinds) - i) + 1):
@@ -1637,7 +1749,8 @@ def layer_plan(cfg: ModelConfig) -> Tuple[Segment, ...]:
             if r >= 2 and p * r > best_p * best_r:
                 best_p, best_r = p, r
         period = tuple(kinds[i:i + best_p])
-        plan.append(Segment(period, best_r, i, n_attn, n_conv, n_ssm))
+        plan.append(Segment(period, best_r, i, n_attn, n_conv, n_ssm, n_win))
+        n_win += best_r * _count_ops(period, *WINDOW_OPS)
         n_attn += best_r * _count_ops(period, *KV_OPS)
         n_conv += best_r * _count_ops(period, OP_CONV)
         n_ssm += best_r * _count_ops(period, *SSM_OPS)
@@ -1647,6 +1760,8 @@ def layer_plan(cfg: ModelConfig) -> Tuple[Segment, ...]:
 
 def fixed_state_names(cfg: ModelConfig) -> str:
     """The fixed-size per-slot state this stack holds, in words."""
+    if cfg.n_window_layers:
+        return "the sliding_attention layers' ring of keys and values"
     if OP_ATTN_MAMBA in cfg.layer_types:
         return ("the attention_mamba layers' SSM and conv state (a Mamba-2 "
                 "mixer beside the attention in the same layer)")
@@ -1782,13 +1897,16 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
             if op == OP_ATTN_MAMBA:  # both mixers' weights in one layer
                 lp.update(attn_block(R))
                 lp.update(mamba_block(R))
-            elif op == OP_ATTN:
+            elif op in (OP_ATTN, OP_SWA):
+                Ht = cfg.heads(op)  # the kind's own head count
                 lp.update(
-                    wq=dense(R, D, H * Dh), wk=dense(R, D, Hkv * Dh),
+                    wq=dense(R, D, Ht * Dh), wk=dense(R, D, Hkv * Dh),
                     wv=dense(R, D, Hkv * Dh),
-                    wo=dense(R, H * Dh, D, scale=damp * (H * Dh) ** -0.5))
+                    wo=dense(R, Ht * Dh, D, scale=damp * (Ht * Dh) ** -0.5))
                 if cfg.qk_norm:
                     lp.update(q_norm=ones(R, Dh), k_norm=ones(R, Dh))
+                if cfg.attn_gate:  # [D, heads]: one value a head
+                    lp["wa"] = dense(R, D, Ht)
             else:
                 lp.update(
                     conv_in=dense(R, D, 3 * D),
@@ -1802,6 +1920,11 @@ def _init_params_patterned(cfg: ModelConfig, key: jax.Array) -> Params:
                 if cfg.router_bias:
                     lp["router_bias"] = dense(R, E, scale=0.05,
                                               dtype=jnp.float32)
+                if Fs:  # the shared expert beside the routed ones
+                    lp.update(
+                        shared_gate=dense(R, D, Fs), shared_up=dense(R, D, Fs),
+                        shared_down=dense(R, Fs, D,
+                                          scale=damp * Fs ** -0.5))
             else:
                 lp.update(
                     w_gate=dense(R, D, F, scale=D ** -0.5 / cfg.mlp_gate_mult),
@@ -2098,7 +2221,9 @@ def _segment_cache(cache, seg: Segment):
     among them: the decode step carries it whole (_run_patterned_decode)."""
     out = {}
     held = {"conv": ((OP_CONV,), seg.conv_start),
-            "ssm_conv": (SSM_OPS, seg.ssm_start)}
+            "ssm_conv": (SSM_OPS, seg.ssm_start),
+            "kw": (WINDOW_OPS, seg.window_start),
+            "vw": (WINDOW_OPS, seg.window_start)}
     for key, arr in cache.items():
         if key == "ssm":
             continue
@@ -2118,6 +2243,35 @@ def _unsegment(parts):
     return flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=0)
 
 
+def _gated(attn, h, lp, cfg):
+    """The per-head output gate (cfg.attn_gate): sigmoid(h Wa), one value
+    a head from the layer's normed input, on that head's attention output
+    attn [B, S, H * Dh]."""
+    if not cfg.attn_gate:
+        return attn
+    with jax.named_scope("attn/gate"):
+        g = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", h, _w(lp, "wa", h.dtype),
+            preferred_element_type=jnp.float32))
+        B, S, _ = attn.shape
+        return (attn.reshape(B, S, g.shape[-1], -1).astype(jnp.float32)
+                * g[..., None]).astype(attn.dtype).reshape(B, S, -1)
+
+
+def _ring_rows(x, plens, window: int):
+    """A prefill's keys or values [B, S, Hkv, Dh] as the ring holds them,
+    [B, 1, W, Hkv * Dh]: row s takes the newest position below the row's
+    own prompt length that is s modulo W. Rows no position has reached
+    yet (a prompt shorter than the window) take whatever lies at the
+    clamp: decode masks them (s >= pos)."""
+    B, S = x.shape[:2]
+    s = jnp.arange(window)[None, :]
+    at = plens[:, None] - 1 - (plens[:, None] - 1 - s) % window
+    rows = jnp.take_along_axis(
+        x.reshape(B, S, -1), jnp.clip(at, 0, S - 1)[:, :, None], axis=1)
+    return rows[:, None]
+
+
 def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
     """Every layer over whole sequences from position 0 (forward,
     prefill). Returns (x, fresh cache arrays by kind or {} when plens is
@@ -2130,7 +2284,8 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
     S = x.shape[1]
     live = None if plens is None else \
         jnp.arange(S)[None, :] < plens[:, None]
-    fresh = {"k": [], "v": [], "conv": [], "ssm": [], "ssm_conv": []}
+    fresh = {"k": [], "v": [], "conv": [], "ssm": [], "ssm_conv": [],
+             "kw": [], "vw": []}
     routing = jnp.zeros((routing_width(cfg),), jnp.int32)
     side = kv_heads_per_row(cfg)
     for seg, sp in zip(layer_plan(cfg), params["segments"]):
@@ -2139,7 +2294,7 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
         def body(carry, xs, seg=seg, experts=experts):
             x, routing = carry
             rep, lps = xs
-            ks, vs, cs, ss, scs = [], [], [], [], []
+            ks, vs, cs, ss, scs, kws, vws = [], [], [], [], [], [], []
             for lp, ex, (op, _) in zip(lps, experts, seg.kinds):
                 h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
                 if op == OP_ATTN_MAMBA:
@@ -2159,6 +2314,28 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
                     ss.append(st)
                     scs.append(cst)
                     routing = routing + _routing_counts(cfg, ssm=True)
+                elif cfg.n_window_layers and op in (OP_ATTN, OP_SWA):
+                    # a stack whose attention kinds differ: by blocks of
+                    # keys on both kinds (no S x S scores), inside the
+                    # window's band on the sliding layers
+                    windowed = op in WINDOW_OPS
+                    q, k, v = _qkv(h, lp, cfg, positions, inv_freq, op=op)
+                    with jax.named_scope(
+                            "attn/window" if windowed else "attn/full"):
+                        attn = prefill_attention.attend(
+                            q, k, v, plens, head_dim=cfg.head_dim,
+                            window=cfg.sliding_window if windowed else 0)
+                    attn = _gated(attn, h, lp, cfg)
+                    with jax.named_scope("attn/out"):
+                        x = x + _qdot(attn, lp, "wo", cfg)
+                    if plens is None:
+                        pass  # forward(): no cache is kept
+                    elif windowed:
+                        kws.append(_ring_rows(k, plens, cfg.sliding_window))
+                        vws.append(_ring_rows(v, plens, cfg.sliding_window))
+                    else:
+                        ks.append(_kv_slab(k, side))
+                        vs.append(_kv_slab(v, side))
                 elif op in KV_OPS:
                     q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
                     attn = gqa_attention(q, k, v, mask)
@@ -2191,6 +2368,8 @@ def _run_patterned_full(params, x, cfg, positions, inv_freq, mask, plens):
                     ys["conv"] = jnp.stack(cs)
                 if ss:
                     ys["ssm"], ys["ssm_conv"] = jnp.stack(ss), jnp.stack(scs)
+                if kws:
+                    ys["kw"], ys["vw"] = jnp.stack(kws), jnp.stack(vws)
             return (x, routing), ys
 
         (x, routing), ys = jax.lax.scan(
@@ -2214,14 +2393,30 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
     their frozen position: harmless, an admission overwrites all three
     before the slot is read again. On a TPU the attention layers read
     the live rows' tokens out of the whole slab by the layer's index
-    (_sparse_decode), and K and V do not ride the scans at all."""
+    (_sparse_decode), and K and V do not ride the scans at all.
+
+    A sliding_attention layer reads its ring "kw" / "vw" the same way,
+    pre-write: of the W rows, those a position has reached (s < pos) less
+    the row s = pos % W, which this step overwrites and which, once pos
+    >= W, still holds position pos - W, the one that has just left the
+    window; the fresh column makes the W-th key. Its fresh k/v land at
+    row pos % W in a scatter of their own after the scans."""
     Smax = cache["k"].shape[3]
     mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
     live2 = None if live is None else live[:, None]
     sched = _sparse_decode(cfg, cache, live, pos, spread)
     riding = cache if sched is None else \
         {key: arr for key, arr in cache.items() if key not in ("k", "v")}
-    fresh = {"k": [], "v": [], "conv": [], "ssm_conv": []}
+    fresh = {"k": [], "v": [], "conv": [], "ssm_conv": [], "kw": [], "vw": []}
+    sched_w = mask_w = None
+    if "kw" in cache:
+        W = cache["kw"].shape[3]
+        sched_w = _sparse_decode(cfg, cache, live, pos, spread, ring=True)
+        if sched_w is not None:
+            riding = {key: arr for key, arr in riding.items()
+                      if key not in ("kw", "vw")}
+        s_ = jnp.arange(W)[None, None, :]
+        mask_w = (s_ < pos[:, None, None]) & (s_ != (pos % W)[:, None, None])
     routing = jnp.zeros((routing_width(cfg),), jnp.int32)
     dt = cache["k"].dtype
     side = kv_heads_per_row(cfg)
@@ -2230,25 +2425,39 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
         sliced, experts = _split_experts(sp, cfg)
         nm = _count_ops(seg.kinds, *SSM_OPS)
         na = _count_ops(seg.kinds, *KV_OPS)
+        nw = _count_ops(seg.kinds, *WINDOW_OPS)
 
-        def body(carry, xs, seg=seg, experts=experts, nm=nm, na=na):
+        def body(carry, xs, seg=seg, experts=experts, nm=nm, na=na, nw=nw):
             x, routing, *ssm = carry
             rep, lps, cl = xs
-            ia = ic = im = 0
-            ks, vs, cs, scs = [], [], [], []
+            ia = ic = im = iw = 0
+            ks, vs, cs, scs, kws, vws = [], [], [], [], [], []
 
-            def attention(h, lp, ia):
-                """Attention layer `ia` of this repeat over the slab as
-                it was before this step, through the output projection;
-                the fresh k and v with it."""
-                q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
-                if sched is None:
-                    attn = gqa_attention_decode(
-                        q, cl["k"][ia], cl["v"][ia], k, v, mask_lt)
-                else:
-                    attn = decode_attention.attend(
-                        q, k, v, cache, seg.attn_start + rep * na + ia,
-                        sched)
+            def attention(h, lp, op, i):
+                """Attention layer `i` of its kind (`op`) in this repeat
+                over the cache as it was before this step: the window
+                kind over its ring, every other over the slab; through
+                the gate, where the stack has one, and the output
+                projection; the fresh k and v with it."""
+                q, k, v = _qkv(h, lp, cfg, positions, inv_freq, op=op)
+                windowed = op in WINDOW_OPS
+                with jax.named_scope(
+                        "attn/window" if windowed else "attn/full"):
+                    if windowed and sched_w is None:
+                        attn = gqa_attention_decode(
+                            q, cl["kw"][i], cl["vw"][i], k, v, mask_w)
+                    elif windowed:
+                        attn = decode_attention.attend(
+                            q, k, v, {"k": cache["kw"], "v": cache["vw"]},
+                            seg.window_start + rep * nw + i, sched_w)
+                    elif sched is None:
+                        attn = gqa_attention_decode(
+                            q, cl["k"][i], cl["v"][i], k, v, mask_lt)
+                    else:
+                        attn = decode_attention.attend(
+                            q, k, v, cache, seg.attn_start + rep * na + i,
+                            sched)
+                attn = _gated(attn, h, lp, cfg)
                 with jax.named_scope("attn/out"):
                     return _qdot(attn, lp, "wo", cfg), k, v
 
@@ -2267,7 +2476,8 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     # as _run_patterned_full: both read h, summed into x;
                     # the layer's k/v join the scatter after the scans,
                     # its SSM state rides the carry
-                    a, k, v = attention(_scaled(h, cfg.attn_in_mult), lp, ia)
+                    a, k, v = attention(_scaled(h, cfg.attn_in_mult), lp, op,
+                                        ia)
                     a = _scaled(a, cfg.attn_out_mult)
                     y, ssm, cst = mixer(h, lp, im, ssm)
                     with jax.named_scope("mixer/sum"):
@@ -2288,8 +2498,14 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     y, st = _sparse_ff(h, lp, ex, rep, cfg, live2)
                     x = x + y
                     routing = routing + _routing_counts(cfg, st)
+                elif op in WINDOW_OPS:
+                    a, k, v = attention(h, lp, op, iw)
+                    x = x + a
+                    kws.append(_kv_rows(k, side)[:, 0].astype(dt))
+                    vws.append(_kv_rows(v, side)[:, 0].astype(dt))
+                    iw += 1
                 elif op in KV_OPS:
-                    a, k, v = attention(h, lp, ia)
+                    a, k, v = attention(h, lp, op, ia)
                     x = x + a
                     ks.append(_kv_rows(k, side)[:, 0].astype(dt))
                     vs.append(_kv_rows(v, side)[:, 0].astype(dt))
@@ -2309,6 +2525,8 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                 ys["conv"] = jnp.stack(cs)
             if scs:
                 ys["ssm_conv"] = jnp.stack(scs)
+            if kws:
+                ys["kw"], ys["vw"] = jnp.stack(kws), jnp.stack(vws)
             return (x, routing, *ssm), ys
 
         (x, routing, *ssm), ys = jax.lax.scan(
@@ -2331,6 +2549,14 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     layers, rows[:, None], :, pos[:, None]].set(
                     jnp.swapaxes(_unsegment(fresh[key]), 0, 1),
                     unique_indices=True)
+        for key in ("kw", "vw"):  # the ring: position p at row p % W
+            if fresh[key]:
+                rings = jnp.arange(cache[key].shape[0])[None, :]
+                new_cache[key] = cache[key].at[
+                    rings, rows[:, None], :,
+                    (pos % cache[key].shape[3])[:, None]].set(
+                    jnp.swapaxes(_unsegment(fresh[key]), 0, 1),
+                    unique_indices=True)
     if fresh["conv"]:
         new_cache["conv"] = _unsegment(fresh["conv"])
     if ssm:
@@ -2347,7 +2573,10 @@ def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):
     B, S = tokens.shape
     x = _scaled(_embed_rows(params, tokens, _dtype(cfg)), cfg.embed_mult)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    mask = jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
+    # a stack with sliding_attention layers attends by blocks of keys and
+    # makes no S x S mask either (_run_patterned_full)
+    mask = None if cfg.n_window_layers else \
+        jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
     x, fresh, _ = _run_patterned_full(
         params, x, cfg, positions, rope_frequencies(cfg), mask, prompt_lens)
     new_cache = dict(cache)

@@ -38,6 +38,14 @@ live at position 0) are never written by the kernel: `attend` gives them
 the fresh token's value alone, which is what attention over no past is,
 so they are finite and nothing of a dead row reaches a live one.
 
+A layer whose KV is a RING as long as its window (cache_spec's
+"kv_window": row s holds the newest position that is s modulo the ring)
+is read by the same walk: its schedule counts min(pos, ring) tokens a
+slot and names the one row to leave out (`skip`: once pos has passed
+the ring's length, the row the step is about to overwrite holds the
+position that has just left the window). The softmax does not care in
+which order rows come.
+
 Like the other kernels of seldon_tpu/ops it never chooses interpret mode
 itself: `applies` is False off a TPU, the caller then keeps
 gqa_attention_decode, and tests run `attend` through
@@ -47,7 +55,7 @@ tests/pallas_interpret.py.
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -100,18 +108,26 @@ class Schedule(NamedTuple):
     pos: jnp.ndarray  # [B] int32
     has_past: jnp.ndarray  # [B] bool: live and past position 0
     block: int
+    skip: Optional[jnp.ndarray] = None  # [B] int32: a row left out (-1: none)
 
 
 def schedule(active: jnp.ndarray, pos: jnp.ndarray, window: int,
-             block: int) -> Schedule:
+             block: int, ring: bool = False) -> Schedule:
+    """`ring`: the layer holds `window` rows a slot, position p at row
+    p % window; a slot at pos reads min(pos, window) rows and not the
+    row pos % window once pos >= window (it holds position pos - window)."""
     has = active & (pos > 0)
+    skip = None
+    if ring:
+        skip = jnp.where(pos >= window, pos % window, -1).astype(jnp.int32)
+        pos = jnp.minimum(pos, window)
     per_slot = jnp.where(has, (pos + block - 1) // block, 0).astype(jnp.int32)
     ends = jnp.cumsum(per_slot)
     items = jnp.arange(pos.shape[0] * (window // block), dtype=jnp.int32)
     slot = jnp.minimum(jnp.searchsorted(ends, items, side="right"),
                        pos.shape[0] - 1).astype(jnp.int32)
     return Schedule(ends[-1:], slot, items - (ends - per_slot)[slot],
-                    pos.astype(jnp.int32), has, block)
+                    pos.astype(jnp.int32), has, block, skip)
 
 
 def tokens_read(sched: Schedule) -> jnp.ndarray:
@@ -120,9 +136,12 @@ def tokens_read(sched: Schedule) -> jnp.ndarray:
     return sched.n_items[0] * sched.block
 
 
-def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref,
-            q_ref, kf_ref, vf_ref, own_ref, *rest,
-            quantized: bool, block: int, scale: float):
+def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, *rest,
+            quantized: bool, block: int, scale: float, ring: bool):
+    skip_ref = None
+    if ring:  # one more prefetched scalar a slot: the row left out
+        skip_ref, *rest = rest
+    q_ref, kf_ref, vf_ref, own_ref, *rest = rest
     if quantized:  # K, V and their scales: four arrays in HBM, four buffers
         spread_ref, *rest = rest
     n_hbm = 4 if quantized else 2
@@ -176,6 +195,8 @@ def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref,
                             preferred_element_type=jnp.float32)
         cols = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = cols < p
+        if ring:
+            mask &= cols != skip_ref[b]
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -235,6 +256,7 @@ def attend(
     f32 = jnp.float32
     slab = [cache["k"], cache["v"]]
     quantized = "k_scale" in cache
+    ring = sched.skip is not None
     if quantized:
         # The layer's scales (1 MiB each at 64 x 1024 x 8), not the
         # whole arrays: the compiler takes a call to read its operands
@@ -267,9 +289,9 @@ def attend(
     with jax.named_scope("attn/scores"):
         out = pl.pallas_call(
             functools.partial(_kernel, quantized=quantized, block=block,
-                              scale=Dh ** -0.5),
+                              scale=Dh ** -0.5, ring=ring),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5, grid=(1,),
+                num_scalar_prefetch=5 + ring, grid=(1,),
                 in_specs=in_specs,
                 out_specs=whole((B, H, LANES)),
                 scratch_shapes=scratch),
@@ -279,7 +301,8 @@ def attend(
                 vmem_limit_bytes=48 * 1024 * 1024),
             name="decode_attention",
         )(jnp.reshape(layer, (1,)).astype(jnp.int32), sched.n_items,
-          sched.slot, sched.blk, sched.pos, *args, *slab)
+          sched.slot, sched.blk, sched.pos,
+          *((sched.skip,) if ring else ()), *args, *slab)
     with jax.named_scope("attn/out"):
         # a row's own head is the one segment of its tile that is not zero
         out = out.reshape(B, H, LANES // Dh, Dh).sum(axis=2)

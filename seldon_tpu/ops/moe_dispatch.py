@@ -15,7 +15,7 @@ sort behind the last group, no group covers them, and they come back as
 zeros.
 
 Two routers (`route`): "softmax" is Mixtral's (top-k of the logits,
-softmax over those k); "sigmoid" scores each expert by sigmoid(logit),
+softmax over those k, times a scale where the model has one); "sigmoid" scores each expert by sigmoid(logit),
 SELECTS on score + bias, WEIGHTS by the unbiased score, optionally
 renormalised over the k, times a scale.
 
@@ -91,8 +91,11 @@ def route(
     """(expert ids [N, k] int32, weights [N, k] float32)."""
     logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32), router_w)
     if router == "softmax":
+        # the softmax over all E renormalised over the chosen k is the
+        # softmax over those k
         top_vals, top_idx = jax.lax.top_k(logits, top_k)
-        return top_idx, jax.nn.softmax(top_vals, axis=-1)
+        w = jax.nn.softmax(top_vals, axis=-1)
+        return top_idx, w if scale == 1.0 else w * scale
     scores = jax.nn.sigmoid(logits)
     select = scores if bias is None else scores + bias[None, :]
     _, top_idx = jax.lax.top_k(select, top_k)

@@ -176,6 +176,13 @@ def _patterned_layer_params(cfg, experts_per_layer: int) -> int:
     d, hd = cfg.d_model, _head_dim(cfg)
     attn = d * (cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd) \
         + cfg.n_heads * hd * d
+    n_window = getattr(cfg, "n_window_layers", 0)
+    window = 0
+    if n_window:  # the window kind's own head count; the gate on both
+        hw = cfg.heads("sliding_attention")
+        window = d * (hw * hd + 2 * cfg.n_kv_heads * hd) + hw * hd * d \
+            + d * hw
+        attn += d * cfg.n_heads
     conv = d * 3 * d + d * d
     n_mamba = getattr(cfg, "n_mamba_layers", 0)
     mamba = 0
@@ -188,8 +195,8 @@ def _patterned_layer_params(cfg, experts_per_layer: int) -> int:
         else cfg.n_layers - sparse
     mats = 2 if getattr(cfg, "ff_act", "swiglu") == "relu2" else 3
     held = getattr(cfg, "experts_held", cfg.n_experts)
-    return (cfg.n_attn_layers * attn + cfg.n_conv_layers * conv
-            + n_mamba * mamba + dense * 3 * d * cfg.d_ff
+    return (cfg.n_attn_layers * attn + n_window * window
+            + cfg.n_conv_layers * conv + n_mamba * mamba + dense * 3 * d * cfg.d_ff
             + sparse * mats * d * (
                 min(experts_per_layer, held) * cfg.expert_width
                 + getattr(cfg, "d_ff_shared", 0)))
@@ -229,14 +236,27 @@ def flops_per_token(cfg, tp: int = 1) -> int:
                 + cfg.d_model * cfg.vocab_size)
 
 
+def _window_attn_flops(cfg, pairs: int) -> int:
+    """The sliding_attention layers' share of attention FLOPs over
+    `pairs` (query, key) pairs inside the window, at their own head
+    count (0 for a stack without such layers)."""
+    n_window = getattr(cfg, "n_window_layers", 0)
+    if not n_window:
+        return 0
+    return 4 * cfg.heads("sliding_attention") * _head_dim(cfg) * pairs \
+        * n_window
+
+
 def attn_flops(cfg, q_tokens: int, kv_len: int, tp: int = 1) -> int:
     """Attention-over-context FLOPs PER CHIP: q_tokens query positions
     each scoring + mixing kv_len cached positions across every layer —
     QK^T and PV are 2 flops per (head, dim, position) each, and GQA
     shares K/V without shrinking the query side: 4 * d_model * q * kv
     per layer. Heads shard on 'tp', so per-chip attention divides."""
-    return 4 * cfg.n_heads * _head_dim(cfg) * q_tokens * kv_len \
-        * _kv_layers(cfg) // tp
+    return (4 * cfg.n_heads * _head_dim(cfg) * q_tokens * kv_len
+            * _kv_layers(cfg)
+            + _window_attn_flops(cfg, q_tokens * min(
+                kv_len, getattr(cfg, "sliding_window", 0)))) // tp
 
 
 def causal_attn_flops(cfg, s_tokens: int, prior: int = 0,
@@ -245,8 +265,10 @@ def causal_attn_flops(cfg, s_tokens: int, prior: int = 0,
     attends prior + i + 1 positions — the arithmetic-series sum of
     attn_flops."""
     total_kv = s_tokens * prior + s_tokens * (s_tokens + 1) // 2
-    return 4 * cfg.n_heads * _head_dim(cfg) * total_kv \
-        * _kv_layers(cfg) // tp
+    w = min(getattr(cfg, "sliding_window", 0), s_tokens)
+    banded = w * (w + 1) // 2 + (s_tokens - w) * w  # j <= i, i - j < w
+    return (4 * cfg.n_heads * _head_dim(cfg) * total_kv * _kv_layers(cfg)
+            + _window_attn_flops(cfg, banded)) // tp
 
 
 def weight_bytes(cfg, tp: int = 1) -> int:
@@ -283,16 +305,29 @@ def kv_bytes_per_token(cfg, tp: int = 1) -> int:
     return 2 * _kv_layers(cfg) * cfg.n_kv_heads * hd * _kvbytes(cfg) // tp
 
 
+def window_bytes_per_slot(cfg) -> int:
+    """Bytes of one slot's rings: K and V of sliding_window positions in
+    each sliding_attention layer (cache_spec's "kv_window")."""
+    n_window = getattr(cfg, "n_window_layers", 0)
+    if not n_window:
+        return 0
+    return 2 * n_window * cfg.sliding_window * cfg.n_kv_heads \
+        * _head_dim(cfg) * _kvbytes(cfg)
+
+
 def state_bytes_per_slot(cfg) -> int:
     """Bytes of fixed-size per-slot state a decode step reads and writes
     whatever the context: a patterned stack's conv state (conv_kernel - 1
     inputs of d_model per conv layer, bf16) and its Mamba-2 layers'
     (ssm_heads x ssm_head_dim x ssm_state float32, and conv_kernel - 1
-    inputs of ssm_conv_dim, bf16), 0 otherwise. With kv_bytes_per_token
-    this is models/transformer.cache_spec, per kind."""
+    inputs of ssm_conv_dim, bf16), and the rings of its sliding_attention
+    layers (K and V of sliding_window positions, as long as the window
+    whatever the context: window_bytes_per_slot), 0 otherwise. With
+    kv_bytes_per_token this is models/transformer.cache_spec, per kind."""
     n_conv = getattr(cfg, "n_conv_layers", 0)
     n_mamba = getattr(cfg, "n_mamba_layers", 0)
     total = n_conv * (cfg.conv_kernel - 1) * cfg.d_model * 2 if n_conv else 0
+    total += window_bytes_per_slot(cfg)
     if n_mamba:
         total += n_mamba * (
             cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4

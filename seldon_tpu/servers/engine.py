@@ -616,6 +616,14 @@ class _DepthEstimator:
 SAMPLER_COUNTERS = ("sampler_steps", "sampler_drawn_steps",
                     "sampler_masked_steps")
 KV_COUNTERS = ("attn_kv_tokens_read", "attn_kv_tokens_held")
+# ... of a stack whose attention kinds differ (sliding_attention layers
+# beside full ones), right after those two, which stay the sums over both
+# kinds (transformer.decode_kv_counts): what the window layers read and
+# hold (their rings), what their live rows would have read without a
+# window (their positions), and what the full layers read and hold.
+WINDOW_COUNTERS = ("attn_window_tokens_read", "attn_window_tokens_held",
+                   "attn_window_tokens_unwindowed", "attn_full_tokens_read",
+                   "attn_full_tokens_held")
 MOE_COUNTERS = ("moe_sparse_layer_steps", "moe_experts_touched",
                 "moe_assignments")
 # ... and, after them, of a stack that holds a share of its experts or
@@ -624,6 +632,18 @@ MOE_COUNTERS = ("moe_sparse_layer_steps", "moe_experts_touched",
 SHARE_COUNTERS = ("moe_assignments_held", "ssm_layer_steps")
 CHUNK_COUNTERS = (SAMPLER_COUNTERS + KV_COUNTERS + MOE_COUNTERS
                   + SHARE_COUNTERS)
+
+
+def chunk_counter_names(cfg) -> Tuple[str, ...]:
+    """The names of a decode chunk's counts (_chunk_impl's fifth value)
+    for this model, in their order."""
+    names = SAMPLER_COUNTERS + KV_COUNTERS
+    if cfg.n_window_layers:
+        names += WINDOW_COUNTERS
+    if InferenceEngine._counts_routing(cfg):
+        names += (MOE_COUNTERS + SHARE_COUNTERS)[
+            :transformer.routing_width(cfg)]
+    return names
 
 
 class EngineStats:
@@ -664,6 +684,17 @@ class EngineStats:
         # where ops/decode_attention reads them alone.
         self.attn_kv_tokens_read = 0  # graftlint: guarded-by(lock) via(stats)
         self.attn_kv_tokens_held = 0  # graftlint: guarded-by(lock) via(stats)
+        # The same by attention kind, for a stack with sliding_attention
+        # layers (WINDOW_COUNTERS; 0 elsewhere): read / unwindowed on the
+        # window layers is what the window saves a decode step.
+        self.attn_window_tokens_read = 0  # graftlint: guarded-by(lock) via(stats)
+        self.attn_window_tokens_held = 0  # graftlint: guarded-by(lock) via(stats)
+        self.attn_window_tokens_unwindowed = 0  # graftlint: guarded-by(lock) via(stats)
+        self.attn_full_tokens_read = 0  # graftlint: guarded-by(lock) via(stats)
+        self.attn_full_tokens_held = 0  # graftlint: guarded-by(lock) via(stats)
+        # Prompt tokens admitted, by the bucket their admission group was
+        # padded to ({bucket: tokens}; the cold dense admission).
+        self.attn_prefill_tokens: Dict[int, int] = {}  # graftlint: guarded-by(lock) via(stats)
         # What routing did in decode (models that dispatch tokens to
         # experts; _note_chunk_counts): sparse layers run over all decode
         # steps, distinct experts those layers read for live rows
@@ -882,7 +913,9 @@ class EngineStats:
                 ),
                 "decode_dispatches": self.decode_dispatches,
                 "decode_steps": self.decode_steps,
-                **{name: getattr(self, name) for name in CHUNK_COUNTERS},
+                **{name: getattr(self, name)
+                   for name in CHUNK_COUNTERS + WINDOW_COUNTERS},
+                "attn_prefill_tokens": dict(self.attn_prefill_tokens),
                 "prefix_hits": self.prefix_hits,
                 "prefix_tokens_saved": self.prefix_tokens_saved,
                 "prefix_evictions": self.prefix_evictions,
@@ -1122,6 +1155,7 @@ class InferenceEngine:
         # Largest power of two <= min(max_admit, max_slots).
         ma = max(1, min(self.ecfg.max_admit, B))
         self._max_admit = 1 << (ma.bit_length() - 1)
+        self._chunk_counters = chunk_counter_names(self.cfg)
 
         # Context-parallel prefill: with attn_impl=="ring" and a mesh
         # carrying a real 'sp' axis, admissions prefill with the prompt
@@ -1477,7 +1511,9 @@ class InferenceEngine:
                 self._hbm.gauge("kv_live", self._hbm_kv_live_bytes)
                 self._hbm.gauge("prefix_cache", self._hbm_prefix_bytes)
                 for kind, nbytes in self.cache_bytes().items():
-                    if kind != "kv":  # a fixed-size state, by its kind
+                    if kind == "kv_window":  # the window layers' rings
+                        self._hbm.set_static(kind, nbytes)
+                    elif kind != "kv":  # a fixed-size state, by its kind
                         self._hbm.set_static(kind + "_state", nbytes)
             else:
                 # Per-device accounting on the mesh: weights are priced
@@ -1545,11 +1581,15 @@ class InferenceEngine:
             return
         e = self.ecfg
         state = "SSM state" if self.cfg.n_mamba_layers else "conv state"
+        if self.cfg.n_window_layers:
+            state = ("sliding_attention layers' ring of keys and values "
+                     "(the window kind of KV)")
         both = f", both in each {OP_ATTN_MAMBA} layer" \
             if OP_ATTN_MAMBA in self.cfg.layer_types else ""
         asked = [
             name for name, on in (
-                ("paged_kv (the block pool holds KV only)", e.paged_kv),
+                ("paged_kv (the block pool holds KV only, every layer's as "
+                 "long as the window)", e.paged_kv),
                 (f"prefix_cache (a reused prefix carries no {state})",
                  e.prefix_cache),
                 (f"chunked_prefill (a chunk would have to resume the {state})",
@@ -2257,7 +2297,8 @@ class InferenceEngine:
 
     def cache_bytes(self) -> Dict[str, int]:
         """Bytes of the slot cache by kind: {"kv": ...} and, for a
-        patterned stack, {"conv": ...} or {"ssm": ..., "ssm_conv": ...}
+        patterned stack, {"conv": ...}, {"ssm": ..., "ssm_conv": ...} or
+        {"kv_window": ...} (the sliding_attention layers' rings)
         (transformer.cache_spec's kinds; the paged pool is all KV).
         Shape metadata — no sync."""
         if self._paged:
@@ -3004,7 +3045,10 @@ class InferenceEngine:
             if self._pilot is not None and self._shed_expired_head():
                 continue  # expired head must not displace a viable one
             key = self._admit_key(self._waiting[0])
-            max_g = min(self._admit_cap(), len(self._free))
+            # ... and bounded by its tokens (the lattice's own rule)
+            max_g = min(self._admit_cap(), len(self._free),
+                        shape_lattice.admit_cap(
+                            self._max_admit, self.ecfg.max_slots, key[0]))
             group: List[_Request] = []
             reserved = 0
             shed = False
@@ -3112,6 +3156,11 @@ class InferenceEngine:
                 self.stats.sched_bucket_pad_tokens += bpad
                 self.stats.sched_group_pad_tokens += gpad
         self._record_first_dispatch(group)
+        if not (Pb or self._paged):
+            with self.stats.lock:
+                self.stats.attn_prefill_tokens[Sb] = \
+                    self.stats.attn_prefill_tokens.get(Sb, 0) \
+                    + sum(len(r.tokens) for r in group)
         for req in group:
             req.slot = self._free.pop()
             req.expected = 1  # the admission samples the first token
@@ -4365,7 +4414,7 @@ class InferenceEngine:
         on the device (the last value of each, CHUNK_COUNTERS' order;
         came to the host in the boundary's own fetch) into the stats."""
         with self.stats.lock:
-            for name, v in zip(CHUNK_COUNTERS, chunk_data[3]):
+            for name, v in zip(self._chunk_counters, chunk_data[3]):
                 setattr(self.stats, name, getattr(self.stats, name) + int(v))
 
     def _process_chunk(self, toks_h, valid_h, active_h, roster) -> None:  # graftlint: holds(_book)
@@ -4514,13 +4563,13 @@ class InferenceEngine:
         # model that dispatches tokens to experts): two lines'
         # difference is what the sampler, attention and routing did in
         # decode between them.
-        names = SAMPLER_COUNTERS + KV_COUNTERS
-        if self._counts_routing(self.cfg):
-            names = CHUNK_COUNTERS[:len(names)
-                                   + transformer.routing_width(self.cfg)]
         with self.stats.lock:
             phases.update({name: getattr(self.stats, name)
-                           for name in names})
+                           for name in self._chunk_counters})
+            if self.cfg.n_window_layers:  # long buckets: where prompts went
+                phases["attn_prefill_tokens"] = {
+                    str(b): n for b, n in
+                    sorted(self.stats.attn_prefill_tokens.items())}
         access_log.info("request %s", json.dumps({
             "rid": req.rid,
             "outcome": req.outcome or "ok",

@@ -40,7 +40,9 @@ Expected category names: "weights", "kv_cache" (static reservation),
 "workspace", and for a model with a patterned stack "conv_state" (the
 conv layers' fixed-size per-slot state, static) or "ssm_state" and
 "ssm_conv_state" (a Mamba-2 mixer's float32 state and its
-convolution's inputs); "kv_cache" then counts the attention layers
+convolution's inputs) or "kv_window" (the rings of keys and values of
+the layers that attend inside a sliding window: slots x window, not
+slots x max_seq_len); "kv_cache" then counts the full attention layers
 only — the engine reads them all from the one per-kind cache spec,
 models/transformer.cache_spec.
 

@@ -40,6 +40,7 @@ from seldon_tpu.servers.engine import (
     KV_COUNTERS,
     MOE_COUNTERS,
     SHARE_COUNTERS,
+    WINDOW_COUNTERS,
     EngineConfig,
     InferenceEngine,
     access_log,
@@ -625,7 +626,9 @@ class JAXServer(SeldonComponent):
             "mesh_devices": [int(d.id) for d in self.mesh.devices.flat],
             "device": device.describe(),
             # Bytes of the per-slot cache by kind (transformer.cache_spec:
-            # "kv" over the layers that hold KV, "conv" the fixed-size
+            # "kv" over the layers that hold KV, "kv_window" the rings of
+            # the layers that attend inside a window (as long as the
+            # window, not as max_seq_len), "conv" the fixed-size
             # state of a patterned stack's conv layers, "ssm" and
             # "ssm_conv" that of its Mamba-2 layers).
             "cache_bytes": self.engine.cache_bytes(),
@@ -1057,7 +1060,12 @@ class JAXServer(SeldonComponent):
                   ("masked", s["sampler_masked_steps"]))),
             *({"type": "GAUGE", "key": "jaxserver_" + name,
                "value": float(s[name])}
-              for name in KV_COUNTERS + MOE_COUNTERS + SHARE_COUNTERS),
+              for name in KV_COUNTERS + WINDOW_COUNTERS + MOE_COUNTERS
+              + SHARE_COUNTERS),
+            # Prompt tokens admitted, by the bucket the group was padded to.
+            *({"type": "GAUGE", "key": "jaxserver_attn_prefill_tokens",
+               "value": float(n), "tags": {"bucket": str(b)}}
+              for b, n in sorted(s["attn_prefill_tokens"].items())),
             {"type": "GAUGE", "key": "jaxserver_prefix_hits",
              "value": float(s["prefix_hits"])},
             {"type": "GAUGE", "key": "jaxserver_prefix_tokens_saved",

@@ -187,6 +187,22 @@ def _min_prefix(spec: LatticeSpec, pb: int) -> int:
     return max(lo, spec.prefix_block)
 
 
+# An admission group holds at most max_admit rows of THIS many tokens
+# (group size x prompt bucket), however many requests wait: the largest
+# group every stack has been sized beside is admit/1024/8 (AOT), so a
+# longer bucket forms smaller groups (4 at 2048, 2 at 4096) whose
+# activations, and the assignment list of a sparse layer's dispatch, k
+# rows a token, are as large and no larger. Groups of buckets up to 1024
+# form as they did (engine._dispatch_admits applies it).
+ADMIT_ROW_TOKENS = 1024
+
+
+def admit_cap(max_admit: int, max_slots: int, sb: int) -> int:
+    """Largest admission group (rows, before padding) of suffix bucket sb."""
+    return min(max_admit, max_slots,
+               max(1, max_admit * ADMIT_ROW_TOKENS // sb))
+
+
 def _group_rungs(gmax: int) -> List[int]:
     """Padded group sizes produced by groups of 1..gmax rows: the
     engine pads to the next power of two (duplicating the tail row), so
@@ -273,10 +289,10 @@ def dispatch_keys(spec: LatticeSpec) -> Set[Key]:
                     keys.add(("seed-prefix", w))
         return keys
 
-    groups = _group_rungs(min(spec.max_admit, spec.max_slots))
     if spec.paged:
         for sb in spec.buckets:
-            for g in groups:
+            for g in _group_rungs(admit_cap(
+                    spec.max_admit, spec.max_slots, sb)):
                 keys.add(("admit-paged", sb, g, 0))
                 if not spec.prefix:
                     continue
@@ -287,7 +303,8 @@ def dispatch_keys(spec: LatticeSpec) -> Set[Key]:
         return keys
 
     for sb in spec.buckets:
-        for g in groups:
+        for g in _group_rungs(admit_cap(
+                    spec.max_admit, spec.max_slots, sb)):
             keys.add(("admit", sb, g))
     if spec.prefix:
         for pb in spec.buckets:
@@ -297,7 +314,8 @@ def dispatch_keys(spec: LatticeSpec) -> Set[Key]:
             for sb in spec.buckets:
                 if mp + _prev(spec.buckets, sb) + 1 > maxp:
                     continue
-                for g in groups:
+                for g in _group_rungs(admit_cap(
+                    spec.max_admit, spec.max_slots, sb)):
                     keys.add(("admit-prefix", pb, sb, g))
     return keys
 
@@ -350,8 +368,8 @@ def simulate_keys(spec: LatticeSpec) -> Set[Key]:
         return [0] + list(range(spec.prefix_block, plen,
                                 spec.prefix_block))
 
-    def admit_groups() -> List[int]:
-        gmax = min(spec.max_admit, spec.max_slots)
+    def admit_groups(sb: int) -> List[int]:
+        gmax = admit_cap(spec.max_admit, spec.max_slots, sb)
         return sorted({pow2ceil(g) for g in range(1, gmax + 1)})
 
     if spec.chunked:
@@ -382,14 +400,14 @@ def simulate_keys(spec: LatticeSpec) -> Set[Key]:
             sb = _bucket(spec.buckets, smax, plen - p0)
             if spec.paged:
                 w = _bucket(spec.buckets, smax, p0) if p0 else 0
-                for g in admit_groups():
+                for g in admit_groups(sb):
                     keys.add(("admit-paged", sb, g, w))
             elif p0:
                 pb = _bucket(spec.buckets, smax, p0)
-                for g in admit_groups():
+                for g in admit_groups(sb):
                     keys.add(("admit-prefix", pb, sb, g))
             else:
-                for g in admit_groups():
+                for g in admit_groups(sb):
                     keys.add(("admit", sb, g))
     return keys
 

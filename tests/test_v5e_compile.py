@@ -392,3 +392,49 @@ def test_decode_step_reads_the_query_and_key_weights_once(
     assert re.search(r"%params__(blocks|segments)\w*__wq__", hlo)
     assert weight_copies(hlo) == []
     assert projection_matrices(hlo, cfg) == []
+
+
+@pytest.mark.parametrize("heads,window,G,S", [
+    (64, 512, 2, 4096), (48, 0, 2, 4096), (64, 512, 8, 512), (48, 0, 1, 32)],
+    ids=["band-64-heads-4096", "causal-48-heads-4096", "band-group-of-8", "bucket-32"])
+def test_prefill_attention_kernel_compiles_at_the_published_heads(
+        one_chip, heads, window, G, S):
+    """Mosaic takes ops/prefill_attention's kernel at laguna-xs.2's two
+    kinds (64 query heads inside a window of 512, 48 causal, over 8 KV
+    heads of 128) at the buckets an admission reaches, and the call holds
+    no array beside its operands: nothing S x S exists (PR 43)."""
+    import jax.numpy as jnp
+
+    from seldon_tpu.ops import prefill_attention as pa
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(
+        pa.kernel, head_dim=128, window=window)).lower(
+        shaped((G, S, heads * 128)), shaped((G, S, 1024)), shaped((G, S, 1024)),
+        shaped((G,), jnp.int32)).compile()
+    assert pa.KERNEL_NAME in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < G * S * S  # a byte a pair, at most
+
+
+def test_decode_kernel_compiles_over_a_ring_with_its_skipped_row(one_chip):
+    """ops/decode_attention over a window layer's ring (32 slots x 512
+    rows of 1024 lanes, 64 query heads): the sixth prefetched scalar, the
+    row a slot leaves out, goes through Mosaic."""
+    import jax.numpy as jnp
+
+    from seldon_tpu.ops import decode_attention as da
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, kf, vf, k, v, active, pos):
+        sched = da.schedule(active, pos, 512, da.reads(k, 128), ring=True)
+        return da.attend(q, kf, vf, {"k": k, "v": v}, jnp.asarray(1), sched)
+
+    compiled = jax.jit(step).lower(
+        shaped((32, 1, 64, 128)), shaped((32, 1, 8, 128)), shaped((32, 1, 8, 128)),
+        shaped((3, 32, 1, 512, 1024)), shaped((3, 32, 1, 512, 1024)),
+        shaped((32,), jnp.bool_), shaped((32,), jnp.int32)).compile()
+    assert "decode_attention" in compiled.as_text()

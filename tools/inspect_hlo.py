@@ -1,6 +1,7 @@
 """What the TPU v5e's compiler makes of the engine's own programs, with
 no chip: AOT-compile `_chunk_impl` (4 steps) or `_admit_impl` of a
-benchmark configuration at the cells' 64 slots x 1024 for the described
+benchmark configuration at the cells' 64 slots x 1024 (--slots, --window:
+laguna.code runs 32 x 4096) for the described
 topology "v5e:2x2" (libtpu compiles for a chip that is not attached;
 .claude/skills/verify/SKILL.md), print `memory_analysis()` and list the
 instructions of the entry and loop computations (not the insides of
@@ -82,6 +83,9 @@ def main(argv=None) -> int:
     ap.add_argument("config", help="a file of benchmark/configs, by name")
     ap.add_argument("program", help="chunk, or admit/<bucket>/<group>")
     ap.add_argument("--dump", help="write the optimized HLO text here")
+    ap.add_argument("--slots", type=int, default=SLOTS)
+    ap.add_argument("--window", type=int, default=WINDOW,
+                    help="the engine's max_seq_len (laguna.code: 32 x 4096)")
     args = ap.parse_args(argv)
     # quiets libtpu's search for a host it is not on
     os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
@@ -91,7 +95,8 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from seldon_tpu.models import slot, transformer
-    from seldon_tpu.ops import decode_attention, moe_dispatch, ssm_update
+    from seldon_tpu.ops import (decode_attention, moe_dispatch,
+                                prefill_attention, ssm_update)
     from seldon_tpu.servers import engine
     from seldon_tpu.servers.engine import InferenceEngine
 
@@ -100,6 +105,7 @@ def main(argv=None) -> int:
     moe_dispatch.grouped_matmul = moe_dispatch._megablox
     ssm_update.update = ssm_update._pallas
     decode_attention.applies = decode_attention.reads
+    prefill_attention.applies = prefill_attention.fits
     cfg, init = configuration(args.config)
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
@@ -114,7 +120,7 @@ def main(argv=None) -> int:
 
     params = shapes(lambda: init(cfg, jax.random.key(0)))
     state = shapes(lambda: slot.fresh(
-        transformer.init_cache(cfg, SLOTS, WINDOW), SLOTS))
+        transformer.init_cache(cfg, args.slots, args.window), args.slots))
     if args.program == "chunk":
         fn = engine._named_partial(InferenceEngine._chunk_impl, cfg=cfg,
                                    n_steps=STEPS)
@@ -138,7 +144,7 @@ def main(argv=None) -> int:
           f"temporaries {ma.temp_size_in_bytes / 1e9:.3f} GB, arguments "
           f"{ma.argument_size_in_bytes / 1e9:.3f} GB, of which aliased to "
           f"outputs {ma.alias_size_in_bytes / 1e9:.3f} GB")
-    layer_k = SLOTS * WINDOW * cfg.n_kv_heads * cfg.head_dim
+    layer_k = args.slots * args.window * cfg.n_kv_heads * cfg.head_dim
     counts = {}
     for comp, op, typ, _ in big_instructions(hlo, layer_k // 4):
         counts[comp[:32], op, typ] = counts.get((comp[:32], op, typ), 0) + 1
