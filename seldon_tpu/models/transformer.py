@@ -965,60 +965,71 @@ def _sparse_decode(cfg, cache, live, pos, spread: bool, ring: bool = False):
 
 
 def decode_kv_counts(cfg, cache, live, pos, spread=False) -> jnp.ndarray:
-    """int32 [2]: KV tokens the attention layers of one decode step
+    """int32 [4]: KV tokens the attention layers of one decode step
     read, and KV tokens the slab holds for them (slots x window x
     attention layers); their ratio is the share of the slab a step
     touches. The einsums read all they hold; the kernel whole blocks of
-    the live slots up to their positions (_sparse_decode)."""
+    the live slots up to their positions (_sparse_decode). Then the K
+    rows the step wrote (slab and rings) and slots x those layers: the
+    scatter after the einsums writes a row of every slot, the kernel
+    the live slots' (one whose position has reached the slab's end
+    writes none)."""
     La, B, _, T, _ = cache["k"].shape
     held = jnp.asarray(La * B * T, jnp.int32)
     sched = _sparse_decode(cfg, cache, live, pos, spread)
+    alive = jnp.ones(pos.shape, bool) if live is None else live
+    written = jnp.asarray(La * B, jnp.int32) if sched is None else \
+        La * jnp.sum(alive & (pos < T), dtype=jnp.int32)
     if "kw" in cache:
-        return _decode_kv_counts_by_kind(cfg, cache, live, pos, spread,
-                                         sched, held)
-    if sched is None:
-        return jnp.stack([held, held])
-    return jnp.stack([La * decode_attention.tokens_read(sched), held])
+        return _decode_kv_counts_by_kind(cfg, cache, alive, pos, spread,
+                                         sched, held, written)
+    read = held if sched is None else La * decode_attention.tokens_read(sched)
+    return jnp.stack([read, held, written, jnp.asarray(La * B, jnp.int32)])
 
 
-def _decode_kv_counts_by_kind(cfg, cache, live, pos, spread, sched, held):
-    """int32 [7] for a stack with sliding_attention layers: [read, held]
-    over both kinds (decode_kv_counts' two), then the window layers'
-    tokens read and held (their rings: slots x window x layers), what
-    those layers' live rows would have read without a window (their
-    positions), and the full layers' read and held."""
+def _decode_kv_counts_by_kind(cfg, cache, alive, pos, spread, sched, held,
+                              written):
+    """int32 [9] for a stack with sliding_attention layers: decode_kv_counts'
+    four over both kinds, then the window layers' tokens read and held
+    (their rings: slots x window x layers), what those layers' live rows
+    would have read without a window (their positions), and the full
+    layers' read and held."""
     La, Lw, B, W = (cache["k"].shape[0], *cache["kw"].shape[:2],
                     cache["kw"].shape[3])
     read = held if sched is None else \
         La * decode_attention.tokens_read(sched)
     w_held = jnp.asarray(Lw * B * W, jnp.int32)
-    sched_w = _sparse_decode(cfg, cache, live, pos, spread, ring=True)
+    sched_w = _sparse_decode(cfg, cache, alive, pos, spread, ring=True)
     w_read = w_held if sched_w is None else \
         Lw * decode_attention.tokens_read(sched_w)
-    alive = pos if live is None else jnp.where(live, pos, 0)
-    unwindowed = Lw * jnp.sum(alive).astype(jnp.int32)
-    return jnp.stack([read + w_read, held + w_held, w_read, w_held,
+    w_written = jnp.asarray(Lw * B, jnp.int32) if sched_w is None else \
+        Lw * jnp.sum(alive, dtype=jnp.int32)
+    unwindowed = Lw * jnp.sum(jnp.where(alive, pos, 0)).astype(jnp.int32)
+    return jnp.stack([read + w_read, held + w_held, written + w_written,
+                      jnp.asarray((La + Lw) * B, jnp.int32), w_read, w_held,
                       unwindowed, read, held])
 
 
 def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
                        act_spec=None, tp=None, live=None, spread=False):
     """Layer scan for DECODE: the cache is read PRE-write (attention
-    handles the current token via an exact fresh column) and all L
-    layers' fresh k/v are written back AFTER the scan in one batched
-    scatter. Two ways to read it, told by what is there to see
-    (_sparse_decode): on a TPU, with the dense slab whole on one device,
-    the scan rides on the layer's INDEX and ops/decode_attention reads
-    the live rows' tokens out of the slab where it lies (`live`: the
-    slots that hold a request; None = every slot); otherwise the cache
-    rides the scan as xs — read-only per-layer slices fuse into the
-    attention einsums (GSPMD-shardable), unlike slice-reads of a
-    just-scattered carry — and every slot's whole window is scored and
-    masked. (The Pallas decode kernel of rounds 3-4 that lost to these
-    einsums, 16.3 vs 8.1 ms/step, read every block of 160 slots ALL
-    live: it priced reading everything by a kernel, where the einsums
-    ride at 85-90 % of the HBM peak. The kernel here wins by what it
-    does not read; PERF.md section 5 has its table by occupancy.)
+    handles the current token via an exact fresh column). Two ways to
+    read and write it, told by what is there to see (_sparse_decode):
+    on a TPU, with the dense slab whole on one device, the scan rides
+    on the layer's INDEX and carries K and V whole; ops/decode_attention
+    reads the live rows' tokens out of the slab where it lies and writes
+    the fresh token's row of the live slots into it (`live`: the slots
+    that hold a request; None = every slot; a dead slot's rows keep what
+    they held). Otherwise the cache rides the scan as xs — read-only
+    per-layer slices fuse into the attention einsums (GSPMD-shardable),
+    unlike slice-reads of a just-scattered carry — every slot's whole
+    window is scored and masked, and all L layers' fresh k/v are written
+    back AFTER the scan in one batched scatter over every slot. (The
+    Pallas decode kernel of rounds 3-4 that lost to these einsums, 16.3
+    vs 8.1 ms/step, read every block of 160 slots ALL live: it priced
+    reading everything by a kernel, where the einsums ride at 85-90 % of
+    the HBM peak. The kernel here wins by what it does not read;
+    PERF.md section 5 has its table by occupancy.)
 
     A stack with experts that lies whole on one device (no `tp`, not
     `spread`) computes its sparse block by token -> expert dispatch
@@ -1038,9 +1049,7 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
     # the one token a slot holds is a live row where the slot is
     routed = None if experts is None or live is None else live[:, None]
 
-    def attend(q, k, v, cl):
-        if sched is not None:
-            return decode_attention.attend(q, k, v, cache, cl, sched)
+    def einsums(q, k, v, cl):
         ck, cv = cl["k"], cl["v"]
         if tp is not None:
             # Each device contracts over its own head group's lanes of
@@ -1051,36 +1060,50 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
             k_scale=cl.get("k_scale"), v_scale=cl.get("v_scale"),
         )
 
+    def stored(k, v):
+        """The fresh token's rows as the slab stores them."""
+        if quantized:
+            kq, ksc = _quantize_kv(k)
+            vq, vsc = _quantize_kv(v)
+            return {"k": _kv_rows(kq, side)[:, 0],
+                    "v": _kv_rows(vq, side)[:, 0],
+                    "k_scale": ksc[:, 0], "v_scale": vsc[:, 0]}
+        dt = cache["k"].dtype
+        return {"k": _kv_rows(k, side)[:, 0].astype(dt),
+                "v": _kv_rows(v, side)[:, 0].astype(dt)}
+
     def body(carry, xs):
+        x, *slab = carry  # K and V whole, where the kernel writes them
         bp, cl, *layer = xs
-        h = rms_norm(carry, bp["attn_norm"], cfg.rms_norm_eps)
+        h = rms_norm(x, bp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(h, bp, cfg, positions, inv_freq, tp=tp)
-        attn = attend(q, k, v, cl)
+        if sched is not None:
+            fresh = stored(k, v)
+            attn, *slab = decode_attention.attend(
+                q, k, v, {**cache, "k": slab[0], "v": slab[1]}, cl, sched,
+                fresh if quantized else None)
+            fresh = {key: val for key, val in fresh.items()
+                     if key not in ("k", "v")}  # the scales' where, below
+        else:
+            attn = einsums(q, k, v, cl)
         if tp is not None:
             attn = tp.gather(tp.flat(attn))
         with jax.named_scope("attn/out"):
-            x = carry + _qdot(attn, bp, "wo", cfg)
+            x = x + _qdot(attn, bp, "wo", cfg)
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
         x, aux = _mlp_res(x, bp, cfg, act_spec, tp=tp, experts=experts,
                           layer=layer[0] if layer else None, live=routed)
-        if quantized:
-            kq, ksc = _quantize_kv(k)
-            vq, vsc = _quantize_kv(v)
-            fresh = {"k": _kv_rows(kq, side)[:, 0],
-                     "v": _kv_rows(vq, side)[:, 0],
-                     "k_scale": ksc[:, 0], "v_scale": vsc[:, 0]}
-        else:
-            dt = cache["k"].dtype
-            fresh = {"k": _kv_rows(k, side)[:, 0].astype(dt),
-                     "v": _kv_rows(v, side)[:, 0].astype(dt)}
-        return x, (fresh, aux)
+        if sched is None:
+            fresh = stored(k, v)
+        return (x, *slab), (fresh, aux)
 
     xs = (blocks,
           cache if sched is None else jnp.arange(cache["k"].shape[0]))
     if experts is not None:
         xs += (jnp.arange(cfg.n_layers),)
-    x, (fresh, aux) = jax.lax.scan(body, x, xs)
+    slab = () if sched is None else (cache["k"], cache["v"])
+    (x, *slab), (fresh, aux) = jax.lax.scan(body, (x, *slab), xs)
     rows = jnp.arange(pos.shape[0])
     # k / v: one scatter covers all layers, with layer, row and position
     # all INDICES of it and only the token's row [1, Hkv*Dh] its window:
@@ -1097,13 +1120,13 @@ def _run_blocks_decode(params, x, cfg, positions, inv_freq, pos, cache,
     layers = jnp.arange(cache["k"].shape[0])[None, :]
     here = (jnp.arange(Smax)[None, :] == pos[:, None])[None, :, None, :]
     with jax.named_scope("attn/cache_update"):
-        new_cache = {
-            key: cache[key].at[layers, rows[:, None], :, pos[:, None]].set(
-                jnp.swapaxes(fresh[key], 0, 1), unique_indices=True)
-            if key in ("k", "v") else
-            jnp.where(here, fresh[key][..., None], cache[key])
-            for key in cache
-        }
+        new_cache = dict(zip(("k", "v"), slab))  # the kernel wrote them
+        for key in fresh:
+            new_cache[key] = cache[key].at[
+                layers, rows[:, None], :, pos[:, None]].set(
+                jnp.swapaxes(fresh[key], 0, 1), unique_indices=True) \
+                if key in ("k", "v") else \
+                jnp.where(here, fresh[key][..., None], cache[key])
     aux = jnp.mean(aux) if experts is None else jnp.sum(aux, axis=0)
     return x, new_cache, aux
 
@@ -2393,34 +2416,38 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
     their frozen position: harmless, an admission overwrites all three
     before the slot is read again. On a TPU the attention layers read
     the live rows' tokens out of the whole slab by the layer's index
-    (_sparse_decode), and K and V do not ride the scans at all.
+    (_sparse_decode) and write the live rows' fresh k/v into it, so K
+    and V ride the scans' CARRY whole beside the SSM state, no scatter
+    follows, and a slot that is not live keeps its KV as it was.
 
     A sliding_attention layer reads its ring "kw" / "vw" the same way,
     pre-write: of the W rows, those a position has reached (s < pos) less
     the row s = pos % W, which this step overwrites and which, once pos
     >= W, still holds position pos - W, the one that has just left the
     window; the fresh column makes the W-th key. Its fresh k/v land at
-    row pos % W in a scatter of their own after the scans."""
+    row pos % W: by the kernel, or in a scatter of their own after the
+    scans."""
     Smax = cache["k"].shape[3]
     mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
     live2 = None if live is None else live[:, None]
     sched = _sparse_decode(cfg, cache, live, pos, spread)
-    riding = cache if sched is None else \
-        {key: arr for key, arr in cache.items() if key not in ("k", "v")}
     fresh = {"k": [], "v": [], "conv": [], "ssm_conv": [], "kw": [], "vw": []}
     sched_w = mask_w = None
     if "kw" in cache:
         W = cache["kw"].shape[3]
         sched_w = _sparse_decode(cfg, cache, live, pos, spread, ring=True)
-        if sched_w is not None:
-            riding = {key: arr for key, arr in riding.items()
-                      if key not in ("kw", "vw")}
         s_ = jnp.arange(W)[None, None, :]
         mask_w = (s_ < pos[:, None, None]) & (s_ != (pos % W)[:, None, None])
     routing = jnp.zeros((routing_width(cfg),), jnp.int32)
     dt = cache["k"].dtype
     side = kv_heads_per_row(cfg)
-    ssm = (cache["ssm"],) if "ssm" in cache else ()
+    # what the layers update where it lies, carried whole by the scans:
+    # the SSM state, and K and V of a kind the kernel reads and writes
+    held = {key: cache[key] for key in
+            ("ssm",) + (("k", "v") if sched is not None else ())
+            + (("kw", "vw") if sched_w is not None else ())
+            if key in cache}
+    riding = {key: arr for key, arr in cache.items() if key not in held}
     for seg, sp in zip(layer_plan(cfg), params["segments"]):
         sliced, experts = _split_experts(sp, cfg)
         nm = _count_ops(seg.kinds, *SSM_OPS)
@@ -2428,7 +2455,8 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
         nw = _count_ops(seg.kinds, *WINDOW_OPS)
 
         def body(carry, xs, seg=seg, experts=experts, nm=nm, na=na, nw=nw):
-            x, routing, *ssm = carry
+            x, routing, held = carry
+            held = dict(held)
             rep, lps, cl = xs
             ia = ic = im = iw = 0
             ks, vs, cs, scs, kws, vws = [], [], [], [], [], []
@@ -2438,7 +2466,8 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                 over the cache as it was before this step: the window
                 kind over its ring, every other over the slab; through
                 the gate, where the stack has one, and the output
-                projection; the fresh k and v with it."""
+                projection; the fresh k and v with it, which the kernel
+                has written into `held` where it runs."""
                 q, k, v = _qkv(h, lp, cfg, positions, inv_freq, op=op)
                 windowed = op in WINDOW_OPS
                 with jax.named_scope(
@@ -2447,39 +2476,40 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                         attn = gqa_attention_decode(
                             q, cl["kw"][i], cl["vw"][i], k, v, mask_w)
                     elif windowed:
-                        attn = decode_attention.attend(
-                            q, k, v, {"k": cache["kw"], "v": cache["vw"]},
+                        attn, held["kw"], held["vw"] = decode_attention.attend(
+                            q, k, v, {"k": held["kw"], "v": held["vw"]},
                             seg.window_start + rep * nw + i, sched_w)
                     elif sched is None:
                         attn = gqa_attention_decode(
                             q, cl["k"][i], cl["v"][i], k, v, mask_lt)
                     else:
-                        attn = decode_attention.attend(
-                            q, k, v, cache, seg.attn_start + rep * na + i,
-                            sched)
+                        attn, held["k"], held["v"] = decode_attention.attend(
+                            q, k, v, {"k": held["k"], "v": held["v"]},
+                            seg.attn_start + rep * na + i, sched)
                 attn = _gated(attn, h, lp, cfg)
                 with jax.named_scope("attn/out"):
                     return _qdot(attn, lp, "wo", cfg), k, v
 
-            def mixer(h, lp, im, ssm):
+            def mixer(h, lp, im):
                 """Mamba-2 mixer `im` of this repeat, its state stepped
-                where it lies in the carried `ssm`."""
+                where it lies in the carried `held`."""
                 at = seg.ssm_start + rep * nm + im
-                y, st, cst = _mamba_op(
+                y, held["ssm"], cst = _mamba_op(
                     h, lp, cfg, conv_state=cl["ssm_conv"][im],
-                    state=(ssm[0], at))
-                return y, [st], cst.astype(cl["ssm_conv"].dtype)
+                    state=(held["ssm"], at))
+                return y, cst.astype(cl["ssm_conv"].dtype)
 
             for lp, ex, (op, _) in zip(lps, experts, seg.kinds):
                 h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
                 if op == OP_ATTN_MAMBA:
                     # as _run_patterned_full: both read h, summed into x;
-                    # the layer's k/v join the scatter after the scans,
-                    # its SSM state rides the carry
+                    # the layer's k/v are written by the kernel or join
+                    # the scatter after the scans, its SSM state rides
+                    # the carry
                     a, k, v = attention(_scaled(h, cfg.attn_in_mult), lp, op,
                                         ia)
                     a = _scaled(a, cfg.attn_out_mult)
-                    y, ssm, cst = mixer(h, lp, im, ssm)
+                    y, cst = mixer(h, lp, im)
                     with jax.named_scope("mixer/sum"):
                         x = x + a + y
                     ks.append(_kv_rows(k, side)[:, 0].astype(dt))
@@ -2489,7 +2519,7 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     ia += 1
                     im += 1
                 elif op == OP_MAMBA:
-                    y, ssm, cst = mixer(h, lp, im, ssm)
+                    y, cst = mixer(h, lp, im)
                     x = x + y
                     scs.append(cst)
                     routing = routing + _routing_counts(cfg, ssm=True)
@@ -2519,23 +2549,23 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     x, r = _ff_res(x, lp, ex, rep, cfg, live2)
                     routing = routing + r
             ys = {}
-            if ks:
+            if ks and sched is None:
                 ys["k"], ys["v"] = jnp.stack(ks), jnp.stack(vs)
             if cs:
                 ys["conv"] = jnp.stack(cs)
             if scs:
                 ys["ssm_conv"] = jnp.stack(scs)
-            if kws:
+            if kws and sched_w is None:
                 ys["kw"], ys["vw"] = jnp.stack(kws), jnp.stack(vws)
-            return (x, routing, *ssm), ys
+            return (x, routing, held), ys
 
-        (x, routing, *ssm), ys = jax.lax.scan(
-            body, (x, routing, *ssm),
+        (x, routing, held), ys = jax.lax.scan(
+            body, (x, routing, held),
             (jnp.arange(seg.reps), sliced, _segment_cache(riding, seg)))
         for key, val in ys.items():
             fresh[key].append(val)
     rows = jnp.arange(pos.shape[0])
-    new_cache = dict(cache)
+    new_cache = {**cache, **held}
     # Layer, row and position are all INDICES of the scatter and only
     # the token's row is its window. With the layer axis a window
     # dimension (`.at[:, rows, :, pos]`) the TPU compiler carried the
@@ -2559,8 +2589,7 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
                     unique_indices=True)
     if fresh["conv"]:
         new_cache["conv"] = _unsegment(fresh["conv"])
-    if ssm:
-        new_cache["ssm"] = ssm[0]
+    if fresh["ssm_conv"]:
         new_cache["ssm_conv"] = _unsegment(fresh["ssm_conv"])
     return x, new_cache, routing
 

@@ -41,10 +41,27 @@ so they are finite and nothing of a dead row reaches a live one.
 A layer whose KV is a RING as long as its window (cache_spec's
 "kv_window": row s holds the newest position that is s modulo the ring)
 is read by the same walk: its schedule counts min(pos, ring) tokens a
-slot and names the one row to leave out (`skip`: once pos has passed
-the ring's length, the row the step is about to overwrite holds the
-position that has just left the window). The softmax does not care in
-which order rows come.
+slot and the row the step is about to overwrite (`row`) is left out of
+the read: once pos has passed the ring's length it holds the position
+that has just left the window. The softmax does not care in which order
+rows come.
+
+The same walk WRITES the fresh token's K and V, for the live slots and
+no other: K and V are aliased to the call's results (ops/ssm_update.py
+holds the state so) and `attend` returns them with row `row` of every
+live slot holding the fresh token in the slab's storage dtype (an int8
+slab: the quantised row, handed in beside the exact column the softmax
+folds). The slab lies in HBM in native tiles of rows (32 of int8, 16 of
+bf16: a row shares its 32-bit words with its neighbours), so what is
+written is the ALIGNED TILE that holds `row`: when the block that holds
+it has been copied in and awaited (the last block of a slab's slot
+unless pos is a block's first row; whichever block of a full ring), the
+tile is cut out of that buffer, the fresh row selected in, and copied
+back, so every other row keeps its bytes and the write cannot race the
+slot's own reads. The live slots whose row is the first of a block that
+the walk does not read (position 0, a block's edge) are `loose`: a
+second, nearly always empty loop fetches their tile, sets its first
+row and puts it back. A slot at pos == T writes nothing.
 
 Like the other kernels of seldon_tpu/ops it never chooses interpret mode
 itself: `applies` is False off a TPU, the caller then keeps
@@ -55,7 +72,7 @@ tests/pallas_interpret.py.
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,18 +125,23 @@ class Schedule(NamedTuple):
     pos: jnp.ndarray  # [B] int32
     has_past: jnp.ndarray  # [B] bool: live and past position 0
     block: int
-    skip: Optional[jnp.ndarray] = None  # [B] int32: a row left out (-1: none)
+    row: jnp.ndarray  # [B] int32: the row the step writes (left unread)
+    n_loose: jnp.ndarray  # [1] int32
+    loose: jnp.ndarray  # [B] int32: live slots whose row no item's block holds
 
 
 def schedule(active: jnp.ndarray, pos: jnp.ndarray, window: int,
              block: int, ring: bool = False) -> Schedule:
     """`ring`: the layer holds `window` rows a slot, position p at row
     p % window; a slot at pos reads min(pos, window) rows and not the
-    row pos % window once pos >= window (it holds position pos - window)."""
+    row pos % window once pos >= window (it holds position pos - window).
+    `row` is where the step's token goes: pos, or pos % window."""
     has = active & (pos > 0)
-    skip = None
+    row = (pos % window if ring else pos).astype(jnp.int32)
+    # a block's first row, of a block the walk below does not reach
+    is_loose = active & (pos < window) & (pos % block == 0)
+    loose = jnp.argsort(~is_loose, stable=True).astype(jnp.int32)
     if ring:
-        skip = jnp.where(pos >= window, pos % window, -1).astype(jnp.int32)
         pos = jnp.minimum(pos, window)
     per_slot = jnp.where(has, (pos + block - 1) // block, 0).astype(jnp.int32)
     ends = jnp.cumsum(per_slot)
@@ -127,7 +149,8 @@ def schedule(active: jnp.ndarray, pos: jnp.ndarray, window: int,
     slot = jnp.minimum(jnp.searchsorted(ends, items, side="right"),
                        pos.shape[0] - 1).astype(jnp.int32)
     return Schedule(ends[-1:], slot, items - (ends - per_slot)[slot],
-                    pos.astype(jnp.int32), has, block, skip)
+                    pos.astype(jnp.int32), has, block, row,
+                    jnp.sum(is_loose, dtype=jnp.int32)[None], loose)
 
 
 def tokens_read(sched: Schedule) -> jnp.ndarray:
@@ -136,21 +159,27 @@ def tokens_read(sched: Schedule) -> jnp.ndarray:
     return sched.n_items[0] * sched.block
 
 
-def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, *rest,
-            quantized: bool, block: int, scale: float, ring: bool):
-    skip_ref = None
-    if ring:  # one more prefetched scalar a slot: the row left out
-        skip_ref, *rest = rest
-    q_ref, kf_ref, vf_ref, own_ref, *rest = rest
-    if quantized:  # K, V and their scales: four arrays in HBM, four buffers
-        spread_ref, *rest = rest
-    n_hbm = 4 if quantized else 2
-    hbm, out_ref, bufs = rest[:n_hbm], rest[n_hbm], rest[n_hbm + 1:2 * n_hbm + 1]
-    sem, qbd, m_scr, l_scr, acc_scr = rest[2 * n_hbm + 1:]
+def tile_rows(dtype) -> int:
+    """Rows of the slab's native tile in HBM: a 32-bit word holds 4 int8
+    or 2 bf16 rows of a lane, 8 words a tile."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, row_ref,
+            n_loose_ref, loose_ref, q_ref, kf_ref, vf_ref, own_ref, *rest,
+            quantized: bool, block: int, scale: float):
+    new, n_hbm = (kf_ref, vf_ref), 2  # the fresh rows as the slab stores them
+    if quantized:  # int8 rows, the scales' spread; K, V and their scales
+        spread_ref, *new = rest[:3]
+        rest, n_hbm = rest[3:], 4
+    hbm, (out_ref, k_out, v_out) = rest[:n_hbm], rest[n_hbm:n_hbm + 3]
+    bufs = rest[n_hbm + 3:2 * n_hbm + 3]
+    sem, qbd, m_scr, l_scr, acc_scr, wbuf, wsem = rest[2 * n_hbm + 3:]
     kbuf, vbuf = bufs[:2]
     layer, n = layer_ref[0], n_ref[0]
     H, C = qbd.shape
     tiles = C // LANES
+    R = wbuf.shape[1]
 
     def copies(w, buf):
         b = slot_ref[w]
@@ -161,14 +190,29 @@ def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, *rest,
         return [pltpu.make_async_copy(src.at[at[i]], dst.at[buf], sem.at[i, buf])
                 for i, (src, dst) in enumerate(zip(hbm, bufs))]
 
+    def tile(slab, b, first):
+        return slab.at[layer, b, 0, pl.ds(pl.multiple_of(first, R), R)]
+
+    def puts(b, first):
+        """wbuf -> the tile of K and of V that starts at row `first`."""
+        return [pltpu.make_async_copy(wbuf.at[i], tile(dst, b, first),
+                                      wsem.at[i])
+                for i, dst in enumerate((k_out, v_out))]
+
+    def stage(i, b, held, at):
+        """wbuf[i] <- `held` [R, C] with the fresh row at row `at`."""
+        here = jax.lax.broadcasted_iota(jnp.int32, held.shape, 0) == at
+        wbuf[i] = jnp.where(here, new[i][b].astype(held.dtype), held)
+
     @pl.when(n > 0)
     def _first():
         for c in copies(0, 0):
             c.start()
 
-    def item(w, carry):
+    def item(w, writing):
         buf = w % 2
         b, j, p = slot_ref[w], blk_ref[w], pos_ref[slot_ref[w]]
+        r = row_ref[b]
 
         @pl.when(w + 1 < n)
         def _next():
@@ -194,9 +238,9 @@ def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, *rest,
             s = s * jnp.dot(spread_ref[...], bufs[2][buf],
                             preferred_element_type=jnp.float32)
         cols = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = cols < p
-        if ring:
-            mask &= cols != skip_ref[b]
+        # below the slot's position, and not the row this step writes (a
+        # full ring's holds the position that has just left the window)
+        mask = (cols < p) & (cols != r)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -231,9 +275,55 @@ def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, *rest,
                     + p_f * vf_ref[b][:, lanes].astype(f32))
             out_ref[b] = (own * inv).astype(out_ref.dtype)
 
+        # the block in hand holds the row this step writes: its tile goes
+        # back with the fresh row in it. The block has been awaited and
+        # no later item of the slot reads it; the copy in flight is of
+        # another block.
+        off = r - j * block
+        holds = (off >= 0) & (off < block)
+
+        @pl.when(holds)
+        def _write():
+            @pl.when(writing > 0)
+            def _last_slots():  # wbuf is free once they have landed
+                for c in puts(0, 0):
+                    c.wait()
+
+            first = pl.multiple_of(off // R * R, R)
+            for i in range(2):
+                stage(i, b, bufs[i][buf, pl.ds(first, R), :], off - first)
+            for c in puts(b, j * block + first):
+                c.start()
+
+        return jnp.where(holds, 1, writing)
+
+    writing = jax.lax.fori_loop(0, n, item, jnp.int32(0))
+
+    @pl.when(writing > 0)
+    def _landed():
+        for c in puts(0, 0):
+            c.wait()
+
+    def loose(i, carry):
+        b = loose_ref[i]
+        first = row_ref[b]  # a block's first row: a tile's too
+        gets = [pltpu.make_async_copy(tile(src, b, first), wbuf.at[i_],
+                                      wsem.at[i_])
+                for i_, src in enumerate(hbm[:2])]
+        for c in gets:
+            c.start()
+        for c in gets:
+            c.wait()
+        for i_ in range(2):
+            stage(i_, b, wbuf[i_], 0)
+        back = puts(b, first)
+        for c in back:
+            c.start()
+        for c in back:
+            c.wait()
         return carry
 
-    jax.lax.fori_loop(0, n, item, 0)
+    jax.lax.fori_loop(0, n_loose_ref[0], loose, 0)
 
 
 def attend(
@@ -243,12 +333,17 @@ def attend(
     cache: Dict[str, jnp.ndarray],  # the WHOLE slab, PRE-write
     layer: jnp.ndarray,  # int32 scalar: the attention layer, of La
     sched: Schedule,
-) -> jnp.ndarray:
+    stored: Optional[Dict[str, jnp.ndarray]] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """gqa_attention_decode over layer `layer` of the slab for the rows
-    `sched` was made of: [B, 1, H * Dh] in q's dtype.
+    `sched` was made of, [B, 1, H * Dh] in q's dtype, and K and V with
+    row `sched.row` of that layer written for every live slot (in place:
+    hand them on, the arrays handed in are spent).
 
     cache {"k", "v"[, "k_scale", "v_scale"]}: [La, B, 1, T, Hkv * Dh]
-    (scales [La, B, Hkv, T])."""
+    (scales [La, B, Hkv, T]). `stored` {"k", "v"}: [B, Hkv * Dh], the
+    fresh rows as an int8 slab stores them; any other slab takes the
+    fresh column cast to its dtype."""
     B, _, H, Dh = q.shape
     C = cache["k"].shape[4]
     Hkv, block = C // Dh, sched.block
@@ -256,17 +351,6 @@ def attend(
     f32 = jnp.float32
     slab = [cache["k"], cache["v"]]
     quantized = "k_scale" in cache
-    ring = sched.skip is not None
-    if quantized:
-        # The layer's scales (1 MiB each at 64 x 1024 x 8), not the
-        # whole arrays: the compiler takes a call to read its operands
-        # whole, and two whole arrays of scales (32 MiB each) fit the
-        # chip's fast memory, so it may move one there ahead of the call
-        # in EVERY layer: 1.1 GB a step on mistral7b.chat (PERF.md
-        # section 6, PR 42). K and V stay whole: a layer of them sliced
-        # out is 64 MiB moved a layer.
-        slab += [jax.lax.dynamic_index_in_dim(cache[key], layer, 0, False)
-                 for key in ("k_scale", "v_scale")]
     own = (jnp.arange(C) // Dh)[None, :] == (jnp.arange(H) // G)[:, None]
     args = [jnp.tile(q[:, 0], (1, 1, LANES // Dh)),
             k_fresh.astype(q.dtype).reshape(B, 1, C),
@@ -277,32 +361,53 @@ def attend(
     if quantized:
         # [H, Hkv] 0/1: a query head's row of scales is its KV head's
         spread = jnp.arange(H)[:, None] // G == jnp.arange(Hkv)[None, :]
-        args.append(spread.astype(cache["k_scale"].dtype))
-        in_specs.append(whole((H, Hkv)))
+        args += [spread.astype(cache["k_scale"].dtype),
+                 stored["k"].reshape(B, 1, C), stored["v"].reshape(B, 1, C)]
+        in_specs += [whole((H, Hkv)), whole((B, 1, C)), whole((B, 1, C))]
+        # The layer's scales (1 MiB each at 64 x 1024 x 8), not the
+        # whole arrays: the compiler takes a call to read its operands
+        # whole, and two whole arrays of scales (32 MiB each) fit the
+        # chip's fast memory, so it may move one there ahead of the call
+        # in EVERY layer: 1.1 GB a step on mistral7b.chat (PERF.md
+        # section 6, PR 42). K and V stay whole: a layer of them sliced
+        # out is 64 MiB moved a layer.
+        slab += [jax.lax.dynamic_index_in_dim(cache[key], layer, 0, False)
+                 for key in ("k_scale", "v_scale")]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(slab)
     scratch = [pltpu.VMEM((2, block, C) if a.ndim == 5 else (2, Hkv, block),
                           a.dtype) for a in slab]
     scratch += [pltpu.SemaphoreType.DMA((len(slab), 2)),
                 pltpu.VMEM((H, C), q.dtype),
                 pltpu.VMEM((H, 1), f32), pltpu.VMEM((H, 1), f32),
-                pltpu.VMEM((H, C), f32)]
+                pltpu.VMEM((H, C), f32),
+                # K's and V's tile on its way back
+                pltpu.VMEM((2, tile_rows(slab[0].dtype), C), slab[0].dtype),
+                pltpu.SemaphoreType.DMA((2,))]
+    scalars = (jnp.reshape(layer, (1,)).astype(jnp.int32), sched.n_items,
+               sched.slot, sched.blk, sched.pos, sched.row, sched.n_loose,
+               sched.loose)
     with jax.named_scope("attn/scores"):
-        out = pl.pallas_call(
+        out, k, v = pl.pallas_call(
             functools.partial(_kernel, quantized=quantized, block=block,
-                              scale=Dh ** -0.5, ring=ring),
+                              scale=Dh ** -0.5),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5 + ring, grid=(1,),
+                num_scalar_prefetch=len(scalars), grid=(1,),
                 in_specs=in_specs,
-                out_specs=whole((B, H, LANES)),
+                out_specs=[whole((B, H, LANES)),
+                           pl.BlockSpec(memory_space=pl.ANY),
+                           pl.BlockSpec(memory_space=pl.ANY)],
                 scratch_shapes=scratch),
-            out_shape=jax.ShapeDtypeStruct((B, H, LANES), q.dtype),
+            out_shape=[jax.ShapeDtypeStruct((B, H, LANES), q.dtype),
+                       jax.ShapeDtypeStruct(slab[0].shape, slab[0].dtype),
+                       jax.ShapeDtypeStruct(slab[1].shape, slab[1].dtype)],
+            # K and V, past the prefetched scalars and the VMEM operands
+            input_output_aliases={len(scalars) + len(args): 1,
+                                  len(scalars) + len(args) + 1: 2},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=48 * 1024 * 1024),
             name="decode_attention",
-        )(jnp.reshape(layer, (1,)).astype(jnp.int32), sched.n_items,
-          sched.slot, sched.blk, sched.pos,
-          *((sched.skip,) if ring else ()), *args, *slab)
+        )(*scalars, *args, *slab)
     with jax.named_scope("attn/out"):
         # a row's own head is the one segment of its tile that is not zero
         out = out.reshape(B, H, LANES // Dh, Dh).sum(axis=2)
@@ -310,4 +415,4 @@ def attend(
         # no past, attention is the fresh token's value
         alone = jnp.repeat(v_fresh[:, 0].astype(q.dtype), G, axis=1)
         out = jnp.where(sched.has_past[:, None, None], out, alone)
-    return out.reshape(B, 1, H * Dh)
+    return out.reshape(B, 1, H * Dh), k, v

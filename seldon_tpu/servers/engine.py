@@ -615,9 +615,12 @@ class _DepthEstimator:
 # routing did (a model that dispatches tokens to experts).
 SAMPLER_COUNTERS = ("sampler_steps", "sampler_drawn_steps",
                     "sampler_masked_steps")
-KV_COUNTERS = ("attn_kv_tokens_read", "attn_kv_tokens_held")
+# ... and the K rows a step wrote beside slots x attention layers, what
+# a scatter over every slot writes (equal where the scatter stands).
+KV_COUNTERS = ("attn_kv_tokens_read", "attn_kv_tokens_held",
+               "attn_kv_rows_written", "attn_kv_rows_slots")
 # ... of a stack whose attention kinds differ (sliding_attention layers
-# beside full ones), right after those two, which stay the sums over both
+# beside full ones), right after those four, which stay the sums over both
 # kinds (transformer.decode_kv_counts): what the window layers read and
 # hold (their rings), what their live rows would have read without a
 # window (their positions), and what the full layers read and hold.
@@ -684,6 +687,12 @@ class EngineStats:
         # where ops/decode_attention reads them alone.
         self.attn_kv_tokens_read = 0  # graftlint: guarded-by(lock) via(stats)
         self.attn_kv_tokens_held = 0  # graftlint: guarded-by(lock) via(stats)
+        # K rows the decode steps wrote over all attention layers (slab
+        # and rings), and slots x those layers: written / slots is 1
+        # where the step scatters a row of every slot, the live slots'
+        # share where ops/decode_attention writes them alone.
+        self.attn_kv_rows_written = 0  # graftlint: guarded-by(lock) via(stats)
+        self.attn_kv_rows_slots = 0  # graftlint: guarded-by(lock) via(stats)
         # The same by attention kind, for a stack with sliding_attention
         # layers (WINDOW_COUNTERS; 0 elsewhere): read / unwindowed on the
         # window layers is what the window saves a decode step.
@@ -1838,8 +1847,9 @@ class InferenceEngine:
         active [B], counts): counts int32 over the chunk, in
         CHUNK_COUNTERS' order: steps, steps that drew, steps that masked;
         KV tokens the attention layers read and KV tokens the slab holds
-        for them; a routed model adds sparse-layer steps, distinct experts read
-        (summed over those), assignments; one that holds a share of its
+        for them, K rows they wrote and slots x layers; a routed model
+        adds sparse-layer steps, distinct experts read (summed over
+        those), assignments; one that holds a share of its
         experts or has Mamba-2 layers adds the assignments held here
         and the Mamba-2 layers run (transformer.routing_width)."""
         Smax = state["cache"]["k"].shape[3]

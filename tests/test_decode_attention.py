@@ -41,6 +41,16 @@ OCCUPANCY = {
 }
 
 
+def _int8(cache, kf, vf):
+    """The bf16 slab and fresh columns as an int8 slab stores them."""
+    Dh = kf.shape[-1]
+    cache = transformer.kv_writes(cache, {}, dataclasses.replace(
+        get_config("tiny"), kv_cache_dtype="int8", head_dim=Dh))
+    (kq, _), (vq, _) = transformer._quantize_kv(kf), transformer._quantize_kv(vf)
+    rows = lambda x: x.reshape(x.shape[0], -1)
+    return cache, {"k": rows(kq), "v": rows(vq)}
+
+
 @functools.lru_cache(maxsize=None)
 def _case(shape: str, kv_dtype: str):
     """The slab, the step's tensors and the two jitted attentions of one
@@ -54,9 +64,9 @@ def _case(shape: str, kv_dtype: str):
     vf = (0.25 * jax.random.normal(ks[2], (B, 1, Hkv, Dh))).astype(bf16)
     cache = {"k": jax.random.normal(ks[3], (LAYERS, B, 1, T, C), bf16),
              "v": 0.25 * jax.random.normal(ks[4], (LAYERS, B, 1, T, C), bf16)}
+    stored = None
     if kv_dtype == "int8":
-        cache = transformer.kv_writes(cache, {}, dataclasses.replace(
-            get_config("tiny"), kv_cache_dtype="int8", head_dim=Dh))
+        cache, stored = _int8(cache, kf, vf)
     with mock.patch.object(da, "ITEM_BYTES", ITEM_BYTES):
         block = da.block_size(cache["k"].shape, Dh, cache["k"].dtype.itemsize)
     assert block == min(T, ITEM_BYTES // (C * cache["k"].dtype.itemsize))
@@ -69,7 +79,7 @@ def _case(shape: str, kv_dtype: str):
     @jax.jit
     def kernel(active, layer):
         return da.attend(q, kf, vf, cache, layer,
-                         da.schedule(active, pos, T, block))
+                         da.schedule(active, pos, T, block), stored)[0]
 
     @jax.jit
     def einsums(layer):
@@ -108,6 +118,75 @@ def test_kernel_matches_the_einsums_on_live_rows(shape, kv_dtype, occupancy):
                 got[~past], np.asarray(fresh_alone, f32)[~past])
 
 
+WRITE_T, WRITE_BLOCK = 512, 256
+# slot -> (live, position): position 0 (no past, no item), a block's first
+# row (the block is not read), a native tile's last row and the next
+# tile's first (32 rows of int8, 16 of bf16), a dead slot between live
+# ones, the window's last row, the slab's end (nothing to write; a ring:
+# many wraps), a block's first row again, dead
+WRITE_SLOTS = [(True, 0), (True, WRITE_BLOCK), (True, WRITE_BLOCK + 31),
+               (True, WRITE_BLOCK + 32), (False, 300), (True, WRITE_T - 1),
+               (True, WRITE_T), (False, 0)]
+RING_SLOTS = [(True, 0), (True, WRITE_BLOCK), (True, WRITE_T + 15),
+              (True, WRITE_T + 16), (False, WRITE_T + 300),
+              (True, 2 * WRITE_T - 1), (True, 5 * WRITE_T + WRITE_BLOCK + 77),
+              (True, 3 * WRITE_T)]
+
+
+@pytest.mark.parametrize("kv_dtype,ring", [
+    ("bf16", False), ("int8", False), ("bf16", True), ("int8", True)],
+    ids=["bf16-slab", "int8-slab", "bf16-ring", "int8-ring"])
+def test_the_kernel_writes_the_live_slots_fresh_rows_and_nothing_else(
+        kv_dtype, ring):
+    """attend returns K and V with the fresh token's row written for the
+    live slots, as the step's scatter writes it (`.at[layer, slot, :,
+    row].set`, bit for bit; an int8 slab: the quantised row), at row pos
+    of a slab and pos % W of a ring; every other byte (the other layer,
+    a dead slot, every other row of a live one, a slot at the slab's
+    end) is the slab's as it was handed in; and the attention it returns
+    is what it read BEFORE the write, the einsums' over the same rows."""
+    Hkv, Dh, G = 8, 128, 4
+    H, C, W = Hkv * G, Hkv * Dh, WRITE_T
+    ks = jax.random.split(jax.random.key(3), 5)
+    bf16, f32 = jnp.bfloat16, np.float32
+    q = jax.random.normal(ks[0], (B, 1, H, Dh)).astype(bf16)
+    kf = jax.random.normal(ks[1], (B, 1, Hkv, Dh)).astype(bf16)
+    vf = (0.25 * jax.random.normal(ks[2], (B, 1, Hkv, Dh))).astype(bf16)
+    cache = {"k": jax.random.normal(ks[3], (LAYERS, B, 1, W, C), bf16),
+             "v": 0.25 * jax.random.normal(ks[4], (LAYERS, B, 1, W, C), bf16)}
+    rows = {"k": kf.reshape(B, C), "v": vf.reshape(B, C)}
+    stored = None
+    if kv_dtype == "int8":
+        cache, stored = _int8(cache, kf, vf)
+        rows = stored
+    assert WRITE_BLOCK % da.tile_rows(cache["k"].dtype) == 0
+    active, pos = (jnp.array(x) for x in zip(*(RING_SLOTS if ring else WRITE_SLOTS)))
+    sched = da.schedule(active, pos, W, WRITE_BLOCK, ring)
+    assert sched.loose[:int(sched.n_loose[0])].tolist() == [0, 1]
+    with pallas_interpret():
+        out, k, v = jax.jit(lambda c: da.attend(
+            q, kf, vf, c, jnp.asarray(1), sched, stored))(cache)
+    row = np.asarray(pos % W if ring else pos)
+    writes = np.asarray(active) & (row < W)
+    assert writes.sum() == (7 if ring else 5)
+    for name, got in (("k", k), ("v", v)):
+        want = np.array(cache[name].astype(f32))
+        for b in np.flatnonzero(writes):
+            want[1, b, 0, row[b]] = np.asarray(rows[name].astype(f32))[b]
+        assert got.dtype == cache[name].dtype
+        np.testing.assert_array_equal(np.asarray(got.astype(f32)), want)
+    s_ = jnp.arange(W)[None, None, :]
+    mask = (s_ < pos[:, None, None]) & (s_ != jnp.asarray(row)[:, None, None])
+    want = transformer.gqa_attention_decode(
+        q, cache["k"][1], cache["v"][1], kf, vf, mask,
+        k_scale=cache.get("k_scale", [None] * 2)[1],
+        v_scale=cache.get("v_scale", [None] * 2)[1])
+    past = np.asarray(active & (pos > 0))
+    np.testing.assert_allclose(
+        np.asarray(out, f32)[past], np.asarray(want, f32)[past],
+        atol=RAGGED_LOGITS_ATOL, rtol=0)
+
+
 def test_schedule_lists_live_blocks_in_order():
     active = jnp.array([0, 1, 1, 0, 1, 1], bool)
     pos = jnp.array([9, 0, 128, 300, 129, 511])
@@ -118,6 +197,13 @@ def test_schedule_lists_live_blocks_in_order():
     assert s.blk[:n].tolist() == [0, 0, 1, 0, 1, 2, 3]
     assert s.has_past.tolist() == [False, False, True, False, True, True]
     assert s.slot.shape == (6 * 4,) and int(s.slot.max()) <= 5
+    # the row a step writes, and the live slots whose row is the first of
+    # a block the walk does not read (slot 3 is dead)
+    assert s.row.tolist() == pos.tolist()
+    assert s.loose[:int(s.n_loose[0])].tolist() == [1, 2]
+    ring = da.schedule(active, pos + 512, 512, 128, ring=True)
+    assert ring.row.tolist() == pos.tolist() and int(ring.n_loose[0]) == 0
+    assert int(da.tokens_read(ring)) == 4 * 512  # a full ring: every block
 
 
 @pytest.mark.parametrize("k_shape,head_dim,itemsize,block", [
@@ -176,7 +262,11 @@ def test_decode_step_with_the_kernel_gives_the_einsums_logits(
     bit of a layer's bf16 output is carried through the layers after
     it, where attention alone is held to RAGGED_LOGITS_ATOL above; a
     wrong layer, slot or mask moves these logits by 0.3 and more) and
-    the cache they write is the same; dead rows stay finite."""
+    the cache they write is the same where a slot is live (the first
+    layer's rows bit for bit; later layers' follow the activations);
+    dead rows stay finite, and a dead slot's K and V are what they were
+    before the step, where the scatter after the einsums writes them
+    too."""
     cfg = _wide(preset, window=256, kv_cache_dtype=kv_dtype)
     monkeypatch.setattr(da, "ITEM_BYTES", 128 * 128 * 2)  # two items a window
     params = init_params(cfg, jax.random.key(0))
@@ -195,18 +285,69 @@ def test_decode_step_with_the_kernel_gives_the_einsums_logits(
     live = np.asarray(active)
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                atol=STEP_ATOL, rtol=0)
-    for name in ("k", "v"):  # the first layer's rows do not pass through attention
+    f32 = lambda a: np.asarray(a, np.float32)
+    for name in ("k", "v"):
+        # the first layer's rows do not pass through attention
         np.testing.assert_array_equal(
-            np.asarray(cache_got[name][0], np.float32),
-            np.asarray(cache_want[name][0], np.float32))
+            f32(cache_got[name][0])[live], f32(cache_want[name][0])[live])
+        np.testing.assert_allclose(
+            f32(cache_got[name])[:, live], f32(cache_want[name])[:, live],
+            atol=2 if kv_dtype == "int8" else STEP_ATOL, rtol=0)
+        np.testing.assert_array_equal(
+            f32(cache_got[name])[:, ~live], f32(state["cache"][name])[:, ~live])
+        assert (f32(cache_want[name])[:, ~live]
+                != f32(state["cache"][name])[:, ~live]).any()
+    for name in set(cache_got) - {"k", "v"}:  # scales, conv and SSM state
+        np.testing.assert_allclose(
+            f32(cache_got[name])[:, live], f32(cache_want[name])[:, live],
+            atol=STEP_ATOL, rtol=2e-2)  # a bf16 last bit of a value over 4
+
+
+@pytest.mark.parametrize("preset,kv_dtype", [
+    ("tiny", "bf16"), ("tiny", "int8"), ("tiny-lfm2", "bf16")])
+def test_a_chunk_with_the_kernel_gives_the_scatter_paths_tokens(
+        monkeypatch, preset, kv_dtype):
+    """Four greedy steps of _chunk_impl with the kernel reading and
+    writing the slab give the tokens of the chunk that scores every
+    window by einsums and scatters every slot's row: a step reads what
+    the steps before it wrote (positions across a block's edge and at
+    one), live slots end with the same rows of K and V, and a dead
+    slot's slab is untouched by the kernel's chunk."""
+    cfg = _wide(preset, window=256, kv_cache_dtype=kv_dtype)
+    monkeypatch.setattr(da, "ITEM_BYTES", 128 * 128 * 2)  # two items a window
+    params = init_params(cfg, jax.random.key(0))
+    active = jnp.array([True, False, True, True])
+    pos = jnp.array([126, 190, 1, 200])
+    state = _armed_state(cfg, 4, active, pos, jax.random.key(3))
+    chunk = lambda: jax.jit(functools.partial(
+        InferenceEngine._chunk_impl, cfg=cfg, n_steps=4))(params, state)
+    want_state, want, valid, *_ = chunk()
+    monkeypatch.setattr(da, "applies", da.reads)
+    with pallas_interpret():
+        got_state, got, got_valid, *_ = chunk()
+    live = np.asarray(active)
+    assert np.asarray(valid)[:, live].all()
+    np.testing.assert_array_equal(np.asarray(got_valid), np.asarray(valid))
+    np.testing.assert_array_equal(np.asarray(got)[:, live],
+                                  np.asarray(want)[:, live])
+    f32 = lambda a: np.asarray(a, np.float32)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(
+            f32(got_state["cache"][name])[:, live],
+            f32(want_state["cache"][name])[:, live],
+            atol=2 if kv_dtype == "int8" else STEP_ATOL, rtol=0)
+        np.testing.assert_array_equal(
+            f32(got_state["cache"][name])[:, ~live],
+            f32(state["cache"][name])[:, ~live])
 
 
 @pytest.mark.parametrize("kernel", [False, True])
 def test_chunk_counts_the_kv_tokens_read_and_held(monkeypatch, kernel):
-    """A chunk over a slab with known `active` and `pos` returns the two
-    sums by hand: with the einsums every step reads all the slab holds,
-    with the kernel whole blocks of the live slots up to where each has
-    got, position 0 reading nothing."""
+    """A chunk over a slab with known `active` and `pos` returns the
+    sums by hand: with the einsums every step reads all the slab holds
+    and writes a row of every slot, with the kernel whole blocks of the
+    live slots up to where each has got, position 0 reading nothing, and
+    the live slots' rows alone."""
     cfg = _wide("tiny", window=384)
     monkeypatch.setattr(da, "ITEM_BYTES", 128 * 128 * 2)  # items of 128 tokens
     params = init_params(cfg, jax.random.key(0))
@@ -228,6 +369,10 @@ def test_chunk_counts_the_kv_tokens_read_and_held(monkeypatch, kernel):
     blocks = (0 + 1 + 1 + 2) + (1 + 1 + 2 + 2)
     assert counts["attn_kv_tokens_read"] == (
         cfg.n_layers * 128 * blocks if kernel else held)
+    # two steps, a K row a layer: of all 5 slots, or of the 4 that are live
+    assert counts["attn_kv_rows_slots"] == 2 * cfg.n_layers * 5
+    assert counts["attn_kv_rows_written"] == 2 * cfg.n_layers * (
+        4 if kernel else 5)
 
 
 def test_a_slab_spread_over_devices_keeps_the_einsums(monkeypatch):
@@ -241,7 +386,12 @@ def test_a_slab_spread_over_devices_keeps_the_einsums(monkeypatch):
     assert transformer._sparse_decode(cfg, cache, live, pos, False) is not None
     assert transformer._sparse_decode(cfg, cache, live, pos, True) is None
     held = cfg.n_layers * 4 * cfg.max_seq_len
+    rows = cfg.n_layers * 4
     assert transformer.decode_kv_counts(
-        cfg, cache, live, pos, spread=True).tolist() == [held, held]
+        cfg, cache, live, pos, spread=True).tolist() == [held, held, rows, rows]
+    assert transformer.decode_kv_counts(cfg, cache, live, pos).tolist() == [
+        cfg.n_layers * 2 * 128, held, cfg.n_layers * 3, rows]
+    # a live slot whose position has reached the slab's end writes no row
     assert transformer.decode_kv_counts(
-        cfg, cache, live, pos).tolist() == [cfg.n_layers * 2 * 128, held]
+        cfg, cache, live, pos.at[0].set(cfg.max_seq_len)).tolist()[2] \
+        == cfg.n_layers * 2

@@ -355,8 +355,9 @@ def test_decode_steps_every_layers_state_and_writes_one_kv_row_in_the_same_layer
     want = np.zeros_like(moved)
     want[:, np.arange(3), np.asarray(pos)] = True
     np.testing.assert_array_equal(moved, want)
-    held, read = (int(v) for v in T.decode_kv_counts(cfg, cache, None, pos))
+    held, read, written, slots = (int(v) for v in T.decode_kv_counts(cfg, cache, None, pos))
     assert held == read == cfg.n_layers * 3 * 8  # off a TPU the einsums read all they hold
+    assert written == slots == cfg.n_layers * 3  # and the scatter writes a row of every slot
 
 
 # -- the two kernels at the published shapes --------------------------------------
@@ -409,8 +410,8 @@ def test_the_attention_kernel_matches_the_einsums_at_twenty_query_heads(live):
     pos = jnp.array([0, 1, block - 1, block, block + 1, Tw - 1, 300, 77])
     active = jnp.zeros((B,), bool).at[jnp.array(live, int)].set(True)
     with pallas_interpret():
-        got = jax.jit(lambda: da.attend(q, kf, vf, cache, jnp.int32(1),
-                                        da.schedule(active, pos, Tw, block)))()
+        got, *_ = jax.jit(lambda: da.attend(q, kf, vf, cache, jnp.int32(1),
+                                            da.schedule(active, pos, Tw, block)))()
     want = T.gqa_attention_decode(
         q, cache["k"][1], cache["v"][1], kf, vf,
         jnp.arange(Tw)[None, None, :] < pos[:, None, None])

@@ -462,7 +462,7 @@ def test_the_decode_kernel_reads_a_ring_as_the_einsums_do(live):
     mask = (s_ < pos[:, None, None]) & (s_ != (pos % ring)[:, None, None])
     want = T.gqa_attention_decode(q, cache["k"][1], cache["v"][1], kf, vf, mask)
     with pallas_interpret():
-        got = da.attend(q, kf, vf, cache, jnp.asarray(1), sched)
+        got, *_ = da.attend(q, kf, vf, cache, jnp.asarray(1), sched)
     rows = np.asarray(live, np.int32)
     np.testing.assert_allclose(np.asarray(got, np.float32)[rows],
                                np.asarray(want, np.float32)[rows], atol=2e-2, rtol=2e-2)
@@ -498,14 +498,19 @@ def test_decode_step_with_both_kernels_gives_the_einsums_logits(monkeypatch):
                                    np.asarray(cache_e[key], np.float32)[:, rows], atol=2e-3)
     np.testing.assert_array_equal(np.asarray(cache_e["kw"][:, 1, 0, 1:]),
                                   np.asarray(cache["kw"][:, 1, 0, 1:]))  # pos 128 -> row 0
-    # [read, held] over both kinds, then window read / held / unwindowed, full read / held
+    for key in ("k", "v", "kw", "vw"):  # the kernel writes no row of the slot that is not live
+        np.testing.assert_array_equal(np.asarray(cache_k[key][:, 2]), np.asarray(cache[key][:, 2]))
+        assert not np.array_equal(np.asarray(cache_e[key][:, 2]), np.asarray(cache[key][:, 2]))
+    # [read, held, rows written, slots x layers] over both kinds, then window read / held /
+    # unwindowed, full read / held
     assert list(np.asarray(counts_e)) == [2 * 4 * 256 + 3 * 4 * 128, 2 * 4 * 256 + 3 * 4 * 128,
+                                          5 * 4, 5 * 4,
                                           3 * 4 * 128, 3 * 4 * 128, 3 * (3 + 128 + 250),
                                           2 * 4 * 256, 2 * 4 * 256]
     full = 2 * 3 * 256   # a slab of 256 float32 rows is one block a live slot
     ring = 3 * 3 * 128   # and a ring of 128: min(pos, 128) in whole blocks
-    assert list(np.asarray(counts_k)) == [full + ring, counts_e[1], ring, counts_e[3],
-                                          counts_e[4], full, counts_e[6]]
+    assert list(np.asarray(counts_k)) == [full + ring, counts_e[1], 5 * 3, 5 * 4, ring,
+                                          counts_e[5], counts_e[6], full, counts_e[8]]
 
 
 # -- through the engine -----------------------------------------------------------

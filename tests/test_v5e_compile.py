@@ -144,40 +144,82 @@ def test_decode_chunk_copies_no_layer_of_the_slab(one_chip, kv_dtype):
 KERNEL_SLOTS, KERNEL_WINDOW = 64, 2048
 
 
-@pytest.mark.parametrize("stack", ["bf16", "int8", "patterned"])
+def kernel_calls(hlo: str):
+    """[(name, the computation's text)] of ops/decode_attention's custom
+    calls: their result is a tuple, the attention and K and V written."""
+    found = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%\S+ \()", hlo):
+        found += [(name, comp) for name in re.findall(
+            r"%(decode_attention[.\w]*) = \(bf16\[", comp)]
+    return found
+
+
+def cache_copies(comp: str, shapes):
+    """The lines of a computation's text that copy an array of one of
+    `shapes` (dims as the HLO prints them: "3,64,4,2048"), started, done
+    or whole: a cache array moved inside the layer loop is a pass over
+    it in every layer of every step (PERF.md section 6, PR 32 and 42)."""
+    found = []
+    for line in comp.split("\n"):
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) copy(-start|-done)?\(", line)
+        if m and any("[%s]" % dims in m.group(1) for dims in shapes):
+            found.append(line.strip()[:160])
+    return found
+
+
+def ring_config():
+    """tiny-laguna's pattern (window layers beside full ones, 6 and 8
+    queries over KV heads of 128 lanes) with 8 KV heads: a layer of the
+    ring over KERNEL_SLOTS x 512 rows is 32 Mi elements, the three window
+    layers' rings 192 MB."""
+    return get_config("tiny-laguna", d_model=256, head_dim=128, n_heads=16,
+                      n_heads_window=16, n_kv_heads=8, sliding_window=512,
+                      max_seq_len=KERNEL_WINDOW, vocab_size=512).validate()
+
+
+@pytest.mark.parametrize("stack", ["bf16", "int8", "patterned", "ring"])
 def test_decode_chunk_reads_the_slab_through_the_kernel(
         one_chip, monkeypatch, stack):
     """On a TPU the decode step's attention is ops/decode_attention: the
-    compiled chunk of the dense bf16 and int8 stacks and of a patterned
-    one holds its custom call (one a layer position of a scan body),
-    handed the slab whole, and nothing else as large as a layer of the
-    slab: no copy, transpose or slice of it, and no float32 score array
-    [slots, heads, window] (what the einsums of gqa_attention_decode
-    materialise a layer)."""
+    compiled chunk of the dense bf16 and int8 stacks, of a patterned one
+    and of one with window layers (slab and rings) holds its custom call
+    (one a layer position of a scan body), handed K and V whole and
+    aliased to its results, and NOTHING else as large as a layer of the
+    slab: no copy, transpose or slice of it, no scatter of the step's
+    fresh rows over every slot (the kernel writes the live slots'), no
+    float32 score array [slots, heads, window] (what the einsums of
+    gqa_attention_decode materialise a layer); and the computation that
+    holds the call, the layer loop's body, copies no cache array."""
     _chip_branches(monkeypatch)
     if stack == "patterned":
         # heads of 128, two to a row: a layer of K is 2 Mi elements, as
         # large as a layer of the SSM state and larger than any weight
         cfg = dataclasses.replace(
             mamba_config(), n_heads=4, n_kv_heads=2, head_dim=128).validate()
+    elif stack == "ring":
+        cfg = ring_config()
     else:
         cfg = dense_config(stack)
     SLOTS, WINDOW = KERNEL_SLOTS, KERNEL_WINDOW
-    hlo, _ = _compiled_chunk(cfg, one_chip, SLOTS, WINDOW)
+    hlo, state = _compiled_chunk(cfg, one_chip, SLOTS, WINDOW)
     row = cfg.n_kv_heads * cfg.head_dim
-    layer_k = SLOTS * WINDOW * row
-    calls = re.findall(r"%(decode_attention[.\w]*) = bf16\[", hlo)
-    assert len(calls) == 1, calls
-    slab = "%s[%d,%d,1,%d,%d]" % (
-        "s8" if stack == "int8" else "bf16",
-        cfg.n_attn_layers, SLOTS, WINDOW, row)
-    assert slab in hlo  # carried whole, in the loops' tuples
-    big = big_instructions(hlo, layer_k)
-    # what is left at that size is the step's scatter of the fresh rows
-    # into the whole slab, in place (a fusion whose result IS the slab)
-    assert {op for _, op, _, _ in big} <= {"fusion"}, big
-    assert {_elements(typ) for _, _, typ, _ in big} <= {
-        cfg.n_attn_layers * layer_k}, big
+    layer_k = SLOTS * min(WINDOW, cfg.sliding_window or WINDOW) * row
+    calls = kernel_calls(hlo)
+    assert stack == "ring" or len(calls) == 1, [n for n, _ in calls]
+    cache = state["cache"]
+    dims = {key: ",".join(map(str, a.shape)) for key, a in cache.items()}
+    written = set()  # K as each call returns it: the slab's, a ring's
+    for name, comp in calls:
+        call = re.search(
+            r"%" + re.escape(name) + r" = \(bf16\[[\d,]*\]\S* (\w+\[[\d,]*\]).*"
+            r"output_to_operand_aliasing=\{\{1\}: \(\d+, \{\}\), "
+            r"\{2\}: \(\d+, \{\}\)\}", comp)
+        assert call, name
+        written.add(call.group(1))
+        assert cache_copies(comp, dims.values()) == []
+    kv = "s8[%s]" if stack == "int8" else "bf16[%s]"
+    assert written == {kv % dims[key] for key in ("k", "kw") if key in cache}
+    assert big_instructions(hlo, layer_k) == []
     scores = SLOTS * cfg.n_heads * WINDOW
     assert [typ for _, _, typ, _ in big_instructions(hlo, scores)
             if typ.startswith("f32[")
@@ -187,10 +229,6 @@ def test_decode_chunk_reads_the_slab_through_the_kernel(
 def _dims(typ: str):
     return [int(d) for d in
             typ[typ.index("[") + 1:typ.index("]")].split(",") if d]
-
-
-def _elements(typ: str) -> int:
-    return math.prod(_dims(typ))
 
 
 def mamba_config():
@@ -252,7 +290,9 @@ def test_attention_and_mixer_in_one_layer_at_the_published_widths(
     cache = state["cache"]
     assert cache["k"].shape == (L, slots, 1, window, 512)
     assert cache["ssm"].shape == (L, slots, 32, 128, 256)
-    assert len(re.findall(r"%(decode_attention[.\w]*) = bf16\[", hlo)) == 1
+    (_, layer_loop), = kernel_calls(hlo)
+    assert cache_copies(layer_loop, [
+        ",".join(map(str, a.shape)) for a in cache.values()]) == []
     whole = re.escape("f32[%d,%d,32,128,256]" % (L, slots))
     assert len(re.findall(
         r"%(ssm_update[.\w]*) = \(" + whole + r"\S* f32\[", hlo)) == 1
@@ -263,10 +303,8 @@ def test_attention_and_mixer_in_one_layer_at_the_published_widths(
         (op, typ) for _, op, typ, _ in big_instructions(hlo, layer_k)
         if re.search(r"\b%d,(1,)?%d,512\]|\b%d,32,128,256\]"
                      % (slots, window, slots), typ)]
-    # the step's scatter of the fresh rows into the whole slab, in place
-    assert {op for op, _ in cachelike} <= {"fusion"}, cachelike
-    assert {_elements(typ) for _, typ in cachelike} <= {L * layer_k}, cachelike
-    assert not [typ for _, typ in cachelike if typ.startswith("f32[")]
+    # not even the step's scatter of the fresh rows: the kernel writes them
+    assert cachelike == []
     scores = slots * cfg.n_heads * window
     assert [typ for _, _, typ, _ in big_instructions(hlo, scores)
             if typ.startswith("f32[")
@@ -418,10 +456,14 @@ def test_prefill_attention_kernel_compiles_at_the_published_heads(
     assert compiled.memory_analysis().temp_size_in_bytes < G * S * S  # a byte a pair, at most
 
 
-def test_decode_kernel_compiles_over_a_ring_with_its_skipped_row(one_chip):
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+def test_decode_kernel_compiles_over_a_ring_with_its_skipped_row(
+        one_chip, dtype):
     """ops/decode_attention over a window layer's ring (32 slots x 512
-    rows of 1024 lanes, 64 query heads): the sixth prefetched scalar, the
-    row a slot leaves out, goes through Mosaic."""
+    rows of 1024 lanes, 64 query heads) goes through Mosaic with the row
+    a slot leaves unread and writes: the native tile of 16 bf16 rows (32
+    of int8) cut out of the block in hand at a traced offset, the fresh
+    row selected in, copied back into the aliased ring."""
     import jax.numpy as jnp
 
     from seldon_tpu.ops import decode_attention as da
@@ -429,12 +471,18 @@ def test_decode_kernel_compiles_over_a_ring_with_its_skipped_row(one_chip):
     def shaped(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def step(q, kf, vf, k, v, active, pos):
+    def step(q, kf, vf, k, v, scale, rows, active, pos):
         sched = da.schedule(active, pos, 512, da.reads(k, 128), ring=True)
-        return da.attend(q, kf, vf, {"k": k, "v": v}, jnp.asarray(1), sched)
+        cache, stored = {"k": k, "v": v}, None
+        if k.dtype == jnp.int8:
+            cache.update(k_scale=scale, v_scale=scale)
+            stored = {"k": rows, "v": rows}
+        return da.attend(q, kf, vf, cache, jnp.asarray(1), sched, stored)
 
-    compiled = jax.jit(step).lower(
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
         shaped((32, 1, 64, 128)), shaped((32, 1, 8, 128)), shaped((32, 1, 8, 128)),
-        shaped((3, 32, 1, 512, 1024)), shaped((3, 32, 1, 512, 1024)),
+        shaped((3, 32, 1, 512, 1024), dtype), shaped((3, 32, 1, 512, 1024), dtype),
+        shaped((3, 32, 8, 512)), shaped((32, 1024), jnp.int8),
         shaped((32,), jnp.bool_), shaped((32,), jnp.int32)).compile()
-    assert "decode_attention" in compiled.as_text()
+    assert kernel_calls(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 32 * 512 * 1024
