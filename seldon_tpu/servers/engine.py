@@ -35,6 +35,7 @@ import math
 import os
 import queue
 import secrets
+import statistics
 import threading
 import time
 from typing import (Any, Deque, Dict, List, NamedTuple, Optional, Sequence,
@@ -129,9 +130,14 @@ class EngineConfig:
     # throughput. A request can only be admitted at a chunk boundary;
     # with mostly-free slots (under-capacity, latency-sensitive regime) a
     # long chunk is pure admission latency, while at saturation nothing
-    # can be admitted mid-chunk anyway — so: near-empty -> min_chunk
-    # boundaries, near-full -> decode_chunk. Compiles one chunk variant
-    # per power-of-two rung (min_chunk..decode_chunk).
+    # can be admitted mid-chunk anyway — so: near-empty -> the low rung,
+    # near-full -> decode_chunk. Compiles one chunk variant per
+    # power-of-two rung (min_chunk..decode_chunk). min_chunk is no
+    # latency knob: it is the low rung's CAP and its cold-start value.
+    # The async scheduler sizes the rung once, from the step and the
+    # host turn it measures on itself, to the fewest steps whose wave
+    # still covers the turn (_chunk_steps), and compiles that one
+    # further variant when it does.
     adaptive_chunk: bool = True
     min_chunk: int = 4
     # Prompt prefix KV cache (opt-in): reuse device-resident KV of
@@ -519,6 +525,29 @@ _DEPTH_MARGIN = 2.0
 _DEPTH_SAMPLES = 8
 
 
+# A wave dispatched while slots are free lasts at least this many host
+# turns, and is no longer than that needs (_chunk_steps). Not below
+# _DEPTH_MARGIN: a wave of _CHUNK_COVER turns is a period of as many,
+# so _pipeline_depth, which reads the same waves and turns, still asks
+# for _DEPTH_MIN; a shorter chunk is never paid for with one more wave
+# dispatched ahead of every arrival. On the chip the turn's median is
+# 1.7-2.1 ms (its mean, 2.6, holds the first dispatches): 4.5 of them
+# are 7.5-9.5 ms, between the two steps of 5.7 ms that it keeps
+# together and the one step of 11 ms that it lets run alone, with a
+# fifth or more to spare on every side (PERF.md section 6, PR 47).
+_CHUNK_COVER = 4.5
+
+
+def _chunk_steps(step_s: float, turn_s: float, cap: int) -> int:
+    """The fewest decode steps, a power of two and at most `cap`, whose
+    wave covers _CHUNK_COVER host turns. A 13.8 ms step behind a 1.9 ms
+    turn: 1; a 6.1 ms step: 2; a 3.3 ms step: the cap of 4."""
+    n = 1
+    while n < cap and n * step_s < _CHUNK_COVER * turn_s:
+        n *= 2
+    return min(n, cap)
+
+
 def _pipeline_depth(period_s: float, turn_s: float) -> int:
     """The smallest D in [_DEPTH_MIN, _DEPTH_MAX] whose D - 1 queued
     wave periods cover _DEPTH_MARGIN host turns. An 81 ms wave behind a
@@ -553,11 +582,22 @@ class _DepthEstimator:
     host turn: a wave's results on the host -> the dispatch that its
     retirement let through returned (fetch processing, wake-up,
     _dispatch_once), plus what a device_get costs when the device had
-    finished before it was called (the transfer, not the wait)."""
+    finished before it was called (the transfer, not the wait).
+    step: the period of a wave that carried no admission, over its
+    decode steps. The low rung's length is sized from it and the turn
+    (chunk_steps), once, so from the medians of the last few dozen
+    samples and not from the means: the first dispatch of a variant
+    (a compile, or a read of the compile cache) is a turn of seconds
+    and leaves the device dry for a step as long, a warm-up is full of
+    them, and a mean remembers one for eight waves."""
 
     def __init__(self):
         self.period = _RunningMean()
         self.turn = _RunningMean()
+        self.steps: Deque[float] = collections.deque(
+            maxlen=4 * _DEPTH_SAMPLES)
+        self.turns: Deque[float] = collections.deque(
+            maxlen=4 * _DEPTH_SAMPLES)
         self.transfer = _RunningMean()
         self.fetched_at: Optional[float] = None  # the last retired wave's
         self._paced_from: Optional[float] = None
@@ -567,20 +607,37 @@ class _DepthEstimator:
             return _DEPTH_MIN
         return _pipeline_depth(self.period.value, self.host_turn_s())
 
+    def chunk_steps(self, cap: int) -> Optional[int]:
+        """The low rung's length (_chunk_steps), None until the step
+        and the turn have their samples."""
+        if min(len(self.steps), len(self.turns)) < self.steps.maxlen:
+            return None
+        return _chunk_steps(self.step_s(), self.typical_turn_s(), cap)
+
+    def step_s(self) -> float:
+        return statistics.median(self.steps) if self.steps else 0.0
+
+    def typical_turn_s(self) -> float:
+        return ((statistics.median(self.turns) if self.turns else 0.0)
+                + self.transfer.value)
+
     def host_turn_s(self) -> float:
         return self.turn.value + self.transfer.value
 
     def note_retire(self, fetched_at: Optional[float], get_s: Optional[float],
-                    more_in_flight: bool) -> None:
+                    more_in_flight: bool, steps: int = 0) -> None:
         """One wave left the registry. `fetched_at`: when its results
         reached the host, None for a wave dropped unread (stale epoch,
         fault), which says nothing of the device's pace. `get_s`: what
-        its device_get took if the device had already finished."""
+        its device_get took if the device had already finished.
+        `steps`: its decode steps if they were all it ran, else 0."""
         if fetched_at is None:
             self._paced_from = None
             return
         if self._paced_from is not None:
             self.period.add(fetched_at - self._paced_from)
+            if steps:
+                self.steps.append((fetched_at - self._paced_from) / steps)
         self._paced_from = fetched_at if more_in_flight else None
         self.fetched_at = fetched_at
         if get_s is not None:
@@ -599,6 +656,7 @@ class _DepthEstimator:
             turn = min(turn, (_DEPTH_MAX - 1) * self.period.value
                        / _DEPTH_MARGIN)
         self.turn.add(turn)
+        self.turns.append(turn)
 
     def gauges(self) -> Dict[str, float]:
         return {
@@ -1305,32 +1363,28 @@ class InferenceEngine:
             sizes = [lo, mid, top]
         else:
             sizes = [top]
+        # Rebound whole, by the scheduler under _book: a reader outside
+        # the lock sees one ladder or the other.
         self._chunk_sizes = tuple(sorted(set(sizes)))
-        self._jit_chunks = {
-            n: jax.jit(
-                _named_partial(
-                    self._chunk_impl,
-                    cfg=self.cfg,
-                    n_steps=n,
-                    mesh=mesh,
-                    **tpkw,
-                ),
+        # The low rung starts at min_chunk and is sized once, when the
+        # depth estimator has its samples (_size_low_rung): a single
+        # fixed length has no low rung to size.
+        self._rung_sized = len(self._chunk_sizes) == 1  # graftlint: guarded-by(_book)
+
+        def chunk_jit(impl, n):
+            return jax.jit(
+                _named_partial(impl, cfg=self.cfg, n_steps=n, mesh=mesh,
+                               **tpkw),
                 donate_argnums=(1,),
             )
-            for n in self._chunk_sizes
+
+        self._chunk_jit = chunk_jit
+        self._jit_chunks = {
+            n: chunk_jit(self._chunk_impl, n) for n in self._chunk_sizes
         }
         if self._paged:
             self._jit_chunks_paged = {
-                n: jax.jit(
-                    _named_partial(
-                        self._paged_chunk_impl,
-                        cfg=self.cfg,
-                        n_steps=n,
-                        mesh=mesh,
-                        **tpkw,
-                    ),
-                    donate_argnums=(1,),
-                )
+                n: chunk_jit(self._paged_chunk_impl, n)
                 for n in self._chunk_sizes
             }
         # Lifecycle reaping: one masked write freezes cancelled/expired
@@ -2445,12 +2499,14 @@ class InferenceEngine:
             return sum(1 for r in self._slots if r is not None)
 
     def pipeline_gauges(self) -> Dict[str, float]:
-        """The async scheduler's pipeline depth in force and the two
-        running means it follows from (_DepthEstimator), read under the
-        bookkeeping lock. A synchronous loop is one deep and measures
-        neither."""
+        """The async scheduler's pipeline depth in force, the two
+        running means it follows from (_DepthEstimator) and the steps
+        of a chunk dispatched while slots are free (the low rung,
+        _size_low_rung), read under the bookkeeping lock. A synchronous
+        loop is one deep, measures neither and keeps min_chunk."""
         with self._book:
             g = self._depth_est.gauges()
+            g["chunk_steps"] = self._chunk_sizes[0]
         if not self._async_fetch:
             g["depth"] = 1
         return g
@@ -4688,8 +4744,12 @@ class InferenceEngine:
         for i, wave in enumerate(self._inflight_waves):
             if wave is item:
                 del self._inflight_waves[i]
+                # Only a wave that ran decode steps alone times a step
+                # (chunk_handles[0] is the tokens array [steps, slots]).
+                steps = (wave.chunk_handles[0].shape[0]
+                         if wave.chunk_handles and not wave.admits else 0)
                 self._depth_est.note_retire(
-                    fetched_at, get_s, bool(self._inflight_waves)
+                    fetched_at, get_s, bool(self._inflight_waves), steps
                 )
                 self._room.set()
                 return
@@ -5236,9 +5296,12 @@ class InferenceEngine:
         free, a mid-chunk arrival would have waited for completions
         anyway, so the full decode_chunk costs nothing and amortizes the
         host round trip. With real free capacity, boundaries stay at
-        min_chunk so TTFT tracks the unloaded floor (one engine holds
+        the low rung so TTFT tracks the unloaded floor (one engine holds
         both the SLO and the saturated-throughput claims — the policy
-        the old chunk-4-vs-64 mode switch approximated by hand)."""
+        the old chunk-4-vs-64 mode switch approximated by hand). The
+        low rung is min_chunk steps at most and as few as still cover
+        the host turn (_size_low_rung): every term of a first token's
+        wait but its own prefill is a multiple of it."""
         sizes = self._chunk_sizes
         if len(sizes) == 1:
             return sizes[0]
@@ -5263,6 +5326,47 @@ class InferenceEngine:
             idx = max(0, min(idx + self._pilot.chunk_bias(),
                              len(sizes) - 1))
         return sizes[idx]
+
+    def _size_low_rung(self) -> None:  # graftlint: holds(_book)
+        """Size the low rung, once: the fewest steps whose wave covers
+        the host turn (_DepthEstimator.chunk_steps), never more than
+        min_chunk, as soon as the estimator has its samples. A rung
+        below min_chunk joins the ladder, and its first dispatch
+        compiles it: one program, on the serving path, which is why
+        the choice is not made again as rows come and go (a step at
+        eight live rows is three times one at one row, and a rung that
+        followed it would compile under load), and why it is made in
+        the engine's first waves or not at all: an estimator still
+        short of samples after four times as many waves has seen the
+        device set the pace of fewer than one in four, the host is
+        what a wave waits for there, and min_chunk stays. Only
+        _loop_async's waves reach the estimator, so the synchronous
+        loops keep min_chunk too; the ragged and speculative waves
+        never pick a chunk."""
+        est = self._depth_est
+        cap = self._chunk_sizes[0]
+        n = est.chunk_steps(cap)
+        if n is None:
+            if self._wave_seq < 4 * est.steps.maxlen:
+                return
+            n = cap
+        self._rung_sized = True
+        logger.info(
+            "low rung sized at wave %d: %d steps a chunk (%d step and %d "
+            "turn samples: step %.2f ms, host turn %.2f ms)",
+            self._wave_seq, n, len(est.steps), len(est.turns),
+            1000.0 * est.step_s(), 1000.0 * est.typical_turn_s(),
+        )
+        if n == cap:
+            return
+        if self._paged:
+            self._jit_chunks_paged[n] = self._chunk_jit(
+                self._paged_chunk_impl, n)
+        else:
+            self._jit_chunks[n] = self._chunk_jit(self._chunk_impl, n)
+        self._chunk_sizes = (n,) + self._chunk_sizes
+        if self._cledger is not None:
+            self._cledger.declare(("decode", n))
 
     def _recycle_budget_spent(self, roster: List[Optional[_Request]],  # graftlint: holds(_book)
                               chunk_len: int) -> None:
@@ -5589,8 +5693,9 @@ class InferenceEngine:
         profiler's clock: in a device profile it shows what the
         scheduler was doing in the gap before a program. Metadata: the
         wave's sequence number, the requests it admitted, its decode
-        steps, and the pipeline depth in force with the two means it
-        follows from (_DepthEstimator)."""
+        steps, the low rung in force (_size_low_rung) and the pipeline
+        depth in force with the two means it follows from
+        (_DepthEstimator)."""
         with jax.profiler.TraceAnnotation("sched.dispatch") as span:
             work = self._dispatch_wave()
             if work is not None:
@@ -5601,6 +5706,7 @@ class InferenceEngine:
                     # chunk_handles[0] is the tokens array [steps, slots]
                     chunk_steps=work.chunk_handles[0].shape[0]
                     if work.chunk_handles else 0,
+                    low_rung=self._chunk_sizes[0],
                     **self._depth_est.gauges(),
                 )
         return work
@@ -5631,6 +5737,8 @@ class InferenceEngine:
         if admits or self._active_host.any():
             roster = self._roster()
             self._dispatch_wreck = _PendingWave(admits, None, roster, None)
+            if not self._rung_sized:
+                self._size_low_rung()
             n = self._pick_chunk()
             out = self._dispatch_decode_chunk(n)
             self._state = out[0]

@@ -197,7 +197,9 @@ def test_waves_in_flight_never_exceed_the_depth(mode):
     assert all(2 <= depth <= 5 for _, depth in seen["dispatch"])
     assert eng._fetch_q.maxsize == 0  # one bound, not two
     g = eng.pipeline_gauges()
-    assert set(g) == {"depth", "wave_period_ms", "host_turn_ms"}
+    assert set(g) == {"depth", "wave_period_ms", "host_turn_ms",
+                      "chunk_steps"}
+    assert g["chunk_steps"] == eng.chunk_sizes[0] <= 4
     assert 2 <= g["depth"] <= 5 and g["wave_period_ms"] > 0.0
 
 

@@ -502,6 +502,10 @@ def test_metrics_are_current_when_scraped(rest_unit):
     assert 2 <= _gauge(text, "jaxserver_sched_depth") <= 5
     assert _gauge(text, "jaxserver_sched_wave_period_ms") >= 0.0
     assert _gauge(text, "jaxserver_sched_host_turn_ms") >= 0.0
+    # ... and what its other terms are multiples of: the steps of a chunk
+    # dispatched while slots are free, min_chunk or fewer
+    assert (_gauge(text, "jaxserver_sched_chunk_steps")
+            == srv.engine.chunk_sizes[0] <= srv.engine.ecfg.min_chunk)
 
 
 def test_scrape_repeats_no_counter_of_the_unit():

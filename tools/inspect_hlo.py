@@ -1,5 +1,5 @@
 """What the TPU v5e's compiler makes of the engine's own programs, with
-no chip: AOT-compile `_chunk_impl` (4 steps) or `_admit_impl` of a
+no chip: AOT-compile `_chunk_impl` (4 steps, or --steps) or `_admit_impl` of a
 benchmark configuration at the cells' 64 slots x 1024 (--slots, --window:
 laguna.code runs 32 x 4096) for the described
 topology "v5e:2x2" (libtpu compiles for a chip that is not attached;
@@ -83,6 +83,9 @@ def main(argv=None) -> int:
     ap.add_argument("config", help="a file of benchmark/configs, by name")
     ap.add_argument("program", help="chunk, or admit/<bucket>/<group>")
     ap.add_argument("--dump", help="write the optimized HLO text here")
+    ap.add_argument("--steps", type=int, default=STEPS,
+                    help="decode steps of the chunk (the low rung may be "
+                         "sized to 1 or 2: engine._size_low_rung)")
     ap.add_argument("--slots", type=int, default=SLOTS)
     ap.add_argument("--window", type=int, default=WINDOW,
                     help="the engine's max_seq_len (laguna.code: 32 x 4096)")
@@ -123,7 +126,7 @@ def main(argv=None) -> int:
         transformer.init_cache(cfg, args.slots, args.window), args.slots))
     if args.program == "chunk":
         fn = engine._named_partial(InferenceEngine._chunk_impl, cfg=cfg,
-                                   n_steps=STEPS)
+                                   n_steps=args.steps)
         more = ()
     else:
         _, Sb, G = args.program.split("/")
