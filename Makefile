@@ -141,7 +141,7 @@ roof-audit:
 	env JAX_PLATFORMS=cpu python -m tools.roof_audit
 
 # Tensor-parallel serving gate (docs/operations.md "Serving on the
-# mesh"): the tiny ragged server booted twice on the fake 8-device CPU
+# mesh"): the tiny paged + chunked server booted twice on the fake 8-device CPU
 # mesh — pinned to an explicit single chip (tp=1), then as a TP=2
 # group via the env knob behind the real REST app — under a loadtester
 # window with GRAFTSAN + SCHED_LEDGER + COMPILE_LEDGER + HBM_LEDGER +

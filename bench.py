@@ -96,17 +96,9 @@ PAGED = os.environ.get("BENCH_PAGED", "0") == "1"
 # and final knob values (tools/bench_compare.py gates slo_goodput
 # higher-is-better and pilot_edf_inversions lower-is-better).
 PILOT_PHASE = os.environ.get("BENCH_PILOT", "0") == "1"
-# Ragged phase: the same mixed-length closed wave run twice at equal
-# hardware — graftragged unified dispatch (RAGGED=1 semantics) vs the
-# bucketed lattice — so the bench line carries per-leg req/s and
-# padding_waste_frac, the ragged leg's compile-variant count (strictly
-# gated by tools/bench_compare.py), and the measured ragged req/s
-# against the bucketed leg's own waste_roofline prediction. Recorded in
-# detail.ragged.
-RAGGED_PHASE = os.environ.get("BENCH_RAGGED", "0") == "1"
 # Spec phase: the same greedy closed wave run twice at equal hardware —
 # graftspec speculative decoding (SPEC=1 semantics: draft k, verify in
-# one ragged wave) vs plain decode — so the bench line carries per-leg
+# one wide wave) vs plain decode — so the bench line carries per-leg
 # decode tok/s, the spec leg's acceptance rate and dispatches/token
 # (tools/bench_compare.py gates spec_acceptance_rate higher-is-better
 # and decode tok/s no-regression). BENCH_SPEC_DRAFT picks the drafter:
@@ -117,8 +109,8 @@ RAGGED_PHASE = os.environ.get("BENCH_RAGGED", "0") == "1"
 SPEC_PHASE = os.environ.get("BENCH_SPEC", "0") == "1"
 SPEC_K = int(os.environ.get("BENCH_SPEC_K", "4"))
 SPEC_DRAFT = os.environ.get("BENCH_SPEC_DRAFT", "self")
-# Mesh phase: the same greedy ragged closed wave run twice at EQUAL
-# engine config — an explicit single chip (tp=1) vs a BENCH_MESH_TP-way
+# Mesh phase: the same greedy paged + chunked closed wave run twice at
+# EQUAL engine config — an explicit single chip (tp=1) vs a BENCH_MESH_TP-way
 # graftmesh tensor-parallel group (servers/mesh_engine.py exact-TP
 # sharding) — so the bench line carries per-leg req/s and decode tok/s,
 # the bit-exact parity assert (exact-TP shards only output dims, so the
@@ -489,12 +481,10 @@ def _sched_counts(engine, req_s: float = 0.0) -> dict:
     scalar (pad + fragmentation share of offered capacity — lower is
     better, gated by tools/bench_compare.py), its per-cause breakdown,
     and — when `req_s` is supplied — the roofline headroom report:
-    req/s the two open perf roadmap items would reclaim at this
-    measured waste. Ragged paged attention (ROADMAP item 1) eliminates
-    bucket + group padding, so its ceiling is req_s / (1 - pad_frac);
-    dense-slab deletion (item 2) frees the HBM that forces pool stalls
-    and preemptions, so its number is the stall/preempt churn this run
-    actually paid. Empty when the ledger is off."""
+    what the measured waste costs. With no bucket or group padding the
+    ceiling is req_s / (1 - pad_frac); freeing the dense slab's HBM
+    would avoid the pool stalls and preemptions this run actually
+    paid. Empty when the ledger is off."""
     snap = engine.debug_sched()
     if snap is None:
         return {}
@@ -516,12 +506,12 @@ def _sched_counts(engine, req_s: float = 0.0) -> dict:
         out["spec_accepted_tokens"] = spec["accepted_tokens"]
     if req_s > 0.0:
         out["waste_roofline"] = {
-            "ragged_attention_req_s": round(
+            "padding_free_req_s": round(
                 req_s / (1.0 - pad_frac) if pad_frac < 1.0 else req_s, 2
-            ),  # ROADMAP item 1: padding-free ceiling
+            ),
             "slab_deletion_stalls": snap["pool_stall_events"],
             "slab_deletion_preempted_tokens": snap["preempted_tokens"],
-        }  # ROADMAP item 2: the churn freed HBM would avoid
+        }
     return out
 
 
@@ -948,138 +938,6 @@ def _measure_paged(params, cfg) -> dict:
     }
 
 
-def _measure_ragged(params, cfg) -> dict:
-    """BENCH_RAGGED phase: one mixed-length closed wave run twice at
-    equal hardware — the bucketed lattice vs graftragged's unified
-    dispatch, both on the same paged + chunked substrate, same pool,
-    same slots. The bucketed leg's sched ledger prices the padding its
-    buckets and pow2 groups paid AND emits the waste_roofline
-    prediction (req/s at zero padding); the ragged leg then has to cash
-    that prediction on the same wave: the report carries per-leg req/s
-    + padding_waste_frac, the ragged leg's compile-variant count
-    (collapse contract: ≤ 2, gated strictly by bench_compare), and
-    ragged_vs_roofline — measured over predicted.
-
-    graftkern adds the kernel axis: the same wave re-run GREEDY per
-    RAGGED_KERNEL leg (masked vs sparse — greedy because that is the
-    legs' token-identity contract), token streams asserted bit-equal,
-    with detail.ragged.kernel carrying per-leg req/s plus the gated
-    sparse_vs_masked_speedup / sparse_vs_bucketed_speedup ratios."""
-    import numpy as np
-
-    from seldon_tpu.models.sampling import SamplingParams
-    from seldon_tpu.servers.engine import EngineConfig, InferenceEngine
-
-    bs = 16          # KV block
-    chunk = 32       # ragged segment / prefill chunk (pow2, bs-aligned)
-    new_toks = min(NEW_TOKENS, 16)
-    slots = 8
-    # Mixed lengths straddling the bucket grid: the bucketed leg rounds
-    # 24->32 and 48/96->128 and pads pow2 admission groups; the ragged
-    # leg packs the exact counts.
-    lengths = [24, 48, 96, 16]
-    smax = 128  # max prompt 96 + 16 new + slack, block-aligned
-    n_req = 3 * slots
-    pool_blocks = slots * (smax // bs) + 1  # full residency + trash
-    rng = np.random.default_rng(29)
-    prompts = [
-        rng.integers(3, cfg.vocab_size,
-                     size=(lengths[i % len(lengths)],)).tolist()
-        for i in range(n_req)
-    ]
-
-    def leg(ragged: bool, kernel: str = "masked", greedy: bool = False):
-        ecfg = EngineConfig(
-            max_slots=slots,
-            max_seq_len=smax,
-            prompt_buckets=(32, 128),
-            max_admit=4,
-            decode_chunk=4,
-            paged_kv=True, kv_block=bs, kv_pool_blocks=pool_blocks,
-            chunked_prefill=True, prefill_chunk=chunk, prefix_block=bs,
-            ragged=ragged,
-            ragged_kernel=kernel if ragged else "masked",
-        )
-        engine = InferenceEngine(params, cfg, ecfg)
-        engine.warmup()
-        engine.start()
-        t0 = time.perf_counter()
-        qs = [engine.submit(p, SamplingParams(
-                  temperature=0.0 if greedy else 0.7, top_k=0, top_p=1.0,
-                  max_new_tokens=new_toks, seed=i))
-              for i, p in enumerate(prompts)]
-        streams = []
-        for q in qs:
-            toks = []
-            while True:
-                item = q.get(timeout=300)
-                if item is None:
-                    break
-                if "error" in item:
-                    raise RuntimeError(item["error"])
-                toks.extend(item.get("tokens", []))
-            streams.append(toks)
-        dt = time.perf_counter() - t0
-        req_s = n_req / dt
-        out = {
-            "req_per_s": round(req_s, 3),
-            "makespan_s": round(dt, 3),
-            **_compile_counts(engine),
-            **_sched_counts(engine, req_s=req_s),
-            **_roof_counts(engine, req_s=req_s,
-                           prompt_len=int(np.mean(lengths)),
-                           max_new=new_toks),
-        }
-        engine.stop()
-        return out, streams
-
-    bucketed, _ = leg(ragged=False)
-    ragged_leg, _ = leg(ragged=True)
-    # graftkern kernel axis: the same wave greedy per kernel leg. The
-    # legs' contract is greedy token-identity, so the bit-parity assert
-    # IS part of the benchmark — a fast-but-wrong kernel must fail
-    # here, not ship a number.
-    kern_masked, want = leg(ragged=True, kernel="masked", greedy=True)
-    kern_sparse, got = leg(ragged=True, kernel="sparse", greedy=True)
-    if got != want:
-        raise RuntimeError(
-            "ragged kernel=sparse diverged from masked greedy stream")
-    # Greedy bucketed twin for the sparse-vs-bucketed ratio: greedy
-    # streams run to full max_new_tokens (no sampled-EOS early exits),
-    # so the ratio must compare legs doing identical token work.
-    kern_bucketed, _ = leg(ragged=False, greedy=True)
-    roofline = bucketed.get("waste_roofline", {}).get(
-        "ragged_attention_req_s", 0.0)
-    return {
-        "bucketed": bucketed,
-        "ragged": ragged_leg,
-        "speedup": (round(ragged_leg["req_per_s"]
-                          / bucketed["req_per_s"], 3)
-                    if bucketed["req_per_s"] else None),
-        "roofline_req_s": roofline,
-        # Measured over predicted: ~1.0 means the unified kernel cashed
-        # exactly the padding the bucketed leg paid; < 1.0 is the gap
-        # the wave kernel itself still owes.
-        "ragged_vs_roofline": (round(ragged_leg["req_per_s"] / roofline, 3)
-                               if roofline else None),
-        "kernel": {
-            "masked": kern_masked,
-            "sparse": kern_sparse,
-            "bit_identical": True,
-            "sparse_vs_masked_speedup": (
-                round(kern_sparse["req_per_s"] / kern_masked["req_per_s"], 3)
-                if kern_masked["req_per_s"] else None),
-            "bucketed_greedy": kern_bucketed,
-            # vs the bucketed lattice at identical (greedy) token work:
-            # the graftragged padding loss the sparse walker un-does.
-            "sparse_vs_bucketed_speedup": (
-                round(kern_sparse["req_per_s"]
-                      / kern_bucketed["req_per_s"], 3)
-                if kern_bucketed["req_per_s"] else None),
-        },
-    }
-
-
 def _measure_spec(params, cfg) -> dict:
     """BENCH_SPEC phase: one greedy closed wave run twice at equal
     hardware — plain paged decode vs graftspec speculative decoding on
@@ -1180,8 +1038,8 @@ def _measure_spec(params, cfg) -> dict:
 
 
 def _measure_mesh(params, cfg) -> dict:
-    """BENCH_MESH phase: the same greedy ragged closed wave run twice
-    at EQUAL engine config — an explicit single chip vs a MESH_TP-way
+    """BENCH_MESH phase: the same greedy paged + chunked closed wave run
+    twice at EQUAL engine config — an explicit single chip vs a MESH_TP-way
     graftmesh tensor-parallel group on the same substrate, same pool,
     same slots. Exact-TP shards only output dims (models/tp_sharding),
     so the mesh leg must reproduce the single-chip stream bit for bit;
@@ -1228,7 +1086,6 @@ def _measure_mesh(params, cfg) -> dict:
             decode_chunk=4,
             paged_kv=True, kv_block=bs, kv_pool_blocks=pool_blocks,
             chunked_prefill=True, prefill_chunk=32, prefix_block=bs,
-            ragged=True,
         )
         if leg_tp > 1:
             engine = MeshEngine(params, cfg, ecfg, tp=leg_tp)
@@ -1468,8 +1325,6 @@ def main() -> None:
         detail["paged"] = _measure_paged(params, cfg)
     if PILOT_PHASE:
         detail["pilot"] = _measure_pilot(params, cfg, sp)
-    if RAGGED_PHASE:
-        detail["ragged"] = _measure_ragged(params, cfg)
     if SPEC_PHASE:
         detail["spec"] = _measure_spec(params, cfg)
     if MESH_PHASE:

@@ -14,11 +14,10 @@ and depth of llama3-8b with seeded random int8 weights:
      buckets, and the first request again (must repeat token for token).
      /metrics must then count every request completed and none failed.
      The child is stopped before anything else touches the chip.
-  2. KERNELS. Only after the child has exited does this process import
-     JAX: both Pallas kernels run COMPILED on the chip at this model's
-     head geometry and are compared with their jnp references — flash
-     attention at a prefill shape, the ragged paged-attention partials
-     for a decode wave and a chunk wave over bf16 and int8 pools.
+  2. KERNEL. Only after the child has exited does this process import
+     JAX: the flash-attention Pallas kernel runs COMPILED on the chip at
+     this model's head geometry, at a prefill shape, and is compared
+     with its jnp reference.
 
 A chip belongs to one process at a time, hence the order. Set-up
 seconds, compile seconds and peak HBM are printed as bring-up
@@ -361,13 +360,10 @@ def run_kernel_phase(args) -> dict:
 
     sys.path.insert(0, HERE)
     from seldon_tpu import device
-    from seldon_tpu.models import transformer
-    from seldon_tpu.ops import ragged_paged_attention as rpa
     from seldon_tpu.ops.flash_attention import (
         attention_reference,
         flash_attention,
     )
-    from seldon_tpu.servers.engine import EngineConfig
 
     cache_dir = device.enable_compile_cache()
     dev = jax.devices()[0]
@@ -378,19 +374,14 @@ def run_kernel_phase(args) -> dict:
           f"{args.platform!r}")
     info(f"kernels: on {found}, compile cache {cache_dir}")
     if not args.rehearse:
-        # llama3-8b head geometry; prefill at the 512 bucket; the pool
-        # the engine would build for SLOTS x WINDOW at its kv_block.
-        Hkv, G, Dh, S, B = 8, 4, 128, 512, SLOTS
-        block = EngineConfig.kv_block
-        nbs = WINDOW // block
-        chunk = EngineConfig.prefill_chunk
+        # llama3-8b head geometry; prefill at the 512 bucket.
+        Hkv, G, Dh, S = 8, 4, 128, 512
         mode, how = contextlib.nullcontext(), "compiled"
     else:
         # Rehearsal: same code, interpreted, at a size a CPU finishes.
         from jax.experimental.pallas import tpu as pltpu
 
-        Hkv, G, Dh, S, B = 2, 2, 16, 32, 4
-        block, nbs, chunk = 8, 8, 8
+        Hkv, G, Dh, S = 2, 2, 16, 32
         mode, how = pltpu.force_tpu_interpret_mode(), "INTERPRETED"
     key = jax.random.key(0)
 
@@ -419,44 +410,6 @@ def run_kernel_phase(args) -> dict:
                 jnp.repeat(k, G, axis=0).astype(jnp.float32),
                 jnp.repeat(v, G, axis=0).astype(jnp.float32), causal=True)
         close(got, want, f"flash_attention[{Hkv * G}x{S}x{Dh}]", 3e-2, 3e-2)
-
-        # ragged paged partials: decode wave (Sq=1) and chunk wave, over
-        # bf16 and int8 pools with disjoint per-slot tables (block 0 is
-        # the trash block) and bounds from empty to the full window.
-        NB = B * nbs + 1
-        table = jnp.asarray(
-            1 + np.arange(B * nbs, dtype=np.int32).reshape(B, nbs))
-        edge = np.array([0, 5, block, block + 3, nbs * block], np.int32)
-        for kv_dtype in ("bf16", "int8"):
-            raw_k = jax.random.normal(jax.random.fold_in(key, 1),
-                                      (NB, Hkv, block, Dh), jnp.bfloat16)
-            raw_v = jax.random.normal(jax.random.fold_in(key, 2),
-                                      (NB, Hkv, block, Dh), jnp.bfloat16)
-            if kv_dtype == "int8":
-                kq8, ks = transformer._quantize_kv(raw_k)
-                vq8, vs = transformer._quantize_kv(raw_v)
-                layer = {"k": kq8, "v": vq8, "k_scale": ks, "v_scale": vs}
-            else:
-                layer = {"k": raw_k, "v": raw_v}
-            for sq in (1, chunk):
-                qq = jax.random.normal(jax.random.fold_in(key, 3 + sq),
-                                       (B, sq, Hkv, G, Dh), jnp.bfloat16)
-                # Row b's query s sees the first (bound_b - (sq-1-s))
-                # pool tokens: a causal chunk tail, clipped at empty.
-                base_b = np.resize(edge, B)
-                bound = jnp.asarray(np.clip(
-                    base_b[:, None] - (sq - 1 - np.arange(sq))[None, :],
-                    0, None).astype(np.int32))
-                _, l, acc = jax.jit(rpa.partials_pallas)(
-                    qq, layer, table, bound)
-                with jax.default_matmul_precision("highest"):
-                    _, rl, racc = rpa.partials_reference(
-                        qq, layer, table, bound)
-                # Empty rows need no mask: both legs leave (l, acc) = 0.
-                close(acc / jnp.maximum(l, 1e-30),
-                      racc / jnp.maximum(rl, 1e-30),
-                      f"ragged_paged_partials[{kv_dtype} Sq={sq} "
-                      f"block={block}]", 2e-2, 2e-2)
     return found
 
 

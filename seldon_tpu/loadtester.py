@@ -352,10 +352,7 @@ def _compile_counts(url: str) -> dict:
         ) as resp:
             comp = json.loads(resp.read())
         # Per-family counts alongside the total: a key's family is its
-        # first '/'-segment ("admit-prefix/64/16/1" -> "admit-prefix"),
-        # so the graftragged collapse is legible in the post-run ledger
-        # — a ragged run shows {"deactivate": 1, "ragged": 1} where the
-        # bucketed lattice fans out per family.
+        # first '/'-segment ("admit-prefix/64/16/1" -> "admit-prefix").
         by_family: dict = {}
         for entry in comp.get("lattice", []):
             fam = str(entry["key"]).split("/", 1)[0]

@@ -2,10 +2,10 @@
 by one rule.
 
 Every engine program that touches a slot (the dense, prefix, chunked and
-paged admissions, the dense and paged decode chunks, the ragged wave's
-two phases, the speculative verify wave) calls these functions, so the
-paths cannot drift: the same seed and prompt give the same completion
-whatever else shares the batch and whichever path serves it.
+paged admissions, the dense and paged decode chunks, the speculative
+verify wave) calls these functions, so the paths cannot drift: the same
+seed and prompt give the same completion whatever else shares the batch
+and whichever path serves it.
 
 State is a dict of [B] arrays beside the KV cache (`fresh`). Keys: the
 first token of a request is drawn under key(seed) folded with the

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 
 from seldon_tpu.models import slot, transformer
 from seldon_tpu.models.config import ModelConfig
-from seldon_tpu.ops import ragged_paged_attention as rpa
 
 Cache = Dict[str, jnp.ndarray]
 State = Dict[str, Any]
@@ -155,130 +154,6 @@ def _run_blocks_verify(params, x, cfg, positions, inv_freq, mask_lt, pool,
     return x, fresh, jnp.mean(aux)
 
 
-def _run_blocks_verify_sparse(params, x, cfg, positions, inv_freq, pool,
-                              table, bound, tp=None, mode="sparse"):
-    """Block-sparse twin of _run_blocks_verify (graftkern): the pool
-    contribution comes from the walker's online-softmax partials over
-    live blocks bounded at each row's pre-wave ``pos`` — NOT pos + i:
-    pool positions >= pos hold stale rejected drafts that the masked
-    path shadows with its in-view scatter — and the wave's own suffix
-    columns join the combine directly from the cache-dtype ``view``
-    arrays (query row s sees suffix rows u < s exactly as the masked
-    path reads them back out of the scattered view; the diagonal stays
-    the exact bf16 fresh column). The combine is manual because the
-    diagonal's value rows are query-row-dependent, which
-    rpa.combine_fresh's shared-value contract cannot express."""
-    quantized = cfg.kv_cache_dtype == "int8"
-    B, Sq = positions.shape
-    Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
-    Smax = table.shape[1] * pool["k"].shape[3]
-    bound2 = jnp.broadcast_to(bound[:, None], (B, Sq)).astype(jnp.int32)
-    offs = jnp.arange(Sq)
-    # Row s sees suffix column u strictly before it (u < s; u == s is
-    # the exact diagonal) and only in-window columns — the masked
-    # path's view scatter drops OOB writes (mode="drop").
-    suf_mask = (offs[None, :] < offs[:, None])[None] \
-        & (positions[:, None, :] < Smax)  # [B, s, u]
-    sm5 = suf_mask[:, None, None, :, :]
-
-    def body(carry, xs):
-        bp, pl = xs
-        h = transformer.rms_norm(carry, bp["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = transformer._qkv(h, bp, cfg, positions, inv_freq,
-                                   tp=tp)
-        if quantized:
-            kq, ksc = transformer._quantize_kv(k)  # [B,Sq,Hkv,(Dh)]
-            vq, vsc = transformer._quantize_kv(v)
-            view = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
-        else:
-            dt = pool["k"].dtype
-            view = {"k": k.astype(dt), "v": v.astype(dt)}
-        qr = q.reshape(B, Sq, Hkv, -1, Dh)
-        s_suf = jnp.einsum(
-            "bskgd,bukd->bkgsu", qr, view["k"].astype(qr.dtype),
-            preferred_element_type=jnp.float32,
-        ) / (Dh**0.5)
-        if quantized:
-            # graftlint: allow(num-barrier) factored-scale scores stay
-            # f32 end to end (preferred_element_type above, softmax
-            # below) — there is no low-precision rounding boundary for
-            # fusion placement to move.
-            s_suf = s_suf \
-                * view["k_scale"].transpose(0, 2, 1)[:, :, None, None, :]
-        s_suf = jnp.where(sm5, s_suf, rpa.NEG_INF)
-        s_diag = jnp.einsum(
-            "bskgd,bskd->bkgs", qr, k.astype(qr.dtype),
-            preferred_element_type=jnp.float32,
-        )[..., None] / (Dh**0.5)
-        vd = v.transpose(0, 2, 1, 3)[:, :, None, :, :]  # [B,Hkv,1,Sq,Dh]
-        if mode == "sparse":
-            # Masked-MATCHED two-pass (ops/ragged_paged_attention):
-            # pool + suffix weights normalized in f32, x v_scale,
-            # rounded to the query dtype, f32-accumulated with one
-            # cast; the exact bf16 diagonal rides
-            # gqa_attention_verify's second-einsum convention.
-            m_p, l_p = rpa.sparse_max_sum(qr, pl, table, bound2)
-            m_t = jnp.maximum(
-                jnp.maximum(m_p, jnp.max(s_suf, axis=-1, keepdims=True)),
-                s_diag,
-            )
-            pw = jnp.where(sm5, jnp.exp(s_suf - m_t), 0.0)
-            p_d = jnp.exp(s_diag - m_t)
-            l_t = l_p * jnp.exp(m_p - m_t) \
-                + jnp.sum(pw, axis=-1, keepdims=True) + p_d
-            acc = rpa.sparse_weighted_value(qr, pl, table, bound2,
-                                            m_t, l_t)
-            w_suf = pw / l_t
-            if quantized:
-                w_suf = w_suf \
-                    * view["v_scale"].transpose(0, 2, 1)[:, :, None,
-                                                         None, :]
-            acc = acc + jnp.einsum(
-                "bkgsu,bukd->bkgsd", w_suf.astype(qr.dtype),
-                view["v"].astype(qr.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            out = acc.astype(qr.dtype) \
-                + (p_d / l_t).astype(qr.dtype) * vd.astype(qr.dtype)
-            attn = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, -1)
-            attn = attn.astype(carry.dtype)
-        else:
-            m_p, l_p, acc = rpa.ragged_paged_partials(
-                qr, pl, table, bound2, mode=mode)
-            # Manual flash-style combine of (pool partials, suffix
-            # columns, diagonal); the diagonal is always live, so m_t
-            # is finite.
-            m_t = jnp.maximum(
-                jnp.maximum(m_p, jnp.max(s_suf, axis=-1, keepdims=True)),
-                s_diag,
-            )
-            alpha = jnp.exp(m_p - m_t)
-            pw = jnp.where(sm5, jnp.exp(s_suf - m_t), 0.0)
-            p_d = jnp.exp(s_diag - m_t)
-            l_t = l_p * alpha + jnp.sum(pw, axis=-1, keepdims=True) + p_d
-            if quantized:
-                pw = pw \
-                    * view["v_scale"].transpose(0, 2, 1)[:, :, None,
-                                                         None, :]
-            out = (
-                acc * alpha
-                + jnp.einsum("bkgsu,bukd->bkgsd", pw,
-                             view["v"].astype(jnp.float32))
-                + p_d * vd.astype(jnp.float32)
-            ) / jnp.maximum(l_t, 1e-30)
-            attn = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, -1)
-            attn = attn.astype(carry.dtype)
-        if tp is not None:
-            attn = tp.gather(tp.flat(attn))
-        x = carry + transformer._qdot(attn, bp, "wo", cfg)
-        x, aux = transformer._mlp_res(x, bp, cfg, None, tp=tp)
-        fresh = {key: jnp.swapaxes(view[key], 1, 2) for key in view}
-        return x, (fresh, aux)
-
-    x, (fresh, aux) = jax.lax.scan(body, x, (params["blocks"], pool))
-    return x, fresh, jnp.mean(aux)
-
-
 def verify_wave(
     params: Any,
     state: State,
@@ -287,8 +162,6 @@ def verify_wave(
     wave: jnp.ndarray,  # [B] bool — row participates in this wave
     cfg: ModelConfig,
     tp=None,
-    kernel: str = "masked",
-    block_budget: int = 0,
 ) -> Tuple[State, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One speculative verify wave over all B slots.
 
@@ -307,12 +180,9 @@ def verify_wave(
     columns are True-prefixes and counts the k + 1 steps' summed, the
     _process_chunk contract.
 
-    ``kernel`` != "masked" swaps the layer scan for the block-sparse
-    twin (_run_blocks_verify_sparse). The docstring's ANY-temperature
-    bit-identity guarantee is the MASKED leg's: sparse/pallas pin
-    greedy token parity + the RAGGED_LOGITS_ATOL logits band
-    (tests/test_ragged_kernel.py), so spec exactness audits run the
-    masked leg."""
+    The layers attend by ``gqa_attention_verify`` over the gathered
+    pool view (_run_blocks_verify): the one leg, and the one that
+    carries the any-temperature bit-identity above."""
     k = drafts.shape[1]
     Sq = k + 1
     pool = state["cache"]
@@ -331,30 +201,9 @@ def verify_wave(
     x = transformer._embed_rows(params, inputs, transformer._dtype(cfg))
     inv_freq = transformer.rope_frequencies(cfg)
 
-    def masked_body():
-        return _run_blocks_verify(
-            params, x, cfg, positions, inv_freq, mask_lt, pool, table,
-            tp=tp,
-        )
-
-    if kernel == "masked":
-        x, fresh, _ = masked_body()
-    else:
-        bound = jnp.where(wave, pos0, 0).astype(jnp.int32)
-
-        def sparse_body():
-            return _run_blocks_verify_sparse(
-                params, x, cfg, positions, inv_freq, pool, table, bound,
-                tp=tp, mode=kernel,
-            )
-
-        if block_budget > 0:
-            n_live = (jnp.max(bound) + block - 1) // block
-            x, fresh, _ = jax.lax.cond(
-                n_live <= block_budget, sparse_body, masked_body
-            )
-        else:
-            x, fresh, _ = sparse_body()
+    x, fresh, _ = _run_blocks_verify(
+        params, x, cfg, positions, inv_freq, mask_lt, pool, table, tp=tp,
+    )
     # All Sq positions project to logits: Sq = k + 1 stays small, and
     # the acceptance chain below needs every row's candidate.
     logits = transformer._logits(params, x, cfg)  # [B, Sq, V] f32

@@ -1,8 +1,9 @@
 """graftmesh sharding tables: EXACT tensor parallelism over the 'tp' axis.
 
 The serving engine's contract is bit-identical greedy output in every
-configuration pair it ships (paged vs dense, spec on/off, ragged vs
-bucketed) — so the TP scheme must be exact too, not Megatron-exact-ish.
+configuration pair it ships (paged vs dense, spec on/off, chunked vs
+one-shot prefill) — so the TP scheme must be exact too, not
+Megatron-exact-ish.
 Classic Megatron TP partitions the CONTRACTION dimension of the second
 matmul in each pair (wo, w_down) and psums partial products; float
 addition is not associative, so the reduction order differs from tp=1

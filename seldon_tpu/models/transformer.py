@@ -917,10 +917,9 @@ def _run_blocks_prefill_prefix(params, x, cfg, positions, inv_freq, mask,
         pk = _kv_tokens(pl["k"], Hkv).astype(q.dtype)
         pv = _kv_tokens(pl["v"], Hkv).astype(q.dtype)
         if quantized:
-            # Barrier-pinned like ops/ragged_paged_attention._sparse_block:
-            # the dequanted prefix must materialize to ONE value before
-            # the concat so every consumer fusion reads the same bits
-            # (certified by graftlint's num-barrier pass).
+            # Barrier-pinned: the dequanted prefix must materialize to
+            # ONE value before the concat so every consumer fusion reads
+            # the same bits (certified by graftlint's num-barrier pass).
             pk = jax.lax.optimization_barrier(
                 pk * pl["k_scale"].transpose(0, 2, 1)[..., None].astype(
                     q.dtype))

@@ -89,6 +89,12 @@ LANES = 128
 # (tools/probe_decode_attention.py: at 256 tokens of 256 bf16 lanes a
 # full slab took 1.5 x the einsums' time, at 1024 tokens 0.9 x).
 ITEM_BYTES = 512 * 1024
+# The kernel's documented tolerance: how far `attend`'s output for a live
+# row may lie from gqa_attention_decode's (f32, the tests' geometries).
+# The softmax summed block by block and normalised once in f32, against
+# einsums that round the normalised weights to bf16 first, sits at ~3e-3
+# there (tests/test_decode_attention.py, tests/test_falcon_h1.py).
+ATTEND_ATOL = 1e-2
 
 
 def block_size(k_shape, head_dim: int, itemsize: int = 2) -> int:

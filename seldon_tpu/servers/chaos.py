@@ -135,11 +135,11 @@ class ChaosMonkey:
         """Called before each admission/decode dispatch; raises to
         simulate a device/compile failure at that site. `rids` is the
         wave's live membership — the sticky fault fires iff the seeded
-        rid rides a WHOLE-BATCH wave (decode/ragged; deterministic, no
+        rid rides a WHOLE-BATCH decode wave (deterministic, no
         rng draw), so the heal bisection can isolate it by dispatching
         suspects alone. Admission sites are exempt: the sticky request
         must be admittable so it can keep wrecking decode waves."""
-        if (self.cfg.sticky_rid >= 0 and site in ("decode", "ragged")
+        if (self.cfg.sticky_rid >= 0 and site == "decode"
                 and self.cfg.sticky_rid in rids):
             self._count("sticky_faults")
             raise ChaosError(

@@ -8,14 +8,11 @@ config. This module prices them:
 
  * :func:`cost_of_key` — (flops, bytes) for ONE dispatch of any lattice
    key, parameterized by the model config (layers/heads/dims/dtype
-   widths) and the engine geometry (slots, cache window, paged block,
-   ragged chunk). Formula conventions are documented per family below;
-   two deliberate ones up front: a dispatch reads the full weight
-   working set once (batched rows amortize it — the serving regime the
-   engine exists for), and the ragged wave is priced at its CAPACITY
-   ``max_slots * ragged_chunk`` (the static shape), so a lightly packed
-   wave reads as low MFU — the roofline's view of the same waste the
-   sched ledger attributes token-by-token.
+   widths) and the engine geometry (slots, cache window, paged
+   block). Formula conventions are documented per family below; one
+   deliberate one up front: a dispatch reads the full weight working
+   set once (batched rows amortize it — the serving regime the engine
+   exists for).
  * :func:`predict` — the per-request cost surface
    ``predict(prompt_len, max_new, config) -> {flops, bytes, est_ms}``:
    prefill plus every decode step at its growing context, weight reads
@@ -339,8 +336,8 @@ def state_bytes_per_slot(cfg) -> int:
 
 
 def cost_of_key(key: Key, cfg, *, max_slots: int, max_seq_len: int,
-                kv_block: int = 0, ragged_chunk: int = 0,
-                draft_cfg=None, tp: int = 1) -> Tuple[float, float]:
+                kv_block: int = 0, draft_cfg=None,
+                tp: int = 1) -> Tuple[float, float]:
     """(flops, hbm_bytes) for ONE dispatch of a lattice key, PER CHIP
     under tp > 1 (graftmesh: the helpers above shard exactly — per-chip
     flops against the per-chip peak is the honest MFU). Covers every
@@ -399,17 +396,6 @@ def cost_of_key(key: Key, cfg, *, max_slots: int, max_seq_len: int,
         bytes_ = n * (wb + B * W * kvpt + B * kvpt
                       + 2 * B * state_bytes_per_slot(cfg))
         return float(flops), float(bytes_)
-    if fam == "ragged":
-        # (tag, C): ONE fused wave priced at its static capacity
-        # max_slots * C. Since graftkern this is the CAPACITY figure
-        # (exported as capacity_* in /debug/roof): the ledger prices
-        # the live fields from per-wave descriptor occupancy
-        # (ragged_occupancy_cost via note_ragged_occupancy) when the
-        # engine feeds it, falling back to this bound otherwise.
-        c = key[1] or ragged_chunk
-        t = B * c
-        flops = t * fpt + attn_flops(cfg, t, W, tp=tp)
-        return float(flops), float(wb + B * W * kvpt + t * kvpt)
     if fam == "verify":
         # (tag, k): every armed row scores k + 1 positions in one wave.
         k = key[1]
@@ -428,27 +414,6 @@ def cost_of_key(key: Key, cfg, *, max_slots: int, max_seq_len: int,
                            max_seq_len=min(max_seq_len,
                                            draft_cfg.max_seq_len))
     raise ValueError(f"unknown dispatch family {fam!r} (key {key!r})")
-
-
-def ragged_occupancy_cost(cfg, *, q_tokens: int, kv_read_tokens: int,
-                          attn_qk: int, tp: int = 1) -> Tuple[float, float]:
-    """(flops, hbm_bytes) of ONE ragged wave priced at its LIVE
-    descriptor occupancy (graftkern): ``q_tokens`` query positions
-    actually packed (prefill segments + decode rows), ``attn_qk`` the
-    summed q*kv attention pairs those rows really score, and
-    ``kv_read_tokens`` the pool positions the block-sparse walk
-    gathers. This is what the sparse/pallas kernels — and, masked's
-    -1e30 columns aside, the useful arithmetic of every leg — actually
-    do, so MFU/MBU stop reading capacity padding as waste. The static
-    ``cost_of_key`` "ragged" formula stays exported as the capacity_*
-    fields (/debug/roof shows both)."""
-    tp = max(1, int(tp))
-    flops = q_tokens * flops_per_token(cfg, tp) \
-        + 4 * cfg.d_model * attn_qk * cfg.n_layers // tp
-    kvpt = kv_bytes_per_token(cfg, tp)
-    bytes_ = weight_bytes(cfg, tp) + kv_read_tokens * kvpt \
-        + q_tokens * kvpt
-    return float(flops), float(bytes_)
 
 
 # -- peaks ------------------------------------------------------------------
@@ -582,25 +547,15 @@ class RoofLedger:
         self._cfg = None
         self._draft_cfg = None
         self._geom: Dict[str, int] = {
-            "max_slots": 1, "max_seq_len": 1, "kv_block": 0,
-            "ragged_chunk": 0, "tp": 1,
+            "max_slots": 1, "max_seq_len": 1, "kv_block": 0, "tp": 1,
         }
         self._platform = ""
         self._peaks = resolve_peaks("")
-        # key -> [dispatches, flops, bytes, device_ms, predicted_ms,
-        #         capacity_flops, capacity_bytes, capacity_predicted_ms]
-        # Live (slots 1-4) == capacity (slots 5-7) for every family
-        # except "ragged" waves fed live occupancy (graftkern).
+        # key -> [dispatches, flops, bytes, device_ms, predicted_ms]
         self._variants: Dict[Key, List[float]] = {}
         self._cost_cache: Dict[Key, Tuple[float, float]] = {}
         self._predict_cache: Dict[Tuple[int, int], float] = {}
         self._waves = 0
-        # Live ragged-wave occupancy FIFO: the engine notes each
-        # dispatched wave's (q_tokens, kv_read_tokens, attn_qk) under
-        # _book BEFORE its boundary prices (note_wave consumes oldest-
-        # first when it meets a "ragged" key). Empty -> ragged prices
-        # at capacity, so occupancy-blind engines are unchanged.
-        self._pending_occ: List[Tuple[int, int, int]] = []
         # Step decomposition accumulators (ms).
         self._boundaries = 0
         self._wall_ms = 0.0
@@ -616,8 +571,8 @@ class RoofLedger:
     # -- wiring (engine __init__, cold) --------------------------------------
 
     def bind(self, cfg, *, max_slots: int, max_seq_len: int,
-             kv_block: int = 0, ragged_chunk: int = 0, draft_cfg=None,
-             platform: str = "", tp: int = 1) -> None:
+             kv_block: int = 0, draft_cfg=None, platform: str = "",
+             tp: int = 1) -> None:
         """Capture the model config + engine geometry and resolve the
         peak table once (the CPU microbench, when it fires, fires HERE
         — engine init, never the hot path). `tp` is the TP group size
@@ -629,7 +584,6 @@ class RoofLedger:
             "max_slots": int(max_slots),
             "max_seq_len": int(max_seq_len),
             "kv_block": int(kv_block),
-            "ragged_chunk": int(ragged_chunk),
             "tp": max(1, int(tp)),
         }
         self._platform = platform or ""
@@ -654,49 +608,21 @@ class RoofLedger:
 
     # -- hot path (scheduler/fetcher thread, under _book) --------------------
 
-    def note_ragged_occupancy(self, q_tokens: int, kv_read_tokens: int,
-                              attn_qk: int) -> None:
-        """Queue one ragged wave's live descriptor occupancy (graftkern)
-        for the boundary that prices it. Called by _dispatch_ragged
-        under _book right before the jit call; note_wave pops FIFO when
-        it meets the wave's "ragged" key, so the pairing is exact as
-        long as every occupancy-noting dispatch reaches note_wave (a
-        drained/failed boundary leaves at most one stale entry, bounded
-        by the cap here)."""
-        if len(self._pending_occ) < 64:
-            self._pending_occ.append(
-                (int(q_tokens), int(kv_read_tokens), int(attn_qk))
-            )
-
     def note_wave(self, keys: List[Key], device_ms: float) -> None:
         """Join one boundary's dispatch keys with its measured device
         time: the wave's device_ms splits across its keys weighted by
         each key's roofline estimate (equal split when nothing prices),
-        so per-variant device time stays conserved across the wave.
-
-        "ragged" keys price their LIVE fields from the engine-fed
-        occupancy queue (falling back to the static capacity formula
-        when it is empty); every key also accumulates the capacity
-        figures, identical to live for every other family."""
+        so per-variant device time stays conserved across the wave."""
         if not keys:
             return
         self._waves += 1
         priced = []
         for key in keys:
-            cap_f, cap_b = self._cost(key)
-            cap_est = roofline_ms(cap_f, cap_b, self._peaks)
-            flops, bytes_, est = cap_f, cap_b, cap_est
-            if key[0] == "ragged" and self._pending_occ:
-                q, kv, qk = self._pending_occ.pop(0)
-                flops, bytes_ = ragged_occupancy_cost(
-                    self._cfg, q_tokens=q, kv_read_tokens=kv,
-                    attn_qk=qk, tp=self._geom["tp"],
-                )
-                est = roofline_ms(flops, bytes_, self._peaks)
-            priced.append((key, flops, bytes_, est, cap_f, cap_b,
-                           cap_est))
+            flops, bytes_ = self._cost(key)
+            priced.append((key, flops, bytes_,
+                           roofline_ms(flops, bytes_, self._peaks)))
         total_est = sum(p[3] for p in priced)
-        for key, flops, bytes_, est, cap_f, cap_b, cap_est in priced:
+        for key, flops, bytes_, est in priced:
             share = (device_ms * est / total_est if total_est > 0.0
                      else device_ms / len(keys))
             row = self._variants.get(key)
@@ -704,16 +630,13 @@ class RoofLedger:
                 key = _OVERFLOW_KEY
                 row = self._variants.get(key)
             if row is None:
-                row = [0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0]
+                row = [0, 0.0, 0.0, 0.0, 0.0]
                 self._variants[key] = row
             row[0] += 1
             row[1] += flops
             row[2] += bytes_
             row[3] += share
             row[4] += est
-            row[5] += cap_f
-            row[6] += cap_b
-            row[7] += cap_est
 
     def note_step(self, host_pre_ms: float, device_ms: float,
                   host_post_ms: float, span_ms: float) -> None:
@@ -786,7 +709,6 @@ class RoofLedger:
             disp, flops, bytes_, dms, pred = (
                 int(v[0]), v[1], v[2], v[3], v[4]
             )
-            cap_f, cap_b, cap_pred = v[5], v[6], v[7]
             secs = dms / 1000.0
             mfu = min(1.0, flops / (secs * pf)) if secs > 0.0 else 0.0
             mbu = min(1.0, bytes_ / (secs * pb)) if secs > 0.0 else 0.0
@@ -804,11 +726,6 @@ class RoofLedger:
                 "bytes": bytes_,
                 "device_ms": round(dms, 3),
                 "predicted_ms": round(pred, 3),
-                # Static serving-shape bound (== live for every family
-                # except occupancy-fed ragged waves, graftkern).
-                "capacity_flops": cap_f,
-                "capacity_bytes": cap_b,
-                "capacity_predicted_ms": round(cap_pred, 3),
                 "mfu": round(mfu, 6),
                 "mbu": round(mbu, 6),
                 "bound": bound,
@@ -876,5 +793,5 @@ def from_env() -> Optional[RoofLedger]:
 # pins the covered set to FAMILIES exactly.
 assert set(FAMILIES) == {
     "deactivate", "admit", "admit-prefix", "admit-paged", "chunk",
-    "seed-prefix", "cow", "decode", "ragged", "draft", "verify",
+    "seed-prefix", "cow", "decode", "draft", "verify",
 }, "shape_lattice.FAMILIES drifted — update cost_of_key"

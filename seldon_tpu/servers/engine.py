@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from seldon_tpu.core import tracing
-from seldon_tpu.models import ragged_attention, tp_sharding
+from seldon_tpu.models import tp_sharding
 from seldon_tpu.models import slot as slot_rules
 from seldon_tpu.models import transformer
 from seldon_tpu.models import spec_decode as spec_model
@@ -176,31 +176,6 @@ class EngineConfig:
     paged_kv: bool = False
     kv_block: int = 16  # tokens per pool block; power of two
     kv_pool_blocks: int = 0  # pool size incl. trash block; 0 -> dense-equiv
-    # Ragged unified dispatch (opt-in; graftragged): every scheduler wave
-    # runs ONE fused kernel over all slots — mixed cold prefills, chunk
-    # continuations and decode steps in a single compiled variant
-    # (models/ragged_attention.py), collapsing the (bucket, group, width)
-    # jit lattice to key ("ragged", chunk) plus ("deactivate",). Requires
-    # paged_kv + chunked_prefill (block tables are the wave's only KV
-    # currency; the wave IS a chunk boundary). False keeps every dispatch
-    # byte-identical to the bucketed engine.
-    ragged: bool = False
-    ragged_chunk: int = 0  # per-slot tokens per wave; 0 -> prefill_chunk
-    # Ragged attention kernel leg (graftkern): "masked" = the bit-exact
-    # full-width baseline above; "sparse" = the block-sparse jnp walker
-    # (ops/ragged_paged_attention.py) that touches only live KV blocks
-    # and skips dead prefill legs — the CPU/default-perf leg; "pallas"
-    # = the Mosaic kernel for the same walk (TPU only: it raises
-    # elsewhere).
-    # All legs compile into the SAME single ("ragged", C) variant.
-    # Greedy outputs are token-identical across legs; non-greedy
-    # sampling may diverge in ulps (masked is the any-temperature
-    # exactness leg). Also selects the spec verify_wave leg.
-    ragged_kernel: str = "masked"
-    # > 0: waves whose longest live row needs more than this many pool
-    # blocks run the masked leg via an in-trace lax.cond (never
-    # truncates, never adds a variant). 0 = no budget (sparse always).
-    ragged_block_budget: int = 0
     # Speculative decoding (opt-in; graftspec): a resident drafter
     # proposes up to `spec_k` tokens per live slot each wave and the
     # target model verifies all k+1 positions in ONE wide dispatch
@@ -208,10 +183,10 @@ class EngineConfig:
     # mismatch rolls the row back by a host-side block-table trim.
     # Sampling keys are sequential per position, so verification is
     # EXACT: outputs are bit-identical to the spec-off engine at any
-    # temperature. Requires paged_kv (rollback is a table trim);
-    # mutually exclusive with ragged (each replaces the decode
-    # dispatch). `spec_draft` names a draft checkpoint preset (the 1B
-    # next to an 8B target); "" uses the zero-dispatch n-gram drafter
+    # temperature. Requires paged_kv (rollback is a table trim); the
+    # verify wave takes the decode chunk's place in a scheduler wave.
+    # `spec_draft` names a draft checkpoint preset (the 1B next to an 8B
+    # target); "" uses the zero-dispatch n-gram drafter
     # (servers/spec_decode.py). False keeps every dispatch
     # byte-identical to the spec-off engine.
     spec_decode: bool = False
@@ -335,47 +310,12 @@ class EngineConfig:
                     f"(1 reserved trash block + 1 usable) or 0 for the "
                     f"dense-equivalent budget"
                 )
-        if self.ragged:
-            if not (self.paged_kv and self.chunked_prefill):
-                raise ValueError(
-                    "ragged=True requires paged_kv=True and "
-                    "chunked_prefill=True — the unified wave walks block "
-                    "tables and admits prompts chunkwise"
-                )
-            rc = self.ragged_chunk or self.prefill_chunk
-            if not pow2(rc):
-                raise ValueError(
-                    f"ragged_chunk ({rc}) must be a power of two — it is "
-                    f"the ONE compiled wave width"
-                )
-            if rc % self.kv_block:
-                raise ValueError(
-                    f"ragged_chunk ({rc}) must be a multiple of kv_block "
-                    f"({self.kv_block}) so wave boundaries append whole "
-                    f"pool blocks"
-                )
-        if self.ragged_kernel not in ("masked", "sparse", "pallas"):
-            raise ValueError(
-                f"ragged_kernel ({self.ragged_kernel!r}) must be one of "
-                f"'masked', 'sparse', 'pallas'"
-            )
-        if self.ragged_block_budget < 0:
-            raise ValueError(
-                f"ragged_block_budget ({self.ragged_block_budget}) must "
-                f"be >= 0 (0 = no budget)"
-            )
         if self.spec_decode:
             if not self.paged_kv:
                 raise ValueError(
                     "spec_decode=True requires paged_kv=True — rollback "
                     "after a rejected draft is a host-side block-table "
                     "trim, which only the paged engine supports"
-                )
-            if self.ragged:
-                raise ValueError(
-                    "spec_decode=True is incompatible with ragged=True — "
-                    "each replaces the decode dispatch (a verify wave IS "
-                    "a ragged decode wave with k+1 tokens per slot)"
                 )
             if not pow2(self.spec_k):
                 raise ValueError(
@@ -1394,34 +1334,6 @@ class InferenceEngine:
         self._jit_deactivate = jax.jit(
             self._deactivate_impl, donate_argnums=(0,)
         )
-        # graftragged (opt-in): the unified ragged wave — ONE jit serving
-        # every mix of cold prefills / chunk continuations / decodes over
-        # all B slots (models/ragged_attention.py), so the whole chunk /
-        # bucket / group ladder above never dispatches and warmup
-        # collapses to {("ragged", C), ("deactivate",)}. Requires the
-        # paged + chunked engines (validated in EngineConfig); inherits
-        # their single-process restriction through self._paged.
-        self._ragged = (
-            bool(self.ecfg.ragged) and self._paged and self._chunked
-        )
-        self._jit_ragged = None
-        if self._ragged:
-            self._ragged_chunk = min(
-                self.ecfg.ragged_chunk or self._prefill_chunk,
-                max(self._buckets),
-            )
-            # graftkern: the kernel leg is a Python constant closed over
-            # at jit time — swapping it swaps the trace, never the
-            # lattice key, so masked/sparse/pallas all stay inside the
-            # ONE ("ragged", C) variant.
-            self._jit_ragged = jax.jit(
-                _named_partial(
-                    self._ragged_impl, cfg=self.cfg, mesh=mesh,
-                    kernel=self.ecfg.ragged_kernel,
-                    block_budget=self.ecfg.ragged_block_budget, **tpkw,
-                ),
-                donate_argnums=(1,),
-            )
         # graftspec (opt-in): speculative decoding. Each boundary a
         # drafter proposes up to spec_k tokens per live decode slot and
         # ONE wide verify dispatch (models/spec_decode.verify_wave)
@@ -1456,9 +1368,7 @@ class InferenceEngine:
             self._spec_k_live = self._spec_rungs[-1]  # graftlint: guarded-by(_book)
             self._jit_verify = jax.jit(
                 _named_partial(
-                    self._verify_impl, cfg=self.cfg, mesh=mesh,
-                    kernel=self.ecfg.ragged_kernel,
-                    block_budget=self.ecfg.ragged_block_budget, **tpkw,
+                    self._verify_impl, cfg=self.cfg, mesh=mesh, **tpkw,
                 ),
                 donate_argnums=(1,),
             )
@@ -1546,7 +1456,6 @@ class InferenceEngine:
                 max_slots=self.ecfg.max_slots,
                 max_seq_len=self.ecfg.max_seq_len,
                 kv_block=self._kv_block if self._paged else 0,
-                ragged_chunk=self._ragged_chunk if self._ragged else 0,
                 draft_cfg=getattr(self, "_draft_cfg", None),
                 platform=(getattr(dev, "device_kind", "") or dev.platform),
                 tp=self.ecfg.tp if self._tp is not None else 1,
@@ -1657,7 +1566,6 @@ class InferenceEngine:
                  e.prefix_cache),
                 (f"chunked_prefill (a chunk would have to resume the {state})",
                  e.chunked_prefill),
-                ("ragged (the fused wave is paged)", e.ragged),
                 (f"spec_decode (a rejected draft cannot rewind the {state})",
                  e.spec_decode),
                 ("heal (replay re-admits by KV position)",
@@ -2102,36 +2010,8 @@ class InferenceEngine:
         return {**state, "cache": new_pool}
 
     @staticmethod
-    def _ragged_impl(
-        params, state, table, tokens, plens, starts, seeds, temps,
-        top_ks, top_ps, max_news, finals, is_prefill, *, cfg, mesh=None,
-        tp=None, kernel="masked", block_budget=0,
-    ):
-        """graftragged: the ONE unified wave — every slot's prefill
-        segment of the flat token buffer plus one decode step for every
-        armed row, fused into a single trace
-        (models/ragged_attention.ragged_wave). Descriptors are [B]
-        arrays, the token buffer is [B * ragged_chunk]; nothing about
-        the live mix is a shape, so this compiles exactly once. The
-        wave math IS _paged_admit_chunk_impl + _paged_chunk_impl(1)
-        with masking instead of slot-gather, so greedy outputs stay
-        bit-identical to the bucketed engine (tests/test_ragged.py)."""
-        state, first, first_done, toks, valid, counts = (
-            ragged_attention.ragged_wave(
-                params, state, table, tokens, plens, starts, seeds, temps,
-                top_ks, top_ps, max_news, finals, is_prefill, cfg, tp=tp,
-                kernel=kernel, block_budget=block_budget,
-            ))
-        if tp is not None:
-            state = tp.constrain_state(state)
-        return (state,) + InferenceEngine._replicate(
-            mesh, first, first_done, toks, valid, state["active"], counts
-        )
-
-    @staticmethod
     def _verify_impl(params, state, table, drafts, wave, *, cfg,
-                     mesh=None, tp=None, kernel="masked",
-                     block_budget=0):
+                     mesh=None, tp=None):
         """graftspec: ONE wide verify dispatch replacing up to k + 1
         sequential decode steps (models/spec_decode.verify_wave). The
         k rung is carried by the drafts width — one compile per rung,
@@ -2140,7 +2020,6 @@ class InferenceEngine:
         so _process_chunk consumes a wave unchanged."""
         state, toks, valid, counts = spec_model.verify_wave(
             params, state, table, drafts, wave, cfg, tp=tp,
-            kernel=kernel, block_budget=block_budget,
         )
         if tp is not None:
             state = tp.constrain_state(state)
@@ -2651,8 +2530,6 @@ class InferenceEngine:
             token_budget=(
                 self.ecfg.dispatch_token_budget or self._prefill_chunk
             ) if chunked else 0,
-            ragged=self._ragged,
-            ragged_chunk=self._ragged_chunk if self._ragged else 0,
             spec=self._spec,
             spec_rungs=self._spec_rungs if self._spec else (),
             spec_draft=self._jit_draft is not None,
@@ -2777,27 +2654,6 @@ class InferenceEngine:
                 jnp.arange(G, dtype=jnp.int32),
                 prefix_width=W,
             )
-        elif kind == "ragged" and self._ragged:
-            # The ONE wave: all-trash tables (starts = Smax routes every
-            # scatter past the table) and an all-False occupancy mask
-            # keep the compile a pure no-op over real state.
-            _, C = key
-            B = self.ecfg.max_slots
-            self._state = self._jit_ragged(
-                self.params,
-                self._state,
-                jnp.zeros((B, self._nbs), jnp.int32),
-                jnp.zeros((B * C,), jnp.int32),
-                jnp.ones((B,), jnp.int32),
-                jnp.full((B,), Smax, jnp.int32),
-                jnp.zeros((B,), jnp.uint32),
-                jnp.ones((B,), jnp.float32),
-                jnp.zeros((B,), jnp.int32),
-                jnp.ones((B,), jnp.float32),
-                jnp.ones((B,), jnp.int32),
-                jnp.zeros((B,), jnp.bool_),
-                jnp.zeros((B,), jnp.bool_),
-            )[0]
         elif kind == "chunk" and self._chunked:
             _, Sc, G, W = key
             start = min(W, Smax - Sc)
@@ -3951,262 +3807,6 @@ class InferenceEngine:
                         self.stats.sched_frag_tokens += left
         return admits
 
-    # --- ragged unified dispatch (graftragged) ------------------------------
-
-    def _collect_ragged_work(  # graftlint: holds(_book)
-        self, left: int
-    ) -> List[Tuple[_Request, int, bool]]:
-        """One wave's prefill packing: each dispatchable request claims
-        its slot's fixed [ragged_chunk] segment of the token buffer, with
-        EXACTLY its real token count — no bucket rounding, no pow2 group
-        replication, so the ledger's padding attribution for a wave is
-        zero by construction. Continuing prefills go first (same
-        round-robin deque as the bucketed path), new admissions gate on
-        a free slot + first-chunk pool reservation BEFORE the slot pop.
-        Returns (req, chunk_len, final) rows; a request appears at most
-        once (one segment per slot per wave)."""
-        C = self._ragged_chunk
-        work: List[Tuple[_Request, int, bool]] = []
-        while left > 0:
-            if self._prefilling:
-                req = self._prefilling.popleft()
-                if req.finished:  # failed by an earlier error path
-                    continue
-            elif self._waiting and self._free:
-                if self._pilot is not None and self._shed_expired_head():
-                    continue  # expired head must not claim a slot
-                req = self._waiting[0]
-                rem = len(req.tokens)
-                est = min(C, rem)
-                if est > left:
-                    break
-                if self._paged and not self._pool_reserve(
-                    min(est, rem) // self._kv_block + 2
-                ):
-                    # First chunk's blocks (+ a possible CoW tail) must
-                    # fit before the slot pop — admissions stall on pool
-                    # exhaustion rather than half-admit.
-                    with self.stats.lock:
-                        self.stats.pool_stalls += 1
-                    if self._recorder is not None:
-                        self._recorder.record(
-                            "pool-stall", req.rid,
-                            {"waiting": len(self._waiting)},
-                        )
-                    if self._sled is not None:
-                        self._sled.note_pool_stall(req.rid)
-                    break
-                self._waiting.popleft()
-                self._admit_chunk_slot(req)
-            else:
-                break
-            rem = len(req.tokens) - req.prefill_done
-            final = rem <= C
-            clen = rem if final else C
-            if clen > left:
-                # Keeps FIFO priority for the next wave's budget.
-                self._prefilling.appendleft(req)
-                break
-            work.append((req, clen, final))
-            left -= clen
-        return work
-
-    def _dispatch_ragged(self):  # graftlint: holds(_book)
-        """One unified ragged wave (the whole scheduler step under
-        RAGGED=1): pack any mix of cold admissions / chunk continuations
-        into the flat token buffer, then dispatch ONE fused kernel that
-        prefills every packed segment AND runs one decode step for every
-        armed row — no admission groups, no bucket choice, no separate
-        decode dispatch, so the only live variant is ("ragged", C).
-        Returns the same (admits, chunk_handles, roster, timing)
-        boundary tuple as the bucketed path (or None when idle), so
-        boundary fetching/processing is shared unchanged."""
-        self._drain_pending()
-        B = self.ecfg.max_slots
-        C = self._ragged_chunk
-        if self._pilot is not None:
-            budget = self._pilot.dispatch_budget()
-        else:
-            budget = self.ecfg.dispatch_token_budget or B * C
-        work = self._collect_ragged_work(budget)
-        if not work and not self._active_host.any():
-            return None
-        self._chaos_dispatch("ragged", self._live_wave_rids())
-        Smax = self.ecfg.max_seq_len
-        toks = np.full((B, C), self.cfg.pad_token_id, np.int32)
-        plens = np.ones((B,), np.int32)
-        # Idle rows' descriptors trash-route every KV write: start =
-        # Smax puts the whole segment past the table (the paged pool's
-        # write-before-read discipline, reused as the occupancy mask's
-        # device-side half).
-        starts = np.full((B,), Smax, np.int32)
-        seeds = np.zeros((B,), np.uint32)
-        temps = np.ones((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        top_ps = np.ones((B,), np.float32)
-        max_news = np.ones((B,), np.int32)
-        finals = np.zeros((B,), bool)
-        is_prefill = np.zeros((B,), bool)
-        packed = 0
-        for req, clen, final in work:
-            s = req.slot
-            sp = req.params
-            start = req.prefill_done
-            toks[s, :clen] = req.tokens[start:start + clen]
-            plens[s] = len(req.tokens)
-            starts[s] = start
-            seeds[s] = np.uint32(int(sp.seed) & 0xFFFFFFFF)
-            temps[s] = sp.temperature
-            top_ks[s] = sp.top_k
-            top_ps[s] = sp.top_p
-            max_news[s] = sp.max_new_tokens
-            finals[s] = final
-            is_prefill[s] = True
-            packed += clen
-        # Append each packed row's pool blocks (trie eviction, then
-        # preemption of younger streams, backstop the allocation — real
-        # KV must never scatter into the trash block).
-        bs = self._kv_block
-        for req, clen, _ in work:
-            need = min(self._nbs, -(-(req.prefill_done + clen) // bs))
-            have = len(req.block_ids)
-            if need > have:
-                got = self._secure_blocks(need - have, requester=req)
-                if got is None:
-                    raise RuntimeError(
-                        "kv cache pool exhausted (ragged wave)"
-                    )
-                for j, bid in enumerate(got):
-                    self._table_host[req.slot, have + j] = bid
-                req.block_ids.extend(got)
-        if self._roof is not None:
-            # graftkern live-occupancy pricing: count the work this wave
-            # ACTUALLY does per descriptor (prefill segments + the
-            # decode leg) before prefill_done advances. The ledger
-            # consumes it when note_wave prices this boundary's
-            # ("ragged", C) key; static max_slots x C capacity pricing
-            # stays exported as the capacity_* fields.
-            q_toks = attn_qk = kv_read = 0
-            in_work = set()
-            for req, clen, final in work:
-                if req.finished:
-                    continue
-                in_work.add(req.slot)
-                start = req.prefill_done
-                q_toks += clen
-                attn_qk += clen * start + clen * (clen + 1) // 2
-                kv_read += start
-                if final:
-                    plen = len(req.tokens)
-                    q_toks += 1
-                    attn_qk += plen
-                    kv_read += plen
-            for slot, req in enumerate(self._slots):
-                if (req is None or slot in in_work or req.finished
-                        or req.prefilling or not self._active_host[slot]):
-                    continue
-                pos = min(
-                    len(req.tokens) + max(req.n_generated, 1) - 1,
-                    Smax - 1,
-                )
-                q_toks += 1
-                attn_qk += pos
-                kv_read += pos
-            self._roof.note_ragged_occupancy(q_toks, kv_read, attn_qk)
-        # Post-prefill bookkeeping BEFORE the roster/growth pass: final
-        # rows flip to decoding so this wave's decode leg covers them
-        # (their table rows grow to the first-token position), exactly
-        # like the off path where the decode chunk follows the final
-        # admission chunk inside one scheduler step.
-        group: List[_Request] = []
-        finals_l: List[bool] = []
-        for req, clen, final in work:
-            if req.finished:
-                # Preempted by a later row's block grab: its table row
-                # is zeroed (KV scatters to trash) — also drop its state
-                # writes so the freed slot stays inert.
-                finals[req.slot] = False
-                is_prefill[req.slot] = False
-                continue
-            req.prefill_done += clen
-            group.append(req)
-            finals_l.append(final)
-            if final:
-                req.prefilling = False
-                req.expected = 1  # the wave samples the first token
-            else:
-                self._prefilling.append(req)
-            if self._paged_prefix is not None:
-                self._insert_paged_prompt(req, upto=req.prefill_done)
-        self._record_first_dispatch(group)
-        roster = self._roster()
-        self._dispatch_wreck = _PendingWave([], None, roster, None)
-        self._grow_decode_blocks(1)
-        if self._observe:
-            t0 = time.perf_counter()
-        out = self._jit_ragged(
-            self.params,
-            self._state,
-            self._table_device(),
-            jnp.asarray(toks.reshape(-1)),
-            jnp.asarray(plens),
-            jnp.asarray(starts),
-            jnp.asarray(seeds),
-            jnp.asarray(temps),
-            jnp.asarray(top_ks),
-            jnp.asarray(top_ps),
-            jnp.asarray(max_news),
-            jnp.asarray(finals),
-            jnp.asarray(is_prefill),
-        )
-        self._state, first, first_done = out[:3]
-        chunk_handles = tuple(out[3:])  # toks, valid, active, counts
-        if self._observe:
-            self._note_dispatch(
-                ("ragged", C), group[0].rid if group else -1,
-                time.perf_counter() - t0,
-            )
-        if self._hbm is not None:
-            self._hbm.note_workspace(
-                int(toks.nbytes) + B * self.cfg.vocab_size * 4
-            )
-        admits = [(group, finals_l, first, first_done)] if group else []
-        self._dispatch_wreck = _PendingWave(admits, None, roster, None)
-        with self.stats.lock:
-            self.stats.decode_dispatches += 1
-            self.stats.decode_steps += 1
-            if group:
-                self.stats.prefill_chunks += len(group)
-                self.stats.prefill_chunk_tokens += packed
-                self.stats.budget_dispatches += 1
-                self.stats.budget_tokens += packed
-                self.stats.budget_limit = budget
-        self._recycle_budget_spent(roster, 1)
-        for h in (first, first_done) + chunk_handles:
-            h.copy_to_host_async()
-        if self._sled is not None and packed:
-            # A wave's unused token-slots are NOT padding: the ragged
-            # kernel walks per-request token counts, so cost scales with
-            # packed tokens, not capacity (docs/benchmarking.md "Ragged
-            # dispatch") — cells == useful, zero bucket/group pad.
-            self._sled.note_group(("ragged", C), packed, packed, 0, 0)
-            with self.stats.lock:
-                self.stats.sched_useful_tokens += packed
-            starved = bool(
-                self._prefilling or (self._waiting and self._free)
-            )
-            self._sled.note_budget(budget, packed, starved)
-            if starved and budget > packed:
-                with self.stats.lock:
-                    self.stats.sched_frag_tokens += budget - packed
-        self._note_boundary(admits=len(group), chunk=1,
-                            packed_tokens=packed)
-        timing = self._make_timing() if self._timing_on else None
-        self._dispatch_wreck = None
-        return _PendingWave(
-            admits, chunk_handles, roster, timing, self._wave_epoch,
-        )
-
     # --- speculative decoding (graftspec) ----------------------------------
 
     def _pick_spec_k(self) -> int:  # graftlint: holds(_book)
@@ -4418,10 +4018,7 @@ class InferenceEngine:
                 if req.finished:  # already failed by an error path
                     continue
                 slot = req.slot
-                # Ragged waves return [B] slot-indexed rows (the whole
-                # batch IS the group); bucketed groups are group-indexed.
-                idx = slot if self._ragged else i
-                first_tok = int(first_h[idx])
+                first_tok = int(first_h[i])
                 req.last_burst_at = now
                 req.n_generated = 1
                 if self._spec or self._heal is not None:
@@ -4441,7 +4038,7 @@ class InferenceEngine:
                     req.out.put({"tokens": [first_tok]})
                 if self._heal is not None:
                     self._heal.note_progress(req.rid)
-                if bool(done_h[idx]):
+                if bool(done_h[i]):
                     self._complete(req)
                 elif self._slots[slot] is req:
                     # Not armed when the slot was already optimistically
@@ -4476,7 +4073,7 @@ class InferenceEngine:
         }
 
     def _note_chunk_counts(self, chunk_data) -> None:
-        """What a decode chunk, a ragged wave or a verify wave counted
+        """What a decode chunk or a verify wave counted
         on the device (the last value of each, CHUNK_COUNTERS' order;
         came to the host in the boundary's own fetch) into the stats."""
         with self.stats.lock:
@@ -4522,7 +4119,7 @@ class InferenceEngine:
                     self.stats.record_itl_locked(g)
 
     def _live_wave_rids(self) -> List[int]:  # graftlint: holds(_book)
-        """The rids riding a whole-batch (decode/ragged/verify) wave —
+        """The rids riding a whole-batch (decode/verify) wave —
         the sticky chaos fault's membership test."""
         return [
             r.rid for r in self._slots
@@ -5341,8 +4938,8 @@ class InferenceEngine:
         device set the pace of fewer than one in four, the host is
         what a wave waits for there, and min_chunk stays. Only
         _loop_async's waves reach the estimator, so the synchronous
-        loops keep min_chunk too; the ragged and speculative waves
-        never pick a chunk."""
+        loops keep min_chunk too; a speculative wave never picks a
+        chunk."""
         est = self._depth_est
         cap = self._chunk_sizes[0]
         n = est.chunk_steps(cap)
@@ -5721,10 +5318,6 @@ class InferenceEngine:
         if self._roof is not None:
             self._step_t0 = time.perf_counter()
         self._reap_lifecycle()
-        if self._ragged:
-            # graftragged: the whole step is ONE fused wave — no
-            # separate admission groups or decode chunk below.
-            return self._dispatch_ragged()
         if self._spec:
             # graftspec: admissions as usual, then a draft pass + one
             # wide verify dispatch instead of the decode chunk.

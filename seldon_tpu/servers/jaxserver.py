@@ -87,9 +87,6 @@ class JAXServer(SeldonComponent):
         paged_kv: int = -1,
         kv_block: int = 0,
         kv_pool_mb: int = 0,
-        ragged: int = -1,
-        ragged_chunk: int = 0,
-        ragged_kernel: str = "",
         spec: int = -1,
         spec_k: int = 0,
         spec_draft: str = "",
@@ -174,30 +171,6 @@ class JAXServer(SeldonComponent):
         self.kv_pool_mb = int(
             kv_pool_mb or _os.environ.get("KV_POOL_MB", "0") or 0
         )
-        # graftragged unified dispatch (servers/engine.py _dispatch_ragged
-        # + models/ragged_attention.py): unit parameter, or RAGGED=1 /
-        # RAGGED_CHUNK env. Implies paged_kv + chunked_prefill (the wave
-        # needs block tables and chunkwise admission), so RAGGED=1 alone
-        # is a complete switch. -1 / 0 = follow the env (default off).
-        if int(ragged) < 0:
-            ragged = int(_os.environ.get("RAGGED", "0") or 0)
-        self.ragged = bool(int(ragged))
-        self.ragged_chunk = int(
-            ragged_chunk or _os.environ.get("RAGGED_CHUNK", "0") or 0
-        )
-        # graftkern attention leg (models/ragged_attention.py +
-        # ops/ragged_paged_attention.py): masked (bit-exact baseline) /
-        # sparse (block-sparse jnp walker) / pallas (Mosaic kernel;
-        # TPU only — it raises elsewhere). Also selects the spec verify
-        # leg.
-        # Empty = follow the env (default masked).
-        self.ragged_kernel = (
-            ragged_kernel or _os.environ.get("RAGGED_KERNEL", "")
-            or "masked"
-        )
-        if self.ragged:
-            self.paged_kv = True
-            self.chunked_prefill = True
         # graftspec speculative decoding (servers/engine.py
         # _dispatch_spec + models/spec_decode.py): unit parameter, or
         # SPEC=1 / SPEC_K / SPEC_DRAFT env. Implies paged_kv (rollback
@@ -381,12 +354,6 @@ class JAXServer(SeldonComponent):
                     )
                     blocks = (self.kv_pool_mb << 20) // (per_tok * kb)
                     ekw["kv_pool_blocks"] = max(2, int(blocks))
-            if self.ragged:
-                ekw["ragged"] = True
-                if self.ragged_chunk:
-                    ekw["ragged_chunk"] = self.ragged_chunk
-            if self.ragged_kernel != "masked":
-                ekw["ragged_kernel"] = self.ragged_kernel
             draft = None
             if self.spec:
                 ekw["spec_decode"] = True

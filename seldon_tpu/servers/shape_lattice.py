@@ -70,7 +70,6 @@ FAMILIES = {
     "seed-prefix": 2,    # (tag, prefix width)
     "cow": 1,            # copy-on-write block copy (traced scalars)
     "decode": 2,         # (tag, chunk-ladder rung)
-    "ragged": 2,         # (tag, per-slot chunk capacity) — the ONE wave
     "draft": 2,          # (tag, spec rung k) — draft-model proposal
     "verify": 2,         # (tag, spec rung k) — the wide verify wave
 }
@@ -95,12 +94,6 @@ class LatticeSpec:
     chunk_buckets: Tuple[int, ...] = ()   # engine _chunk_buckets
     prefill_chunk: int = 0          # engine _prefill_chunk (clamped C)
     token_budget: int = 0           # dispatch_token_budget or C
-    # graftragged (models/ragged_attention.py): every scheduler wave is
-    # ONE fused kernel of fixed shape [max_slots, ragged_chunk] —
-    # bucketing, pow2 grouping, decode rungs and the whole admit/chunk
-    # key space collapse to the single ("ragged", C) variant.
-    ragged: bool = False
-    ragged_chunk: int = 0           # engine _ragged_chunk (per-slot C)
     # graftspec (models/spec_decode.py): the decode chunk ladder never
     # dispatches — one ("verify", k) rung per pow2 k replaces it, plus
     # the ("draft", k) ladder when a draft checkpoint is resident.
@@ -123,18 +116,11 @@ class LatticeSpec:
                 "token_budget >= prefill_chunk (EngineConfig validates "
                 "the same)"
             )
-        if self.ragged and (not self.paged or not self.chunked
-                            or self.ragged_chunk <= 0):
-            raise ValueError(
-                "ragged spec needs paged + chunked engines and a "
-                "positive ragged_chunk (EngineConfig validates the same)"
-            )
         if self.spec:
-            if not self.paged or self.ragged:
+            if not self.paged:
                 raise ValueError(
-                    "spec needs the paged engine and excludes ragged — "
-                    "each replaces the decode dispatch (EngineConfig "
-                    "validates the same)"
+                    "spec needs the paged engine (EngineConfig validates "
+                    "the same)"
                 )
             if not self.spec_rungs or any(
                 kk <= 0 or kk & (kk - 1) for kk in self.spec_rungs
@@ -238,15 +224,6 @@ def dispatch_keys(spec: LatticeSpec) -> Set[Key]:
     """The closed-form lattice: every static-shape key live scheduling
     can dispatch under `spec`.  warmup() compiles exactly this set."""
     maxp = max(spec.buckets)
-    if spec.ragged:
-        # graftragged: the whole admit/chunk/decode key space is ONE
-        # fixed-shape wave — the lattice is the lifecycle freeze plus
-        # the wave itself (plus the traced-scalar CoW copy when the
-        # paged prefix trie can share a partially-filled block).
-        keys = {("deactivate",), ("ragged", spec.ragged_chunk)}
-        if spec.prefix:
-            keys.add(("cow",))
-        return keys
     keys: Set[Key] = {("deactivate",)}
     if spec.spec:
         # graftspec: the decode ladder never dispatches — the verify
@@ -330,22 +307,6 @@ def simulate_keys(spec: LatticeSpec) -> Set[Key]:
     the certifier's grid check is the two derivations agreeing."""
     maxp = max(spec.buckets)
     smax = spec.max_seq_len
-    if spec.ragged:
-        # Scenario walk: every prompt, at every prefix-match offset,
-        # prefills in ceil(rem / C) waves and decodes one step per
-        # wave — and EVERY one of those dispatches is the same fixed
-        # [max_slots, ragged_chunk] kernel. Only warm partial-block
-        # tails add the CoW copy.
-        keys = {("deactivate",)}
-        if spec.prefix:
-            keys.add(("cow",))
-        for plen in range(1, maxp + 1):
-            start = 0
-            while start < plen:
-                keys.add(("ragged", spec.ragged_chunk))  # prefill wave
-                start += spec.ragged_chunk
-            keys.add(("ragged", spec.ragged_chunk))      # decode wave
-        return keys
     keys: Set[Key] = {("deactivate",)}
     if spec.spec:
         # Scenario walk: every boundary's decode leg is ONE verify wave
@@ -417,8 +378,8 @@ def simulate_keys(spec: LatticeSpec) -> Set[Key]:
 # sequence), numeric components ascending within a family.
 _FAMILY_RANK = {
     "deactivate": 0, "admit": 1, "admit-prefix": 2, "admit-paged": 3,
-    "seed-prefix": 4, "chunk": 5, "cow": 6, "decode": 7, "ragged": 8,
-    "draft": 9, "verify": 10,
+    "seed-prefix": 4, "chunk": 5, "cow": 6, "decode": 7,
+    "draft": 8, "verify": 9,
 }
 
 # The dispatch-family set in warmup order — THE exported constant for
@@ -448,15 +409,12 @@ GRID_SHAPES: Tuple[Tuple, ...] = (
 # (paged, chunked, prefix) — the full flag cube.
 GRID_FLAG_COMBOS: Tuple[Tuple[bool, bool, bool], ...] = tuple(
     itertools.product((False, True), repeat=3))
-# Ragged leg: paged+chunked forced, prefix trie on/off.
-GRID_RAGGED_COMBOS: Tuple[bool, ...] = (False, True)
 # Spec leg: (chunked, draft-resident), over the first two shapes only.
 GRID_SPEC_COMBOS: Tuple[Tuple[bool, bool], ...] = tuple(
     itertools.product((False, True), repeat=2))
 GRID_SPEC_SHAPES = 2
 
 GRID_COUNT = (len(GRID_FLAG_COMBOS) * len(GRID_SHAPES)
-              + len(GRID_RAGGED_COMBOS) * len(GRID_SHAPES)
               + len(GRID_SPEC_COMBOS) * GRID_SPEC_SHAPES)
 
 
@@ -478,19 +436,6 @@ def grid() -> List[LatticeSpec]:
                                            | {c})) if chunked else (),
                 prefill_chunk=c if chunked else 0,
                 token_budget=budget if chunked else 0,
-            ))
-    # graftragged collapse: same shapes, paged+chunked forced (the
-    # ragged wave's preconditions), with and without the prefix trie.
-    for prefix in GRID_RAGGED_COMBOS:
-        for buckets, smax, slots, ma, c, budget in shapes:
-            specs.append(LatticeSpec(
-                buckets=buckets, max_seq_len=smax, max_slots=slots,
-                max_admit=ma, decode_rungs=(4, 8), paged=True,
-                chunked=True, prefix=prefix, prefix_block=16,
-                chunk_buckets=tuple(sorted({min(b, c) for b in buckets}
-                                           | {c})),
-                prefill_chunk=c, token_budget=budget,
-                ragged=True, ragged_chunk=c,
             ))
     # graftspec: the verify/draft ladders replace the decode rungs —
     # paged forced (spec's precondition), crossed with chunked prefill

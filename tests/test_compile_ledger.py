@@ -247,10 +247,10 @@ def test_trace_view_variant_lanes_and_retrace_instants():
     rec.record("dispatch", -1, {"variant": "admit/32/4", "ms": 812.0})
     rec.record("dispatch", -1, {"variant": "decode/8", "ms": 2.5})
     rec.record("dispatch", -1, {"variant": "decode/8", "ms": 2.4})
-    # The graftragged wave key uses the same stable slash rendering —
-    # repeated waves share ONE lane named "ragged/8".
-    rec.record("dispatch", -1, {"variant": "ragged/8", "ms": 3.0})
-    rec.record("dispatch", -1, {"variant": "ragged/8", "ms": 2.9})
+    # A wave's key uses the same stable slash rendering — repeated
+    # waves share ONE lane named "verify/4".
+    rec.record("dispatch", -1, {"variant": "verify/4", "ms": 3.0})
+    rec.record("dispatch", -1, {"variant": "verify/4", "ms": 2.9})
     rec.record("terminal", 1, {"outcome": "ok"})
 
     out = json.loads(json.dumps(trace_view.convert(rec.snapshot())))
@@ -266,7 +266,7 @@ def test_trace_view_variant_lanes_and_retrace_instants():
     by_name = {}
     for e in slices:
         by_name.setdefault(e["name"], set()).add(e["tid"])
-    assert set(by_name) == {"admit/32/4", "decode/8", "ragged/8"}
+    assert set(by_name) == {"admit/32/4", "decode/8", "verify/4"}
     assert all(len(tids) == 1 for tids in by_name.values())
     # Slices back-span from the sync point with the recorded duration.
     admit = next(e for e in slices if e["name"] == "admit/32/4")
@@ -275,7 +275,7 @@ def test_trace_view_variant_lanes_and_retrace_instants():
     metas = [e for e in lanes if e["ph"] == "M"]
     assert {"seldon-tpu variants"} == {
         e["args"]["name"] for e in metas if e["name"] == "process_name"}
-    assert {"admit/32/4", "decode/8", "ragged/8"} == {
+    assert {"admit/32/4", "decode/8", "verify/4"} == {
         e["args"]["name"] for e in metas if e["name"] == "thread_name"}
 
 

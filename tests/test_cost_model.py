@@ -12,7 +12,7 @@ The load-bearing claims, in test form:
    audit is not vacuous (a ledger fed inconsistent spans breaches);
  * the ledger is pure observation — greedy outputs are BIT-IDENTICAL
    with ROOF_LEDGER on vs off across all five dispatch paths (dense,
-   paged-KV, chunked prefill, ragged, spec-decode).
+   paged-KV, chunked prefill, both together, spec-decode).
 """
 
 import jax
@@ -35,8 +35,11 @@ MODES = {
     "paged": dict(paged_kv=True, kv_block=16, kv_pool_blocks=12,
                   prompt_buckets=(16, 32)),
     "chunked": dict(chunked_prefill=True, prefill_chunk=8, prefix_block=8),
-    "ragged": dict(paged_kv=True, chunked_prefill=True, prefill_chunk=8,
-                   prefix_block=8, kv_block=8, ragged=True),
+    "paged+chunked": dict(paged_kv=True, chunked_prefill=True,
+                          prefill_chunk=8, prefix_block=8, kv_block=8),
+    "paged+chunked+prefix": dict(paged_kv=True, chunked_prefill=True,
+                                 prefill_chunk=8, prefix_block=8,
+                                 kv_block=8, prefix_cache=True),
     "spec": dict(spec_decode=True, spec_k=2, paged_kv=True, kv_block=8,
                  prefix_block=8),
 }
@@ -205,7 +208,6 @@ REPRESENTATIVE = {
     "seed-prefix": ("seed-prefix", 16),
     "cow": ("cow",),
     "decode": ("decode", 8),
-    "ragged": ("ragged", 8),
     "draft": ("draft", 4),
     "verify": ("verify", 4),
 }
@@ -215,8 +217,7 @@ def test_every_family_is_priced():
     assert set(REPRESENTATIVE) == set(FAMILIES), \
         "FAMILIES drifted — add a representative key AND a cost formula"
     for fam, key in REPRESENTATIVE.items():
-        flops, bytes_ = cost_model.cost_of_key(key, TINY, kv_block=16,
-                                               ragged_chunk=8, **GEOM)
+        flops, bytes_ = cost_model.cost_of_key(key, TINY, kv_block=16, **GEOM)
         assert flops >= 0.0 and bytes_ >= 0.0, fam
         # Everything but the host-drafted spec rung moves SOME bytes.
         if fam != "draft":
@@ -236,59 +237,6 @@ def test_draft_prices_zero_without_resident_model():
                                            draft_cfg=TINY, **GEOM)
     assert (flops, bytes_) == cost_model.cost_of_key(("decode", 4), TINY,
                                                      **GEOM)
-
-
-def test_ragged_priced_at_capacity():
-    # The static cost_of_key formula stays the capacity bound at
-    # max_slots * C regardless of packing (exported as capacity_*
-    # since graftkern; the ledger's live fields come from
-    # ragged_occupancy_cost when the engine feeds occupancy).
-    f8, _ = cost_model.cost_of_key(("ragged", 8), TINY, **GEOM)
-    f16, _ = cost_model.cost_of_key(("ragged", 16), TINY, **GEOM)
-    assert f16 > f8 > 0.0
-
-
-def test_ragged_occupancy_cost_hand_counted():
-    # graftkern live pricing: q_tokens * fpt + 4 * d_model * attn_qk *
-    # layers; bytes = weights + (kv_read + q) positions of KV traffic.
-    flops, bytes_ = cost_model.ragged_occupancy_cost(
-        TINY, q_tokens=10, kv_read_tokens=20, attn_qk=100)
-    assert flops == 10 * 180224 + 4 * 64 * 100 * 2 == 1853440
-    assert bytes_ == 212992 + 20 * 256 + 10 * 256 == 220672
-    # tp=2: fpt/kv/weights all take their per-chip forms.
-    flops2, bytes2 = cost_model.ragged_occupancy_cost(
-        TINY, q_tokens=10, kv_read_tokens=20, attn_qk=100, tp=2)
-    assert flops2 == 10 * 131072 + 4 * 64 * 100 * 2 // 2 == 1336320
-    assert bytes2 == 163840 + 20 * 128 + 10 * 128 == 167680
-
-
-def test_ragged_occupancy_ledger_live_vs_capacity():
-    # The ledger prices a "ragged" key's LIVE fields from the queued
-    # occupancy (FIFO, one entry per wave) and always accumulates the
-    # static capacity figure alongside; with the queue empty the live
-    # fields fall back to capacity, and non-ragged families are always
-    # live == capacity.
-    led = cost_model.RoofLedger()
-    led.bind(TINY, ragged_chunk=8, **GEOM)
-    cap_f, cap_b = led._cost(("ragged", 8))
-    led.note_ragged_occupancy(10, 20, 100)
-    led.note_wave([("ragged", 8)], 2.0)
-    (v,) = led.snapshot()["variants"]
-    assert v["flops"] == 1853440.0 and v["bytes"] == 220672.0
-    assert v["capacity_flops"] == cap_f
-    assert v["capacity_bytes"] == cap_b
-    assert v["capacity_flops"] > v["flops"]
-    # Queue drained: an occupancy-blind wave prices live == capacity.
-    led.note_wave([("ragged", 8)], 2.0)
-    (v,) = led.snapshot()["variants"]
-    assert v["flops"] == 1853440.0 + cap_f
-    assert v["capacity_flops"] == 2 * cap_f
-    led.note_wave([("decode", 8)], 1.0)
-    (d,) = [x for x in led.snapshot()["variants"]
-            if x["family"] == "decode"]
-    assert d["capacity_flops"] == d["flops"]
-    assert d["capacity_bytes"] == d["bytes"]
-    assert d["capacity_predicted_ms"] == d["predicted_ms"]
 
 
 # ---------------------------------------------------------------------------

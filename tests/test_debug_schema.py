@@ -182,8 +182,7 @@ ROOF_STEP_KEYS = frozenset({
 ROOF_CONSERVATION_KEYS = frozenset({"checked", "breaches", "last_breach"})
 ROOF_VARIANT_KEYS = frozenset({
     "key", "family", "dispatches", "flops", "bytes", "device_ms",
-    "predicted_ms", "capacity_flops", "capacity_bytes",
-    "capacity_predicted_ms", "mfu", "mbu", "bound",
+    "predicted_ms", "mfu", "mbu", "bound",
 })
 ROOF_TOTALS_KEYS = frozenset({
     "dispatches", "flops", "bytes", "device_ms", "predicted_ms",
@@ -196,13 +195,13 @@ def _populated_compile_ledger() -> CompileLedger:
     keys, a sealed lattice, and one live-retrace witness."""
     led = CompileLedger()
     led.declare(("admit", 64, 4, 1))
-    led.declare(("ragged", 8))  # graftragged's one wave-kernel variant
+    led.declare(("verify", 4))  # a whole-batch wave's variant
     led.dispatch(("admit", 64, 4, 1), rid=-1, seconds=0.5)
     led.dispatch(("decode", 8), rid=-1, seconds=0.2)
-    led.dispatch(("ragged", 8), rid=-1, seconds=0.4)
+    led.dispatch(("verify", 4), rid=-1, seconds=0.4)
     led.warmup_done()
     led.dispatch(("admit", 64, 4, 1), rid=1, seconds=0.001)  # cache hit
-    led.dispatch(("ragged", 8), rid=3, seconds=0.0)          # cache hit
+    led.dispatch(("verify", 4), rid=3, seconds=0.0)          # cache hit
     witness = led.dispatch(("admit", 128, 8, 1), rid=2, seconds=0.7)
     assert witness is not None  # undeclared post-seal => live retrace
     return led
@@ -224,9 +223,6 @@ def _populated_sched_ledger() -> SchedLedger:
     led = SchedLedger()
     led.note_group(("admit", 64, 4), 256, 100, 92, 64)
     led.note_group(("chunk", 128, 2, 0), 256, 200, 56, 0)
-    # A graftragged wave: cells == useful by construction (exact-length
-    # segments, no bucket rounding, no group replication).
-    led.note_group(("ragged", 8), 46, 46, 0, 0)
     # A graftspec verify wave: 2 rows x (k=4 drafts + 1) = 10 cells, 7
     # emitted tokens -> 5 accepted drafts, 3 rejected positions.
     led.note_group(("verify", 4), 10, 7, 0, 0, spec_rejected=3)
@@ -322,11 +318,11 @@ def test_compile_snapshot_value_kinds():
         # Keys render as the canonical slash-joined string, not tuples.
         assert isinstance(entry["key"], str) and "/" in entry["key"]
         assert isinstance(entry["declared"], bool)
-    # The ragged family key renders with the same stable slash form as
+    # A wave family's key renders with the same stable slash form as
     # every other family — consumers key lanes/gates on the string.
-    ragged = [e for e in snap["lattice"] if e["key"] == "ragged/8"]
-    assert len(ragged) == 1 and ragged[0]["declared"] is True
-    assert ragged[0]["dispatches"] == 2
+    wave = [e for e in snap["lattice"] if e["key"] == "verify/4"]
+    assert len(wave) == 1 and wave[0]["declared"] is True
+    assert wave[0]["dispatches"] == 2
 
 
 def test_compile_snapshot_empty_ledger_same_keys():
@@ -397,13 +393,13 @@ def test_sched_snapshot_value_kinds():
     for entry in snap["by_shape"]:
         # Keys render as the canonical slash-joined string, not tuples.
         assert isinstance(entry["key"], str) and "/" in entry["key"]
-    # The ragged family's by_shape entry: stable "ragged/C" key, and
-    # its waste attribution is zero-pad by construction.
-    ragged = [e for e in snap["by_shape"] if e["key"] == "ragged/8"]
-    assert len(ragged) == 1
-    assert ragged[0]["cells"] == ragged[0]["useful_tokens"] == 46
-    assert ragged[0]["bucket_pad_tokens"] == 0
-    assert ragged[0]["group_pad_tokens"] == 0
+    # The verify family's by_shape entry: stable "verify/k" key, and
+    # a wave pads neither a bucket nor a group.
+    wave = [e for e in snap["by_shape"] if e["key"] == "verify/4"]
+    assert len(wave) == 1
+    assert wave[0]["cells"] == 10 and wave[0]["useful_tokens"] == 7
+    assert wave[0]["bucket_pad_tokens"] == 0
+    assert wave[0]["group_pad_tokens"] == 0
 
 
 def test_sched_snapshot_empty_ledger_same_keys():

@@ -20,7 +20,7 @@ import pytest
 from seldon_tpu.models import init_params, slot, transformer
 from seldon_tpu.models.config import get_config
 from seldon_tpu.ops import decode_attention as da
-from seldon_tpu.ops.ragged_paged_attention import RAGGED_LOGITS_ATOL
+from seldon_tpu.ops.decode_attention import ATTEND_ATOL
 from seldon_tpu.servers.engine import (
     CHUNK_COUNTERS, KV_COUNTERS, InferenceEngine)
 from tests._engine_fixture import live_config
@@ -113,7 +113,7 @@ def test_kernel_matches_the_einsums_on_live_rows(shape, kv_dtype, occupancy):
             assert np.isfinite(got).all()
             past = np.asarray(active & (pos > 0))
             np.testing.assert_allclose(
-                got[past], want[past], atol=RAGGED_LOGITS_ATOL, rtol=0)
+                got[past], want[past], atol=ATTEND_ATOL, rtol=0)
             np.testing.assert_array_equal(
                 got[~past], np.asarray(fresh_alone, f32)[~past])
 
@@ -184,7 +184,14 @@ def test_the_kernel_writes_the_live_slots_fresh_rows_and_nothing_else(
     past = np.asarray(active & (pos > 0))
     np.testing.assert_allclose(
         np.asarray(out, f32)[past], np.asarray(want, f32)[past],
-        atol=RAGGED_LOGITS_ATOL, rtol=0)
+        atol=ATTEND_ATOL, rtol=0)
+
+
+def test_the_kernels_tolerance_is_its_own_modules_constant():
+    """The bound the comparisons above hold the kernel to is documented
+    where the kernel is, at the value they have used since the kernel
+    came."""
+    assert da.ATTEND_ATOL == ATTEND_ATOL == 1e-2
 
 
 def test_schedule_lists_live_blocks_in_order():
@@ -260,7 +267,7 @@ def test_decode_step_with_the_kernel_gives_the_einsums_logits(
     """The three decode stacks take the kernel where the slab allows:
     live rows' logits agree with the einsums' (to STEP_ATOL: a last
     bit of a layer's bf16 output is carried through the layers after
-    it, where attention alone is held to RAGGED_LOGITS_ATOL above; a
+    it, where attention alone is held to ATTEND_ATOL above; a
     wrong layer, slot or mask moves these logits by 0.3 and more) and
     the cache they write is the same where a slot is live (the first
     layer's rows bit for bit; later layers' follow the activations);

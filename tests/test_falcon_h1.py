@@ -393,7 +393,7 @@ def test_the_attention_kernel_matches_the_einsums_at_twenty_query_heads(live):
     heads of 128: 5 queries a KV head, a head count that is neither a
     power of two nor a multiple of the sublane tile, rows of 512 lanes."""
     from seldon_tpu.ops import decode_attention as da
-    from seldon_tpu.ops.ragged_paged_attention import RAGGED_LOGITS_ATOL
+    from seldon_tpu.ops.decode_attention import ATTEND_ATOL
     from tests.pallas_interpret import pallas_interpret
 
     B, Tw, layers, Hkv, Dh, G = 8, 512, 2, 4, 128, 5
@@ -418,7 +418,7 @@ def test_the_attention_kernel_matches_the_einsums_at_twenty_query_heads(live):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert got.shape == (B, 1, H * Dh) and np.isfinite(got).all()
     past = np.asarray(active & (pos > 0))
-    np.testing.assert_allclose(got[past], want[past], atol=RAGGED_LOGITS_ATOL, rtol=0)
+    np.testing.assert_allclose(got[past], want[past], atol=ATTEND_ATOL, rtol=0)
     alone = np.asarray(jnp.repeat(vf[:, 0], G, axis=1).reshape(B, 1, H * Dh), np.float32)
     np.testing.assert_array_equal(got[~past], alone[~past])
 

@@ -28,6 +28,7 @@ Claims under test, by layer:
    covers the tools entry points.
 """
 
+import dataclasses
 import os
 import re
 import subprocess
@@ -78,6 +79,24 @@ def test_grid_closed_form_matches_simulation():
         holes, waste = shape_lattice.check_spec(spec)
         assert holes == [], (spec, holes)
         assert waste == [], (spec, waste)
+
+
+def test_the_grid_is_the_flag_cube_and_the_spec_legs():
+    """Two ways to dispatch a wave, so two legs of the grid: the (paged,
+    chunked, prefix) cube over every shape and the spec combos over
+    two; no family but those a key of the grid can name."""
+    specs = shape_lattice.grid()
+    assert shape_lattice.GRID_COUNT == (
+        len(shape_lattice.GRID_FLAG_COMBOS) * len(shape_lattice.GRID_SHAPES)
+        + len(shape_lattice.GRID_SPEC_COMBOS)
+        * shape_lattice.GRID_SPEC_SHAPES) == 40
+    assert sum(s.spec for s in specs) == 8
+    named = {k[0] for s in specs for k in shape_lattice.dispatch_keys(s)}
+    assert named == set(shape_lattice.FAMILIES) == set(
+        shape_lattice.FAMILY_TAGS)
+    assert not [f for f in shape_lattice.FAMILIES if "ragged" in f]
+    assert not [f.name for f in dataclasses.fields(shape_lattice.LatticeSpec)
+                if "ragged" in f.name]
 
 
 def test_every_lattice_key_matches_registered_arity():

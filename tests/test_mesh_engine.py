@@ -4,7 +4,7 @@ mesh, pinned bit-exact against tp=1.
 
 The load-bearing claims, in test form:
  * greedy output is BIT-IDENTICAL tp=2 vs tp=1 across every dispatch
-   family the engine ships — dense, paged, chunked, ragged, spec —
+   family the engine ships — dense, paged, chunked, both, spec —
    and for bf16, int8-KV and W8A8 weights: the exact-TP scheme shards
    only output dims (models/tp_sharding docstring), so per-element
    reduction order never changes;
@@ -50,8 +50,8 @@ MODES = {
     "paged": dict(paged_kv=True, kv_block=16, kv_pool_blocks=12,
                   prompt_buckets=(16, 32)),
     "chunked": dict(chunked_prefill=True, prefill_chunk=8, prefix_block=8),
-    "ragged": dict(paged_kv=True, chunked_prefill=True, prefill_chunk=8,
-                   prefix_block=8, kv_block=8, ragged=True),
+    "paged+chunked": dict(paged_kv=True, chunked_prefill=True,
+                          prefill_chunk=8, prefix_block=8, kv_block=8),
     "spec": dict(spec_decode=True, spec_k=2, paged_kv=True, kv_block=8,
                  prefix_block=8),
 }
@@ -105,11 +105,11 @@ def test_greedy_bit_identical_tp2_vs_tp1(mode):
     assert all(len(t) > 0 for t in want)
 
 
-def test_greedy_bit_identical_int8_kv_ragged():
+def test_greedy_bit_identical_int8_kv_paged_chunked():
     cfg = dataclasses.replace(get_config("tiny"), kv_cache_dtype="int8")
     params = _params(cfg)
-    want = _run(cfg, params, 1, **MODES["ragged"])
-    got = _run(cfg, params, 2, **MODES["ragged"])
+    want = _run(cfg, params, 1, **MODES["paged+chunked"])
+    got = _run(cfg, params, 2, **MODES["paged+chunked"])
     assert got == want, "tp=2 diverged from tp=1 with int8 KV"
 
 
@@ -138,8 +138,7 @@ def test_greedy_bit_identical_w8a8_big_bucket():
     params = _params(cfg)
     big = dict(max_slots=4, max_seq_len=128, prompt_buckets=(32, 128),
                paged_kv=True, kv_block=16, kv_pool_blocks=33,
-               chunked_prefill=True, prefill_chunk=32, prefix_block=16,
-               ragged=True)
+               chunked_prefill=True, prefill_chunk=32, prefix_block=16)
     prompts = [list(range(2, 2 + n)) for n in (24, 48, 96, 16)]
     sp = SamplingParams(temperature=0.0, max_new_tokens=16)
 

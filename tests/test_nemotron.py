@@ -468,7 +468,6 @@ def test_engine_prefill_and_decode_through_the_slab_follow_the_reference(served,
     ("paged_kv", dict(paged_kv=True)),
     ("prefix_cache", dict(prefix_cache=True)),
     ("chunked_prefill", dict(chunked_prefill=True)),
-    ("ragged", dict(ragged=True, paged_kv=True, chunked_prefill=True)),
     ("spec_decode", dict(spec_decode=True, paged_kv=True)),
     ("heal", dict(heal=True)),
     ("tp > 1", dict(tp=2)),

@@ -1,4 +1,4 @@
-"""Paged KV cache (block pool + ragged block-table attention): exactness,
+"""Paged KV cache (block pool + block-table attention): exactness,
 zero-copy sharing, and allocator mechanics.
 
 The load-bearing claims, in test form:

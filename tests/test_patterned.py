@@ -405,7 +405,6 @@ def test_engine_serves_groups_of_unequal_length_and_reuses_slots(served):
     ("paged_kv", dict(paged_kv=True)),
     ("prefix_cache", dict(prefix_cache=True)),
     ("chunked_prefill", dict(chunked_prefill=True)),
-    ("ragged", dict(ragged=True, paged_kv=True, chunked_prefill=True)),
     ("spec_decode", dict(spec_decode=True, paged_kv=True)),
     ("heal", dict(heal=True)),
     ("tp > 1", dict(tp=2)),

@@ -26,13 +26,21 @@ SAMPLED = SamplingParams(temperature=0.8, top_k=8, seed=7,
 
 PAGED = dict(paged_kv=True, kv_block=8, prefix_block=8)
 CHUNKED = dict(chunked_prefill=True, prefill_chunk=8, prefix_block=8)
+PREFIX = dict(prefix_cache=True, prefix_block=8)
 PATHS = {
     "dense": {},
     "dense-sync": dict(async_fetch=False),
     "paged": PAGED,
     "chunked": CHUNKED,
-    "ragged": {"ragged": True, **PAGED, **CHUNKED},
+    "paged+chunked": {**PAGED, **CHUNKED},
     "spec": dict(spec_decode=True, spec_k=4, **PAGED),
+    # the compositions: the trie over each substrate, the synchronous
+    # loop under the pool and under chunked prefill
+    "prefix": PREFIX,
+    "chunked+prefix": {**CHUNKED, **PREFIX},
+    "paged+chunked+prefix": {**PAGED, **CHUNKED, **PREFIX},
+    "paged-sync": dict(async_fetch=False, **PAGED),
+    "chunked-sync": dict(async_fetch=False, **CHUNKED),
 }
 
 
@@ -52,9 +60,13 @@ def _engine(cfg, tp=1, **ekw):
 
 
 def _stream(cfg, sp, **ekw):
+    """PROMPT's stream; a prefix engine is asked twice and answers with
+    its second, warm admission."""
     eng = _engine(cfg, **ekw)
     try:
-        return eng.generate_blocking(PROMPT, sp)["token_ids"]
+        for _ in range(2 if ekw.get("prefix_cache") else 1):
+            out = eng.generate_blocking(PROMPT, sp)["token_ids"]
+        return out
     finally:
         eng.stop()
 
@@ -203,7 +215,7 @@ def test_every_path_answers_the_same(want, preset, path, name, sp):
 # tensor-parallel engine shards its rows by head group, and the
 # speculative engine turns a cold prefill's rows by head for its pool.
 SLAB_PATHS = {
-    "prefix": dict(prefix_cache=True, prefix_block=8),
+    "prefix": PREFIX,
     "chunked": CHUNKED,
     "spec": dict(spec_decode=True, spec_k=4, **PAGED),
     "tp2": dict(tp=2),
@@ -258,7 +270,9 @@ def test_prefilled_kv_lands_where_a_decode_step_reads_it(slab_want, path,
 # --- (e) every path counts its sampler steps --------------------------------
 
 
-@pytest.mark.parametrize("path", ["paged", "ragged", "spec"])
+@pytest.mark.parametrize("path", [
+    "paged", "paged+chunked", "spec", "prefix", "chunked+prefix",
+    "paged+chunked+prefix", "paged-sync", "chunked-sync"])
 def test_paths_count_sampler_steps(path):
     eng = _engine(live_config(), **PATHS[path])
     try:

@@ -1,5 +1,5 @@
 """graftspec (models/spec_decode.py + engine._dispatch_spec): draft
-k tokens, verify all k+1 positions in one ragged wave, commit the
+k tokens, verify all k+1 positions in one wide wave, commit the
 accepted prefix, roll the rest back — pinned against the plain engine.
 
 The load-bearing claims, in test form:
@@ -129,28 +129,29 @@ class _AntiOracle:
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-@pytest.mark.parametrize("mode", ["paged", "chunked", "prefix"])
+@pytest.mark.parametrize("mode", ["paged", "chunked", "prefix",
+                                  "chunked+prefix"])
 def test_spec_bit_identical_across_modes(kv_dtype, mode):
     """The acceptance gate's exactness criterion: greedy output under
     SPEC matches the spec-off engine token-for-token in every paged
     mode x KV dtype."""
     cfg = live_config(kv_cache_dtype=kv_dtype)
     extra = {}
-    if mode == "chunked":
-        extra = dict(chunked_prefill=True, prefill_chunk=8)
-    elif mode == "prefix":
-        extra = dict(prefix_cache=True)
+    if "chunked" in mode:
+        extra.update(chunked_prefill=True, prefill_chunk=8)
+    if "prefix" in mode:
+        extra.update(prefix_cache=True)
     want = _want(cfg, **PAGED, **extra)
 
     eng = _engine(cfg, **SPEC, **extra)
     try:
-        if mode == "prefix":
+        if "prefix" in mode:
             # Cold admission seeds the trie; the warm resume is the
             # interesting path (spec waves over shared blocks).
             assert eng.generate_blocking(PROMPT, GREEDY)["token_ids"] \
                 == want
         got = eng.generate_blocking(PROMPT, GREEDY)["token_ids"]
-        if mode == "prefix":
+        if "prefix" in mode:
             assert eng.stats.snapshot()["zero_copy_admissions"] >= 1
     finally:
         eng.stop()
@@ -433,6 +434,21 @@ def test_spec_pilot_binds_fourth_knob(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
+def test_verify_wave_has_one_attention_leg():
+    """No argument picks between implementations of the wave's
+    attention: it is gqa_attention_verify, the leg that is exact at
+    any temperature."""
+    import inspect
+
+    from seldon_tpu.models import spec_decode
+
+    assert list(inspect.signature(spec_decode.verify_wave).parameters) == [
+        "params", "state", "table", "drafts", "wave", "cfg", "tp"]
+    assert list(inspect.signature(
+        InferenceEngine._verify_impl).parameters) == [
+        "params", "state", "table", "drafts", "wave", "cfg", "mesh", "tp"]
+
+
 def test_spec_off_engine_is_untouched():
     cfg = live_config()
     eng = _engine(cfg, start=False, **PAGED)
@@ -446,10 +462,6 @@ def test_spec_config_validation():
     base = dict(max_slots=4, max_seq_len=64, prompt_buckets=(8, 32))
     with pytest.raises(ValueError, match="paged_kv"):
         EngineConfig(spec_decode=True, **base)
-    with pytest.raises(ValueError, match="ragged"):
-        EngineConfig(spec_decode=True, paged_kv=True, kv_block=8,
-                     prefix_block=8, chunked_prefill=True,
-                     prefill_chunk=8, ragged=True, **base)
     with pytest.raises(ValueError, match="power of two"):
         EngineConfig(spec_decode=True, spec_k=3, paged_kv=True,
                      kv_block=8, prefix_block=8, **base)

@@ -8,8 +8,8 @@ The load-bearing claims, in test form:
    raw `_fail_all` failure path;
  * resurrection is BIT-IDENTICAL: a mid-stream wave fault resurrects
    every innocent request and the delivered stream matches the
-   fault-free reference token-for-token — dense / paged / ragged /
-   spec, bf16 AND int8 KV, greedy AND sampled (per-position sampling
+   fault-free reference token-for-token — dense / paged /
+   paged+chunked / spec, bf16 AND int8 KV, greedy AND sampled (per-position sampling
    keys make the replayed continuation exact);
  * poison quarantine bisects: a seeded sticky request that
    deterministically wrecks every wave it rides is isolated in log2
@@ -65,8 +65,8 @@ MODES = {
     "dense": dict(),
     "paged": dict(paged_kv=True, kv_block=16, kv_pool_blocks=9,
                   prompt_buckets=(16, 32)),
-    "ragged": dict(paged_kv=True, chunked_prefill=True, prefill_chunk=8,
-                   prefix_block=8, kv_block=8, ragged=True),
+    "paged+chunked": dict(paged_kv=True, chunked_prefill=True,
+                          prefill_chunk=8, prefix_block=8, kv_block=8),
     "spec": dict(spec_decode=True, spec_k=4, paged_kv=True, kv_block=8,
                  prefix_block=8),
 }

@@ -25,8 +25,11 @@ MODES = {
     "dense": {},
     "chunked": dict(chunked_prefill=True, prefill_chunk=8, prefix_block=8),
     "paged": dict(paged_kv=True, kv_block=8, prefix_block=8),
-    "ragged": dict(paged_kv=True, kv_block=8, prefix_block=8,
-                   chunked_prefill=True, prefill_chunk=8, ragged=True),
+    "paged+chunked": dict(paged_kv=True, kv_block=8, prefix_block=8,
+                          chunked_prefill=True, prefill_chunk=8),
+    "paged+chunked+prefix": dict(paged_kv=True, kv_block=8, prefix_block=8,
+                                 chunked_prefill=True, prefill_chunk=8,
+                                 prefix_cache=True),
     "sync": dict(async_fetch=False),
 }
 PHASES = ("executor_wait_ms", "queue_wait_ms", "device_wait_ms",
@@ -318,7 +321,7 @@ JIT_NAMES = {
     "_jit_seed_prefix": "_seed_prefix_impl",
     "_jit_admit_paged": "_paged_admit_impl", "_jit_cow": "_cow_copy_impl",
     "_jit_chunks": "_chunk_impl", "_jit_chunks_paged": "_paged_chunk_impl",
-    "_jit_deactivate": "_deactivate_impl", "_jit_ragged": "_ragged_impl",
+    "_jit_deactivate": "_deactivate_impl",
     "_jit_verify": "_verify_impl", "_jit_draft": "draft_tokens",
 }
 
@@ -328,10 +331,10 @@ JIT_NAMES = {
     dict(prefix_cache=True, prefix_block=8, chunked_prefill=True,
          prefill_chunk=8),
     dict(paged_kv=True, kv_block=8, prefix_block=8, chunked_prefill=True,
-         prefill_chunk=8, ragged=True),
+         prefill_chunk=8),
     dict(paged_kv=True, kv_block=8, prefix_block=8, spec_decode=True,
          spec_k=2),
-], ids=["dense-prefix", "chunked-prefix", "ragged", "spec"])
+], ids=["dense-prefix", "chunked-prefix", "paged+chunked", "spec"])
 def test_every_engine_jit_carries_its_methods_name(ekw):
     eng = _engine(start=False, **ekw)
     jits = {a: v for a, v in vars(eng).items()

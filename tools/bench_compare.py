@@ -37,8 +37,8 @@ and compared base -> candidate with a direction heuristic:
  * strict:           ``live_retraces`` and ``compile_variants`` — any
    increase over base fails regardless of tolerance (a retrace storm
    is a correctness-of-the-lattice bug, and the variant count is an
-   exact closed-form property of the config — graftragged collapses
-   it to ≤ 2, so even one stray variant is a real regression);
+   exact closed-form property of the config, so even one stray
+   variant is a real regression);
  * everything else is informational (printed, never gated).
 
 A gated metric regresses when it moves the wrong way by more than the

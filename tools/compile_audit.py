@@ -31,21 +31,7 @@ Before booting anything it also runs the graftnum certifier passes
 a tree the audit is about to *measure* must already be numerics- and
 lifetime-clean, or the measured bits aren't the contract bits.
 
-The audit then runs a second, RAGGED leg — once per attention-kernel
-leg (``RAGGED_KERNEL=masked`` and ``sparse``; graftkern): the same
-warmed tiny server under ``RAGGED=1`` driven by the same loadtester
-mix, asserting the graftragged collapse holds on EVERY kernel leg —
-compile-variant count ≤ ``RAGGED_VARIANT_BUDGET`` (deactivate + the
-one ``ragged/C`` wave kernel; the kernel string is closed over at jit
-time, so swapping it must not widen the lattice) and zero live
-retraces. The masked leg's numbers ride the metric line
-(``ragged_compile_variants`` / ``ragged_live_retraces``) and the
-sparse leg adds ``ragged_sparse_*`` twins, so ``bench_compare`` gates
-both strictly. The pallas leg is exercised by
-tests/test_ragged_kernel.py (interpreted) and chip_smoke.py (compiled,
-on the chip) instead — the kernel raises on the CPU this audit runs on.
-
-A third, SPEC leg boots the same server under ``SPEC=1`` and asserts
+A second, SPEC leg boots the same server under ``SPEC=1`` and asserts
 the graftspec lattice contract: the pow2 ``verify/k`` ladder replaces
 the ``decode/n`` chunk rungs (a verify wave dispatched, no decode
 variant did), every dispatched key stays inside ``static_lattice()``,
@@ -70,12 +56,6 @@ import sys
 # Roadmap items 1-2 drive this DOWN; raising it needs a written
 # justification in the PR that does so.
 VARIANT_BUDGET = 32
-
-# The graftragged contract is exact, not a ceiling with headroom: one
-# unified wave kernel + deactivate. A third variant means the collapse
-# broke (ISSUE 12 acceptance: static_lattice() size ≤ 2 under RAGGED=1).
-RAGGED_VARIANT_BUDGET = 2
-
 
 def _check(cond: bool, msg: str) -> None:
     if not cond:
@@ -269,64 +249,6 @@ def main(argv=None) -> int:
 
     srv.engine.stop()
 
-    # --- RAGGED leg: the graftragged collapse, once per kernel leg ------
-    ragged_legs = {}
-    ragged_static_size = None
-    for kern in ("masked", "sparse"):
-        tag = f"ragged[{kern}]"
-        rsrv, rdetail, rcomp, _, _ = _drive(ragged=1, ragged_kernel=kern)
-        _check(rcomp["warmup_complete"],
-               f"{tag}: warmup never sealed the lattice")
-        _check(
-            rcomp["live_retrace_count"] == 0,
-            f"{tag}: {rcomp['live_retrace_count']} live retraces after "
-            f"warmup: {rcomp['live_retraces']}",
-        )
-        _check(
-            1 <= rcomp["dispatched_variants"] <= RAGGED_VARIANT_BUDGET,
-            f"{tag}: {rcomp['dispatched_variants']} variants dispatched "
-            f"— the collapse contract is ≤ {RAGGED_VARIANT_BUDGET} "
-            f"(deactivate + one ragged/C wave kernel)",
-        )
-        rogue = [e["key"] for e in rcomp["lattice"] if not e["declared"]]
-        _check(not rogue, f"{tag}: undeclared lattice keys: {rogue}")
-        _check(
-            any(e["key"].startswith("ragged/") for e in rcomp["lattice"]),
-            f"{tag}: no ragged/C variant dispatched "
-            f"(got: {sorted(e['key'] for e in rcomp['lattice'])})",
-        )
-        _check(
-            rdetail.get("compile_variants") == rcomp["dispatched_variants"],
-            f"{tag}: ledger compile_variants "
-            f"{rdetail.get('compile_variants')} != /debug/compile "
-            f"{rcomp['dispatched_variants']}",
-        )
-        if args.static_xcheck:
-            rstatic = set(rsrv.engine.static_lattice())
-            _check(
-                len(rstatic) <= RAGGED_VARIANT_BUDGET,
-                f"{tag}: static lattice holds {len(rstatic)} keys "
-                f"({sorted(rstatic)}) — the closed-form collapse broke",
-            )
-            rdispatched = {e["key"] for e in rcomp["lattice"]}
-            rrogue = sorted(rdispatched - rstatic)
-            _check(
-                not rrogue,
-                f"{tag}: runtime dispatched {len(rrogue)} key(s) outside "
-                f"the static lattice: {rrogue}",
-            )
-            _check(
-                rcomp["declared_variants"] == len(rstatic),
-                f"{tag}: warmup declared {rcomp['declared_variants']} "
-                f"variants but the static lattice holds {len(rstatic)}",
-            )
-            if kern == "masked":
-                ragged_static_size = len(rstatic)
-        rsrv.engine.stop()
-        ragged_legs[kern] = (rdetail, rcomp)
-    rdetail, rcomp = ragged_legs["masked"]
-    sdetail_sparse, scomp_sparse = ragged_legs["sparse"]
-
     # --- SPEC leg: the verify ladder stays inside the lattice -----------
     # graftspec replaces the decode-chunk rungs with the pow2
     # ("verify", k) ladder; the contract here is containment + zero
@@ -394,16 +316,6 @@ def main(argv=None) -> int:
             "variant_lanes": sorted(lanes),
             "hbm_total_bytes": hbm["total_bytes"],
             "static_lattice": static_size,
-            "ragged_requests": rdetail["requests"],
-            "ragged_compile_variants": rcomp["dispatched_variants"],
-            "ragged_variant_budget": RAGGED_VARIANT_BUDGET,
-            "ragged_live_retraces": rcomp["live_retrace_count"],
-            "ragged_static_lattice": ragged_static_size,
-            "ragged_sparse_requests": sdetail_sparse["requests"],
-            "ragged_sparse_compile_variants":
-                scomp_sparse["dispatched_variants"],
-            "ragged_sparse_live_retraces":
-                scomp_sparse["live_retrace_count"],
             "spec_requests": sdetail["requests"],
             "spec_compile_variants": scomp["dispatched_variants"],
             "spec_live_retraces": scomp["live_retrace_count"],

@@ -10,7 +10,7 @@ shape error and plausible-looking output.
 Rule ``einsum-broadcast``: for every ``jnp.einsum`` / ``lax.dot_general``
 whose operand shapes are statically traceable (tuple-unpacked
 ``.shape``, ``reshape``/``zeros``/``ones``/``full``/``broadcast_to``
-literals — the descriptor-driven fixed buffers of the ragged path),
+literals — descriptor-driven fixed buffers),
 flag a repeated label binding a literal size-1 dimension against a
 dimension of literal size > 1 or a named (symbolic) size.  Two
 bindings of the SAME symbol (legitimate batch that may be 1 at

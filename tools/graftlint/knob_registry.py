@@ -49,35 +49,13 @@ KNOBS = {
                    "KV block size in tokens (paged mode)."),
     "KV_POOL_MB": _k("engine-serving", "0 (dense-equivalent)",
                      "KV pool size in HBM MiB (paged mode)."),
-    "RAGGED": _k("engine-serving", "0",
-                 "graftragged unified dispatch: pack any mix of prefill "
-                 "chunks, continuations and decode steps into ONE "
-                 "ragged wave kernel (single compiled variant, no "
-                 "bucket/group lattice). Forces paged_kv + "
-                 "chunked_prefill."),
-    "RAGGED_CHUNK": _k("engine-serving", "0 (prefill_chunk)",
-                       "Per-slot token segment per ragged wave; the "
-                       "wave's flat token buffer is max_slots * "
-                       "ragged_chunk. Power of two, multiple of "
-                       "kv_block."),
-    "RAGGED_KERNEL": _k("engine-serving", "masked",
-                        "graftkern ragged attention leg: `masked` = "
-                        "bit-exact full-width baseline; `sparse` = "
-                        "block-sparse jnp walker touching only live KV "
-                        "blocks (online softmax, int8 dequant fused; "
-                        "the CPU perf leg); `pallas` = the Mosaic TPU "
-                        "kernel for the same walk (raises off a TPU). "
-                        "Greedy outputs token-identical across "
-                        "legs; all legs share the ONE (ragged, C) "
-                        "compiled variant. Also selects the spec "
-                        "verify_wave leg."),
     "SPEC": _k("engine-serving", "0",
                "graftspec speculative decoding: a drafter proposes k "
-               "tokens per live decode row and ONE wide ragged verify "
+               "tokens per live decode row and ONE wide verify "
                "wave scores all k + 1 positions against the paged "
                "block tables; exact-match acceptance keeps output "
                "bit-identical to SPEC=0 at any temperature. Requires "
-               "paged_kv (forced on); incompatible with RAGGED."),
+               "paged_kv (forced on)."),
     "SPEC_K": _k("engine-serving", "0 (engine default 4)",
                  "Draft tokens per verify wave (power of two); the "
                  "compiled pow2 verify ladder spans 1..spec_k and "
@@ -334,11 +312,6 @@ KNOBS = {
     "MB_DRAFT": _k("bench-tools", "(unset)", "Draft-model preset for the "
                    "`--spec k` microbench mode; adds the draft dispatch "
                    "to the wave cost."),
-    "MB_RAGGED_CHUNK": _k("bench-tools", "16", "Per-slot chunk capacity "
-                          "C for the `--ragged` kernel microbench wave."),
-    "MB_PALLAS": _k("bench-tools", "(unset)", "Non-empty adds the pallas "
-                    "leg (TPU only: the kernel raises elsewhere) to "
-                    "the `--ragged` kernel microbench."),
     "TUNE_ACT": _k("bench-tools", "int8", "Activation dtype for the 8b "
                    "tuning sweep."),
     "PROBE_PRESET": _k("bench-tools", "llama3-8b", "Slot-cliff probe preset "
@@ -419,12 +392,6 @@ KNOBS = {
                                   "compares against."),
     "BENCH_PAGED_KV_BLOCK": _k("bench-harness", "16",
                                "Paged phase KV block size."),
-    "BENCH_RAGGED": _k("bench-harness", "0",
-                       "Run the ragged-dispatch phase: the same closed "
-                       "wave RAGGED=1 vs bucketed at equal hardware, "
-                       "reporting req/s, padding_waste_frac, compile "
-                       "variant count, and the measured speedup vs the "
-                       "waste_roofline prediction."),
     "BENCH_SPEC": _k("bench-harness", "0",
                      "Run the speculative-decoding phase: the same "
                      "greedy closed wave SPEC on vs off at equal "
@@ -442,8 +409,8 @@ KNOBS = {
                            "the host n-gram drafter, or a preset name "
                            "for a resident draft model."),
     "BENCH_MESH": _k("bench-harness", "0",
-                     "Run the graftmesh phase: the same greedy ragged "
-                     "closed wave tp=BENCH_MESH_TP vs single-chip at "
+                     "Run the graftmesh phase: the same greedy paged + "
+                     "chunked closed wave tp=BENCH_MESH_TP vs single-chip at "
                      "EQUAL engine config, asserting bit-identical "
                      "streams and recording per-device HBM "
                      "(bench_compare gates bytes_per_device and "

@@ -16,9 +16,9 @@ to tests until a near-tied greedy argmax flips:
 
 Both are fixed by ``jax.lax.optimization_barrier``: it pins the
 intermediate to ONE materialized value shared by every consumer and
-every compilation.  This pass makes the two hand-placed barriers
-(``models/transformer._quantize_act``/``_quantize_kv`` and
-``ops/ragged_paged_attention._sparse_block``) machine-certified
+every compilation.  This pass makes the hand-placed barriers
+(``models/transformer._quantize_act``/``_quantize_kv`` and the
+dequanted prefix of ``_run_blocks_prefill_prefix``) machine-certified
 instead of folklore, and every future kernel leg inherits the check.
 
 Rule ``num-barrier``:
@@ -384,7 +384,7 @@ def run(files: List[core.SourceFile], ctx: core.Context) -> List[core.Finding]:
                     f"points",
                     hint="wrap the product: jax.lax.optimization_barrier"
                          "(w.astype(dt) * scale.astype(dt)) "
-                         "(ops/ragged_paged_attention._sparse_block)",
+                         "(models/transformer._run_blocks_prefill_prefix)",
                     qualname=core.qualname_of(m),
                 ))
 

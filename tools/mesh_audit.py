@@ -9,8 +9,8 @@ set here, matching tests/conftest.py) — with ``GRAFTSAN=1`` +
 ``ROOF_LEDGER=1``, and asserts the graftmesh contract in one pass:
 
  * BIT-EXACT PARITY: the TP group reproduces the single-chip greedy
-   streams token for token on a mixed-length prompt matrix (ragged
-   paged serving — the full unified dispatch stack runs SPMD);
+   streams token for token on a mixed-length prompt matrix (paged +
+   chunked serving — pool, block tables and chunk admissions run SPMD);
  * ONE SEALED LATTICE serves the whole group: ``/debug/compile``
    reports the TP geometry (tp=2, mesh_devices=2), every dispatched
    variant sits inside ``static_lattice()``, and a real loadtester
@@ -100,7 +100,7 @@ def main(argv=None) -> int:
     from seldon_tpu.servers.jaxserver import JAXServer
 
     SERVE = dict(preset="tiny", max_slots=4, max_seq_len=64, warmup=1,
-                 ragged=1)
+                 paged_kv=1, chunked_prefill=1)
 
     # --- reference leg: same weights on an explicit single chip --------
     # (tp=1 unit param overrides the TP=2 env; init_seed-determined
@@ -149,15 +149,17 @@ def main(argv=None) -> int:
         # live KV genuinely returned to zero rather than never moving.
         from seldon_tpu.models.sampling import SamplingParams
 
-        q = srv.engine.submit(PARITY_PROMPTS[2], SamplingParams(
-            temperature=0.0, max_new_tokens=PARITY_NEW))
-        _check(q.get(timeout=120) is not None,
-               "occupancy probe stream produced nothing")
-        probe = get("/debug/hbm")
-        _check(probe["categories"]["kv_live"]["bytes"] > 0,
-               "no live KV bytes with an occupied slot on the mesh")
+        # A stream of 40 tokens (this prompt's greedy stream meets no
+        # EOS), looked at in this process at each delivery: a short one
+        # has ended, and freed its blocks, before an HTTP round trip.
+        q = srv.engine.submit(PARITY_PROMPTS[0], SamplingParams(
+            temperature=0.0, max_new_tokens=40))
+        live = 0
         while q.get(timeout=120) is not None:
-            pass
+            live = live or srv.engine.debug_hbm()[
+                "categories"]["kv_live"]["bytes"]
+        _check(live > 0,
+               "no live KV bytes with an occupied slot on the mesh")
 
         # --- bit-exact parity ------------------------------------------
         got = _streams(srv.engine)

@@ -16,23 +16,11 @@ when mean acceptance clears it) and the full-acceptance speedup bound.
 proposal dispatch (models/spec_decode.draft_tokens); without it the
 n-gram drafter's host cost (~0) is assumed.
 
-``--ragged`` switches to the graftkern kernel legs: ONE ragged decode
-wave (models/ragged_attention.ragged_wave — the decode-only regime
-that dominates a serving trace) over all slots at MIXED context
-lengths, timed per kernel leg: masked (full-width baseline) vs sparse
-(block-sparse walker); ``MB_PALLAS=1`` adds the pallas leg (TPU only:
-the Mosaic kernel raises elsewhere, so opt-in). Prints ms/wave per leg and
-the sparse-vs-masked speedup.
-
 ``--roof`` adds graftroof's analytical prediction next to every
 measured number (servers/cost_model.cost_of_key at this bench's exact
 geometry, peaks resolved per platform env > table > microbench): the
 predicted ms per decode step / per verify wave and the measured-over-
-predicted ratio — the cost model's calibration check. Under
-``--ragged`` it prints BOTH pricings: live occupancy
-(cost_model.ragged_occupancy_cost at the wave's real descriptor
-occupancy — the post-graftkern ledger number) and the static
-capacity bound, against each leg's measured wave.
+predicted ratio — the cost model's calibration check.
 """
 
 from __future__ import annotations
@@ -251,129 +239,14 @@ def bench_spec(k: int, weights: str, kv: str, attn: str = "xla") -> None:
         )
 
 
-def bench_ragged(weights: str, kv: str, attn: str = "xla") -> None:
-    """graftkern kernel legs: one ragged decode wave at mixed context
-    lengths, per RAGGED_KERNEL leg. The wave is decode-only (the
-    steady-state regime): masked still pays its full-width prefill leg
-    and full-window attention reads; sparse skips the dead prefill via
-    the wave cond and walks only ceil(pos/block) live blocks per row —
-    exactly the serving-trace gap the engine's kernel knob toggles."""
-    from seldon_tpu.models import ragged_attention as ra
-
-    cfg = get_config(PRESET, weight_dtype=weights, kv_cache_dtype=kv,
-                     attn_impl=attn, act_dtype=act_for(weights))
-    if weights == "int8":
-        from seldon_tpu.models.quantize import init_params_int8
-
-        params = init_params_int8(cfg, jax.random.key(0))
-    else:
-        params = init_params(cfg, jax.random.key(0))
-    B = SLOTS
-    block = 64
-    nbs = -(-WINDOW // block)
-    Smax = nbs * block
-    C = int(os.environ.get("MB_RAGGED_CHUNK", "16"))
-    # Block 0 is the trash block; row i owns blocks [1 + i*nbs, ...).
-    table = jnp.arange(1, B * nbs + 1, dtype=jnp.int32).reshape(B, nbs)
-    # Mixed live contexts: cycle a spread across the window so the
-    # sparse walker's per-row trip counts genuinely differ.
-    ctx = [max(1, (Smax * f) // 8) for f in (1, 2, 4, 7)]
-    pos0 = jnp.asarray([ctx[i % len(ctx)] for i in range(B)], jnp.int32)
-    pos0 = jnp.minimum(pos0, Smax - 2)
-    tokens = jnp.ones((B * C,), jnp.int32)
-    plens = jnp.zeros((B,), jnp.int32)
-    starts = jnp.full((B,), Smax, jnp.int32)  # idle rows, engine-style
-    finals = jnp.zeros((B,), jnp.bool_)
-    is_prefill = jnp.zeros((B,), jnp.bool_)
-    seeds = jnp.arange(B, dtype=jnp.uint32)
-    temps = jnp.zeros((B,), jnp.float32)
-    top_ks = jnp.zeros((B,), jnp.int32)
-    top_ps = jnp.ones((B,), jnp.float32)
-    max_news = jnp.full((B,), 64, jnp.int32)
-
-    from tools.timing import slope_time
-
-    # One pool chained through every leg: each kernel's jit donates the
-    # state in and slope_time's final state seeds the next leg. Every
-    # chain link resets pos/active/remaining, so leg timings stay
-    # comparable regardless of order.
-    # State arrays are copies — the wave args stay undonated.
-    state = {
-        "cache": transformer.init_paged_cache(cfg, B * nbs + 1, block),
-        "last_tok": jnp.ones((B,), jnp.int32),
-        "pos": pos0 + 0,
-        "active": jnp.ones((B,), jnp.bool_),
-        "remaining": jnp.full((B,), 64, jnp.int32),
-        "temp": jnp.zeros((B,), jnp.float32),
-        "top_k": jnp.zeros((B,), jnp.int32),
-        "top_p": jnp.ones((B,), jnp.float32),
-        "seeds": jnp.arange(B, dtype=jnp.uint32),
-    }
-
-    def time_kernel(kern: str, state: dict):
-        fn = jax.jit(
-            functools.partial(ra.ragged_wave, cfg=cfg, kernel=kern),
-            donate_argnums=(1,))
-
-        def one(st):
-            st = dict(st, pos=pos0 + 0, active=jnp.ones((B,), jnp.bool_),
-                      remaining=jnp.full((B,), 64, jnp.int32))
-            st = fn(params, st, table, tokens, plens, starts, seeds,
-                    temps, top_ks, top_ps, max_news, finals,
-                    is_prefill)[0]
-            return st
-
-        dt, state = slope_time(one, state, k1=2, k2=6)
-        return 1000.0 * dt, state
-
-    kernels = ["masked", "sparse"]
-    if os.environ.get("MB_PALLAS", ""):
-        kernels.append("pallas")
-    ms = {}
-    for kern in kernels:
-        ms[kern], state = time_kernel(kern, state)
-    line = (f"w={weights:5s} kv={kv:5s} act={cfg.act_dtype:5s} ragged "
-            f"B={B} ctx~{int(pos0.mean())}/{Smax}")
-    for kern in kernels:
-        line += f"  {kern} {ms[kern]:7.3f} ms/wave"
-    line += f"  sparse speedup {ms['masked'] / ms['sparse']:.2f}x"
-    print(line, flush=True)
-    if ROOF:
-        from seldon_tpu.servers import cost_model
-
-        dev = jax.devices()[0]
-        peaks = cost_model.resolve_peaks(
-            getattr(dev, "device_kind", "") or dev.platform
-        )
-        live_qk = int(pos0.sum())
-        lf, lb = cost_model.ragged_occupancy_cost(
-            cfg, q_tokens=B, kv_read_tokens=live_qk, attn_qk=live_qk)
-        pred_live = cost_model.roofline_ms(lf, lb, peaks)
-        cf, cb = cost_model.cost_of_key(
-            ("ragged", C), cfg, max_slots=B, max_seq_len=Smax,
-            kv_block=block)
-        pred_cap = cost_model.roofline_ms(cf, cb, peaks)
-        print(
-            f"  roof: live-occupancy predicted {pred_live:7.3f} ms/wave  "
-            f"capacity predicted {pred_cap:7.3f} ms/wave  "
-            f"measured/predicted sparse {ms['sparse'] / pred_live:6.2f}x  "
-            f"masked {ms['masked'] / pred_cap:6.2f}x",
-            flush=True,
-        )
-
-
 ROOF = False
 
 if __name__ == "__main__":
     args = sys.argv[1:]
     spec_k = 0
-    ragged = False
     if "--roof" in args:
         args.remove("--roof")
         ROOF = True
-    if "--ragged" in args:
-        args.remove("--ragged")
-        ragged = True
     if "--spec" in args:
         i = args.index("--spec")
         spec_k = int(args[i + 1])
@@ -381,9 +254,7 @@ if __name__ == "__main__":
     combos = args or ["int8:bf16", "int8:int8", "bf16:bf16", "bf16:int8"]
     for c in combos:
         parts = c.split(":")
-        if ragged:
-            bench_ragged(*parts[:3])
-        elif spec_k:
+        if spec_k:
             bench_spec(spec_k, *parts[:3])
         else:
             bench(*parts[:3])

@@ -159,7 +159,7 @@ def probe(name, B, T, layers, repeats, live, contexts, block, interpret):
         us, cache = time_of(scatter, cache,
                             jnp.arange(B, dtype=jnp.int32) * 7)
         row["scatter_us"] = round(us, 2)
-        # the chat mix's occupancy: 3 live rows, ragged contexts
+        # the chat mix's occupancy: 3 live rows, uneven contexts
         active = jnp.arange(B) % (B // min(B, 3)) == 0
         pos = (jnp.arange(B) * 37 % (T - 1)).astype(jnp.int32)
         sched = da.schedule(active, pos, T, block)

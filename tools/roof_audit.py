@@ -51,12 +51,11 @@ ROOF_TOP_KEYS = frozenset({
 })
 ROOF_VARIANT_KEYS = frozenset({
     "key", "family", "dispatches", "flops", "bytes", "device_ms",
-    "predicted_ms", "capacity_flops", "capacity_bytes",
-    "capacity_predicted_ms", "mfu", "mbu", "bound",
+    "predicted_ms", "mfu", "mbu", "bound",
 })
 DEBUG_ROUTES = frozenset({
     "/debug/timeline", "/debug/compile", "/debug/hbm", "/debug/sched",
-    "/debug/pilot", "/debug/roof",
+    "/debug/pilot", "/debug/roof", "/debug/health",
 })
 
 
