@@ -195,6 +195,23 @@ class ModelConfig:
     # sigmoid(h Wa), one value a head from the layer's normed input, on
     # each head's attention output before the output projection.
     attn_gate: bool = False
+    # --- generation by diffusion over blocks (0 = autoregressive) ---
+    # A slot's decode step is a PASS over the gen_block positions at
+    # pos .. pos + gen_block - 1, which attend to the cache and to each
+    # other in both directions (prefill is block-causal: position i sees
+    # j when j // gen_block <= i // gen_block). An undecided position's
+    # input is the embedding of mask_token_id; a denoising pass decides
+    # gen_block // denoise_steps of them, the leftmost ("sequential") or
+    # the most confident, and every one above denoise_threshold when at
+    # least that many are ("low_confidence"; None = no threshold); once
+    # all are decided a commit pass writes the block's KV and emits its
+    # tokens (models/slot.block_step). The logits are unshifted: row i
+    # scores position i itself.
+    gen_block: int = 0
+    denoise_steps: int = 1
+    remask: str = "sequential"
+    denoise_threshold: Optional[float] = None
+    mask_token_id: int = 0
 
     def __post_init__(self):
         for name in ("layer_types", "ssm_mults"):  # a list: stored as a tuple
@@ -406,6 +423,25 @@ class ModelConfig:
             )
         assert self.ff_act in ("swiglu", "relu2"), (
             f"unknown ff_act {self.ff_act!r}")
+        if self.gen_block:
+            assert set(self.layer_types) == {OP_ATTN}, (
+                "gen_block (generation by diffusion over blocks) is built "
+                "for full_attention layers only: a conv, Mamba-2 or "
+                "sliding_attention layer's state cannot hold a block that "
+                "is not final yet")
+            assert self.gen_block > 1 and self.denoise_steps >= 1 \
+                and self.gen_block % self.denoise_steps == 0, (
+                    "gen_block must be a multiple of denoise_steps")
+            assert self.remask in ("sequential", "low_confidence"), (
+                f"unknown remask {self.remask!r}")
+            assert 0 <= self.mask_token_id < self.vocab_size, (
+                "mask_token_id must lie in the vocabulary")
+        else:
+            assert (self.denoise_steps == 1 and self.remask == "sequential"
+                    and self.denoise_threshold is None
+                    and self.mask_token_id == 0), (
+                "denoise_steps / remask / denoise_threshold / mask_token_id "
+                "need gen_block")
         assert 0 <= self.expert_first and \
             self.expert_first + self.experts_held <= max(self.n_experts, 0) \
             or not self.n_experts, (
@@ -574,6 +610,29 @@ PRESETS = {
         router_scale=2.5,
         sliding_window=8,
         attn_gate=True,
+    ),
+    # Generation by diffusion over blocks at CPU-test size: attention with
+    # QK-norm and a softmax-routed sparse SwiGLU (8 experts top-2) in
+    # every layer, blocks of 4 positions denoised in 2 passes, untied head.
+    "tiny-sdar": ModelConfig(
+        vocab_size=256,
+        d_model=64,
+        n_layers=2,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=128,
+        max_seq_len=128,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        eos_token_id=1,
+        n_experts=8,
+        n_experts_per_token=2,
+        layer_types=("full_attention",) * 2,
+        d_ff_expert=32,
+        qk_norm=True,
+        gen_block=4,
+        denoise_steps=2,
+        mask_token_id=255,
     ),
     # ~1.1B params: single v5e chip (16 GB HBM) with room for KV cache.
     "bench-1b": ModelConfig(

@@ -690,9 +690,11 @@ def _run_blocks(params, x, cfg, positions, inv_freq, mask,
     return x, None, jnp.mean(aux)
 
 
-def _qkv(h, bp, cfg, positions, inv_freq, tp=None, op=None):
+def _qkv(h, bp, cfg, positions, inv_freq, tp=None, op=None, step=False):
     """`op`: the layer's kind where a stack's attention kinds differ in
     head count and rotary table (cfg.n_window_layers; rope_by_kind).
+    `step`: a decode step whatever S is (cfg.gen_block: a block of
+    positions a slot), fenced as S == 1 is below.
 
     `tp` (models/tp_sharding.TpHints, EngineConfig.tp > 1 only) pins
     the projected heads sharded on 'tp': each device computes the FULL
@@ -713,7 +715,7 @@ def _qkv(h, bp, cfg, positions, inv_freq, tp=None, op=None):
     activations through memory once more, so it keeps its program."""
     B, S, _ = h.shape
     Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
-    flat = jax.lax.optimization_barrier if S == 1 else (lambda t: t)
+    flat = jax.lax.optimization_barrier if S == 1 or step else (lambda t: t)
     with jax.named_scope("attn/qkv"):
         hq = _quantize_act(h) if _w8a8_applies(bp, "wq", cfg) else None
         q = flat(_qdot(h, bp, "wq", cfg, act_q=hq)).reshape(
@@ -1152,6 +1154,14 @@ def _logits(params, x, cfg):
 # ---------------------------------------------------------------------------
 
 
+def _full_mask(cfg: ModelConfig, B: int, S: int) -> jnp.ndarray:
+    """[B, S, S] bool of attention over whole sequences from position 0:
+    causal, or block-causal under cfg.gen_block (position i sees its
+    whole own block and every block before it)."""
+    at = jnp.arange(S) // cfg.gen_block if cfg.gen_block else jnp.arange(S)
+    return jnp.broadcast_to(at[None, :] <= at[:, None], (B, S, S))
+
+
 def forward(
     params: Params,
     tokens: jnp.ndarray,  # [B, S] int32
@@ -1173,6 +1183,8 @@ def forward(
     inv_freq = rope_frequencies(cfg)
     mask = None if cfg.n_window_layers else \
         jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
+    if cfg.gen_block:
+        mask = _full_mask(cfg, B, S)
     if cfg.patterned:
         refuse_patterned(cfg, "sharded or rematerialised forward",
                           act_spec is not None or remat
@@ -2593,11 +2605,147 @@ def _run_patterned_decode(params, x, cfg, positions, inv_freq, pos, cache,
     return x, new_cache, routing
 
 
+def gqa_attention_block(q, ck, cv, k_fresh, v_fresh, mask_lt):
+    """gqa_attention_decode for a BLOCK of query positions a slot
+    (cfg.gen_block): q [B, Bk, H, Dh] against the pre-write slab layer
+    ck / cv [B, 1, T, Hkv * Dh] below each slot's position (`mask_lt`
+    [B, 1, T]) and against the block's own fresh keys and values
+    [B, Bk, Hkv, Dh], every one of which every query of the block sees.
+    The einsums' leg: off a TPU and where the slab is spread over
+    devices; ops/decode_attention.attend is the other."""
+    B, S, H, Dh = q.shape
+    Hkv = k_fresh.shape[2]
+    G = H // Hkv
+    qr = q.reshape(B, S, Hkv, G, Dh)
+    with jax.named_scope("attn/scores"):
+        scores = jnp.einsum(
+            "bskgd,btkd->bkgst", qr, _kv_tokens(ck, Hkv).astype(qr.dtype),
+            preferred_element_type=jnp.float32) / (Dh**0.5)
+        scores = jnp.where(mask_lt[:, None, None, :, :], scores, -1e30)
+        s_fresh = jnp.einsum(
+            "bskgd,bukd->bkgsu", qr, k_fresh.astype(qr.dtype),
+            preferred_element_type=jnp.float32) / (Dh**0.5)
+        w = jax.nn.softmax(
+            jnp.concatenate([scores, s_fresh], axis=-1), axis=-1)
+        T = scores.shape[-1]
+    with jax.named_scope("attn/out"):
+        out = jnp.einsum("bkgst,btkd->bskgd", w[..., :T].astype(qr.dtype),
+                         _kv_tokens(cv, Hkv).astype(qr.dtype))
+        out = out + jnp.einsum("bkgsu,bukd->bskgd",
+                               w[..., T:].astype(qr.dtype),
+                               v_fresh.astype(qr.dtype))
+    return out.reshape(B, S, H * Dh)
+
+
+def block_kv_counts(cfg, cache, live, pos, commit, spread=False):
+    """decode_kv_counts for a pass over a block of positions a slot
+    (cfg.gen_block): KV tokens the attention layers read and hold, as
+    there, then the K rows written, which only the rows that commit
+    write (gen_block each), beside slots x gen_block x layers."""
+    La, B, _, T, _ = cache["k"].shape
+    held = jnp.asarray(La * B * T, jnp.int32)
+    sched = _sparse_decode(cfg, cache, live, pos, spread)
+    read = held if sched is None else La * decode_attention.tokens_read(sched)
+    written = La * cfg.gen_block * jnp.sum(
+        live & commit & (pos < T), dtype=jnp.int32)
+    return jnp.stack([read, held, written,
+                      jnp.asarray(La * B * cfg.gen_block, jnp.int32)])
+
+
+def decode_block(params, tokens, known, pos, cache, cfg, live, commit,
+                 spread: bool = False):
+    """One PASS of a model that generates by diffusion over blocks
+    (cfg.gen_block = Bk; full_attention layers only): the Bk positions
+    pos .. pos + Bk - 1 of every slot, `tokens` [B, Bk] where `known`
+    and the embedding of cfg.mask_token_id elsewhere, against the cache
+    below pos and against each other in both directions. Returns
+    (logits [B * Bk, V] of the block's own positions, the cache with the
+    block's K/V rows written for the slots that `commit` and are `live`
+    and for no other, the routing counters of decode_step). A slot that
+    is not live routes its Bk rows to no expert."""
+    Bk = cfg.gen_block
+    with jax.named_scope("diff/mask_embed"):
+        x = _embed_rows(params, jnp.where(known, tokens, cfg.mask_token_id),
+                        _dtype(cfg))
+    positions = pos[:, None] + jnp.arange(Bk)[None, :]
+    inv_freq = rope_frequencies(cfg)
+    Smax = cache["k"].shape[3]
+    mask_lt = jnp.arange(Smax)[None, None, :] < pos[:, None, None]
+    live2 = jnp.broadcast_to(live[:, None], (live.shape[0], Bk))
+    writes = live & commit & (pos < Smax)
+    sched = _sparse_decode(cfg, cache, live, pos, spread)
+    if sched is not None:
+        sched = decode_attention.committing(sched, writes, Smax)
+    routing = jnp.zeros((routing_width(cfg),), jnp.int32)
+    dt = cache["k"].dtype
+    side = kv_heads_per_row(cfg)
+    held = {key: cache[key] for key in ("k", "v")} if sched is not None \
+        else {}
+    fresh = {"k": [], "v": []}
+    for seg, sp in zip(layer_plan(cfg), params["segments"]):
+        sliced, experts = _split_experts(sp, cfg)
+        na = len(seg.kinds)  # every layer is a full_attention one
+
+        def body(carry, xs, seg=seg, experts=experts, na=na):
+            x, routing, held = carry
+            held = dict(held)
+            rep, lps, cl = xs
+            ks, vs = [], []
+            for i, (lp, ex) in enumerate(zip(lps, experts)):
+                h = rms_norm(x, lp["op_norm"], cfg.rms_norm_eps)
+                q, k, v = _qkv(h, lp, cfg, positions, inv_freq, step=True)
+                with jax.named_scope("attn/full"):
+                    if sched is None:
+                        attn = gqa_attention_block(
+                            q, cl["k"][i], cl["v"][i], k, v, mask_lt)
+                    else:
+                        with jax.named_scope("attn/commit"):
+                            attn, held["k"], held["v"] = \
+                                decode_attention.attend(
+                                    q, k, v, held,
+                                    seg.attn_start + rep * na + i, sched)
+                with jax.named_scope("attn/out"):
+                    x = x + _qdot(attn, lp, "wo", cfg)
+                ks.append(_kv_rows(k, side)[:, :, 0].astype(dt))
+                vs.append(_kv_rows(v, side)[:, :, 0].astype(dt))
+                x, r = _ff_res(x, lp, ex, rep, cfg, live2)
+                routing = routing + r
+            ys = {"k": jnp.stack(ks), "v": jnp.stack(vs)} \
+                if sched is None else {}
+            return (x, routing, held), ys
+
+        riding = {} if sched is not None else \
+            {key: cache[key] for key in ("k", "v")}
+        (x, routing, held), ys = jax.lax.scan(
+            body, (x, routing, held),
+            (jnp.arange(seg.reps), sliced, _segment_cache(riding, seg)))
+        for key, val in ys.items():
+            fresh[key].append(val)
+    new_cache = {**cache, **held}
+    if sched is None:
+        # the committing slots' Bk rows; every other slot's index lies
+        # past the window and its write is dropped (its bytes stay)
+        rows = jnp.arange(pos.shape[0])[:, None, None]
+        layers = jnp.arange(cache["k"].shape[0])[None, :, None]
+        at = jnp.where(writes[:, None], positions, Smax)[:, None, :]
+        with jax.named_scope("attn/commit"):
+            for key in ("k", "v"):
+                # [La, B, Bk, C] -> [B, La, Bk, C]
+                new_cache[key] = cache[key].at[layers, rows, 0, at].set(
+                    jnp.swapaxes(_unsegment(fresh[key]), 0, 1), mode="drop")
+    # one row a (slot, position): [B, Bk, V] would put Bk in the tile's
+    # sublanes and every later view of it [B * Bk, V] would be a copy
+    return _logits(params, x.reshape(1, -1, x.shape[-1]), cfg)[0], \
+        new_cache, routing
+
+
 def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):
     """prefill() for a patterned stack: KV of the attention layers into
     positions [0, S), each conv or Mamba-2 layer's state at the row's
     own prompt length (rows of one admission group are right-padded to
-    the bucket: the state at the bucket's end would be the padding's)."""
+    the bucket: the state at the bucket's end would be the padding's).
+    Under cfg.gen_block the mask is block-causal, `prompt_lens` are the
+    prompts' whole blocks and no logits are returned (None)."""
     B, S = tokens.shape
     x = _scaled(_embed_rows(params, tokens, _dtype(cfg)), cfg.embed_mult)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
@@ -2605,6 +2753,8 @@ def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):
     # makes no S x S mask either (_run_patterned_full)
     mask = None if cfg.n_window_layers else \
         jnp.tril(jnp.ones((S, S), dtype=bool))[None].repeat(B, 0)
+    if cfg.gen_block:
+        mask = _full_mask(cfg, B, S)
     x, fresh, _ = _run_patterned_full(
         params, x, cfg, positions, rope_frequencies(cfg), mask, prompt_lens)
     new_cache = dict(cache)
@@ -2615,6 +2765,8 @@ def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):
                 new_cache[key] = val
             else:
                 new_cache[key] = cache[key].at[:, :, :, :S].set(val)
+    if cfg.gen_block:  # a prefill deposits KV and scores nothing
+        return None, new_cache
     last = jnp.clip(prompt_lens - 1, 0, S - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
     return _logits(params, x_last, cfg)[:, 0], new_cache

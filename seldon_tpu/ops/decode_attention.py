@@ -63,6 +63,15 @@ the walk does not read (position 0, a block's edge) are `loose`: a
 second, nearly always empty loop fetches their tile, sets its first
 row and puts it back. A slot at pos == T writes nothing.
 
+A model that generates by diffusion over blocks (ModelConfig.gen_block)
+asks for Sq > 1 query positions a slot: Sq x H query rows walk the same
+list (the matrix unit's rows are the better filled), the Sq fresh
+columns are folded in unmasked among themselves, and the Sq rows
+pos .. pos + Sq - 1, which lie in one native tile because pos is a
+multiple of Sq and Sq divides the tile, are written for the slots that
+commit and for no other (`committing`). With Sq = 1 every line of the
+program is what it was: the two cases part on the static Sq in Python.
+
 Like the other kernels of seldon_tpu/ops it never chooses interpret mode
 itself: `applies` is False off a TPU, the caller then keeps
 gqa_attention_decode, and tests run `attend` through
@@ -159,6 +168,18 @@ def schedule(active: jnp.ndarray, pos: jnp.ndarray, window: int,
                     jnp.sum(is_loose, dtype=jnp.int32)[None], loose)
 
 
+def committing(sched: Schedule, writes: jnp.ndarray, window: int) -> Schedule:
+    """`sched` for a pass in which only the slots `writes` [B] (live,
+    committing, below the window's end) write their rows: every other
+    slot's `row` is the window's end, which no block holds, and only a
+    writing slot can be loose."""
+    is_loose = writes & (sched.pos % sched.block == 0)
+    return sched._replace(
+        row=jnp.where(writes, sched.row, window).astype(jnp.int32),
+        n_loose=jnp.sum(is_loose, dtype=jnp.int32)[None],
+        loose=jnp.argsort(~is_loose, stable=True).astype(jnp.int32))
+
+
 def tokens_read(sched: Schedule) -> jnp.ndarray:
     """KV tokens one attention layer fetches under `sched` (whole
     blocks: what the copies move)."""
@@ -173,7 +194,7 @@ def tile_rows(dtype) -> int:
 
 def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, row_ref,
             n_loose_ref, loose_ref, q_ref, kf_ref, vf_ref, own_ref, *rest,
-            quantized: bool, block: int, scale: float):
+            quantized: bool, block: int, scale: float, sq: int = 1):
     new, n_hbm = (kf_ref, vf_ref), 2  # the fresh rows as the slab stores them
     if quantized:  # int8 rows, the scales' spread; K, V and their scales
         spread_ref, *new = rest[:3]
@@ -206,9 +227,17 @@ def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, row_ref,
                 for i, dst in enumerate((k_out, v_out))]
 
     def stage(i, b, held, at):
-        """wbuf[i] <- `held` [R, C] with the fresh row at row `at`."""
-        here = jax.lax.broadcasted_iota(jnp.int32, held.shape, 0) == at
-        wbuf[i] = jnp.where(here, new[i][b].astype(held.dtype), held)
+        """wbuf[i] <- `held` [R, C] with the fresh row at row `at` (the
+        sq fresh rows from row `at` on)."""
+        rows = jax.lax.broadcasted_iota(jnp.int32, held.shape, 0)
+        if sq == 1:
+            here = rows == at
+            wbuf[i] = jnp.where(here, new[i][b].astype(held.dtype), held)
+            return
+        fresh = new[i][b].astype(held.dtype)  # [sq, C]
+        for u in range(sq):
+            held = jnp.where(rows == at + u, fresh[u:u + 1], held)
+        wbuf[i] = held
 
     @pl.when(n > 0)
     def _first():
@@ -265,6 +294,25 @@ def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, row_ref,
         def _slot_ends():
             # the fresh token's exact column, folded in as one more block
             f32 = jnp.float32
+            if sq > 1:
+                # the block's sq fresh columns, each seen by every query
+                qf, kf = qbd[...].astype(f32), kf_ref[b].astype(f32)
+                s_f = [jnp.sum(qf * kf[u:u + 1], axis=-1, keepdims=True)
+                       * scale for u in range(sq)]  # sq x [sq * H, 1]
+                m_t = functools.reduce(jnp.maximum, s_f, m_scr[...])
+                alpha = jnp.exp(m_scr[...] - m_t)
+                p_f = [jnp.exp(s_u - m_t) for s_u in s_f]
+                inv = 1.0 / (l_scr[...] * alpha + sum(p_f))
+                own = jnp.zeros((H, LANES), f32)
+                for t in range(tiles):
+                    lanes = slice(t * LANES, (t + 1) * LANES)
+                    vf = vf_ref[b][:, lanes].astype(f32)  # [sq, 128]
+                    own = own + own_ref[:, lanes] * (
+                        acc_scr[:, lanes] * alpha
+                        + sum(p_u * vf[u:u + 1]
+                              for u, p_u in enumerate(p_f)))
+                out_ref[b] = (own * inv).astype(out_ref.dtype)
+                return
             s_f = jnp.sum(qbd[...].astype(f32) * kf_ref[b].astype(f32),
                           axis=-1, keepdims=True) * scale  # [H, 1]
             m_t = jnp.maximum(m_scr[...], s_f)
@@ -333,38 +381,48 @@ def _kernel(layer_ref, n_ref, slot_ref, blk_ref, pos_ref, row_ref,
 
 
 def attend(
-    q: jnp.ndarray,  # [B, 1, H, Dh]
-    k_fresh: jnp.ndarray,  # [B, 1, Hkv, Dh] (exact, this token)
-    v_fresh: jnp.ndarray,  # [B, 1, Hkv, Dh]
+    q: jnp.ndarray,  # [B, Sq, H, Dh]
+    k_fresh: jnp.ndarray,  # [B, Sq, Hkv, Dh] (exact, this token)
+    v_fresh: jnp.ndarray,  # [B, Sq, Hkv, Dh]
     cache: Dict[str, jnp.ndarray],  # the WHOLE slab, PRE-write
     layer: jnp.ndarray,  # int32 scalar: the attention layer, of La
     sched: Schedule,
     stored: Optional[Dict[str, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """gqa_attention_decode over layer `layer` of the slab for the rows
-    `sched` was made of, [B, 1, H * Dh] in q's dtype, and K and V with
+    `sched` was made of, [B, Sq, H * Dh] in q's dtype, and K and V with
     row `sched.row` of that layer written for every live slot (in place:
     hand them on, the arrays handed in are spent).
 
     cache {"k", "v"[, "k_scale", "v_scale"]}: [La, B, 1, T, Hkv * Dh]
     (scales [La, B, Hkv, T]). `stored` {"k", "v"}: [B, Hkv * Dh], the
     fresh rows as an int8 slab stores them; any other slab takes the
-    fresh column cast to its dtype."""
-    B, _, H, Dh = q.shape
+    fresh column cast to its dtype.
+
+    Sq > 1 (a bf16 slab; transformer.gqa_attention_block is the same
+    over the einsums): Sq query positions a slot from `sched.pos` on,
+    their fresh columns seen by each of them, rows `sched.row` ..
+    + Sq - 1 written where `committing` left a row to write."""
+    B, Sq, H, Dh = q.shape
     C = cache["k"].shape[4]
     Hkv, block = C // Dh, sched.block
     G = H // Hkv
     f32 = jnp.float32
     slab = [cache["k"], cache["v"]]
     quantized = "k_scale" in cache
-    own = (jnp.arange(C) // Dh)[None, :] == (jnp.arange(H) // G)[:, None]
-    args = [jnp.tile(q[:, 0], (1, 1, LANES // Dh)),
-            k_fresh.astype(q.dtype).reshape(B, 1, C),
-            v_fresh.astype(q.dtype).reshape(B, 1, C), own.astype(f32)]
+    # query rows are (position, head): row n reads KV head (n % H) // G
+    lane_head = (jnp.arange(C) // Dh)[None, :]
+    head = jnp.arange(H) if Sq == 1 else jnp.arange(Sq * H) % H
+    own = lane_head == (head // G)[:, None]
+    q_rows = q[:, 0] if Sq == 1 else q.reshape(B, Sq * H, Dh)
+    args = [jnp.tile(q_rows, (1, 1, LANES // Dh)),
+            k_fresh.astype(q.dtype).reshape(B, Sq, C),
+            v_fresh.astype(q.dtype).reshape(B, Sq, C), own.astype(f32)]
     whole = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
-    in_specs = [whole((B, H, LANES)), whole((B, 1, C)), whole((B, 1, C)),
-                whole((H, C))]
+    in_specs = [whole((B, Sq * H, LANES)), whole((B, Sq, C)),
+                whole((B, Sq, C)), whole((Sq * H, C))]
     if quantized:
+        assert Sq == 1, "a block of query positions reads a bf16 slab"
         # [H, Hkv] 0/1: a query head's row of scales is its KV head's
         spread = jnp.arange(H)[:, None] // G == jnp.arange(Hkv)[None, :]
         args += [spread.astype(cache["k_scale"].dtype),
@@ -383,27 +441,29 @@ def attend(
     scratch = [pltpu.VMEM((2, block, C) if a.ndim == 5 else (2, Hkv, block),
                           a.dtype) for a in slab]
     scratch += [pltpu.SemaphoreType.DMA((len(slab), 2)),
-                pltpu.VMEM((H, C), q.dtype),
-                pltpu.VMEM((H, 1), f32), pltpu.VMEM((H, 1), f32),
-                pltpu.VMEM((H, C), f32),
+                pltpu.VMEM((Sq * H, C), q.dtype),
+                pltpu.VMEM((Sq * H, 1), f32), pltpu.VMEM((Sq * H, 1), f32),
+                pltpu.VMEM((Sq * H, C), f32),
                 # K's and V's tile on its way back
                 pltpu.VMEM((2, tile_rows(slab[0].dtype), C), slab[0].dtype),
                 pltpu.SemaphoreType.DMA((2,))]
     scalars = (jnp.reshape(layer, (1,)).astype(jnp.int32), sched.n_items,
                sched.slot, sched.blk, sched.pos, sched.row, sched.n_loose,
                sched.loose)
+    static = dict(quantized=quantized, block=block, scale=Dh ** -0.5)
+    if Sq > 1:
+        static["sq"] = Sq
     with jax.named_scope("attn/scores"):
         out, k, v = pl.pallas_call(
-            functools.partial(_kernel, quantized=quantized, block=block,
-                              scale=Dh ** -0.5),
+            functools.partial(_kernel, **static),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(scalars), grid=(1,),
                 in_specs=in_specs,
-                out_specs=[whole((B, H, LANES)),
+                out_specs=[whole((B, Sq * H, LANES)),
                            pl.BlockSpec(memory_space=pl.ANY),
                            pl.BlockSpec(memory_space=pl.ANY)],
                 scratch_shapes=scratch),
-            out_shape=[jax.ShapeDtypeStruct((B, H, LANES), q.dtype),
+            out_shape=[jax.ShapeDtypeStruct((B, Sq * H, LANES), q.dtype),
                        jax.ShapeDtypeStruct(slab[0].shape, slab[0].dtype),
                        jax.ShapeDtypeStruct(slab[1].shape, slab[1].dtype)],
             # K and V, past the prefetched scalars and the VMEM operands
@@ -416,9 +476,18 @@ def attend(
         )(*scalars, *args, *slab)
     with jax.named_scope("attn/out"):
         # a row's own head is the one segment of its tile that is not zero
-        out = out.reshape(B, H, LANES // Dh, Dh).sum(axis=2)
+        out = out.reshape(B, Sq * H, LANES // Dh, Dh).sum(axis=2)
         # rows the kernel never wrote hold whatever the buffer held: with
         # no past, attention is the fresh token's value
-        alone = jnp.repeat(v_fresh[:, 0].astype(q.dtype), G, axis=1)
+        if Sq == 1:
+            alone = jnp.repeat(v_fresh[:, 0].astype(q.dtype), G, axis=1)
+        else:  # ... the block's positions over one another alone
+            qr = q.reshape(B, Sq, Hkv, G, Dh)
+            w = jax.nn.softmax(jnp.einsum(
+                "bskgd,bukd->bkgsu", qr, k_fresh.astype(q.dtype),
+                preferred_element_type=f32) * Dh ** -0.5, axis=-1)
+            alone = jnp.einsum(
+                "bkgsu,bukd->bskgd", w.astype(q.dtype),
+                v_fresh.astype(q.dtype)).reshape(B, Sq * H, Dh)
         out = jnp.where(sched.has_past[:, None, None], out, alone)
-    return out.reshape(B, 1, H * Dh), k, v
+    return out.reshape(B, Sq, H * Dh), k, v

@@ -397,6 +397,9 @@ class _Request:
     # latest token burst was emitted (drives the ITL histogram).
     first_dispatch_at: Optional[float] = None
     last_burst_at: Optional[float] = None
+    # Passes dispatched for this request (ModelConfig.gen_block: the
+    # tokens in flight follow from them, slot_rules.tokens_after).
+    passes: int = 0
     # TTFT from inside: the five instants received_at <= submitted_at <=
     # first_dispatch_at <= admit_ready_at <= first_token_at cut a
     # request's first-token time into executor_wait, queue_wait,
@@ -633,12 +636,22 @@ MOE_COUNTERS = ("moe_sparse_layer_steps", "moe_experts_touched",
 SHARE_COUNTERS = ("moe_assignments_held", "ssm_layer_steps")
 CHUNK_COUNTERS = (SAMPLER_COUNTERS + KV_COUNTERS + MOE_COUNTERS
                   + SHARE_COUNTERS)
+# ... of a model that generates by diffusion over blocks
+# (ModelConfig.gen_block; 0 elsewhere), right after the sampler's: a
+# decode step is a pass, and these are the (slot, pass) pairs that ran,
+# those of them that committed a block, and the tokens those emitted
+# (slot_rules.block_step). tokens / slot passes is what a slot's pass
+# yields: gen_block / (denoise_steps + 1) less tails and cuts.
+DIFF_COUNTERS = ("diff_slot_passes", "diff_commit_passes", "diff_tokens_out")
 
 
 def chunk_counter_names(cfg) -> Tuple[str, ...]:
     """The names of a decode chunk's counts (_chunk_impl's fifth value)
     for this model, in their order."""
-    names = SAMPLER_COUNTERS + KV_COUNTERS
+    names = SAMPLER_COUNTERS
+    if cfg.gen_block:
+        names += DIFF_COUNTERS
+    names += KV_COUNTERS
     if cfg.n_window_layers:
         names += WINDOW_COUNTERS
     if InferenceEngine._counts_routing(cfg):
@@ -699,6 +712,11 @@ class EngineStats:
         self.attn_window_tokens_unwindowed = 0  # graftlint: guarded-by(lock) via(stats)
         self.attn_full_tokens_read = 0  # graftlint: guarded-by(lock) via(stats)
         self.attn_full_tokens_held = 0  # graftlint: guarded-by(lock) via(stats)
+        # Passes of a model that generates by diffusion over blocks
+        # (DIFF_COUNTERS; 0 elsewhere).
+        self.diff_slot_passes = 0  # graftlint: guarded-by(lock) via(stats)
+        self.diff_commit_passes = 0  # graftlint: guarded-by(lock) via(stats)
+        self.diff_tokens_out = 0  # graftlint: guarded-by(lock) via(stats)
         # Prompt tokens admitted, by the bucket their admission group was
         # padded to ({bucket: tokens}; the cold dense admission).
         self.attn_prefill_tokens: Dict[int, int] = {}  # graftlint: guarded-by(lock) via(stats)
@@ -921,7 +939,8 @@ class EngineStats:
                 "decode_dispatches": self.decode_dispatches,
                 "decode_steps": self.decode_steps,
                 **{name: getattr(self, name)
-                   for name in CHUNK_COUNTERS + WINDOW_COUNTERS},
+                   for name in CHUNK_COUNTERS + WINDOW_COUNTERS
+                   + DIFF_COUNTERS},
                 "attn_prefill_tokens": dict(self.attn_prefill_tokens),
                 "prefix_hits": self.prefix_hits,
                 "prefix_tokens_saved": self.prefix_tokens_saved,
@@ -1051,6 +1070,7 @@ class InferenceEngine:
         self._buckets = tuple(
             b for b in self.ecfg.prompt_buckets if b <= Smax
         ) or (Smax,)
+        self._refuse_gen_block_shapes()
 
         # Paged KV cache (opt-in, single-process only — the block
         # allocator and tables are host-side state, and multi-process
@@ -1556,6 +1576,11 @@ class InferenceEngine:
         if self.cfg.n_window_layers:
             state = ("sliding_attention layers' ring of keys and values "
                      "(the window kind of KV)")
+        if self.cfg.gen_block:
+            # generation by diffusion over blocks: every opt-in path
+            # moves, shares, replays or verifies KV a token at a time
+            state = (f"block in hand (gen_block {self.cfg.gen_block} "
+                     "positions, final only at its commit pass)")
         both = f", both in each {OP_ATTN_MAMBA} layer" \
             if OP_ATTN_MAMBA in self.cfg.layer_types else ""
         asked = [
@@ -1581,6 +1606,20 @@ class InferenceEngine:
                 "only; not with " + "; ".join(asked)
             )
 
+    def _refuse_gen_block_shapes(self) -> None:
+        """A model that generates by diffusion over blocks
+        (cfg.gen_block) prefills whole blocks and commits whole blocks:
+        a window or a prompt bucket that cuts one is refused. (The
+        opt-in paths refuse it as they refuse any patterned stack, by
+        name: _refuse_unpatterned_paths.)"""
+        Bk = self.cfg.gen_block
+        cut = [n for n in (self.ecfg.max_seq_len,) + self._buckets
+               if Bk and n % Bk]
+        if cut:
+            raise ValueError(
+                f"gen_block {Bk} needs max_seq_len and every prompt bucket "
+                f"to be a multiple of it; {cut} are not")
+
     def _fresh_state(self) -> Dict[str, Any]:
         B, Smax = self.ecfg.max_slots, self.ecfg.max_seq_len
         if self._paged:
@@ -1589,7 +1628,7 @@ class InferenceEngine:
             )
         else:
             cache = transformer.init_cache(self.cfg, B, Smax)
-        state = slot_rules.fresh(cache, B)
+        state = slot_rules.fresh(cache, B, self.cfg.gen_block)
         if self._tp is not None:
             # Commit the state onto the mesh (KV heads on 'tp', per-slot
             # scalars replicated) so the FIRST dispatch already sees the
@@ -1638,6 +1677,21 @@ class InferenceEngine:
         and arming rules). One dispatch, no host sync."""
         G, Sb = toks.shape
         sub = transformer.init_cache(cfg, G, Sb)
+        if cfg.gen_block:
+            # Generation by diffusion over blocks: the prompt's whole
+            # blocks deposit their KV and nothing is scored; its tail is
+            # the decided part of the first block in hand, and the slot
+            # has no first token (slot_rules.first_block).
+            whole = plens - plens % cfg.gen_block
+            _, sub = transformer.prefill(params, toks, whole, sub, cfg)
+            none, done = jnp.zeros((G,), jnp.int32), max_news <= 0
+            new_state = slot_rules.arm(
+                state, slots, cache=transformer.cache_scatter_slots(
+                    cfg, state["cache"], sub, slots, Sb),
+                first=none, done=done, pos=whole, temps=temps,
+                top_ks=top_ks, top_ps=top_ps, seeds=seeds, max_news=max_news,
+                block=slot_rules.first_block(toks, plens, cfg.gen_block))
+            return (new_state,) + InferenceEngine._replicate(mesh, none, done)
         if ring_mesh is not None:
             sp = dict(ring_mesh.shape).get("sp", 1)
             if Sb % sp != 0:  # static per-bucket decision
@@ -1806,7 +1860,10 @@ class InferenceEngine:
     def _chunk_impl(params, state, *, cfg, n_steps, mesh=None, tp=None):
         """`n_steps` decode iterations over every slot in one lax.scan
         (slot_rules.decode_chunk). Returns (state, toks [K,B], valid [K,B],
-        active [B], counts): counts int32 over the chunk, in
+        active [B], counts), toks and valid [K,B,Bk] where an iteration is
+        a pass over a block of Bk positions a slot (cfg.gen_block: its
+        three counters come right after the sampler's); counts int32 over
+        the chunk, in
         CHUNK_COUNTERS' order: steps, steps that drew, steps that masked;
         KV tokens the attention layers read and KV tokens the slab holds
         for them, K rows they wrote and slots x layers; a routed model
@@ -1832,8 +1889,21 @@ class InferenceEngine:
             kv = transformer.decode_kv_counts(cfg, cache, live, pos, spread)
             return logits, cache_, jnp.concatenate([kv, *routing])
 
+        def pass_model(carry):
+            """step_model for cfg.gen_block: the block in hand of every
+            slot; the slots whose block is decided commit its KV."""
+            live, pos, cache = carry["active"], carry["pos"], carry["cache"]
+            commit = slot_rules.committing(carry)
+            logits, cache_, routing = transformer.decode_block(
+                params, carry["blk_tok"], carry["blk_known"], pos, cache,
+                cfg, live, commit, spread)
+            kv = transformer.block_kv_counts(cfg, cache, live, pos, commit,
+                                             spread)
+            return logits, cache_, jnp.concatenate([kv, routing])
+
         state, toks, valid, counts = slot_rules.decode_chunk(
-            step_model, state, n_steps, Smax, cfg)
+            pass_model if cfg.gen_block else step_model, state, n_steps,
+            Smax, cfg)
         if tp is not None:
             state = tp.constrain_state(state)
         toks, valid, active, counts = InferenceEngine._replicate(
@@ -2610,7 +2680,8 @@ class InferenceEngine:
                 jnp.ones((G,), jnp.float32),
                 jnp.zeros((G,), jnp.int32),
                 jnp.ones((G,), jnp.float32),
-                jnp.ones((G,), jnp.int32),
+                # max_new: a gen_block row has no first token to end on
+                jnp.full((G,), 0 if self.cfg.gen_block else 1, jnp.int32),
                 jnp.arange(G, dtype=jnp.int32),
             )
             self._state = out[0]
@@ -3085,7 +3156,9 @@ class InferenceEngine:
                     + sum(len(r.tokens) for r in group)
         for req in group:
             req.slot = self._free.pop()
-            req.expected = 1  # the admission samples the first token
+            # the admission samples the first token; under gen_block it
+            # deposits KV and the first commit pass emits the first
+            req.expected, req.passes = (0 if self.cfg.gen_block else 1), 0
         toks = np.full((Gp, Sb), self.cfg.pad_token_id, np.int32)
         plens = np.empty((Gp,), np.int32)
         pref_lens = np.empty((Gp,), np.int32)
@@ -4018,6 +4091,16 @@ class InferenceEngine:
                 if req.finished:  # already failed by an error path
                     continue
                 slot = req.slot
+                if self.cfg.gen_block:
+                    # no token yet: the first commit pass brings the
+                    # first, and _process_chunk stamps it
+                    n_armed -= 1
+                    req.admit_ready_at = admit_ready
+                    if bool(done_h[i]):  # no budget at all
+                        self._complete(req)
+                    elif self._slots[slot] is req:
+                        self._active_host[slot] = True
+                    continue
                 first_tok = int(first_h[i])
                 req.last_burst_at = now
                 req.n_generated = 1
@@ -4085,10 +4168,14 @@ class InferenceEngine:
         `roster` is the slot->request snapshot taken when THIS chunk was
         dispatched (the live slot table may have moved on: optimistic
         recycling hands freed slots to new requests before old results
-        are read). `valid` is a True-prefix per column (rows stop and
-        stay stopped within a chunk), so the first n_valid rows are the
-        emitted tokens."""
-        n_valid = valid_h.sum(axis=0)
+        are read). `valid` marks the emitted tokens: an autoregressive
+        row's column is a True-prefix (rows stop and stay stopped within
+        a chunk), so its first n_valid rows; under ModelConfig.gen_block
+        both arrays are [K, B, Bk] and a slot holds tokens at its commit
+        passes only, the first of which is its request's first token
+        (`_first_burst`)."""
+        blocks = toks_h.ndim == 3
+        n_valid = valid_h.sum(axis=(0, 2) if blocks else 0)
         total = 0
         now = time.perf_counter()
         gaps_ms: List[float] = []
@@ -4097,10 +4184,14 @@ class InferenceEngine:
                 continue
             n = int(n_valid[slot])
             if n:
-                burst = toks_h[:n, slot].tolist()
+                burst = (toks_h[:, slot][valid_h[:, slot]] if blocks
+                         else toks_h[:n, slot]).tolist()
                 if self._spec or self._heal is not None:
                     req.gen_hist.extend(burst)
-                req.out.put({"tokens": burst})
+                if blocks and req.first_token_at is None:
+                    self._first_burst(req, burst, now)
+                else:
+                    req.out.put({"tokens": burst})
                 req.n_generated += n
                 total += n
                 if self._heal is not None:
@@ -4117,6 +4208,25 @@ class InferenceEngine:
                 self.stats.tokens_out += total
                 for g in gaps_ms:
                     self.stats.record_itl_locked(g)
+
+    def _first_burst(self, req: _Request, burst: List[int],  # graftlint: holds(_book)
+                     now: float) -> None:
+        """A request's first tokens where they come with a decode chunk
+        (ModelConfig.gen_block: the first commit pass), stamped and
+        counted as _process_admits does an admission's first token. Its
+        first_token_held_ms runs from the admission's arrival on the
+        host through the denoising passes to this chunk's end."""
+        req.first_token_at = now
+        ttft_ms = 1000.0 * (now - req.submitted_at)
+        req.out.put({"tokens": burst, "ttft_ms": ttft_ms,
+                     "timings": self._timings(req)})
+        with self.stats.lock:
+            self.stats.ttft_sum += ttft_ms / 1000.0
+            self.stats.ttft_count += 1
+            if req.admit_ready_at is not None:
+                self.stats.device_wait_sum += \
+                    req.admit_ready_at - req.first_dispatch_at
+                self.stats.first_token_held_sum += now - req.admit_ready_at
 
     def _live_wave_rids(self) -> List[int]:  # graftlint: holds(_book)
         """The rids riding a whole-batch (decode/verify) wave —
@@ -4932,7 +5042,8 @@ class InferenceEngine:
         compiles it: one program, on the serving path, which is why
         the choice is not made again as rows come and go (a step at
         eight live rows is three times one at one row, and a rung that
-        followed it would compile under load), and why it is made in
+        followed it would compile under load; under cfg.gen_block the
+        rung is whole blocks' passes, slot_rules.whole_blocks), and why it is made in
         the engine's first waves or not at all: an estimator still
         short of samples after four times as many waves has seen the
         device set the pace of fewer than one in four, the host is
@@ -4947,6 +5058,12 @@ class InferenceEngine:
             if self._wave_seq < 4 * est.steps.maxlen:
                 return
             n = cap
+        if self.cfg.gen_block:
+            # a step is a pass and tokens come at commits: whole blocks.
+            # (The rule alone sat on its line here: 2 x 4.7 ms against
+            # 4.5 turns of 1.8-2.1 ms, and a run's TTFT read 59 or 86 ms
+            # by which side it fell: PERF.md section 6, PR 52.)
+            n = slot_rules.whole_blocks(n, cap, self.cfg)
         self._rung_sized = True
         logger.info(
             "low rung sized at wave %d: %d steps a chunk (%d step and %d "
@@ -4979,7 +5096,15 @@ class InferenceEngine:
         for slot, req in enumerate(roster):
             if req is None or req.finished:
                 continue
-            req.expected += max(1, chunk_len)
+            if self.cfg.gen_block:
+                # what a row still running will have emitted: exact, or
+                # less where a threshold decides faster
+                req.passes += chunk_len
+                req.expected = slot_rules.tokens_after(
+                    req.passes, len(req.tokens) % self.cfg.gen_block,
+                    self.cfg)
+            else:
+                req.expected += max(1, chunk_len)
             if req.expected >= req.params.max_new_tokens:
                 if self._slots[slot] is req:
                     self._slots[slot] = None
@@ -5304,6 +5429,7 @@ class InferenceEngine:
                     chunk_steps=work.chunk_handles[0].shape[0]
                     if work.chunk_handles else 0,
                     low_rung=self._chunk_sizes[0],
+                    gen_block=self.cfg.gen_block,
                     **self._depth_est.gauges(),
                 )
         return work

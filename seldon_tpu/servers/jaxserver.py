@@ -41,6 +41,7 @@ from seldon_tpu.servers.engine import (
     MOE_COUNTERS,
     SHARE_COUNTERS,
     WINDOW_COUNTERS,
+    DIFF_COUNTERS,
     EngineConfig,
     InferenceEngine,
     access_log,
@@ -1028,7 +1029,7 @@ class JAXServer(SeldonComponent):
             *({"type": "GAUGE", "key": "jaxserver_" + name,
                "value": float(s[name])}
               for name in KV_COUNTERS + WINDOW_COUNTERS + MOE_COUNTERS
-              + SHARE_COUNTERS),
+              + SHARE_COUNTERS + DIFF_COUNTERS),
             # Prompt tokens admitted, by the bucket the group was padded to.
             *({"type": "GAUGE", "key": "jaxserver_attn_prefill_tokens",
                "value": float(n), "tags": {"bucket": str(b)}}

@@ -402,3 +402,77 @@ def test_a_slab_spread_over_devices_keeps_the_einsums(monkeypatch):
     assert transformer.decode_kv_counts(
         cfg, cache, live, pos.at[0].set(cfg.max_seq_len)).tolist()[2] \
         == cfg.n_layers * 2
+
+
+# -- a block of query positions a slot (ModelConfig.gen_block) ----------------
+
+SQ = 4
+# slot -> (live, commits, position): no past (attention among the fresh
+# columns alone; a loose write), a pass that only denoises, a block's
+# last tile, a block's first row (loose), a dead slot that would commit,
+# the window's last block, a denoising slot inside a tile, a tile's
+# second group of four rows
+BLOCK_SLOTS = [(True, True, 0), (True, False, 4), (True, True, 252),
+               (True, True, 256), (False, True, 260), (True, True, T - SQ),
+               (True, False, 300), (True, True, 68)]
+
+
+@functools.lru_cache(maxsize=None)
+def _block_case():
+    Hkv, Dh, G = 4, 128, 8  # sdar-30b-a3b-chat's heads
+    H, C = Hkv * G, Hkv * Dh
+    ks = jax.random.split(jax.random.key(11), 5)
+    bf16 = jnp.bfloat16
+    q = jax.random.normal(ks[0], (B, SQ, H, Dh)).astype(bf16)
+    kf = jax.random.normal(ks[1], (B, SQ, Hkv, Dh)).astype(bf16)
+    vf = (0.25 * jax.random.normal(ks[2], (B, SQ, Hkv, Dh))).astype(bf16)
+    cache = {"k": jax.random.normal(ks[3], (LAYERS, B, 1, T, C), bf16),
+             "v": 0.25 * jax.random.normal(ks[4], (LAYERS, B, 1, T, C), bf16)}
+    live, commit, pos = (jnp.asarray(x) for x in zip(*BLOCK_SLOTS))
+    with mock.patch.object(da, "ITEM_BYTES", ITEM_BYTES):
+        block = da.block_size(cache["k"].shape, Dh, 2)
+    assert block == 256
+    with pallas_interpret():
+        sched = da.committing(da.schedule(live, pos, T, block),
+                              live & commit, T)
+        out, k, v = jax.jit(lambda: da.attend(
+            q, kf, vf, dict(cache), jnp.int32(1), sched))()
+    mask_lt = jnp.arange(T)[None, None, :] < pos[:, None, None]
+    want = transformer.gqa_attention_block(
+        q, cache["k"][1], cache["v"][1], kf, vf, mask_lt)
+    return (np.asarray(out, np.float32), np.asarray(want, np.float32),
+            {"k": (cache["k"], k, kf), "v": (cache["v"], v, vf)})
+
+
+def test_a_block_of_queries_matches_the_einsums():
+    """Four query positions a slot: the fresh columns see each other (the
+    einsums' leg masks nothing among them), a slot with no past attends
+    among them alone."""
+    got, want, _ = _block_case()
+    live = np.array([s[0] for s in BLOCK_SLOTS])
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], atol=ATTEND_ATOL, rtol=0)
+
+
+def test_a_block_of_queries_sees_its_own_fresh_columns_unmasked():
+    """The first position of a block with no past: under a causal mask
+    among the fresh columns it would be its own value alone."""
+    got, _, slabs = _block_case()
+    _, _, vf = slabs["v"]
+    alone = np.asarray(jnp.repeat(vf[0, 0], 8, axis=0), np.float32).reshape(-1)
+    assert np.abs(got[0, 0] - alone).max() > 10 * ATTEND_ATOL
+
+
+@pytest.mark.parametrize("key", ["k", "v"])
+def test_only_the_committing_live_slots_rows_are_written(key):
+    """The four rows pos .. pos + 3 of the slots that are live and
+    commit, in the layer asked for; every other byte of the slab, the
+    dead slot's and the denoising slots' among them, is what it was."""
+    _, _, slabs = _block_case()
+    before, after, fresh = slabs[key]
+    want = np.array(before.astype(jnp.float32))
+    for b, (live, commit, p) in enumerate(BLOCK_SLOTS):
+        if live and commit:
+            want[1, b, 0, p:p + SQ] = np.asarray(
+                fresh[b].astype(jnp.float32)).reshape(SQ, -1)
+    np.testing.assert_array_equal(np.asarray(after.astype(jnp.float32)), want)

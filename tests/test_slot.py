@@ -296,3 +296,59 @@ def test_live_stream_is_live(path):
     got = _stream(cfg, GREEDY, **PATHS[path])
     assert len(got) == LIVE_TOKENS
     assert cfg.eos_token_id not in got
+
+
+# -- (f) which positions a denoising pass decides (cfg.gen_block) ------------
+
+_T, _F = True, False
+# (rule, k, threshold, known, confidences) -> decided this pass
+TRANSFER = {
+    "sequential takes the leftmost k": (
+        "sequential", 2, None, [_F, _F, _F, _F], [.1, .9, .8, .7], [0, 1]),
+    "sequential skips the decided": (
+        "sequential", 2, None, [_T, _F, _T, _F], [.1, .9, .8, .7], [1, 3]),
+    "sequential takes what is left": (
+        "sequential", 2, None, [_T, _T, _T, _F], [.1, .9, .8, .7], [3]),
+    "sequential ignores a threshold": (
+        "sequential", 1, 0.5, [_F, _F, _F, _F], [.1, .9, .8, .7], [0]),
+    "confidence takes the highest k": (
+        "low_confidence", 2, None, [_F, _F, _F, _F], [.1, .9, .8, .7], [1, 2]),
+    "confidence never takes a decided one": (
+        "low_confidence", 2, None, [_F, _T, _F, _F], [.1, .9, .8, .7], [2, 3]),
+    "ties go to the left": (
+        "low_confidence", 2, None, [_F, _F, _F, _F], [.5, .5, .5, .5], [0, 1]),
+    "ties go to the left past a decided one": (
+        "low_confidence", 1, None, [_T, _F, _F, _F], [.9, .5, .5, .5], [1]),
+    "k of one": (
+        "low_confidence", 1, None, [_F, _F, _F, _F], [.1, .2, .8, .7], [2]),
+    "a threshold that k pass takes all above it": (
+        "low_confidence", 2, 0.6, [_F, _F, _F, _F], [.1, .9, .8, .7], [1, 2, 3]),
+    "a threshold that fewer than k pass changes nothing": (
+        "low_confidence", 2, 0.85, [_F, _F, _F, _F], [.1, .9, .8, .7], [1, 2]),
+    "a threshold counts the undecided only": (
+        "low_confidence", 2, 0.6, [_F, _T, _F, _F], [.1, .9, .8, .5], [2, 3]),
+    "nothing left": (
+        "low_confidence", 2, 0.1, [_T, _T, _T, _T], [.1, .9, .8, .7], []),
+}
+
+
+@pytest.mark.parametrize("case", list(TRANSFER))
+def test_transfer_decides_by_rule_k_threshold_and_ties(case):
+    rule, k, threshold, known, conf, want = TRANSFER[case]
+    # two rows: the case, and a row with nothing decided (rows are
+    # independent)
+    take = slot.transfer(
+        jnp.asarray([known, [False] * 4]), jnp.asarray([conf, conf]),
+        k, rule, threshold)
+    assert np.flatnonzero(np.asarray(take[0])).tolist() == want
+    assert not (np.asarray(take[0]) & np.asarray(known)).any()
+
+
+def test_first_block_is_the_prompts_tail_then_undecided():
+    toks = jnp.asarray([[5, 6, 7, 8, 9, 10, 0, 0], [5, 6, 7, 8, 0, 0, 0, 0],
+                        [5, 6, 7, 0, 0, 0, 0, 0]])
+    blk = slot.first_block(toks, jnp.asarray([6, 4, 3]), 4)
+    assert blk["blk_tok"].tolist() == [[9, 10, 0, 0], [0, 0, 0, 0], [5, 6, 7, 0]]
+    assert blk["blk_known"].tolist() == [[_T, _T, _F, _F], [_F] * 4,
+                                         [_T, _T, _T, _F]]
+    assert blk["blk_skip"].tolist() == [2, 0, 3]

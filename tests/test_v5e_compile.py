@@ -80,7 +80,7 @@ def _compiled_chunk(cfg, one_chip, slots=SLOTS, window=WINDOW,
     params = shapes(jax.eval_shape(lambda: init(cfg, jax.random.key(0))))
     state = shapes(jax.eval_shape(
         lambda: slot.fresh(transformer.init_cache(cfg, slots, window),
-                           slots)))
+                           slots, cfg.gen_block)))
     chunk = jax.jit(
         functools.partial(InferenceEngine._chunk_impl, cfg=cfg,
                           n_steps=STEPS),
@@ -224,6 +224,36 @@ def test_decode_chunk_reads_the_slab_through_the_kernel(
     assert [typ for _, _, typ, _ in big_instructions(hlo, scores)
             if typ.startswith("f32[")
             and typ.split("]")[0].endswith(",%d" % WINDOW)] == []
+
+
+def test_a_pass_over_blocks_reads_and_commits_through_the_kernel(
+        published_chunk):
+    """sdar-30b-a3b-chat as its file states it, 64 slots x 1024: a pass's
+    attention is the decode-attention kernel at four query positions a
+    slot (128 query rows), handed K and V whole and aliased to its
+    results, so the commit's rows are written where they lie; nothing as
+    large as a layer of the slab is copied, and no logits [slots, 4,
+    vocabulary] with the block in the tile's sublanes are made beside
+    the flat ones the sampler reads."""
+    cfg, hlo, state = published_chunk("sdar-30b-a3b-chat")
+    assert cfg.gen_block == 4 and state["blk_tok"].shape == (64, 4)
+    calls = kernel_calls(hlo)
+    assert len(calls) == 1, [n for n, _ in calls]
+    name, comp = calls[0]
+    dims = ",".join(map(str, state["cache"]["k"].shape))
+    call = re.search(
+        r"%" + re.escape(name) + r" = \(bf16\[64,128,128\]\S* (\w+\[[\d,]*\]).*"
+        r"output_to_operand_aliasing=\{\{1\}: \(\d+, \{\}\), "
+        r"\{2\}: \(\d+, \{\}\)\}", comp)
+    assert call and call.group(1) == "bf16[%s]" % dims
+    assert cache_copies(comp, [dims]) == []
+    layer_k = 64 * 1024 * cfg.n_kv_heads * cfg.head_dim
+    # (the sampler's drawn tier sorts float32 logits: a branch of its own)
+    assert [typ for _, typ in relayouts(hlo, layer_k)
+            if not typ.startswith("f32[256,")] == []
+    assert not re.search(r"f32\[64,4,151936\]", hlo)
+    # the grouped products see slots x 4 positions x 8 experts a token
+    assert re.search(r"%gmm[.\d]* = bf16\[2048,768\]", hlo)
 
 
 def _dims(typ: str):

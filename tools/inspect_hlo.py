@@ -123,7 +123,8 @@ def main(argv=None) -> int:
 
     params = shapes(lambda: init(cfg, jax.random.key(0)))
     state = shapes(lambda: slot.fresh(
-        transformer.init_cache(cfg, args.slots, args.window), args.slots))
+        transformer.init_cache(cfg, args.slots, args.window), args.slots,
+        cfg.gen_block))
     if args.program == "chunk":
         fn = engine._named_partial(InferenceEngine._chunk_impl, cfg=cfg,
                                    n_steps=args.steps)

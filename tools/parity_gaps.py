@@ -36,6 +36,29 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 sys.path.insert(0, ROOT)
 
 
+def _gaps(reference, fam, params, cfg, probes):
+    """reference.logit_gaps with the control. A family whose
+    teacher-forced logits depend on where the prompt ends (its
+    forward_logits takes `prompt_len`: generation by diffusion over
+    blocks, where a prompt that ends inside a block shifts which
+    positions were decided together) is judged a probe at a time, told
+    each probe's; the harness's own probes end on a block and need none."""
+    import functools
+    import inspect
+    import types
+
+    if "prompt_len" not in inspect.signature(fam.forward_logits).parameters:
+        return reference.logit_gaps(fam, params, cfg, probes, control=True)
+    gaps, control = [], []
+    for prompt, toks in probes:
+        told = types.SimpleNamespace(forward_logits=functools.partial(
+            fam.forward_logits, prompt_len=len(prompt)))
+        g, c = reference.logit_gaps(told, params, cfg, [(prompt, toks)], control=True)
+        gaps += g
+        control += c
+    return gaps, control
+
+
 def main(job_file: str, out_file: str = "") -> int:
     import family
     import reference
@@ -49,7 +72,7 @@ def main(job_file: str, out_file: str = "") -> int:
     device.enable_compile_cache()  # a probe's length is a shape: seeds share the programs
     fam = family.load(os.path.join(ROOT, "benchmark"), cfg)
     params = fam.build_params(cfg, int(job["seed"]))
-    gaps, control = reference.logit_gaps(fam, params, cfg, job["probes"], control=True)
+    gaps, control = _gaps(reference, fam, params, cfg, job["probes"])
     # the scale the gaps are read against: the reference's own spread of
     # logits over the vocabulary at the first probe's generated positions
     import jax.numpy as jnp
