@@ -27,6 +27,7 @@ key(seed) folded with a.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -214,44 +215,87 @@ def transfer(known, conf, k: int, rule: str, threshold: Optional[float]):
         return take & ~known
 
 
+# A pass scores the slots that are live and hold an undecided position,
+# and of all B slots at most this many unless more need it: the head's
+# weights are read once whatever the rows, so up to here a pass pays for
+# them alone, and past it for float32 logits [rows, V] written and read
+# back. One rung, read off the chip (tools/probe_block_head.py at D 2048,
+# V 151936, the engine's own chunk; the table is in CHANGES.md, PR 53):
+# greedy, the head and what reads it cost 0.83 ms at 4 slots, 1.4 % more
+# at 8, 3.3 % at 16, 5.4 % at 32 and 67 % more over all 64; a pass that
+# draws pays 0.02 ms of Gumbel noise for every slot scored besides (9 %
+# more at 8 than at 4, 27 % at 16). So the most slots that stay within a
+# tenth of the fewest's in both tiers, which is also the most requests
+# the REST executor's threads hold.
+SCORED_SLOTS = 8
+
+
 def block_step(
-    carry: State, logits: jnp.ndarray, cache, Smax: int, cfg: ModelConfig,
+    carry: State, hidden: jnp.ndarray, cache, Smax: int, cfg: ModelConfig,
+    head: Callable[[jnp.ndarray], jnp.ndarray],
 ) -> Tuple[State, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """decode_step for a model that generates by diffusion over blocks:
-    all of a pass that is not the model call. `logits` [B * Bk, V] score
-    the block's own positions, slot by slot; `cache` is what the model
+    all of a pass that is not the model call. `hidden` [B, Bk, D] are
+    the block's own positions as the layers left them and `head` scores
+    rows of them ([N, D] -> logits [N, V]); `cache` is what the model
     call returned (the block's rows written for the rows that commit,
     `committing`, and for no other). A running row whose block holds an undecided
     position takes x0 = the sampled token of each (the mask id
     excluded; greedy: the argmax) and decides some (`transfer`); a row
     whose block is decided commits: its tokens past the prompt's tail
     are emitted, cut after the first EOS and at the budget, pos + Bk,
-    and the next block starts undecided.
+    and the next block starts undecided. Only the first kind of row
+    reads its scores, so the head, the sampler and the confidence run
+    over those slots alone, moved to the front: over none in a pass
+    where no slot needs them, over SCORED_SLOTS while so many hold them
+    all, over every slot otherwise (`lax.switch` on their count: the
+    logits exist inside a branch only).
 
-    Returns (carry, toks [B, Bk], valid [B, Bk], counts [6] int32:
+    Returns (carry, toks [B, Bk], valid [B, Bk], counts [7] int32:
     decode_step's three, then the slots that ran this pass, those of
-    them that committed, the tokens emitted)."""
+    them that committed, the tokens emitted, the rows the head
+    scored)."""
     Bk = cfg.gen_block
-    B = logits.shape[0] // Bk
+    B, _, D = hidden.shape
     run = carry["active"]
     commit = committing(carry)
+    need = run & ~commit
     known, pos = carry["blk_known"], carry["pos"]
-    with jax.named_scope("diff/confidence"):
-        logits = jnp.where(
-            jnp.arange(logits.shape[-1]) == cfg.mask_token_id, -jnp.inf,
-            logits)
-        knobs = sampling.live_knobs(
-            run & ~commit, carry["temp"], carry["top_k"], carry["top_p"])
-        at = pos[:, None] + jnp.arange(Bk)[None, :]  # absolute positions
-        keys = step_key(jnp.repeat(carry["seeds"], Bk), at.reshape(-1) - 1)
-        x0 = sampling.sample_per_row(
-            logits, keys, *(jnp.repeat(kn, Bk) for kn in knobs))
-        conf = jnp.exp(
-            jnp.take_along_axis(logits, x0[:, None], axis=-1)[:, 0]
-            - jax.nn.logsumexp(logits, axis=-1)).reshape(B, Bk)
-        x0 = x0.reshape(B, Bk)
+    knobs = sampling.live_knobs(
+        need, carry["temp"], carry["top_k"], carry["top_p"])
+
+    def scored(n: int):
+        """(x0, conf) [B, Bk] with the first n slots that need scores
+        scored and the others 0, which nothing reads."""
+        if not n:
+            return jnp.zeros((B, Bk), jnp.int32), jnp.zeros((B, Bk))
+        slots = jnp.argsort(~need, stable=True)[:n]
+        of = (lambda a: a[slots]) if n < B else (lambda a: a)
+        with jax.named_scope("diff/confidence"):
+            logits = head(of(hidden).reshape(-1, D))
+            logits = jnp.where(
+                jnp.arange(logits.shape[-1]) == cfg.mask_token_id, -jnp.inf,
+                logits)
+            at = of(pos)[:, None] + jnp.arange(Bk)[None, :]  # absolute
+            keys = step_key(jnp.repeat(of(carry["seeds"]), Bk),
+                            at.reshape(-1) - 1)
+            x0 = sampling.sample_per_row(
+                logits, keys, *(jnp.repeat(of(kn), Bk) for kn in knobs))
+            conf = jnp.exp(
+                jnp.take_along_axis(logits, x0[:, None], axis=-1)[:, 0]
+                - jax.nn.logsumexp(logits, axis=-1)).reshape(n, Bk)
+            x0 = x0.reshape(n, Bk)
+        if n == B:
+            return x0, conf
+        return (jnp.zeros((B, Bk), x0.dtype).at[slots].set(x0),
+                jnp.zeros((B, Bk), conf.dtype).at[slots].set(conf))
+
+    sizes = sorted({0, min(SCORED_SLOTS, B), B})
+    which = jnp.sum(jnp.sum(need) > jnp.asarray(sizes[:-1]))
+    x0, conf = jax.lax.switch(
+        which, [functools.partial(scored, n) for n in sizes])
     take = transfer(known, conf, Bk // cfg.denoise_steps, cfg.remask,
-                    cfg.denoise_threshold) & (run & ~commit)[:, None]
+                    cfg.denoise_threshold) & need[:, None]
     tok = jnp.where(take, x0, carry["blk_tok"])
     # the commit: tokens past the prompt's tail, up to the budget and to
     # the first EOS among them
@@ -280,7 +324,8 @@ def block_step(
     }
     counts = jnp.stack(
         (jnp.ones((), bool),) + sampling.tier(*knobs)
-        + (jnp.sum(run), jnp.sum(commit), jnp.sum(n_out))
+        + (jnp.sum(run), jnp.sum(commit), jnp.sum(n_out),
+           Bk * jnp.asarray(sizes)[which])
     ).astype(jnp.int32)
     return new_carry, jnp.where(valid, tok, cfg.pad_token_id), valid, counts
 
@@ -316,6 +361,7 @@ def whole_blocks(passes: int, cap: int, cfg: ModelConfig) -> int:
 def decode_chunk(
     step_model: Callable[[State], tuple], state: State, n_steps: int,
     Smax: int, cfg: ModelConfig,
+    head: Optional[Callable[[jnp.ndarray], jnp.ndarray]] = None,
 ) -> Tuple[State, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """`n_steps` decode steps over every slot in one lax.scan.
     `step_model(carry)` is all that differs between paths: it runs the
@@ -326,9 +372,12 @@ def decode_chunk(
     a token (an autoregressive row's column is a True-prefix: rows stop
     and stay stopped), counts the steps' counts summed, decode_step's
     three and then the path's. Under cfg.gen_block a step is a pass
-    (block_step) over the carry's block in hand, toks and valid are
+    (block_step) over the carry's block in hand: step_model returns the
+    block's hidden rows [B, Bk, D] in the logits' place and `head`
+    scores rows of them; toks and valid are
     [K, B, Bk], and a column holds tokens at its commit passes only."""
-    one_step = block_step if cfg.gen_block else decode_step
+    one_step = functools.partial(block_step, head=head) if cfg.gen_block \
+        else decode_step
 
     def step(carry, _):
         logits, cache, *more = step_model(carry)

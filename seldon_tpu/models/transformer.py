@@ -2659,7 +2659,8 @@ def decode_block(params, tokens, known, pos, cache, cfg, live, commit,
     pos .. pos + Bk - 1 of every slot, `tokens` [B, Bk] where `known`
     and the embedding of cfg.mask_token_id elsewhere, against the cache
     below pos and against each other in both directions. Returns
-    (logits [B * Bk, V] of the block's own positions, the cache with the
+    (the hidden rows [B, Bk, D] of the block's own positions, for
+    block_logits to score, the cache with the
     block's K/V rows written for the slots that `commit` and are `live`
     and for no other, the routing counters of decode_step). A slot that
     is not live routes its Bk rows to no expert."""
@@ -2733,10 +2734,15 @@ def decode_block(params, tokens, known, pos, cache, cfg, live, commit,
                 # [La, B, Bk, C] -> [B, La, Bk, C]
                 new_cache[key] = cache[key].at[layers, rows, 0, at].set(
                     jnp.swapaxes(_unsegment(fresh[key]), 0, 1), mode="drop")
-    # one row a (slot, position): [B, Bk, V] would put Bk in the tile's
-    # sublanes and every later view of it [B * Bk, V] would be a copy
-    return _logits(params, x.reshape(1, -1, x.shape[-1]), cfg)[0], \
-        new_cache, routing
+    return x, new_cache, routing
+
+
+def block_logits(params, x, cfg):
+    """The head over rows `x` [N, D] of a pass's hidden rows
+    (decode_block), whichever slots' they are: logits [N, V]. One row a
+    (slot, position): [slots, Bk, V] would put Bk in the tile's sublanes
+    and every later view of it [slots * Bk, V] would be a copy."""
+    return _logits(params, x[None], cfg)[0]
 
 
 def _prefill_patterned(params, tokens, prompt_lens, cache, cfg):

@@ -642,7 +642,10 @@ CHUNK_COUNTERS = (SAMPLER_COUNTERS + KV_COUNTERS + MOE_COUNTERS
 # those of them that committed a block, and the tokens those emitted
 # (slot_rules.block_step). tokens / slot passes is what a slot's pass
 # yields: gen_block / (denoise_steps + 1) less tails and cuts.
-DIFF_COUNTERS = ("diff_slot_passes", "diff_commit_passes", "diff_tokens_out")
+# The fourth is the rows the head scored: 0, SCORED_SLOTS x gen_block or
+# slots x gen_block a pass, by how many slots held an undecided position.
+DIFF_COUNTERS = ("diff_slot_passes", "diff_commit_passes", "diff_tokens_out",
+                 "diff_rows_scored")
 
 
 def chunk_counter_names(cfg) -> Tuple[str, ...]:
@@ -717,6 +720,7 @@ class EngineStats:
         self.diff_slot_passes = 0  # graftlint: guarded-by(lock) via(stats)
         self.diff_commit_passes = 0  # graftlint: guarded-by(lock) via(stats)
         self.diff_tokens_out = 0  # graftlint: guarded-by(lock) via(stats)
+        self.diff_rows_scored = 0  # graftlint: guarded-by(lock) via(stats)
         # Prompt tokens admitted, by the bucket their admission group was
         # padded to ({bucket: tokens}; the cold dense admission).
         self.attn_prefill_tokens: Dict[int, int] = {}  # graftlint: guarded-by(lock) via(stats)
@@ -1862,7 +1866,7 @@ class InferenceEngine:
         (slot_rules.decode_chunk). Returns (state, toks [K,B], valid [K,B],
         active [B], counts), toks and valid [K,B,Bk] where an iteration is
         a pass over a block of Bk positions a slot (cfg.gen_block: its
-        three counters come right after the sampler's); counts int32 over
+        four counters come right after the sampler's); counts int32 over
         the chunk, in
         CHUNK_COUNTERS' order: steps, steps that drew, steps that masked;
         KV tokens the attention layers read and KV tokens the slab holds
@@ -1891,19 +1895,22 @@ class InferenceEngine:
 
         def pass_model(carry):
             """step_model for cfg.gen_block: the block in hand of every
-            slot; the slots whose block is decided commit its KV."""
+            slot; the slots whose block is decided commit its KV. The
+            head is slot_rules.block_step's to call, over the slots
+            that will read their scores."""
             live, pos, cache = carry["active"], carry["pos"], carry["cache"]
             commit = slot_rules.committing(carry)
-            logits, cache_, routing = transformer.decode_block(
+            hidden, cache_, routing = transformer.decode_block(
                 params, carry["blk_tok"], carry["blk_known"], pos, cache,
                 cfg, live, commit, spread)
             kv = transformer.block_kv_counts(cfg, cache, live, pos, commit,
                                              spread)
-            return logits, cache_, jnp.concatenate([kv, routing])
+            return hidden, cache_, jnp.concatenate([kv, routing])
 
         state, toks, valid, counts = slot_rules.decode_chunk(
             pass_model if cfg.gen_block else step_model, state, n_steps,
-            Smax, cfg)
+            Smax, cfg,
+            head=functools.partial(transformer.block_logits, params, cfg=cfg))
         if tp is not None:
             state = tp.constrain_state(state)
         toks, valid, active, counts = InferenceEngine._replicate(

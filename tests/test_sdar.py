@@ -16,6 +16,7 @@ argmax of 256 seeded logits does not sit on a tie.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import os
 from unittest import mock
@@ -246,14 +247,15 @@ def _admitted(cfg, p, slots=4, window=64, bucket=32):
 
 def _pass_logits(cfg, state, compute=None):
     live = state["active"]
-    logits, cache, routing = T.decode_block(
-        params_of(cfg) if compute is None else jax.tree.map(
-            lambda a: a.astype(compute) if a.dtype == jnp.float32
-            and a.ndim > 1 else a, params_of(cfg)),
-        state["blk_tok"], state["blk_known"], state["pos"], state["cache"],
-        cfg if compute is None else dataclasses.replace(
-            cfg, dtype="bfloat16"),
-        live, slot.committing(state))
+    params = params_of(cfg) if compute is None else jax.tree.map(
+        lambda a: a.astype(compute) if a.dtype == jnp.float32
+        and a.ndim > 1 else a, params_of(cfg))
+    if compute is not None:
+        cfg = dataclasses.replace(cfg, dtype="bfloat16")
+    hidden, cache, routing = T.decode_block(
+        params, state["blk_tok"], state["blk_known"], state["pos"],
+        state["cache"], cfg, live, slot.committing(state))
+    logits = T.block_logits(params, hidden.reshape(-1, cfg.d_model), cfg)
     return np.asarray(logits, np.float32).reshape(-1, BK, cfg.vocab_size), \
         cache, routing
 
@@ -375,6 +377,99 @@ def test_a_dead_slots_rows_route_nowhere():
     assert int(routing[2]) == 4 * assigned
 
 
+# -- the head over the slots that will read their scores ---------------------
+
+R = slot.SCORED_SLOTS
+WIDE = R + 3  # slots: more than the rung holds, and more than one more
+PASSES = 6
+
+
+def _group(cfg, tails):
+    """A slab of WIDE slots after one admission of len(tails) prompts,
+    prompt i into slot i with `tails[i]` tokens past its last whole
+    block; greedy and drawn rows alternate, every fourth row asks for
+    top-k as well."""
+    n = len(tails)
+    state = slot.fresh(T.init_cache(cfg, WIDE, 64), WIDE, cfg.gen_block)
+    toks = np.zeros((n, 32), np.int32)
+    plens = [8 + 4 * (i % 2) + t for i, t in enumerate(tails)]
+    for i, plen in enumerate(plens):
+        toks[i, :plen] = prompt(plen, seed=9 + i)
+    rows = np.arange(n)
+    state, _, _ = InferenceEngine._admit_impl(
+        params_of(cfg), state, jnp.asarray(toks),
+        jnp.asarray(plens, jnp.int32), jnp.asarray(rows + 5, jnp.uint32),
+        jnp.asarray(np.where(rows % 2, 0.8, 0.0), jnp.float32),
+        jnp.asarray(np.where(rows % 4 == 3, 5, 0), jnp.int32),
+        jnp.ones((n,), jnp.float32), jnp.full((n,), 20, jnp.int32),
+        jnp.asarray(rows, jnp.int32), cfg=cfg)
+    return state
+
+
+@functools.lru_cache(maxsize=None)
+def _passes(cfg, every_slot: bool):
+    """One pass of the engine's chunk, jitted: as the program scores
+    (the slots that need it, by the rung), or with the head, the sampler
+    and the confidence over every slot whatever the pass holds."""
+    def one(params, state):
+        if not every_slot:
+            return InferenceEngine._chunk_impl(params, state, cfg=cfg,
+                                               n_steps=1)
+        with mock.patch.object(
+                jax.lax, "switch", lambda _, branches: branches[-1]()):
+            return InferenceEngine._chunk_impl(params, state, cfg=cfg,
+                                               n_steps=1)
+    return jax.jit(one)
+
+
+# slots that need scores in the first pass; a tail of 2 or 3 commits a
+# pass before a tail of 0 or 1, so later passes hold slots of both kinds
+TAILS = {
+    "none": [0, 0],               # both commit in one pass: 2, 2, 0, ...
+    "one": [2],                   # 1, 0, 1, 1, 0, ...
+    "the_rung": [i % 4 for i in range(R)],
+    "one_more": [i % 4 for i in range(R + 1)],
+    "every_slot": [i % 4 for i in range(WIDE)],
+}
+RULES = {
+    "sequential": {},
+    "low_confidence": {"remask": "low_confidence", "denoise_threshold": 0.006},
+}
+
+
+@pytest.mark.parametrize("rule", RULES)
+@pytest.mark.parametrize("case", TAILS)
+def test_scoring_the_slots_that_need_it_changes_nothing(case, rule):
+    """Pass by pass, the chunk whose head runs over the slots that hold
+    an undecided position (none, SCORED_SLOTS of them or all, by their
+    count) hands on what the chunk whose head runs over every slot does:
+    tokens, valid, the whole carry, the counts; and diff_rows_scored is
+    0, SCORED_SLOTS x Bk or slots x Bk by that count."""
+    cfg = tiny(**RULES[rule])
+    state = _group(cfg, TAILS[case])
+    names = chunk_counter_names(cfg)
+    needs = []
+    for _ in range(PASSES):
+        need = int(np.sum(np.asarray(state["active"])
+                          & ~np.asarray(state["blk_known"]).all(axis=1)))
+        needs.append(need)
+        want = _passes(cfg, True)(params_of(cfg), state)
+        got = _passes(cfg, False)(params_of(cfg), state)
+        for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+        counts = dict(zip(names, np.asarray(got[4]).tolist()))
+        assert counts["diff_rows_scored"] == BK * (
+            0 if need == 0 else R if need <= R else WIDE)
+        state = got[0]
+    assert needs[0] == len(TAILS[case])
+    # a slot that commits and one that denoises share a pass
+    assert case in ("none", "one") or any(
+        0 < n < len(TAILS[case]) for n in needs)
+    assert case not in ("none", "one") or 0 in needs
+    # every request has emitted a block by now
+    assert (np.asarray(state["remaining"])[:len(TAILS[case])] < 20).all()
+
+
 # -- the teacher-forced form against the procedure ---------------------------
 
 @pytest.mark.parametrize("plen,n_new", [(8, 12), (12, 7), (4, 5)])
@@ -463,8 +558,8 @@ def test_a_window_or_bucket_that_cuts_a_block_is_refused():
 
 def test_the_passes_counters_sit_after_the_samplers():
     names = chunk_counter_names(get_config("tiny-sdar"))
-    assert names[3:6] == DIFF_COUNTERS
-    assert names[6:10] == ("attn_kv_tokens_read", "attn_kv_tokens_held",
+    assert names[3:7] == DIFF_COUNTERS
+    assert names[7:11] == ("attn_kv_tokens_read", "attn_kv_tokens_held",
                            "attn_kv_rows_written", "attn_kv_rows_slots")
     assert not set(DIFF_COUNTERS) & set(chunk_counter_names(get_config("tiny")))
 

@@ -22,7 +22,12 @@ import pytest
 from seldon_tpu.models import init_params, slot, transformer
 from seldon_tpu.models.config import get_config
 from seldon_tpu.servers.engine import InferenceEngine
-from tools.inspect_hlo import big_instructions, configuration
+from tools.inspect_hlo import (
+    big_instructions,
+    computations,
+    configuration,
+    reachable,
+)
 
 SLOTS, WINDOW, STEPS = 32, 256, 4
 
@@ -234,7 +239,12 @@ def test_a_pass_over_blocks_reads_and_commits_through_the_kernel(
     results, so the commit's rows are written where they lie; nothing as
     large as a layer of the slab is copied, and no logits [slots, 4,
     vocabulary] with the block in the tile's sublanes are made beside
-    the flat ones the sampler reads."""
+    the flat ones the sampler reads. The head, the sampler and the
+    confidence sit in one conditional of three branches by the count of
+    slots that hold an undecided position (slot.block_step): the empty
+    branch makes no array with the vocabulary in it, the rung's scores
+    slot.SCORED_SLOTS x 4 rows, and arrays of all 256 rows x the
+    vocabulary are made in the full branch alone."""
     cfg, hlo, state = published_chunk("sdar-30b-a3b-chat")
     assert cfg.gen_block == 4 and state["blk_tok"].shape == (64, 4)
     calls = kernel_calls(hlo)
@@ -252,6 +262,20 @@ def test_a_pass_over_blocks_reads_and_commits_through_the_kernel(
     assert [typ for _, typ in relayouts(hlo, layer_k)
             if not typ.startswith("f32[256,")] == []
     assert not re.search(r"f32\[64,4,151936\]", hlo)
+    comps = computations(hlo)
+    (switch,) = [m for m in re.finditer(
+        r"branch_computations=\{([^}]*)\}", hlo) if m.group(1).count(",") == 2]
+    empty, rung, full = (reachable(comps, name.strip(" %"))
+                         for name in switch.group(1).split(","))
+    rows = 4 * slot.SCORED_SLOTS
+    assert "151936" not in "".join(comps[c] for c in empty)
+    rung_text = "".join(comps[c] for c in rung)
+    assert "f32[%d,151936]" % rows in rung_text
+    # 256 rows x the vocabulary (151936 = 1187 x 128), of any dtype
+    wide = re.compile(r"\[256,(151936|1187,128)\]")
+    assert not wide.search(rung_text)
+    assert "f32[256,151936]" in "".join(comps[c] for c in full)
+    assert [c for c in comps if c not in full and wide.search(comps[c])] == []
     # the grouped products see slots x 4 positions x 8 experts a token
     assert re.search(r"%gmm[.\d]* = bf16\[2048,768\]", hlo)
 

@@ -59,6 +59,32 @@ def big_instructions(hlo: str, at_least: int):
     return out
 
 
+def computations(hlo: str):
+    """{name: text} of a compiled program's computations."""
+    out, name = {}, None
+    for line in hlo.split("\n"):
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+        if name:
+            out[name] = out.get(name, "") + line + "\n"
+    return out
+
+
+def reachable(comps, name: str):
+    """The computations `name` calls, itself among them (a fusion, a
+    branch, a loop body, a reducer: whatever its text names)."""
+    seen, todo = set(), [name]
+    while todo:
+        at = todo.pop()
+        if at in seen:
+            continue
+        seen.add(at)
+        todo += [c for c in re.findall(r"%([\w.\-]+)", comps[at])
+                 if c in comps]
+    return seen
+
+
 def configuration(name: str):
     """(ModelConfig, the init that bears its tree) of a file of
     benchmark/configs, by name, as the file states it."""
