@@ -69,10 +69,10 @@ taps, the SwiGLU's; not norms, A_log, dt_bias, D, the conv bias, embedding
 or head) rounded to float8 e4m3, the nearest precision below the served
 bf16.
 
-The costs price what a decode step NEEDS: every layer's weights and the
-head once, the SSM and conv state of every slot the program holds read and
-written once in every layer (the dense slab steps them all), KV of every
-layer at the live context.
+The costs price what a decode step NEEDS, whatever program serves it:
+every layer's weights and the head once, the SSM and conv state of the
+slots that hold a request read and written once in every layer, KV of
+every layer at the live context.
 
 run.py loads this file and never imports JAX, so JAX is imported by the
 functions that compute (_need_jax), not by the module."""
@@ -331,13 +331,6 @@ def layer_counts(cfg: Dict) -> Dict[str, int]:
     return {"mamba": n, "attention": n, "dense": n}
 
 
-def slots_held(cfg: Dict) -> int:
-    """Slots of the dense slab: every one of them has its SSM state
-    stepped on every decode step, live or not."""
-    s = cfg["serving"]
-    return int(s["kv_budget_tokens"]) // int(s["window_tokens"])
-
-
 def ssm_inner(cfg: Dict) -> int:
     return int(cfg["mamba_n_heads"]) * int(cfg["mamba_d_head"])
 
@@ -421,14 +414,16 @@ def ssm_update_cost(cfg: Dict, slots: int) -> Tuple[float, float]:
 
 def decode_step_cost(cfg: Dict, rows: float, context: float) -> Tuple[float, float]:
     """(flops, bytes) one decode step needs for `rows` live rows with a
-    mean live context of `context` tokens each, on a slab of slots_held
-    slots whose fixed-size state is stepped whole."""
-    n, slots = layer_counts(cfg), slots_held(cfg)
+    mean live context of `context` tokens each. A live row is a live
+    slot: its SSM and conv state are read and written once a step, as its
+    KV is read at its live context; the state of a slot that holds no
+    request is no part of the need, however many the slab has."""
+    n = layer_counts(cfg)
     h, dh = cfg["num_attention_heads"], int(cfg["head_dim"])
-    uf, _ = ssm_update_cost(cfg, slots)
+    uf, _ = ssm_update_cost(cfg, rows)
     flops = rows * (flops_per_token(cfg) + n["attention"] * h * 4.0 * dh * context) \
         + n["mamba"] * uf
     bytes_ = (weight_bytes(cfg)
               + rows * (context + 1) * kv_bytes_per_token(cfg)
-              + 2 * slots * (ssm_state_bytes_per_slot(cfg) + conv_state_bytes_per_slot(cfg)))
+              + 2 * rows * (ssm_state_bytes_per_slot(cfg) + conv_state_bytes_per_slot(cfg)))
     return flops, bytes_

@@ -458,17 +458,22 @@ def weight_bytes(cfg: Dict, touched: Optional[float] = None) -> float:
             + 4 * n["sparse"] * router_params(cfg))
 
 
-def decode_step_cost(cfg: Dict, rows: float, context: float) -> Tuple[float, float]:
+def decode_step_cost(cfg: Dict, rows: float, context: float,
+                     touched: Optional[float] = None) -> Tuple[float, float]:
     """(flops, bytes) one decode step needs for `rows` live rows with a
     mean live context of `context` tokens each: a full layer attends over
-    the context, a sliding layer over min(context, sliding_window)."""
+    the context, a sliding layer over min(context, sliding_window).
+    `touched`: the distinct experts a sparse layer read a step, as the
+    unit counted them; absent (a unit that counts none), a uniform
+    router's expectation at `rows`."""
     n, heads, dh = layer_counts(cfg), heads_by_kind(cfg), cfg["head_dim"]
     inside = min(context, float(cfg["sliding_window"]))
     attn = 4.0 * dh * (n["full"] * heads.get(FULL, 0) * context
                        + n["sliding"] * heads.get(SLIDING, 0) * inside)
     flops = rows * (flops_per_token(cfg) + attn)
     kv = n["full"] * (context + 1) + n["sliding"] * (inside + 1)
-    bytes_ = (weight_bytes(cfg, experts_touched(cfg, rows))
+    touched = experts_touched(cfg, rows) if touched is None else touched
+    bytes_ = (weight_bytes(cfg, touched)
               + rows * kv * kv_bytes_per_token_layer(cfg))
     return flops, bytes_
 

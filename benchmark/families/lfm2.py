@@ -375,13 +375,17 @@ def weight_bytes(cfg: Dict, touched: Optional[float] = None) -> float:
     return b * body + routers
 
 
-def decode_step_cost(cfg: Dict, rows: float, context: float) -> Tuple[float, float]:
+def decode_step_cost(cfg: Dict, rows: float, context: float,
+                     touched: Optional[float] = None) -> Tuple[float, float]:
     """(flops, bytes) one decode step needs for `rows` live rows with a
-    mean live context of `context` tokens each."""
+    mean live context of `context` tokens each. `touched`: the distinct
+    experts a sparse layer read a step, as the unit counted them; absent
+    (a unit that counts none), a uniform router's expectation at `rows`."""
     h, dh = cfg["num_attention_heads"], cfg["hidden_size"] // cfg["num_attention_heads"]
     n_attn = layer_counts(cfg)["attention"]
+    touched = experts_touched(cfg, rows) if touched is None else touched
     flops = rows * (flops_per_token(cfg) + n_attn * h * 4.0 * dh * context)
-    bytes_ = (weight_bytes(cfg, experts_touched(cfg, rows))
+    bytes_ = (weight_bytes(cfg, touched)
               + rows * (context + 1) * kv_bytes_per_token(cfg)
               + 2 * rows * conv_state_bytes_per_row(cfg))  # read and written
     return flops, bytes_

@@ -32,7 +32,7 @@ functions that compute (_need_jax), not by the module."""
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 CONTROL_BITS = 4
 CONTROL = f"int{CONTROL_BITS} grid"
@@ -261,11 +261,17 @@ def experts_touched(cfg: Dict, rows: float) -> float:
     return n_exp * (1.0 - (1.0 - k / n_exp) ** max(rows, 0.0))
 
 
-def decode_step_cost(cfg: Dict, rows: float, context: float) -> Tuple[float, float]:
+def decode_step_cost(cfg: Dict, rows: float, context: float,
+                     touched: Optional[float] = None) -> Tuple[float, float]:
     """(flops, bytes) one decode step needs for `rows` live rows with a
-    mean live context of `context` tokens each."""
+    mean live context of `context` tokens each. `touched`: the distinct
+    experts a sparse layer read a step, as the unit counted them; absent
+    (a unit that counts none, a dense model), a uniform router's
+    expectation at `rows`."""
     _, h, _, dh, _, layers, _ = _dims(cfg)
+    if touched is None or not cfg.get("num_local_experts"):
+        touched = experts_touched(cfg, rows)
     flops = rows * (flops_per_token(cfg) + layers * h * 4.0 * dh * context)
-    bytes_ = (weight_bytes(cfg, experts_touched(cfg, rows))
+    bytes_ = (weight_bytes(cfg, touched)
               + rows * (context + 1) * kv_bytes_per_token(cfg))
     return flops, bytes_

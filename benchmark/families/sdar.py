@@ -453,23 +453,21 @@ def passes_per_block(cfg: Dict) -> int:
     return procedure(cfg)["denoise_steps"] + 1
 
 
-def decode_step_cost(cfg: Dict, rows: float, context: float) -> Tuple[float, float]:
+def decode_step_cost(cfg: Dict, rows: float, context: float,
+                     touched: Optional[float] = None) -> Tuple[float, float]:
     """(flops, bytes) one PASS needs. `rows` is what the harness hands
     over: tokens emitted a pass, so the live slots are rows x (steps + 1)
     / Bk, each with Bk positions in the pass. A pass reads the attention
-    weights and routers once, the experts that slots x Bk x k assignments
-    are expected to touch, the live slots' KV, and (on the steps of
-    steps + 1 passes that denoise) the head; a committing slot writes Bk
-    rows of KV. The experts touched are a concave function of the live
-    slots, so a need priced at their mean overstates it (~6 % at three
-    slots), and a uniform router overstates them besides: the undecided
-    positions of a pass share the mask's embedding and route alike (on
-    the chip 24-40 experts a layer were read at 2-3 live slots where
-    this expects 52-69: PERF.md section 6, PR 52), so this need is up
-    to a third too high and step.decode_roofline reads high with it; the
-    roofline that is exact is the grouped products' own
-    (moe.block_kernel_roofline.chat), priced at the slice's counted
-    experts."""
+    weights and routers once, `touched` experts a layer, the live slots'
+    KV, and (on the steps of steps + 1 passes that denoise) the head; a
+    committing slot writes Bk rows of KV. `touched`: the distinct experts
+    a sparse layer read a pass, as the unit counted them. Absent (a unit
+    that counts none), what slots x Bk x k assignments touch under a
+    uniform router, which is far too many here: the undecided positions
+    of a pass share the mask's embedding and route alike (28 experts a
+    layer counted at 2.5 live slots where a uniform router expects 61:
+    PERF.md section 6, PRs 52 to 54), and a concave count priced at the
+    mean live slots overstates itself besides (~6 % at three slots)."""
     proc = procedure(cfg)
     bk, steps = proc["gen_block"], proc["denoise_steps"]
     slots = rows * (steps + 1) / bk
@@ -481,8 +479,8 @@ def decode_step_cost(cfg: Dict, rows: float, context: float) -> Tuple[float, flo
         L * (attn_params(cfg) + k * expert_params(cfg) + router_params(cfg))
         + denoising * head_params(cfg)) \
         + positions * L * h * 4.0 * dh * (context + bk)
-    bytes_ = (b * L * (attn_params(cfg)
-                       + experts_touched(cfg, positions) * expert_params(cfg))
+    touched = experts_touched(cfg, positions) if touched is None else touched
+    bytes_ = (b * L * (attn_params(cfg) + touched * expert_params(cfg))
               + 4 * L * router_params(cfg)
               + b * denoising * head_params(cfg)
               + slots * context * kv_bytes_per_token(cfg)

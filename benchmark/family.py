@@ -7,7 +7,7 @@ configurations PR 23 brought). A family's file provides
     model_config_kwargs(cfg) -> dict                 ModelConfig keyword arguments
     build_params(cfg, seed) -> tree                  the weights the unit serves
     forward_logits(params, tokens, cfg, control=False) -> [S, V] float32
-    decode_step_cost(cfg, rows, context) -> (flops, bytes)
+    decode_step_cost(cfg, rows, context[, touched]) -> (flops, bytes)
 
 where `cfg` is always the configuration file as a dict (the source's key
 names). launcher.py, run.py, reference.py and the roofline reader reach

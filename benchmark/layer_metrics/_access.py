@@ -7,6 +7,7 @@ chiprun_out/benchmark/<cell>/unit.log, which outlives the unit. A unit
 that writes no such lines (an older program) leaves every reader here
 with nothing to read: None, and the metric is left out of the line."""
 
+import bisect
 import json
 import os
 import re
@@ -75,6 +76,51 @@ def window_delta(obs, fields):
         return None
     rows.sort(key=lambda r: r[fields[0]])
     d = {f: rows[-1][f] - rows[0][f] for f in fields}
+    return d if d[fields[0]] > 0 else None
+
+
+def ended_unix(row):
+    """When the request ended on the unit's wall clock: received plus
+    every phase of its line (the engine stamps them from one clock)."""
+    ms = [row.get(k) for k in PHASES + ("decode_ms",)]
+    if not all(isinstance(v, (int, float)) for v in ms + [row.get("received_unix")]):
+        return None
+    return row["received_unix"] + sum(ms) / 1000.0
+
+
+def slice_delta(obs, fields):
+    """The growth of the running counters `fields` over the TRACED SLICE,
+    the seconds the device times of obs.trace come from: each line gives
+    the counters at the instant its request ended, and the counters at
+    the slice's two ends are read off the line between the two requests
+    that ended around each (live rows change little between two ends:
+    only an admission moves them). The window's mean is no stand-in:
+    live rows, and with them the experts read, differ by a quarter
+    between one three-second slice and the next (unit.log holds one load
+    of the unit, so its counters only grow). None where the lines do not
+    reach both ends of the slice, or fields[0] did not move."""
+    tr = obs.trace
+    if not tr or not tr.get("slice"):
+        return None
+    off = time.time() - time.perf_counter()  # as window()
+    a, b = (t + off for t in tr["slice"])
+    pts = sorted((t,) + tuple(r[f] for f in fields)
+                 for r in lines(obs, "request") for t in (ended_unix(r),)
+                 if t is not None
+                 and all(isinstance(r.get(f), (int, float)) for f in fields))
+    times = [p[0] for p in pts]
+
+    def at(t):
+        j = bisect.bisect_left(times, t)
+        if j == 0 or j == len(pts):
+            return None
+        (t0, *c0), (t1, *c1) = pts[j - 1], pts[j]
+        w = (t - t0) / (t1 - t0) if t1 > t0 else 0.0
+        return [x0 + w * (x1 - x0) for x0, x1 in zip(c0, c1)]
+    ca, cb = at(a), at(b)
+    if ca is None or cb is None:
+        return None
+    d = {f: y - x for f, x, y in zip(fields, ca, cb)}
     return d if d[fields[0]] > 0 else None
 
 

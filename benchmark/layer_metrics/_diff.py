@@ -9,9 +9,7 @@ diff_slot_passes ((slot, pass) pairs run), diff_commit_passes and
 diff_tokens_out. A program that writes no such fields (another model, an
 older program) leaves every reader here with nothing to read: None."""
 
-import bisect
 import re
-import time
 
 import _access
 import _moe
@@ -33,36 +31,6 @@ def tokens_per_pass(obs):
     """Tokens a live slot's pass emitted, over the window."""
     d = _access.window_delta(obs, PASSES)
     return d["diff_tokens_out"] / d["diff_slot_passes"] if d else None
-
-
-def slice_delta(obs, fields):
-    """_moe.slice_delta for any of the access lines' running counters:
-    their growth over the TRACED SLICE, read off the lines of the two
-    requests that ended around each of its ends. None where the lines do
-    not reach both ends, or fields[0] did not move."""
-    tr = obs.trace
-    if not tr or not tr.get("slice"):
-        return None
-    off = time.time() - time.perf_counter()  # as _access.window
-    a, b = (t + off for t in tr["slice"])
-    pts = sorted((_moe._ended_unix(r),) + tuple(r[f] for f in fields)
-                 for r in _access.lines(obs, "request")
-                 if _moe._ended_unix(r) is not None
-                 and all(isinstance(r.get(f), (int, float)) for f in fields))
-    times = [p[0] for p in pts]
-
-    def at(t):
-        j = bisect.bisect_left(times, t)
-        if j == 0 or j == len(pts):
-            return None
-        (t0, *c0), (t1, *c1) = pts[j - 1], pts[j]
-        w = (t - t0) / (t1 - t0) if t1 > t0 else 0.0
-        return [x0 + w * (x1 - x0) for x0, x1 in zip(c0, c1)]
-    ca, cb = at(a), at(b)
-    if ca is None or cb is None:
-        return None
-    d = {f: y - x for f, x, y in zip(fields, ca, cb)}
-    return d if d[fields[0]] > 0 else None
 
 
 def block_grouped_ops(obs):

@@ -12,17 +12,18 @@ def read(obs):
     query positions a slot). Need: the family's attention_cost of the KV
     tokens the layers read, the K rows the committing slots wrote and the
     (slot, pass) pairs run in the SAME seconds, by the unit's counters
-    (_diff.slice_delta). Low by design at short contexts: a call costs a
+    (_access.slice_delta). Low by design at short contexts: a call costs a
     few microseconds a layer whatever it reads. None where no op carries
     the name, the family has no such closed form or the counters do not
     cover the slice."""
+    import _access
     import _diff
     import costs
     fam, ops = obs.family, _diff.attention_ops(obs)
     if not ops or not obs.peaks or not hasattr(fam, "attention_cost") \
             or not _diff.block_length(obs):
         return None
-    d = _diff.slice_delta(obs, _diff.ATTN)
+    d = _access.slice_delta(obs, _diff.ATTN)
     if not d:
         return None
     flops, bytes_ = fam.attention_cost(

@@ -142,7 +142,7 @@ def test_key_map_gives_the_single_block_fields_and_survives_a_json_round_trip(fa
 def test_closed_forms_against_hand_values(fam):
     cfg = _cfg()
     assert fam.layer_counts(cfg) == {"mamba": 6, "attention": 2, "moe": 6}
-    assert fam.slots_held(cfg) == 64
+    assert not hasattr(fam, "slots_held")   # a need does not know how many slots the slab has
     assert (fam.ssm_inner(cfg), fam.ssm_conv_dim(cfg)) == (4096, 6144)
     assert fam.mamba_params(cfg) == 2688 * 10304 + 4096 * 2688 + 5 * 6144 == 38737920   # 38.7 M
     assert fam.attn_params(cfg) == 2 * 2688 * 4096 + 2 * 2688 * 256 == 23396352        # 23.4 M
@@ -159,20 +159,23 @@ def test_closed_forms_against_hand_values(fam):
     # uniform routing over 128, of which 64 are held: 64 (1 - (122/128)^rows)
     assert fam.experts_touched(cfg, 1) == pytest.approx(3.0)
     assert fam.experts_touched(cfg, 2.5) == pytest.approx(64 * (1 - (122 / 128) ** 2.5))
-    # one layer's update over the slab: the state read and written, x B C dt in, y out
+    # one layer's update over 64 slots: the state read and written, x B C dt in, y out
     uf, ub = fam.ssm_update_cost(cfg, 64)
     assert ub == 64 * (2 * 64 * 64 * 128 * 4 + (4096 + 2048) * 2 + 64 * 4 + 4096 * 4)
     assert uf == 64 * 5.0 * 64 * 64 * 128
-    assert 0.26e9 < ub < 0.28e9            # 270 MB a layer a step: 0.33 ms at the HBM peak
+    assert 0.26e9 < ub < 0.28e9            # 270 MB a layer a step were every slot live
+    assert fam.ssm_update_cost(cfg, 2.5) == (uf * 2.5 / 64, ub * 2.5 / 64)   # linear in the slots
+    # a step's need: the state of the 2.5 LIVE slots, not of the slab's 64
     flops, bytes_ = fam.decode_step_cost(cfg, 2.5, 400)
     touched = fam.experts_touched(cfg, 2.5)
     assert bytes_ == pytest.approx(
         whole - 2 * 6 * (64 - touched) * 9977856 + 2.5 * 401 * 2048
-        + 2 * 64 * (12582912 + 6 * 3 * 6144 * 2))
-    assert 3.4e9 < bytes_ < 3.8e9          # of which the state's 1.64 GB: the Mamba layers are half
+        + 2 * 2.5 * (12582912 + 6 * 3 * 6144 * 2))
+    assert 2.0e9 < bytes_ < 2.2e9          # of which the live state's 0.064 GB (1.64 over the slab)
     per_tok = 2 * (6 * 38737920 + 2 * 23396352
                    + 6 * (3 * 9977856 + 19955712 + 2688 * 128) + 2688 * 65536)
-    assert flops == pytest.approx(2.5 * (per_tok + 2 * 32 * 4 * 128 * 400) + 6 * uf)
+    assert flops == pytest.approx(2.5 * (per_tok + 2 * 32 * 4 * 128 * 400)
+                                  + 6 * uf * 2.5 / 64)
     gf, gb = fam.grouped_product_cost(cfg, 2.5)
     assert gf == pytest.approx(2 * 2.5 * 3 * 2688 * 1856)
     assert gb == pytest.approx(touched * 2688 * 1856 * 2 + 2.5 * 3 * (2688 + 1856) * 2)
@@ -245,13 +248,25 @@ def test_update_roofline_reads_the_decode_programs_updates_by_shape_or_by_name(f
     assert _ssm.state_dims(obs) == (6, 64, 64, 64, 128)
     found = _ssm.decode_update_ops(obs)
     assert sorted(found.values()) == [0.01, 0.16, 0.30, 0.30]
-    _, bytes_ = fam.ssm_update_cost(cfg, 64)
+    # the need is the 2 LIVE slots' (no access line here: the window's rows by /metrics),
+    # whatever the ops stepped: 2 / 64 of what a full slab needs in the time of a full slab
+    _, bytes_ = fam.ssm_update_cost(cfg, 2.0)
     need = bytes_ / 819e9 * 6 * 400            # memory-bound; 6 layers x 100 chunks x 4 steps
     assert roof.read(obs) == pytest.approx(100.0 * need / 0.77, rel=1e-6)
-    assert 0 < roof.read(obs) < 105
+    assert 3.0 < roof.read(obs) < 3.5
+    full = _Obs(obs, rows_per_step=64.0)       # every slot live: the reading of PR 34 to 53
+    assert roof.read(full) == pytest.approx(32 * roof.read(obs)) and roof.read(full) < 105
     named = dict(chunk_ops, **{"ssm_update.3_f32_6_64_64_64_128_4_3_2_1_0_T": 0.9})
     obs2 = _Obs(obs, trace=dict(obs.trace, ops_by_program={"_chunk_impl": named}))
     assert list(_ssm.decode_update_ops(obs2).values()) == [0.9]
+    # THE NAME COMES FIRST: a kernel that steps the live slots alone has no operand of
+    # the slab's shape, and fusions of that shape beside it are no part of the update
+    live = {"ssm_update.3_f32_6_2_64_64_128_4_3_2_1_0_T_8_128_f32_2_64_64": 0.02,
+            "ssm_update.4_f32_2_64_64_128_3_2_1_0_T_8_128": 0.01,
+            "fusion.7_f32_6_64_64_64_128_4_3_2_1_0_T": 0.30, "gmm.5_bf16_384_2688_1_0_T_8_128": 0.06}
+    obs3 = _Obs(obs, trace=dict(obs.trace, ops_by_program={"_chunk_impl": live}))
+    assert sorted(_ssm.decode_update_ops(obs3).values()) == [0.01, 0.02]
+    assert roof.read(obs3) == pytest.approx(100.0 * need / 0.03, rel=1e-6)
 
 
 def _request_line(received, counters):

@@ -42,9 +42,10 @@ def test_the_configuration_names_its_family_and_the_loader_finds_the_file(fam):
     assert entry["source"] == cfg["source"]
     (cell,) = [w for w in bench["workloads"] if w["config"] == "sdar-30b-a3b-chat"]
     assert (cell["name"], cell["traffic"], cell["chips"]) == ("sdar.chat", "chat", 1)
+    # found by name, wherever later metrics of the cell were appended
     mine = [m["name"] for m in bench["per_layer"] if m.get("workloads") == ["sdar.chat"]]
-    assert sorted(mine) == sorted(MINE)
-    for name in mine:  # each reader agrees with its entry
+    assert set(MINE) <= set(mine)
+    for name in MINE:  # each reader agrees with its entry
         (e,) = [m for m in bench["per_layer"] if m["name"] == name]
         mod = metrics.load_reader(BENCH, name)
         assert (mod.UNIT, mod.LAYER, mod.MOVES) == (e["unit"], e["layer"], e["moves"])

@@ -84,3 +84,74 @@ def test_stratified_order_spreads_the_work_evenly():
 def test_buckets_reached():
     assert traffic.buckets_reached(CHAT, 51, [32, 128, 512, 1024]) == [128, 512, 1024]
     assert traffic.buckets_reached(dict(CHAT, rate_rps=20.0), 51, [32, 128, 512, 1024]) == [32, 128, 512, 1024]
+
+
+# -- "placement": "ring" (PR 54): the order and the places are part of the work
+
+RING = dict(CHAT, rate_rps=1.4, placement="ring")
+
+
+def _turn(reqs):
+    """A run's window as (prompt, output, place inside the slot) by slot."""
+    w = _window(reqs)
+    slot = 51.0 / len(w)
+    return [(r.prompt_len, r.max_new, round(r.due / slot - i, 9)) for i, r in enumerate(w)]
+
+
+def test_the_default_placement_draws_what_it_drew_before_the_ring():
+    import hashlib
+    import json
+    spec = dict(CHAT, rate_rps=2.5)  # lfm2.chat's: the digests are the parent's generator's
+    for seed, digest in ((3, "c9c328b90863e250"), (2 ** 31 + 11, "16963e365234b32d")):
+        r = traffic.open_loop(spec, seed, 51, 65536)
+        text = json.dumps([(q.phase, q.prompt_len, q.max_new, q.due, q.prompt_ids) for q in r])
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+    with pytest.raises(ValueError):
+        traffic.open_loop(dict(CHAT, placement="clumped"), 1, 51, 32000)
+
+
+def test_a_ring_is_the_same_turn_for_every_seed_begun_elsewhere():
+    a, b = (_turn(traffic.open_loop(RING, s, 51, 32000)) for s in (3, 2 ** 31 + 11))
+    assert len(a) == len(b) == 71 and a != b
+    k = b.index(a[0])
+    assert b[k:] + b[:k] == a  # sizes AND places: who meets whom never changes
+    assert sorted((p, o) for p, o, _ in a) == sorted(traffic.multiset(RING, 71))
+    ids = [[r.prompt_ids for r in _window(traffic.open_loop(RING, s, 51, 32000))] for s in (3, 4)]
+    assert ids[0] != ids[1]  # the token ids are the seed's
+    assert traffic.open_loop(RING, 5, 51, 32000)[7].prompt_ids == \
+        traffic.open_loop(RING, 5, 51, 32000)[7].prompt_ids
+
+
+@pytest.mark.parametrize("rate", [1.4, 2.2, 3.9])
+def test_a_ring_s_lead_in_and_tail_are_its_own_neighbours(rate):
+    spec = dict(RING, rate_rps=rate)
+    n = round(rate * 51)
+    slot = 51.0 / n
+    for seed in (1, 2 ** 31 + 7):
+        reqs = traffic.open_loop(spec, seed, 51, 32000)
+        assert [r.due for r in reqs] == sorted(r.due for r in reqs)
+        assert [r.idx for r in reqs] == list(range(len(reqs)))
+        w = _window(reqs)
+        lead = [r for r in reqs if r.phase == "lead"]
+        tail = [r for r in reqs if r.phase == "tail"]
+        assert len(w) == n and len(lead) == int(8.0 / slot) and len(tail) == int(15.0 / slot)
+        assert -8.0 <= lead[0].due and lead[-1].due < 0.0 <= w[0].due
+        assert w[-1].due < 51.0 <= tail[0].due and tail[-1].due < 66.0
+        # one period earlier and later: the same request at the same place
+        for x, y in zip(lead, w[-len(lead):]):
+            assert (x.prompt_len, x.max_new) == (y.prompt_len, y.max_new)
+            assert x.due == pytest.approx(y.due - 51.0)
+        for x, y in zip(tail, w):
+            assert (x.prompt_len, x.max_new) == (y.prompt_len, y.max_new)
+            assert x.due == pytest.approx(y.due + 51.0)
+        for r in reqs:
+            assert len(r.prompt_ids) == r.prompt_len and r.prompt_len + r.max_new < 1024
+
+
+def test_a_ring_warms_the_window_s_buckets_and_two_cells_ask_for_it():
+    import os
+    assert traffic.buckets_reached(RING, 51, [32, 128, 512, 1024]) == [128, 512, 1024]
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for cell in ("mixtral.chat", "nemotron3.chat"):  # by name: a later cell may ask too
+        assert traffic.load_traffic(here, "chat", cell)["placement"] == "ring"
+    assert "placement" not in traffic.load_traffic(here, "chat", "lfm2.chat")

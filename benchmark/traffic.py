@@ -6,7 +6,16 @@ Every run of a cell offers the same work: the multiset of (prompt,
 output) lengths is the N quantile midpoints of the file's distributions,
 paired by a shuffle that never sees --seed. The seed orders the pairs,
 places the arrivals and draws the token ids: two seeds are two traces of
-the same work."""
+the same work.
+
+Where a cell's file says `"placement": "ring"` the order and the places
+are part of the work too (`ring`): the window is one turn of a fixed
+ring of requests, the seed says at which of them the turn begins, and
+the lead-in and the tail are the ring's requests before and after. Which
+request meets which is then the same for every seed. That is for a cell
+in which a live row is a large part of a step, where which requests
+overlap moved TPOT more from seed to seed than any change would (PERF.md
+section 2)."""
 
 from __future__ import annotations
 
@@ -114,11 +123,47 @@ def arrival_times(n: int, span: float, rng: random.Random) -> List[float]:
     return [(i + rng.random()) * span / n for i in range(n)]
 
 
+def ring(spec: Dict, n: int) -> Tuple[List[Tuple[int, int]], List[float]]:
+    """The window's n requests as one turn of a ring that never sees
+    --seed: the multiset in a stratified order, and each request's place
+    inside its slot of 1/rate seconds."""
+    rng = random.Random(_PAIRING_SEED + 7 * n)
+    pairs = seeded_order(multiset(spec, n), rng.randrange(1 << 30),
+                         int(spec["stratify"]))
+    return pairs, [rng.random() for _ in range(n)]
+
+
+def ring_loop(spec: Dict, seed: int, seconds: float, vocab: int) -> List[Request]:
+    """`open_loop` for `"placement": "ring"`: slot j of the run (j < 0 the
+    lead-in, j >= n the tail) holds request (j + k) % n of the ring at
+    the ring's own place inside the slot, k drawn from the seed. Every
+    window request has the same neighbours at the same distances for
+    every k; the seed turns the ring and draws the token ids."""
+    rng = random.Random(seed)
+    n = int(round(float(spec["rate_rps"]) * seconds))
+    slot = float(seconds) / n
+    pairs, places = ring(spec, n)
+    k = rng.randrange(n)
+    out: List[Request] = []
+    for j in range(-int(float(spec["lead_in_s"]) / slot),
+                   n + int(float(spec["tail_s"]) / slot)):
+        (p, o), u = pairs[(j + k) % n], places[(j + k) % n]
+        phase = "lead" if j < 0 else "window" if j < n else "tail"
+        out.append(Request(len(out), phase, p, o, (j + u) * slot,
+                           [rng.randrange(vocab) for _ in range(p)]))
+    return out
+
+
 def open_loop(spec: Dict, seed: int, seconds: float, vocab: int) -> List[Request]:
     """Lead-in, window and tail of a cell, sorted by due time. The window
     holds exactly round(rate * seconds) requests; the lead-in and the
     tail are the same mix at the same rate with their own fixed
-    multisets. Only window requests are sampled."""
+    multisets (`"placement": "ring"`: the ring's neighbours, `ring_loop`).
+    Only window requests are sampled."""
+    if spec.get("placement", "seeded") == "ring":
+        return ring_loop(spec, seed, seconds, vocab)
+    if spec.get("placement", "seeded") != "seeded":
+        raise ValueError(f"unknown placement {spec['placement']!r}")
     rate = float(spec["rate_rps"])
     rng = random.Random(seed)
     out: List[Request] = []
@@ -140,7 +185,10 @@ def buckets_reached(spec: Dict, seconds: float, buckets: List[int]) -> List[int]
     """Prompt buckets of the engine that any request of this cell can
     land in (lead-in, window and tail)."""
     reached = set()
-    for span in (spec["lead_in_s"], seconds, spec["tail_s"]):
+    spans = (spec["lead_in_s"], seconds, spec["tail_s"])
+    if spec.get("placement") == "ring":  # lead-in and tail are the window's own
+        spans = (seconds,)
+    for span in spans:
         for p, _ in multiset(spec, int(round(float(spec["rate_rps"]) * span))):
             reached.add(next((b for b in sorted(buckets) if p <= b), max(buckets)))
     return sorted(reached)
